@@ -29,11 +29,12 @@ batch_size = get_config_arg("batch_size", int, 16)
 compute_dtype = get_config_arg("compute_dtype", str, "")
 attn_impl = get_config_arg("attn_impl", str, "auto")  # auto/dense/flash/blockwise/ring/ulysses
 block_k_min = get_config_arg("block_k_min", int, 0)   # 0 = default crossover
+seq_len = get_config_arg("seq_len", int, 33)          # provider sequence length
 
 define_py_data_sources2(
     train_list="demo/model_zoo/lm_train.list", test_list=None,
     module="demo.model_zoo.lm_provider", obj="process",
-    args={"vocab": vocab})
+    args={"vocab": vocab, "seq_len": seq_len})
 
 settings(
     batch_size=batch_size,

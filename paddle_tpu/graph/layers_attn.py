@@ -35,10 +35,9 @@ from paddle_tpu.ops.attention import (
 from paddle_tpu.parameter.argument import Argument
 
 # beyond this many key positions, prefer the O(T)-memory flash/blockwise
-# path.  Measured on v5e (MEASURE/attn_bench, round 4, B4 H8 D64 bf16
-# fwd+bwd): dense wins below 2k keys (0.033 vs 0.036 ms at 1024),
-# blockwise ties at 2048 (0.028 vs 0.030) and dense OOMs by 16k — so the
-# crossover sits at 2048; override per layer with block_k_min
+# path.  The crossover (dense below 2k keys, dense out of memory by 16k)
+# comes from a sweep since withdrawn, see ROADMAP S5 — not measured on this
+# round's chip; override per layer with block_k_min
 _BLOCKWISE_MIN_KEYS = 2048
 
 
@@ -98,8 +97,8 @@ def multi_head_attention_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argumen
 
     mesh = ctx.mesh
     from paddle_tpu.ops import pallas_attention
-    from paddle_tpu.parallel.context import (ring_attn_fn, seq_axis_size,
-                                             ulysses_attn_fn)
+    from paddle_tpu.parallel.context import (flash_attn_fn, ring_attn_fn,
+                                             seq_axis_size, ulysses_attn_fn)
     impl = str(cfg.attrs.get("attn_impl", "auto"))
     if impl not in ("auto", "ring", "ulysses", "flash", "blockwise",
                     "dense"):
@@ -136,6 +135,8 @@ def multi_head_attention_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argumen
                 f"{jax.default_backend()!r}")
         attn_fn = functools.partial(pallas_attention.flash_attention,
                                     **_flash_blocks(cfg))
+        if mesh is not None and mesh.devices.size > 1:
+            attn_fn = flash_attn_fn(mesh, attn_fn)
     elif impl == "blockwise":
         attn_fn = functools.partial(
             blockwise_attention, block_k=int(cfg.attrs.get("block_k", 512)))

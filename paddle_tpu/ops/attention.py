@@ -207,8 +207,8 @@ def blockwise_attention(
                  k_valid, (B, n_blocks * block_k)).reshape(B, n_blocks, block_k), 1, 0))
 
     # remat: without it, scan's backward saves every block's score tile —
-    # n_blocks x [B, H, Tq, block_k] fp32 residuals, measured 32 GB at
-    # T=16384 on v5e (MEASURE/attn_bench round 4) where the whole point of
+    # n_blocks x [B, H, Tq, block_k] fp32 residuals, 32 GB at T=16384 (from
+    # a sweep since withdrawn, see ROADMAP S5) where the whole point of
     # blockwise is O(T) memory.  Recomputing the tile in backward is the
     # standard flash-attention trade and keeps train-mode long context
     # viable on the portable (non-pallas) path too.

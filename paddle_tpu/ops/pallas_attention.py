@@ -177,8 +177,9 @@ def _kv_index(H, H_kv):
 def _in_kernel_precision(*arrays):
     """fp32 inputs get 3-pass (HIGHEST) in-kernel matmuls — the MXU's
     default single-bf16-pass fp32 visibly diverges from a true-fp32
-    reference (measured on v5e: 0.02% of elements out at 2e-3, MEASURE/
-    parity round 4); bf16 inputs keep the fast default, their tolerance
+    reference (0.02% of elements out at 2e-3 in an on-chip parity run
+    since withdrawn, see ROADMAP S5); bf16 inputs keep the fast default,
+    their tolerance
     already absorbs one bf16 rounding."""
     if any(a.dtype == jnp.float32 for a in arrays):
         return jax.lax.Precision.HIGHEST

@@ -107,6 +107,32 @@ def ring_attn_fn(mesh: Mesh, causal_default: bool = False):
     return fn
 
 
+def flash_attn_fn(mesh: Mesh, flash):
+    """An `attn_fn` that runs the Pallas flash kernel `flash` on each
+    device's batch shard.  GSPMD cannot partition a Mosaic kernel (lowering
+    for a multi-chip mesh raises "Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map" — interpret mode on
+    the CPU mesh never does), so under a mesh the call sits in shard_map:
+    batch on `data`, everything else whole.  A `seq` axis > 1 belongs to
+    ring/ulysses, which combine across token shards."""
+    if seq_axis_size(mesh) > 1:
+        raise ValueError(
+            "attn_impl='flash' attends within one device's tokens; a mesh "
+            "with a `seq` axis > 1 needs attn_impl='ring' or 'ulysses'")
+
+    def fn(q, k, v, q_valid=None, k_valid=None, causal=False, scale=None,
+           window=None):
+        def wrapped(q, k, v, qm, km):
+            return flash(q, k, v,
+                         q_valid=qm if q_valid is not None else None,
+                         k_valid=km if k_valid is not None else None,
+                         causal=causal, scale=scale, window=window)
+
+        return _sharded_ctx_call(mesh, wrapped, q, k, v, q_valid, k_valid,
+                                 use_flash=True)
+    return fn
+
+
 def ulysses_attention_sharded(
     mesh: Mesh,
     q: Array, k: Array, v: Array,          # [B, T, H, Dh], T % seq_axis == 0

@@ -15,7 +15,8 @@ import jax
 from paddle_tpu.config.parser import parse_config
 from paddle_tpu.parallel.mesh import mesh_from_flag
 from paddle_tpu.trainer.trainer import Trainer
-from paddle_tpu.utils import FLAGS, get_logger, parse_flags
+from paddle_tpu.utils import (FLAGS, enable_compile_cache, get_logger,
+                              parse_flags)
 
 log = get_logger("main")
 
@@ -29,6 +30,7 @@ def main(argv=None) -> int:
               "[--steps_per_dispatch=K] [--detect_nan] [--profile_dir=DIR] "
               "[--show_parameter_stats_period=N]", file=sys.stderr)
         return 2
+    enable_compile_cache()
 
     if FLAGS.coordinator_address:
         from paddle_tpu.parallel.mesh import init_distributed
@@ -37,6 +39,9 @@ def main(argv=None) -> int:
         log.info("joined cluster as process %d/%d (coordinator %s)",
                  FLAGS.process_id, FLAGS.num_processes,
                  FLAGS.coordinator_address)
+    devs = jax.devices()
+    log.info("devices: %d x %s (platform=%s)", len(devs),
+             devs[0].device_kind, devs[0].platform)
 
     if FLAGS.detect_nan:
         # FP-anomaly trapping (ref: feenableexcept(FE_INVALID|...) at trainer
@@ -76,7 +81,8 @@ def main(argv=None) -> int:
     try:
         if job == "train":
             trainer.train(num_passes=FLAGS.num_passes, log_period=FLAGS.log_period,
-                          save_dir=FLAGS.save_dir or None)
+                          save_dir=FLAGS.save_dir or None,
+                          saving_period=FLAGS.saving_period)
         elif job == "test":
             if trainer.config.test_data_config is None:
                 log.error("--job=test: this config declares no test data "

@@ -1,12 +1,9 @@
-"""Portability shims for the jax API surface this repo targets.
+"""The few jax entry points this repo calls through one name.
 
-The codebase is written against current jax — `jax.shard_map` with its
-`check_vma` flag, `jax.enable_x64` — while older installs (0.4.x) expose
-the same functionality under `jax.experimental` with earlier names
-(`shard_map`'s replication check is `check_rep`; `enable_x64` lives in
-`jax.experimental`).  Importing from here instead of `jax` directly keeps
-every mesh/precision path runnable on both, so the tier-1 suite exercises
-the same code the TPU build runs.
+Written against the installed jax (0.9): `jax.shard_map` with `check_vma`,
+`jax.enable_x64`, `lax.axis_size`, `pltpu.CompilerParams`.  Each function
+is the direct call; the module stays so the import sites have one place to
+change when an API moves.
 """
 
 from __future__ import annotations
@@ -15,41 +12,25 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """`jax.shard_map`, falling back to `jax.experimental.shard_map` with
-    `check_vma` renamed to its pre-rename spelling `check_rep` (same
-    semantics: False opts out of the replication/varying-axes check for
-    bodies — pallas calls, hand-rolled ppermute rings — the checker cannot
-    type)."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
+    """`jax.shard_map`; `check_vma=False` opts out of the varying-axes
+    check for bodies — pallas calls, hand-rolled ppermute rings — the
+    checker cannot type."""
+    kw = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def enable_x64():
-    """`jax.enable_x64()` context manager (f64 checkgrad/test paths),
-    falling back to `jax.experimental.enable_x64`."""
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64()
-    from jax.experimental import enable_x64 as _enable_x64
-    return _enable_x64()
+    """`jax.enable_x64()` context manager (f64 checkgrad/test paths)."""
+    return jax.enable_x64()
 
 
 def axis_size(axis_name) -> int:
-    """`lax.axis_size(name)` inside a shard_map/pmap body; older jax spells
-    it `psum(1, name)` (constant-folded to a static int)."""
-    from jax import lax
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    """`lax.axis_size(name)` inside a shard_map/pmap body."""
+    return jax.lax.axis_size(axis_name)
 
 
 def pallas_tpu_compiler_params(**kw):
-    """`pltpu.CompilerParams` (renamed from `TPUCompilerParams`)."""
+    """`pltpu.CompilerParams`."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kw)
+    return pltpu.CompilerParams(**kw)

@@ -107,8 +107,8 @@ class TestBlockwise:
     def test_backward_memory_stays_blockwise(self):
         """The scan body is rematerialized: backward must NOT save every
         block's score tile (n_blocks x [B,H,Tq,block_k] residuals measured
-        32 GB at T=16384 on v5e, MEASURE/attn_bench round 4 — it OOM'd the
-        chip).  Without remat, temp memory is quadratic in T (n_blocks
+        32 GB at T=16384 in a sweep since withdrawn, see ROADMAP S5 — it
+        ran the chip out of memory).  Without remat, temp memory is quadratic in T (n_blocks
         tiles, each itself linear in T): doubling T must NOT ~4x the
         compiled backward's temp bytes.  Measured with remat: 106.9 ->
         246.6 MB (2.3x); without: would be >= 4.3x."""
@@ -367,3 +367,54 @@ class TestUlysses:
                               np.ones((2, 4), np.int32), max_new=3,
                               use_cache=True)
         assert np.asarray(toks).shape == (2, 7)
+
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_flash_attn_fn_shards_batch_over_data_axis(masked):
+    """Under a mesh the flash kernel runs inside shard_map on each device's
+    batch shard (GSPMD cannot partition a Mosaic kernel — the chip's
+    lowering refuses); values and gradients equal the unsharded call."""
+    import functools
+
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.ops.pallas_attention import flash_attention
+    from paddle_tpu.parallel.context import flash_attn_fn
+    from paddle_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(data=4, devices=jax.devices()[:4])
+    rng = np.random.default_rng(0)
+    B, T, H, D = 8, 16, 2, 8
+    q, k, v = (jnp.asarray(rng.normal(size=(B, T, H, D)), jnp.float32)
+               for _ in range(3))
+    valid = (jnp.asarray(np.arange(T)[None, :] <
+                         rng.integers(1, T + 1, B)[:, None])
+             if masked else None)
+    flash = functools.partial(flash_attention, block_q=8, block_k=8)
+    sharded = flash_attn_fn(mesh, flash)
+
+    def loss(fn, q, k, v):
+        return jnp.sum(fn(q, k, v, q_valid=valid, k_valid=valid,
+                          causal=True) ** 2)
+
+    put = lambda x: jax.device_put(x, NamedSharding(mesh, P("data")))
+    got = jax.jit(jax.value_and_grad(functools.partial(loss, sharded),
+                                     argnums=(0, 1, 2)))(put(q), put(k),
+                                                         put(v))
+    want = jax.value_and_grad(functools.partial(loss, flash),
+                              argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_flash_attn_fn_refuses_a_seq_axis():
+    import jax
+
+    from paddle_tpu.parallel.context import flash_attn_fn
+    from paddle_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(data=2, seq=2, devices=jax.devices()[:4])
+    with pytest.raises(ValueError, match="ring"):
+        flash_attn_fn(mesh, None)

@@ -1,16 +1,16 @@
-"""bench.py orchestrator logic — the record must always be parseable.
-
-Unit-tests the pieces that made BENCH_r02 unrecoverable when they were
-missing: last-known-good selection (newest complete record, errored/skipped
-extras stripped), the degraded-record merge, and the PERF_LOG append gate.
-The live subprocess paths (child bench, wedged-backend degradation) are
-exercised against the real backend by the driver and tools/tpu_measure.py.
+"""bench.py's record helpers: last-known-good selection (newest complete
+record, errored/skipped extras stripped), the degraded-record merge, and
+the PERF_LOG append gate.  `bench.py:main()` no longer reaches the
+last-known-good helpers (no TPU or a failed headline exits 1 and replays
+nothing); they and these tests go with the benchmark PR (ROADMAP D1).
 """
 
 import importlib.util
 import json
 import os
 import sys
+
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -353,10 +353,8 @@ def test_assemble_lkg_stitches_serving_spill_record(tmp_path):
 
 
 def test_serving_latency_fields_ride_the_lkg_and_freshness_paths(tmp_path):
-    """PR 4 wiring: the serving record's p99 per-token latency companion
-    (lm_serving_p99_tok_latency_ms) must survive _assemble_lkg, and the
-    tpu_measure queue's freshness gate must treat a record WITHOUT the
-    field as stale (pre-latency-era records force one re-measure)."""
+    """The serving record's p99 per-token latency companion
+    (lm_serving_p99_tok_latency_ms) must survive _assemble_lkg."""
     bench = _load_bench()
     M = bench._METRIC_OF
     log = tmp_path / "PERF_LOG.jsonl"
@@ -372,25 +370,6 @@ def test_serving_latency_fields_ride_the_lkg_and_freshness_paths(tmp_path):
     bench._PERF_LOG = str(log)
     out = bench._assemble_lkg()
     assert out["serving"]["lm_serving_p99_tok_latency_ms"] == 9.7
-
-    # freshness: need_field distinguishes the eras (tools/tpu_measure.py
-    # passes it for the bench_serving_record step)
-    sys.path.insert(0, os.path.join(REPO, ""))
-    os.environ["BENCH_PERF_LOG"] = str(log)
-    try:
-        import importlib
-
-        import tools.tpu_measure as tm
-        importlib.reload(tm)
-        assert tm._metric_fresh(M["serving"], 1e6,
-                                need_field="lm_serving_p99_tok_latency_ms")
-        # only the latency-era record satisfies it: rewrite with old alone
-        log.write_text(json.dumps(old) + "\n")
-        assert not tm._metric_fresh(
-            M["serving"], 1e6, need_field="lm_serving_p99_tok_latency_ms")
-        assert tm._metric_fresh(M["serving"], 1e6)
-    finally:
-        del os.environ["BENCH_PERF_LOG"]
 
 
 def test_assemble_lkg_decode_only_survives_missing_train(tmp_path):
@@ -465,8 +444,8 @@ def test_degraded_record_merges_lkg(tmp_path):
                     "value": 123.0, "vs_baseline": 2.5, "mfu": 0.41,
                     "platform": "tpu"}}) + "\n")
     bench._PERF_LOG = str(log)
-    out = bench._degraded_record("tunnel died")
-    assert out["error"] == "tunnel died" and out["degraded"] is True
+    out = bench._degraded_record("backend died")
+    assert out["error"] == "backend died" and out["degraded"] is True
     assert out["value"] == 123.0 and out["mfu"] == 0.41
     assert out["platform"] == "tpu"           # provenance preserved
     assert "last-known-good" in out["degraded_source"]
@@ -557,7 +536,7 @@ def test_assemble_lkg_skips_degraded_records_explicitly(tmp_path):
         # a degraded fallback record echoing LKG parts (parent flag) —
         # its nested serving echo must not read as a fresh measurement
         {"ts": "2026-07-31T10:00:00+00:00",
-         "record": {"error": "tunnel died", "degraded": True,
+         "record": {"error": "backend died", "degraded": True,
                     "metric": M["vgg"], "value": 100.0,
                     "serving": {"metric": M["serving"], "value": 777.0,
                                 "measured_at":
@@ -613,3 +592,39 @@ def test_assemble_lkg_stitches_train_dist_record(tmp_path):
     assert out["train_dist"]["train_dist_trace_overhead_pct"] == 0.8
     assert out["train_dist"]["trace_overhead_spread_pct"] == 2.1
     assert out["train_dist"]["trace_off_samples_per_sec"] == 5400.0
+
+
+def test_main_without_a_tpu_exits_1_and_replays_nothing(tmp_path):
+    """No TPU: the error on stderr, nothing on stdout (no last-known-good
+    number replayed from PERF_LOG.jsonl), exit 1."""
+    import subprocess
+
+    log = tmp_path / "PERF_LOG.jsonl"
+    log.write_text(json.dumps({
+        "ts": "2026-08-01T10:00:00+00:00",
+        "record": {"metric": "vgg16_cifar10_train_samples_per_sec_per_chip",
+                   "value": 51393.97, "platform": "tpu"}}) + "\n")
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bench.py")], cwd=REPO,
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "BENCH_PERF_LOG": str(log)},
+        capture_output=True, text=True, timeout=300)
+    assert out.returncode == 1
+    assert out.stdout.strip() == ""
+    assert "no TPU backend" in out.stderr
+    assert "51393" not in out.stdout + out.stderr
+
+
+def test_unknown_device_kind_has_no_assumed_peak(monkeypatch):
+    import types
+
+    import jax
+
+    bench = _load_bench()
+    monkeypatch.setattr(jax, "devices", lambda: [
+        types.SimpleNamespace(device_kind="TPU v5 lite")])
+    assert bench._chip_peak_tflops("bfloat16") == 197.0
+    monkeypatch.setattr(jax, "devices", lambda: [
+        types.SimpleNamespace(device_kind="Some Future Chip")])
+    with pytest.raises(ValueError, match="some future chip"):
+        bench._chip_peak_tflops("bfloat16")

@@ -139,3 +139,20 @@ def test_load_canonicalizes_key_order(tmp_path):
     for name in out["opt"]["slots"]:
         assert list(out2["opt"]["slots"][name]) == \
             list(out["opt"]["slots"][name])
+
+
+def test_train_saving_period_skips_passes_but_keeps_last_and_metrics(tmp_path):
+    """--saving_period=N: a checkpoint every N passes and after the last
+    pass; the metrics row still lands every pass."""
+    from paddle_tpu.config.parser import parse_config
+    from paddle_tpu.trainer.trainer import Trainer
+
+    cfg = parse_config("demo/model_zoo/transformer_lm.py",
+                       "vocab=32,dim=16,layers=1,heads=2,batch_size=64")
+    save_dir = str(tmp_path / "run")
+    Trainer(cfg, seed=1).train(num_passes=3, log_period=0, save_dir=save_dir,
+                               saving_period=2)
+    passes = sorted(d for d in os.listdir(save_dir) if d.startswith("pass-"))
+    assert passes == ["pass-00001", "pass-00002"]
+    with open(os.path.join(save_dir, "metrics.jsonl")) as f:
+        assert len(f.readlines()) == 3

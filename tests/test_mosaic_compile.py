@@ -1,0 +1,261 @@
+"""Ask the TPU v5e's compiler (Mosaic, via a DESCRIBED chip — nothing runs)
+whether the Pallas kernels compile at the widths the demos use.
+
+Interpret mode never checks Mosaic's block, tiling and VMEM rules, so a
+kernel can pass every CPU test and be refused on first contact with the
+chip.  These cases cost ~1-3 s each and no chip time.
+
+Rules this file keeps (on-chip-measurement guide §2): the topology is
+described inside a module-scoped fixture, never at import time; shapes and
+shardings are built in fixtures/tests; compiles run in the test's own
+process with the persistent compile cache off; all cases live in THIS one
+file so a single xdist worker owns the TPU library.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 — any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip — keep these compiles out of it
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def mosaic(one_chip, no_persistent_cache, monkeypatch):
+    """Compile `fn` for one described v5e chip and require a Mosaic kernel
+    in the result.  The kernels ask `jax.default_backend()` (sees `cpu`
+    here) to pick interpret mode — steer that from the test."""
+    from paddle_tpu.ops import (pallas_additive, pallas_attention,
+                                pallas_paged, pallas_rnn)
+    for mod in (pallas_additive, pallas_attention, pallas_paged, pallas_rnn):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+
+    def compile_(fn, *shapes):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in shapes]
+        compiled = jax.jit(fn).lower(*args).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        return compiled
+
+    return compile_
+
+
+bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+# ---------------------------------------------------------------------------
+# flash attention — the LM trainer's kernel (B=64, H=8, T=512, D=64, bf16)
+# ---------------------------------------------------------------------------
+
+FLASH_CASES = {
+    "lm": dict(B=64, T=512, H=8, H_kv=8),
+    "gqa": dict(B=64, T=512, H=8, H_kv=2),
+    "ragged_T500": dict(B=64, T=500, H=8, H_kv=8),
+}
+
+
+def _flash_shapes(B, T, H, H_kv, D=64):
+    return [((B, T, H, D), bf16), ((B, T, H_kv, D), bf16),
+            ((B, T, H_kv, D), bf16)]
+
+
+@pytest.mark.parametrize("case", list(FLASH_CASES))
+def test_flash_forward(mosaic, case):
+    from paddle_tpu.ops.pallas_attention import flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=128,
+                               block_k=128)
+
+    mosaic(fwd, *_flash_shapes(**FLASH_CASES[case]))
+
+
+@pytest.mark.parametrize("case", list(FLASH_CASES))
+def test_flash_backward(mosaic, case):
+    from paddle_tpu.ops.pallas_attention import flash_attention
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, causal=True, block_q=128, block_k=128)
+        return jnp.sum(o.astype(f32))
+
+    mosaic(jax.grad(loss, argnums=(0, 1, 2)),
+           *_flash_shapes(**FLASH_CASES[case]))
+
+
+def test_flash_under_data_mesh(topo, no_persistent_cache, monkeypatch):
+    """`--mesh_shape=data:4` with attn_impl=flash: lowering the bare kernel
+    over a 4-chip mesh raises "Mosaic kernels cannot be automatically
+    partitioned"; parallel/context.py:flash_attn_fn wraps it in shard_map.
+    Forward and backward for four described chips."""
+    import functools
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.ops import pallas_attention
+    from paddle_tpu.parallel.context import flash_attn_fn
+    from paddle_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setattr(pallas_attention, "_interpret", lambda: False)
+    mesh = make_mesh(data=4, devices=topo.devices)
+    attn = flash_attn_fn(mesh, functools.partial(
+        pallas_attention.flash_attention, block_q=128, block_k=128))
+
+    def loss(q, k, v):
+        return jnp.sum(attn(q, k, v, causal=True).astype(f32))
+
+    sh = NamedSharding(mesh, P("data"))
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sh)
+            for s, d in _flash_shapes(**FLASH_CASES["lm"])]
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# paged decode — the serving engine's kernel (16 slots, page 16, context 768)
+# ---------------------------------------------------------------------------
+
+S, PAGE, CTX, H_KV, DH = 16, 16, 768, 8, 64
+MAXP = CTX // PAGE
+POOL = S * MAXP + 1               # worst-case pool + the trash page
+
+
+def _pool_shapes():
+    return [((POOL, PAGE, H_KV, DH), bf16), ((POOL, PAGE, H_KV, DH), bf16),
+            ((S, MAXP), i32)]
+
+
+def test_paged_decode(mosaic):
+    from paddle_tpu.ops import pallas_paged
+
+    def step(q, kp, vp, table, lengths):
+        return pallas_paged.paged_attention(q, kp, vp, table, lengths)
+
+    mosaic(step, ((S, 8, DH), bf16), *_pool_shapes(), ((S,), i32))
+
+
+def test_paged_decode_through_attention_step(mosaic):
+    """The call the engine makes (ops/attention.py:paged_attention_step):
+    scatter the new token's k/v, then the kernel reads the pool."""
+    from paddle_tpu.ops.attention import paged_attention_step
+
+    def step(q, k, v, kp, vp, table, pos):
+        return paged_attention_step(q, k, v, kp, vp, table, pos,
+                                    use_kernel=True)
+
+    mosaic(step, ((S, 1, 8, DH), bf16), ((S, 1, H_KV, DH), bf16),
+           ((S, 1, H_KV, DH), bf16), *_pool_shapes(), ((S,), i32))
+
+
+def test_paged_mixed_rows(mosaic):
+    """Row-indirected mixed prefill/decode (and spec-verify) form at the
+    engine's default max_step_tokens = prefill_chunk + slots = 4*16 + 16."""
+    from paddle_tpu.ops.attention import ragged_paged_attention_step
+    T = 4 * PAGE + S
+
+    def step(q, k, v, kp, vp, table, row_slot, row_pos):
+        return ragged_paged_attention_step(q, k, v, kp, vp, table, row_slot,
+                                           row_pos, use_kernel=True)
+
+    mosaic(step, ((T, 8, DH), bf16), ((T, H_KV, DH), bf16),
+           ((T, H_KV, DH), bf16), *_pool_shapes(), ((T,), i32), ((T,), i32))
+
+
+# ---------------------------------------------------------------------------
+# fused recurrent kernels — sentiment LSTM, seq2seq GRU
+# ---------------------------------------------------------------------------
+
+def _lstm_loss(x4, lengths, w, peeps, h0, c0):
+    from paddle_tpu.ops.pallas_rnn import lstm_fused
+    hs, h_last, c_last = lstm_fused(
+        x4, lengths, w, peeps, h0, c0, active_type="tanh",
+        gate_active_type="sigmoid", state_active_type="tanh", reverse=False)
+    return jnp.sum(hs) + jnp.sum(h_last) + jnp.sum(c_last)
+
+
+def _lstm_shapes(B, T, D):
+    return [((B, T, 4 * D), f32), ((B,), i32), ((D, 4 * D), f32),
+            ((3, D), f32), ((B, D), f32), ((B, D), f32)]
+
+
+def test_lstm_forward(mosaic):
+    mosaic(_lstm_loss, *_lstm_shapes(128, 100, 512))
+
+
+def test_lstm_backward(mosaic):
+    mosaic(jax.grad(_lstm_loss, argnums=(0, 2, 3, 4, 5)),
+           *_lstm_shapes(128, 100, 512))
+
+
+@pytest.mark.xfail(strict=True, reason=(
+    "Mosaic: RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem ... "
+    "Scoped allocation with size 23.02M and limit 16.00M — the [D,4D] fp32 "
+    "weight plus the per-step [B,4D] blocks at D=1024, B=128 (ROADMAP S1)"))
+def test_lstm_forward_d1024_exceeds_scoped_vmem(mosaic):
+    mosaic(_lstm_loss, *_lstm_shapes(128, 100, 1024))
+
+
+def _gru_loss(x3, lengths, wg, wc, h0):
+    from paddle_tpu.ops.pallas_rnn import gru_fused
+    hs, h_last = gru_fused(x3, lengths, wg, wc, h0, active_type="tanh",
+                           gate_active_type="sigmoid", reverse=False)
+    return jnp.sum(hs) + jnp.sum(h_last)
+
+
+def _gru_shapes(B, T, D):
+    return [((B, T, 3 * D), f32), ((B,), i32), ((D, 2 * D), f32),
+            ((D, D), f32), ((B, D), f32)]
+
+
+def test_gru_forward(mosaic):
+    mosaic(_gru_loss, *_gru_shapes(64, 30, 512))
+
+
+def test_gru_backward(mosaic):
+    mosaic(jax.grad(_gru_loss, argnums=(0, 2, 3, 4)),
+           *_gru_shapes(64, 30, 512))
+
+
+# ---------------------------------------------------------------------------
+# fused additive-attention step — seq2seq decoder (B=64, T=30, D=512)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dtype", [bf16, f32], ids=["bf16", "fp32"])
+def test_additive_step(mosaic, dtype):
+    from paddle_tpu.ops.pallas_additive import additive_attention_step
+    B, T, D = 64, 30, 512
+
+    def step(dec, w, v, proj, seq, lengths):
+        return additive_attention_step(dec, w, v, proj, seq, lengths=lengths)
+
+    mosaic(step, ((B, D), dtype), ((D, D), dtype), ((D,), dtype),
+           ((B, T, D), dtype), ((B, T, D), dtype), ((B,), i32))

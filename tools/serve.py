@@ -203,8 +203,14 @@ async def amain(args) -> int:
                         role=args.role)
     try:
         host, port = await srv.start()
+        import jax
+
+        dev = jax.devices()
         print("SERVE_JSON:" + json.dumps(
-            {"host": host, "port": port, "pid": os.getpid()}), flush=True)
+            {"host": host, "port": port, "pid": os.getpid(),
+             "device": {"platform": dev[0].platform,
+                        "kind": dev[0].device_kind, "count": len(dev)}}),
+            flush=True)
 
         stop = asyncio.Event()
         loop = asyncio.get_running_loop()
@@ -370,6 +376,9 @@ def main(argv=None) -> int:
 
     if args.client:
         return run_client(args)
+    from paddle_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     return asyncio.run(amain(args))
 
 

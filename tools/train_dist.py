@@ -88,7 +88,9 @@ def main(argv=None) -> int:
     from paddle_tpu.config.parser import parse_config
     from paddle_tpu.optim.remote_updater import RemoteParameterUpdater
     from paddle_tpu.trainer.trainer import Trainer
+    from paddle_tpu.utils import enable_compile_cache
 
+    enable_compile_cache()
     tracer = None
     if args.trace_out:
         from paddle_tpu.obs import get_tracer
