@@ -1,0 +1,13 @@
+"""Device idle time while the trainer loop waited for its next batch
+(`pt.train.next_batch`) or placed it (`pt.train.stage`: shard_batch, the key
+split), % of the traced window: benchmark/lib/phases.py."""
+from benchmark.lib.phases import Phases
+
+LAYER = "trainer loop"
+UNIT = "%"
+MOVES = "train_tokens_per_s_per_chip"
+
+
+def read(ctx):
+    ph = Phases.of(ctx, "train")
+    return None if ph is None else ph.idle_share("input")

@@ -18,6 +18,7 @@ from typing import Iterator, Optional
 import numpy as np
 
 from paddle_tpu.data.provider import DataProviderWrapper, InputType, SeqType, SlotKind
+from paddle_tpu.obs.trace import get_tracer
 from paddle_tpu.parameter.argument import Argument
 
 
@@ -341,11 +342,16 @@ class DataFeeder:
                 chunks.pop()
         if self.shuffle and self.bucket_by_length:
             self.rng.shuffle(chunks)
+        tracer = get_tracer()
         for chunk in chunks:
-            batch = make_batch(chunk, self.types, self.names)
-            for name, val in zip(self._const_names, self.constant_slots):
-                batch[name] = Argument(
-                    value=np.full((len(chunk), 1), val, np.float32))
+            # on the prefetch thread under prefetched_batches(), else on
+            # the trainer's own (then inside its pt.train.next_batch)
+            with tracer.span("pt.feeder.make_batch", track="feeder",
+                             n=len(chunk)):
+                batch = make_batch(chunk, self.types, self.names)
+                for name, val in zip(self._const_names, self.constant_slots):
+                    batch[name] = Argument(
+                        value=np.full((len(chunk), 1), val, np.float32))
             yield batch
 
     def prefetched_batches(self) -> Iterator[dict[str, Argument]]:

@@ -7,7 +7,10 @@ dependency-light serving client path without pulling in jax):
     serving pump, per-dispatch phases on the trainer), exportable as
     structured JSONL and Chrome `trace_event` JSON (Perfetto-loadable;
     `tools/trace_dump.py`).  `get_tracer()` is the process-global
-    instance, disabled by default.
+    instance, disabled by default.  Its `span()` also enters a
+    `jax.profiler.TraceAnnotation` (looked up only once the process has
+    imported JAX), so the `pt.` phase spans land on a profiler trace's
+    host plane beside the device's ops.
   * `obs.metrics` — a registry of counters/gauges/histograms with labels
     that unifies StatSet, BarrierTimer, and the serving engine's counters
     behind one Prometheus-style `render()` (the server's `metrics` frame)
@@ -19,7 +22,8 @@ dependency-light serving client path without pulling in jax):
     (`pump_last_step_age_s`, `pump_alive`).
   * `obs.compile_watch` — per-signature jit compile events on a `compile`
     tracer lane with a recompile-storm detector (`get_compile_watch()`,
-    always on — compiles are rare).
+    always on — compiles are rare); every other backend compile is
+    counted under the `pt.` span it happened in.
   * `obs.hbm` — device-memory accounting (KV pool / param / live-array
     bytes plus the backend's own stats, CPU-safe).
   * `obs.flight` — the flight recorder: a bounded structured-event ring

@@ -29,6 +29,21 @@ makes every compile an event:
     `jit_recompile_storms_total` — then stays quiet until the window
     drains (so a sustained storm is one alert, not an alert storm).
 
+  * EVERY backend compile has a site.  Once the process has JAX, the
+    watcher listens to `jax.monitoring`'s `backend_compile_duration` (one
+    event per executable XLA builds or loads from the cache, on the
+    thread that asked).  An event inside a wrapped call belongs to that
+    site and sets its count; one outside any — an eager op with a new
+    shape: the admission-time `jax.random.split`, the loss drain's
+    `jnp.stack` — is counted as `unwrapped` under the innermost open
+    `pt.` span of that thread (obs/trace.py), else `(unattributed)`.  So
+    `jit_compiles_total` summed over sites accounts for every backend
+    compile of the process, and a compile inside a serving window names
+    the phase that paid for it.  A site's `compiles` stays what it was — new signatures
+    of a step program (each at least 1, more if the call built several
+    executables), what the storm detector watches and what a benchmark
+    holds to 0 inside its window.
+
 Like the tracer and flight recorder this is a process-global singleton
 (`get_compile_watch()`), stdlib-only, and always on: compile events are
 rare enough that there is no flag to forget.
@@ -37,12 +52,55 @@ rare enough that there is no flag to forget.
 from __future__ import annotations
 
 import hashlib
+import sys
 import threading
 import time
 from collections import deque
 
 from paddle_tpu.obs.flight import get_flight_recorder
-from paddle_tpu.obs.trace import get_tracer
+from paddle_tpu.obs.trace import current_span, get_tracer
+
+#: site of a backend compile no wrapped call and no open `pt.` span claims
+UNATTRIBUTED = "(unattributed)"
+
+# per thread: the wrapped calls in flight, innermost last, each a
+# [site, backend compiles seen so far] frame the listener counts into
+_calls = threading.local()
+_listening = False
+
+
+def _frames() -> list:
+    try:
+        return _calls.frames
+    except AttributeError:
+        frames = _calls.frames = []
+        return frames
+
+
+def _on_duration(event: str, seconds: float, **_kw) -> None:
+    """jax.monitoring listener (runs on the compiling thread)."""
+    if not event.endswith("/backend_compile_duration"):
+        return
+    frames = _frames()
+    if frames:
+        frames[-1][1] += 1               # the wrapped call accounts for it
+    else:
+        _watch.record_unwrapped(current_span("pt.") or UNATTRIBUTED, seconds)
+
+
+def listen() -> bool:
+    """Start counting every backend compile (idempotent).  Needs JAX in the
+    process already — a wrapped jit or a trainer brings it; the JAX-free
+    client path never calls this."""
+    global _listening
+    if not _listening and "jax" in sys.modules:
+        try:
+            from jax import monitoring
+            monitoring.register_event_duration_secs_listener(_on_duration)
+            _listening = True
+        except Exception:                  # noqa: BLE001 — no monitoring API
+            pass
+    return _listening
 
 
 def signature_of(args: tuple, kwargs: dict) -> str:
@@ -77,23 +135,29 @@ def signature_of(args: tuple, kwargs: dict) -> str:
 
 
 class _Watch:
-    """Context manager for watch(): records on exit iff the key was new."""
+    """Context manager for watch(): records on exit iff the key was new,
+    or the call built an executable all the same."""
 
-    __slots__ = ("cw", "site", "key", "t0")
+    __slots__ = ("cw", "site", "key", "known", "t0")
 
-    def __init__(self, cw, site, key):
+    def __init__(self, cw, site, key, known):
         self.cw = cw
         self.site = site
         self.key = key
+        self.known = known
 
     def __enter__(self):
+        listen()
+        _frames().append([self.site, 0])
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, *exc):
-        if exc_type is None and self.key is not None:
+        n = _frames().pop()[1]
+        if exc_type is None and (n or not self.known):
             self.cw.note(self.site, self.key,
-                         time.perf_counter() - self.t0, t0=self.t0)
+                         time.perf_counter() - self.t0, t0=self.t0,
+                         backend=n)
         return False
 
 
@@ -113,16 +177,24 @@ class _WatchedJit:
             n0 = fn._cache_size()
         except Exception:                  # noqa: BLE001 — no cache probe
             n0 = None
+        frames = _frames()
+        frame = [self._site, 0]
+        frames.append(frame)
         t0 = time.perf_counter()
-        out = fn(*args, **kwargs)
+        try:
+            out = fn(*args, **kwargs)
+        finally:
+            frames.pop()
+        compiled = False
         if n0 is not None:
             try:
                 compiled = fn._cache_size() > n0
             except Exception:              # noqa: BLE001
-                compiled = False
-            if compiled:
-                self._cw.record(self._site, signature_of(args, kwargs),
-                                time.perf_counter() - t0, t0=t0)
+                pass
+        if compiled or frame[1]:
+            self._cw.record(self._site, signature_of(args, kwargs),
+                            time.perf_counter() - t0, t0=t0,
+                            backend=frame[1])
         return out
 
     def __getattr__(self, name):           # .lower(), ._cache_size(), ...
@@ -137,6 +209,7 @@ class CompileWatch:
         self.storm_window_s = float(storm_window_s)
         self._lock = threading.Lock()
         self.compiles: dict[str, int] = {}        # site -> compile count
+        self.unwrapped: dict[str, int] = {}       # span -> eager compiles
         self.seconds: dict[str, float] = {}       # site -> wall seconds
         self.storms: dict[str, int] = {}          # site -> storms fired
         self._sigs: dict[str, set] = {}           # site -> distinct sigs
@@ -146,6 +219,7 @@ class CompileWatch:
     def clear(self) -> None:
         with self._lock:
             self.compiles.clear()
+            self.unwrapped.clear()
             self.seconds.clear()
             self.storms.clear()
             self._sigs.clear()
@@ -156,6 +230,7 @@ class CompileWatch:
     def wrap_jit(self, site: str, fn) -> _WatchedJit:
         """Wrap a jitted callable; compile events detected by jit-cache
         growth, so repeat signatures cost no signature computation."""
+        listen()
         return _WatchedJit(fn, site, self)
 
     def watch(self, site: str, key) -> _Watch:
@@ -163,25 +238,41 @@ class CompileWatch:
         records a compile event on exit if `key` is new for the site."""
         with self._lock:
             known = key in self._sigs.get(site, ())
-        return _Watch(self, site, None if known else key)
+        return _Watch(self, site, key, known)
 
-    def note(self, site: str, key, seconds: float, t0: float = 0.0) -> None:
+    def note(self, site: str, key, seconds: float, t0: float = 0.0,
+             backend: int = 0) -> None:
         """Record a first-call-for-key event unless the key raced in."""
         with self._lock:
-            if key in self._sigs.get(site, ()):
+            if key in self._sigs.get(site, ()) and not backend:
                 return
-        self.record(site, str(key), seconds, t0=t0, raw_key=key)
+        self.record(site, str(key), seconds, t0=t0, raw_key=key,
+                    backend=backend)
 
     # -- the event ---------------------------------------------------------
+    def record_unwrapped(self, span: str, seconds: float) -> None:
+        """One backend compile outside every wrapped site, in phase `span`
+        (an eager op with a new shape).  Counted apart from `compiles`: it
+        is no new signature of a step program."""
+        with self._lock:
+            self.unwrapped[span] = self.unwrapped.get(span, 0) + 1
+            self.seconds[span] = self.seconds.get(span, 0.0) + seconds
+        get_flight_recorder().record("compile", site=span, sig="(eager)",
+                                     seconds=round(seconds, 4))
+
     def record(self, site: str, sig: str, seconds: float,
-               t0: float = 0.0, raw_key=None) -> None:
-        """One compile happened at `site` with signature `sig`, costing
-        `seconds` of wall time (compile + first run)."""
+               t0: float = 0.0, raw_key=None, backend: int = 0) -> None:
+        """A call at `site` with signature `sig` compiled, costing
+        `seconds` of wall time (compile + first run).  `backend` is how
+        many backend compiles the listener saw inside the call; the site
+        counts them all, and at least 1 (a new signature is a compile
+        event even where XLA had the executable already)."""
         now = time.perf_counter()
         storm = None
         key = raw_key if raw_key is not None else sig
         with self._lock:
-            self.compiles[site] = self.compiles.get(site, 0) + 1
+            self.compiles[site] = self.compiles.get(site, 0) + \
+                max(1, backend)
             self.seconds[site] = self.seconds.get(site, 0.0) + seconds
             self._sigs.setdefault(site, set()).add(key)
             dq = self._recent.setdefault(site, deque())
@@ -217,12 +308,16 @@ class CompileWatch:
             return len(self._sigs.get(site, ()))
 
     def snapshot(self) -> dict:
-        """{site: {"compiles", "seconds", "signatures", "storms"}} — the
-        postmortem-bundle shape."""
+        """{site: {"compiles", "unwrapped", "seconds", "signatures",
+        "storms"}} — the postmortem-bundle shape.  A wrapped site has
+        `compiles`; a `pt.` span (or `(unattributed)`) has `unwrapped`, the
+        eager compiles that happened in that phase; the two summed over
+        all rows are the process's backend compiles."""
         with self._lock:
-            sites = set(self.compiles) | set(self._sigs)
+            sites = set(self.compiles) | set(self._sigs) | set(self.unwrapped)
             return {site: {
                 "compiles": self.compiles.get(site, 0),
+                "unwrapped": self.unwrapped.get(site, 0),
                 "seconds": round(self.seconds.get(site, 0.0), 4),
                 "signatures": len(self._sigs.get(site, ())),
                 "storms": self.storms.get(site, 0),
@@ -240,7 +335,7 @@ def compile_collector(cw: "CompileWatch" = None):
         for site, st in w.snapshot().items():
             labels = {"site": site}
             out.append(("jit_compiles_total", "counter", labels,
-                        float(st["compiles"])))
+                        float(st["compiles"] + st["unwrapped"])))
             out.append(("jit_compile_seconds", "counter", labels,
                         float(st["seconds"])))
             out.append(("jit_signatures", "gauge", labels,

@@ -11,9 +11,12 @@ holds the last `capacity` spans, not its lifetime.
 Design constraints, in order:
 
   1. **Off means off.**  `tracer.enabled` is False by default and every
-     recording entry point checks it first — a disabled tracer costs one
-     attribute read per call site (the bench_serving overhead budget is
-     <= 2% with tracing off).
+     ring write checks it first.  `span()` / `begin()` additionally enter
+     a `jax.profiler.TraceAnnotation` of the same name — the SECOND sink,
+     built only while a profiler session runs (`is_enabled()`, 0.05 us
+     to ask) — so one call site feeds the operator's ring and the
+     profiler's timeline alike (see "Two sinks" below).  With both off a
+     span is its object and the thread's open-span stack: about 0.9 us.
   2. **Single-writer ring.**  Spans are appended by the owning thread
      only; `snapshot()` may run on another thread (drain, a test) and
      copies the list under the GIL, using each record's monotonic `seq`
@@ -28,6 +31,22 @@ horizontal lane the viewer shows — one per request (`req:<id>`), one for
 the engine (`engine`), one for the trainer (`trainer`).  `dur` 0.0 with
 `instant=True` renders as an instant marker (preempt, done).  Times are
 `time.perf_counter()` seconds; exports convert to microseconds.
+
+Two sinks (docs/observability.md "The span model"): `span()` and
+`begin()`/`end()` time a PHASE of the thread that runs it — the `pt.`
+vocabulary, thread then phase (`pt.step.dispatch`, `pt.train.drain`) —
+and feed (a) the ring, while `enabled`, and (b) the profiler trace, while
+a `jax.profiler` session runs, where the event lands on the host plane on
+the same clock as the device planes (the ring's perf_counter stamps cannot
+be lined up with a profiler trace after the fact).  `add()`/`instant()`
+stay ring-only: the per-request lanes (`req:<id>`) overlap and so cannot
+nest on a thread; `annotation()` is the profiler sink alone, for a site
+that runs once a token (`pt.loop.send`).  JAX is looked up lazily and only
+once the process has imported it (a profiler session needs JAX
+in-process), so this module — and the client/router import path — stays
+stdlib-only.  The names of the spans open on the calling thread are kept
+(`current_span()`), which is how obs/compile_watch.py names the phase an
+eager compile happened in.
 
 Distributed tracing (docs/observability.md "Distributed tracing"): a
 request that crosses processes (client → fleet router → replica) carries
@@ -47,8 +66,44 @@ import json
 import os
 import socket
 import sys
+import threading
 import time
 from typing import Optional
+
+# -- the profiler sink and the per-thread stack of open spans ---------------
+_annotation = None       # jax.profiler.TraceAnnotation; False = cannot have it
+_open = threading.local()
+
+
+def _annotation_cls():
+    """jax.profiler.TraceAnnotation once this process has imported JAX,
+    else None: ring-only (a profiler session needs JAX in-process, so no
+    event is lost, and a JAX-free client never pays the import)."""
+    global _annotation
+    if _annotation is None and "jax" in sys.modules:
+        try:
+            from jax.profiler import TraceAnnotation
+            _annotation = TraceAnnotation
+        except ImportError:
+            _annotation = False
+    return _annotation or None
+
+
+def _open_spans() -> list:
+    try:
+        return _open.names
+    except AttributeError:
+        names = _open.names = []
+        return names
+
+
+def current_span(prefix: str = "") -> Optional[str]:
+    """Name of the innermost span open on the CALLING thread whose name
+    starts with `prefix`, or None."""
+    for name in reversed(_open_spans()):
+        if name.startswith(prefix):
+            return name
+    return None
 
 
 def new_trace_id() -> str:
@@ -117,7 +172,7 @@ def flush_trace_file(tracer: "Tracer", path: str, role: str,
 
 
 class _NullSpan:
-    """Shared no-op context manager — the disabled-tracer fast path."""
+    """Shared no-op context manager: `annotation()` outside a session."""
 
     __slots__ = ()
 
@@ -131,26 +186,67 @@ class _NullSpan:
 _NULL = _NullSpan()
 
 
+def annotation(name: str):
+    """The profiler sink alone: a `jax.profiler.TraceAnnotation` while a
+    session runs, else a no-op.  For a per-token site (`pt.loop.send`),
+    where a ring record a frame would wrap the bounded ring in seconds."""
+    cls = _annotation or _annotation_cls()
+    return cls(name) if cls is not None and cls.is_enabled() else _NULL
+
+
 class _Span:
-    """Context manager recording one complete span on exit."""
+    """Context manager for one phase span: enters the profiler annotation,
+    keeps the thread's open-span stack, and on exit feeds the ring (while
+    the tracer is enabled) and `sink` (a callable taking the seconds — a
+    Stat's add, a BarrierTimer window's append) from ONE clock pair."""
 
-    __slots__ = ("tracer", "name", "track", "attrs", "t0")
+    __slots__ = ("tracer", "name", "track", "attrs", "sink", "t0", "ann")
 
-    def __init__(self, tracer, name, track, attrs):
+    def __init__(self, tracer, name, track, attrs, sink=None):
         self.tracer = tracer
         self.name = name
         self.track = track
         self.attrs = attrs
+        self.sink = sink
 
     def __enter__(self):
-        self.t0 = time.perf_counter()
+        try:
+            _open.names.append(self.name)
+        except AttributeError:
+            _open.names = [self.name]
+        cls = _annotation or _annotation_cls()
+        if cls is not None and cls.is_enabled():     # a session runs
+            self.ann = cls(self.name, **self.attrs) if self.attrs \
+                else cls(self.name)
+            self.ann.__enter__()
+        else:
+            self.ann = None
+        self.t0 = time.perf_counter() \
+            if self.sink is not None or self.tracer.enabled else 0.0
         return self
 
     def __exit__(self, *exc):
-        self.tracer.add(self.name, self.t0,
-                        time.perf_counter() - self.t0,
-                        track=self.track, attrs=self.attrs)
+        if self.t0:
+            dur = time.perf_counter() - self.t0
+            if self.sink is not None:
+                self.sink(dur)
+            self.tracer.add(self.name, self.t0, dur, track=self.track,
+                            attrs=self.attrs)
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        names = _open.names
+        if names[-1] is self.name:
+            names.pop()
+        else:                    # a begin()/end() pair closed out of order
+            _close(self.name)
         return False
+
+
+def _close(name: str) -> None:
+    """Drop the innermost `name` from the thread's open spans."""
+    names = _open_spans()
+    if name in names:
+        del names[len(names) - 1 - names[::-1].index(name)]
 
 
 class Tracer:
@@ -183,29 +279,29 @@ class Tracer:
             self._ring[self._n % self.capacity] = rec
         self._n += 1
 
-    def span(self, name: str, track: str = "main", **attrs):
-        """``with tracer.span("prefill", bucket=32): ...`` — records on
-        exit; a shared no-op object when disabled."""
-        if not self.enabled:
-            return _NULL
-        return _Span(self, name, track, attrs or None)
+    def span(self, name: str, track: str = "main", sink=None, **attrs):
+        """``with tracer.span("pt.step.dispatch", track="engine", rows=64):``
+        — one timed phase of the calling thread, fed to both sinks: the
+        profiler annotation while a session runs, the ring while enabled.
+        `sink(seconds)`, when given, is fed from the same clock pair (how
+        global_stat / BarrierTimer sites are timed once)."""
+        return _Span(self, name, track, attrs or None, sink)
 
     def begin(self, name: str, track: str = "main", **attrs):
-        """Open a span that a LATER call (possibly in another method)
-        closes via end().  Returns an opaque handle; None when disabled —
-        end(None) is a no-op, so call sites never branch."""
-        if not self.enabled:
-            return None
-        return [name, track, time.perf_counter(), attrs or None]
+        """Open a span that a LATER call on the SAME thread (possibly in
+        another method) closes via end(); spans opened in between must be
+        closed first.  Returns an opaque handle."""
+        sp = _Span(self, name, track, attrs or None)
+        sp.__enter__()
+        return sp
 
     def end(self, handle, **extra_attrs) -> None:
         if handle is None:
             return
-        name, track, t0, attrs = handle
         if extra_attrs:
-            attrs = dict(attrs or (), **extra_attrs)
-        self.add(name, t0, time.perf_counter() - t0, track=track,
-                 attrs=attrs)
+            # the ring's record only: the profiler took its attrs at begin
+            handle.attrs = dict(handle.attrs or (), **extra_attrs)
+        handle.__exit__(None, None, None)
 
     def instant(self, name: str, track: str = "main", **attrs) -> None:
         """Zero-duration marker (preempt, done, cancelled)."""

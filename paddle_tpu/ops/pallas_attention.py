@@ -197,6 +197,7 @@ def _fwd_call(q, k, v, kv_mask, q_off, k_off, H, scale, causal, window,
                                _in_kernel_precision(q, k, v))
     return pl.pallas_call(
         kernel,
+        name="flash_fwd",       # the device op's name in a profiler trace
         grid=(BH, nq, nk),
         in_specs=[
             _scalar_spec(),
@@ -354,6 +355,7 @@ def _bwd_call(q, k, v, kv_mask, q_off, k_off, o, lse, do, dlse,
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, H, Bq, Bk, scale, causal, window,
                           prec),
+        name="flash_bwd_dq",
         grid=(BH, nq, nk),
         in_specs=[_scalar_spec(), _scalar_spec(),
                   q_spec, kv_spec, kv_spec, kmask_spec, q_spec,
@@ -384,6 +386,7 @@ def _bwd_call(q, k, v, kv_mask, q_off, k_off, o, lse, do, dlse,
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, H, nq, Bq, Bk, scale, causal,
                           window, prec),
+        name="flash_bwd_dkv",
         grid=(BHkv, nk, rep * nq),
         in_specs=[_scalar_spec(), _scalar_spec(),
                   q_spec2, kv_spec2, kv_spec2, kmask_spec2, q_spec2,

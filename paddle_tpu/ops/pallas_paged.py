@@ -219,6 +219,7 @@ def paged_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_attn",      # the device op's name in a profiler trace
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, Hp, Dp), q.dtype),
         compiler_params=pallas_tpu_compiler_params(

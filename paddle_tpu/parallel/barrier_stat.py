@@ -21,7 +21,6 @@ log_period (see Trainer.train_one_pass).
 
 from __future__ import annotations
 
-import time
 from collections import deque
 from typing import Optional
 
@@ -46,16 +45,18 @@ def _fmt_pct(name: str, p: dict[str, float]) -> str:
 class BarrierTimer:
     """Rolling per-step timing windows + cross-process straggler report.
 
-    When a `tracer` (paddle_tpu/obs/trace.py) is attached and enabled,
-    every timed window ALSO lands as a span on the given track — the
-    trainer's per-dispatch phases (dispatch / sync / h2d / scan) become
-    Perfetto-viewable without a second instrumentation layer; h2d spans
-    are emitted from the prefetch thread onto their own track so the
-    staging-vs-scan overlap is visible as parallel lanes."""
+    Every window IS a tracer span (paddle_tpu/obs/trace.py) whose `sink`
+    is the window's deque, so a site is timed once: the trainer's
+    per-dispatch phases land in the ring (while enabled) and on the
+    profiler's timeline under the `pt.train.*` names — `pt.train.dispatch`
+    (dispatch), `pt.train.drain` (sync), `pt.train.scan` (scan); h2d spans
+    come from the prefetch thread, so they are `pt.feeder.stage` on their
+    own track and the staging-vs-scan overlap shows as parallel lanes."""
 
     def __init__(self, window: int = 500, tracer=None,
                  track: str = "trainer"):
-        self.tracer = tracer
+        from paddle_tpu.obs.trace import Tracer
+        self.tracer = tracer if tracer is not None else Tracer(capacity=1)
         self.track = track
         self.dispatch_s: deque[float] = deque(maxlen=window)
         self.sync_s: deque[float] = deque(maxlen=window)
@@ -69,22 +70,30 @@ class BarrierTimer:
         self._t_enter: Optional[float] = None
 
     # -- recording --------------------------------------------------------
-    def time_dispatch(self):
-        """Context manager timing one step dispatch."""
-        return _Timed(self.dispatch_s, self.tracer, "dispatch", self.track)
+    def time_dispatch(self, windowed: bool = True):
+        """Context manager timing one step dispatch.  `windowed=False`
+        keeps a dispatch that compiles (seconds of XLA work, not queue
+        backpressure) out of the rolling window; the span is the same."""
+        return self.tracer.span(
+            "pt.train.dispatch", self.track,
+            sink=self.dispatch_s.append if windowed else None)
 
     def time_sync(self):
         """Context manager timing one host<-device drain (the barrier)."""
-        return _Timed(self.sync_s, self.tracer, "sync", self.track)
+        return self.tracer.span("pt.train.drain", self.track,
+                                sink=self.sync_s.append)
 
     def time_h2d(self):
         """Context manager timing one k-group host->device staging (runs on
         the prefetch thread — overlaps the current scan)."""
-        return _Timed(self.h2d_s, self.tracer, "h2d", self.track + ":h2d")
+        return self.tracer.span("pt.feeder.stage", self.track + ":h2d",
+                                sink=self.h2d_s.append)
 
-    def time_scan(self):
+    def time_scan(self, windowed: bool = True):
         """Context manager timing one fused k-step scan dispatch."""
-        return _Timed(self.scan_s, self.tracer, "scan", self.track)
+        return self.tracer.span(
+            "pt.train.scan", self.track,
+            sink=self.scan_s.append if windowed else None)
 
     # -- reporting --------------------------------------------------------
     def local_summary(self) -> dict[str, dict[str, float]]:
@@ -133,24 +142,3 @@ class BarrierTimer:
                 f"{strag['slowest_ms']:.2f}ms vs mean {strag['mean_ms']:.2f}ms "
                 f"(skew {strag['skew']:.2f}x)")
         return "; ".join(parts) if parts else "no samples"
-
-
-class _Timed:
-    def __init__(self, sink: deque, tracer=None, name: str = "",
-                 track: str = "trainer"):
-        self.sink = sink
-        self.tracer = tracer
-        self.name = name
-        self.track = track
-
-    def __enter__(self):
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        dt = time.perf_counter() - self.t0
-        self.sink.append(dt)
-        t = self.tracer
-        if t is not None and t.enabled:
-            t.add(self.name, self.t0, dt, track=self.track)
-        return False

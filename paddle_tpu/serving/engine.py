@@ -306,7 +306,7 @@ class ServingEngine:
         self.prefix: Optional[PrefixTree] = \
             PrefixTree(self.kv) if prefix_cache else None
         if self.prefix is not None:
-            self.kv.on_page_pressure = self.prefix.evict_for
+            self.kv.on_page_pressure = self._evict_for
         self.n_prefix_hits = 0
         self.n_prefix_misses = 0
         self.prefill_tokens_saved = 0
@@ -354,6 +354,7 @@ class ServingEngine:
         self.tracer = tracer if tracer is not None else get_tracer()
         self._obs_open: dict = {}   # req_id -> open span handle (one phase
                                     # open per request at any moment)
+        self._plan_span = None      # the running step's open pt.step.plan
         self._req_trace: dict = {}  # req_id -> inbound trace context
         # per-request latency attribution (ALWAYS on — the phase
         # transitions below are a handful of clock reads per request
@@ -761,6 +762,31 @@ class ServingEngine:
         self._d_topk = st.topk
         self._d_topp = st.topp
 
+    # -- phase spans (the pump thread's `pt.step.*` / `pt.kv.*` family) ----
+    def _phase(self, name: str, **attrs):
+        """One phase of a step on the engine lane: `pt.step.<name>`, fed to
+        the ring and the profiler alike (obs/trace.py "Two sinks")."""
+        return self.tracer.span("pt.step." + name, track="engine", **attrs)
+
+    def _compiled_step(self, kind: str, **attrs):
+        """The span of ONE compiled step — `pt.step.decode` / `.mixed` /
+        `.scan` / `.spec`, the kind the scheduler chose in the name — from
+        the call into the compiled program to the host token read (the
+        inter-token latency every live slot paid).  Closes the step's
+        `pt.step.plan` first: planning ends where the dispatch begins."""
+        self._end_plan()
+        return self._phase(kind, **attrs)
+
+    def _end_plan(self) -> None:
+        plan, self._plan_span = self._plan_span, None
+        self.tracer.end(plan)
+
+    def _evict_for(self, n_pages: int) -> int:
+        """The allocator's page-pressure hook: PrefixTree.evict_for under
+        its own span (it walks the tree in Python once the pool is full)."""
+        with self.tracer.span("pt.kv.evict", track="engine", pages=n_pages):
+            return self.prefix.evict_for(n_pages)
+
     # -- lifecycle tracing helpers ----------------------------------------
     def _tr_on(self) -> bool:
         t = self.tracer
@@ -960,13 +986,29 @@ class ServingEngine:
         the MIXED step: decode rows and prompt-chunk rows pack into one
         ragged [max_step_tokens] dispatch under the token budget.  Steps
         with only decoding slots keep the classic [S, 1] decode step —
-        the steady state pays nothing for the chunk machinery."""
-        self._sweep_deadlines()
-        self._admit_from_queue()
+        the steady state pays nothing for the chunk machinery.
+
+        Phases, each a span (docs/observability.md "The span model"):
+        `pt.step.admit` -> `pt.step.plan` -> the compiled step under its
+        kind's name (`pt.step.dispatch`, `pt.step.readback` inside) ->
+        `pt.step.emit`."""
+        with self._phase("admit"):
+            self._sweep_deadlines()
+            self._admit_from_queue()
         live = [s for s in range(len(self.slots)) if self.slots[s] is not None]
         if not live:
             self._t_prev_decode = None   # idle: don't charge the idle gap
             return False
+        self._plan_span = self.tracer.begin("pt.step.plan", track="engine")
+        try:
+            return self._plan_and_run(live)
+        finally:
+            if self._plan_span is not None:  # no compiled step ran (preempt
+                self._end_plan()             # to empty, or an exception)
+
+    def _plan_and_run(self, live) -> bool:
+        """step() after admission: secure pages (preempting on a wedged
+        pool), choose the step's kind, run it."""
         while True:
             # decode-mode slots need their next page; prefill-mode slots
             # (gen == 0, chunked admission) had their prompt pages secured
@@ -1034,8 +1076,6 @@ class ServingEngine:
             # keeps the legacy spec_k > 0 exclusion.
             return self._run_scan_step(live, runnable, self.decode_steps)
 
-        traced = self._tr_on()
-        t_step = time.perf_counter() if traced else 0.0
         S = len(self.slots)
         for s in runnable:
             sl = self.slots[s]
@@ -1053,22 +1093,20 @@ class ServingEngine:
         # every component so no stale (deleted-buffer) aliases survive.
         self._sync_run_mask(runnable)
         self._sync_device_state()
-        st, nxt = self._decode_step(self.params, self._build_state(),
-                                    self._d_run)
-        self._unpack_state(st)
-        self.n_decode_steps += 1
-        self.occupancy_sum += len(live) / S
-        nxt = np.asarray(nxt)                          # host sync
-        self._note_step_metrics(len(runnable), decoded=True)
-        if traced:
-            # one engine-lane span per compiled step (dispatch + the host
-            # token read = the inter-token latency every live slot paid)
-            self.tracer.add("decode_step", t_step,
-                            time.perf_counter() - t_step, track="engine",
-                            attrs={"live": len(live),
-                                   "step": self.n_decode_steps})
-        for s in runnable:
-            self._bank_token(s, int(nxt[s]))
+        with self._compiled_step("decode", live=len(live),
+                                 step=self.n_decode_steps + 1):
+            with self._phase("dispatch"):
+                st, nxt = self._decode_step(self.params, self._build_state(),
+                                            self._d_run)
+            self._unpack_state(st)
+            self.n_decode_steps += 1
+            self.occupancy_sum += len(live) / S
+            with self._phase("readback"):
+                nxt = np.asarray(nxt)                      # host sync
+            self._note_step_metrics(len(runnable), decoded=True)
+        with self._phase("emit", n=len(runnable)):
+            for s in runnable:
+                self._bank_token(s, int(nxt[s]))
         return True
 
     def _bank_token(self, s: int, tok: int) -> None:
@@ -1130,8 +1168,6 @@ class ServingEngine:
         Per-slot banking cuts each slot's column at its own eos/max_new —
         the exact retirement the device run mask applied — so host
         mirrors re-converge with device state without any readback."""
-        traced = self._tr_on()
-        t_step = time.perf_counter() if traced else 0.0
         S = len(self.slots)
         psize = self.kv.page_size
         for s in runnable:
@@ -1144,38 +1180,39 @@ class ServingEngine:
                     f"slot {s} scan window would write a shared page"
         self._sync_run_mask(runnable)
         self._sync_device_state()
-        st, blk = self._scan_step_fn()(
-            k, self.params, self._build_state(), self._d_run,
-            self._d_eos, self._d_maxnew)
-        self._unpack_state(st)
-        self.n_decode_steps += 1
-        self.n_scan_flushes += 1
-        self.n_scan_steps += k
-        self.occupancy_sum += len(live) / S
-        blk = np.asarray(blk)                          # [k, S] host sync
-        self._note_step_metrics(len(runnable), decoded=True)
-        if traced:
-            self.tracer.add("scan_step", t_step,
-                            time.perf_counter() - t_step, track="engine",
-                            attrs={"live": len(live), "k": k,
-                                   "step": self.n_decode_steps})
+        scan_step = self._scan_step_fn()
+        with self._compiled_step("scan", live=len(live), k=k,
+                                 step=self.n_decode_steps + 1):
+            with self._phase("dispatch"):
+                st, blk = scan_step(
+                    k, self.params, self._build_state(), self._d_run,
+                    self._d_eos, self._d_maxnew)
+            self._unpack_state(st)
+            self.n_decode_steps += 1
+            self.n_scan_flushes += 1
+            self.n_scan_steps += k
+            self.occupancy_sum += len(live) / S
+            with self._phase("readback"):
+                blk = np.asarray(blk)                  # [k, S] host sync
+            self._note_step_metrics(len(runnable), decoded=True)
         # per-flush, never per-token: one boundary event each k tokens
         self.flight.record("scan_flush", k=k, slots=len(runnable))
-        for s in runnable:
-            sl = self.slots[s]
-            burst = []
-            for i in range(k):
-                t = int(blk[i, s])
-                burst.append(t)
-                if t == sl.req.eos_id or sl.gen + len(burst) >= \
-                        sl.req.max_new:
-                    break                # device run mask froze here too
-            self.cur_burst = len(burst)
-            try:
-                for t in burst:
-                    self._bank_token(s, t)
-            finally:
-                self.cur_burst = 1
+        with self._phase("emit", n=len(runnable)):
+            for s in runnable:
+                sl = self.slots[s]
+                burst = []
+                for i in range(k):
+                    t = int(blk[i, s])
+                    burst.append(t)
+                    if t == sl.req.eos_id or sl.gen + len(burst) >= \
+                            sl.req.max_new:
+                        break            # device run mask froze here too
+                self.cur_burst = len(burst)
+                try:
+                    for t in burst:
+                        self._bank_token(s, t)
+                finally:
+                    self.cur_burst = 1
         return True
 
     def _run_mixed_step(self, live, runnable, filling) -> bool:
@@ -1193,8 +1230,6 @@ class ServingEngine:
         are packed FIRST (every decoding slot advances every step it has
         pages for), chunk rows only fill what remains — so no single
         step, whatever the prompt mix, exceeds max_step_tokens rows."""
-        traced = self._tr_on()
-        t_step = time.perf_counter() if traced else 0.0
         S = len(self.slots)
         T = self.max_step_tokens
         ps = self.kv.page_size
@@ -1232,26 +1267,26 @@ class ServingEngine:
         # step's scheduling decision, so the six row/mask operands stage
         # per mixed step; the EngineState (donated, rebound) does not.
         self._sync_device_state()
-        st, nxt = self._mixed_step(
-            self.params, self._build_state(), self._stage(row_ids),
-            self._stage(row_slot), self._stage(row_pos),
-            self._stage(sample_row), self._stage(adv), self._stage(emit))
-        self._unpack_state(st)
-        self.n_decode_steps += 1
-        self.n_mixed_steps += 1
-        self.occupancy_sum += len(live) / S
-        nxt = np.asarray(nxt)                          # host sync
-        self._note_step_metrics(r, decoded=bool(runnable))
-        if traced:
-            self.tracer.add("decode_step", t_step,
-                            time.perf_counter() - t_step, track="engine",
-                            attrs={"live": len(live),
-                                   "step": self.n_decode_steps,
-                                   "mixed": True, "rows": r,
-                                   "decode_rows": len(runnable)})
-        for s in runnable:
-            self._bank_token(s, int(nxt[s]))
-        self._advance_chunks(advanced, lambda s: int(nxt[s]))
+        with self._compiled_step("mixed", live=len(live), rows=r,
+                                 decode_rows=len(runnable),
+                                 step=self.n_decode_steps + 1):
+            with self._phase("dispatch"):
+                st, nxt = self._mixed_step(
+                    self.params, self._build_state(), self._stage(row_ids),
+                    self._stage(row_slot), self._stage(row_pos),
+                    self._stage(sample_row), self._stage(adv),
+                    self._stage(emit))
+            self._unpack_state(st)
+            self.n_decode_steps += 1
+            self.n_mixed_steps += 1
+            self.occupancy_sum += len(live) / S
+            with self._phase("readback"):
+                nxt = np.asarray(nxt)                      # host sync
+            self._note_step_metrics(r, decoded=bool(runnable))
+        with self._phase("emit", n=len(runnable)):
+            for s in runnable:
+                self._bank_token(s, int(nxt[s]))
+            self._advance_chunks(advanced, lambda s: int(nxt[s]))
         return True
 
     def _pack_chunk_rows(self, filling, row_ids, row_slot, row_pos,
@@ -1390,39 +1425,35 @@ class ServingEngine:
                 want[s] = k
         if not want:
             return out
-        traced = self._tr_on()
-        t0 = time.perf_counter()
-        if hasattr(self.drafter, "propose_batch"):
-            out = self._propose_batched(want, W)
-        else:
-            for s, k in want.items():
-                sl = self.slots[s]
-                ctx = self._draft_ctx(s, W)
-                if self._drafter_takes_eos:
-                    d = self.drafter.propose(ctx, k,
-                                             eos_id=sl.req.eos_id)
-                else:
-                    d = self.drafter.propose(ctx, k)
-                d = np.asarray(d, np.int32).reshape(-1)
-                assert d.size <= k, \
-                    f"drafter returned {d.size} tokens for k={k} — the " \
-                    f"clamp contract is the drafter's (see " \
-                    f"serving/drafter.py); truncating here would skew " \
-                    f"accept-rate stats"
-                if d.size:
-                    out[s] = d
-        dt = time.perf_counter() - t0
+        took: list = []                  # the span's own clock pair
+        with self._phase("draft", sink=took.append, k=self.spec_k,
+                         drafter=self.drafter_kind):
+            if hasattr(self.drafter, "propose_batch"):
+                out = self._propose_batched(want, W)
+            else:
+                for s, k in want.items():
+                    sl = self.slots[s]
+                    ctx = self._draft_ctx(s, W)
+                    if self._drafter_takes_eos:
+                        d = self.drafter.propose(ctx, k,
+                                                 eos_id=sl.req.eos_id)
+                    else:
+                        d = self.drafter.propose(ctx, k)
+                    d = np.asarray(d, np.int32).reshape(-1)
+                    assert d.size <= k, \
+                        f"drafter returned {d.size} tokens for k={k} — " \
+                        f"the clamp contract is the drafter's (see " \
+                        f"serving/drafter.py); truncating here would skew " \
+                        f"accept-rate stats"
+                    if d.size:
+                        out[s] = d
+        dt = took[0]
         self.draft_ms_hist.observe(dt * 1e3)
         if out:
             self.n_draft_steps += 1
             self.flight.record("draft_step", slots=len(out),
                                drafter=self.drafter_kind,
                                ms=round(dt * 1e3, 3))
-            if traced:
-                self.tracer.add("draft_step", t0, dt, track="engine",
-                                attrs={"slots": len(out),
-                                       "k": self.spec_k,
-                                       "drafter": self.drafter_kind})
         return out
 
     def _propose_batched(self, want: dict, W: int) -> dict:
@@ -1480,8 +1511,6 @@ class ServingEngine:
         Chains need page cover for their deepest write; a page-starved
         slot verifies fewer drafts instead of stalling (the plain row
         needs only the page the runnable check already secured)."""
-        traced = self._tr_on()
-        t_step = time.perf_counter() if traced else 0.0
         S = len(self.slots)
         K = self.spec_k
         T = self.max_step_tokens if self.prefill_chunk is not None \
@@ -1568,64 +1597,63 @@ class ServingEngine:
             filling, row_ids, row_slot, row_pos, first_row, adv_chunk,
             emit, r, T - r)
         self._sync_device_state()
-        st, sampled, acc = self._spec_step(
-            self.params, self._build_state(), self._stage(row_ids),
-            self._stage(row_slot), self._stage(row_pos),
-            self._stage(first_row), self._stage(n_draft),
-            self._stage(draft_toks), self._stage(spec),
-            self._stage(emit), self._stage(adv_chunk))
-        self._unpack_state(st)
-        self.n_decode_steps += 1
-        self.n_spec_steps += 1
-        if advanced:
-            self.n_mixed_steps += 1
-        self.occupancy_sum += len(live) / S
-        sampled = np.asarray(sampled)                  # host sync
-        acc = np.asarray(acc)
-        self._note_step_metrics(r, decoded=bool(runnable))
-        if traced:
-            self.tracer.add("decode_step", t_step,
-                            time.perf_counter() - t_step, track="engine",
-                            attrs={"live": len(live),
-                                   "step": self.n_decode_steps,
-                                   "spec": True, "rows": r,
-                                   "decode_rows": len(runnable)})
-        for s in runnable:
-            sl = self.slots[s]
-            a = int(acc[s])
-            nd = int(n_draft[s])
-            self.n_spec_accepted += a
-            self.n_spec_chains += 1
-            if self.spec_dynamic and nd:
-                # feed the slot's accept EWMA BEFORE banking may retire
-                # it — the next flush window's _dyn_k steers by this.
-                # Draft-free rows (nd == 0) carry no signal: skipped, so
-                # a k=0 slot's estimate moves only on its paced probes.
-                rate = a / nd
-                sl.accept_ewma = rate if sl.accept_ewma is None else \
-                    (1.0 - _EWMA_ALPHA) * sl.accept_ewma \
-                    + _EWMA_ALPHA * rate
-            if nd:
-                rid = str(sl.req.req_id)
-                self._bump_attr(sl.req.req_id, "spec_drafted", nd)
-                if a:
-                    self._bump_attr(sl.req.req_id, "spec_accepted", a)
-                    self.flight.record("spec_accept", req=rid, slot=s,
-                                       accepted=a, drafted=nd)
-                if nd > a:
-                    self.flight.record("spec_reject", req=rid, slot=s,
-                                       rejected=nd - a, drafted=nd)
-            # host page rollback BEFORE banking: banking may retire the
-            # slot (eos / max_new), and retire releases every mapping —
-            # while the slot is live, pages past pages_for(pos + a + 1)
-            # hold only rejected-draft garbage
-            self.kv.uncommit_tail(s, sl.pos + a + 1)
-            for i in range(a + 1):
-                self._bank_token(s, int(sampled[s, i]))
-                self.n_spec_tokens += 1
-                if self.slots[s] is None:     # retired mid-chain (eos)
-                    break
-        self._advance_chunks(advanced, lambda s: int(sampled[s, 0]))
+        with self._compiled_step("spec", live=len(live), rows=r,
+                                 decode_rows=len(runnable),
+                                 step=self.n_decode_steps + 1):
+            with self._phase("dispatch"):
+                st, sampled, acc = self._spec_step(
+                    self.params, self._build_state(), self._stage(row_ids),
+                    self._stage(row_slot), self._stage(row_pos),
+                    self._stage(first_row), self._stage(n_draft),
+                    self._stage(draft_toks), self._stage(spec),
+                    self._stage(emit), self._stage(adv_chunk))
+            self._unpack_state(st)
+            self.n_decode_steps += 1
+            self.n_spec_steps += 1
+            if advanced:
+                self.n_mixed_steps += 1
+            self.occupancy_sum += len(live) / S
+            with self._phase("readback"):
+                sampled = np.asarray(sampled)              # host sync
+                acc = np.asarray(acc)
+            self._note_step_metrics(r, decoded=bool(runnable))
+        with self._phase("emit", n=len(runnable)):
+            for s in runnable:
+                sl = self.slots[s]
+                a = int(acc[s])
+                nd = int(n_draft[s])
+                self.n_spec_accepted += a
+                self.n_spec_chains += 1
+                if self.spec_dynamic and nd:
+                    # feed the slot's accept EWMA BEFORE banking may retire
+                    # it — the next flush window's _dyn_k steers by this.
+                    # Draft-free rows (nd == 0) carry no signal: skipped, so
+                    # a k=0 slot's estimate moves only on its paced probes.
+                    rate = a / nd
+                    sl.accept_ewma = rate if sl.accept_ewma is None else \
+                        (1.0 - _EWMA_ALPHA) * sl.accept_ewma \
+                        + _EWMA_ALPHA * rate
+                if nd:
+                    rid = str(sl.req.req_id)
+                    self._bump_attr(sl.req.req_id, "spec_drafted", nd)
+                    if a:
+                        self._bump_attr(sl.req.req_id, "spec_accepted", a)
+                        self.flight.record("spec_accept", req=rid, slot=s,
+                                           accepted=a, drafted=nd)
+                    if nd > a:
+                        self.flight.record("spec_reject", req=rid, slot=s,
+                                           rejected=nd - a, drafted=nd)
+                # host page rollback BEFORE banking: banking may retire the
+                # slot (eos / max_new), and retire releases every mapping —
+                # while the slot is live, pages past pages_for(pos + a + 1)
+                # hold only rejected-draft garbage
+                self.kv.uncommit_tail(s, sl.pos + a + 1)
+                for i in range(a + 1):
+                    self._bank_token(s, int(sampled[s, i]))
+                    self.n_spec_tokens += 1
+                    if self.slots[s] is None:     # retired mid-chain (eos)
+                        break
+            self._advance_chunks(advanced, lambda s: int(sampled[s, 0]))
         return True
 
     def run(self, requests=()) -> dict:
@@ -2220,7 +2248,7 @@ class ServingEngine:
             return
         if enabled:
             self.prefix = PrefixTree(self.kv)
-            self.kv.on_page_pressure = self.prefix.evict_for
+            self.kv.on_page_pressure = self._evict_for
             return
         stack = list(self.prefix.root.children.values())
         while stack:

@@ -68,7 +68,7 @@ from paddle_tpu.obs.hbm import hbm_collector, hbm_snapshot
 from paddle_tpu.obs.slo import SloEvaluator, default_serving_slos
 from paddle_tpu.obs.timeseries import (HistorySampler, MetricHistory,
                                        history_collector, history_reply)
-from paddle_tpu.obs.trace import trace_reply
+from paddle_tpu.obs.trace import annotation, trace_reply
 from paddle_tpu.serving import wire
 from paddle_tpu.serving.engine import Request, ServingEngine
 from paddle_tpu.utils.stat import StatSet
@@ -105,11 +105,18 @@ class _ReqState:
         self.burst_share = 0.0        # per-token share of the burst gap
 
 
-#: one client connection (asyncio side): the shared slow-reader-severing
-#: frame connection — hoisted to wire.py so the fleet router's client
-#: face can never drift from this server's (conn.rids maps client id ->
-#: engine req_id here)
-_Conn = wire.FrameConn
+class _Conn(wire.FrameConn):
+    """One client connection (asyncio side): the shared slow-reader-
+    severing frame connection — hoisted to wire.py so the fleet router's
+    client face can never drift from this server's (conn.rids maps client
+    id -> engine req_id here).  The replica times the encode + write of
+    every frame as `pt.loop.send` on the loop thread (here, not in
+    wire.py, which the JAX-free client imports) — for the profiler alone:
+    a span a token would wrap the ring within seconds."""
+
+    def send(self, msg: dict) -> None:
+        with annotation("pt.loop.send"):
+            self._write(wire.encode(msg))
 
 
 def _kv_push_frames(cid, toks, meta: dict, payload: bytes) -> list[bytes]:
@@ -552,99 +559,108 @@ class ServingServer:
             raise RuntimeError("engine pump died") from self._pump_error
 
     # -- the engine pump (its own thread; sole owner of the engine) --------
-    def _pump(self) -> None:
+    def _drain_commands(self) -> bool:
+        """Apply every queued command between steps (the pump thread is the
+        engine's sole owner); True when one of them was "stop"."""
         try:
             while True:
-                # heartbeat FIRST: written once per loop iteration, so a
-                # wedge anywhere below (a hung compiled step, a stuck
-                # host sync) freezes it and pump_last_step_age_s grows
-                now = time.monotonic()
-                self._pump_beat = (now, self.engine.n_decode_steps)
-                if now - self._last_beat_event >= 1.0:
-                    # SAMPLED into the flight ring (~1/s): a postmortem
-                    # shows how recently, and at what step, the pump was
-                    # demonstrably alive — without beats evicting the
-                    # lifecycle events the ring exists for
-                    self._last_beat_event = now
-                    self.flight.record(
-                        "pump_beat", step=self.engine.n_decode_steps,
-                        queue_depth=len(self.engine.queue),
-                        inflight=self._inflight)
-                try:
-                    while True:
-                        cmd = self._cmds.get_nowait()
-                        if cmd[0] == "stop":
-                            # commands queued behind "stop" must not be
-                            # orphaned: a consistent-stats client is
-                            # blocking on its reply — answer it here (we
-                            # ARE between steps on the pump thread, so
-                            # the snapshot is consistent); _shutdown
-                            # sweeps anything put after this drain
-                            try:
-                                while True:
-                                    cmd = self._cmds.get_nowait()
-                                    if cmd[0] == "stats":
-                                        self._loop.call_soon_threadsafe(
-                                            self._stats_on_loop, cmd[1],
-                                            self._engine_stats())
-                                    elif cmd[0] == "kv_import":
-                                        self._loop.call_soon_threadsafe(
-                                            cmd[2].send,
-                                            {"type": "kv_push",
-                                             "id": cmd[1]["cid"],
-                                             "ok": False,
-                                             "error": "replica stopping"})
-                            except queue.Empty:
-                                pass
-                            return
-                        if cmd[0] == "add":
-                            req = cmd[1]
-                            try:
-                                self.engine.add_request(req)
-                            except (ValueError, AssertionError) as e:
-                                # validate() ran at admission, so only a
-                                # race with a reconfigured engine lands
-                                # here — still must answer the client
+                cmd = self._cmds.get_nowait()
+                if cmd[0] == "stop":
+                    # commands queued behind "stop" must not be orphaned: a
+                    # consistent-stats client is blocking on its reply —
+                    # answer it here (we ARE between steps on the pump
+                    # thread, so the snapshot is consistent); _shutdown
+                    # sweeps anything put after this drain
+                    try:
+                        while True:
+                            cmd = self._cmds.get_nowait()
+                            if cmd[0] == "stats":
                                 self._loop.call_soon_threadsafe(
-                                    self._fail_on_loop, req.req_id, str(e))
-                        elif cmd[0] == "cancel":
-                            self.engine.cancel(cmd[1])
-                        elif cmd[0] == "kv_import":
-                            # between steps kv.pools is authoritative (the
-                            # engine rebuilds its state pytree from it at
-                            # every dispatch), so the mount's scatter is
-                            # exactly as safe as an admission-time restore
-                            push, conn = cmd[1], cmd[2]
-                            try:
-                                added = self.engine.import_prefix(
-                                    push["tokens"], push["meta"],
-                                    b"".join(push["parts"]))
-                                reply = {"type": "kv_push",
-                                         "id": push["cid"], "ok": True,
-                                         "pages": int(push["meta"]
-                                                      ["n_pages"]),
-                                         "mounted": int(added)}
-                            except (ValueError, AssertionError) as e:
-                                reply = {"type": "kv_push",
-                                         "id": push["cid"], "ok": False,
-                                         "error": f"{type(e).__name__}: "
-                                                  f"{e}"}
-                            self._loop.call_soon_threadsafe(
-                                conn.send, reply)
-                        elif cmd[0] == "stats":
-                            # between-steps = the consistent view: no
-                            # slot/page/queue mutation can interleave
-                            self._loop.call_soon_threadsafe(
-                                self._stats_on_loop, cmd[1],
-                                self._engine_stats())
-                except queue.Empty:
-                    pass
-                busy = self.engine.step()
+                                    self._stats_on_loop, cmd[1],
+                                    self._engine_stats())
+                            elif cmd[0] == "kv_import":
+                                self._loop.call_soon_threadsafe(
+                                    cmd[2].send,
+                                    {"type": "kv_push", "id": cmd[1]["cid"],
+                                     "ok": False,
+                                     "error": "replica stopping"})
+                    except queue.Empty:
+                        pass
+                    return True
+                if cmd[0] == "add":
+                    req = cmd[1]
+                    try:
+                        self.engine.add_request(req)
+                    except (ValueError, AssertionError) as e:
+                        # validate() ran at admission, so only a race with
+                        # a reconfigured engine lands here — still must
+                        # answer the client
+                        self._loop.call_soon_threadsafe(
+                            self._fail_on_loop, req.req_id, str(e))
+                elif cmd[0] == "cancel":
+                    self.engine.cancel(cmd[1])
+                elif cmd[0] == "kv_import":
+                    # between steps kv.pools is authoritative (the engine
+                    # rebuilds its state pytree from it at every dispatch),
+                    # so the mount's scatter is exactly as safe as an
+                    # admission-time restore
+                    push, conn = cmd[1], cmd[2]
+                    try:
+                        added = self.engine.import_prefix(
+                            push["tokens"], push["meta"],
+                            b"".join(push["parts"]))
+                        reply = {"type": "kv_push", "id": push["cid"],
+                                 "ok": True,
+                                 "pages": int(push["meta"]["n_pages"]),
+                                 "mounted": int(added)}
+                    except (ValueError, AssertionError) as e:
+                        reply = {"type": "kv_push", "id": push["cid"],
+                                 "ok": False,
+                                 "error": f"{type(e).__name__}: {e}"}
+                    self._loop.call_soon_threadsafe(conn.send, reply)
+                elif cmd[0] == "stats":
+                    # between-steps = the consistent view: no
+                    # slot/page/queue mutation can interleave
+                    self._loop.call_soon_threadsafe(
+                        self._stats_on_loop, cmd[1], self._engine_stats())
+        except queue.Empty:
+            pass
+        return False
+
+    def _pump(self) -> None:
+        """The pump loop.  Its phases are spans on the `pump` lane (names
+        `pt.pump.*`, `pt.engine.step`; the engine's own `pt.step.*` nest
+        inside the latter): a profiler trace splits the device's idle time
+        by what this thread was doing."""
+        span = self.tracer.span
+        try:
+            while True:
+                with span("pt.pump.commands", track="pump"):
+                    # heartbeat FIRST: written once per loop iteration, so
+                    # a wedge anywhere below (a hung compiled step, a stuck
+                    # host sync) freezes it and pump_last_step_age_s grows
+                    now = time.monotonic()
+                    self._pump_beat = (now, self.engine.n_decode_steps)
+                    if now - self._last_beat_event >= 1.0:
+                        # SAMPLED into the flight ring (~1/s): a postmortem
+                        # shows how recently, and at what step, the pump
+                        # was demonstrably alive — without beats evicting
+                        # the lifecycle events the ring exists for
+                        self._last_beat_event = now
+                        self.flight.record(
+                            "pump_beat", step=self.engine.n_decode_steps,
+                            queue_depth=len(self.engine.queue),
+                            inflight=self._inflight)
+                    if self._drain_commands():
+                        return
+                with span("pt.engine.step", track="pump"):
+                    busy = self.engine.step()
                 if not busy:
                     # idle: nothing queued or in flight — sleep until a
                     # command arrives (bounded wait as a safety net)
-                    self._wake.wait(timeout=0.5)
-                    self._wake.clear()
+                    with span("pt.pump.wait", track="pump"):
+                        self._wake.wait(timeout=0.5)
+                        self._wake.clear()
         except BaseException as e:                     # noqa: BLE001
             self._pump_error = e
             # the black-box moment: the pump thread is dying with the

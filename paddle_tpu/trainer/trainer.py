@@ -475,24 +475,21 @@ class Trainer:
         (loss, partials, host_out) device values.  `key` overrides the
         internal rng split with a pre-split per-step key (the fused path's
         settling dispatch must consume the key already drawn for batch 0)."""
-        if self.mesh is not None:
-            from paddle_tpu.parallel.dp import shard_batch
-            batch = shard_batch(self.mesh, batch)
-        if key is None:
-            self.rng, key = jax.random.split(self.rng)
-        self._last_rng = key
+        with self._tracer.span("pt.train.stage", track="trainer"):
+            if self.mesh is not None:
+                from paddle_tpu.parallel.dp import shard_batch
+                batch = shard_batch(self.mesh, batch)
+            if key is None:
+                self.rng, key = jax.random.split(self.rng)
+            self._last_rng = key
+            sig = self._batch_signature(batch)
         # any UNSEEN (batch-shape, net_state-structure) signature likely
         # retraces+recompiles — seconds of XLA work, not queue backpressure;
         # keep those dispatches out of the barrier timing windows (this
         # covers the first batch, every new length bucket, and the
         # net_state pytree change after batch 1)
-        sig = self._batch_signature(batch)
         seen = self._seen_sigs()
-        if sig in seen:
-            with self.barrier_stat.time_dispatch():
-                out = self._train_step(self.params, self.opt_state,
-                                       self.net_state, batch, key)
-        else:
+        with self.barrier_stat.time_dispatch(windowed=sig in seen):
             seen.add(sig)
             out = self._train_step(self.params, self.opt_state,
                                    self.net_state, batch, key)
@@ -531,11 +528,7 @@ class Trainer:
         self._last_rng = keys[-1]
         fsig = ("fused", int(keys.shape[0]), sig)
         seen = self._seen_sigs()
-        if fsig in seen:
-            with self.barrier_stat.time_scan():
-                out = self._fused_step(self.params, self.opt_state,
-                                       self.net_state, staged, keys)
-        else:
+        with self.barrier_stat.time_scan(windowed=fsig in seen):
             seen.add(fsig)
             out = self._fused_step(self.params, self.opt_state,
                                    self.net_state, staged, keys)
@@ -685,8 +678,8 @@ class Trainer:
             return self._train_one_pass_fused(batches, log_period, k, t0)
         n_batches, n_samples = 0, 0
         stats_period = FLAGS.show_parameter_stats_period
-        for batch in batches:
-            with global_stat.time("trainOneBatch"):
+        for batch in self._timed_batches(batches):
+            with self._step_span("trainOneBatch"):
                 self.train_one_batch(batch)
             n_batches += 1
             n_samples += _batch_size(batch)
@@ -695,6 +688,24 @@ class Trainer:
             if stats_period and n_batches % stats_period == 0:
                 self.log_param_stats()
         return self._finish_pass_stats(t0, n_batches, n_samples)
+
+    def _timed_batches(self, batches):
+        """`batches`, with the wait for each under `pt.train.next_batch`
+        (the trainer loop's input wait, on the profiler's clock)."""
+        it = iter(batches)
+        end = object()
+        while True:
+            with self._tracer.span("pt.train.next_batch", track="trainer"):
+                batch = next(it, end)
+            if batch is end:
+                return
+            yield batch
+
+    def _step_span(self, stat: str):
+        """`pt.train.step` around one train step (or one fused k-group),
+        feeding the `global_stat` timer of the same site from its clock."""
+        return self._tracer.span("pt.train.step", track="trainer",
+                                 sink=global_stat.get(stat).add)
 
     def _log_progress(self, n_batches: int) -> None:
         self._drained_cost += self._drain_losses()
@@ -826,7 +837,7 @@ class Trainer:
             return self._finish_pass_stats(t0, 0, 0)
         if not self._net_state_settled(first[0][0], first[1][0]):
             b0, key0 = first[0][0], first[1][0]
-            with global_stat.time("trainOneBatch"):
+            with self._step_span("trainOneBatch"):
                 loss, partials, host_out = self._dispatch_step(b0, key=key0)
                 self._acc = self.evaluators.accumulate(self._acc, partials)
                 if self._host_acc is not None:
@@ -844,9 +855,10 @@ class Trainer:
         staged = DeviceDoubleBuffer(chain(), self._stage_group,
                                     timer=self.barrier_stat.time_h2d)
         try:
-            for stacked, keys, host_batches, sig in staged:
+            for stacked, keys, host_batches, sig in \
+                    self._timed_batches(staged):
                 j = len(host_batches)
-                with global_stat.time("trainKSteps"):
+                with self._step_span("trainKSteps"):
                     losses, partials, host_outs = self._dispatch_fused(
                         stacked, keys, sig)
                 self._acc = self.evaluators.accumulate_stacked(

@@ -72,6 +72,15 @@ def mosaic(one_chip, no_persistent_cache, monkeypatch):
 bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
 
 
+def kernel_names(compiled) -> list[str]:
+    """The instruction names of the Mosaic custom calls in a compiled
+    program: what a profiler trace shows as the device op's name (and what
+    benchmark/layer_metrics/flash_*_ms_per_step.train.py match)."""
+    import re
+    return sorted(re.findall(r"%([\w.]+) = [^\n]*tpu_custom_call",
+                             compiled.as_text()))
+
+
 # ---------------------------------------------------------------------------
 # flash attention — the LM trainer's kernel (B=64, H=8, T=512, D=64, bf16)
 # ---------------------------------------------------------------------------
@@ -138,6 +147,28 @@ def test_flash_under_data_mesh(topo, no_persistent_cache, monkeypatch):
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         *args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # under shard_map too each kernel is told apart by its own name
+    names = kernel_names(compiled)
+    assert len(names) == 3
+    for want in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert sum(want in n for n in names) == 1, names
+
+
+def test_flash_kernels_carry_their_names(mosaic):
+    """Forward, dq and dk/dv are three Pallas calls with three names, so a
+    trace's device_ops say which kernel took the time (they were `jvp__` /
+    `transpose_jvp__`, two calls under one name)."""
+    from paddle_tpu.ops.pallas_attention import flash_attention
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, causal=True, block_q=128, block_k=128)
+        return jnp.sum(o.astype(f32))
+
+    names = kernel_names(mosaic(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                                *_flash_shapes(**FLASH_CASES["gqa"])))
+    assert len(names) == 3
+    for want in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert sum(want in n for n in names) == 1, names
 
 
 # ---------------------------------------------------------------------------
@@ -188,6 +219,32 @@ def test_paged_mixed_rows(mosaic):
 
     mosaic(step, ((T, 8, DH), bf16), ((T, H_KV, DH), bf16),
            ((T, H_KV, DH), bf16), *_pool_shapes(), ((T,), i32), ((T,), i32))
+
+
+@pytest.mark.parametrize("form", ["decode", "mixed"])
+def test_paged_kernel_carries_its_name(mosaic, form):
+    """The paged kernel is `paged_attn` in both forms (it was
+    `_decode_impl` / `_mixed_impl`, the jitted step's name)."""
+    from paddle_tpu.ops.attention import (paged_attention_step,
+                                          ragged_paged_attention_step)
+    T = 4 * PAGE + S
+    if form == "decode":
+        def step(q, k, v, kp, vp, table, pos):
+            return paged_attention_step(q, k, v, kp, vp, table, pos,
+                                        use_kernel=True)
+        compiled = mosaic(step, ((S, 1, 8, DH), bf16),
+                          ((S, 1, H_KV, DH), bf16), ((S, 1, H_KV, DH), bf16),
+                          *_pool_shapes(), ((S,), i32))
+    else:
+        def step(q, k, v, kp, vp, table, row_slot, row_pos):
+            return ragged_paged_attention_step(q, k, v, kp, vp, table,
+                                               row_slot, row_pos,
+                                               use_kernel=True)
+        compiled = mosaic(step, ((T, 8, DH), bf16), ((T, H_KV, DH), bf16),
+                          ((T, H_KV, DH), bf16), *_pool_shapes(),
+                          ((T,), i32), ((T,), i32))
+    names = kernel_names(compiled)
+    assert names and all("paged_attn" in n for n in names), names
 
 
 # ---------------------------------------------------------------------------

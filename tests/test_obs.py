@@ -382,16 +382,19 @@ def test_request_lifecycle_phases_incl_preempt_replay(lifecycle_tracer):
     order = [spans[n] for n in ("queued", "prefill", "decode")]
     for a, b in zip(order, order[1:]):
         assert b["ts"] >= a["ts"] + a["dur"] - 1e-6
-    # the engine lane recorded one span per compiled step (the mixed
-    # chunk step that sampled token 0 carries a `mixed` attr)
+    # the engine lane recorded one span per compiled step, the step's
+    # kind in its name (the chunk step that sampled token 0 is `mixed`)
     steps = [s for s in lifecycle_tracer.snapshot()
-             if s["track"] == "engine" and s["name"] == "decode_step"]
+             if s["track"] == "engine"
+             and s["name"] in ("pt.step.decode", "pt.step.mixed")]
     assert len(steps) == eng.n_decode_steps
+    assert [s["attrs"]["step"] for s in steps] == \
+        list(range(1, eng.n_decode_steps + 1))
     # span-vs-stats reconciliation: the decode span covers every PURE
     # decode step this (only) request was live for — the mixed prefill
     # step ran inside the `prefill` phase, before decode opened
     assert spans["decode"]["dur"] >= sum(
-        s["dur"] for s in steps if not s["attrs"].get("mixed")) - 1e-6
+        s["dur"] for s in steps if s["name"] == "pt.step.decode") - 1e-6
 
     # -- overcommitted pool: preempt + replay phases ---------------------
     lifecycle_tracer.clear()
@@ -502,3 +505,285 @@ def test_trainer_metrics_jsonl_sink(tmp_path):
     tr.append_metrics(str(tmp_path / "run"))
     with open(path) as f:
         assert len(f.readlines()) == 2
+
+
+# ---------------------------------------------------------------------------
+# one span() call, two sinks: the ring and the profiler's timeline
+# ---------------------------------------------------------------------------
+
+def _profiler_events(trace_dir):
+    """{name: [stats dict, ...]} of the `pt.` events a jax.profiler session
+    left on the host planes, and the per-line (name, start, end) lists."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(str(trace_dir), "**",
+                                         "*.xplane.pb"), recursive=True))[-1]
+    by_name, lines = {}, []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                    dict(e.stats)) for e in line.events
+                   if e.name.startswith("pt.")]
+            if evs:
+                lines.append(evs)
+            for name, _, _, stats in evs:
+                by_name.setdefault(name, []).append(stats)
+    return by_name, lines
+
+
+def _nested_ok(evs):
+    """One thread's events nest properly: any two are disjoint or one
+    holds the other (1 us of clock slack)."""
+    for i, (_, s0, e0, _) in enumerate(evs):
+        for _, s1, e1, _ in evs[i + 1:]:
+            disjoint = e0 <= s1 + 1000 or e1 <= s0 + 1000
+            holds = (s0 <= s1 + 1000 and e1 <= e0 + 1000) or \
+                    (s1 <= s0 + 1000 and e0 <= e1 + 1000)
+            if not (disjoint or holds):
+                return False
+    return True
+
+
+def test_one_span_feeds_both_sinks(tmp_path):
+    import jax
+
+    from paddle_tpu.obs.trace import _NULL, annotation, current_span
+
+    t = Tracer()
+    # disabled and outside a session: nothing is recorded anywhere
+    with t.span("pt.test.off", track="engine", n=1):
+        assert current_span("pt.") == "pt.test.off"
+    assert current_span() is None
+    assert t.recorded == 0
+    assert annotation("pt.test.off") is _NULL
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with t.span("pt.test.outer", track="engine", live=3, step=7):
+            with t.span("pt.test.inner", track="engine"):
+                pass
+            h = t.begin("pt.test.begun", track="engine", rows=5)
+            assert current_span("pt.") == "pt.test.begun"
+            t.end(h)
+        t.enabled = True                   # the ring: only while enabled
+        with t.span("pt.test.ring", track="engine", k=2):
+            pass
+        with annotation("pt.test.noring"):     # the profiler sink alone
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    assert [(s["name"], s["track"], s.get("attrs")) for s in t.snapshot()] \
+        == [("pt.test.ring", "engine", {"k": 2})]
+    by_name, lines = _profiler_events(tmp_path)
+    assert set(by_name) == {"pt.test.outer", "pt.test.inner",
+                            "pt.test.begun", "pt.test.ring",
+                            "pt.test.noring"}       # not pt.test.off
+    assert by_name["pt.test.outer"] == [{"live": 3, "step": 7}]
+    assert by_name["pt.test.begun"] == [{"rows": 5}]
+    assert len(lines) == 1 and _nested_ok(lines[0])
+
+
+def test_span_sink_is_fed_from_the_spans_own_clock():
+    """global_stat / BarrierTimer sites go through span(): the sink gets
+    the very duration the ring records, enabled or not."""
+    from paddle_tpu.parallel.barrier_stat import BarrierTimer
+
+    t = Tracer()
+    got = []
+    with t.span("pt.test.sink", sink=got.append):
+        pass
+    assert len(got) == 1 and got[0] >= 0.0 and t.recorded == 0
+    t.enabled = True
+    bt = BarrierTimer(tracer=t)
+    with bt.time_dispatch():
+        pass
+    with bt.time_dispatch(windowed=False):      # a compiling dispatch
+        pass
+    with bt.time_sync():
+        pass
+    with bt.time_h2d():
+        pass
+    with bt.time_scan():
+        pass
+    snap = t.snapshot()
+    assert [(s["name"], s["track"]) for s in snap] == [
+        ("pt.train.dispatch", "trainer"), ("pt.train.dispatch", "trainer"),
+        ("pt.train.drain", "trainer"), ("pt.feeder.stage", "trainer:h2d"),
+        ("pt.train.scan", "trainer")]
+    assert list(bt.dispatch_s) == [snap[0]["dur"]]
+    assert list(bt.sync_s) == [snap[2]["dur"]]
+    assert list(bt.h2d_s) == [snap[3]["dur"]]
+    assert list(bt.scan_s) == [snap[4]["dur"]]
+    assert set(bt.local_summary()) == {"dispatch", "sync", "h2d", "scan"}
+
+
+def test_trace_module_imports_and_records_with_jax_blocked():
+    """The client / router import path: obs.trace must work where JAX
+    cannot be imported, ring-only."""
+    import subprocess
+
+    code = (
+        "import sys; sys.modules['jax'] = None\n"
+        "from paddle_tpu.obs.trace import Tracer, current_span\n"
+        "import paddle_tpu.obs.compile_watch as cw\n"
+        "t = Tracer(); t.enabled = True\n"
+        "with t.span('pt.x', k=1):\n"
+        "    assert current_span('pt.') == 'pt.x'\n"
+        "t.end(t.begin('pt.y'))\n"
+        "assert [s['name'] for s in t.snapshot()] == ['pt.x', 'pt.y']\n"
+        "assert cw.listen() is False\n"
+        "assert 'jax.profiler' not in sys.modules\n"
+        "print('ok')\n")
+    out = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
+
+
+def test_trace_module_never_imports_jax_itself():
+    import subprocess
+
+    code = ("import sys\n"
+            "import paddle_tpu.obs.trace as tr\n"
+            "with tr.get_tracer().span('pt.x'):\n"
+            "    pass\n"
+            "assert 'jax' not in sys.modules, 'span() imported jax'\n"
+            "print('ok')\n")
+    out = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
+
+
+def test_eager_compile_is_counted_under_the_open_span():
+    """PERF.md section 6 finding 1: the admission-time key split compiles
+    for every new length, outside every wrapped site.  compile_watch now
+    counts it under the innermost open `pt.` span of the thread."""
+    import jax
+
+    from paddle_tpu.obs.compile_watch import (UNATTRIBUTED,
+                                              get_compile_watch)
+
+    cw = get_compile_watch()
+    seen = {"n": 0}
+
+    def count(event, secs, **kw):
+        seen["n"] += event.endswith("/backend_compile_duration")
+
+    jax.monitoring.register_event_duration_secs_listener(count)
+    key = jax.random.PRNGKey(0)
+    wrapped = cw.wrap_jit("test.obs.site", jax.jit(lambda x: x * 3 + 1))
+    before = cw.snapshot()
+    n0 = seen["n"]
+    t = Tracer()
+    with t.span("pt.step.admit", track="engine"):
+        jax.random.split(key, 1237)        # a length nothing else splits
+        wrapped(np.ones(1237, np.float32))     # a wrapped site keeps its own
+    jax.random.split(key, 1238)            # outside any span
+    after = cw.snapshot()
+
+    def grew(site, field):
+        return after.get(site, {}).get(field, 0) - \
+            before.get(site, {}).get(field, 0)
+
+    assert grew("pt.step.admit", "unwrapped") >= 1
+    assert grew("pt.step.admit", "compiles") == 0
+    assert grew(UNATTRIBUTED, "unwrapped") >= 1
+    assert grew("test.obs.site", "compiles") == 1
+    assert grew("test.obs.site", "unwrapped") == 0
+    # every backend compile of the process is accounted for
+    total = sum(grew(s, "compiles") + grew(s, "unwrapped") for s in after)
+    assert total == seen["n"] - n0
+    # jit_compiles_total carries both kinds
+    from paddle_tpu.obs.compile_watch import compile_collector
+    rows = {(r[0], r[2]["site"]): r[3] for r in compile_collector(cw)()}
+    assert rows[("jit_compiles_total", "pt.step.admit")] == \
+        after["pt.step.admit"]["unwrapped"]
+
+
+def test_site_compiles_counts_signatures_and_backend_executables():
+    """What `correct` holds to 0 in a window (benchmark/lib/common.py sums
+    a site's `compiles`): a new signature is exactly 1 even where XLA built
+    nothing (a cached executable), a known signature is 0, and a call that
+    does build counts its backend executables, known signature or not."""
+    import jax
+
+    from paddle_tpu.obs.compile_watch import CompileWatch
+
+    cw = CompileWatch()
+    site = "test.obs.cached"
+    with cw.watch(site, ("sig", 1)):       # new signature, no backend event
+        pass
+    assert cw.snapshot()[site]["compiles"] == 1
+    with cw.watch(site, ("sig", 1)):       # known, nothing built
+        pass
+    assert cw.snapshot()[site]["compiles"] == 1
+    with cw.watch(site, ("sig", 1)):       # known, yet it builds one
+        jax.random.split(jax.random.PRNGKey(1), 1301)
+    snap = cw.snapshot()[site]
+    assert snap["compiles"] >= 2 and snap["signatures"] == 1
+    assert snap["unwrapped"] == 0
+
+
+def test_trainer_loop_spans_per_batch_and_fused(tmp_path):
+    """Every `pt.train.*` / `pt.feeder.*` name of docs/observability.md,
+    in the ring, from both loops; the step's global_stat timer is fed by
+    the span."""
+    from paddle_tpu.config.parser import parse_config
+    from paddle_tpu.parameter.argument import Argument
+    from paddle_tpu.trainer.trainer import Trainer
+    from paddle_tpu.utils import global_stat
+
+    cfg_file = tmp_path / "cfg.py"
+    cfg_file.write_text(
+        "from paddle_tpu.dsl import *\n"
+        "settings(batch_size=8, learning_rate=0.1)\n"
+        "x = data_layer(name='x', size=4)\n"
+        "out = fc_layer(input=x, size=2, act=SoftmaxActivation())\n"
+        "classification_cost(input=out, label=data_layer(name='y', "
+        "size=2))\n")
+    tr = Trainer(parse_config(str(cfg_file), ""), seed=0)
+    rng = np.random.default_rng(0)
+
+    def batches(n):
+        for _ in range(n):
+            x = rng.normal(size=(8, 4)).astype(np.float32)
+            yield {"x": Argument(value=x),
+                   "y": Argument(ids=(x.sum(-1) > 0).astype(np.int32))}
+
+    t = get_tracer()
+    saved = (t.enabled, t._ring, t._n)
+    t.clear()
+    t.enabled = True
+    try:
+        n0 = global_stat.get("trainOneBatch").count
+        tr.train_one_pass(batches=batches(3))
+        names = [s["name"] for s in t.snapshot() if s["track"] == "trainer"]
+        assert names.count("pt.train.step") == 3
+        assert names.count("pt.train.next_batch") == 4   # the last finds END
+        assert names.count("pt.train.stage") == 3
+        assert names.count("pt.train.dispatch") == 3
+        assert names.count("pt.train.drain") == 1
+        assert names[-1] == "train_pass"
+        assert global_stat.get("trainOneBatch").count == n0 + 3
+        # stage and dispatch lie inside their step
+        spans = [s for s in t.snapshot() if s["track"] == "trainer"]
+        steps = [s for s in spans if s["name"] == "pt.train.step"]
+        for child in (s for s in spans
+                      if s["name"] in ("pt.train.stage",
+                                       "pt.train.dispatch")):
+            assert any(p["ts"] <= child["ts"] and child["ts"] + child["dur"]
+                       <= p["ts"] + p["dur"] + 1e-6 for p in steps)
+        t.clear()
+        tr.train_one_pass(batches=batches(4), steps_per_dispatch=2)
+        snap = t.snapshot()
+        names = [s["name"] for s in snap]
+        assert names.count("pt.train.scan") == 2
+        assert names.count("pt.train.step") == 2
+        assert names.count("pt.feeder.stage") == 2
+        assert {s["track"] for s in snap
+                if s["name"] == "pt.feeder.stage"} == {"trainer:h2d"}
+        assert names.count("pt.train.drain") == 1
+    finally:
+        t.enabled, t._ring, t._n = saved

@@ -1263,3 +1263,142 @@ def test_kv_push_malformed_reply_degrades_to_push_ok_false(tiny_tr):
         lst.close()
         for p in peers:
             p.close()
+
+
+# ---------------------------------------------------------------------------
+# phase spans (docs/observability.md "The span model"): the pump thread's
+# `pt.` vocabulary on the profiler's timeline and in the ring
+# ---------------------------------------------------------------------------
+
+def test_pump_and_engine_phase_spans_nest_on_the_profilers_clock(
+        tiny_tr, tmp_path):
+    """A few mixed and decode steps through the server under a CPU
+    profiler session: every span name of the pump's family is on ONE
+    thread's line, properly nested and in order; the loop thread's
+    `pt.loop.send` is on another."""
+    import jax
+
+    from tests.test_obs import _nested_ok, _profiler_events
+
+    eng = _engine(tiny_tr)
+    srv = ServingServer(eng, max_queue=32)
+    host, port = srv.start_background()
+    try:
+        with ServingClient(host, port) as c:
+            c.collect([c.submit([3, 4, 5], max_new=2)])       # warm
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                rng = np.random.default_rng(0)
+                ids = [c.submit(rng.integers(2, 31, n).tolist(), max_new=6,
+                                stream=True) for n in (20, 11)]
+                c.collect(ids)
+                time.sleep(0.1)        # the pump goes idle: pt.pump.wait
+                c.stats()              # ... and a command ends the wait
+            finally:
+                jax.profiler.stop_trace()
+    finally:
+        srv.stop_background(drain=True)
+    assert eng.n_mixed_steps >= 2 and eng.n_decode_steps > eng.n_mixed_steps
+    by_name, lines = _profiler_events(tmp_path)
+    pump_names = {"pt.pump.commands", "pt.pump.wait", "pt.engine.step",
+                  "pt.step.admit", "pt.step.plan", "pt.step.decode",
+                  "pt.step.mixed", "pt.step.dispatch", "pt.step.readback",
+                  "pt.step.emit"}
+    assert pump_names | {"pt.loop.send"} <= set(by_name), sorted(by_name)
+    pump = [evs for evs in lines if any(n == "pt.engine.step"
+                                        for n, *_ in evs)]
+    assert len(pump) == 1, "the pump's spans are on one thread's line"
+    pump = pump[0]
+    assert {n for n, *_ in pump} == pump_names
+    assert _nested_ok(pump)
+    loop = [evs for evs in lines if any(n == "pt.loop.send" for n, *_ in evs)]
+    assert len(loop) == 1 and loop[0] is not pump
+    # the step's kind and counts ride as attributes
+    assert all({"live", "step"} <= set(st) for st in by_name["pt.step.decode"])
+    assert all({"rows", "decode_rows"} <= set(st)
+               for st in by_name["pt.step.mixed"])
+
+    # inside every busy pt.engine.step: admit, plan, ONE compiled step
+    # holding dispatch then readback, then emit — in that order
+    def inside(outer, name):
+        return sorted((s, e) for n, s, e, _ in pump
+                      if n == name and outer[0] <= s and e <= outer[1])
+
+    busy = 0
+    for n, s, e, _ in pump:
+        if n != "pt.engine.step":
+            continue
+        kinds = inside((s, e), "pt.step.decode") + \
+            inside((s, e), "pt.step.mixed")
+        if not kinds:
+            continue                   # an idle poll: admission only
+        busy += 1
+        assert len(kinds) == 1
+        (admit,), (plan,), (emit,) = (inside((s, e), "pt.step." + p)
+                                      for p in ("admit", "plan", "emit"))
+        (disp,), (read,) = (inside(kinds[0], "pt.step." + p)
+                            for p in ("dispatch", "readback"))
+        order = [admit, plan, disp, read, emit]
+        assert all(a[1] <= b[0] + 1000 for a, b in zip(order, order[1:]))
+    assert busy >= eng.n_decode_steps - 2      # the warm request's are out
+
+
+@pytest.mark.parametrize("kind", ["decode", "mixed", "scan", "spec"])
+def test_engine_ring_spans_name_the_step_kind(tiny_tr, kind):
+    """The ring (the operator's sink) gets the same phases: one compiled-
+    step span a step, named by the kind the scheduler chose, between
+    plan and emit.  No perf_counter pair around a compiled step is left."""
+    from paddle_tpu.obs import Tracer
+
+    t = Tracer()
+    t.enabled = True
+    kw = {"scan": {"decode_steps": 4}, "spec": {"spec_k": 2}}.get(kind, {})
+    eng = ServingEngine(tiny_tr.executor, tiny_tr.params, num_slots=2,
+                        page_size=8, max_context=64, tracer=t, **kw)
+    rng = np.random.default_rng(1)
+    prompt = np.tile(rng.integers(2, 31, 4), 5)     # repetitive: drafts hit
+    eng.add_request(Request("a", prompt, max_new=9))
+    eng.run()
+    lane = [s for s in t.snapshot() if s["track"] == "engine"]
+    names = [s["name"] for s in lane]
+    assert "pt.step." + kind in names, names
+    steps = [s for s in lane if s["name"] in (
+        "pt.step.decode", "pt.step.mixed", "pt.step.scan", "pt.step.spec")]
+    assert len(steps) == eng.n_decode_steps
+    assert [s["attrs"]["step"] for s in steps] == \
+        list(range(1, len(steps) + 1))
+    if kind == "spec":
+        assert "pt.step.draft" in names
+        assert names.count("pt.step.draft") >= eng.n_draft_steps > 0
+    # per step: admit, plan, dispatch, readback, <kind>, emit close in
+    # this order (a span is recorded when it ends)
+    per_step = [n for n in names if n != "pt.step.draft"]
+    i = per_step.index("pt.step.plan") - 1
+    assert per_step[i:i + 6] == [
+        "pt.step.admit", "pt.step.plan", "pt.step.dispatch",
+        "pt.step.readback", steps[0]["name"], "pt.step.emit"]
+    import inspect
+    import re
+    src = inspect.getsource(ServingEngine)
+    assert not re.search(r"\bt_step\b|tracer\.add\(\"\w+_step\"", src)
+
+
+def test_prefix_eviction_has_its_own_span(tiny_tr):
+    """`pt.kv.evict` times PrefixTree.evict_for, the walk that costs a
+    full pool 25-30% of its decode rate (PERF.md section 6)."""
+    from paddle_tpu.obs import Tracer
+
+    t = Tracer()
+    t.enabled = True
+    rng = np.random.default_rng(3)
+    eng = ServingEngine(tiny_tr.executor, tiny_tr.params, num_slots=1,
+                        page_size=4, max_context=16, num_pages=5, tracer=t)
+    for i in range(2):
+        eng.run([Request(f"f{i}", rng.integers(2, 23, 7).astype(np.int32),
+                         max_new=5)])
+    eng.run([Request("big", rng.integers(2, 23, 9).astype(np.int32),
+                     max_new=7)])
+    assert eng.prefix.n_evictions > 0
+    evicts = [s for s in t.snapshot() if s["name"] == "pt.kv.evict"]
+    assert evicts and all(s["attrs"]["pages"] >= 1 for s in evicts)
+    assert {s["track"] for s in evicts} == {"engine"}
