@@ -3,34 +3,50 @@
 The serving engine's decode hot path (the Ragged Paged Attention shape,
 arXiv:2604.15464): every slot's KV context lives in fixed-size pages of a
 shared HBM pool, mapped by a per-slot page table, and each step attends ONE
-query token per slot over its 0..pos positions.  The jnp fallback
+query token per row over its 0..pos positions.  The jnp fallback
 (ops/attention.py:paged_attention_step) gathers the mapped pages into a
 contiguous [S, max_pages*page_size] view every step — a transient HBM copy
-of the whole context.  This kernel reads pages straight from the pool:
+of the whole context.  This kernel reads LIVE KV only, straight from the
+pool:
 
-  grid (S, max_pages), pages innermost sequential: the page table rides a
-  scalar-prefetch ref (pltpu.PrefetchScalarGridSpec) so the k/v BlockSpec
-  index maps resolve `table[s, p]` BEFORE the DMA is issued — the pool
-  page streams into VMEM with no gathered intermediate.  Per page, fold
-  scores into a running online-softmax (max, sum, acc) VMEM scratch (the
-  same recurrence as pallas_attention.py's flash kernel); pages past the
-  slot's length are skipped entirely via pl.when (the "ragged" part — a
-  slot holding 40 tokens reads 3 pages, not max_pages).
+  grid (rows,): one query row a grid step.  The pools stay in HBM
+  (memory_space=pl.ANY) in the layout the engine holds them,
+  [P, page_size, H_kv, D]; the page table, the lengths and the row->slot
+  indirection ride the scalar-prefetch channel.  Inside a step the kernel
+  loops over the row's own KV in BLOCKS of several pages (128-512 tokens;
+  `block_tokens` derives the count from page_size, H_kv, D and the dtype's
+  bytes against a VMEM budget — one algorithm adapted by shape, nothing
+  selects it), with the trip count cdiv(lengths[r], block) a run-time
+  value: a page past the row's length is never stepped, a dead or padding
+  row costs one grid step and one block of the trash page, and one
+  compiled program serves any fill of the pool.  A block's pages are
+  fetched by the kernel's own async copies addressed through
+  table[row_slot[r], ·], double-buffered: while block i is folded into
+  the running online softmax (max, sum, accumulator: float32 loop
+  carries, the recurrence of pallas_attention.py's flash kernel), block
+  i+1's copies — or the NEXT row's first block — are in flight.
 
-Grouped-query heads are handled in-kernel (per-kv-head score/weight dots,
-a static python loop), so the pool stays at H_kv heads and no expanded
-copy is ever materialized.  Sliding-window decode stays on the jnp
-fallback.  Interpret-mode parity with the fallback is the CPU oracle
-(tests/test_serving.py); on-TPU timing rides tools/bench_serving.py.
+Grouped-query heads are handled in-kernel without a per-head gather: a
+block is one dense [block*H_kv, D] operand as the pool stores it, every
+query head is scored against every (token, kv head) column, and the
+columns of the other groups are masked — scores and weights are
+[H, block*H_kv], 128 or more lanes wide where a page gave 16.  q, k and v enter the dots in
+the dtype they are stored in (bf16 in the cells), accumulated in
+float32, the weights cast to v's dtype: the jnp fallback's precision.
+Sliding-window decode stays on the jnp fallback.  Interpret-mode parity
+with the fallback is the CPU oracle (tests/test_serving.py,
+tests/test_chunked_prefill.py); tests/test_mosaic_compile.py asks the
+chip's compiler at the serve cells' shapes.
 
 MIXED prefill/decode (chunked prefill): the optional `row_slot` operand
 generalizes the query dimension from one-token-per-slot to a packed
 ragged row list — row r attends table row `row_slot[r]` up to
 `lengths[r]` tokens, so a prompt chunk (several consecutive rows, same
 slot) and live decode rows share one grid.  `row_slot` rides the same
-scalar-prefetch channel as the page table; everything else (online
-softmax over live pages, pl.when page skipping, in-kernel GQA) is
-unchanged.
+scalar-prefetch channel as the page table; everything else (the block
+loop over live KV, the online softmax, in-kernel GQA) is unchanged.  A
+chunk's rows each walk their slot's blocks again: sharing a block among
+the rows of one slot is not done here.
 
 SPECULATIVE verify rows (the engine's `--spec-k` draft chains) are the
 same row-indirected shape from this kernel's point of view: a chain is
@@ -51,7 +67,7 @@ here are the per-device counts (H/N and h_kv/N of the model; the engine
 validates divisibility, and the grouped-query ratio H/h_kv is shard-
 invariant).  The kernel itself needs no collective and no change: page
 tables and lengths arrive replicated, every DMA stays on-chip, and the
-head padding below (`max(H, 8)`) applies to the LOCAL count.
+head padding and the block size below follow the LOCAL counts.
 
 MULTI-STEP decode (the engine's `--decode-steps K` scanned dispatch):
 the kernel is scan-body safe — pure in its operands with no host
@@ -101,59 +117,118 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def _kernel(H, h_kv, ps, scale, table_ref, len_ref, row_ref, q_ref, k_ref,
-            v_ref, o_ref, m_s, l_s, acc_s):
-    p = pl.program_id(1)
-    n_pages = pl.num_programs(1)
-    s = pl.program_id(0)
+# VMEM a block's K and V may take together, both buffers counted.  On the
+# v5e the loop costs about 0.23 us a block and 0.08 us a page (my chip
+# run, PR 28), so a wider block amortizes the step and a narrower one
+# fetches fewer dead tokens past a short row's end (the block fill the
+# engine counts: kv_tokens_attended / kv_tokens_fetched).
+_KV_VMEM_BUDGET = 512 << 10
+_BLOCK_TOKENS = (128, 512)       # floor and ceiling of a block, in tokens
 
-    @pl.when(p == 0)
+
+def block_tokens(page_size: int, h_kv: int, head_dim: int, itemsize: int,
+                 max_pages: int) -> int:
+    """Tokens of KV one loop step of the kernel folds: a whole number of
+    pages, derived from the operands' shapes alone — as many as
+    `_KV_VMEM_BUDGET` holds (K and V, two buffers each) within
+    `_BLOCK_TOKENS`, never more than the table maps.  The engine calls
+    this with the pool's shapes to count what the kernel fetches."""
+    per_token = 2 * 2 * h_kv * _round_up(head_dim, 128) * itemsize
+    lo, hi = _BLOCK_TOKENS
+    tokens = max(lo, min(hi, _KV_VMEM_BUDGET // per_token))
+    pages = max(1, min(tokens // page_size, max_pages))
+    return pages * page_size
+
+
+def _kernel(H, h_kv, scale, table_ref, len_ref, row_ref, q_ref, k_hbm, v_hbm,
+            o_ref, kbuf, vbuf, sems, slot_ref):
+    r = pl.program_id(0)
+    n_rows = pl.num_programs(0)
+    _, npb, ps, _, Dp = kbuf.shape
+    maxp = table_ref.shape[1]
+    bt = npb * ps                       # tokens a block
+    C = bt * h_kv                       # score columns: (token, kv head)
+    Hp = q_ref.shape[1]
+    rep = H // h_kv
+
+    def start_fetch(row, blk, slot):
+        """Start the 2*npb page copies of block `blk` of row `row` into
+        buffer `slot`.  A page past the table's end re-reads its last
+        entry; a logical page past the row's length is mapped (or 0, the
+        trash page) and masked below."""
+        s = row_ref[row]
+        for i in range(npb):
+            page = table_ref[s, jnp.minimum(blk * npb + i, maxp - 1)]
+            pltpu.make_async_copy(
+                k_hbm.at[page], kbuf.at[slot, i], sems.at[0, slot]).start()
+            pltpu.make_async_copy(
+                v_hbm.at[page], vbuf.at[slot, i], sems.at[1, slot]).start()
+
+    def wait_fetch(slot):
+        # one wait a buffer: a descriptor of the whole buffer's size takes
+        # what its npb page copies signalled together
+        for j, buf in enumerate((kbuf, vbuf)):
+            pltpu.make_async_copy(
+                buf.at[slot], buf.at[slot], sems.at[j, slot]).wait()
+
+    @pl.when(r == 0)
     def _():
-        m_s[:] = jnp.full_like(m_s, _NEG_INF)
-        l_s[:] = jnp.zeros_like(l_s)
-        acc_s[:] = jnp.zeros_like(acc_s)
+        slot_ref[0] = 0
+        start_fetch(0, 0, 0)
 
-    length = len_ref[s]
+    # never past what the table maps, whatever `lengths` holds: the trip
+    # count is a run-time value, and a corrupted one would be device time
+    length = jnp.minimum(len_ref[r], maxp * ps)
+    # every row folds at least one block (a dead or padding row: one block
+    # of the trash page), so each row's last block can always prefetch the
+    # next row's first
+    nblk = jnp.maximum(pl.cdiv(length, bt), 1)
+    q = q_ref[0]                                          # [Hp, Dp]
+    # column c of a block is token c // h_kv under kv head c % h_kv; query
+    # head h reads kv head h // rep.  Scoring every head against every
+    # column and masking the other groups keeps the block one dense
+    # [bt*h_kv, Dp] operand as the pool stores it — no per-head gather —
+    # and costs the MXU nothing it was not already paying to load K.
+    col = jax.lax.broadcasted_iota(jnp.int32, (Hp, C), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (Hp, C), 0)
+    own_group = (col % h_kv) == (head // rep)
+    tok = col // h_kv
 
-    @pl.when(p * ps < length)
-    def _():
-        rep = H // h_kv
-        q = q_ref[0].astype(jnp.float32)                 # [Hp, Dp]
-        k = k_ref[0].astype(jnp.float32)                 # [ps, h_kv, Dp]
-        v = v_ref[0].astype(jnp.float32)
-        # grouped-query scores: each kv head serves its rep query heads
-        # (static python loop — h_kv is a compile-time constant)
-        parts = []
-        for g in range(h_kv):
-            qg = q[g * rep:(g + 1) * rep, :]             # [rep, Dp]
-            sg = jax.lax.dot_general(
-                qg, k[:, g, :], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)      # [rep, ps]
-            parts.append(sg)
-        sc = jnp.concatenate(parts, axis=0) * scale      # [H, ps]
-        tpos = p * ps + jax.lax.broadcasted_iota(jnp.int32, (H, ps), 1)
-        valid = tpos < length
+    def fold(b, carry):
+        m_prev, l_prev, acc, slot = carry
+        last = b == nblk - 1
+        nrow = jnp.where(last, r + 1, r)
+        nb = jnp.where(last, 0, b + 1)
+
+        @pl.when(nrow < n_rows)
+        def _():
+            start_fetch(nrow, nb, 1 - slot)
+
+        wait_fetch(slot)
+        k = kbuf[slot].reshape(C, Dp)
+        v = vbuf[slot].reshape(C, Dp)
+        sc = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # [Hp, C]
+        valid = jnp.logical_and(own_group, tok < length - b * bt)
         sc = jnp.where(valid, sc, _NEG_INF)
-
-        m_prev = m_s[:H, :1]
-        l_prev = l_s[:H, :1]
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-        w = jnp.where(valid, jnp.exp(sc - m_new), 0.0)   # [H, ps]
+        w = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_s[:H, :1] = corr * l_prev + jnp.sum(w, axis=-1, keepdims=True)
-        pv = []
-        for g in range(h_kv):
-            wg = w[g * rep:(g + 1) * rep, :]             # [rep, ps]
-            pv.append(jax.lax.dot_general(
-                wg, v[:, g, :], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))     # [rep, Dp]
-        acc_s[:H] = acc_s[:H] * corr + jnp.concatenate(pv, axis=0)
-        m_s[:H, :1] = m_new
+        l_new = corr * l_prev + jnp.sum(w, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            w.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)           # [Hp, Dp]
+        return m_new, l_new, acc * corr + pv, 1 - slot
 
-    @pl.when(p == n_pages - 1)
-    def _():
-        l = jnp.maximum(l_s[:, :1], 1e-30)
-        o_ref[0] = (acc_s[:] / l).astype(o_ref.dtype)
+    _, l, acc, slot = jax.lax.fori_loop(
+        0, nblk, fold,
+        (jnp.full((Hp, 1), _NEG_INF, jnp.float32),
+         jnp.zeros((Hp, 1), jnp.float32),
+         jnp.zeros((Hp, Dp), jnp.float32),
+         slot_ref[0]))
+    slot_ref[0] = slot
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def paged_attention(
@@ -169,17 +244,20 @@ def paged_attention(
                             # (the classic one-token-per-slot decode)
 ) -> Array:
     """Ragged paged attention -> [R, H, D].  Same math as the jnp
-    fallback's gather path (online softmax re-association aside).
+    fallback's gather path (online softmax re-association aside): q, k
+    and v enter the dots in the dtype they are stored in, scores and the
+    running max / sum / accumulator are float32, the weights are cast to
+    v's dtype.
 
     `row_slot` is the MIXED prefill/decode generalization (the full
     ragged-query shape of arXiv:2604.15464): the query rows are no longer
     one-per-slot — a chunk-prefilling prompt packs several consecutive
     rows against the same page-table row, a decode slot keeps its single
-    row, and padding rows aim at an all-zero table row.  The indirection
-    rides the scalar-prefetch channel next to the page table, so the k/v
-    BlockSpec index map resolves `table[row_slot[r], p]` before the page
-    DMA is issued — same zero-copy pool streaming as the decode-only
-    kernel, one compiled program for any prefill/decode mix."""
+    row, and padding rows aim at an all-zero table row.  The table, the
+    lengths and the indirection ride the scalar-prefetch channel, so the
+    kernel addresses `table[row_slot[r], ·]` itself and bounds each row's
+    loop by `lengths[r]` at run time — one compiled program for any
+    prefill/decode mix and any fill of the pool."""
     R, H, D = q.shape
     P, ps, h_kv, _ = k_pages.shape
     maxp = page_table.shape[1]
@@ -189,32 +267,32 @@ def paged_attention(
     if row_slot is None:
         row_slot = jnp.arange(R, dtype=jnp.int32)
 
-    Hp = _round_up(max(H, 8), 8)
+    itemsize = jnp.dtype(k_pages.dtype).itemsize
+    # q's rows fill whole sublane tiles of its dtype (8 rows of 32 bits)
+    Hp = _round_up(H, 8 * max(1, 4 // jnp.dtype(q.dtype).itemsize))
     Dp = _round_up(D, 128)
+    npb = block_tokens(ps, h_kv, D, itemsize, maxp) // ps
     qp = jnp.pad(q, ((0, 0), (0, Hp - H), (0, Dp - D)))
     kp = jnp.pad(k_pages, ((0, 0), (0, 0), (0, 0), (0, Dp - D)))
     vp = jnp.pad(v_pages, ((0, 0), (0, 0), (0, 0), (0, Dp - D)))
 
-    kernel = functools.partial(_kernel, H, h_kv, ps, scale)
+    kernel = functools.partial(_kernel, H, h_kv, scale)
+    row_block = pl.BlockSpec((1, Hp, Dp),
+                             lambda r, tbl, lens, rows: (r, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,               # page_table, lengths, row_slot
-        grid=(R, maxp),
+        grid=(R,),
         in_specs=[
-            pl.BlockSpec((1, Hp, Dp),
-                         lambda s, p, tbl, lens, rows: (s, 0, 0)),
-            pl.BlockSpec((1, ps, h_kv, Dp),
-                         lambda s, p, tbl, lens, rows:
-                         (tbl[rows[s], p], 0, 0, 0)),
-            pl.BlockSpec((1, ps, h_kv, Dp),
-                         lambda s, p, tbl, lens, rows:
-                         (tbl[rows[s], p], 0, 0, 0)),
+            row_block,
+            pl.BlockSpec(memory_space=pl.ANY),    # the pools stay in HBM
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, Hp, Dp),
-                               lambda s, p, tbl, lens, rows: (s, 0, 0)),
+        out_specs=row_block,
         scratch_shapes=[
-            pltpu.VMEM((Hp, 128), jnp.float32),   # running max (lane 0)
-            pltpu.VMEM((Hp, 128), jnp.float32),   # running sum (lane 0)
-            pltpu.VMEM((Hp, Dp), jnp.float32),    # output accumulator
+            pltpu.VMEM((2, npb, ps, h_kv, Dp), k_pages.dtype),
+            pltpu.VMEM((2, npb, ps, h_kv, Dp), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),      # (K | V, buffer)
+            pltpu.SMEM((1,), jnp.int32),          # buffer the next row reads
         ],
     )
     out = pl.pallas_call(
@@ -222,8 +300,9 @@ def paged_attention(
         name="paged_attn",      # the device op's name in a profiler trace
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, Hp, Dp), q.dtype),
+        # rows run in order: each prefetches its successor's first block
         compiler_params=pallas_tpu_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       row_slot.astype(jnp.int32), qp, kp, vp)

@@ -377,6 +377,12 @@ class ServingEngine:
         self.n_expired = 0
         self.tokens_generated = 0
         self.occupancy_sum = 0.0              # sum of live/S over steps
+        # what the paged kernel reads, summed over compiled steps (one
+        # layer's worth a step): the KV tokens its rows attend, and the
+        # tokens it fetches for them in whole blocks (ops/pallas_paged.py
+        # block_tokens).  attended / fetched is the block fill.
+        self.kv_tokens_attended = 0
+        self.kv_tokens_fetched = 0
         self._admit_seq = 0
         self._prefill_cache: dict[int, object] = {}
         self._pack_cache: dict[int, object] = {}
@@ -397,6 +403,11 @@ class ServingEngine:
         self.n_host_stages = 0
         S = num_slots
         self._kk = self.kv.capacity_tokens     # keys per slot (> max_new)
+        from paddle_tpu.ops.pallas_paged import block_tokens
+        pool = next(iter(self.kv.pools.values()))["k"]
+        self._kv_block = block_tokens(
+            self.kv.page_size, pool.shape[2] // self.kv.tp_shards,
+            pool.shape[3], pool.dtype.itemsize, self.kv.pages_per_slot)
         self._kv_synced = -1                   # kv.version last uploaded
         self._slots_dirty = True
         self._run_host: Optional[np.ndarray] = None
@@ -1101,6 +1112,7 @@ class ServingEngine:
             self._unpack_state(st)
             self.n_decode_steps += 1
             self.occupancy_sum += len(live) / S
+            self._count_kv(self._slot_lengths())
             with self._phase("readback"):
                 nxt = np.asarray(nxt)                      # host sync
             self._note_step_metrics(len(runnable), decoded=True)
@@ -1142,6 +1154,19 @@ class ServingEngine:
                 self.decode_gap_hist.observe(
                     (now - self._t_prev_decode) * 1e3)
             self._t_prev_decode = now
+
+    def _slot_lengths(self) -> np.ndarray:
+        """Tokens each slot's decode row attends: pos + 1 (an empty slot
+        sits at pos 0 and reads one token of the trash page)."""
+        return np.fromiter((1 if sl is None else sl.pos + 1
+                            for sl in self.slots), np.int64,
+                           len(self.slots))
+
+    def _count_kv(self, lengths: np.ndarray) -> None:
+        """Add one compiled step's rows to the kernel's two counters."""
+        bt = self._kv_block
+        self.kv_tokens_attended += int(lengths.sum())
+        self.kv_tokens_fetched += int((-(-lengths // bt)).sum()) * bt
 
     def _scan_window_ok(self, runnable, k: int) -> bool:
         """Page precondition for ONE k-step scanned dispatch: every
@@ -1192,6 +1217,8 @@ class ServingEngine:
             self.n_scan_flushes += 1
             self.n_scan_steps += k
             self.occupancy_sum += len(live) / S
+            base = self._slot_lengths()
+            ran = np.zeros(S, np.int64)     # bodies each slot advanced in
             with self._phase("readback"):
                 blk = np.asarray(blk)                  # [k, S] host sync
             self._note_step_metrics(len(runnable), decoded=True)
@@ -1207,12 +1234,17 @@ class ServingEngine:
                     if t == sl.req.eos_id or sl.gen + len(burst) >= \
                             sl.req.max_new:
                         break            # device run mask froze here too
+                ran[s] = len(burst)
                 self.cur_burst = len(burst)
                 try:
                     for t in burst:
                         self._bank_token(s, t)
                 finally:
                     self.cur_burst = 1
+        # body i reads a slot at pos + min(i, bodies it ran): a retired or
+        # paused slot recomputes at its frozen position
+        self._count_kv(base[None, :] + np.minimum(
+            np.arange(k)[:, None], ran[None, :]))
         return True
 
     def _run_mixed_step(self, live, runnable, filling) -> bool:
@@ -1280,6 +1312,7 @@ class ServingEngine:
             self.n_decode_steps += 1
             self.n_mixed_steps += 1
             self.occupancy_sum += len(live) / S
+            self._count_kv(row_pos + 1)           # a padding row reads 1
             with self._phase("readback"):
                 nxt = np.asarray(nxt)                      # host sync
             self._note_step_metrics(r, decoded=bool(runnable))
@@ -1613,6 +1646,7 @@ class ServingEngine:
             if advanced:
                 self.n_mixed_steps += 1
             self.occupancy_sum += len(live) / S
+            self._count_kv(row_pos + 1)           # a padding row reads 1
             with self._phase("readback"):
                 sampled = np.asarray(sampled)              # host sync
                 acc = np.asarray(acc)
@@ -2374,7 +2408,8 @@ class ServingEngine:
             "counters": {k: getattr(self, k) for k in (
                 "_admit_seq", "n_decode_steps", "n_preemptions",
                 "n_cancelled", "n_expired", "tokens_generated",
-                "occupancy_sum", "n_prefix_hits", "n_prefix_misses",
+                "occupancy_sum", "kv_tokens_attended", "kv_tokens_fetched",
+                "n_prefix_hits", "n_prefix_misses",
                 "prefill_tokens_saved", "n_restore_hits",
                 "restore_tokens_saved", "n_prefill_chunks",
                 "n_mixed_steps", "n_spec_steps", "n_spec_chains",

@@ -1444,6 +1444,10 @@ class ServingServer:
             "max_step_tokens": eng.max_step_tokens,
             "prefill_chunks": eng.n_prefill_chunks,
             "mixed_steps": eng.n_mixed_steps,
+            # the paged kernel's reads: tokens its rows attended against
+            # tokens it fetched in whole blocks (their ratio = block fill)
+            "kv_tokens_attended": eng.kv_tokens_attended,
+            "kv_tokens_fetched": eng.kv_tokens_fetched,
             # speculative decoding: the A/B-able knobs + the counters the
             # accept rate reconciles from, plus the adaptive state
             # (drafter kind, dynamic-k flag, per-slot learned EWMAs)

@@ -421,3 +421,101 @@ def test_pallas_ragged_kernel_matches_fallback(tr):
     np.testing.assert_allclose(np.asarray(got)[real],
                                np.asarray(want)[real],
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the paged kernel's row indirection over its block loop (interpret mode)
+# ---------------------------------------------------------------------------
+
+def _ragged_pools(slot_tokens, Hkv=2, D=128, ps=16, maxp=20, seed=0):
+    """Pools and a table (+ the virtual all-zero trash row) whose slots
+    hold pages for `slot_tokens` tokens each."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    S = len(slot_tokens)
+    P = 1 + S * maxp
+    kp = jnp.asarray(rng.normal(size=(P, ps, Hkv, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P, ps, Hkv, D)), jnp.float32)
+    table = np.zeros((S + 1, maxp), np.int32)
+    free = rng.permutation(np.arange(1, P)).tolist()
+    for s, n in enumerate(slot_tokens):
+        for j in range(-(-n // ps)):
+            table[s, j] = free.pop()
+    return rng, kp, vp, jnp.asarray(table)
+
+
+# rows as (slot, position); slot 3 is the virtual trash row.  A block is
+# 128 tokens at these shapes (tests/test_serving.py), so positions
+# 126..130 walk a chunk or a chain across a block boundary
+_ROW_CASES = {
+    "chunk-rows-across-a-block": [(0, 200)] + [(1, p) for p in
+                                               range(125, 131)] + [(2, 40)],
+    "spec-chain-of-one-slot": [(0, 126), (0, 127), (0, 128), (0, 129),
+                               (1, 9), (2, 260)],
+    "padding-rows-beside-live": [(3, 0), (0, 255), (3, 0), (1, 0), (3, 0)],
+    "final-row-is-padding": [(0, 128), (2, 319), (3, 0)],
+}
+
+
+@pytest.mark.parametrize("case", list(_ROW_CASES))
+def test_paged_kernel_ragged_rows_match_fallback(case):
+    """Chunk rows, a speculative chain and padding rows through the whole
+    step (scatter, then the kernel's read) against use_kernel=False."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.attention import ragged_paged_attention_step
+
+    rng, kp, vp, table = _ragged_pools([264, 136, 320])
+    rows = _ROW_CASES[case]
+    row_slot = jnp.asarray([s for s, _ in rows], jnp.int32)
+    row_pos = jnp.asarray([p for _, p in rows], jnp.int32)
+    T = len(rows)
+    q = jnp.asarray(rng.normal(size=(T, 4, 128)), jnp.float32)
+    kn = jnp.asarray(rng.normal(size=(T, 2, 128)), jnp.float32)
+    vn = jnp.asarray(rng.normal(size=(T, 2, 128)), jnp.float32)
+    outs = [ragged_paged_attention_step(q, kn, vn, kp, vp, table, row_slot,
+                                        row_pos, use_kernel=use)[0]
+            for use in (True, False)]
+    real = np.asarray(row_slot) < 3
+    np.testing.assert_allclose(np.asarray(outs[0])[real],
+                               np.asarray(outs[1])[real],
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("form", ["decode", "mixed"])
+def test_paged_kernel_inside_scan_matches_fallback(form):
+    """The engine's scanned dispatch: positions are a scan carry, so each
+    body's trip counts come from run-time values — three bodies walk one
+    slot from the last token of a block into the next."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.attention import (paged_attention_step,
+                                          ragged_paged_attention_step)
+
+    rng, kp, vp, table = _ragged_pools([264, 136, 320], seed=1)
+    S = 3
+    q = jnp.asarray(rng.normal(size=(3, S, 4, 128)), jnp.float32)
+    kn = jnp.asarray(rng.normal(size=(3, S, 2, 128)), jnp.float32)
+    vn = jnp.asarray(rng.normal(size=(3, S, 2, 128)), jnp.float32)
+    pos0 = jnp.asarray([126, 0, 255], jnp.int32)
+
+    def run(use_kernel):
+        def body(carry, x):
+            kp, vp, pos = carry
+            q, kn, vn = x
+            if form == "decode":
+                out, kp, vp = paged_attention_step(
+                    q[:, None], kn[:, None], vn[:, None], kp, vp,
+                    table[:S], pos, use_kernel=use_kernel)
+                out = out[:, 0]
+            else:
+                out, kp, vp = ragged_paged_attention_step(
+                    q, kn, vn, kp, vp, table, jnp.arange(S), pos,
+                    use_kernel=use_kernel)
+            return (kp, vp, pos + 1), out
+        return jax.lax.scan(body, (kp, vp, pos0), (q, kn, vn))[1]
+
+    np.testing.assert_allclose(np.asarray(run(True)), np.asarray(run(False)),
+                               rtol=2e-5, atol=2e-5)

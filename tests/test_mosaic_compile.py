@@ -59,10 +59,10 @@ def mosaic(one_chip, no_persistent_cache, monkeypatch):
     for mod in (pallas_additive, pallas_attention, pallas_paged, pallas_rnn):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
-    def compile_(fn, *shapes):
+    def compile_(fn, *shapes, donate=()):
         args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
                 for s, d in shapes]
-        compiled = jax.jit(fn).lower(*args).compile()
+        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
         assert "tpu_custom_call" in compiled.as_text()
         return compiled
 
@@ -219,6 +219,52 @@ def test_paged_mixed_rows(mosaic):
 
     mosaic(step, ((T, 8, DH), bf16), ((T, H_KV, DH), bf16),
            ((T, H_KV, DH), bf16), *_pool_shapes(), ((T,), i32), ((T,), i32))
+
+
+# the serve cells' own shapes (benchmark/configs/starcoder2-3b-serve.json:
+# 64 slots, page 16, context 4,096, 24 query / 2 KV heads of 128, bf16)
+CELL = dict(S=64, PAGE=16, MAXP=4096 // 16, H=24, H_KV=2, D=128,
+            POOL=16384)
+
+
+@pytest.mark.parametrize("form", ["decode", "mixed-128-rows"])
+def test_paged_kernel_at_the_serve_cells_shapes(mosaic, form):
+    """Decode (64 rows) and the mixed step (prefill_chunk 64 + 64 slots =
+    128 rows, the table carrying its virtual trash row) through the calls
+    the engine makes, pools in HBM as the engine holds them."""
+    from paddle_tpu.ops.attention import (paged_attention_step,
+                                          ragged_paged_attention_step)
+    c = CELL
+    pools = [((c["POOL"], c["PAGE"], c["H_KV"], c["D"]), bf16)] * 2
+    if form == "decode":
+        def step(q, k, v, kp, vp, table, pos):
+            return paged_attention_step(q, k, v, kp, vp, table, pos,
+                                        use_kernel=True)
+        S = c["S"]
+        compiled = mosaic(
+            step, ((S, 1, c["H"], c["D"]), bf16),
+            ((S, 1, c["H_KV"], c["D"]), bf16),
+            ((S, 1, c["H_KV"], c["D"]), bf16), *pools,
+            ((S, c["MAXP"]), i32), ((S,), i32), donate=(3, 4))
+    else:
+        def step(q, k, v, kp, vp, table, row_slot, row_pos):
+            return ragged_paged_attention_step(q, k, v, kp, vp, table,
+                                               row_slot, row_pos,
+                                               use_kernel=True)
+        T = 64 + c["S"]
+        compiled = mosaic(
+            step, ((T, c["H"], c["D"]), bf16), ((T, c["H_KV"], c["D"]), bf16),
+            ((T, c["H_KV"], c["D"]), bf16), *pools,
+            ((c["S"] + 1, c["MAXP"]), i32), ((T,), i32), ((T,), i32),
+            donate=(3, 4))
+    # one Pallas call a layer a step, and — the pools donated, as the
+    # engine's steps donate their state — no copy of a pool on its way in
+    # (a layout the kernel could not take would cost 2 x 134 MB a layer)
+    assert kernel_names(compiled) == ["paged_attn.1"], kernel_names(compiled)
+    import re
+    made_by = re.findall(r"= bf16\[16384,16,2,128\]\S* ([\w-]+)\(",
+                         compiled.as_text())
+    assert made_by and "copy" not in made_by, made_by
 
 
 @pytest.mark.parametrize("form", ["decode", "mixed"])

@@ -186,6 +186,10 @@ def test_stats_rpc_reports_occupancy_and_latency(tiny_tr):
         assert s["queue_depth"] == 0 and s["inflight"] == 0
         assert s["tokens_generated"] >= 4
         assert s["free_pages"] == s["num_pages"] - 1
+        # the paged kernel's reads: whole blocks fetched for the tokens
+        # its rows attended (one 64-token block a row at this context)
+        assert 0 < s["kv_tokens_attended"] <= s["kv_tokens_fetched"]
+        assert s["kv_tokens_fetched"] % 64 == 0
         assert s["draining"] is False
         lat = s["latency_ms"]
         assert lat["request_latency"]["p50"] > 0.0
@@ -1381,6 +1385,49 @@ def test_engine_ring_spans_name_the_step_kind(tiny_tr, kind):
     import re
     src = inspect.getsource(ServingEngine)
     assert not re.search(r"\bt_step\b|tracer\.add\(\"\w+_step\"", src)
+
+
+@pytest.mark.parametrize("kind", ["decode", "mixed", "scan", "spec"])
+def test_kv_read_counters_follow_each_step_kind(tiny_tr, kind):
+    """`kv_tokens_attended` / `kv_tokens_fetched` grow by what the step's
+    rows read: pos + 1 a decode row (1 for an empty slot), every body of
+    a scanned dispatch at the position it had then, every packed row of a
+    mixed or verify step (a padding row reads 1), and a whole block a
+    row — here the 64 tokens the table maps."""
+    kw = {"scan": {"decode_steps": 4}, "spec": {"spec_k": 2}}.get(kind, {})
+    eng = ServingEngine(tiny_tr.executor, tiny_tr.params, num_slots=2,
+                        page_size=8, max_context=64, **kw)
+    assert eng._kv_block == 64
+    rng = np.random.default_rng(1)
+    eng.add_request(Request("a", np.tile(rng.integers(2, 31, 4), 5),
+                            max_new=9))
+    S, T, seen = 2, eng.max_step_tokens, set()
+    while eng.queue or any(sl is not None for sl in eng.slots):
+        pos = [None if sl is None else sl.pos for sl in eng.slots]
+        before = (eng.kv_tokens_attended, eng.kv_tokens_fetched,
+                  eng.n_decode_steps, eng.n_mixed_steps, eng.n_spec_steps,
+                  eng.n_scan_flushes, eng.tokens_generated)
+        eng.step()
+        att = eng.kv_tokens_attended - before[0]
+        fetched = eng.kv_tokens_fetched - before[1]
+        if eng.n_decode_steps == before[2]:
+            assert att == fetched == 0          # no compiled step ran
+        elif eng.n_scan_flushes > before[5]:
+            seen.add("scan")
+            ran = eng.tokens_generated - before[6]
+            want = sum(pos[0] + min(i, ran) + 1 for i in range(4)) + 4
+            assert (att, fetched) == (want, 4 * S * 64)
+        elif eng.n_mixed_steps > before[3] or eng.n_spec_steps > before[4]:
+            seen.add("spec" if eng.n_spec_steps > before[4] else "mixed")
+            assert T <= att <= T * 64 and fetched == T * 64
+        else:
+            seen.add("decode")
+            want = sum(1 if p is None else p + 1 for p in pos)
+            assert (att, fetched) == (want, S * 64)
+    assert kind in seen, seen
+    snap = eng.checkpoint_state()["counters"]
+    assert snap["kv_tokens_attended"] == eng.kv_tokens_attended
+    assert snap["kv_tokens_fetched"] == eng.kv_tokens_fetched
 
 
 def test_prefix_eviction_has_its_own_span(tiny_tr):

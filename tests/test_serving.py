@@ -528,3 +528,113 @@ def test_pallas_paged_kernel_matches_fallback():
         np.testing.assert_allclose(np.asarray(got), np.asarray(want[:, 0]),
                                    rtol=2e-5, atol=2e-5,
                                    err_msg=str((S, H, Hkv, D, ps, maxp)))
+
+
+# ---------------------------------------------------------------------------
+# the paged kernel's block loop (interpret mode) against the jnp fallback
+# ---------------------------------------------------------------------------
+
+def _paged_case(lengths, H, Hkv, D, ps, maxp, dtype, seed=0):
+    """Pools, a table and queries for one kernel call: one slot a length
+    (0 = a dead slot: an all-zero table row read at length 1), each live
+    slot's pages scattered over the pool.  Returns the kernel's output and
+    the fallback's (the decode step with use_kernel=False)."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.attention import paged_attention_step
+    from paddle_tpu.ops.pallas_paged import paged_attention
+
+    rng = np.random.default_rng(seed)
+    S = len(lengths)
+    P = 1 + S * maxp
+    kp = jnp.asarray(rng.normal(size=(P, ps, Hkv, D)), dtype)
+    vp = jnp.asarray(rng.normal(size=(P, ps, Hkv, D)), dtype)
+    table = np.zeros((S, maxp), np.int32)
+    free = rng.permutation(np.arange(1, P)).tolist()
+    for s, n in enumerate(lengths):
+        for j in range(-(-n // ps)):
+            table[s, j] = free.pop()
+    pos = np.maximum(np.asarray(lengths, np.int32) - 1, 0)
+    q = jnp.asarray(rng.normal(size=(S, 1, H, D)), dtype)
+    kn = jnp.asarray(rng.normal(size=(S, 1, Hkv, D)), dtype)
+    vn = jnp.asarray(rng.normal(size=(S, 1, Hkv, D)), dtype)
+    want, ck, cv = paged_attention_step(
+        q, kn, vn, kp, vp, jnp.asarray(table), jnp.asarray(pos),
+        use_kernel=False)
+    got = jax.jit(paged_attention)(q[:, 0], ck, cv, jnp.asarray(table),
+                                   jnp.asarray(pos) + 1)
+    return np.asarray(got, np.float32), np.asarray(want[:, 0], np.float32)
+
+
+# at H_kv 2, D 128, float32 a block is 128 tokens (8 pages of 16); the
+# table maps 320, so the last block of a full row runs past its end
+_BLOCK = 128
+_LENGTH_CASES = {
+    "one-token": 1,
+    "block-minus-1": _BLOCK - 1,
+    "one-block": _BLOCK,
+    "block-plus-1": _BLOCK + 1,
+    "boundary-inside-a-page-run": _BLOCK + 24,
+    "two-blocks": 2 * _BLOCK,
+    "full-context": 320,
+}
+
+
+@pytest.mark.parametrize("case", list(_LENGTH_CASES))
+def test_paged_kernel_block_loop_matches_fallback(case):
+    """Every trip count of the in-kernel block loop, beside a dead slot
+    (one block of the trash page) and a second live row of another length:
+    no token left out, none past the row's length let in."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas_paged import block_tokens
+
+    assert block_tokens(16, 2, 128, 4, 20) == _BLOCK
+    got, want = _paged_case([_LENGTH_CASES[case], 0, 77], H=4, Hkv=2,
+                            D=128, ps=16, maxp=20, dtype=jnp.float32)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+_SHAPE_CASES = {
+    # name: (H, H_kv, D, page_size, max_pages, dtype, tolerance)
+    "rep1-d64-page8": (4, 4, 64, 8, 40, "float32", 2e-5),
+    "rep12-d128-page16": (24, 2, 128, 16, 20, "float32", 2e-5),
+    "rep12-d128-page16-bf16": (24, 2, 128, 16, 20, "bfloat16", 2e-2),
+    "rep3-d64-page16": (6, 2, 64, 16, 20, "float32", 2e-5),
+    "rep2-d128-page8": (4, 2, 128, 8, 40, "float32", 2e-5),
+    "one-kv-head": (4, 1, 32, 16, 20, "float32", 2e-5),
+}
+
+
+@pytest.mark.parametrize("case", list(_SHAPE_CASES))
+def test_paged_kernel_shapes_match_fallback(case):
+    """One algorithm adapted by shape: grouped-query ratios 1-12, head
+    sizes under and at a lane tile, pages of 8 and 16, float32 and
+    bfloat16 pools (bf16 operands, float32 accumulation — the fallback's
+    precision, so only the output's rounding separates them)."""
+    import jax.numpy as jnp
+
+    H, Hkv, D, ps, maxp, dtype, tol = _SHAPE_CASES[case]
+    got, want = _paged_case([1, 130, 0, 300, 256], H, Hkv, D, ps, maxp,
+                            jnp.dtype(dtype), seed=1)
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+
+
+def test_paged_kernel_bounds_its_loop_by_the_table():
+    """The trip count is a run-time value, so a length no table can hold
+    (a corrupted position) must not become minutes of device time: the
+    loop stops where the table ends and reads what a full row reads."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas_paged import paged_attention
+
+    rng = np.random.default_rng(2)
+    ps, maxp, Hkv, D = 16, 20, 2, 128
+    kp = jnp.asarray(rng.normal(size=(1 + maxp, ps, Hkv, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(1 + maxp, ps, Hkv, D)), jnp.float32)
+    table = jnp.asarray(np.arange(1, maxp + 1, dtype=np.int32)[None, :])
+    q = jnp.asarray(rng.normal(size=(1, 4, D)), jnp.float32)
+    outs = [np.asarray(paged_attention(q, kp, vp, table,
+                                       jnp.asarray([n], jnp.int32)))
+            for n in (maxp * ps, 2 ** 31 - 1)]
+    np.testing.assert_array_equal(outs[0], outs[1])
