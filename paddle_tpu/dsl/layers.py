@@ -34,6 +34,7 @@ from paddle_tpu.dsl.base import LayerOutput, current_context
 from paddle_tpu.dsl.poolings import AvgPooling, BasePoolingType, FirstPooling, LastPooling, MaxPooling
 
 __all__ = [
+    "rms_norm_layer", "gated_ffn_layer", "mla_attention_layer",
     "data_layer", "fc_layer", "embedding_layer", "mixed_layer", "addto_layer",
     "concat_layer", "dropout_layer", "full_matrix_projection",
     "trans_full_matrix_projection", "identity_projection", "table_projection",
@@ -1077,6 +1078,121 @@ def layer_norm_layer(
                        seq_level=input.seq_level)
 
 
+def rms_norm_layer(
+    input: LayerOutput,
+    name: Optional[str] = None,
+    eps: float = 1e-6,
+    param_attr: Optional[ParameterAttribute] = None,
+    layer_attr: Optional[ExtraLayerAttribute] = None,
+) -> LayerOutput:
+    """Last-dim RMS normalization with a learned scale, no bias
+    (graph/layers_misc.py rms_norm)."""
+    name = _name(name, "rms_norm")
+    cfg = LayerConfig(name=name, type="rms_norm", size=input.size,
+                      active_type="")
+    cfg.attrs["eps"] = eps
+    pa = param_attr or ParameterAttribute(initial_mean=1.0, initial_std=0.0)
+    pname = _make_param(name, 0, [1, input.size], pa)
+    cfg.inputs.append(LayerInput(input_layer_name=input.name,
+                                 input_parameter_name=pname))
+    _layer_attr_fields(cfg, layer_attr)
+    current_context().add_layer(cfg)
+    return LayerOutput(name, "rms_norm", input.size, parents=[input],
+                       seq_level=input.seq_level)
+
+
+def gated_ffn_layer(
+    input: LayerOutput,
+    *,
+    hidden: int,
+    size: Optional[int] = None,
+    name: Optional[str] = None,
+    param_attr: Optional[ParameterAttribute] = None,
+    layer_attr: Optional[ExtraLayerAttribute] = None,
+) -> LayerOutput:
+    """The SwiGLU feed-forward block (silu(x W_gate) * x W_up) W_down,
+    bias-free (graph/layers_misc.py gated_ffn); `param_attr` initializes
+    all three matrices (it must not be named: that would tie them)."""
+    size = size if size is not None else input.size
+    name = _name(name, "gated_ffn")
+    assert param_attr is None or not param_attr.name, \
+        "a named param_attr would share one matrix across gate/up/down"
+    cfg = LayerConfig(name=name, type="gated_ffn", size=size, active_type="")
+    for i, dims in enumerate(([input.size, hidden], [input.size, hidden],
+                              [hidden, size])):
+        pname = _make_param(name, i, dims, param_attr)
+        cfg.inputs.append(LayerInput(input_layer_name=input.name,
+                                     input_parameter_name=pname))
+    _layer_attr_fields(cfg, layer_attr)
+    current_context().add_layer(cfg)
+    return LayerOutput(name, "gated_ffn", size, parents=[input],
+                       seq_level=input.seq_level)
+
+
+def mla_attention_layer(
+    input: LayerOutput,
+    *,
+    num_heads: int,
+    q_lora_rank: int,
+    kv_lora_rank: int,
+    qk_nope_head_dim: int,
+    qk_rope_head_dim: int,
+    v_head_dim: int,
+    size: Optional[int] = None,
+    rope_theta: float = 10000.0,
+    rope_scaling: Optional[dict] = None,
+    rms_eps: float = 1e-6,
+    attn_impl: Optional[str] = None,
+    name: Optional[str] = None,
+    param_attr: Optional[ParameterAttribute] = None,
+    layer_attr: Optional[ExtraLayerAttribute] = None,
+) -> LayerOutput:
+    """Causal multi-head LATENT self-attention (DeepSeek-V2/V3 MLA;
+    ops/mla.py, graph/layers_attn.py mla_attention): queries through a
+    rank-`q_lora_rank` bottleneck, keys and values through ONE shared
+    rank-`kv_lora_rank` latent plus a `qk_rope_head_dim`-wide rotated
+    position key — the row a serving cache stores.  `rope_scaling` is the
+    model's YaRN dict (factor, original_max_position_embeddings, beta_fast,
+    beta_slow, mscale, mscale_all_dim) or None.  `param_attr` initializes
+    the five matrices; the two inner RMSNorm scales start at 1."""
+    assert qk_rope_head_dim % 2 == 0, "the rotated width must be even"
+    assert param_attr is None or not param_attr.name, \
+        "a named param_attr would share one matrix across the projections"
+    size = size if size is not None else input.size
+    name = _name(name, "mla_layer")
+    d, H = input.size, num_heads
+    cfg = LayerConfig(name=name, type="mla_attention", size=size,
+                      active_type="")
+    cfg.attrs.update(
+        num_heads=H, causal=True, q_lora_rank=q_lora_rank,
+        kv_lora_rank=kv_lora_rank, qk_nope_head_dim=qk_nope_head_dim,
+        qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+        rope_theta=rope_theta, rms_eps=rms_eps)
+    if rope_scaling:
+        cfg.attrs["rope_scaling"] = dict(rope_scaling)
+    if attn_impl is not None:
+        cfg.attrs["attn_impl"] = attn_impl
+    one = lambda: ParameterAttribute(initial_mean=1.0, initial_std=0.0)
+    specs = [
+        ([d, q_lora_rank], param_attr),
+        ([1, q_lora_rank], one()),
+        ([q_lora_rank, H * (qk_nope_head_dim + qk_rope_head_dim)],
+         param_attr),
+        ([d, kv_lora_rank + qk_rope_head_dim], param_attr),
+        ([1, kv_lora_rank], one()),
+        ([kv_lora_rank, H * (qk_nope_head_dim + v_head_dim)], param_attr),
+        ([H * v_head_dim, size], param_attr),
+    ]
+    for i, (dims, attr) in enumerate(specs):
+        pname = _make_param(name, i, dims, attr)
+        cfg.inputs.append(LayerInput(input_layer_name=input.name,
+                                     input_parameter_name=pname))
+    _layer_attr_fields(cfg, layer_attr)
+    current_context().add_layer(cfg)
+    return LayerOutput(name, "mla_attention", size, parents=[input],
+                       seq_level=input.seq_level)
+
+
 def moe_layer(
     input: LayerOutput,
     *,
@@ -1084,35 +1200,92 @@ def moe_layer(
     expert_hidden: int,
     size: Optional[int] = None,
     top_k: int = 2,
-    capacity_factor: float = 1.25,
     aux_weight: float = 0.01,
+    gated: bool = False,
+    scoring: str = "softmax",
+    n_group: int = 1,
+    topk_group: int = 1,
+    select_bias: bool = False,
+    norm_topk: bool = True,
+    routed_scale: float = 1.0,
+    shared_hidden: int = 0,
+    experts_held: Optional[int] = None,
+    first_expert: int = 0,
     name: Optional[str] = None,
+    param_attr: Optional[ParameterAttribute] = None,
     layer_attr: Optional[ExtraLayerAttribute] = None,
 ) -> LayerOutput:
     """Mixture-of-experts FFN block — NEW capability (parallel/moe.py):
-    top-k routed experts with capacity, load-balancing aux loss, expert
-    weights sharded over the `model` mesh axis (expert parallelism).
-    size defaults to the input width (residual-friendly)."""
+    top-k routed experts, DROPLESS (every routed pair is computed), with a
+    load-balancing aux loss; stacked expert weights shard over the `model`
+    mesh axis (expert parallelism).  size defaults to the input width
+    (residual-friendly).
+
+    Defaults give softmax routing over plain ReLU experts with biases.
+    `gated` makes the experts bias-free SwiGLU; `scoring='sigmoid'`,
+    `n_group`/`topk_group`, `select_bias` (a [1, E] bias used for selection
+    only), `routed_scale` and `shared_hidden` (one always-on gated expert of
+    that width) give the DeepSeek-V3 layer.  `experts_held`/`first_expert`
+    cut the layer to one expert-parallel rank's share: the router still
+    scores all `num_experts`, the weights hold only the experts
+    [first_expert, first_expert + experts_held) and the layer computes
+    their part of the result (what the absent experts would add is left
+    out; the shared expert is computed whole).  `param_attr` sets the
+    initializer of every matrix."""
     import math as _math
     size = size if size is not None else input.size
     name = _name(name, "moe_layer")
     D, E, H = input.size, num_experts, expert_hidden
+    held = E if experts_held is None else int(experts_held)
+    assert 0 <= first_expert and first_expert + held <= E, \
+        f"experts [{first_expert}, {first_expert + held}) are not among {E}"
+    assert E % n_group == 0 and topk_group <= n_group, \
+        f"{E} experts in {n_group} groups, {topk_group} kept"
+    assert shared_hidden == 0 or gated, "a shared expert is a gated expert"
     cfg = LayerConfig(name=name, type="moe", size=size, active_type="")
-    cfg.attrs["top_k"] = top_k
-    cfg.attrs["capacity_factor"] = capacity_factor
-    cfg.attrs["aux_weight"] = aux_weight
+    cfg.attrs.update(top_k=top_k, aux_weight=aux_weight, num_experts=E)
+    if gated:
+        cfg.attrs["gated"] = True
+    if scoring != "softmax":
+        cfg.attrs["scoring"] = scoring
+    if n_group > 1:
+        cfg.attrs.update(n_group=n_group, topk_group=topk_group)
+    if select_bias:
+        cfg.attrs["select_bias"] = True
+    if not norm_topk:
+        cfg.attrs["norm_topk"] = False
+    if routed_scale != 1.0:
+        cfg.attrs["routed_scale"] = routed_scale
+    if shared_hidden:
+        cfg.attrs["shared_hidden"] = shared_hidden
+    if first_expert:
+        cfg.attrs["first_expert"] = first_expert
+
+    def w(fan_in, spec=None):
+        if param_attr is not None:
+            import copy as _copy
+            pa = _copy.copy(param_attr)
+            pa.partition_spec = spec
+            return pa
+        return ParameterAttribute(initial_std=1.0 / _math.sqrt(fan_in),
+                                  partition_spec=spec)
+
+    zero = lambda spec=None: ParameterAttribute(
+        initial_std=0.0, initial_mean=0.0, partition_spec=spec)
     espec = ["model", None, None]
-    specs = [
-        ([D, E], ParameterAttribute(initial_std=1.0 / _math.sqrt(D))),
-        ([E, D, H], ParameterAttribute(initial_std=1.0 / _math.sqrt(D),
-                                       partition_spec=espec)),
-        ([E, H], ParameterAttribute(initial_std=0.0, initial_mean=0.0,
-                                    partition_spec=espec[:2])),
-        ([E, H, size], ParameterAttribute(initial_std=1.0 / _math.sqrt(H),
-                                          partition_spec=espec)),
-        ([E, size], ParameterAttribute(initial_std=0.0, initial_mean=0.0,
-                                       partition_spec=espec[:2])),
-    ]
+    specs = [([D, E], w(D))]
+    if gated:
+        specs += [([held, D, H], w(D, espec)), ([held, D, H], w(D, espec)),
+                  ([held, H, size], w(H, espec))]
+        if select_bias:
+            specs.append(([1, E], zero()))
+        if shared_hidden:
+            specs += [([D, shared_hidden], w(D)), ([D, shared_hidden], w(D)),
+                      ([shared_hidden, size], w(shared_hidden))]
+    else:
+        specs += [([held, D, H], w(D, espec)), ([held, H], zero(espec[:2])),
+                  ([held, H, size], w(H, espec)),
+                  ([held, size], zero(espec[:2]))]
     for i, (dims, attr) in enumerate(specs):
         pname = _make_param(name, i, dims, attr)
         cfg.inputs.append(LayerInput(input_layer_name=input.name,

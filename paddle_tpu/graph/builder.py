@@ -105,10 +105,16 @@ class GraphExecutor:
         return plan
 
     # -- parameters -------------------------------------------------------
-    def init_params(self, rng: jax.Array) -> dict[str, Array]:
+    def init_params(self, rng: jax.Array, dtype=None) -> dict[str, Array]:
+        """Every parameter from its config's initializer; with `dtype`,
+        floating parameters are cast to it one by one as they are made
+        (a server holding bf16 weights never holds the fp32 set)."""
         params: dict[str, Array] = {}
         for i, pc in enumerate(self.model.parameters):
-            params[pc.name] = init_parameter(pc, jax.random.fold_in(rng, i))
+            v = init_parameter(pc, jax.random.fold_in(rng, i))
+            if dtype is not None and jnp.issubdtype(v.dtype, jnp.floating):
+                v = v.astype(dtype)
+            params[pc.name] = v
         return params
 
     def init_state(self) -> dict[str, Any]:

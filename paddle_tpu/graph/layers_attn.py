@@ -381,3 +381,168 @@ def additive_attention_step_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argu
         out = additive_attention_step(dec.value, w, v.reshape(-1),
                                       proj.value, seq.value, mask)
     return finish_layer(ctx, cfg, out, like=dec)
+
+
+@register_layer("mla_attention")
+def mla_attention_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
+    """Multi-head latent attention (ops/mla.py) — causal self-attention
+    whose cache row is one latent `[c_kv, k_pe]` a token.
+
+    inputs (all the one data input): w_qa [d, q_rank], q_norm [1, q_rank],
+    w_qb [q_rank, H*(nope+rope)], w_kva [d, kv_rank+rope], kv_norm
+    [1, kv_rank], w_kvb [kv_rank, H*(nope+v)], w_o [H*v, d].
+    attrs: num_heads, q_lora_rank, kv_lora_rank, qk_nope_head_dim,
+    qk_rope_head_dim, v_head_dim, rope_theta, rope_scaling (YaRN dict or
+    absent), rms_eps, attn_impl.
+
+    Three paths, picked by the state the executor hands in (as
+    multi_head_attention): none = the whole sequence in the EXPANDED form;
+    a paged latent pool (`kv_pages`, with `row_slot` for the mixed step) or
+    a dense latent cache (`kv`) = the ABSORBED form over the cache."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.graph.layers_misc import rms_norm
+    from paddle_tpu.ops import mla
+
+    x_arg = ctx.get_input(cfg, 0)
+    w_qa, g_q, w_qb, w_kva, g_kv, w_kvb, w_o = (
+        ctx.param_of(cfg, i) for i in range(7))
+    a = cfg.attrs
+    H = int(a["num_heads"])
+    kv_rank = int(a["kv_lora_rank"])
+    nope, rdim = int(a["qk_nope_head_dim"]), int(a["qk_rope_head_dim"])
+    vdim = int(a["v_head_dim"])
+    eps = float(a.get("rms_eps", 1e-6))
+    scaling = a.get("rope_scaling") or None
+    inv_freq = mla.yarn_inv_freq(rdim, float(a.get("rope_theta", 10000.0)),
+                                 scaling)
+    amp = mla.rope_amplitude(scaling)
+    scale = mla.softmax_scale(nope + rdim, scaling)
+    assert bool(a.get("causal", True)), \
+        f"layer {cfg.name!r}: latent attention is causal self-attention"
+
+    x = x_arg.value                                        # [B, T, d]
+    B, T, _ = x.shape
+    cache = ctx.state_in.get(cfg.name)
+    paged = isinstance(cache, dict) and "kv_pages" in cache
+    dense = isinstance(cache, dict) and "kv" in cache
+    if paged and "row_slot" in cache:
+        pos = cache["row_pos"][None, :]                    # [1, T]
+    elif paged or dense:
+        pos = cache["pos"][:, None] + jnp.arange(T)[None, :]
+    else:
+        pos = jnp.arange(T)[None, :]
+
+    with jax.named_scope("mla.project"):
+        c_q = rms_norm(x @ w_qa, g_q, eps)
+        q = (c_q @ w_qb).reshape(B, T, H, nope + rdim)
+        q_nope = q[..., :nope]
+        q_pe = mla.rotate(q[..., nope:], pos[..., None], inv_freq, amp)
+        ckv = x @ w_kva
+        c_kv = rms_norm(ckv[..., :kv_rank], g_kv, eps)
+        k_pe = mla.rotate(ckv[..., kv_rank:], pos, inv_freq, amp)
+        rows = jnp.concatenate([c_kv, k_pe], axis=-1)      # [B, T, W]
+        if paged or dense:       # a cache stores the row at whole lane tiles
+            rows = mla.pad_lanes(
+                rows, cache["kv_pages" if paged else "kv"].shape[-1])
+
+    n_new = (x_arg.lengths.astype(jnp.int32) if x_arg.lengths is not None
+             else jnp.full((B,), T, jnp.int32))
+    whole = not paged and (not dense or (T > 1 and "cont" not in cache))
+    if whole:
+        # the expanded form: plain H-head attention at width nope + rope
+        # (v padded to that width so every impl, flash included, takes it)
+        with jax.named_scope("mla.project"):
+            kv = (c_kv @ w_kvb).reshape(B, T, H, nope + vdim)
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_pe[:, :, None, :], (B, T, H, rdim))], -1)
+            assert vdim <= nope + rdim, f"layer {cfg.name!r}: v_head_dim " \
+                f"{vdim} exceeds the qk head width {nope + rdim}"
+            v = mla.pad_lanes(kv[..., nope:], nope + rdim)
+        valid = x_arg.mask() if not dense else \
+            (jnp.arange(T)[None, :] < n_new[:, None])
+        attend = _mla_whole_attn(ctx, cfg, T)
+        with jax.named_scope("mla.attend"):
+            o = attend(jnp.concatenate([q_nope, q_pe], -1), k, v,
+                       q_valid=valid, k_valid=valid, causal=True,
+                       scale=scale)[..., :vdim]
+        if dense:
+            ctx.state_out[cfg.name] = {
+                "kv": cache["kv"].at[:, :T].set(
+                    rows.astype(cache["kv"].dtype)),
+                "pos": cache["pos"] + n_new}
+    else:
+        q_full = mla.pad_lanes(mla.absorb_query(q_nope, q_pe, w_kvb, nope),
+                               rows.shape[-1])             # [B, T, H, W]
+        with jax.named_scope("mla.attend"):
+            if paged:
+                ragged = "row_slot" in cache
+                assert (B == 1) if ragged else (T == 1), \
+                    f"layer {cfg.name!r}: a paged step feeds one token a " \
+                    f"slot, or one packed ragged row list (got {x.shape})"
+                R = B * T
+                row_slot = cache["row_slot"] if ragged \
+                    else jnp.arange(R, dtype=jnp.int32)
+                row_pos = pos.reshape(R)
+                o_lat, pool = mla.paged_latent_step(
+                    q_full.reshape(R, H, -1), rows.reshape(R, -1),
+                    cache["kv_pages"], cache["page_table"], row_slot,
+                    row_pos, scale, kv_rank,
+                    use_kernel=_mla_use_kernel(cfg))
+                o_lat = o_lat.reshape(B, T, H, kv_rank)
+                out_state = dict(cache, kv_pages=pool)
+                if not ragged:
+                    out_state["pos"] = cache["pos"] + 1
+                ctx.state_out[cfg.name] = out_state
+            else:
+                o_lat, new = mla.cached_latent_step(
+                    q_full, rows, cache["kv"], cache["pos"], n_new, scale,
+                    kv_rank)
+                ctx.state_out[cfg.name] = {"kv": new,
+                                           "pos": cache["pos"] + n_new}
+        with jax.named_scope("mla.project"):
+            o = mla.expand_value(o_lat, w_kvb, nope)       # [B, T, H, v]
+    with jax.named_scope("mla.project"):
+        out = o.reshape(B, T, H * vdim).astype(x.dtype) @ w_o
+    return finish_layer(ctx, cfg, out, like=x_arg)
+
+
+def _mla_use_kernel(cfg: LayerConfig) -> bool:
+    """The Pallas latent paged kernel unless the config pins the jnp path
+    (attn_impl dense/blockwise, as for multi_head_attention)."""
+    from paddle_tpu.ops import pallas_paged
+
+    return pallas_paged.supported() and \
+        str(cfg.attrs.get("attn_impl", "auto")) not in ("dense", "blockwise")
+
+
+def _mla_whole_attn(ctx: ForwardContext, cfg: LayerConfig, T: int):
+    """The whole-sequence attention impl for the expanded form: the
+    config's attn_impl (dense / blockwise / flash), or by length."""
+    import functools
+
+    from paddle_tpu.ops import pallas_attention
+
+    impl = str(cfg.attrs.get("attn_impl", "auto"))
+    if impl not in ("auto", "flash", "blockwise", "dense"):
+        raise ValueError(
+            f"layer {cfg.name!r}: attn_impl {impl!r} is not one of "
+            f"auto/flash/blockwise/dense (latent attention has no "
+            f"context-parallel path)")
+    if ctx.mesh is not None and ctx.mesh.devices.size > 1:
+        raise ValueError(
+            f"layer {cfg.name!r}: latent attention does not run on a mesh "
+            f"yet (tensor- and context-parallel paths: ROADMAP)")
+    if impl == "auto":
+        impl = "dense" if T < _BLOCKWISE_MIN_KEYS else \
+            ("flash" if pallas_attention.supported() else "blockwise")
+    if impl == "flash":
+        if not pallas_attention.supported():
+            raise ValueError(
+                f"layer {cfg.name!r}: attn_impl='flash' needs a TPU backend "
+                f"(or PADDLE_TPU_PALLAS_INTERPRET=1)")
+        return functools.partial(pallas_attention.flash_attention,
+                                 **_flash_blocks(cfg))
+    return blockwise_attention if impl == "blockwise" \
+        else dot_product_attention

@@ -237,3 +237,42 @@ def layer_norm_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
     if b is not None:
         normed = normed + promote_compute(b).reshape(-1)
     return finish_layer(ctx, cfg, normed.astype(x.value.dtype), like=x)
+
+
+def rms_norm(x, scale, eps: float):
+    """x / sqrt(mean(x^2) + eps) * scale over the last dim; statistics in
+    fp32 under mixed precision, the result in x's dtype."""
+    from paddle_tpu.utils.dtypes import promote_compute
+    v32 = promote_compute(x)
+    normed = v32 * jax.lax.rsqrt(
+        jnp.mean(v32 * v32, axis=-1, keepdims=True) + eps)
+    if scale is not None:
+        normed = normed * promote_compute(scale).reshape(-1)
+    return normed.astype(x.dtype)
+
+
+@register_layer("rms_norm")
+def rms_norm_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
+    """Root-mean-square normalization with a learned scale and no bias
+    (Zhang & Sennrich 2019) — the norm of the llama/deepseek-era blocks;
+    attrs['eps'] (default 1e-6)."""
+    x = ctx.get_input(cfg, 0)
+    out = rms_norm(x.value, ctx.param_of(cfg, 0),
+                   float(cfg.attrs.get("eps", 1e-6)))
+    return finish_layer(ctx, cfg, out, like=x)
+
+
+def gated_ffn(x, w_gate, w_up, w_down):
+    """SwiGLU: (silu(x W_gate) * (x W_up)) W_down, bias-free."""
+    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+
+
+@register_layer("gated_ffn")
+def gated_ffn_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
+    """The gated feed-forward block (Shazeer 2020, "GLU variants"): three
+    bias-free matrices gate [d, f], up [d, f], down [f, size], all carried
+    by the one data input."""
+    x = ctx.get_input(cfg, 0)
+    w_gate, w_up, w_down = (ctx.param_of(cfg, i) for i in range(3))
+    return finish_layer(ctx, cfg, gated_ffn(x.value, w_gate, w_up, w_down),
+                        like=x)

@@ -1,14 +1,26 @@
-"""Mixture-of-experts layer — expert-parallel FFN block.
+"""Mixture-of-experts layer — routed (and optionally shared) expert FFNs.
 
 NEW capability beyond the reference (see parallel/moe.py).  The layer's
-5 parameters ride the standard input-parameter mechanism: five LayerInputs
-all referencing the single data input carry router/w1/b1/w2/b2.  The aux
-load-balancing loss registers into ctx.costs like a cost layer, scaled by
-attrs['aux_weight'].
+parameters ride the standard input-parameter mechanism: LayerInputs all
+referencing the single data input.  Plain experts (the default): router,
+w1, b1, w2, b2.  Gated experts (attrs['gated']): router, w_gate, w_up,
+w_down, then the selection bias [1, E] if attrs['select_bias'], then the
+shared expert's gate, up, down if attrs['shared_hidden'] > 0.
+
+attrs['first_expert'] says which experts the stacked weights are: the layer
+routes over all attrs['num_experts'] and computes the held block's part
+(parallel/moe.py); the shared expert, which every chip computes alike, is
+added whole.  The aux load-balancing loss registers into ctx.costs like a
+cost layer, scaled by attrs['aux_weight'].
+
+A caller that hands in a state entry for the layer (the serving engine)
+gets back `pairs` [rows, E_held]: which held experts each row was routed
+to — the load counters' source.
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from paddle_tpu.config.schema import LayerConfig
@@ -22,10 +34,15 @@ from paddle_tpu.parameter.argument import Argument
 @register_layer("moe")
 def moe_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
     x = ctx.get_input(cfg, 0)
-    w_router, w1, b1, w2, b2 = (ctx.param_of(cfg, i) for i in range(5))
-    top_k = int(cfg.attrs.get("top_k", 2))
-    cap = float(cfg.attrs.get("capacity_factor", 1.25))
-    aux_w = float(cfg.attrs.get("aux_weight", 0.01))
+    a = cfg.attrs
+    params = [ctx.param_of(cfg, i) for i in range(len(cfg.inputs))]
+    w_router = params[0]
+    gated = bool(a.get("gated", False))
+    experts = tuple(params[1:4] if gated else params[1:5])
+    rest = params[1 + len(experts):]
+    select_bias = rest.pop(0).reshape(-1) if a.get("select_bias") else None
+    shared = tuple(rest[:3]) if int(a.get("shared_hidden", 0) or 0) else None
+    aux_w = float(a.get("aux_weight", 0.01))
 
     v = x.value
     seq_shape = None
@@ -36,8 +53,20 @@ def moe_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
         mask = x.mask()                  # padding never routed (cf. attention)
         if mask is not None:
             valid = mask.reshape(-1)
-    y, aux = moe_ffn(v, w_router, w1, b1, w2, b2, top_k=top_k,
-                     capacity_factor=cap, valid=valid)
+    y, aux, pairs = moe_ffn(
+        v, w_router, experts, top_k=int(a.get("top_k", 2)),
+        first_expert=int(a.get("first_expert", 0)), valid=valid,
+        scoring=str(a.get("scoring", "softmax")),
+        n_group=int(a.get("n_group", 1)),
+        topk_group=int(a.get("topk_group", 1)), select_bias=select_bias,
+        norm_topk=bool(a.get("norm_topk", True)),
+        scale=float(a.get("routed_scale", 1.0)))
+    if shared is not None:
+        from paddle_tpu.graph.layers_misc import gated_ffn
+        with jax.named_scope("moe.shared"):
+            y = y + gated_ffn(v, *shared)
+    if ctx.state_in.get(cfg.name) is not None:
+        ctx.state_out[cfg.name] = {"pairs": pairs}
     if seq_shape is not None:
         y = y.reshape(seq_shape + (y.shape[-1],))
     if aux_w > 0 and ctx.is_training:

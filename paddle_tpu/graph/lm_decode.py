@@ -271,17 +271,25 @@ def init_kv_caches(executor: GraphExecutor, batch: int, total: int) -> dict:
         else jnp.float32
     state: dict = {}
     for l in executor.model.layers:
-        if l.type != "multi_head_attention":
+        if l.type == "multi_head_attention":
+            heads = int(l.attrs["num_heads"])
+            h_kv = int(l.attrs.get("num_kv_heads", 0) or heads)
+            dh = int(l.size) // heads
+            state[l.name] = {
+                "k": jnp.zeros((batch, total, h_kv, dh), dtype),
+                "v": jnp.zeros((batch, total, h_kv, dh), dtype),
+            }
+        elif l.type == "mla_attention":
+            # one latent row [c_kv, k_pe] a token, at the paged pool's
+            # width so a prefill's rows pack into pages as they are
+            from paddle_tpu.ops.mla import lane_width
+            width = lane_width(int(l.attrs["kv_lora_rank"]) +
+                               int(l.attrs["qk_rope_head_dim"]))
+            state[l.name] = {"kv": jnp.zeros((batch, total, width), dtype)}
+        else:
             continue
-        heads = int(l.attrs["num_heads"])
-        h_kv = int(l.attrs.get("num_kv_heads", 0) or heads)
-        dh = int(l.size) // heads
-        state[l.name] = {
-            "k": jnp.zeros((batch, total, h_kv, dh), dtype),
-            "v": jnp.zeros((batch, total, h_kv, dh), dtype),
-            "pos": jnp.zeros((batch,), jnp.int32),
-        }
-    assert state, "model has no multi_head_attention layers to cache"
+        state[l.name]["pos"] = jnp.zeros((batch,), jnp.int32)
+    assert state, "model has no attention layers to cache"
     return state
 
 

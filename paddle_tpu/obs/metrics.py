@@ -67,6 +67,14 @@ CATALOG: dict[str, str] = {
     "serving_spill_bytes":
         "host-RAM bytes currently held by the spill tier",
     "serving_decode_steps_total": "compiled decode steps executed",
+    # -- mixture-of-experts load of the experts held here -----------------
+    "serving_moe_pairs_total":
+        "routed (token, expert) pairs the held experts drew, all MoE layers",
+    "serving_moe_pairs_max_total":
+        "per step, the busiest held expert's routed pairs (summed over the "
+        "MoE layers), summed over steps",
+    "serving_moe_steps_total":
+        "compiled steps whose routed pairs were counted",
     # -- cross-replica KV transfer (docs/serving.md "Disaggregated
     # prefill/decode") ----------------------------------------------------
     "serving_kv_xfer_pushes_total":
@@ -542,6 +550,32 @@ class MetricsRegistry:
 
 
 # -- collector adapters for the pre-existing stat systems -------------------
+
+class ProcessCounters:
+    """Cumulative counters of the PROCESS, not of one object: they outlive
+    the engine or trainer that bumps them, so a harness can read them after
+    the server has stopped (as it reads obs/compile_watch.py's).  Names are
+    CATALOG names; values only grow."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._values: dict[str, float] = {}
+
+    def add(self, name: str, n: float) -> None:
+        with self._lock:
+            self._values[name] = self._values.get(name, 0) + n
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return dict(self._values)
+
+
+_PROCESS_COUNTERS = ProcessCounters()
+
+
+def process_counters() -> ProcessCounters:
+    return _PROCESS_COUNTERS
+
 
 def statset_collector(statset, metric: str, count_metric: str,
                       label: str = "stat", qs=(50.0, 90.0, 99.0),

@@ -140,34 +140,47 @@ def block_tokens(page_size: int, h_kv: int, head_dim: int, itemsize: int,
     return pages * page_size
 
 
-def _kernel(H, h_kv, scale, table_ref, len_ref, row_ref, q_ref, k_hbm, v_hbm,
-            o_ref, kbuf, vbuf, sems, slot_ref):
+def _kernel(H, h_kv, scale, v_width, table_ref, len_ref, row_ref, q_ref,
+            *rest):
+    """One query row against its slot's live KV.  `v_width` None: K and V
+    pools of [P, ps, h_kv, Dp] (grouped-query heads).  `v_width` set: ONE
+    latent pool of [P, ps, W] whose rows are both — every query head scores
+    the whole row and weighs its first `v_width` columns (ops/mla.py), so a
+    block is fetched once and there are no groups to mask."""
+    if v_width is None:
+        k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, slot_ref = rest
+        pools = ((k_hbm, kbuf), (v_hbm, vbuf))
+    else:
+        k_hbm, o_ref, kbuf, sems, slot_ref = rest
+        vbuf = None
+        pools = ((k_hbm, kbuf),)
     r = pl.program_id(0)
     n_rows = pl.num_programs(0)
-    _, npb, ps, _, Dp = kbuf.shape
+    npb, ps = kbuf.shape[1:3]
+    Dp = kbuf.shape[-1]
     maxp = table_ref.shape[1]
     bt = npb * ps                       # tokens a block
     C = bt * h_kv                       # score columns: (token, kv head)
     Hp = q_ref.shape[1]
+    Dv = o_ref.shape[-1]
     rep = H // h_kv
 
     def start_fetch(row, blk, slot):
-        """Start the 2*npb page copies of block `blk` of row `row` into
-        buffer `slot`.  A page past the table's end re-reads its last
+        """Start the page copies of block `blk` of row `row` into buffer
+        `slot` (npb a pool).  A page past the table's end re-reads its last
         entry; a logical page past the row's length is mapped (or 0, the
         trash page) and masked below."""
         s = row_ref[row]
         for i in range(npb):
             page = table_ref[s, jnp.minimum(blk * npb + i, maxp - 1)]
-            pltpu.make_async_copy(
-                k_hbm.at[page], kbuf.at[slot, i], sems.at[0, slot]).start()
-            pltpu.make_async_copy(
-                v_hbm.at[page], vbuf.at[slot, i], sems.at[1, slot]).start()
+            for j, (hbm, buf) in enumerate(pools):
+                pltpu.make_async_copy(
+                    hbm.at[page], buf.at[slot, i], sems.at[j, slot]).start()
 
     def wait_fetch(slot):
         # one wait a buffer: a descriptor of the whole buffer's size takes
         # what its npb page copies signalled together
-        for j, buf in enumerate((kbuf, vbuf)):
+        for j, (_, buf) in enumerate(pools):
             pltpu.make_async_copy(
                 buf.at[slot], buf.at[slot], sems.at[j, slot]).wait()
 
@@ -190,9 +203,12 @@ def _kernel(H, h_kv, scale, table_ref, len_ref, row_ref, q_ref, k_hbm, v_hbm,
     # [bt*h_kv, Dp] operand as the pool stores it — no per-head gather —
     # and costs the MXU nothing it was not already paying to load K.
     col = jax.lax.broadcasted_iota(jnp.int32, (Hp, C), 1)
-    head = jax.lax.broadcasted_iota(jnp.int32, (Hp, C), 0)
-    own_group = (col % h_kv) == (head // rep)
     tok = col // h_kv
+    # one KV head under unpadded query heads: every column is every
+    # head's own, nothing to mask but the row's length
+    own_group = None if (h_kv == 1 and Hp == H) else \
+        (col % h_kv) == (jax.lax.broadcasted_iota(jnp.int32, (Hp, C), 0)
+                         // rep)
 
     def fold(b, carry):
         m_prev, l_prev, acc, slot = carry
@@ -206,11 +222,13 @@ def _kernel(H, h_kv, scale, table_ref, len_ref, row_ref, q_ref, k_hbm, v_hbm,
 
         wait_fetch(slot)
         k = kbuf[slot].reshape(C, Dp)
-        v = vbuf[slot].reshape(C, Dp)
+        v = k[:, :Dv] if vbuf is None else vbuf[slot].reshape(C, Dp)
         sc = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # [Hp, C]
-        valid = jnp.logical_and(own_group, tok < length - b * bt)
+        valid = tok < length - b * bt
+        if own_group is not None:
+            valid = jnp.logical_and(own_group, valid)
         sc = jnp.where(valid, sc, _NEG_INF)
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
         w = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
@@ -218,17 +236,53 @@ def _kernel(H, h_kv, scale, table_ref, len_ref, row_ref, q_ref, k_hbm, v_hbm,
         l_new = corr * l_prev + jnp.sum(w, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
             w.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [Hp, Dp]
+            preferred_element_type=jnp.float32)           # [Hp, Dv]
         return m_new, l_new, acc * corr + pv, 1 - slot
 
     _, l, acc, slot = jax.lax.fori_loop(
         0, nblk, fold,
         (jnp.full((Hp, 1), _NEG_INF, jnp.float32),
          jnp.zeros((Hp, 1), jnp.float32),
-         jnp.zeros((Hp, Dp), jnp.float32),
+         jnp.zeros((Hp, Dv), jnp.float32),
          slot_ref[0]))
     slot_ref[0] = slot
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def _head_rows(H: int, dtype) -> int:
+    """q's rows fill whole sublane tiles of its dtype (8 rows of 32 bits)."""
+    return _round_up(H, 8 * max(1, 4 // jnp.dtype(dtype).itemsize))
+
+
+def _call(name: str, kernel, qp: Array, pools: tuple, buf_shape: tuple,
+          out_width: int, page_table: Array, lengths: Array,
+          row_slot: Array) -> Array:
+    """The one pallas_call of the family: grid over query rows, the pools
+    in HBM, table / lengths / row->slot on the scalar-prefetch channel, a
+    double buffer of `buf_shape` a pool."""
+    R, Hp, Dq = qp.shape
+    index = lambda r, tbl, lens, rows: (r, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,               # page_table, lengths, row_slot
+        grid=(R,),
+        in_specs=[pl.BlockSpec((1, Hp, Dq), index)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),  # stay in HBM
+        out_specs=pl.BlockSpec((1, Hp, out_width), index),
+        scratch_shapes=[pltpu.VMEM((2,) + buf_shape, p.dtype) for p in pools]
+        + [pltpu.SemaphoreType.DMA((len(pools), 2)),      # (pool, buffer)
+           pltpu.SMEM((1,), jnp.int32)],          # buffer the next row reads
+    )
+    return pl.pallas_call(
+        kernel,
+        name=name,              # the device op's name in a profiler trace
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((R, Hp, out_width), qp.dtype),
+        # rows run in order: each prefetches its successor's first block
+        compiler_params=pallas_tpu_compiler_params(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(),
+    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
+      row_slot.astype(jnp.int32), qp, *pools)
 
 
 def paged_attention(
@@ -268,42 +322,51 @@ def paged_attention(
         row_slot = jnp.arange(R, dtype=jnp.int32)
 
     itemsize = jnp.dtype(k_pages.dtype).itemsize
-    # q's rows fill whole sublane tiles of its dtype (8 rows of 32 bits)
-    Hp = _round_up(H, 8 * max(1, 4 // jnp.dtype(q.dtype).itemsize))
+    Hp = _head_rows(H, q.dtype)
     Dp = _round_up(D, 128)
     npb = block_tokens(ps, h_kv, D, itemsize, maxp) // ps
     qp = jnp.pad(q, ((0, 0), (0, Hp - H), (0, Dp - D)))
     kp = jnp.pad(k_pages, ((0, 0), (0, 0), (0, 0), (0, Dp - D)))
     vp = jnp.pad(v_pages, ((0, 0), (0, 0), (0, 0), (0, Dp - D)))
-
-    kernel = functools.partial(_kernel, H, h_kv, scale)
-    row_block = pl.BlockSpec((1, Hp, Dp),
-                             lambda r, tbl, lens, rows: (r, 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,               # page_table, lengths, row_slot
-        grid=(R,),
-        in_specs=[
-            row_block,
-            pl.BlockSpec(memory_space=pl.ANY),    # the pools stay in HBM
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=row_block,
-        scratch_shapes=[
-            pltpu.VMEM((2, npb, ps, h_kv, Dp), k_pages.dtype),
-            pltpu.VMEM((2, npb, ps, h_kv, Dp), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),      # (K | V, buffer)
-            pltpu.SMEM((1,), jnp.int32),          # buffer the next row reads
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        name="paged_attn",      # the device op's name in a profiler trace
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, Hp, Dp), q.dtype),
-        # rows run in order: each prefetches its successor's first block
-        compiler_params=pallas_tpu_compiler_params(
-            dimension_semantics=("arbitrary",)),
-        interpret=_interpret(),
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      row_slot.astype(jnp.int32), qp, kp, vp)
+    out = _call("paged_attn", functools.partial(_kernel, H, h_kv, scale, None),
+                qp, (kp, vp), (npb, ps, h_kv, Dp), Dp, page_table, lengths,
+                row_slot)
     return out[:, :H, :D]
+
+
+def latent_paged_attention(
+    q: Array,               # [R, H, W] absorbed queries, one token per ROW
+    kv_pages: Array,        # [P, page_size, W] latent pool: a row is a
+                            # token's [c_kv, k_pe], key AND value
+    page_table: Array,      # [S, max_pages] int32 (0 = unmapped)
+    lengths: Array,         # [R] int32 valid tokens per row
+    scale: float,
+    row_slot: Optional[Array] = None,
+    v_width: Optional[int] = None,      # the value is the row's first
+                            # v_width columns (kv_lora_rank); default W
+) -> Array:
+    """Ragged paged attention over a LATENT pool (ops/mla.py's absorbed
+    form) -> weighted latents [R, H, v_width].  The same grid, block loop
+    bounded by each row's length, double-buffered page copies and online
+    softmax as `paged_attention`, adapted by shape: one pool instead of
+    two (a block is fetched once and serves as K and, its first v_width
+    columns, as V), all H query heads against the one latent row, no group
+    mask.  W need not be a multiple of 128: the row is the pool's full
+    last dimension, copied and multiplied as it is stored (padding it
+    would copy the pool every step).  Named `mla_paged_attn` in a trace."""
+    R, H, W = q.shape
+    P, ps, Wp = kv_pages.shape
+    assert W == Wp, f"query width {W} != latent row width {Wp}"
+    v_width = W if v_width is None else int(v_width)
+    maxp = page_table.shape[1]
+    if row_slot is None:
+        row_slot = jnp.arange(R, dtype=jnp.int32)
+    Hp = _head_rows(H, q.dtype)
+    npb = block_tokens(ps, 1, W, jnp.dtype(kv_pages.dtype).itemsize,
+                       maxp) // ps
+    qp = jnp.pad(q, ((0, 0), (0, Hp - H), (0, 0)))
+    out = _call("mla_paged_attn",
+                functools.partial(_kernel, H, 1, scale, v_width), qp,
+                (kv_pages,), (npb, ps, W), v_width, page_table, lengths,
+                row_slot)
+    return out[:, :H]

@@ -107,6 +107,7 @@ from paddle_tpu.graph.lm_decode import (_is_probs, _resolve_io_names,
                                         init_kv_caches, pick_next)
 from paddle_tpu.obs.compile_watch import get_compile_watch
 from paddle_tpu.obs.flight import get_flight_recorder
+from paddle_tpu.obs.metrics import process_counters
 from paddle_tpu.obs.trace import get_tracer
 from paddle_tpu.parallel.mesh import MODEL_AXIS, axis_size
 from paddle_tpu.parameter.argument import Argument
@@ -404,10 +405,20 @@ class ServingEngine:
         S = num_slots
         self._kk = self.kv.capacity_tokens     # keys per slot (> max_new)
         from paddle_tpu.ops.pallas_paged import block_tokens
-        pool = next(iter(self.kv.pools.values()))["k"]
+        pool = next(iter(next(iter(self.kv.pools.values())).values()))
+        # a latent pool's row is one [W] vector: one KV "head" of width W
+        h_kv = pool.shape[2] if pool.ndim == 4 else 1
         self._kv_block = block_tokens(
-            self.kv.page_size, pool.shape[2] // self.kv.tp_shards,
-            pool.shape[3], pool.dtype.itemsize, self.kv.pages_per_slot)
+            self.kv.page_size, h_kv // self.kv.tp_shards,
+            pool.shape[-1], pool.dtype.itemsize, self.kv.pages_per_slot)
+        # routed-pair counters of the held experts (docs/observability.md):
+        # the steps return, behind the tokens they already read back, the
+        # pairs each held expert drew, summed over the MoE layers
+        self._moe_layers = [l.name for l in executor.model.layers
+                            if l.type == "moe"]
+        self.moe_pairs_total = 0       # routed pairs the held experts drew
+        self.moe_pairs_max_sum = 0     # sum over steps of the busiest's
+        self.moe_steps = 0             # steps counted
         self._kv_synced = -1                   # kv.version last uploaded
         self._slots_dirty = True
         self._run_host: Optional[np.ndarray] = None
@@ -539,6 +550,11 @@ class ServingEngine:
         whole query heads and whole kv heads (the shard_map attention core
         and the pool's kv-head partition both depend on it)."""
         for l in model.layers:
+            if l.type == "mla_attention":
+                raise ValueError(
+                    f"layer {l.name!r}: latent attention has no "
+                    f"tensor-parallel path yet (--mesh model={self.tp}): "
+                    f"its cache row is shared by every head")
             if l.type != "multi_head_attention":
                 continue
             heads = int(l.attrs["num_heads"])
@@ -646,8 +662,7 @@ class ServingEngine:
         return sh
 
     def _state_shardings(self) -> "EngineState":
-        pool = {name: {"k": self._pool_sharding, "v": self._pool_sharding}
-                for name in self.kv.pools}
+        pool = self.kv.pool_shardings()
         r = self._repl_sharding
         return EngineState(pools=pool, table=r, pos=r, toks=r, gen=r,
                            keys=r, temp=r, topk=r, topp=r)
@@ -674,9 +689,7 @@ class ServingEngine:
         decode-step state expects."""
         if self.tp <= 1:
             return {}
-        return {"out_shardings": {
-            name: {"k": self._pool_sharding, "v": self._pool_sharding}
-            for name in self.kv.pools}}
+        return {"out_shardings": self.kv.pool_shardings()}
 
     # -- host mirror -> device pytree sync ---------------------------------
     def _stage(self, x):
@@ -794,7 +807,13 @@ class ServingEngine:
 
     def _evict_for(self, n_pages: int) -> int:
         """The allocator's page-pressure hook: PrefixTree.evict_for under
-        its own span (it walks the tree in Python once the pool is full)."""
+        its own span.  A call walks the whole tree in Python (3.6 ms at
+        16 k nodes) whatever it frees, and a full pool asks for ONE page at
+        nearly every page boundary of every slot — so a call frees a batch:
+        at least 1/256 of the pool (64 pages of 16,385; nothing extra in a
+        pool under 256 pages), the coldest leaves first as ever.  What is
+        given up is the prefix cache's 0.4% least-recently-used tail."""
+        n_pages = max(int(n_pages), self.kv.num_pages // 256)
         with self.tracer.span("pt.kv.evict", track="engine", pages=n_pages):
             return self.prefix.evict_for(n_pages)
 
@@ -1114,7 +1133,7 @@ class ServingEngine:
             self.occupancy_sum += len(live) / S
             self._count_kv(self._slot_lengths())
             with self._phase("readback"):
-                nxt = np.asarray(nxt)                      # host sync
+                nxt = self._count_moe(np.asarray(nxt), S)  # host sync
             self._note_step_metrics(len(runnable), decoded=True)
         with self._phase("emit", n=len(runnable)):
             for s in runnable:
@@ -1220,7 +1239,7 @@ class ServingEngine:
             base = self._slot_lengths()
             ran = np.zeros(S, np.int64)     # bodies each slot advanced in
             with self._phase("readback"):
-                blk = np.asarray(blk)                  # [k, S] host sync
+                blk = self._count_moe(np.asarray(blk), S)  # [k, S] sync
             self._note_step_metrics(len(runnable), decoded=True)
         # per-flush, never per-token: one boundary event each k tokens
         self.flight.record("scan_flush", k=k, slots=len(runnable))
@@ -1314,7 +1333,7 @@ class ServingEngine:
             self.occupancy_sum += len(live) / S
             self._count_kv(row_pos + 1)           # a padding row reads 1
             with self._phase("readback"):
-                nxt = np.asarray(nxt)                      # host sync
+                nxt = self._count_moe(np.asarray(nxt), S)  # host sync
             self._note_step_metrics(r, decoded=bool(runnable))
         with self._phase("emit", n=len(runnable)):
             for s in runnable:
@@ -2370,8 +2389,9 @@ class ServingEngine:
                        "prefix_cache": self.prefix is not None,
                        "spill_bytes_budget": kv.spill_bytes_budget,
                        "layer_specs": dict(kv.layer_specs)},
-            "pools": {name: {p: np.asarray(kv.pools[name][p]).copy()
-                             for p in ("k", "v")} for name in kv.pools},
+            "pools": {name: {p: np.asarray(a).copy()
+                             for p, a in pool.items()}
+                      for name, pool in kv.pools.items()},
             "kv": {"table": kv.table.copy(), "free": list(kv._free),
                    "n_pages": kv._n_pages.copy(), "ref": kv._ref.copy(),
                    "cached": kv._cached.copy(), "n_cow": kv.n_cow,
@@ -2381,8 +2401,9 @@ class ServingEngine:
                    # spilled runs' restore-on-hit stays bit-exact on the
                    # target) — generations re-stamp on restore
                    "host": {hid: {"nbytes": e["nbytes"],
-                                  "data": {name: (k.copy(), v.copy())
-                                           for name, (k, v)
+                                  "data": {name: {p: a.copy() for p, a
+                                                  in parts.items()}
+                                           for name, parts
                                            in e["data"].items()}}
                             for hid, e in kv._host.items()},
                    "next_hid": kv._next_hid,
@@ -2461,10 +2482,9 @@ class ServingEngine:
         for name in kv.pools:
             put = ((lambda a: jax.device_put(a, self._pool_sharding))
                    if self._pool_sharding is not None else jnp.asarray)
-            dtype = kv.pools[name]["k"].dtype
             kv.pools[name] = {
-                p: put(np.asarray(snap["pools"][name][p], dtype))
-                for p in ("k", "v")}
+                p: put(np.asarray(snap["pools"][name][p], a.dtype))
+                for p, a in kv.pools[name].items()}
         kv.table[:, :] = snap["kv"]["table"]
         kv._free = list(snap["kv"]["free"])
         kv._n_pages[:] = snap["kv"]["n_pages"]
@@ -2482,9 +2502,9 @@ class ServingEngine:
         kv._host_drained += len(kv._host)
         kv._host = {int(hid): {"gen": kv._host_gen,
                                "nbytes": int(e["nbytes"]),
-                               "data": {name: (np.asarray(k),
-                                               np.asarray(v))
-                                        for name, (k, v)
+                               "data": {name: {p: np.asarray(a) for p, a
+                                               in parts.items()}
+                                        for name, parts
                                         in e["data"].items()}}
                     for hid, e in snap["kv"].get("host", {}).items()}
         kv._host_bytes = sum(e["nbytes"] for e in kv._host.values())
@@ -2631,10 +2651,7 @@ class ServingEngine:
         batch-independent and their writes land in the trash page)."""
         S = st.toks.shape[0]
         table = st.table[:S]                  # drop the virtual trash row
-        state = {name: {"k_pages": st.pools[name]["k"],
-                        "v_pages": st.pools[name]["v"],
-                        "page_table": table, "pos": st.pos}
-                 for name in st.pools}
+        state = self._layer_state(st, page_table=table, pos=st.pos)
         feed = {self.input_name: Argument(ids=st.toks[:, None],
                                           lengths=jnp.ones((S,), jnp.int32))}
         outputs, _, state_out = self.executor.forward(params, feed, state,
@@ -2642,13 +2659,12 @@ class ServingEngine:
         last = outputs[self.logits_name].value[:, 0, :]
         nxt = pick_next_per_slot(last, self._slot_keys(st), st.temp,
                                  st.topk, st.topp, is_probs=self._probs)
-        new_pools = {name: {"k": state_out[name]["k_pages"],
-                            "v": state_out[name]["v_pages"]}
-                     for name in st.pools}
+        new_pools = self._pools_out(st, state_out)
+        nxt = self._with_moe_pairs(nxt, state_out, run)
         runi = run.astype(jnp.int32)
         new_st = EngineState(pools=new_pools, table=st.table,
                              pos=st.pos + runi,
-                             toks=jnp.where(run, nxt, st.toks),
+                             toks=jnp.where(run, nxt[:S], st.toks),
                              gen=st.gen + runi, keys=st.keys, temp=st.temp,
                              topk=st.topk, topp=st.topp)
         return new_st, nxt
@@ -2669,7 +2685,8 @@ class ServingEngine:
         def body(carry, _):
             st, run = carry
             new_st, nxt = self._decode_impl(params, st, run)
-            run = run & (nxt != eos) & (new_st.gen < maxnew)
+            S = run.shape[0]          # behind the tokens: the MoE pairs
+            run = run & (nxt[:S] != eos) & (new_st.gen < maxnew)
             return (new_st, run), nxt
         (new_st, _), toks = jax.lax.scan(body, (st, run), None, length=k)
         return new_st, toks
@@ -2705,11 +2722,8 @@ class ServingEngine:
         (mid-prefill, paused, empty) sample a padding/decode row's logits
         — computed and discarded, their state frozen by the masks."""
         T = row_ids.shape[0]
-        state = {name: {"k_pages": st.pools[name]["k"],
-                        "v_pages": st.pools[name]["v"],
-                        "page_table": st.table, "row_slot": row_slot,
-                        "row_pos": row_pos}
-                 for name in st.pools}
+        state = self._layer_state(st, page_table=st.table,
+                                  row_slot=row_slot, row_pos=row_pos)
         feed = {self.input_name: Argument(
             ids=row_ids[None, :], lengths=jnp.full((1,), T, jnp.int32))}
         outputs, _, state_out = self.executor.forward(params, feed, state,
@@ -2718,16 +2732,61 @@ class ServingEngine:
         last = logits[sample_row]                      # [S, V]
         nxt = pick_next_per_slot(last, self._slot_keys(st), st.temp,
                                  st.topk, st.topp, is_probs=self._probs)
-        new_pools = {name: {"k": state_out[name]["k_pages"],
-                            "v": state_out[name]["v_pages"]}
-                     for name in st.pools}
+        new_pools = self._pools_out(st, state_out)
+        S = st.toks.shape[0]
         new_st = EngineState(pools=new_pools, table=st.table,
                              pos=st.pos + adv,
                              toks=jnp.where(emit, nxt, st.toks),
                              gen=st.gen + emit.astype(jnp.int32),
                              keys=st.keys, temp=st.temp, topk=st.topk,
                              topp=st.topp)
-        return new_st, nxt
+        # a padding row aims at the virtual trash table row S
+        return new_st, self._with_moe_pairs(nxt, state_out, row_slot < S)
+
+    def _layer_state(self, st: EngineState, **shared) -> dict:
+        """The state dict a paged step hands the executor: each attention
+        layer's pool parts as `<part>_pages` (k_pages and v_pages; a latent
+        layer's kv_pages) beside the step's shared operands, and an empty
+        entry for each MoE layer — the request for its routed pairs."""
+        state = {name: dict({part + "_pages": a for part, a in pool.items()},
+                            **shared)
+                 for name, pool in st.pools.items()}
+        state.update({name: {} for name in self._moe_layers})
+        return state
+
+    @staticmethod
+    def _pools_out(st: EngineState, state_out: dict) -> dict:
+        return {name: {part: state_out[name][part + "_pages"]
+                       for part in pool}
+                for name, pool in st.pools.items()}
+
+    def _with_moe_pairs(self, nxt, state_out: dict, live_rows):
+        """`nxt` with the routed pairs of the held experts behind it:
+        [S + E_held] int32, the pairs summed over the MoE layers and the
+        live rows — one array, one read-back.  `nxt` itself without MoE
+        layers."""
+        if not self._moe_layers:
+            return nxt
+        live = live_rows.reshape(-1, 1)
+        pairs = sum(jnp.sum(jnp.logical_and(state_out[name]["pairs"], live),
+                            axis=0, dtype=jnp.int32)
+                    for name in self._moe_layers)
+        return jnp.concatenate([nxt.astype(jnp.int32), pairs])
+
+    def _count_moe(self, nxt: np.ndarray, n_rows: int) -> np.ndarray:
+        """Split a step's read-back into its tokens and the MoE pair
+        counts behind them; bank the counts.  Returns the tokens."""
+        if nxt.shape[-1] > n_rows:
+            pairs = nxt[..., n_rows:].reshape(-1, nxt.shape[-1] - n_rows)
+            total, busiest = int(pairs.sum()), int(pairs.max(axis=1).sum())
+            self.moe_pairs_total += total
+            self.moe_pairs_max_sum += busiest
+            self.moe_steps += pairs.shape[0]
+            pc = process_counters()
+            pc.add("serving_moe_pairs_total", total)
+            pc.add("serving_moe_pairs_max_total", busiest)
+            pc.add("serving_moe_steps_total", pairs.shape[0])
+        return nxt[..., :n_rows]
 
     def _spec_impl(self, params, st: EngineState, row_ids, row_slot,
                    row_pos, first_row, n_draft, draft_toks, spec, emit,
@@ -2757,11 +2816,8 @@ class ServingEngine:
         T = row_ids.shape[0]
         S = st.toks.shape[0]
         K = draft_toks.shape[1]
-        state = {name: {"k_pages": st.pools[name]["k"],
-                        "v_pages": st.pools[name]["v"],
-                        "page_table": st.table, "row_slot": row_slot,
-                        "row_pos": row_pos}
-                 for name in st.pools}
+        state = self._layer_state(st, page_table=st.table,
+                                  row_slot=row_slot, row_pos=row_pos)
         feed = {self.input_name: Argument(
             ids=row_ids[None, :], lengths=jnp.full((1,), T, jnp.int32))}
         outputs, _, state_out = self.executor.forward(params, feed, state,
@@ -2784,9 +2840,7 @@ class ServingEngine:
         last = sampled[jnp.arange(S), acc]
         toks_new = jnp.where(spec, last,
                              jnp.where(emit, sampled[:, 0], st.toks))
-        new_pools = {name: {"k": state_out[name]["k_pages"],
-                            "v": state_out[name]["v_pages"]}
-                     for name in st.pools}
+        new_pools = self._pools_out(st, state_out)
         new_st = EngineState(pools=new_pools, table=st.table,
                              pos=st.pos + committed, toks=toks_new,
                              gen=st.gen + gen_adv, keys=st.keys,
@@ -2801,6 +2855,8 @@ class ServingEngine:
             executor = self.executor
             input_name, logits_name = self.input_name, self.logits_name
             attn_layers = list(self.kv.pools)
+            parts = {name: tuple(pool)
+                     for name, pool in self.kv.pools.items()}
 
             def prefill(params, ids, n):               # ids [1, Lb], n [1]
                 state = init_kv_caches(executor, 1, Lb)
@@ -2810,7 +2866,8 @@ class ServingEngine:
                 logits = outputs[logits_name].value
                 last = jnp.take_along_axis(
                     logits, (n - 1)[:, None, None], axis=1)[:, 0, :]
-                return last, {name: (state[name]["k"], state[name]["v"])
+                return last, {name: {part: state[name][part]
+                                     for part in parts[name]}
                               for name in attn_layers}
 
             fn = self._prefill_cache[Lb] = get_compile_watch().wrap_jit(
@@ -2828,18 +2885,12 @@ class ServingEngine:
             specs = self.kv.layer_specs
 
             def pack(pools, kv_prompt, pages):
-                out = {}
-                for name, (h_kv, dh) in specs.items():
-                    k, v = kv_prompt[name]
-                    out[name] = {
-                        "k": pools[name]["k"].at[pages].set(
-                            k[0, :Lb].reshape(n_pages, ps, h_kv, dh)
-                            .astype(pools[name]["k"].dtype)),
-                        "v": pools[name]["v"].at[pages].set(
-                            v[0, :Lb].reshape(n_pages, ps, h_kv, dh)
-                            .astype(pools[name]["v"].dtype)),
-                    }
-                return out
+                return {name: {
+                    part: a.at[pages].set(
+                        kv_prompt[name][part][0, :Lb]
+                        .reshape((n_pages, ps) + row).astype(a.dtype))
+                    for part, a in pools[name].items()}
+                    for name, row in specs.items()}
 
             fn = self._pack_cache[Lb] = get_compile_watch().wrap_jit(
                 "serving.pack", jax.jit(pack, donate_argnums=(0,),
@@ -2875,18 +2926,13 @@ class ServingEngine:
                 # scatter overwrites [c, c+Lb) before attention and its
                 # causal mask never reaches the rest
                 state = {}
-                for name, (h_kv, dh) in specs.items():
-                    seed_k = pools[name]["k"][ctx_pages] \
-                        .reshape(1, Cpad, h_kv, dh)
-                    seed_v = pools[name]["v"][ctx_pages] \
-                        .reshape(1, Cpad, h_kv, dh)
+                for name, row in specs.items():
                     state[name] = {
-                        "k": jnp.zeros((1, Cpad + Lb, h_kv, dh), dtype)
-                        .at[:, :Cpad].set(seed_k),
-                        "v": jnp.zeros((1, Cpad + Lb, h_kv, dh), dtype)
-                        .at[:, :Cpad].set(seed_v),
-                        "pos": c, "cont": (),
-                    }
+                        part: jnp.zeros((1, Cpad + Lb) + row, dtype)
+                        .at[:, :Cpad].set(
+                            a[ctx_pages].reshape((1, Cpad) + row))
+                        for part, a in pools[name].items()}
+                    state[name].update(pos=c, cont=())
                 outputs, _, state = executor.forward(
                     params, {input_name: Argument(ids=ids, lengths=n)},
                     state, TEST, None)
@@ -2894,10 +2940,9 @@ class ServingEngine:
                 last = jnp.take_along_axis(
                     logits, (n - 1)[:, None, None], axis=1)[:, 0, :]
                 return last, {
-                    name: tuple(
-                        jax.lax.dynamic_slice_in_dim(state[name][part],
-                                                     c[0], Lb, axis=1)
-                        for part in ("k", "v"))
+                    name: {part: jax.lax.dynamic_slice_in_dim(
+                        state[name][part], c[0], Lb, axis=1)
+                        for part in pools[name]}
                     for name in specs}
 
             fn = self._prefix_prefill_cache[key] = \
@@ -2920,16 +2965,11 @@ class ServingEngine:
                 idx = off + jnp.arange(Lb)
                 phys = pages[idx // ps]                       # [Lb]
                 row = idx % ps
-                out = {}
-                for name in specs:
-                    k, v = kv_suffix[name]
-                    out[name] = {
-                        "k": pools[name]["k"].at[phys, row].set(
-                            k[0].astype(pools[name]["k"].dtype)),
-                        "v": pools[name]["v"].at[phys, row].set(
-                            v[0].astype(pools[name]["v"].dtype)),
-                    }
-                return out
+                return {name: {
+                    part: a.at[phys, row].set(
+                        kv_suffix[name][part][0].astype(a.dtype))
+                    for part, a in pools[name].items()}
+                    for name in specs}
 
             fn = self._prefix_pack_cache[Lb] = get_compile_watch().wrap_jit(
                 "serving.prefix_pack",

@@ -1,5 +1,10 @@
 """Paged KV cache: a fixed pool of [num_pages, page_size, h_kv, dh] pages
-per attention layer plus per-slot page tables.
+per attention layer plus per-slot page tables.  A latent-attention layer
+(mla_attention) holds ONE tensor of [num_pages, page_size, W] — W =
+kv_lora_rank + qk_rope_head_dim rounded up to 128 lanes (576 -> 640, the
+width HBM tiling gives the row anyway) — instead of a K and a V pool: its cache row is the latent
+every head reads (ops/mla.py); allocation, sharing, COW, spill and transfer
+below walk each layer's own parts, so they are the same code for both.
 
 Replaces the dense `lm_decode.init_kv_caches` layout for SERVING: a dense
 cache sizes every row at P+max_new whatever the row actually holds, and its
@@ -111,26 +116,44 @@ class PagedKVCache:
 
         dtype = jnp.dtype(executor.compute_dtype) if executor.compute_dtype \
             else jnp.float32
-        self.layer_specs: dict[str, tuple[int, int]] = {}
+        # layer_specs[name] = the row shape every part of that layer's pool
+        # shares: (h_kv, dh) for multi_head_attention (parts "k" and "v"),
+        # (kv_lora_rank + qk_rope_head_dim,) for mla_attention (ONE part
+        # "kv": the latent row [c_kv, k_pe] — V is its first kv_lora_rank
+        # columns, so there is no second tensor).  Everything below walks
+        # `self.pools[name]`'s own parts and shapes, never a fixed pair.
+        self.layer_specs: dict[str, tuple] = {}
         self.pools: dict[str, dict[str, jnp.ndarray]] = {}
         for l in executor.model.layers:
-            if l.type != "multi_head_attention":
+            if l.type == "multi_head_attention":
+                heads = int(l.attrs["num_heads"])
+                h_kv = int(l.attrs.get("num_kv_heads", 0) or heads)
+                row, parts = (h_kv, int(l.size) // heads), ("k", "v")
+            elif l.type == "mla_attention":
+                if self.tp_shards > 1:
+                    raise ValueError(
+                        f"layer {l.name!r}: a latent cache row is shared by "
+                        f"every head and cannot shard on a kv-head axis — "
+                        f"latent attention under --mesh model=N is not "
+                        f"supported yet")
+                from paddle_tpu.ops.mla import lane_width
+                row = (lane_width(int(l.attrs["kv_lora_rank"]) +
+                                  int(l.attrs["qk_rope_head_dim"])),)
+                parts = ("kv",)
+            else:
                 continue
-            heads = int(l.attrs["num_heads"])
-            h_kv = int(l.attrs.get("num_kv_heads", 0) or heads)
-            dh = int(l.size) // heads
-            self.layer_specs[l.name] = (h_kv, dh)
-            shape = (self.num_pages, page_size, h_kv, dh)
+            self.layer_specs[l.name] = row
+            shape = (self.num_pages, page_size) + row
 
             def _pool():
-                # distinct buffers per part — k and v are donated side by
+                # distinct buffers per part — parts are donated side by
                 # side, and XLA refuses to donate one buffer twice
                 z = jnp.zeros(shape, dtype)
                 return jax.device_put(z, self.pool_sharding) \
                     if self.pool_sharding is not None else z
 
-            self.pools[l.name] = {"k": _pool(), "v": _pool()}
-        assert self.pools, "model has no multi_head_attention layers to page"
+            self.pools[l.name] = {part: _pool() for part in parts}
+        assert self.pools, "model has no attention layers to page"
 
         # host allocator state: table[s, j] = physical page backing logical
         # page j of slot s (0 = unmapped -> trash)
@@ -152,7 +175,7 @@ class PagedKVCache:
         self.n_cow = 0                 # copy-on-write page copies performed
         self._copy_fn = None           # lazily-jitted device page copy
         # -- host spill tier (module docstring "HOST SPILL TIER") ----------
-        # hid -> {"gen", "nbytes", "data": {layer: (k_np, v_np)}}; the
+        # hid -> {"gen", "nbytes", "data": {layer: {part: ndarray}}}; the
         # prefix index owns the POLICY (who spills, who drops) — this is
         # the mechanism + the byte accounting
         self.spill_bytes_budget = int(spill_bytes_budget or 0)
@@ -213,8 +236,8 @@ class PagedKVCache:
     @property
     def pool_bytes(self) -> int:
         """Total device bytes of the K/V page pools (all shards)."""
-        return sum(int(p[part].size) * p[part].dtype.itemsize
-                   for p in self.pools.values() for part in ("k", "v"))
+        return sum(int(a.size) * a.dtype.itemsize
+                   for p in self.pools.values() for a in p.values())
 
     @property
     def pool_bytes_per_shard(self) -> int:
@@ -419,10 +442,9 @@ class PagedKVCache:
     # -- host spill tier ---------------------------------------------------
     @property
     def page_nbytes(self) -> int:
-        """Host bytes one spilled page costs: k + v across every layer."""
-        itemsize = next(iter(self.pools.values()))["k"].dtype.itemsize
-        return sum(2 * self.page_size * h_kv * dh * itemsize
-                   for (h_kv, dh) in self.layer_specs.values())
+        """Host bytes one spilled page costs: every part of every layer."""
+        return sum(int(np.prod(a.shape[1:])) * a.dtype.itemsize
+                   for p in self.pools.values() for a in p.values())
 
     @property
     def host_page_count(self) -> int:
@@ -454,9 +476,8 @@ class PagedKVCache:
         nbytes = self.page_nbytes
         if self._host_bytes + nbytes > self.spill_bytes_budget:
             return None
-        data = {name: (np.asarray(self.pools[name]["k"][page]),
-                       np.asarray(self.pools[name]["v"][page]))
-                for name in self.pools}
+        data = {name: {part: np.asarray(a[page]) for part, a in pool.items()}
+                for name, pool in self.pools.items()}
         hid = self._next_hid
         self._next_hid += 1
         self._host[hid] = {"gen": self._host_gen, "nbytes": nbytes,
@@ -540,42 +561,35 @@ class PagedKVCache:
             bucket *= 2
         idx = np.zeros(bucket, np.int32)            # pad -> trash page 0
         idx[:n] = pages
-        ks: dict = {}
-        vs: dict = {}
-        for name in self.pools:
-            h_kv, dh = self.layer_specs[name]
-            dtype = np.dtype(self.pools[name]["k"].dtype)
-            k = np.zeros((bucket, self.page_size, h_kv, dh), dtype)
-            v = np.zeros_like(k)
-            for i, hid in enumerate(hids):
-                e = self._host[int(hid)]
-                k[i], v[i] = e["data"][name]
-            ks[name], vs[name] = k, v
+        rows: dict = {}
+        for name, pool in self.pools.items():
+            rows[name] = {}
+            for part, a in pool.items():
+                buf = np.zeros((bucket,) + a.shape[1:], np.dtype(a.dtype))
+                for i, hid in enumerate(hids):
+                    buf[i] = self._host[int(hid)]["data"][name][part]
+                rows[name][part] = buf
         self.pools = self._restore_fn(bucket)(
-            self.pools, jnp.asarray(idx), ks, vs)
+            self.pools, jnp.asarray(idx), rows)
         for hid in hids:
             self.drop_host_page(hid, reason="restore")
         self.n_restored += n
 
     def _restore_fn(self, bucket: int):
         if bucket not in self._restore_fns:
-            def scatter(pools, pages, ks, vs):
+            def scatter(pools, pages, rows):
                 # duplicate pad indices all write zeros to the trash
                 # page, so the scatter's write order is immaterial
-                return {name: {
-                    "k": pools[name]["k"].at[pages].set(ks[name]),
-                    "v": pools[name]["v"].at[pages].set(vs[name]),
-                } for name in pools}
+                return {name: {part: a.at[pages].set(rows[name][part])
+                               for part, a in pool.items()}
+                        for name, pool in pools.items()}
 
             from paddle_tpu.obs.compile_watch import get_compile_watch
             kw = {}
             if self.pool_sharding is not None:
                 # same canonical-pool-sharding pin as the COW copy — a
                 # drifted layout would reshard every pool next step
-                kw["out_shardings"] = {
-                    name: {"k": self.pool_sharding,
-                           "v": self.pool_sharding}
-                    for name in self.pools}
+                kw["out_shardings"] = self.pool_shardings()
             self._restore_fns[bucket] = get_compile_watch().wrap_jit(
                 "serving.spill_restore",
                 jax.jit(scatter, donate_argnums=(0,), **kw))
@@ -587,8 +601,9 @@ class PagedKVCache:
         transfer plane's sender half (docs/serving.md "Disaggregated
         prefill/decode").  One batched device->host gather per layer part
         in the spill tier's per-layer ndarray layout: the payload is the
-        concatenation, over layers in SORTED name order, of the k block
-        then the v block, each `[n, page_size, h_kv, dh]` row-major.
+        concatenation, over layers in SORTED name order, of each of the
+        layer's parts in turn (the k block then the v block; a latent
+        layer's one kv block), each `[n, page_size, *row]` row-major.
         Returns `(meta, payload)` where meta names the shapes/dtypes the
         importer must match exactly.  Pages must be live (slot-mapped or
         prefix-cached) — exporting a free page would ship garbage."""
@@ -603,13 +618,12 @@ class PagedKVCache:
         parts = []
         layers = []
         for name in names:
-            h_kv, dh = self.layer_specs[name]
-            k = np.ascontiguousarray(np.asarray(self.pools[name]["k"][idx]))
-            v = np.ascontiguousarray(np.asarray(self.pools[name]["v"][idx]))
-            parts.append(k.tobytes())
-            parts.append(v.tobytes())
-            layers.append({"name": name, "h_kv": h_kv, "dh": dh,
-                           "dtype": str(k.dtype)})
+            pool = self.pools[name]
+            for part in pool:
+                parts.append(np.ascontiguousarray(
+                    np.asarray(pool[part][idx])).tobytes())
+            layers.append({"name": name, **self._row_meta(name),
+                           "dtype": str(next(iter(pool.values())).dtype)})
         meta = {"n_pages": len(pages), "page_size": self.page_size,
                 "layers": layers}
         self.n_exported += len(pages)
@@ -640,15 +654,17 @@ class PagedKVCache:
                 f"!= pool layers {sorted(self.pools)}")
         total = 0
         for l in layers:
-            h_kv, dh = self.layer_specs[l["name"]]
-            dtype = np.dtype(self.pools[l["name"]]["k"].dtype)
-            if int(l.get("h_kv", -1)) != h_kv or int(l.get("dh", -1)) != dh \
-                    or str(l.get("dtype")) != str(dtype):
+            pool = self.pools[l["name"]]
+            row = self.layer_specs[l["name"]]
+            dtype = np.dtype(next(iter(pool.values())).dtype)
+            want = dict(self._row_meta(l["name"]), dtype=str(dtype))
+            got = {k: l.get(k) for k in want}
+            if got != want:
                 raise ValueError(
-                    f"kv import: layer {l['name']!r} shape/dtype "
-                    f"({l.get('h_kv')},{l.get('dh')},{l.get('dtype')}) != "
-                    f"pool ({h_kv},{dh},{dtype})")
-            total += 2 * n * self.page_size * h_kv * dh * dtype.itemsize
+                    f"kv import: layer {l['name']!r} shape/dtype {got} != "
+                    f"pool {want}")
+            total += len(pool) * n * self.page_size * int(np.prod(row)) \
+                * dtype.itemsize
         if len(payload) != total:
             raise ValueError(
                 f"kv import: payload is {len(payload)} bytes, "
@@ -662,36 +678,44 @@ class PagedKVCache:
             bucket *= 2
         idx = np.zeros(bucket, np.int32)            # pad -> trash page 0
         idx[:n] = [int(p) for p in pages]
-        ks: dict = {}
-        vs: dict = {}
+        rows: dict = {}
         off = 0
         for l in layers:
             name = l["name"]
-            h_kv, dh = self.layer_specs[name]
-            dtype = np.dtype(self.pools[name]["k"].dtype)
-            nb = n * self.page_size * h_kv * dh * dtype.itemsize
-            shape = (n, self.page_size, h_kv, dh)
-            k = np.zeros((bucket,) + shape[1:], dtype)
-            v = np.zeros_like(k)
-            k[:n] = np.frombuffer(payload, dtype, count=nb // dtype.itemsize,
-                                  offset=off).reshape(shape)
-            off += nb
-            v[:n] = np.frombuffer(payload, dtype, count=nb // dtype.itemsize,
-                                  offset=off).reshape(shape)
-            off += nb
-            ks[name], vs[name] = k, v
+            rows[name] = {}
+            for part, a in self.pools[name].items():
+                dtype = np.dtype(a.dtype)
+                shape = (n,) + tuple(a.shape[1:])
+                count = int(np.prod(shape))
+                buf = np.zeros((bucket,) + shape[1:], dtype)
+                buf[:n] = np.frombuffer(payload, dtype, count=count,
+                                        offset=off).reshape(shape)
+                off += count * dtype.itemsize
+                rows[name][part] = buf
         self.pools = self._restore_fn(bucket)(
-            self.pools, jnp.asarray(idx), ks, vs)
+            self.pools, jnp.asarray(idx), rows)
         self.n_imported += n
+
+    def _row_meta(self, name: str) -> dict:
+        """How a transfer blob names one layer's rows: `h_kv`, `dh` for
+        per-head K and V; `parts`, `row` for a latent layer's one tensor."""
+        row = self.layer_specs[name]
+        if list(self.pools[name]) == ["k", "v"]:
+            return {"h_kv": int(row[0]), "dh": int(row[1])}
+        return {"parts": list(self.pools[name]), "row": [int(r) for r in row]}
+
+    def pool_shardings(self) -> dict:
+        """The canonical pool sharding laid over the pools' own tree."""
+        return {name: {part: self.pool_sharding for part in pool}
+                for name, pool in self.pools.items()}
 
     # -- device page copy (COW) -------------------------------------------
     def _page_copy(self):
         if self._copy_fn is None:
             def copy(pools, dst, src):
-                return {name: {
-                    "k": pools[name]["k"].at[dst].set(pools[name]["k"][src]),
-                    "v": pools[name]["v"].at[dst].set(pools[name]["v"][src]),
-                } for name in pools}
+                return {name: {part: a.at[dst].set(a[src])
+                               for part, a in pool.items()}
+                        for name, pool in pools.items()}
 
             from paddle_tpu.obs.compile_watch import get_compile_watch
             kw = {}
@@ -699,10 +723,7 @@ class PagedKVCache:
                 # sharded pools must come back in the canonical pool
                 # sharding — a drifted layout would force the next decode
                 # step's explicit in_shardings to reshard every pool
-                kw["out_shardings"] = {
-                    name: {"k": self.pool_sharding,
-                           "v": self.pool_sharding}
-                    for name in self.pools}
+                kw["out_shardings"] = self.pool_shardings()
             self._copy_fn = get_compile_watch().wrap_jit(
                 "serving.cow_copy", jax.jit(copy, donate_argnums=(0,), **kw))
         return self._copy_fn

@@ -337,6 +337,13 @@ class ServingServer:
                  float(eng.n_prefill_chunks)),
                 ("serving_mixed_steps_total", "counter", None,
                  float(eng.n_mixed_steps)),
+                # mixture-of-experts: the load of the experts held here
+                ("serving_moe_pairs_total", "counter", None,
+                 float(eng.moe_pairs_total)),
+                ("serving_moe_pairs_max_total", "counter", None,
+                 float(eng.moe_pairs_max_sum)),
+                ("serving_moe_steps_total", "counter", None,
+                 float(eng.moe_steps)),
                 # multi-step decode: scan body iterations vs boundary
                 # flushes — steps/flushes ≈ decode_steps in steady state
                 ("serving_scan_steps_total", "counter", None,
@@ -1448,6 +1455,10 @@ class ServingServer:
             # tokens it fetched in whole blocks (their ratio = block fill)
             "kv_tokens_attended": eng.kv_tokens_attended,
             "kv_tokens_fetched": eng.kv_tokens_fetched,
+            # routed pairs the held experts drew (0 without MoE layers)
+            "moe_pairs_total": eng.moe_pairs_total,
+            "moe_pairs_max_sum": eng.moe_pairs_max_sum,
+            "moe_steps": eng.moe_steps,
             # speculative decoding: the A/B-able knobs + the counters the
             # accept rate reconciles from, plus the adaptive state
             # (drafter kind, dynamic-k flag, per-slot learned EWMAs)

@@ -1,56 +1,155 @@
-"""Mixture-of-experts tests: routing invariants, single-expert oracle,
+"""Mixture-of-experts tests: routing invariants (plain softmax top-k and
+the sigmoid group-limited family), the dropless expert block against a
+literal loop, the held-share decomposition, single-expert oracle,
 mesh-sharded equivalence (expert parallelism), DSL layer training."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-from paddle_tpu.parallel.moe import moe_ffn, moe_routing
+from paddle_tpu.parallel.moe import combine_weights, moe_ffn, moe_route
+
+
+def _loop_route(scores, bias, k, n_group, topk_group, scale):
+    """DeepSeek-V3's selection, spelled out one token at a time."""
+    T, E = scores.shape
+    per = E // n_group
+    ids, ws = [], []
+    for t in range(T):
+        choice = scores[t] + bias
+        gscore = [np.sort(choice[g * per:(g + 1) * per])[-2:].sum()
+                  for g in range(n_group)]
+        kept = np.argsort(gscore)[-topk_group:]
+        masked = np.full(E, -np.inf)
+        for g in kept:
+            masked[g * per:(g + 1) * per] = choice[g * per:(g + 1) * per]
+        pick = np.argsort(masked)[-k:]
+        w = scores[t, pick]                # weights: from the scores alone
+        ids.append(sorted(pick))
+        ws.append({int(e): float(x / w.sum() * scale)
+                   for e, x in zip(pick, w)})
+    return ids, ws
 
 
 class TestRouting:
-    def test_dispatch_capacity_respected(self):
+    def test_dropless_under_a_skew_a_capacity_would_drop(self):
+        """Every token prefers expert 0 (32 tokens, 4 experts, top-2: a
+        capacity of 1.25 * 2 * 32 / 4 = 20 slots would drop 12 of expert
+        0's pairs).  Here every token keeps both its pairs at full weight
+        and the block equals the literal sum over its picks."""
         rng = np.random.default_rng(0)
-        logits = jnp.asarray(rng.normal(size=(32, 4)), jnp.float32)
-        dispatch, combine, aux = moe_routing(logits, top_k=2, capacity=3)
-        # each expert's buffer slot holds at most one token
-        per_slot = jnp.sum(dispatch, axis=0)          # [E, C]
-        assert float(per_slot.max()) <= 1.0 + 1e-6
-        # each token occupies at most top_k slots
-        per_tok = jnp.sum(dispatch, axis=(1, 2))
-        assert float(per_tok.max()) <= 2.0 + 1e-6
+        B, E, D, H = 32, 4, 8, 16
+        logits = jnp.asarray(rng.normal(size=(B, E)) * 0.1, jnp.float32)
+        logits = logits.at[:, 0].add(5.0)
+        idx, w, _ = moe_route(logits, top_k=2)
+        assert bool((idx[:, 0] == 0).all())
+        comb = combine_weights(idx, w, 0, E)
+        assert int((comb[:, 0] > 0).sum()) == B          # nothing dropped
+        np.testing.assert_allclose(comb.sum(-1), np.ones(B), rtol=1e-5)
+        # the block itself, against a loop over each token's picks
+        x = jnp.asarray(rng.normal(size=(B, D)), jnp.float32)
+        w_r = jnp.zeros((D, E), jnp.float32).at[0, 0].set(1.0)
+        x = x.at[:, 0].set(jnp.abs(x[:, 0]) + 3.0)   # x W_r favors expert 0
+        w1 = jnp.asarray(rng.normal(size=(E, D, H)) * 0.3, jnp.float32)
+        w2 = jnp.asarray(rng.normal(size=(E, H, D)) * 0.3, jnp.float32)
+        b1, b2 = jnp.zeros((E, H)), jnp.zeros((E, D))
+        y, _, pairs = moe_ffn(x, w_r, (w1, b1, w2, b2), top_k=2)
+        idx, w, _ = moe_route(x @ w_r, top_k=2)
+        want = np.zeros((B, D), np.float32)
+        for b in range(B):
+            for e, g in zip(np.asarray(idx[b]), np.asarray(w[b])):
+                want[b] += g * np.asarray(
+                    jax.nn.relu(x[b] @ w1[e]) @ w2[e])
+        np.testing.assert_allclose(y, want, rtol=2e-5, atol=1e-5)
+        assert int(pairs.sum()) == 2 * B and bool(pairs[:, 0].all())
 
     def test_combine_weights_normalized(self):
         rng = np.random.default_rng(1)
         logits = jnp.asarray(rng.normal(size=(8, 4)), jnp.float32)
-        # big capacity: nothing dropped -> combine sums to 1 per token
-        _, combine, _ = moe_routing(logits, top_k=2, capacity=16)
-        sums = jnp.sum(combine, axis=(1, 2))
+        idx, w, _ = moe_route(logits, top_k=2)
+        sums = jnp.sum(combine_weights(idx, w, 0, 4), axis=1)
         np.testing.assert_allclose(sums, np.ones(8), rtol=1e-5)
 
     def test_aux_loss_uniform_is_one(self):
         # uniform routing -> aux loss == 1 (its minimum for balanced load)
         logits = jnp.zeros((16, 4), jnp.float32)
-        _, _, aux = moe_routing(logits, top_k=1, capacity=16)
+        _, _, aux = moe_route(logits, top_k=1)
         np.testing.assert_allclose(float(aux), 1.0, rtol=1e-5)
+
+    def test_padding_tokens_are_never_routed(self):
+        logits = jnp.asarray(np.random.default_rng(2).normal(size=(6, 4)),
+                             jnp.float32)
+        valid = jnp.asarray([1, 1, 0, 1, 0, 1], bool)
+        idx, w, _ = moe_route(logits, top_k=2, valid=valid)
+        assert float(jnp.abs(w[~valid]).max()) == 0.0
+        assert float(w[valid].sum()) == pytest.approx(4.0, rel=1e-5)
+
+    def test_group_limited_sigmoid_routing_matches_a_literal_loop(self):
+        """Groups, the bias used for SELECTION only, renormalization over
+        the picks, the scaling factor — against the loop above."""
+        rng = np.random.default_rng(3)
+        T, E, k, G, kg, scale = 24, 32, 4, 8, 3, 2.5
+        logits = jnp.asarray(rng.normal(size=(T, E)), jnp.float32)
+        bias = jnp.asarray(rng.normal(size=(E,)) * 0.3, jnp.float32)
+        idx, w, _ = moe_route(logits, k, scoring="sigmoid", n_group=G,
+                              topk_group=kg, select_bias=bias, scale=scale)
+        scores = np.asarray(jax.nn.sigmoid(logits), np.float64)
+        ids, ws = _loop_route(scores, np.asarray(bias, np.float64), k, G, kg,
+                              scale)
+        changed = 0
+        for t in range(T):
+            assert sorted(np.asarray(idx[t]).tolist()) == ids[t], t
+            for e, g in zip(np.asarray(idx[t]), np.asarray(w[t])):
+                assert g == pytest.approx(ws[t][int(e)], rel=1e-5)
+            assert float(w[t].sum()) == pytest.approx(scale, rel=1e-5)
+            unbiased = np.argsort(scores[t])[-k:]
+            changed += sorted(unbiased.tolist()) != ids[t]
+        # the bias and the groups really steer the selection in this draw
+        assert changed > 0
+
+    def test_held_shares_add_up_to_the_whole_layer(self):
+        """Expert parallelism without the exchange: the parts the ranks'
+        blocks give add up to the block that holds every expert."""
+        rng = np.random.default_rng(4)
+        B, E, D, H, k = 12, 8, 8, 16, 3
+        x = jnp.asarray(rng.normal(size=(B, D)), jnp.float32)
+        w_r = jnp.asarray(rng.normal(size=(D, E)), jnp.float32)
+        wg = jnp.asarray(rng.normal(size=(E, D, H)) * 0.3, jnp.float32)
+        wu = jnp.asarray(rng.normal(size=(E, D, H)) * 0.3, jnp.float32)
+        wd = jnp.asarray(rng.normal(size=(E, H, D)) * 0.3, jnp.float32)
+        kw = dict(top_k=k, scoring="sigmoid", n_group=4, topk_group=2,
+                  scale=2.5)
+        whole, _, all_pairs = moe_ffn(x, w_r, (wg, wu, wd), **kw)
+        parts, n_pairs = 0.0, 0
+        for r in range(4):
+            sl = slice(2 * r, 2 * r + 2)
+            y, _, pairs = moe_ffn(x, w_r, (wg[sl], wu[sl], wd[sl]),
+                                  first_expert=2 * r, **kw)
+            parts = parts + y
+            n_pairs += int(pairs.sum())
+        np.testing.assert_allclose(parts, whole, rtol=2e-5, atol=1e-5)
+        assert n_pairs == int(all_pairs.sum()) == B * k
 
 
 class TestMoeFfn:
     def _params(self, rng, E, D, H, Dout):
         return dict(
             w_router=jnp.asarray(rng.normal(size=(D, E)) * 0.1, jnp.float32),
-            w1=jnp.asarray(rng.normal(size=(E, D, H)) * 0.3, jnp.float32),
-            b1=jnp.zeros((E, H), jnp.float32),
-            w2=jnp.asarray(rng.normal(size=(E, H, Dout)) * 0.3, jnp.float32),
-            b2=jnp.zeros((E, Dout), jnp.float32),
+            experts=(
+                jnp.asarray(rng.normal(size=(E, D, H)) * 0.3, jnp.float32),
+                jnp.zeros((E, H), jnp.float32),
+                jnp.asarray(rng.normal(size=(E, H, Dout)) * 0.3, jnp.float32),
+                jnp.zeros((E, Dout), jnp.float32)),
         )
 
     def test_single_expert_equals_plain_ffn(self):
         rng = np.random.default_rng(2)
         p = self._params(rng, E=1, D=8, H=16, Dout=8)
         x = jnp.asarray(rng.normal(size=(6, 8)), jnp.float32)
-        y, aux = moe_ffn(x, **p, top_k=1, capacity_factor=8.0)
-        ref = jax.nn.relu(x @ p["w1"][0] + p["b1"][0]) @ p["w2"][0] + p["b2"][0]
+        y, aux, _ = moe_ffn(x, **p, top_k=1)
+        w1, b1, w2, b2 = p["experts"]
+        ref = jax.nn.relu(x @ w1[0] + b1[0]) @ w2[0] + b2[0]
         np.testing.assert_allclose(y, ref, rtol=2e-5, atol=1e-6)
         np.testing.assert_allclose(float(aux), 1.0, rtol=1e-5)
 
@@ -63,20 +162,20 @@ class TestMoeFfn:
         E, D, H = 4, 8, 16
         p = self._params(rng, E=E, D=D, H=H, Dout=D)
         x = jnp.asarray(rng.normal(size=(16, D)), jnp.float32)
-        ref, _ = moe_ffn(x, **p, top_k=2, capacity_factor=2.0)
+        ref = moe_ffn(x, **p, top_k=2)[0]
 
         mesh = make_mesh(data=2, model=4)
         px = jax.device_put(x, NamedSharding(mesh, P("data")))
-        pp = dict(p)
-        for k in ("w1", "b1", "w2", "b2"):
-            spec = P("model", *([None] * (p[k].ndim - 1)))
-            pp[k] = jax.device_put(p[k], NamedSharding(mesh, spec))
+        experts = tuple(
+            jax.device_put(w, NamedSharding(
+                mesh, P("model", *([None] * (w.ndim - 1)))))
+            for w in p["experts"])
 
         @jax.jit
-        def run(x, pp):
-            return moe_ffn(x, **pp, top_k=2, capacity_factor=2.0)[0]
+        def run(x, w_router, experts):
+            return moe_ffn(x, w_router, experts, top_k=2)[0]
 
-        out = run(px, pp)
+        out = run(px, p["w_router"], experts)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=1e-6)
 
@@ -86,12 +185,12 @@ class TestMoeFfn:
         x = jnp.asarray(rng.normal(size=(16, 8)), jnp.float32)
 
         def loss(p):
-            y, aux = moe_ffn(x, **p, top_k=2, capacity_factor=2.0)
+            y, aux, _ = moe_ffn(x, **p, top_k=2)
             return jnp.sum(jnp.square(y)) + 0.01 * aux
 
         g = jax.grad(loss)(p)
-        for k, v in g.items():
-            assert float(jnp.abs(v).max()) > 0.0, f"zero grad for {k}"
+        for v in [g["w_router"], *g["experts"]]:
+            assert float(jnp.abs(v).max()) > 0.0
 
 
 class TestMoeLayer:

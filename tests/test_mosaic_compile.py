@@ -267,6 +267,41 @@ def test_paged_kernel_at_the_serve_cells_shapes(mosaic, form):
     assert made_by and "copy" not in made_by, made_by
 
 
+# the latent cell's own shapes (benchmark/configs/
+# gigachat3.1-702b-a36b-serve.json: 64 slots, page 16, context 4,096, 64
+# query heads against ONE 576-wide latent row stored 640 wide, its first 512
+# columns the value, bf16)
+LATENT = dict(S=64, PAGE=16, MAXP=4096 // 16, H=64, W=576, WP=640, V=512,
+              POOL=16385)
+
+
+@pytest.mark.parametrize("rows", [64, 128], ids=["decode", "mixed-128-rows"])
+def test_latent_paged_kernel_at_the_cells_shape(mosaic, rows):
+    """The decode step (64 rows) and the mixed step (128 rows) of the
+    latent pool through the call the layer makes (ops/mla.py:
+    paged_latent_step): one `mla_paged_attn` call, and — the pool donated —
+    no copy of the pool on its way in (its rows are taken as they are
+    stored)."""
+    from paddle_tpu.ops import mla
+    c = LATENT
+
+    def step(q, new, pool, table, row_slot, row_pos):
+        return mla.paged_latent_step(q, new, pool, table, row_slot, row_pos,
+                                     0.1, c["V"], use_kernel=True)
+
+    compiled = mosaic(
+        step, ((rows, c["H"], c["WP"]), bf16), ((rows, c["WP"]), bf16),
+        ((c["POOL"], c["PAGE"], c["WP"]), bf16),
+        ((c["S"] + 1, c["MAXP"]), i32), ((rows,), i32), ((rows,), i32),
+        donate=(2,))
+    assert kernel_names(compiled) == ["mla_paged_attn.1"], \
+        kernel_names(compiled)
+    import re
+    made_by = re.findall(r"= bf16\[16385,16,640\]\S* ([\w-]+)\(",
+                         compiled.as_text())
+    assert made_by and "copy" not in made_by, made_by
+
+
 @pytest.mark.parametrize("form", ["decode", "mixed"])
 def test_paged_kernel_carries_its_name(mosaic, form):
     """The paged kernel is `paged_attn` in both forms (it was

@@ -403,3 +403,25 @@ def test_prefix_tree_match_insert_evict(tr):
     assert tree.evict_for(99) == 3
     assert kv.free_page_count == kv.num_pages - 1
     kv.check()
+
+
+def test_page_pressure_frees_a_batch_a_walk_in_a_large_pool(tr):
+    """A full pool asks for one page at a time; each call of the pressure
+    hook walks the whole tree, so it frees 1/256 of the pool a call (the
+    coldest leaves first) — and exactly what was asked in a small pool."""
+    big = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
+                        max_context=16, num_pages=1025)
+    rng = np.random.default_rng(5)
+    for i in range(4):
+        big.run([Request(f"b{i}", rng.integers(2, 23, 9).astype(np.int32),
+                         max_new=3)])
+    cached = big.kv.cached_page_count
+    assert cached >= 8
+    assert big._evict_for(1) == 1025 // 256 == 4
+    assert big.kv.cached_page_count == cached - 4
+    big.kv.check()
+    small = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
+                          max_context=16, num_pages=9)
+    small.run([Request("s", rng.integers(2, 23, 9).astype(np.int32),
+                       max_new=3)])
+    assert small._evict_for(1) == 1

@@ -104,19 +104,49 @@ def run_client(args) -> int:
     return 0
 
 
-def build_engine(args):
+def build_model(args):
+    """(executor, parameters) of the served model: the config's graph and
+    its parameters from the config's initializers — or a checkpoint — held
+    in `--param-dtype`.  No Trainer: nothing of an optimizer is built, so
+    start-up holds one copy of the weights in the dtype they are served
+    in."""
+    import jax
+    import jax.numpy as jnp
+
     from paddle_tpu.config.parser import parse_config
-    from paddle_tpu.serving import ServingEngine
-    from paddle_tpu.trainer.trainer import Trainer
+    from paddle_tpu.graph import GraphExecutor
+    from paddle_tpu.utils.flags import FLAGS
 
     cfg = parse_config(args.config, args.config_args)
-    tr = Trainer(cfg, seed=args.seed or 0)
+    executor = GraphExecutor(
+        cfg.model_config,
+        compute_dtype=FLAGS.compute_dtype or cfg.opt_config.compute_dtype)
+    dtype = jnp.dtype(args.param_dtype) if args.param_dtype else None
+    params = executor.init_params(jax.random.PRNGKey(args.seed or 0),
+                                  dtype=dtype)
     if args.checkpoint:
-        from paddle_tpu.trainer.checkpoint import latest_checkpoint
+        from paddle_tpu.trainer.checkpoint import (latest_checkpoint,
+                                                   load_checkpoint)
 
         path = latest_checkpoint(args.checkpoint) or args.checkpoint
         print(f"loading checkpoint {path}", file=sys.stderr)
-        tr.load(path)
+        data = load_checkpoint(path)
+        for name, cur in params.items():
+            assert name in data["params"], \
+                f"checkpoint missing parameter {name!r}"
+            arr = jnp.asarray(data["params"][name])
+            assert arr.size == cur.size, (
+                f"parameter {name!r}: checkpoint has {arr.size} values, "
+                f"the model expects {cur.size}")
+            # reference-format files are flat fp32: restore shape and dtype
+            params[name] = arr.reshape(cur.shape).astype(cur.dtype)
+    return executor, params
+
+
+def build_engine(args):
+    from paddle_tpu.serving import ServingEngine
+
+    executor, params = build_model(args)
     if args.prefill_chunk < 0:
         chunk = None                 # chunking off: legacy prefill
     else:
@@ -145,7 +175,7 @@ def build_engine(args):
             # truncated window, batched across all slots in one
             # dispatch — zero extra weights to load or train
             from paddle_tpu.serving.drafter import ModelDrafter
-            drafter = ModelDrafter.from_target(tr.executor, tr.params)
+            drafter = ModelDrafter.from_target(executor, params)
         dyn = " (dynamic per-slot k)" if args.spec_dynamic else ""
         print(f"speculative decoding: up to {args.spec_k} drafts/slot/"
               f"step ({args.drafter} drafter{dyn}; emitted tokens "
@@ -158,7 +188,7 @@ def build_engine(args):
         print(f"KV spill tier: cold cached pages spill to host RAM "
               f"(budget {args.spill_budget} bytes) and restore on "
               f"prefix hits", file=sys.stderr)
-    return ServingEngine(tr.executor, tr.params, num_slots=args.slots,
+    return ServingEngine(executor, params, num_slots=args.slots,
                          page_size=args.page_size,
                          max_context=args.max_context,
                          num_pages=args.num_pages,
@@ -324,6 +354,10 @@ def main(argv=None) -> int:
                     help="pump beat age past which the watchdog declares "
                          "a wedge and dumps a bundle")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--param-dtype", default="",
+                    help="dtype the weights are held and served in (e.g. "
+                         "bfloat16: no per-step cast, half the bytes); "
+                         "default: each parameter's own (float32)")
     # client mode
     ap.add_argument("--client", default="",
                     help="HOST:PORT — run as a one-shot client instead")
