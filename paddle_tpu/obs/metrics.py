@@ -75,6 +75,12 @@ CATALOG: dict[str, str] = {
         "MoE layers), summed over steps",
     "serving_moe_steps_total":
         "compiled steps whose routed pairs were counted",
+    # -- token delivery: a step's frames leave as one write a connection --
+    "serving_token_frames_total":
+        "streamed token frames written to client connections",
+    "serving_frame_writes_total":
+        "transport writes that carried token frames (one per connection "
+        "and engine step; frames/writes = how far a step's tokens coalesce)",
     # -- cross-replica KV transfer (docs/serving.md "Disaggregated
     # prefill/decode") ----------------------------------------------------
     "serving_kv_xfer_pushes_total":

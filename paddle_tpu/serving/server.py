@@ -12,8 +12,10 @@ arXiv:2605.25645) over `serving/engine.py`:
     stream back as they decode.  All engine access goes through the pump:
     the asyncio side never touches scheduler state, it posts commands
     (add/cancel) to a thread-safe queue the pump drains between steps, and
-    the engine's on_token/on_finish hooks post frames back via
-    call_soon_threadsafe.  No locks around the scheduler, no torn state.
+    the engine's on_token/on_finish hooks append to the pump's ORDERED
+    OUTBOX, which the pump hands to the loop with one call_soon_threadsafe
+    a step; the loop writes each connection's token frames of that step
+    as one transport write.  No locks around the scheduler, no torn state.
   * BOUNDED ADMISSION: the server accepts at most
     `num_slots + max_queue` unfinished requests; one more gets an explicit
     `overload` response instead of unbounded queueing (the client backs
@@ -65,6 +67,7 @@ from paddle_tpu.obs import (MetricsRegistry, statset_collector,
 from paddle_tpu.obs.compile_watch import compile_collector, get_compile_watch
 from paddle_tpu.obs.flight import flight_collector, get_flight_recorder
 from paddle_tpu.obs.hbm import hbm_collector, hbm_snapshot
+from paddle_tpu.obs.metrics import process_counters
 from paddle_tpu.obs.slo import SloEvaluator, default_serving_slos
 from paddle_tpu.obs.timeseries import (HistorySampler, MetricHistory,
                                        history_collector, history_reply)
@@ -110,13 +113,18 @@ class _Conn(wire.FrameConn):
     severing frame connection — hoisted to wire.py so the fleet router's
     client face can never drift from this server's (conn.rids maps client
     id -> engine req_id here).  The replica times the encode + write of
-    every frame as `pt.loop.send` on the loop thread (here, not in
-    wire.py, which the JAX-free client imports) — for the profiler alone:
-    a span a token would wrap the ring within seconds."""
+    every WRITE (one frame, or a step's token frames for this connection)
+    as `pt.loop.send` on the loop thread (here, not in wire.py, which the
+    JAX-free client imports) — for the profiler alone: a span a write
+    would wrap the ring within minutes."""
 
     def send(self, msg: dict) -> None:
         with annotation("pt.loop.send"):
             self._write(wire.encode(msg))
+
+    def send_many(self, msgs: list) -> None:
+        with annotation("pt.loop.send"):
+            super().send_many(msgs)
 
 
 def _kv_push_frames(cid, toks, meta: dict, payload: bytes) -> list[bytes]:
@@ -224,6 +232,19 @@ class ServingServer:
         self._routes: dict[str, _ReqState] = {}
         self._cmds: queue.Queue = queue.Queue()
         self._wake = threading.Event()
+        # the pump's ordered outbox (pump thread only): everything the
+        # pump has for the loop since its last hand-off, as (conn, fn,
+        # args) records — fn None = one token frame (args) for conn.
+        # _flush_outbox hands the whole list over with ONE
+        # call_soon_threadsafe; the pump never waits, stops or dies with
+        # records in it.  _tok_lat is the same idea for token_latency:
+        # a step's samples go in under one lock, before the hand-off.
+        self._outbox: list = []
+        self._tok_lat: list = []
+        # token delivery accounting (loop thread): frames written and the
+        # transport writes that carried them
+        self.n_token_frames = 0
+        self.n_frame_writes = 0
         self._pump_thread: Optional[threading.Thread] = None
         self._pump_error: Optional[BaseException] = None
         self._loop: Optional[asyncio.AbstractEventLoop] = None
@@ -377,6 +398,12 @@ class ServingServer:
                  float(eng.kv.n_imported)),
                 ("serving_kv_xfer_mounts_total", "counter", None,
                  float(eng.n_kv_mounts)),
+                # token delivery: frames over the writes that carried
+                # them = how far a step's tokens coalesce per connection
+                ("serving_token_frames_total", "counter", None,
+                 float(self.n_token_frames)),
+                ("serving_frame_writes_total", "counter", None,
+                 float(self.n_frame_writes)),
             ] + eng.step_tokens_hist.samples() \
               + eng.decode_gap_hist.samples() \
               + eng.draft_ms_hist.samples() \
@@ -582,12 +609,11 @@ class ServingServer:
                         while True:
                             cmd = self._cmds.get_nowait()
                             if cmd[0] == "stats":
-                                self._loop.call_soon_threadsafe(
-                                    self._stats_on_loop, cmd[1],
-                                    self._engine_stats())
+                                self._post(cmd[1], self._stats_on_loop,
+                                           cmd[1], self._engine_stats())
                             elif cmd[0] == "kv_import":
-                                self._loop.call_soon_threadsafe(
-                                    cmd[2].send,
+                                self._post(
+                                    cmd[2], cmd[2].send,
                                     {"type": "kv_push", "id": cmd[1]["cid"],
                                      "ok": False,
                                      "error": "replica stopping"})
@@ -602,8 +628,8 @@ class ServingServer:
                         # validate() ran at admission, so only a race with
                         # a reconfigured engine lands here — still must
                         # answer the client
-                        self._loop.call_soon_threadsafe(
-                            self._fail_on_loop, req.req_id, str(e))
+                        self._post(None, self._fail_on_loop, req.req_id,
+                                   str(e))
                 elif cmd[0] == "cancel":
                     self.engine.cancel(cmd[1])
                 elif cmd[0] == "kv_import":
@@ -624,21 +650,88 @@ class ServingServer:
                         reply = {"type": "kv_push", "id": push["cid"],
                                  "ok": False,
                                  "error": f"{type(e).__name__}: {e}"}
-                    self._loop.call_soon_threadsafe(conn.send, reply)
+                    self._post(conn, conn.send, reply)
                 elif cmd[0] == "stats":
                     # between-steps = the consistent view: no
                     # slot/page/queue mutation can interleave
-                    self._loop.call_soon_threadsafe(
-                        self._stats_on_loop, cmd[1], self._engine_stats())
+                    self._post(cmd[1], self._stats_on_loop, cmd[1],
+                               self._engine_stats())
         except queue.Empty:
             pass
         return False
+
+    def _post(self, conn, fn, *args) -> None:
+        """Pump thread: queue `fn(*args)` for the loop thread, in order
+        behind everything already in the outbox.  `conn` is the connection
+        whose earlier token frames must be on the wire before fn runs
+        (None = every connection's)."""
+        self._outbox.append((conn, fn, args))
+
+    def _flush_outbox(self) -> None:
+        """Pump thread: hand the loop everything banked since the last
+        hand-off — ONE call_soon_threadsafe (one Handle, one self-pipe
+        write) however many tokens the step banked.  The step's
+        token_latency samples go in first, under one lock, so a client
+        that has a token can read its latency."""
+        if self._tok_lat:
+            lat, self._tok_lat = self._tok_lat, []
+            self.stats.get("token_latency").add_many(lat)
+        if self._outbox:
+            batch, self._outbox = self._outbox, []
+            self._loop.call_soon_threadsafe(self._deliver_on_loop, batch)
+
+    def _deliver_on_loop(self, batch: list) -> None:
+        """Loop thread: one hand-off's records, in order.  Token frames
+        gather per CONNECTION (slot order interleaves connections) and go
+        out as one write each — when the batch ends, or before a callback
+        that concerns their connection runs, so a `done`, an `error` or a
+        stats reply never overtakes a token banked before it.  The bytes
+        a connection reads are the frames, in the order, that one send a
+        token would have produced."""
+        pending: dict = {}             # conn -> its token frames, in order
+        frames = writes = 0
+
+        def write(conn) -> None:
+            nonlocal frames, writes
+            msgs = pending.pop(conn)
+            conn.send_many(msgs)
+            frames += len(msgs)
+            writes += 1
+
+        for conn, fn, args in batch:
+            if fn is None:
+                pending.setdefault(conn, []).append(args)
+                continue
+            if conn is None:
+                for c in list(pending):
+                    write(c)
+            elif conn in pending:
+                write(conn)
+            try:
+                fn(*args)
+            except Exception as e:             # noqa: BLE001 — one record's
+                # failure must not strand the rest of the batch (each was
+                # its own loop callback once, and failed alone)
+                self._loop.call_exception_handler({
+                    "message": f"serving delivery {fn!r} failed",
+                    "exception": e})
+        for c in list(pending):
+            write(c)
+        if writes:
+            self.n_token_frames += frames
+            self.n_frame_writes += writes
+            pc = process_counters()
+            pc.add("serving_token_frames_total", frames)
+            pc.add("serving_frame_writes_total", writes)
 
     def _pump(self) -> None:
         """The pump loop.  Its phases are spans on the `pump` lane (names
         `pt.pump.*`, `pt.engine.step`; the engine's own `pt.step.*` nest
         inside the latter): a profiler trace splits the device's idle time
-        by what this thread was doing."""
+        by what this thread was doing.  The outbox is flushed wherever the
+        engine may have banked something: after the command drain (a
+        cancel finishes a request there) and after the step — so it is
+        empty whenever the pump waits, stops or dies."""
         span = self.tracer.span
         try:
             while True:
@@ -658,10 +751,13 @@ class ServingServer:
                             "pump_beat", step=self.engine.n_decode_steps,
                             queue_depth=len(self.engine.queue),
                             inflight=self._inflight)
-                    if self._drain_commands():
+                    stop = self._drain_commands()
+                    self._flush_outbox()
+                    if stop:
                         return
                 with span("pt.engine.step", track="pump"):
                     busy = self.engine.step()
+                    self._flush_outbox()
                 if not busy:
                     # idle: nothing queued or in flight — sleep until a
                     # command arrives (bounded wait as a safety net)
@@ -677,11 +773,16 @@ class ServingServer:
             import traceback
 
             err = f"{type(e).__name__}: {e}"
-            self.flight.record("pump_death", error=err)
-            self._write_bundle("pump_death",
-                               error=err + "\n" + traceback.format_exc())
-            if self._loop is not None:
-                self._loop.call_soon_threadsafe(self._pump_died_on_loop)
+            try:
+                self.flight.record("pump_death", error=err)
+                self._write_bundle("pump_death",
+                                   error=err + "\n" + traceback.format_exc())
+            finally:
+                if self._loop is not None:
+                    # what the dying step banked goes out first, then
+                    # every route still open is failed
+                    self._post(None, self._pump_died_on_loop)
+                    self._flush_outbox()
 
     def _pump_died_on_loop(self) -> None:
         """A dead pump strands every accepted request — fail them all so
@@ -900,7 +1001,7 @@ class ServingServer:
                     # at k>1 this keeps token_latency percentiles
                     # comparable across decode_steps settings.
                     st.burst_share = (now - st.t_last) / (st.burst_left + 1)
-                self.stats.get("token_latency").add(st.burst_share)
+                self._tok_lat.append(st.burst_share)
             # t_last advances on FRESH tokens only: replayed (deduped)
             # emissions reach no client, so the first post-replay fresh
             # token must charge the whole preempt+re-prefill+replay stall
@@ -909,10 +1010,10 @@ class ServingServer:
             st.t_last = now
             st.next_idx = idx + 1
             if st.stream:
-                self._loop.call_soon_threadsafe(
-                    st.conn.send, {"type": "token", "id": st.cid,
-                                   "token": int(tok), "index": int(idx),
-                                   "burst": st.burst_left + 1})
+                self._outbox.append(
+                    (st.conn, None, {"type": "token", "id": st.cid,
+                                     "token": int(tok), "index": int(idx),
+                                     "burst": st.burst_left + 1}))
 
     def _on_finish(self, rid: str, toks: np.ndarray, reason: str) -> None:
         # the server owns delivery — keep the engine's archive empty so a
@@ -941,14 +1042,12 @@ class ServingServer:
             # A cancelled/expired prefill finishes NORMALLY — shipping
             # pages nobody will decode would only burn wire and counters.
             export = self.engine.export_prefix(st.prompt)
-            self._loop.call_soon_threadsafe(
-                self._push_then_finish_on_loop, rid,
-                np.asarray(toks).astype(int).tolist(), reason, timing,
-                export)
+            self._post(st.conn, self._push_then_finish_on_loop, rid,
+                       np.asarray(toks).astype(int).tolist(), reason, timing,
+                       export)
             return
-        self._loop.call_soon_threadsafe(
-            self._finish_on_loop, rid,
-            np.asarray(toks).astype(int).tolist(), reason, timing)
+        self._post(st.conn, self._finish_on_loop, rid,
+                   np.asarray(toks).astype(int).tolist(), reason, timing)
 
     # -- loop-side completion/error delivery -------------------------------
     def _finish_on_loop(self, rid: str, tokens: list, reason: str,
@@ -1502,6 +1601,9 @@ class ServingServer:
             "role": self.role,
             "kv_pushes": self._kv_pushes,
             "kv_push_failures": self._kv_push_failures,
+            # token delivery: frames / the writes that carried them
+            "token_frames": self.n_token_frames,
+            "frame_writes": self.n_frame_writes,
             "pump_alive": self.pump_alive(),
             "pump_last_step_age_s": round(self.pump_last_step_age(), 3),
             "latency_ms": lat,

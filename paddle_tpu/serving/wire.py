@@ -106,9 +106,14 @@ class FrameError(ValueError):
     """Malformed frame: oversized length prefix or non-JSON body."""
 
 
+#: json.dumps(msg, separators=(",", ":")) without building an encoder a
+#: call — the same bytes; a token frame is encoded once a token
+_dumps = json.JSONEncoder(separators=(",", ":")).encode
+
+
 def encode(msg: dict) -> bytes:
     """One message -> length-prefixed wire bytes."""
-    body = json.dumps(msg, separators=(",", ":")).encode("utf-8")
+    body = _dumps(msg).encode("utf-8")
     if len(body) > MAX_FRAME:
         raise FrameError(f"frame of {len(body)} bytes exceeds the "
                          f"{MAX_FRAME}-byte cap")
@@ -129,7 +134,7 @@ def _decode_body(body: bytes) -> dict:
 def encode_bin(msg: dict, payload: bytes) -> bytes:
     """One message + raw payload -> binary wire frame (module docstring
     layout).  `msg` must not already carry PAYLOAD_KEY."""
-    header = json.dumps(msg, separators=(",", ":")).encode("utf-8")
+    header = _dumps(msg).encode("utf-8")
     n = _LEN.size + len(header) + len(payload)
     if n > MAX_FRAME:
         raise FrameError(f"binary frame of {n} bytes exceeds the "
@@ -218,6 +223,13 @@ class FrameConn:
 
     def send(self, msg: dict) -> None:
         self._write(encode(msg))
+
+    def send_many(self, msgs: list) -> None:
+        """Several messages as ONE write: the same frames, in order, that
+        one send() each would put on the wire, under one slow-reader check
+        and one transport write (so one socket send while the reader keeps
+        up).  The replica's per-step token delivery."""
+        self._write(b"".join([encode(m) for m in msgs]))
 
     def send_bin(self, msg: dict, payload: bytes) -> None:
         """Binary frame variant (header + raw payload) — negotiated via

@@ -50,15 +50,21 @@ class Stat:
                                  repr=False, compare=False)
 
     def add(self, dt: float) -> None:
+        self.add_many((dt,))
+
+    def add_many(self, dts) -> None:
+        """Every sample of `dts`, in order, under ONE lock acquire (the
+        serving pump banks a step's token latencies together)."""
         with self.lock:
-            if len(self.samples) < SAMPLE_WINDOW:
-                self.samples.append(dt)
-            else:
-                self.samples[self.count % SAMPLE_WINDOW] = dt
-            self.total_s += dt
-            self.count += 1
-            if dt > self.max_s:
-                self.max_s = dt
+            for dt in dts:
+                if len(self.samples) < SAMPLE_WINDOW:
+                    self.samples.append(dt)
+                else:
+                    self.samples[self.count % SAMPLE_WINDOW] = dt
+                self.total_s += dt
+                self.count += 1
+                if dt > self.max_s:
+                    self.max_s = dt
 
     def reset(self) -> None:
         with self.lock:

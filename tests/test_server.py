@@ -1449,3 +1449,423 @@ def test_prefix_eviction_has_its_own_span(tiny_tr):
     evicts = [s for s in t.snapshot() if s["name"] == "pt.kv.evict"]
     assert evicts and all(s["attrs"]["pages"] >= 1 for s in evicts)
     assert {s["track"] for s in evicts} == {"engine"}
+
+
+# -- one hand-off a step, one write a connection (ISSUE 30) ------------------
+class _StubWriter:
+    """An asyncio StreamWriter as far as wire.FrameConn uses one."""
+
+    def __init__(self, buffered=0):
+        self.writes, self.closed, self.buffered = [], False, buffered
+        self.transport = self
+
+    def is_closing(self):
+        return self.closed
+
+    def get_write_buffer_size(self):
+        return self.buffered
+
+    def write(self, data):
+        self.writes.append(bytes(data))
+
+    def close(self):
+        self.closed = True
+
+
+class _StubLoop:
+    """The loop as far as the pump uses it: counts the hand-offs and runs
+    each at once, on the calling thread."""
+
+    def __init__(self):
+        self.handoffs = []
+
+    def call_soon_threadsafe(self, fn, *args):
+        self.handoffs.append((fn, args))
+        fn(*args)
+
+    def call_exception_handler(self, ctx):
+        raise ctx["exception"]
+
+
+def _split_frames(data: bytes) -> list:
+    """Wire bytes -> [(message, its raw bytes)]."""
+    import json
+    import struct
+
+    out, i = [], 0
+    while i < len(data):
+        (n,) = struct.unpack(">I", data[i:i + 4])
+        out.append((json.loads(data[i + 4:i + 4 + n]), data[i:i + 4 + n]))
+        i += 4 + n
+    assert i == len(data)
+    return out
+
+
+def _read_until_terminal(sock, ids) -> list:
+    """(message, raw bytes) of every frame off a raw socket until each of
+    `ids` has had its done / error frame."""
+    from paddle_tpu.serving import wire
+
+    left, out = set(ids), []
+    while left:
+        head = wire._recv_exact(sock, 4)
+        assert head is not None and len(head) == 4, "server closed early"
+        body = wire._recv_exact(sock, wire.check_length(head))
+        msg = wire._decode_body(body)
+        out.append((msg, head + body))
+        if msg["type"] in ("done", "error"):
+            left.discard(msg["id"])
+    return out
+
+
+def _record_per_token_path(srv) -> dict:
+    """{client id: [wire bytes]} of the frame the per-token path sent for
+    each fresh streamed token — encoded AT BANKING TIME, from the state
+    `_on_token` had just left, the way it handed each to the loop."""
+    from paddle_tpu.serving import wire
+
+    rec, inner = {}, srv._on_token
+
+    def on_token(rid, tok, idx):
+        st = srv._routes.get(rid)
+        fresh = st is not None and idx >= st.next_idx
+        inner(rid, tok, idx)
+        if fresh and st.stream:
+            rec.setdefault(st.cid, []).append(wire.encode(
+                {"type": "token", "id": st.cid, "token": int(tok),
+                 "index": int(idx), "burst": st.burst_left + 1}))
+
+    srv.engine.on_token = on_token
+    return rec
+
+
+def _generate(sock, cid, prompt, max_new, **kw):
+    from paddle_tpu.serving import wire
+
+    wire.write_frame_sync(sock, {"type": "generate", "id": cid,
+                                 "prompt": [int(t) for t in prompt],
+                                 "max_new": max_new, "stream": True, **kw})
+
+
+_KIND_KW = {"decode": {}, "mixed": {"num_slots": 4},
+            "scan": {"decode_steps": 4}, "spec": {"spec_k": 2}}
+
+
+@pytest.mark.parametrize("kind", ["decode", "mixed", "scan", "spec"])
+def test_batched_delivery_is_byte_identical_per_request(tiny_tr, kind):
+    """N concurrent streams over ONE connection: the bytes the socket
+    carries are, per request, exactly `wire.encode` of the frames the
+    per-token path produced — indexes 0..n-1 in order, the same `burst`
+    fields, `done` last — whatever kind of step banked them."""
+    import socket
+
+    eng = _engine(tiny_tr, **_KIND_KW[kind])
+    srv = ServingServer(eng, max_queue=32)
+    want = _record_per_token_path(srv)
+    host, port = srv.start_background()
+    rng = np.random.default_rng(5)
+    if kind == "mixed":          # later admissions chunk beside decode rows
+        lens, news = (20, 11, 5, 17, 9, 13), (9, 6, 8, 7, 9, 6)
+    else:
+        lens, news = (5, 9, 20), (9, 7, 8)
+    prompts = [np.tile(rng.integers(2, 31, 4), 5)[:n] for n in lens]
+    try:
+        sock = socket.create_connection((host, port), timeout=60)
+        try:
+            for i, (p, n) in enumerate(zip(prompts, news)):
+                _generate(sock, f"r{i}", p, n)
+            got = _read_until_terminal(sock, [f"r{i}" for i in
+                                              range(len(lens))])
+        finally:
+            sock.close()
+    finally:
+        srv.stop_background(drain=True)
+    assert {"mixed": eng.n_mixed_steps >= 4,
+            "decode": eng.n_decode_steps > eng.n_mixed_steps,
+            "scan": eng.n_scan_flushes > 0,
+            "spec": eng.n_spec_steps > 0}[kind]
+    for i, (p, n) in enumerate(zip(prompts, news)):
+        mine = [(m, raw) for m, raw in got if m["id"] == f"r{i}"]
+        toks, done = mine[:-1], mine[-1][0]
+        assert done["type"] == "done" and done["reason"] == "length"
+        assert b"".join(raw for _, raw in toks) == b"".join(want[f"r{i}"])
+        assert [m["index"] for m, _ in toks] == list(range(n))
+        assert [m["token"] for m, _ in toks] == done["tokens"][len(p):]
+        assert done["tokens"] == _oracle(tiny_tr, p, n)
+    if kind == "scan":
+        assert max(m.get("burst", 0) for m, _ in got) == 4
+    assert srv.n_token_frames == sum(news)
+    assert srv._outbox == [] and srv._tok_lat == []
+
+
+@pytest.mark.parametrize("end", ["length", "cancelled", "deadline",
+                                 "failed"])
+def test_terminal_frame_never_overtakes_the_last_token(tiny_tr, end):
+    """A request that finishes, is cancelled, expires or fails IN the
+    step that banked its last token: every token banked for it is on the
+    wire, in order, before its done / error frame."""
+    import socket
+
+    eng = _engine(tiny_tr)
+    inner = eng.step
+
+    def step():
+        busy = inner()
+        sl = next((s for s in eng.slots if s is not None
+                   and s.req.req_id.endswith(":s:r")), None)
+        if sl is not None and sl.gen >= 3:
+            if end == "cancelled":    # a finish in the banking step itself
+                eng.cancel(sl.req.req_id)
+            elif end == "failed":
+                raise RuntimeError("boom")
+        return busy
+
+    eng.step = step
+    srv = ServingServer(eng, max_queue=8)
+    want = _record_per_token_path(srv)
+    host, port = srv.start_background()
+    try:
+        sock = socket.create_connection((host, port), timeout=60)
+        try:
+            kw = {"timeout_s": 3.0} if end == "deadline" else {}
+            _generate(sock, "other", [4, 9, 2], 12 if end != "failed" else 30)
+            _generate(sock, "r", [3, 7, 5, 11], 6 if end == "length" else 30,
+                      **kw)
+            got = _read_until_terminal(sock, ["r", "other"])
+        finally:
+            sock.close()
+    finally:
+        if end == "failed":
+            with pytest.raises(RuntimeError, match="engine pump died"):
+                srv.stop_background(drain=True)
+        else:
+            srv.stop_background(drain=True)
+    for cid in ("r", "other"):
+        mine = [(m, raw) for m, raw in got if m["id"] == cid]
+        toks, last = mine[:-1], mine[-1][0]
+        assert all(m["type"] == "token" for m, _ in toks)
+        # nothing banked was dropped, nothing reordered
+        assert [raw for _, raw in toks] == want[cid]
+        assert [m["index"] for m, _ in toks] == list(range(len(toks)))
+        if cid == "r" or end == "failed":
+            assert last["type"] == ("error" if end == "failed" else "done")
+        if last["type"] == "done":
+            assert [m["token"] for m, _ in toks] == \
+                last["tokens"][-len(toks):]
+            if cid == "r":
+                assert last["reason"] == end
+    if end != "length":
+        assert 2 <= len(want["r"]) < 30
+    assert srv._outbox == [] and srv._inflight == 0
+
+
+@pytest.mark.parametrize("topology", ["one_connection", "connection_each"])
+def test_one_handoff_a_step_and_one_write_a_connection(tiny_tr, topology):
+    """With a stub loop that counts `call_soon_threadsafe`: a step that
+    banks S tokens makes ONE hand-off; S streams on one connection make
+    one transport write, S connections make S."""
+    from paddle_tpu.serving.server import _Conn, _ReqState
+
+    S = 4
+    eng = _engine(tiny_tr, num_slots=S)
+    srv = ServingServer(eng, max_queue=8)
+    loop = srv._loop = _StubLoop()
+    writers = [_StubWriter() for _ in range(S)]
+    conns = [_Conn(w) for w in writers]
+    if topology == "one_connection":
+        conns = conns[:1] * S
+    per_step = []                      # (tokens banked, hand-offs, writes)
+    inner = eng.step
+
+    def step():
+        before = (eng.tokens_generated, len(loop.handoffs),
+                  sum(len(w.writes) for w in writers))
+        busy = inner()
+        srv._flush_outbox()            # what the pump does after the step
+        per_step.append((eng.tokens_generated - before[0],
+                         len(loop.handoffs) - before[1],
+                         sum(len(w.writes) for w in writers) - before[2]))
+        return busy
+
+    eng.step = step
+    rng = np.random.default_rng(2)
+    for i in range(S):
+        req = Request(f"q{i}", rng.integers(2, 31, 5), max_new=8)
+        srv._routes[req.req_id] = _ReqState(conns[i], f"q{i}", True)
+        srv._inflight += 1
+        srv._cmds.put(("add", req))
+    srv.start_pump()
+    deadline = time.time() + 60
+    while srv._routes and time.time() < deadline:
+        time.sleep(0.01)
+    srv._cmds.put(("stop",))
+    srv._wake.set()
+    srv._pump_thread.join(timeout=30)
+    assert not srv._routes and srv._pump_error is None
+    assert all(fn == srv._deliver_on_loop for fn, _ in loop.handoffs)
+    full = [p for p in per_step if p[0] == S]
+    assert len(full) >= 5, per_step    # the pure-decode steps, all S live
+    n_conns = 1 if topology == "one_connection" else S
+    for banked, handoffs, writes in per_step:
+        assert handoffs == (1 if banked else 0), per_step
+        # the retiring step adds each finished stream's `done` write
+        assert writes >= (min(banked, n_conns) if banked else 0)
+    assert (S, 1, n_conns) in full, per_step
+    # every write of token frames carried a connection's whole share
+    sent = [m for w in writers for data in w.writes
+            for m, _ in _split_frames(data)]
+    assert sum(m["type"] == "token" for m in sent) == S * 8 \
+        == srv.n_token_frames
+    assert sum(m["type"] == "done" for m in sent) == S
+    # mixed (prefill) steps bank a row or two each, decode steps S
+    assert S * 8 / srv.n_frame_writes >= (2.0 if n_conns == 1 else 1.0)
+    assert srv.n_frame_writes == sum(
+        1 for w in writers for data in w.writes
+        if _split_frames(data)[0][0]["type"] == "token")
+
+
+@pytest.mark.parametrize("end", ["drain", "stop", "death"])
+def test_outbox_is_empty_whenever_the_pump_rests(tiny_tr, end):
+    """Nothing is left in the outbox when the pump enters `pt.pump.wait`,
+    after stop_background, or after the pump died — and every client has
+    its terminal frame, no token_latency sample lost."""
+    import socket
+
+    eng = _engine(tiny_tr)
+    srv = ServingServer(eng, max_queue=8)
+    at_wait = []
+
+    class Wake(threading.Event):
+        def wait(self, timeout=None):
+            at_wait.append((len(srv._outbox), len(srv._tok_lat)))
+            return super().wait(timeout)
+
+    srv._wake = Wake()
+    if end == "death":
+        inner = eng.step
+
+        def step():
+            busy = inner()
+            if eng.tokens_generated >= 6:
+                raise RuntimeError("boom")
+            return busy
+
+        eng.step = step
+    host, port = srv.start_background()
+    sock = socket.create_connection((host, port), timeout=60)
+    try:
+        ids = ["a", "b", "c"]
+        _generate(sock, "a", [3, 4, 5], 6)
+        _generate(sock, "b", [7, 8], 30)
+        _generate(sock, "c", [9, 2, 6, 4], 30)
+        if end == "stop":              # hard stop: b and c are cancelled
+            _read_until_terminal(sock, ["a"])
+            stopper = threading.Thread(
+                target=srv.stop_background, kwargs={"drain": False})
+            stopper.start()
+        got = _read_until_terminal(sock, ids if end != "stop" else ids[1:])
+        last = {m["id"]: m for m, _ in got if m["type"] != "token"}
+        if end == "death":             # every client has its terminal frame
+            assert all("pump died" in last[i]["error"] for i in ids)
+        elif end == "stop":
+            assert {last[i]["reason"] for i in ids[1:]} == {"cancelled"}
+        else:
+            assert all(last[i]["reason"] == "length" for i in ids)
+            time.sleep(0.1)            # the pump goes idle
+    finally:
+        sock.close()
+    if end == "death":
+        with pytest.raises(RuntimeError, match="engine pump died"):
+            srv.stop_background(drain=True)
+    elif end == "drain":
+        srv.stop_background(drain=True)
+    else:
+        stopper.join(timeout=60)
+        assert not stopper.is_alive()
+    assert srv._outbox == [] and srv._tok_lat == []
+    assert srv._inflight == 0 and not srv._routes
+    if end != "death":
+        assert at_wait, "the pump never waited"
+    assert all(w == (0, 0) for w in at_wait), at_wait
+    # every fresh post-first token charged token_latency exactly once
+    assert srv.stats.get("token_latency").count == \
+        srv.n_token_frames - srv.stats.get("first_token_latency").count
+
+
+@pytest.mark.parametrize("path", ["send_many", "deliver"])
+def test_slow_reader_is_still_severed_at_max_write_buffer(tiny_tr, path):
+    """A reader whose transport buffer is past MAX_WRITE_BUFFER is closed
+    instead of buffered into — by the batched write as by send()."""
+    from paddle_tpu.serving import wire
+    from paddle_tpu.serving.server import _Conn
+
+    frames = [{"type": "token", "id": "r", "token": 5, "index": i,
+               "burst": 1} for i in range(3)]
+    slow = _StubWriter(buffered=wire.FrameConn.MAX_WRITE_BUFFER + 1)
+    ok = _StubWriter(buffered=wire.FrameConn.MAX_WRITE_BUFFER)
+    if path == "send_many":
+        conns = [wire.FrameConn(slow), wire.FrameConn(ok)]
+        for conn in conns:
+            conn.send_many(frames)
+    else:
+        srv = ServingServer(_engine(tiny_tr), max_queue=4)
+        srv._loop = _StubLoop()
+        conns = [_Conn(slow), _Conn(ok)]
+        srv._deliver_on_loop([(conn, None, f) for f in frames
+                              for conn in conns])
+        assert (srv.n_token_frames, srv.n_frame_writes) == (6, 2)
+    assert conns[0].dead and slow.closed and slow.writes == []
+    assert not conns[1].dead and not ok.closed
+    assert ok.writes == [b"".join(wire.encode(f) for f in frames)]
+    conns[0].send_many(frames)         # a dead connection stays silent
+    assert slow.writes == []
+
+
+def test_token_frame_counters_reconcile_with_tokens_generated(tiny_tr):
+    """`serving_token_frames_total` counts exactly the streamed requests'
+    tokens, `serving_frame_writes_total` the writes that carried them, in
+    the stats RPC, the metrics frame and the process's counters alike."""
+    from paddle_tpu.obs.metrics import process_counters
+
+    pc0 = process_counters().snapshot()
+    eng = _engine(tiny_tr, num_slots=4)
+    srv = ServingServer(eng, max_queue=8)
+    host, port = srv.start_background()
+    try:
+        with ServingClient(host, port) as c:
+            streamed = [c.submit([3, 4, 5 + i], max_new=7 + i, stream=True)
+                        for i in range(3)]
+            quiet = c.submit([9, 8, 7], max_new=5, stream=False)
+            out = c.collect(streamed + [quiet])
+            s = c.stats()
+            text = c.metrics()
+    finally:
+        srv.stop_background(drain=True)
+    n_streamed = sum(len(out[r]["stream"]) for r in streamed)
+    assert n_streamed == 7 + 8 + 9 and out[quiet]["stream"] == []
+    assert eng.tokens_generated == n_streamed + 5
+    assert s["token_frames"] == n_streamed == srv.n_token_frames
+    assert 9 <= s["frame_writes"] == srv.n_frame_writes < n_streamed
+    vals = dict(line.rsplit(" ", 1) for line in text.splitlines()
+                if line and not line.startswith("#"))
+    assert float(vals["serving_token_frames_total"]) == n_streamed
+    assert float(vals["serving_frame_writes_total"]) == srv.n_frame_writes
+    pc = process_counters().snapshot()
+    assert pc["serving_token_frames_total"] \
+        - pc0.get("serving_token_frames_total", 0) == n_streamed
+    assert pc["serving_frame_writes_total"] \
+        - pc0.get("serving_frame_writes_total", 0) == srv.n_frame_writes
+
+
+def test_stat_add_many_is_add_in_order():
+    from paddle_tpu.utils import stat
+
+    a, b = stat.Stat("a"), stat.Stat("b")
+    dts = [0.001 * (i % 7) for i in range(stat.SAMPLE_WINDOW + 50)]
+    for dt in dts:
+        a.add(dt)
+    b.add_many(dts[:10])
+    b.add_many(dts[10:])
+    b.add_many([])
+    assert (a.count, a.total_s, a.max_s, a.samples) == \
+        (b.count, b.total_s, b.max_s, b.samples)
