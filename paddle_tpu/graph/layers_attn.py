@@ -188,7 +188,7 @@ def _cached_step(ctx: ForwardContext, cfg: LayerConfig, x_arg: Argument,
     n_new = (x_arg.lengths.astype(jnp.int32) if x_arg.lengths is not None
              else jnp.full((B,), Tn, jnp.int32))
     window = (int(cfg.attrs["window"]) if "window" in cfg.attrs else None)
-    if Tn > 1 and "cont" not in cache:
+    if Tn > 1:
         # prefill contract: a multi-token cached call starts from an EMPTY
         # cache (lm_decode feeds the whole prompt once), so attention over
         # the cache degenerates to plain causal self-attention — run it
@@ -196,12 +196,6 @@ def _cached_step(ctx: ForwardContext, cfg: LayerConfig, x_arg: Argument,
         # than cached_attention_step, whose O(Tn*Tmax) dense scores and
         # one-hot scatter would defeat the cache at exactly the long
         # contexts it exists for; k/v land in the cache as a static slice.
-        # A state dict carrying the static "cont" marker opts OUT of this
-        # fast path: the cache is pre-seeded with a committed prefix (the
-        # serving engine's prefix-hit suffix prefill) and the new tokens
-        # continue FROM `pos` — cached_attention_step below already handles
-        # multi-token writes at a per-row dynamic offset with global
-        # causal positions, so the continuation needs no new math
         valid = (jnp.arange(Tn)[None, :] < n_new[:, None])
         # honor an explicit attn_impl like the regular forward does (a
         # config pinned to dense — e.g. to sidestep a pallas issue or for
@@ -448,7 +442,7 @@ def mla_attention_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
 
     n_new = (x_arg.lengths.astype(jnp.int32) if x_arg.lengths is not None
              else jnp.full((B,), T, jnp.int32))
-    whole = not paged and (not dense or (T > 1 and "cont" not in cache))
+    whole = not paged and (not dense or T > 1)
     if whole:
         # the expanded form: plain H-head attention at width nope + rope
         # (v padded to that width so every impl, flash included, takes it)

@@ -17,7 +17,7 @@ makes every compile an event:
     `jit_signatures` samples via `compile_collector()`.  The non-compile
     fast path costs two `_cache_size()` reads and two clock reads — noise
     against a real dispatch.  Attribute access proxies to the wrapped fn,
-    so `.lower()` / `._cache_size()` introspection (bench.py, the HLO
+    so `.lower()` / `._cache_size()` introspection (the HLO
     checks, the serving signature oracles) keeps working.
   * `watch(site, key)` is the context-manager form for compiled paths that
     are not a single jit object (lm_generate's per-(B,P,max_new) scans):

@@ -186,8 +186,8 @@ class MetricHistory:
 class HistorySampler:
     """Background thread: one `sample()` per period, plus an optional
     post-sample hook (the SLO evaluator rides it).  `enabled` is a live
-    flip — bench_serving's overhead probe toggles it mid-run to price
-    the sampler against the decode hot path.  A collector that raises
+    flip, so an overhead probe can toggle it mid-run to price the
+    sampler against the decode hot path.  A collector that raises
     must never kill the health plane: errors are counted and the thread
     keeps ticking."""
 

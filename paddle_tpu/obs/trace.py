@@ -437,7 +437,7 @@ def merge_chrome(sources: list[dict]) -> dict:
 
 #: the process-global tracer every subsystem records into by default —
 #: serving engine spans, trainer barrier windows, pass/eval spans.  Off
-#: until something (tools/serve.py --trace-out, bench.py's overhead probe,
+#: until something (tools/serve.py --trace-out, the benchmark's phase probe,
 #: a test) flips `.enabled`.
 _tracer = Tracer()
 

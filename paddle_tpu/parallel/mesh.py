@@ -67,7 +67,7 @@ def axis_size(mesh: Optional[Mesh], axis: str) -> int:
 def model_mesh(n: int, devices=None) -> Optional[Mesh]:
     """A SERVING tensor-parallel mesh: exactly the first `n` devices on the
     `model` axis, every other axis 1 (the `--mesh model=N` flag of
-    tools/serve.py / bench_serving).  Unlike make_mesh's data=0 remainder
+    tools/serve.py).  Unlike make_mesh's data=0 remainder
     rule this never swallows spare devices into a data axis — replicating
     the KV pools over an unused data axis would defeat the per-chip HBM
     win sharding exists for.  n <= 1 returns None (no mesh: the engine

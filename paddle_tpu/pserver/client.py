@@ -184,7 +184,11 @@ class ParameterClient:
         msg = {"type": "ps_join"}
         if rank is not None:
             msg["rank"] = int(rank)
-        reply = self._ctl_rpc(msg, ("ps_join",))
+        try:
+            reply = self._ctl_rpc(msg, ("ps_join",))
+        except BaseException:
+            self.close()               # refused: hold no shard's socket
+            raise
         self.tid = reply["tid"]
         self.rank = int(reply["rank"])
         self.window = int(reply["window"])
@@ -253,6 +257,7 @@ class ParameterClient:
             # init while the others hold trained state — training on
             # that mix would silently blend pass-N and pass-0 blocks
             fresh = [i for i, f in enumerate(flags) if f]
+            self.close()               # refused: hold no shard's socket
             raise PServerError(
                 f"shard(s) {fresh} had no state and took this trainer's "
                 f"fresh init while the other shard(s) hold trained "

@@ -444,6 +444,7 @@ class ParameterServer:
         self._config_json: Optional[str] = None
         self.membership = Membership()
         self._conn_tid: dict[int, str] = {}      # ctl conn seq -> tid
+        self._writers: set = set()     # open connections, for _shutdown
         # coordinator window state
         self._next_window = 0
         self._contrib: dict[str, dict] = {}      # tid -> contribution
@@ -621,6 +622,11 @@ class ParameterServer:
         self._snap_event.set()
         if self._server is not None:
             self._server.close()
+            # wait_closed() waits for every accepted connection (Python
+            # 3.12): hang up on the peers still connected, or one idle
+            # client holds the shutdown for as long as it likes
+            for writer in list(self._writers):
+                writer.close()
             await self._server.wait_closed()
         self._closed.set()
 
@@ -1058,6 +1064,7 @@ class ParameterServer:
     async def _handle(self, reader, writer) -> None:
         conn = FrameConn(writer)
         first = True
+        self._writers.add(writer)
         try:
             while True:
                 try:
@@ -1083,6 +1090,7 @@ class ParameterServer:
             tid = self._conn_tid.pop(conn.seq, None)
             if tid is not None and self.membership.get(tid) is not None:
                 self._trainer_gone(tid, "connection lost")
+            self._writers.discard(writer)
             try:
                 writer.close()
             except (ConnectionError, RuntimeError):

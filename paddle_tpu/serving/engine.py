@@ -18,9 +18,7 @@ the serving answer (the slot configuration studied in arXiv:2605.25645):
     every decoding slot's inter-token latency behind its own prefill
     program, and the per-step token budget bounds p99 inter-token
     latency by construction.  Chunk count derives from prompt length —
-    any prompt the page pool can hold is admissible, no bucket ceiling.
-    `prefill_chunk=None` restores the legacy whole-prompt bucketed
-    prefill dispatches (`data/feeder._bucket_len`) — the A/B baseline;
+    any prompt the page pool can hold is admissible, no bucket ceiling;
   * per-slot rng streams and sampling knobs are preserved EXACTLY: request
     r's tokens are identical to `lm_generate(..., use_cache=True)` run on r
     alone (same rng key schedule, same sampler semantics via
@@ -101,10 +99,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from paddle_tpu.data.feeder import _bucket_len
 from paddle_tpu.graph.context import TEST
-from paddle_tpu.graph.lm_decode import (_is_probs, _resolve_io_names,
-                                        init_kv_caches, pick_next)
+from paddle_tpu.graph.lm_decode import _is_probs, _resolve_io_names
 from paddle_tpu.obs.compile_watch import get_compile_watch
 from paddle_tpu.obs.flight import get_flight_recorder
 from paddle_tpu.obs.metrics import process_counters
@@ -124,6 +120,10 @@ from paddle_tpu.serving.sampler import pick_next_chain, pick_next_per_slot
 # wasted verify row per window.
 _EWMA_ALPHA = 0.25
 _PROBE_EVERY = 16
+
+_NO_UNCHUNKED = ("prefill_chunk=None (whole-prompt prefill) no longer "
+                 "exists: chunked prefill is the only admission path — "
+                 "pass a positive chunk size")
 
 
 class EngineState(NamedTuple):
@@ -199,26 +199,19 @@ class _Slot:
     slot is still committing its prompt chunk-by-chunk through the mixed
     step (`pos` = prompt tokens committed so far, nothing emitted yet);
     `gen >= 1` is DECODE mode — token 0 was sampled from the last prompt
-    position's logits and the slot advances one token per step.  Legacy
-    (unchunked) admission constructs the slot directly in decode mode
-    with `first_tok` set."""
+    position's logits and the slot advances one token per step."""
 
     __slots__ = ("req", "keys", "pos", "gen", "last_tok", "generated",
                  "admit_seq", "replay_until", "accept_ewma", "probe_tick")
 
     def __init__(self, req: Request, keys: np.ndarray, pos: int,
-                 first_tok: Optional[int], admit_seq: int):
+                 admit_seq: int):
         self.req = req
         self.keys = keys          # [max_new, 2] uint32 — key g samples token g
         self.pos = pos            # tokens resident in the paged cache
-        if first_tok is None:     # prefill mode: nothing emitted yet
-            self.gen = 0
-            self.last_tok = -1
-            self.generated = []
-        else:
-            self.gen = 1          # tokens emitted so far (token 0 at admit)
-            self.last_tok = first_tok  # emitted but not yet in the cache
-            self.generated = [first_tok]
+        self.gen = 0              # tokens emitted so far (0 = prefill mode)
+        self.last_tok = -1        # emitted but not yet in the cache
+        self.generated = []
         self.admit_seq = admit_seq  # admission order — preemption victims
                                     # are youngest-first (least work lost)
         # tokens below this generation index are a post-preemption REPLAY
@@ -250,12 +243,11 @@ class ServingEngine:
                  logits_name: Optional[str] = None,
                  prefix_cache: bool = True,
                  spill_bytes_budget: int = 0,
-                 prefill_chunk: Optional[int] = -1,
+                 prefill_chunk: int = -1,
                  max_step_tokens: Optional[int] = None,
                  spec_k: int = 0, drafter=None,
                  spec_dynamic: bool = False,
                  decode_steps: int = 1,
-                 decode_mode: str = "auto",
                  mesh=None, tracer=None):
         self.executor = executor
         self.input_name, self.logits_name = _resolve_io_names(
@@ -315,8 +307,7 @@ class ServingEngine:
         # n_spilled/n_restored/host_bytes — live on the kv allocator):
         # hits whose prefix needed a host->device restore, and the
         # prefill tokens among `C` served from restored pages — the
-        # number kv.n_restored * page_size must bound (the bench's
-        # restored-vs-saved reconciliation)
+        # number kv.n_restored * page_size must bound
         self.n_restore_hits = 0
         self.restore_tokens_saved = 0
         # cross-replica kv transfer plane (docs/serving.md "Disaggregated
@@ -385,15 +376,6 @@ class ServingEngine:
         self.kv_tokens_attended = 0
         self.kv_tokens_fetched = 0
         self._admit_seq = 0
-        self._prefill_cache: dict[int, object] = {}
-        self._pack_cache: dict[int, object] = {}
-        # prefix-hit compiled pieces: suffix prefill keyed on (prefix
-        # pages, suffix bucket), offset pack keyed on suffix bucket — the
-        # matched token count and in-page offset stay DYNAMIC operands, so
-        # signatures are bounded by (pages_per_slot x buckets), never by
-        # distinct prefix lengths
-        self._prefix_prefill_cache: dict[tuple, object] = {}
-        self._prefix_pack_cache: dict[int, object] = {}
         # -- device-resident EngineState + its host sync machinery --------
         # The compiled steps advance pos/gen/toks on device, so the hot
         # path re-stages NOTHING: the page table re-uploads only when a
@@ -428,28 +410,20 @@ class ServingEngine:
         self._d_eos = self._d_maxnew = None
         # every engine jit reports to the compile watcher (obs/
         # compile_watch.py): the decode step must stay at ONE signature,
-        # per-bucket prefill compiles feed the recompile-storm detector
+        # the mixed step at one per max_step_tokens value
         dec_jit = jax.jit(self._decode_impl, donate_argnums=(1,),
                           **self._step_sharding_kwargs(n_extra=1))
         self._decode_step = get_compile_watch().wrap_jit(
             "serving.decode_step", dec_jit)
-        # CHUNKED PREFILL (mixed prefill/decode steps): prompts commit in
-        # `prefill_chunk`-token chunks INSIDE the regular step — decode
-        # rows and chunk rows pack into one ragged [max_step_tokens] row
-        # list (ops/attention.py:ragged_paged_attention_step), so a long
-        # cold prompt can no longer stall every decoding slot behind its
-        # own prefill dispatch, and the per-step token budget bounds p99
-        # inter-token latency BY CONSTRUCTION under adversarial prompt
-        # mixes.  Compiled signatures: the [S,1] decode step (pure-decode
-        # steps keep it) + ONE mixed-step signature per max_step_tokens
-        # value.  prefill_chunk=None disables chunking (legacy bucketed
-        # whole-prompt prefill); -1 (the default) picks 4*page_size.
+        # CHUNKED PREFILL (the module docstring's mixed step; rows through
+        # ops/attention.py:ragged_paged_attention_step).  Compiled
+        # signatures: the [S,1] decode step (pure-decode steps keep it) +
+        # ONE mixed-step signature per max_step_tokens value.
+        # prefill_chunk=-1 (the default) picks 4*page_size.
         mix_jit = jax.jit(self._mixed_impl, donate_argnums=(1,),
                           **self._step_sharding_kwargs(n_extra=6))
         self._mixed_step = get_compile_watch().wrap_jit(
             "serving.mixed_step", mix_jit)
-        self.prefill_chunk: Optional[int] = None
-        self.max_step_tokens = 0
         self.set_chunking(4 * self.kv.page_size if prefill_chunk == -1
                           else prefill_chunk, max_step_tokens)
         self.n_prefill_chunks = 0
@@ -504,18 +478,6 @@ class ServingEngine:
         # stays honest across decode_steps settings (serving/server.py)
         self.cur_burst = 1
         self.set_decode_steps(decode_steps)
-        # DISPATCH POLICY (`decode_mode`): "auto" (the default) picks the
-        # best dispatch PER FLUSH WINDOW among what is configured — the
-        # spec verify step when any slot drafted (or prefill chunks are
-        # in flight), the k-step scan when the window is pure-decode and
-        # draft-free, the mixed step otherwise — so speculation and
-        # multi-step decode COMPOSE instead of excluding each other
-        # (drafting happens at the scan boundary, chains verify inside
-        # the verify dispatch).  "static" keeps the legacy exclusivity:
-        # spec_k > 0 disables the scan entirely.  A dispatch knob like
-        # decode_steps: emitted tokens are bit-identical either way.
-        self.decode_mode = "auto"
-        self.set_decode_mode(decode_mode)
         # token-budget observability: per-step scheduled-token histogram
         # and the pump-step gap decoding slots actually saw (ms) — the
         # HOL-blocking number chunking exists to bound.  Standalone
@@ -1012,9 +974,9 @@ class ServingEngine:
         compiled step over all slots -> retire.  Returns False when idle
         (nothing in flight and nothing admittable).
 
-        With chunked prefill on, a step with any slot mid-prefill runs
-        the MIXED step: decode rows and prompt-chunk rows pack into one
-        ragged [max_step_tokens] dispatch under the token budget.  Steps
+        A step with any slot mid-prefill runs the MIXED step: decode rows
+        and prompt-chunk rows pack into one ragged [max_step_tokens]
+        dispatch under the token budget.  Steps
         with only decoding slots keep the classic [S, 1] decode step —
         the steady state pays nothing for the chunk machinery.
 
@@ -1041,7 +1003,7 @@ class ServingEngine:
         pool), choose the step's kind, run it."""
         while True:
             # decode-mode slots need their next page; prefill-mode slots
-            # (gen == 0, chunked admission) had their prompt pages secured
+            # (gen == 0) had their prompt pages secured
             # at reservation and can always take chunk rows
             decoding = [s for s in live if self.slots[s].gen > 0]
             filling = [s for s in live if self.slots[s].gen == 0]
@@ -1091,19 +1053,17 @@ class ServingEngine:
             return self._run_mixed_step(live, runnable, filling)
 
         if self.decode_steps > 1 \
-                and (self.spec_k == 0 or self.decode_mode == "auto") \
                 and self._scan_window_ok(runnable, self.decode_steps):
             # pure-decode steady state with multi-step on: ONE scanned
             # dispatch advances every runnable slot up to k tokens.  Any
             # slot that cannot secure pages for its whole window drops
             # THIS dispatch back to the k=1 step below (progress without
             # livelock); mixed/spec steps never scan — the engine returns
-            # to the scanned path once it is pure-decode again.  Under
-            # decode_mode="auto" this is how speculation and multi-step
-            # COMPOSE: the drafter already had its say at this boundary
-            # (above) and proposed nothing, so the window is draft-free
-            # and the scan is the best remaining dispatch; "static"
-            # keeps the legacy spec_k > 0 exclusion.
+            # to the scanned path once it is pure-decode again.  This is
+            # how speculation and multi-step COMPOSE: the drafter already
+            # had its say at this boundary (above) and proposed nothing,
+            # so the window is draft-free and the scan is the best
+            # remaining dispatch.
             return self._run_scan_step(live, runnable, self.decode_steps)
 
         S = len(self.slots)
@@ -1274,8 +1234,8 @@ class ServingEngine:
         compiled mixed step, then bank decode tokens and advance chunk
         cursors.  A slot whose FINAL chunk ran this step emits token 0
         from the last prompt position's logits (keys[0] — the same key
-        schedule the legacy one-dispatch prefill consumed), so chunk
-        rows emit nothing until their final chunk.
+        schedule lm_generate consumes), so chunk rows emit nothing until
+        their final chunk.
 
         The per-step token budget is the HOL-blocking bound: decode rows
         are packed FIRST (every decoding slot advances every step it has
@@ -1293,7 +1253,7 @@ class ServingEngine:
         # slot s banks a sampled token (decode rows + final chunks).  The
         # compiled step advances pos/gen/toks from these; keys and knobs
         # already live in the EngineState (keys[s, gen[s]] — gen 0 at a
-        # final chunk IS the legacy keys[0] decision).
+        # final chunk IS lm_generate's keys[0] decision).
         adv = np.zeros(S, np.int32)
         emit = np.zeros(S, bool)
         r = 0
@@ -1351,8 +1311,8 @@ class ServingEngine:
         accounting can never diverge between them.  A slot whose FINAL
         chunk lands this step gets its sampling row pointed at the last
         prompt position (`sample_row[s]`; the verify step's chain
-        position 0) and `emit[s]` set — token 0 sampled with keys[gen=0],
-        the legacy prefill decision.  Returns (advanced, r')."""
+        position 0) and `emit[s]` set — token 0 sampled with keys[gen=0].
+        Returns (advanced, r')."""
         ps = self.kv.page_size
         advanced = []                        # (slot, n_rows, final)
         for s in sorted(filling, key=lambda s: self.slots[s].admit_seq):
@@ -1565,8 +1525,7 @@ class ServingEngine:
         needs only the page the runnable check already secured)."""
         S = len(self.slots)
         K = self.spec_k
-        T = self.max_step_tokens if self.prefill_chunk is not None \
-            else S * (K + 1)
+        T = self.max_step_tokens
         ps = self.kv.page_size
         row_ids = np.zeros(T, np.int32)
         row_slot = np.full(T, S, np.int32)   # S = the virtual trash row
@@ -1727,18 +1686,6 @@ class ServingEngine:
             self.finish_timing.pop(k, None)
         return out
 
-    def bucket_for(self, prompt_len: int) -> int:
-        """LEGACY-prefill length for a prompt: the feeder bucket,
-        page-aligned, capped at slot capacity — one compiled prefill per
-        distinct value.  Only the prefill_chunk=None path uses buckets;
-        chunked admission derives chunk count from the prompt length, so
-        prompts beyond the largest feeder bucket admit without growing
-        the signature set (validate() rejects only pool-capacity
-        violations)."""
-        ps = self.kv.page_size
-        Lb = -(-_bucket_len(int(prompt_len)) // ps) * ps
-        return min(Lb, self.kv.capacity_tokens)
-
     # -- scheduling internals --------------------------------------------
     def _admit_from_queue(self) -> None:
         for s in range(len(self.slots)):
@@ -1754,10 +1701,7 @@ class ServingEngine:
                 # on it would be invisible to a retry on a different slot)
                 return
             self.queue.popleft()
-            if self.prefill_chunk is not None:
-                self._admit_chunked(s, req, *res)
-            else:
-                self._admit(s, req, *res)
+            self._admit(s, req, *res)
 
     def _reserve(self, s: int, req: Request):
         """Map any cached prefix into empty slot `s` and allocate the
@@ -1927,12 +1871,16 @@ class ServingEngine:
         self.flight.record("kv_recv", pages=n, mounted=added)
         return added
 
-    def _admit(self, s: int, req: Request, C: int = 0, n_pp: int = 0) -> None:
-        """Prefill the prompt (or, on a prefix hit, ONLY its uncached
-        suffix) at a bucket length, pack its K/V into the slot's pages,
-        sample token 0 from the prefill logits (keys[0] — the same key
-        schedule lm_generate consumes).  `C` = tokens already mapped from
-        the prefix index across the slot's first `n_pp` pages.
+    def _admit(self, s: int, req: Request, C: int = 0,
+               n_pp: int = 0) -> None:
+        """Chunk-granular admission — NO prefill dispatch: the slot enters
+        PREFILL mode (gen=0) with its prompt pages already reserved, and
+        the prompt commits in `prefill_chunk`-token rows inside the next
+        mixed steps (_run_mixed_step).  A prefix hit just means the first
+        `C` tokens are already mapped — the chunk cursor starts at C, and
+        a mid-page start writes into the boundary page _reserve COW'd.
+        Token 0 is sampled by the step that runs the FINAL chunk; until
+        then the slot emits nothing.
 
         A re-admission after preemption keeps req._preempted_gen: until the
         deterministic replay catches up, an abort must still report those
@@ -1940,96 +1888,41 @@ class ServingEngine:
         preemption simply overwrites it with the longer prefix."""
         self._tr_end(req.req_id)                       # queued ends here
         p = req.prompt_ids.size
-        ps = self.kv.page_size
         keys = np.asarray(jax.random.split(req.rng, req.max_new))
-        self._count_prefix(req, C, n_pp, p)
-        if C > 0:
-            # suffix-only prefill: the transformer runs on tokens [C, p)
-            # against a cache seeded from the slot's mapped prefix pages
-            # (layers_attn's "cont" continuation path), so prefill compute
-            # scales with the UNCACHED suffix only.  The suffix is
-            # bucketed like cold prefill; C and the in-page offset ride as
-            # dynamic operands.
-            suf = p - C
-            Lb = min(-(-_bucket_len(suf) // ps) * ps,
-                     self.kv.capacity_tokens - C)
-            self._tr_begin(req.req_id, "prefill", bucket=Lb,
-                           prefix_tokens=C)
-            ids = np.zeros((1, Lb), np.int32)
-            ids[0, :suf] = req.prompt_ids[C:]
-            last, kv_suffix = self._prefix_prefill_fn(n_pp, Lb)(
-                self.params, self.kv.pools,
-                jnp.asarray(self.kv.table[s, :n_pp].copy()),
-                jnp.asarray(ids), jnp.asarray([suf], np.int32),
-                jnp.asarray([C], np.int32))
-            tok0 = int(np.asarray(pick_next(
-                last, jnp.asarray(keys[0]),
-                temperature=req.temperature, top_k=req.top_k,
-                top_p=req.top_p, is_probs=self._probs))[0])
-            # suffix K/V scatter from in-page offset C % ps across the
-            # slot's remaining pages (trash page 0 beyond the prompt)
-            n_span = Lb // ps + 1
-            pages = np.zeros(n_span, np.int32)
-            m_b = C // ps
-            span = min(n_span, self.kv.pages_for(p) - m_b)
-            pages[:span] = self.kv.table[s, m_b:m_b + span]
-            self.kv.pools = self._prefix_pack_fn(Lb)(
-                self.kv.pools, kv_suffix, jnp.asarray(pages),
-                jnp.asarray(C % ps, np.int32))
-            self._tr_end(req.req_id)
-        else:
-            Lb = self.bucket_for(p)
-            self._tr_begin(req.req_id, "prefill", bucket=Lb)
-            ids = np.zeros((1, Lb), np.int32)
-            ids[0, :p] = req.prompt_ids
-            last, kv_prompt = self._prefill_fn(Lb)(
-                self.params, jnp.asarray(ids),
-                jnp.asarray([p], np.int32))
-            tok0 = int(np.asarray(pick_next(
-                last, jnp.asarray(keys[0]),
-                temperature=req.temperature, top_k=req.top_k,
-                top_p=req.top_p, is_probs=self._probs))[0])
-
-            pages = np.zeros(Lb // ps, np.int32)   # 0 = trash for pad
-            n_real = self.kv.pages_for(p)
-            pages[:n_real] = self.kv.table[s, :n_real]
-            self.kv.pools = self._pack_fn(Lb)(self.kv.pools, kv_prompt,
-                                              jnp.asarray(pages))
-            self._tr_end(req.req_id)
-        self._admit_seq += 1
-        sl = _Slot(req, keys, pos=p, first_tok=tok0,
-                   admit_seq=self._admit_seq)
-        self.slots[s] = sl
-        self._slots_dirty = True
-        self.flight.record("admit", req=str(req.req_id), slot=s,
-                           bucket=Lb, prompt_len=p,
-                           pages=int(self.kv.pages_for(p)))
-        self._begin_stream(s, tok0)
-
-    def _count_prefix(self, req: Request, C: int, n_pp: int, p: int) -> None:
-        """Prefix-index hit/miss accounting shared by both admission
-        paths (chunked admission counts the SAME tokens-saved: the first
-        `C` prompt tokens never take a chunk row)."""
-        if self.prefix is None:
-            return
-        if C > 0:
+        if self.prefix is not None and C > 0:
             self.n_prefix_hits += 1
             self.prefill_tokens_saved += C
             self._tr_instant(req.req_id, "prefix_hit", n_pages=n_pp,
                              tokens=C)
             self.flight.record("prefix_hit", req=str(req.req_id),
                                pages=n_pp, tokens=C, suffix=p - C)
-        else:
+        elif self.prefix is not None:
             self.n_prefix_misses += 1
             self.flight.record("prefix_miss", req=str(req.req_id),
                                prompt_len=int(p))
+        self._admit_seq += 1
+        self.slots[s] = _Slot(req, keys, pos=C, admit_seq=self._admit_seq)
+        self._slots_dirty = True
+        self._tr_begin(req.req_id, "prefill",
+                       chunk=int(self.prefill_chunk), prompt_len=p,
+                       prefix_tokens=C)
+        self.flight.record("admit", req=str(req.req_id), slot=s,
+                           prompt_len=p, chunk=int(self.prefill_chunk),
+                           prefix_tokens=C,
+                           pages=int(self.kv.pages_for(p)))
 
-    def _begin_stream(self, s: int, tok0: int) -> None:
-        """Stream token 0 of a freshly-prefilled slot (legacy one-dispatch
-        prefill or the mixed step's final chunk): open the decode/replay
-        lifecycle phase, fire on_token(.., 0), retire on eos/max_new=1."""
+    def _emit_first(self, s: int, tok0: int) -> None:
+        """Final-chunk emission: the slot's whole prompt is committed and
+        `tok0` was sampled from the last prompt position's logits with
+        keys[0] — the same key schedule lm_generate consumes.  Flips the
+        slot into decode mode and streams token 0: opens the decode/replay
+        lifecycle phase, fires on_token(.., 0), retires on eos/max_new=1."""
         sl = self.slots[s]
         req = sl.req
+        sl.gen = 1
+        sl.last_tok = tok0
+        sl.generated = [tok0]
+        self._tr_end(req.req_id)                       # prefill ends here
         stash = req._preempted_gen or []
         if stash:
             # tokens 0..len(stash)-1 re-emit deterministically — a replay
@@ -2043,44 +1936,6 @@ class ServingEngine:
             self.on_token(req.req_id, tok0, 0)
         if tok0 == req.eos_id or req.max_new == 1:
             self._retire(s)
-
-    def _admit_chunked(self, s: int, req: Request, C: int = 0,
-                       n_pp: int = 0) -> None:
-        """Chunk-granular admission — NO prefill dispatch: the slot enters
-        PREFILL mode (gen=0) with its prompt pages already reserved, and
-        the prompt commits in `prefill_chunk`-token rows inside the next
-        mixed steps (_run_mixed_step).  A prefix hit just means the first
-        `C` tokens are already mapped — the chunk cursor starts at C, and
-        a mid-page start writes into the boundary page _reserve COW'd.
-        Token 0 is sampled by the step that runs the FINAL chunk; until
-        then the slot emits nothing."""
-        self._tr_end(req.req_id)                       # queued ends here
-        p = req.prompt_ids.size
-        keys = np.asarray(jax.random.split(req.rng, req.max_new))
-        self._count_prefix(req, C, n_pp, p)
-        self._admit_seq += 1
-        self.slots[s] = _Slot(req, keys, pos=C, first_tok=None,
-                              admit_seq=self._admit_seq)
-        self._slots_dirty = True
-        self._tr_begin(req.req_id, "prefill",
-                       chunk=int(self.prefill_chunk), prompt_len=p,
-                       prefix_tokens=C)
-        self.flight.record("admit", req=str(req.req_id), slot=s,
-                           prompt_len=p, chunk=int(self.prefill_chunk),
-                           prefix_tokens=C,
-                           pages=int(self.kv.pages_for(p)))
-
-    def _emit_first(self, s: int, tok0: int) -> None:
-        """Final-chunk emission: the slot's whole prompt is committed and
-        `tok0` was sampled from the last prompt position's logits with
-        keys[0] — the exact decision the legacy one-dispatch prefill
-        made.  Flips the slot into decode mode and streams token 0."""
-        sl = self.slots[s]
-        sl.gen = 1
-        sl.last_tok = tok0
-        sl.generated = [tok0]
-        self._tr_end(sl.req.req_id)                    # prefill ends here
-        self._begin_stream(s, tok0)
 
     def _preempt(self, s: int) -> None:
         sl = self.slots[s]
@@ -2140,30 +1995,24 @@ class ServingEngine:
         if self.prefix is not None:
             self.prefix.clear()
 
-    def set_chunking(self, prefill_chunk: Optional[int],
+    def set_chunking(self, prefill_chunk: int,
                      max_step_tokens: Optional[int] = None) -> None:
         """Configure chunked prefill (idle engine only — a live slot may
-        be mid-chunk).  `prefill_chunk=None` disables chunking: prompts
-        prefill through the legacy bucketed one-dispatch paths — the
-        baseline side of bench_serving's heavy-tail A/B.  Enabled (the
-        default: 4*page_size), prompts commit in chunk rows inside the
-        mixed step under `max_step_tokens` (default prefill_chunk +
-        num_slots): one row per decoding slot plus up to prefill_chunk
-        rows per chunking prompt, never more than the budget per step —
-        the p99 inter-token bound.  Each distinct max_step_tokens value
+        be mid-chunk).  Prompts commit in `prefill_chunk`-token rows
+        (default 4*page_size) inside the mixed step under
+        `max_step_tokens` (default prefill_chunk + num_slots): one row per
+        decoding slot plus up to prefill_chunk rows per chunking prompt,
+        never more than the budget per step — the p99 inter-token bound.  Each distinct max_step_tokens value
         is one mixed-step signature; hold it fixed in production."""
         assert all(sl is None for sl in self.slots) and not self.queue, \
             "set_chunking requires an idle engine"
-        self._mst_explicit = max_step_tokens is not None
         if prefill_chunk is None:
-            self.prefill_chunk = None
-            self.max_step_tokens = 0
-            return
+            raise ValueError(_NO_UNCHUNKED)
+        self._mst_explicit = max_step_tokens is not None
         prefill_chunk = int(prefill_chunk)
         if prefill_chunk <= 0:
             raise ValueError(
-                f"prefill_chunk must be positive (or None to disable "
-                f"chunking), got {prefill_chunk}")
+                f"prefill_chunk must be positive, got {prefill_chunk}")
         prefill_chunk = min(prefill_chunk, self.kv.capacity_tokens)
         S = len(self.slots)
         mst = self._default_budget(prefill_chunk) \
@@ -2182,15 +2031,14 @@ class ServingEngine:
         plus a FULL chain per slot — `chunk + S` with speculation off
         (the classic default), `chunk + S*(spec_k+1)` with it on, so a
         default deployment's draft depth is never silently throttled to
-        the chunk headroom (the bench pins the same formula)."""
+        the chunk headroom."""
         return prefill_chunk + len(self.slots) * (
             int(getattr(self, "spec_k", 0)) + 1)
 
     def set_speculation(self, spec_k: int, drafter=None,
                         dynamic: Optional[bool] = None) -> None:
         """Configure speculative decoding (idle engine only — a live
-        chain would straddle the toggle).  `spec_k=0` disables — the
-        baseline side of bench_serving's --spec-k A/B; `spec_k > 0`
+        chain would straddle the toggle).  `spec_k=0` disables; `spec_k > 0`
         drafts up to k lookahead tokens per decoding slot per step
         (serving/drafter.py's prompt-lookup NgramDrafter by default;
         pass `drafter` for anything with a `.propose(ctx, k)` — a
@@ -2211,11 +2059,10 @@ class ServingEngine:
         self.spec_k = spec_k
         if dynamic is not None:
             self.spec_dynamic = bool(dynamic)
-        if self.prefill_chunk is not None and not self._mst_explicit:
+        if not self._mst_explicit:
             # a DEFAULTED budget follows the speculation depth (chunk +
             # S*(k+1)): otherwise `--spec-k` deployments would silently
-            # throttle draft rows to the chunk headroom, and the banked
-            # bench number would not represent a default deployment.
+            # throttle draft rows to the chunk headroom.
             # An explicit budget is the operator's pin — untouched.
             self.max_step_tokens = self._default_budget(
                 self.prefill_chunk)
@@ -2244,31 +2091,10 @@ class ServingEngine:
         return getattr(self.drafter, "kind", None) \
             if self.drafter is not None else None
 
-    def set_decode_mode(self, mode: str) -> None:
-        """Configure the step() dispatch policy (idle engine only, like
-        every dispatch knob).  "auto" (the default) picks per flush
-        window between the spec verify step, the pure-decode k-step
-        scan, and the mixed step — speculation and multi-step COMPOSE: a
-        window where the drafter proposes runs the verify step, a
-        draft-free pure-decode window runs the scan, and filling slots
-        drop to the mixed step so admissions never stall.  "static"
-        keeps the legacy exclusivity (spec_k > 0 disables the scan) for
-        apples-to-apples A/B against pre-auto behavior.  Tokens are
-        bit-identical across modes — this chooses dispatch shapes, never
-        content — which is also why checkpoints deliberately do not pin
-        it (restore composes with either mode, like decode_steps)."""
-        assert all(sl is None for sl in self.slots) and not self.queue, \
-            "set_decode_mode requires an idle engine"
-        if mode not in ("auto", "static"):
-            raise ValueError(
-                f"decode_mode must be 'auto' or 'static', got {mode!r}")
-        self.decode_mode = mode
-
     def set_decode_steps(self, decode_steps: int) -> None:
         """Configure multi-step decode (idle engine only — a live slot's
         host mirrors must be at a scan boundary).  `decode_steps=1`
-        disables — the baseline side of bench_serving's --decode-steps
-        A/B; k > 1 runs up to k decode bodies per dispatch inside ONE
+        disables; k > 1 runs up to k decode bodies per dispatch inside ONE
         jitted lax.scan whenever the engine is pure-decode.  Emitted
         tokens are IDENTICAL either way; only dispatches-per-token (and
         the streaming burst size) change.  Each distinct k is ONE scanned
@@ -2291,8 +2117,8 @@ class ServingEngine:
                 if self.n_spec_drafted else 0.0)
 
     def set_prefix_cache(self, enabled: bool) -> None:
-        """A/B knob (bench_serving --prefix-skew measures the same engine
-        with the cache off, then on): disabling detaches AND empties the
+        """A/B knob (the same engine with the prefix cache off, then
+        on): disabling detaches AND empties the
         index — every node's page drops its cached retention, so pages
         still mapped by live slots stay with their slots and free through
         the normal release flow — leaving nothing for a baseline run to
@@ -2319,8 +2145,8 @@ class ServingEngine:
         self.kv.on_page_pressure = None
 
     def set_spill_budget(self, spill_bytes_budget: int) -> None:
-        """A/B knob (bench_serving --spill-budget measures the same
-        engine spill-off, then on): sets the host tier's byte budget.
+        """A/B knob (the same engine spill-off, then on): sets the host
+        tier's byte budget.
         Shrinking below current residency drops LRU HOST leaves until
         the tier fits (0 drains it entirely) — never device state, so
         an idle-engine flip is allocator-exact either way."""
@@ -2448,6 +2274,8 @@ class ServingEngine:
         corrupt page accounting, so it raises instead).  Device state
         re-uploads lazily through the ordinary dirty-sync paths."""
         cfg = snap["config"]
+        if cfg.get("prefill_chunk") is None:
+            raise ValueError("restore_state: " + _NO_UNCHUNKED)
         mine = {"num_slots": len(self.slots),
                 "page_size": self.kv.page_size,
                 "pages_per_slot": self.kv.pages_per_slot,
@@ -2636,8 +2464,8 @@ class ServingEngine:
     def _slot_keys(self, st: EngineState) -> jnp.ndarray:
         """Each slot's key for THIS step: keys[s, gen[s]] — key g samples
         token g, so a paused slot (gen frozen) consumes nothing and a
-        final prompt chunk (gen still 0) samples with keys[0], exactly the
-        legacy prefill decision."""
+        final prompt chunk (gen still 0) samples with keys[0], exactly
+        lm_generate's first decision."""
         g = jnp.clip(st.gen, 0, st.keys.shape[1] - 1)
         return jnp.take_along_axis(st.keys, g[:, None, None], axis=1)[:, 0]
 
@@ -2699,7 +2527,7 @@ class ServingEngine:
         site `serving.scan_step` distinguishes k (static ints are part
         of the call signature, where a partial-bound k would vanish
         from the aval-only view) — the recompile-storm detector sees a
-        knob-churning deployment the same way it sees bucket churn."""
+        knob-churning deployment the same way it sees budget churn."""
         if self._scan_step is None:
             scan_jit = jax.jit(self._scan_impl, static_argnums=(0,),
                                donate_argnums=(2,),
@@ -2846,133 +2674,3 @@ class ServingEngine:
                              gen=st.gen + gen_adv, keys=st.keys,
                              temp=st.temp, topk=st.topk, topp=st.topp)
         return new_st, sampled, acc
-
-    def _prefill_fn(self, Lb: int):
-        """Jitted prompt prefill for bucket length Lb — compiled once per
-        BUCKET (the feeder's _bucket_len grid), not per prompt length."""
-        fn = self._prefill_cache.get(Lb)
-        if fn is None:
-            executor = self.executor
-            input_name, logits_name = self.input_name, self.logits_name
-            attn_layers = list(self.kv.pools)
-            parts = {name: tuple(pool)
-                     for name, pool in self.kv.pools.items()}
-
-            def prefill(params, ids, n):               # ids [1, Lb], n [1]
-                state = init_kv_caches(executor, 1, Lb)
-                outputs, _, state = executor.forward(
-                    params, {input_name: Argument(ids=ids, lengths=n)},
-                    state, TEST, None)
-                logits = outputs[logits_name].value
-                last = jnp.take_along_axis(
-                    logits, (n - 1)[:, None, None], axis=1)[:, 0, :]
-                return last, {name: {part: state[name][part]
-                                     for part in parts[name]}
-                              for name in attn_layers}
-
-            fn = self._prefill_cache[Lb] = get_compile_watch().wrap_jit(
-                "serving.prefill", jax.jit(prefill))
-        return fn
-
-    def _pack_fn(self, Lb: int):
-        """Jitted page writer: scatter a bucket-length prompt's K/V into
-        the slot's pages (page j of the prompt -> physical pages[j]; pad
-        pages target the trash page 0)."""
-        fn = self._pack_cache.get(Lb)
-        if fn is None:
-            ps = self.kv.page_size
-            n_pages = Lb // ps
-            specs = self.kv.layer_specs
-
-            def pack(pools, kv_prompt, pages):
-                return {name: {
-                    part: a.at[pages].set(
-                        kv_prompt[name][part][0, :Lb]
-                        .reshape((n_pages, ps) + row).astype(a.dtype))
-                    for part, a in pools[name].items()}
-                    for name, row in specs.items()}
-
-            fn = self._pack_cache[Lb] = get_compile_watch().wrap_jit(
-                "serving.pack", jax.jit(pack, donate_argnums=(0,),
-                                        **self._pools_out_kwargs()))
-        return fn
-
-    def _prefix_prefill_fn(self, n_pp: int, Lb: int):
-        """Jitted SUFFIX prefill for a prefix-hit admission: gather the
-        matched prefix K/V out of `n_pp` pool pages into a dense seed
-        cache, then run the stack on the Lb-bucket suffix tokens through
-        layers_attn's continuation path (the static "cont" marker routes
-        multi-token cached attention through cached_attention_step, which
-        scatters at the dynamic offset `c` and masks on global positions).
-        Compiled once per (prefix pages, suffix bucket); the matched token
-        count `c` and valid suffix length `n` are dynamic operands.
-        Returns (last-valid-position logits, per-layer suffix K/V sliced
-        at [c, c+Lb) — the shape _prefix_pack_fn scatters)."""
-        key = (n_pp, Lb)
-        fn = self._prefix_prefill_cache.get(key)
-        if fn is None:
-            executor = self.executor
-            input_name, logits_name = self.input_name, self.logits_name
-            specs = self.kv.layer_specs
-            ps = self.kv.page_size
-            Cpad = n_pp * ps
-            dtype = jnp.dtype(executor.compute_dtype) \
-                if executor.compute_dtype else jnp.float32
-
-            def prefill(params, pools, ctx_pages, ids, n, c):
-                # ctx_pages [n_pp] physical pages; positions [c, Cpad) of
-                # the seed hold the boundary page's beyond-match tokens —
-                # garbage for THIS request, but cached_attention_step's
-                # scatter overwrites [c, c+Lb) before attention and its
-                # causal mask never reaches the rest
-                state = {}
-                for name, row in specs.items():
-                    state[name] = {
-                        part: jnp.zeros((1, Cpad + Lb) + row, dtype)
-                        .at[:, :Cpad].set(
-                            a[ctx_pages].reshape((1, Cpad) + row))
-                        for part, a in pools[name].items()}
-                    state[name].update(pos=c, cont=())
-                outputs, _, state = executor.forward(
-                    params, {input_name: Argument(ids=ids, lengths=n)},
-                    state, TEST, None)
-                logits = outputs[logits_name].value
-                last = jnp.take_along_axis(
-                    logits, (n - 1)[:, None, None], axis=1)[:, 0, :]
-                return last, {
-                    name: {part: jax.lax.dynamic_slice_in_dim(
-                        state[name][part], c[0], Lb, axis=1)
-                        for part in pools[name]}
-                    for name in specs}
-
-            fn = self._prefix_prefill_cache[key] = \
-                get_compile_watch().wrap_jit(
-                    "serving.prefix_prefill", jax.jit(prefill))
-        return fn
-
-    def _prefix_pack_fn(self, Lb: int):
-        """Jitted offset page writer: scatter an Lb-token suffix's K/V into
-        the slot's pages starting at dynamic in-page offset `off` — token i
-        lands in pages[(off + i) // ps] at row (off + i) % ps.  Pages past
-        the prompt's real span are the trash page 0 (same padded-bucket
-        discipline as the cold pack)."""
-        fn = self._prefix_pack_cache.get(Lb)
-        if fn is None:
-            ps = self.kv.page_size
-            specs = self.kv.layer_specs
-
-            def pack(pools, kv_suffix, pages, off):
-                idx = off + jnp.arange(Lb)
-                phys = pages[idx // ps]                       # [Lb]
-                row = idx % ps
-                return {name: {
-                    part: a.at[phys, row].set(
-                        kv_suffix[name][part][0].astype(a.dtype))
-                    for part, a in pools[name].items()}
-                    for name in specs}
-
-            fn = self._prefix_pack_cache[Lb] = get_compile_watch().wrap_jit(
-                "serving.prefix_pack",
-                jax.jit(pack, donate_argnums=(0,),
-                        **self._pools_out_kwargs()))
-        return fn

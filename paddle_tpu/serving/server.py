@@ -935,7 +935,6 @@ class ServingServer:
             "spec_dynamic": bool(self.engine.spec_dynamic),
             "drafter": self.engine.drafter_kind,
             "decode_steps": int(self.engine.decode_steps),
-            "decode_mode": self.engine.decode_mode,
             "role": self.role,
             "wedge_threshold_s": self.wedge_threshold_s,
             "postmortem_dir": self.postmortem_dir,
@@ -1575,7 +1574,6 @@ class ServingServer:
             # multi-step decode: the A/B-able knobs + scan dispatch
             # counters (flushes = boundaries, steps = body iterations)
             "decode_steps_k": eng.decode_steps,
-            "decode_mode": eng.decode_mode,
             "scan_steps": eng.n_scan_steps,
             "scan_flushes": eng.n_scan_flushes,
             # sharding: model-axis shard count + per-device pool bytes

@@ -1229,8 +1229,7 @@ class Trainer:
                     "benchmark(scan=True) hosts the optimizer inside one "
                     "compiled dispatch — incompatible with the remote "
                     "(parameter-server) updater; benchmark with "
-                    "scan=False or tools/train_dist.py / bench.py "
-                    "train_dist")
+                    "scan=False or tools/train_dist.py")
             return self._benchmark_scan(batch_list, warmup, n_samples)
         for b in batch_list[:warmup]:
             self._dispatch_step(b)

@@ -48,11 +48,9 @@ def _assert_exact(tr, reqs, results):
 
 def _assert_sigs(eng):
     """The tentpole's signature discipline: one decode signature, at most
-    one mixed signature, NO per-bucket prefill programs."""
+    one mixed signature — prompt length never mints a program."""
     assert eng._decode_step._cache_size() == 1
     assert eng._mixed_step._cache_size() <= 1
-    assert not eng._prefill_cache and not eng._pack_cache, \
-        "chunked mode compiled a legacy per-bucket prefill program"
 
 
 # ---------------------------------------------------------------------------
@@ -299,9 +297,10 @@ def test_prompts_beyond_the_largest_feeder_bucket_admit_and_serve(tr):
 
 
 def test_set_chunking_validates_and_toggles(tr):
-    """set_chunking is the A/B knob: budget must exceed num_slots,
-    toggling to None restores the legacy bucketed path, and both modes
-    produce identical tokens for the same request."""
+    """set_chunking is the A/B knob: budget must exceed num_slots, the
+    chunk must be positive, None (the whole-prompt prefill that no longer
+    exists) is refused by name, and two chunk sizes produce identical
+    tokens for the same request."""
     rng = np.random.default_rng(8)
     eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
                         max_context=32, prefix_cache=False)
@@ -310,13 +309,89 @@ def test_set_chunking_validates_and_toggles(tr):
         eng.set_chunking(4, max_step_tokens=2)
     with pytest.raises(ValueError, match="must be positive"):
         eng.set_chunking(0)
+    with pytest.raises(ValueError, match="chunked prefill is the only"):
+        eng.set_chunking(None)
+    assert eng.prefill_chunk == 16 and eng.max_step_tokens == 18, \
+        "a refused set_chunking must leave the engine as it was"
     prompt = rng.integers(2, 23, 9).astype(np.int32)
-    chunked = eng.run([Request("r", prompt.copy(), max_new=5)])["r"]
-    eng.set_chunking(None)
-    assert eng.prefill_chunk is None
-    legacy = eng.run([Request("r", prompt.copy(), max_new=5)])["r"]
-    np.testing.assert_array_equal(chunked, legacy)
-    assert len(eng._prefill_cache) > 0, "legacy mode never bucketed"
+    wide = eng.run([Request("r", prompt.copy(), max_new=5)])["r"]
+    assert eng.n_prefill_chunks == 1
+    eng.set_chunking(4)
+    assert eng.prefill_chunk == 4 and eng.max_step_tokens == 6
+    narrow = eng.run([Request("r", prompt.copy(), max_new=5)])["r"]
+    assert eng.n_prefill_chunks == 1 + 3
+    np.testing.assert_array_equal(wide, narrow)
+
+
+def test_unchunked_prefill_is_refused_at_construction(tr):
+    """prefill_chunk=None selected the whole-prompt prefill programs; they
+    are gone, and asking for them says so instead of defaulting."""
+    with pytest.raises(ValueError, match="chunked prefill is the only"):
+        ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
+                      max_context=32, prefill_chunk=None)
+
+
+def test_restore_refuses_a_snapshot_taken_unchunked(tr):
+    """A checkpoint whose engine ran prefill_chunk=None must not resume
+    into a mode that no longer exists: the refusal names the cause, not
+    a bare configuration diff."""
+    kw = dict(num_slots=2, page_size=4, max_context=32)
+    donor = ServingEngine(tr.executor, tr.params, **kw)
+    donor.add_request(Request("r", np.arange(2, 11, dtype=np.int32),
+                              max_new=4))
+    donor.step()
+    snap = donor.checkpoint_state()
+    snap["config"]["prefill_chunk"] = None
+    with pytest.raises(ValueError, match="chunked prefill is the only"):
+        ServingEngine(tr.executor, tr.params, **kw).restore_state(snap)
+
+
+def test_decode_mode_is_no_longer_an_argument(tr):
+    """The dispatch policy is not an option: the scan composes with
+    speculation always, and the retired argument is a TypeError rather
+    than a silently ignored keyword."""
+    with pytest.raises(TypeError, match="decode_mode"):
+        ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
+                      max_context=32, decode_mode="auto")
+    assert not hasattr(ServingEngine, "set_decode_mode")
+
+
+@pytest.mark.parametrize("length", ["chunk-1", "chunk", "chunk+1",
+                                    "2*chunk+3"])
+def test_chunk_boundary_lengths_exact_cold_and_on_a_prefix_hit(tr, length):
+    """The lengths the whole-prompt prefill tests used, on the one path
+    that remains: a prompt just under, at, just over one chunk and over
+    two chunks bit-matches the cold lm_generate oracle — and so does the
+    SAME prompt admitted a second time, when its leading pages are mapped
+    from the prefix index and only the suffix takes chunk rows (the case
+    the suffix-prefill program served)."""
+    chunk, ps = 8, 4
+    p = {"chunk-1": chunk - 1, "chunk": chunk, "chunk+1": chunk + 1,
+         "2*chunk+3": 2 * chunk + 3}[length]
+    rng = np.random.default_rng(p)
+    prompt = rng.integers(2, 23, p).astype(np.int32)
+    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=ps,
+                        max_context=48, prefill_chunk=chunk)
+    cold = Request("cold", prompt.copy(), max_new=6, temperature=0.8,
+                   top_k=5, rng=jax.random.PRNGKey(p))
+    res = eng.run([cold])
+    _assert_exact(tr, [cold], res)
+    assert eng.n_prefix_hits == 0
+    assert eng.n_prefill_chunks == -(-p // chunk)
+    chunks0 = eng.n_prefill_chunks
+    warm = Request("warm", prompt.copy(), max_new=6, temperature=0.8,
+                   top_k=5, rng=jax.random.PRNGKey(p))
+    res = eng.run([warm])
+    _assert_exact(tr, [warm], res)
+    # the retired run donated the prompt's pages and its output's, so
+    # the walk matches all but the last prompt token (one always
+    # prefills): the suffix is one row starting mid-page, on the copy of
+    # the boundary page that reservation made
+    assert eng.n_prefix_hits == 1 and eng.prefill_tokens_saved == p - 1
+    assert eng.n_prefill_chunks - chunks0 == 1
+    assert eng.kv.n_cow == (1 if (p - 1) % ps else 0)
+    _assert_sigs(eng)
+    eng.kv.check_reclaimed()
 
 
 # ---------------------------------------------------------------------------

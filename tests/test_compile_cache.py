@@ -11,7 +11,7 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ENTRY_POINTS = ["paddle_tpu/trainer_main.py", "tools/serve.py",
-                "tools/train_dist.py", "bench.py", "chip_smoke.py"]
+                "tools/train_dist.py", "chip_smoke.py"]
 
 _PROBE = """
 import jax

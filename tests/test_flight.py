@@ -72,7 +72,7 @@ def test_wrap_jit_detects_compiles_by_cache_growth_and_proxies_attrs():
     snap = cw.snapshot()["t.site"]
     assert snap["compiles"] == 2 and snap["signatures"] == 2
     assert snap["storms"] == 0
-    # introspection flows through the proxy (bench.py / oracle tests use
+    # introspection flows through the proxy (the oracle tests use
     # ._cache_size() and .lower() on the wrapped object)
     assert fn._cache_size() == 2
     assert fn.lower() == "lowered"
@@ -125,10 +125,11 @@ def test_compile_collector_emits_catalog_names_per_site():
 
 
 def test_bucket_churn_fires_storm_exactly_once(monkeypatch):
-    """The acceptance storm: REAL per-bucket prefill compiles.  Prompts
-    spanning 3 feeder buckets (8/16/32) against storm_n=3 fire the
-    detector exactly once at serving.prefill — and the decode step stays
-    one signature throughout (no storm there)."""
+    """The acceptance storm: REAL compiles at a site that churns.  The
+    mixed step is one signature per token budget, so three distinct
+    max_step_tokens values against storm_n=3 fire the detector exactly
+    once at serving.mixed_step — and the decode step stays one signature
+    throughout (no storm there)."""
     from paddle_tpu.config.parser import parse_config
     from paddle_tpu.serving import Request, ServingEngine
     from paddle_tpu.trainer.trainer import Trainer
@@ -140,20 +141,17 @@ def test_bucket_churn_fires_storm_exactly_once(monkeypatch):
                        "vocab=31,dim=16,layers=1,heads=2,batch_size=4")
     tr = Trainer(cfg, seed=7)
     rng = np.random.default_rng(0)
-    # lengths 3 -> bucket 8, 12 -> 16, 20 -> 32 (feeder _bucket_len)
-    prompts = [rng.integers(2, 31, n).astype(np.int32)
-               for n in (3, 12, 20)]
-    reqs = [Request(i, p, max_new=2) for i, p in enumerate(prompts)]
-    # prefill_chunk=None: the LEGACY bucketed path is the one that churns
-    # per-bucket compiles (chunked admission has no prefill programs)
     eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=8,
-                        max_context=64, prefill_chunk=None)
-    eng.run(reqs)
+                        max_context=64)
+    for i, budget in enumerate((10, 12, 14)):
+        eng.set_chunking(8, max_step_tokens=budget)
+        eng.run([Request(i, rng.integers(2, 31, 12).astype(np.int32),
+                         max_new=3)])
 
     snap = fresh.snapshot()
-    assert snap["serving.prefill"]["signatures"] == 3
-    assert snap["serving.prefill"]["storms"] == 1, \
-        "3 distinct prefill signatures at storm_n=3 must fire EXACTLY once"
+    assert snap["serving.mixed_step"]["signatures"] == 3
+    assert snap["serving.mixed_step"]["storms"] == 1, \
+        "3 distinct mixed-step signatures at storm_n=3 must fire EXACTLY once"
     assert snap["serving.decode_step"]["signatures"] == 1
     assert snap["serving.decode_step"].get("storms", 0) == 0
 

@@ -187,8 +187,8 @@ def test_absorbed_over_a_dense_cache_equals_expanded(model):
 
 
 @pytest.mark.parametrize("chunk,kernel", [(4, False), (4, True),
-                                          (None, False)],
-                         ids=["chunked-jnp", "chunked-kernel", "legacy"])
+                                          (32, False)],
+                         ids=["chunked-jnp", "chunked-kernel", "one-chunk"])
 def test_engine_greedy_tokens_match_lm_generate(model, chunk, kernel,
                                                 monkeypatch):
     import jax
@@ -202,7 +202,8 @@ def test_engine_greedy_tokens_match_lm_generate(model, chunk, kernel,
             for i, n in enumerate((3, 19, 9, 17))]
     eng = ServingEngine(ex, w, num_slots=2, page_size=4, max_context=32,
                         prefill_chunk=chunk,
-                        max_step_tokens=7 if chunk else None)
+                        # one-chunk: a whole prompt in ONE mixed step
+                        max_step_tokens=7 if chunk == 4 else None)
     results = eng.run(reqs)
     for r in reqs:
         toks, lens = lm_generate(ex, w, r.prompt_ids[None, :],

@@ -89,6 +89,9 @@ FLASH_CASES = {
     "lm": dict(B=64, T=512, H=8, H_kv=8),
     "gqa": dict(B=64, T=512, H=8, H_kv=2),
     "ragged_T500": dict(B=64, T=500, H=8, H_kv=8),
+    # the train cells' own shape (benchmark/configs/starcoder2-3b-train.json:
+    # 2 sequences of 4,096 a chip, 24 query heads over 2 KV heads of 128)
+    "train_cell": dict(B=2, T=4096, H=24, H_kv=2, D=128),
 }
 
 
@@ -120,11 +123,14 @@ def test_flash_backward(mosaic, case):
            *_flash_shapes(**FLASH_CASES[case]))
 
 
-def test_flash_under_data_mesh(topo, no_persistent_cache, monkeypatch):
+@pytest.mark.parametrize("case,batch", [("lm", 64), ("train_cell", 8)])
+def test_flash_under_data_mesh(topo, no_persistent_cache, monkeypatch,
+                               case, batch):
     """`--mesh_shape=data:4` with attn_impl=flash: lowering the bare kernel
     over a 4-chip mesh raises "Mosaic kernels cannot be automatically
     partitioned"; parallel/context.py:flash_attn_fn wraps it in shard_map.
-    Forward and backward for four described chips."""
+    Forward and backward for four described chips, at the LM trainer's
+    shape and at the seq4k-dp4 cell's (8 sequences a step, 2 a chip)."""
     import functools
 
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -143,7 +149,7 @@ def test_flash_under_data_mesh(topo, no_persistent_cache, monkeypatch):
 
     sh = NamedSharding(mesh, P("data"))
     args = [jax.ShapeDtypeStruct(s, d, sharding=sh)
-            for s, d in _flash_shapes(**FLASH_CASES["lm"])]
+            for s, d in _flash_shapes(**dict(FLASH_CASES[case], B=batch))]
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         *args).compile()
     assert "tpu_custom_call" in compiled.as_text()

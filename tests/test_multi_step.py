@@ -412,12 +412,12 @@ def test_scan_matches_under_model_parallel():
 
 
 # ---------------------------------------------------------------------------
-# decode_mode=auto (PR 18): speculation and the scan COMPOSE per window
+# speculation and the scan COMPOSE per window (PR 18)
 # ---------------------------------------------------------------------------
 
 
 def test_auto_mode_composes_spec_and_scan(tr):
-    """With spec_k > 0 AND decode_steps > 1 under decode_mode=auto, the
+    """With spec_k > 0 AND decode_steps > 1, the
     per-window policy routes drafted windows through the verify step and
     draft-free pure-decode windows through the scan — BOTH counters
     advance in one run, tokens stay bit-exact against the plain engine
@@ -450,14 +450,14 @@ def test_auto_mode_composes_spec_and_scan(tr):
     spec0 = cw.signature_count("serving.spec_step")
     eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=8,
                         max_context=64, spec_k=2, decode_steps=3,
-                        decode_mode="auto", drafter=ParityReplay())
+                        drafter=ParityReplay())
     res = eng.run([mk_req()])
     _assert_equal_results(res_plain, res, "auto spec x scan vs plain")
     np.testing.assert_array_equal(full, np.asarray(res["c"]))
     assert eng.n_spec_steps > 0, "no window ever took the verify step"
     assert eng.n_scan_flushes > 0, \
         "no draft-free window ever scanned — spec_k > 0 must not " \
-        "disable multi-step under decode_mode=auto"
+        "disable multi-step"
     assert eng.n_spec_accepted > 0, "the replay chains never accepted"
     # per-engine: ONE scan program and ONE verify program carried the
     # whole composed run.  (The compile-watch site counts are global
@@ -471,38 +471,17 @@ def test_auto_mode_composes_spec_and_scan(tr):
     eng.kv.check_reclaimed()
 
 
-def test_static_mode_keeps_legacy_exclusivity(tr):
-    """decode_mode=static restores the old behavior: spec_k > 0 disables
-    the scan entirely (the A/B control arm), with identical tokens."""
-    prompt = _prompts((8,), 61, seed=12)[0]
-    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=8,
-                        max_context=64, spec_k=2, decode_steps=3,
-                        decode_mode="static")
-    res = eng.run([Request("s", prompt.copy(), max_new=12)])
-    np.testing.assert_array_equal(
-        _oracle(tr, Request("s", prompt.copy(), max_new=12)),
-        np.asarray(res["s"]))
-    assert eng.n_scan_flushes == 0, \
-        "static mode must keep the spec-xor-scan exclusivity"
-    # the idle toggle flips the policy without rebuilding the engine
-    eng.set_decode_mode("auto")
-    assert eng.decode_mode == "auto"
-    with pytest.raises(ValueError, match="decode_mode"):
-        eng.set_decode_mode("sometimes")
-
-
 def test_admission_never_stalls_behind_scan(tr):
     """The adaptive fallback regression (PR 18 satellite): a request
     admitted MID-FLIGHT while the engine is in scanned steady state must
     start chunk-prefilling on the very next dispatch — the window falls
     back to mixed/verify scheduling instead of making the prompt wait
-    out k-step scan windows.  Checked with speculation on (auto mode)
+    out k-step scan windows.  Checked with speculation on
     AND off: no scan flush may occur while a prompt is mid-prefill."""
     for spec_k in (0, 2):
         eng = ServingEngine(tr.executor, tr.params, num_slots=2,
                             page_size=8, max_context=64, prefill_chunk=8,
-                            decode_steps=4, decode_mode="auto",
-                            spec_k=spec_k)
+                            decode_steps=4, spec_k=spec_k)
         short, long_ = _prompts((5, 30), 61, seed=13)
         eng.add_request(Request("short", short, max_new=24))
         # reach scanned steady state before the mid-flight admission

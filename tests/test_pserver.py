@@ -565,6 +565,49 @@ def test_restarted_shard_mixed_init_is_loud(tmp_path):
         s0.stop_background(drain=False)
 
 
+def test_refused_joiner_holds_no_socket():
+    """A joiner the coordinator refuses (its rank is held by a live
+    trainer) raises and closes every socket it opened — a caller that
+    catches the error is left with nothing to leak."""
+    from paddle_tpu.pserver.client import PServerError
+    srvs, addrs = _start(n_shards=1)
+    try:
+        a = _client(addrs, rank=0)
+        b = ParameterClient(addrs, timeout=30.0)
+        with pytest.raises(PServerError, match="already held"):
+            b.join(rank=0)
+        assert all(s.fileno() == -1 for s in b.socks + [b._ctl])
+        a.leave()
+        a.close()
+    finally:
+        for s in srvs:
+            s.stop_background(drain=False)
+
+
+def test_stop_does_not_wait_for_an_idle_peer():
+    """A server asked to stop while a connected peer sits idle, its
+    socket never closed, stops anyway: shutdown closes the connections
+    it accepted instead of waiting for each peer to hang up."""
+    import socket
+
+    from paddle_tpu.serving import wire
+    srv = ParameterServer(port=0, shard_index=0, n_shards=1)
+    host, port = srv.start_background()
+    peer = socket.create_connection((host, port), timeout=10.0)
+    try:
+        # a completed round trip: the server has accepted the connection
+        # and its handler is parked on the next read
+        wire.write_frame_sync(peer, {"type": "ping"})
+        assert wire.read_frame_sync(peer)["type"] == "pong"
+        t0 = time.monotonic()
+        srv.stop_background(drain=False, timeout=30)
+        assert time.monotonic() - t0 < 10.0
+        assert wire.read_frame_sync(peer) is None, \
+            "the idle peer must see the server hang up"
+    finally:
+        peer.close()
+
+
 def test_joiner_pull_waits_for_commit_relay():
     """A joiner pulling between a coordinator commit and the commit-set
     relay must not assemble a mixed-version parameter state: the

@@ -123,25 +123,6 @@ def test_engine_eos_early_stop_refills_slots():
                for r in reqs)
 
 
-def test_prefill_compiles_per_bucket_not_per_length():
-    """LEGACY (prefill_chunk=None) path: prompts of lengths 3/5/7 share
-    the 8-bucket; 12 lands in the 16-bucket — exactly two prefill
-    signatures (the feeder's _bucket_len grid, page-aligned), not four.
-    The chunked default compiles NO per-bucket prefill programs at all —
-    tests/test_chunked_prefill.py pins that signature discipline."""
-    tr = _make("vocab=31,dim=16,layers=1,heads=2,batch_size=4")
-    prompts = _prompts((3, 5, 7, 12), 31, seed=2)
-    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=8,
-                        max_context=32, prefill_chunk=None)
-    results = eng.run([Request(i, p, max_new=3)
-                       for i, p in enumerate(prompts)])
-    assert len(results) == 4
-    assert sorted(eng._prefill_cache) == [8, 16]
-    assert eng._decode_step._cache_size() == 1
-    assert eng._mixed_step._cache_size() == 0, \
-        "legacy mode must never touch the mixed step"
-
-
 def test_overcommitted_pool_preempts_and_stays_exact():
     """A pool smaller than the worst case forces pauses/preemptions; the
     deterministic per-request key schedule makes them invisible in the

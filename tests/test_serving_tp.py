@@ -148,21 +148,6 @@ def test_tp_overcommitted_pool_preempt_replay_stays_exact():
     tp_eng.kv.check_reclaimed()
 
 
-def test_tp_legacy_unchunked_prefill_path_stays_exact():
-    """prefill_chunk=None (legacy whole-prompt bucketed prefill) under
-    sharding: the dense prefill + pack path partitions too — same
-    tokens, zero mixed-step signatures."""
-    tr = _make("vocab=31,dim=16,layers=1,heads=2,batch_size=4")
-    prompts = _prompts((3, 5, 12), 31, seed=2)
-    kw = dict(num_slots=2, page_size=8, max_context=32, prefill_chunk=None)
-    base = _tp_engine(tr, 1, **kw).run(
-        [Request(i, p, max_new=4) for i, p in enumerate(prompts)])
-    eng = _tp_engine(tr, 2, **kw)
-    tp = eng.run([Request(i, p, max_new=4) for i, p in enumerate(prompts)])
-    _assert_same_results(base, tp, "model=2 (legacy prefill)")
-    assert eng._mixed_step._cache_size() == 0
-
-
 def test_tp_head_divisibility_validated():
     """heads (and kv heads) must divide the model axis — a mesh the model
     cannot shard over is an actionable construction-time error, not a

@@ -518,9 +518,8 @@ def test_engine_asserts_on_drafter_clamp_violation(tr):
 
 def test_model_drafter_self_spec_exact_and_one_signature(tr):
     """Self-speculation end to end: ModelDrafter.from_target drafting
-    for ALL slots in one batched dispatch, with dynamic k and
-    decode_mode=auto on — tokens bit-identical to the spec-off engine
-    and the lm_generate oracle across all four sampling modes, the
+    for ALL slots in one batched dispatch, with dynamic k on — tokens
+    bit-identical to the spec-off engine and the lm_generate oracle across all four sampling modes, the
     accept path genuinely exercised (greedy self-drafts agree with the
     greedy target), and EXACTLY ONE serving.draft_step signature for
     the whole workload (dynamic k rides as data)."""

@@ -6,6 +6,7 @@ import subprocess
 import sys
 
 import numpy as np
+import pytest
 
 from paddle_tpu.config.parser import parse_config_callable
 
@@ -481,3 +482,56 @@ def test_cli_multiplexer_dispatch(tmp_path, capsys):
     out = capsys.readouterr().out
     import json
     assert json.loads(out)["model_config"]["layers"]
+
+
+# -- tools/serve.py: the flags the benchmark's configurations pass ------------
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _serve_parse(argv):
+    """tools/serve.py's own main() parses `argv`, stopped where it would
+    start the server (the hook benchmark/kinds/serve.py uses)."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "tools_serve_flags", os.path.join(_ROOT, "tools", "serve.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    got = {}
+
+    async def capture(args):
+        got["args"] = args
+        return 0
+
+    tool.amain = capture
+    tool.main(argv)
+    return got["args"]
+
+
+@pytest.mark.parametrize("config", ["starcoder2-3b-serve.json",
+                                    "gigachat3.1-702b-a36b-serve.json"])
+def test_serve_parses_every_server_flag_of_the_configuration(config):
+    """Every key of a serve configuration's `server_flags` is a flag of
+    tools/serve.py with the value it was given (`max_context` ->
+    `--max-context`): the benchmark passes them by data alone."""
+    import json
+    with open(os.path.join(_ROOT, "benchmark", "configs", config)) as f:
+        flags = json.load(f)["server_flags"]
+    assert flags, config
+    args = _serve_parse([w for k, v in flags.items()
+                         for w in ("--" + k.replace("_", "-"), str(v))])
+    for k, v in flags.items():
+        assert getattr(args, k) == v, (k, getattr(args, k), v)
+
+
+@pytest.mark.parametrize("argv", [["--decode-mode", "auto"],
+                                  ["--prefill-chunk", "-1"]],
+                         ids=["decode-mode", "negative-prefill-chunk"])
+def test_serve_refuses_the_retired_fork_selectors(argv, capsys):
+    """`--decode-mode` is gone and a negative `--prefill-chunk` selected
+    the whole-prompt prefill that is gone: both are parser errors, not
+    silently accepted spellings of the default."""
+    with pytest.raises(SystemExit) as ei:
+        _serve_parse(argv)
+    assert ei.value.code == 2
+    assert argv[0] in capsys.readouterr().err

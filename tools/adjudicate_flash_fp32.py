@@ -1,6 +1,6 @@
 """Adjudicate the round-4 fp32 flash parity failure with an f64 oracle.
 
-MEASURE/parity.out (v5e, round 4): `flash B2_T512_H4_D64_float32` had
+The round-4 parity run on a v5e: `flash B2_T512_H4_D64_float32` had
 46/262144 elements outside rtol/atol=2e-3 against an fp32 dense reference
 (max abs diff 5e-3, max REL diff 0.49 — i.e. tiny-magnitude outputs).
 Question (VERDICT r4 item 2): kernel bug (masking/accumulation) or

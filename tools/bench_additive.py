@@ -28,7 +28,7 @@ import numpy as np
 
 def bench_impl(name, step_fn, args, dec_len, reps):
     """Times the full fwd+bwd decoder-scan step with the dispatch-proof
-    chained-scan harness (tools/_scan_bench.py) — the r4 numbers from the
+    chained-scan harness (tools/_scan_bench) — the r4 numbers from the
     old block_until_ready loop were physically impossible (0.028 ms for
     ~10 GFLOP of work) and are superseded."""
     from _scan_bench import fold, scan_length, timed_chain

@@ -1,6 +1,6 @@
 """Micro-benchmark: pallas LSTM/GRU time-grid kernels vs the lax.scan
-fallback — the routing evidence the additive kernel already has
-(MEASURE/additive_bench.out) but the RNN kernels never got on hardware.
+fallback — the routing evidence the additive kernel got in round 4
+but the RNN kernels never got on hardware.
 
 Measures fwd+bwd training-step time at the shapes that matter:
 the sentiment bench (B64 T30-ish D512-class hidden) plus a small and a
@@ -26,7 +26,7 @@ import numpy as np
 
 
 def _time(loss, argnums, args, reps, est_flops):
-    """Dispatch-proof timing (tools/_scan_bench.py): the grads of `loss`
+    """Dispatch-proof timing (tools/_scan_bench): the grads of `loss`
     w.r.t. `argnums` chain into the next iteration's inputs inside one
     jitted scan — the old per-call loop + block_until_ready reported
     dispatch latency, not compute."""

@@ -229,7 +229,7 @@ def main() -> int:
                     help="mesh model-axis size (tensor-parallel shards)")
     ap.add_argument("--config-args",
                     default="vocab=61,dim=32,layers=2,heads=4,batch_size=4")
-    ap.add_argument("--save", default=os.path.join(REPO, "MEASURE",
+    ap.add_argument("--save", default=os.path.join(REPO, "output",
                                                    "serving_tp_step.hlo"))
     args = ap.parse_args()
 

@@ -90,7 +90,7 @@ def gather_spans_table(line: str, tables) -> bool:
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--save", default=os.path.join(REPO, "MEASURE",
+    ap.add_argument("--save", default=os.path.join(REPO, "output",
                                                    "recsys_step.hlo"))
     ap.add_argument("--data", type=int, default=8)
     ap.add_argument("--model", type=int, default=1)

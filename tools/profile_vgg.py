@@ -3,7 +3,7 @@
 Evidence tool for the round-4 regression (VERDICT r5 item 3: 51.4k
 samples/s @ 37.1% MFU measured r4 vs 56.7k @ ~41% claimed r2 — same code
 paths).  Runs the exact bench_vgg step under `jax.profiler.trace`, banks
-the raw xplane under MEASURE/xplane_vgg/, and prints an op-level
+the raw xplane under output/xplane_vgg/, and prints an op-level
 breakdown (top self-time HLO ops) so the evidence is kept with the run.  The r2 profile's signature to compare against (PERF.md): BN
 fusions ~25%, max-pool select-and-scatter ~9%, no single op >4.4%.
 
@@ -117,7 +117,7 @@ def main() -> int:
     ap.add_argument("--batch", type=int, default=128)
     ap.add_argument("--analyze-only", default="")
     ap.add_argument("--outdir",
-                    default=os.path.join(REPO, "MEASURE", "xplane_vgg"))
+                    default=os.path.join(REPO, "output", "xplane_vgg"))
     args = ap.parse_args()
     if args.analyze_only:
         analyze(args.analyze_only)

@@ -147,10 +147,6 @@ def build_engine(args):
     from paddle_tpu.serving import ServingEngine
 
     executor, params = build_model(args)
-    if args.prefill_chunk < 0:
-        chunk = None                 # chunking off: legacy prefill
-    else:
-        chunk = args.prefill_chunk or -1   # 0 = engine default
     mesh = None
     if args.mesh:
         # tensor-parallel serving: '--mesh model=N' shards attention heads
@@ -192,13 +188,13 @@ def build_engine(args):
                          page_size=args.page_size,
                          max_context=args.max_context,
                          num_pages=args.num_pages,
-                         prefill_chunk=chunk,
+                         # 0 = engine default (4 * page_size)
+                         prefill_chunk=args.prefill_chunk or -1,
                          max_step_tokens=args.max_step_tokens or None,
                          spec_k=args.spec_k,
                          drafter=drafter,
                          spec_dynamic=args.spec_dynamic,
                          decode_steps=args.decode_steps,
-                         decode_mode=args.decode_mode,
                          spill_bytes_budget=args.spill_budget,
                          mesh=mesh)
 
@@ -290,8 +286,7 @@ def main(argv=None) -> int:
                          "restore on prefix hits (docs/serving.md)")
     ap.add_argument("--prefill-chunk", type=int, default=0,
                     help="chunked-prefill chunk size in tokens "
-                         "(0 = engine default 4*page_size, negative = "
-                         "disable chunking: legacy whole-prompt prefill)")
+                         "(0 = engine default 4*page_size)")
     ap.add_argument("--max-step-tokens", type=int, default=0,
                     help="per-step token budget for mixed prefill/decode "
                          "steps (0 = prefill_chunk + slots)")
@@ -325,13 +320,6 @@ def main(argv=None) -> int:
                          "tokens are identical either way, streaming "
                          "arrives in <=K bursts — docs/serving.md "
                          "'Multi-step decode')")
-    ap.add_argument("--decode-mode", choices=["auto", "static"],
-                    default="auto",
-                    help="step dispatch policy: 'auto' composes "
-                         "speculation and multi-step per flush window "
-                         "(draft-free pure-decode windows ride the "
-                         "scan); 'static' keeps the legacy exclusivity "
-                         "(spec disables the scan)")
     ap.add_argument("--role", choices=["prefill", "decode", "both"],
                     default="both",
                     help="disaggregated prefill/decode placement role, "
@@ -407,6 +395,9 @@ def main(argv=None) -> int:
                          "as JSONL here on drain (tools/trace_dump.py "
                          "converts to Perfetto-loadable Chrome JSON)")
     args = ap.parse_args(argv)
+    if args.prefill_chunk < 0:
+        ap.error("--prefill-chunk must be >= 0 (0 = engine default): "
+                 "chunked prefill is the only admission path")
 
     if args.client:
         return run_client(args)

@@ -2,7 +2,7 @@
 item 7: "tune flash block sizes").
 
 Sweeps (block_q, block_k) over the flash kernel at transformer-LM-ish
-shapes with the shared dispatch-proof harness (tools/_scan_bench.py) and
+shapes with the shared dispatch-proof harness (tools/_scan_bench) and
 prints one JSON row per point plus a `best` row per sequence length.
 Apply a winner globally via the env defaults the attention layer reads
 (PADDLE_TPU_FLASH_BLOCK_Q / PADDLE_TPU_FLASH_BLOCK_K,
