@@ -42,18 +42,12 @@ _BLOCKWISE_MIN_KEYS = 2048
 
 
 def _flash_blocks(cfg: LayerConfig) -> dict:
-    """Flash-kernel block sizes: per-layer attrs win; else the env-tuned
-    defaults (PADDLE_TPU_FLASH_BLOCK_Q/K — written from
-    tools/tune_flash.py's on-device sweep); else the kernel's 128x128.
-    Used by BOTH the training path and the cached-decode prefill, so a
-    tuned configuration applies everywhere flash runs."""
-    import os
-    return {
-        "block_q": int(cfg.attrs.get(
-            "block_q", os.environ.get("PADDLE_TPU_FLASH_BLOCK_Q", 128))),
-        "block_k": int(cfg.attrs.get(
-            "block_k", os.environ.get("PADDLE_TPU_FLASH_BLOCK_K", 128))),
-    }
+    """Flash-kernel block sizes a layer pins: its `block_q` / `block_k`
+    attrs, else nothing — the kernel then derives its blocks from the shape
+    (ops/pallas_attention.py:derive_blocks).  Used by BOTH the training
+    path and the cached-decode prefill."""
+    return {key: int(cfg.attrs[key]) for key in ("block_q", "block_k")
+            if key in cfg.attrs}
 
 
 @register_layer("multi_head_attention")

@@ -81,6 +81,11 @@ CATALOG: dict[str, str] = {
     "serving_frame_writes_total":
         "transport writes that carried token frames (one per connection "
         "and engine step; frames/writes = how far a step's tokens coalesce)",
+    # -- flash kernels, counted when a call is traced into a program ------
+    "flash_grid_steps_total":
+        "grid steps of the flash kernel calls traced so far (label kernel)",
+    "flash_live_tiles_total":
+        "of those grid steps, tiles causality and the window leave alive",
     # -- cross-replica KV transfer (docs/serving.md "Disaggregated
     # prefill/decode") ----------------------------------------------------
     "serving_kv_xfer_pushes_total":

@@ -233,51 +233,65 @@ class TestMHALayer:
         assert np.isfinite(loss)
 
     def test_layer_flash_block_sizes_attrs_beat_env(self, monkeypatch):
-        """The flash branch forwards block_q/block_k to the kernel in BOTH
-        the training path and the cached-decode prefill: per-layer attrs
-        win over the PADDLE_TPU_FLASH_BLOCK_Q/K env defaults (written from
-        tools/tune_flash.py's on-device sweep), which beat the 128x128
-        kernel default."""
+        """The flash branch hands the kernel a layer's block_q/block_k
+        attrs in BOTH the training path and the cached-decode prefill;
+        with no attrs it passes none, and the kernel runs at the blocks
+        `derive_blocks` gives the shape (the env defaults that once sat
+        between the two are gone: the name of this test is from then)."""
         from paddle_tpu.config.parser import parse_config
         from paddle_tpu.graph.lm_decode import lm_generate
         from paddle_tpu.ops import pallas_attention
         from paddle_tpu.trainer.trainer import Trainer
 
         monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
-        monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCK_Q", "256")
-        monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCK_K", "512")
-        seen = {}
-        real = pallas_attention.flash_attention
+        seen, ran = {}, []
+        real, real_fwd = (pallas_attention.flash_attention,
+                          pallas_attention._fwd_call)
 
         def spy(*a, **kw):
             seen.update({k: kw.get(k) for k in ("block_q", "block_k")})
             return real(*a, **kw)
 
+        def spy_fwd(q, k, *a):
+            # a = (v, kv_mask, q_off, k_off, H, scale, causal, window,
+            # blocks); blocks[0] is the forward's (Bq, Bk)
+            ran.append((q.shape[1], k.shape[1], a[-1][0]))
+            return real_fwd(q, k, *a)
+
         monkeypatch.setattr(pallas_attention, "flash_attention", spy)
+        monkeypatch.setattr(pallas_attention, "_fwd_call", spy_fwd)
         cfg = parse_config("demo/model_zoo/transformer_lm.py",
                            "dim=32,layers=1,heads=2,vocab=64,batch_size=2,"
                            "attn_impl=flash")
         tr = Trainer(cfg, seed=0)
-        tr.train_one_batch(next(tr.train_batches()))
-        assert seen == {"block_q": 256, "block_k": 512}   # env defaults
+        batch = next(tr.train_batches())
+        tr.train_one_batch(batch)
+        assert seen == {"block_q": None, "block_k": None}   # nothing pinned
+        # ... so the kernel received the rule's pick for its shape
+        T = int(jax.tree.leaves(batch)[0].shape[1])
+        want = pallas_attention.derive_blocks(T, T, 16, jnp.float32)
+        assert ran and all(r[2] == want["flash_fwd"] for r in ran), (ran, want)
 
-        # cached-decode prefill takes the same tuned sizes (it is the
-        # long-context case tuning targets)
+        # cached-decode prefill: the same, no blocks pinned
         seen.clear()
         toks, _ = lm_generate(tr.executor, tr.params,
                               np.ones((1, 4), np.int32), max_new=2,
                               use_cache=True)
-        assert seen == {"block_q": 256, "block_k": 512}
+        assert seen == {"block_q": None, "block_k": None}
 
-        # per-layer attrs beat the env defaults
-        seen.clear()
+        # per-layer attrs beat the derived rule, in both paths
         for layer in cfg.model_config.layers:
             if layer.type == "multi_head_attention":
                 layer.attrs["block_q"] = 128
-                layer.attrs["block_k"] = 128
+                layer.attrs["block_k"] = 256
         tr2 = Trainer(cfg, seed=0)
+        seen.clear()
         tr2.train_one_batch(next(tr2.train_batches()))
-        assert seen == {"block_q": 128, "block_k": 128}
+        assert seen == {"block_q": 128, "block_k": 256}
+        seen.clear()
+        lm_generate(tr2.executor, tr2.params, np.ones((1, 4), np.int32),
+                    max_new=2, use_cache=True)
+        assert seen == {"block_q": 128, "block_k": 256}
 
     def test_ring_path_matches_single_device(self):
         """Same params, same batch: seq-parallel mesh loss == local loss."""

@@ -92,40 +92,53 @@ FLASH_CASES = {
     # the train cells' own shape (benchmark/configs/starcoder2-3b-train.json:
     # 2 sequences of 4,096 a chip, 24 query heads over 2 KV heads of 128)
     "train_cell": dict(B=2, T=4096, H=24, H_kv=2, D=128),
+    # graph/layers_attn.py:mla_attention's expanded whole-sequence path:
+    # 64 heads, qk 128 + 64 = 192 and v padded to it (256 lanes in VMEM)
+    "latent_head": dict(B=2, T=4096, H=64, H_kv=64, D=192),
+    # fp32 inputs: fp32 operands at Precision.HIGHEST, (8, 128) tiles
+    "fp32": dict(B=2, T=4096, H=24, H_kv=2, D=128, dtype=f32),
 }
+#: the two ways a caller sizes the blocks: pinned (a layer's attrs, the
+#: kernel's old default) and derived from the shape (no argument)
+BLOCKS = {"pinned128": dict(block_q=128, block_k=128), "derived": {}}
+#: the cases that predate the derived rule stay pinned at 128 as they were;
+#: every case also compiles at the blocks the rule gives its shape
+PINNED = ["lm", "gqa", "ragged_T500", "train_cell"]
+FLASH_PARAMS = [(c, "pinned128") for c in PINNED] \
+    + [(c, "derived") for c in FLASH_CASES]
 
 
-def _flash_shapes(B, T, H, H_kv, D=64):
-    return [((B, T, H, D), bf16), ((B, T, H_kv, D), bf16),
-            ((B, T, H_kv, D), bf16)]
+def _flash_shapes(B, T, H, H_kv, D=64, dtype=bf16):
+    return [((B, T, H, D), dtype), ((B, T, H_kv, D), dtype),
+            ((B, T, H_kv, D), dtype)]
 
 
-@pytest.mark.parametrize("case", list(FLASH_CASES))
-def test_flash_forward(mosaic, case):
+@pytest.mark.parametrize("case,blocks", FLASH_PARAMS)
+def test_flash_forward(mosaic, case, blocks):
     from paddle_tpu.ops.pallas_attention import flash_attention
 
     def fwd(q, k, v):
-        return flash_attention(q, k, v, causal=True, block_q=128,
-                               block_k=128)
+        return flash_attention(q, k, v, causal=True, **BLOCKS[blocks])
 
     mosaic(fwd, *_flash_shapes(**FLASH_CASES[case]))
 
 
-@pytest.mark.parametrize("case", list(FLASH_CASES))
-def test_flash_backward(mosaic, case):
+@pytest.mark.parametrize("case,blocks", FLASH_PARAMS)
+def test_flash_backward(mosaic, case, blocks):
     from paddle_tpu.ops.pallas_attention import flash_attention
 
     def loss(q, k, v):
-        o = flash_attention(q, k, v, causal=True, block_q=128, block_k=128)
+        o = flash_attention(q, k, v, causal=True, **BLOCKS[blocks])
         return jnp.sum(o.astype(f32))
 
     mosaic(jax.grad(loss, argnums=(0, 1, 2)),
            *_flash_shapes(**FLASH_CASES[case]))
 
 
+@pytest.mark.parametrize("blocks", list(BLOCKS))
 @pytest.mark.parametrize("case,batch", [("lm", 64), ("train_cell", 8)])
 def test_flash_under_data_mesh(topo, no_persistent_cache, monkeypatch,
-                               case, batch):
+                               case, batch, blocks):
     """`--mesh_shape=data:4` with attn_impl=flash: lowering the bare kernel
     over a 4-chip mesh raises "Mosaic kernels cannot be automatically
     partitioned"; parallel/context.py:flash_attn_fn wraps it in shard_map.
@@ -142,7 +155,7 @@ def test_flash_under_data_mesh(topo, no_persistent_cache, monkeypatch,
     monkeypatch.setattr(pallas_attention, "_interpret", lambda: False)
     mesh = make_mesh(data=4, devices=topo.devices)
     attn = flash_attn_fn(mesh, functools.partial(
-        pallas_attention.flash_attention, block_q=128, block_k=128))
+        pallas_attention.flash_attention, **BLOCKS[blocks]))
 
     def loss(q, k, v):
         return jnp.sum(attn(q, k, v, causal=True).astype(f32))
@@ -158,6 +171,37 @@ def test_flash_under_data_mesh(topo, no_persistent_cache, monkeypatch,
     assert len(names) == 3
     for want in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         assert sum(want in n for n in names) == 1, names
+
+
+def test_flash_ring_shard_pair_with_traced_offsets(mosaic):
+    """ops/attention.py:ring_attention's call: traced q_offset / k_offset
+    (prefetched scalars the index maps read), return_lse, an lse cotangent,
+    derived blocks — forward and backward for the chip."""
+    from paddle_tpu.ops.pallas_attention import flash_attention
+
+    def loss(q, k, v, q_off, k_off):
+        o, lse = flash_attention(q, k, v, causal=True, q_offset=q_off[0],
+                                 k_offset=k_off[0], return_lse=True)
+        return jnp.sum(o.astype(f32)) + jnp.sum(
+            jnp.where(jnp.isfinite(lse), lse, 0.0))
+
+    mosaic(jax.grad(loss, argnums=(0, 1, 2)),
+           *_flash_shapes(B=2, T=2048, H=8, H_kv=2, D=128),
+           ((1,), i32), ((1,), i32))
+
+
+def test_flash_sliding_window(mosaic):
+    """Causal + sliding window at the train cells' shape (the published
+    window of 4,096 over a longer sequence is ROADMAP B8): the index maps
+    clamp both edges of the band; forward and backward, derived blocks."""
+    from paddle_tpu.ops.pallas_attention import flash_attention
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, causal=True, window=1024)
+        return jnp.sum(o.astype(f32))
+
+    mosaic(jax.grad(loss, argnums=(0, 1, 2)),
+           *_flash_shapes(**FLASH_CASES["train_cell"]))
 
 
 def test_flash_kernels_carry_their_names(mosaic):
