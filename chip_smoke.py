@@ -2,7 +2,8 @@
 """First contact with the chip: drive the LM trainer and the serving engine
 through their normal CLIs on one TPU, check what comes out, say so.
 
-  python chip_smoke.py               # one chip: train, serve, kernels
+  python chip_smoke.py               # one chip: train, serve, kernels,
+                                     # hybrid
   python chip_smoke.py --chips 4     # four chips: data-parallel train vs
                                      # the single-device run, nothing else
   python chip_smoke.py --rehearse    # CPU control-flow rehearsal at tiny
@@ -30,6 +31,12 @@ child processes, one after another, each owning the chip alone:
            greedy token against a dense fp32 forward of the same weights
   kernels  `tools/tpu_parity.py`: each Pallas kernel against its jnp /
            lax.scan reference at the demo widths
+  hybrid   `tools/serve.py` on benchmark/configs/kimi_linear.py at the
+           benchmark's rehearsal size (hidden 64, 4 heads, 2 layers: one KDA
+           layer and one NoPE latent layer at their PUBLISHED widths, 16 of
+           256 experts): greedy twice alike, a long prompt chunked beside a
+           decoding one, alone == batched, the recurrent counters in the
+           stats, SIGTERM drains
 
 No fallback: a child that finds no TPU exits non-zero at once, any failed
 check aborts the run with exit 1, and only a run in which every phase
@@ -53,6 +60,10 @@ REPO = os.path.dirname(os.path.abspath(__file__))
 WORK = os.path.join(REPO, "output", "chip_smoke")   # output/ is git-ignored
 BUDGET_S = 1140.0           # the driver allows 1200 s, compiles included
 LM_CONFIG = "demo/model_zoo/transformer_lm.py"
+HYBRID_CONFIG = "benchmark/configs/kimi_linear.py"
+HYBRID_ARGS = ("vocab=128,dim=64,layers=2,heads=4,kv_heads=4,ffn=128,"
+               "rope_theta=10000,batch_size=1,compute_dtype=bfloat16,"
+               "attn_impl=flash")
 
 FULL = dict(vocab=32000, dim=512, layers=8, heads=8, batch=64, seq=512,
             passes=6, slots=16, page=16, context=768,
@@ -265,20 +276,7 @@ def phase_serve(w: dict, rehearse: bool, checkpoint: str) -> dict:
             str(w["context"]), "--port", "0", "--seed", "1"]
     if checkpoint:
         argv += ["--checkpoint", checkpoint]
-    say(f"  $ {' '.join(argv[1:])}")
-    t0 = time.time()
-    srv, log = _spawn("serve", argv, env)
-    while "SERVE_JSON:" not in _read(log):
-        need(srv.poll() is None,
-             f"server died (exit {srv.returncode}):\n{_read(log)[-3000:]}")
-        need(_remaining() > 0 and time.time() - t0 < 600,
-             f"server never bound:\n{_read(log)[-3000:]}")
-        time.sleep(0.5)
-    hello = _tagged(_read(log), "SERVE_JSON:")
-    port = int(hello["port"])
-    need(rehearse or hello["device"]["platform"] == "tpu",
-         f"server is not on a TPU: {hello}")
-    say(f"  server up in {time.time() - t0:.1f}s on {hello['device']}")
+    srv, log, port = _serve_up("serve", argv, env, rehearse)
 
     rng = random.Random(0)
     V, N = w["vocab"], w["max_new"]
@@ -345,6 +343,32 @@ def phase_serve(w: dict, rehearse: bool, checkpoint: str) -> dict:
                                     "prefill_chunks", "prefix_hits",
                                     "tokens_generated")))
 
+    _drain(srv, log)
+    return {"config_args": config_args(w), "checkpoint": checkpoint,
+            "slots": w["slots"], "page": w["page"], "context": w["context"],
+            "greedy": greedy}
+
+
+def _serve_up(name: str, argv: list[str], env: dict, rehearse: bool):
+    """Start a tools/serve.py server; wait for its SERVE_JSON line."""
+    say(f"  $ {' '.join(argv[1:])}")
+    t0 = time.time()
+    srv, log = _spawn(name, argv, env)
+    while "SERVE_JSON:" not in _read(log):
+        need(srv.poll() is None,
+             f"server died (exit {srv.returncode}):\n{_read(log)[-3000:]}")
+        need(_remaining() > 0 and time.time() - t0 < 600,
+             f"server never bound:\n{_read(log)[-3000:]}")
+        time.sleep(0.5)
+    hello = _tagged(_read(log), "SERVE_JSON:")
+    need(rehearse or hello["device"]["platform"] == "tpu",
+         f"server is not on a TPU: {hello}")
+    say(f"  server up in {time.time() - t0:.1f}s on {hello['device']}")
+    return srv, log, int(hello["port"])
+
+
+def _drain(srv, log: str) -> None:
+    """SIGTERM the server's group: it must drain and exit 0."""
     os.killpg(srv.pid, signal.SIGTERM)
     try:
         rc = srv.wait(timeout=max(1.0, min(120.0, _remaining())))
@@ -352,9 +376,58 @@ def phase_serve(w: dict, rehearse: bool, checkpoint: str) -> dict:
         raise SmokeFailure(f"server ignored SIGTERM:\n{_read(log)[-2000:]}")
     need(rc == 0, f"server exit {rc} after SIGTERM:\n{_read(log)[-2000:]}")
     say("  SIGTERM: drained, exit 0")
-    return {"config_args": config_args(w), "checkpoint": checkpoint,
-            "slots": w["slots"], "page": w["page"], "context": w["context"],
-            "greedy": greedy}
+
+
+def phase_hybrid(rehearse: bool) -> None:
+    """The hybrid linear-attention model through the same server: slot
+    state beside the latent pages, no prefix index."""
+    say("== hybrid: tools/serve.py on benchmark/configs/kimi_linear.py")
+    env = _env(rehearse, 1)
+    cenv = _env(rehearse, 1, client=True)
+    argv = [sys.executable, "tools/serve.py", "--config", HYBRID_CONFIG,
+            "--config-args", HYBRID_ARGS, "--slots", "4", "--page-size", "8",
+            "--max-context", "128", "--prefill-chunk", "16",
+            "--param-dtype", "bfloat16", "--port", "0", "--seed", "1"]
+    srv, log, port = _serve_up("hybrid", argv, env, rehearse)
+    rng = random.Random(1)
+    p0, n = _prompt(rng, 9, 128), 12
+    first = _result("hybrid_greedy", _run(
+        "client_hybrid_greedy", _client_argv(port, p0, n, []), cenv), p0, n)
+    long = _prompt(rng, 70, 128)
+    procs = {"again": _spawn("client_hybrid_again",
+                             _client_argv(port, p0, n, []), cenv),
+             "long": _spawn("client_hybrid_long",
+                            _client_argv(port, long, n, []), cenv)}
+    got = {}
+    for name, (proc, clog) in procs.items():
+        try:
+            rc = proc.wait(timeout=max(1.0, min(400.0, _remaining())))
+        except subprocess.TimeoutExpired:
+            raise SmokeFailure(f"client {name} timed out:\n{_read(clog)}")
+        need(rc == 0, f"client {name}: exit {rc}\n{_read(clog)[-2000:]}")
+        got[name] = _result(name, _read(clog),
+                            p0 if name == "again" else long, n)
+    need(got["again"] == first,
+         f"a slot's state leaked between requests or batching changed the "
+         f"tokens: {first} vs {got['again']}")
+    alone = _result("hybrid_long_alone", _run(
+        "client_hybrid_long_alone", _client_argv(port, long, n, []), cenv),
+        long, n)
+    need(alone == got["long"], f"batched {got['long']} != alone {alone}")
+    out = _run("client_hybrid_stats", [
+        sys.executable, "tools/serve.py", "--client", f"127.0.0.1:{port}",
+        "--stats"], cenv)
+    stats = json.loads(out[out.index("\n{\n") if "\n{\n" in out else 0:])
+    need(stats.get("consistent") is True, f"stats not consistent: {stats}")
+    need(stats["recurrent_steps"] > 0 and stats["recurrent_slot_updates"] > 0
+         and stats["slot_state_bytes"] > 0 and stats["prefix_hits"] == 0,
+         f"no recurrent state counted: {stats}")
+    say("  greedy twice alike, batched == alone; " + ", ".join(
+        f"{k}={stats[k]}" for k in ("decode_steps", "mixed_steps",
+                                    "recurrent_steps",
+                                    "recurrent_slot_updates",
+                                    "slot_state_bytes", "moe_pairs_total")))
+    _drain(srv, log)
 
 
 def phase_kernels(rehearse: bool) -> None:
@@ -587,7 +660,7 @@ def main(argv=None) -> int:
                     help="4 = ONLY the data-parallel train comparison")
     ap.add_argument("--rehearse", action="store_true",
                     help="CPU rehearsal at tiny widths (exit 3, never ok)")
-    ap.add_argument("--phases", default="train,serve,kernels",
+    ap.add_argument("--phases", default="train,serve,kernels,hybrid",
                     help="one-chip phases to run (all are needed for ok)")
     ap.add_argument("--child", choices=sorted(CHILDREN), help=argparse.SUPPRESS)
     ap.add_argument("--spec", help=argparse.SUPPRESS)
@@ -609,7 +682,7 @@ def main(argv=None) -> int:
     os.makedirs(WORK)
     w = TINY if args.rehearse else FULL
     phases = [p for p in args.phases.split(",") if p]
-    all_phases = sorted(phases) == ["kernels", "serve", "train"]
+    all_phases = sorted(phases) == ["hybrid", "kernels", "serve", "train"]
 
     def check(child: str, spec: dict) -> None:
         spec["rehearse"] = args.rehearse
@@ -636,6 +709,8 @@ def main(argv=None) -> int:
                     w, args.rehearse, trained["k1"] if trained else ""))
             if "kernels" in phases:
                 phase_kernels(args.rehearse)
+            if "hybrid" in phases:
+                phase_hybrid(args.rehearse)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr, flush=True)
         return 1

@@ -35,6 +35,7 @@ from paddle_tpu.dsl.poolings import AvgPooling, BasePoolingType, FirstPooling, L
 
 __all__ = [
     "rms_norm_layer", "gated_ffn_layer", "mla_attention_layer",
+    "kda_attention_layer",
     "data_layer", "fc_layer", "embedding_layer", "mixed_layer", "addto_layer",
     "concat_layer", "dropout_layer", "full_matrix_projection",
     "trans_full_matrix_projection", "identity_projection", "table_projection",
@@ -1133,7 +1134,7 @@ def mla_attention_layer(
     input: LayerOutput,
     *,
     num_heads: int,
-    q_lora_rank: int,
+    q_lora_rank: Optional[int],
     kv_lora_rank: int,
     qk_nope_head_dim: int,
     qk_rope_head_dim: int,
@@ -1141,6 +1142,7 @@ def mla_attention_layer(
     size: Optional[int] = None,
     rope_theta: float = 10000.0,
     rope_scaling: Optional[dict] = None,
+    use_rope: bool = True,
     rms_eps: float = 1e-6,
     attn_impl: Optional[str] = None,
     name: Optional[str] = None,
@@ -1153,8 +1155,13 @@ def mla_attention_layer(
     rank-`kv_lora_rank` latent plus a `qk_rope_head_dim`-wide rotated
     position key — the row a serving cache stores.  `rope_scaling` is the
     model's YaRN dict (factor, original_max_position_embeddings, beta_fast,
-    beta_slow, mscale, mscale_all_dim) or None.  `param_attr` initializes
-    the five matrices; the two inner RMSNorm scales start at 1."""
+    beta_slow, mscale, mscale_all_dim) or None.  `q_lora_rank` None or 0:
+    the query is ONE matrix [d, H*(nope+rope)] with no norm inside
+    (parameters: w_q, w_kva, kv_norm, w_kvb, w_o).  `use_rope=False`: the
+    `qk_rope_head_dim` columns stay in the query and in the cache row and
+    are never rotated (NoPE).  `param_attr` initializes the matrices; the
+    inner RMSNorm scales start at 1."""
+    q_lora_rank = int(q_lora_rank or 0)
     assert qk_rope_head_dim % 2 == 0, "the rotated width must be even"
     assert param_attr is None or not param_attr.name, \
         "a named param_attr would share one matrix across the projections"
@@ -1168,16 +1175,18 @@ def mla_attention_layer(
         kv_lora_rank=kv_lora_rank, qk_nope_head_dim=qk_nope_head_dim,
         qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
         rope_theta=rope_theta, rms_eps=rms_eps)
+    if not use_rope:
+        cfg.attrs["use_rope"] = False
     if rope_scaling:
         cfg.attrs["rope_scaling"] = dict(rope_scaling)
     if attn_impl is not None:
         cfg.attrs["attn_impl"] = attn_impl
     one = lambda: ParameterAttribute(initial_mean=1.0, initial_std=0.0)
-    specs = [
-        ([d, q_lora_rank], param_attr),
-        ([1, q_lora_rank], one()),
-        ([q_lora_rank, H * (qk_nope_head_dim + qk_rope_head_dim)],
-         param_attr),
+    q_width = H * (qk_nope_head_dim + qk_rope_head_dim)
+    specs = ([([d, q_lora_rank], param_attr),
+              ([1, q_lora_rank], one()),
+              ([q_lora_rank, q_width], param_attr)]
+             if q_lora_rank else [([d, q_width], param_attr)]) + [
         ([d, kv_lora_rank + qk_rope_head_dim], param_attr),
         ([1, kv_lora_rank], one()),
         ([kv_lora_rank, H * (qk_nope_head_dim + v_head_dim)], param_attr),
@@ -1190,6 +1199,64 @@ def mla_attention_layer(
     _layer_attr_fields(cfg, layer_attr)
     current_context().add_layer(cfg)
     return LayerOutput(name, "mla_attention", size, parents=[input],
+                       seq_level=input.seq_level)
+
+
+def kda_attention_layer(
+    input: LayerOutput,
+    *,
+    num_heads: int,
+    head_dim: int,
+    conv_size: int = 4,
+    size: Optional[int] = None,
+    rms_eps: float = 1e-5,
+    attn_impl: Optional[str] = None,
+    name: Optional[str] = None,
+    param_attr: Optional[ParameterAttribute] = None,
+    layer_attr: Optional[ExtraLayerAttribute] = None,
+) -> LayerOutput:
+    """Kimi Delta Attention (arXiv:2510.26692; ops/kda.py,
+    graph/layers_kda.py): a causal token mixer whose context is one
+    recurrent state [head_dim, head_dim] a head, moved by a gated delta
+    rule with a per-channel decay — q, k and v through a depthwise causal
+    convolution of `conv_size` taps and SiLU, q and k l2-normed a head, the
+    decay and the output gate through rank-`head_dim` projections, a gated RMSNorm a head in front of the output projection.
+    `param_attr` initializes the matrices and the convolutions; A_log starts
+    uniform in [0, log 16] and dt_bias in softplus^-1 of [1e-3, 1e-1] (the
+    ranges of the published initializers), the norm's scale at 1."""
+    assert param_attr is None or not param_attr.name, \
+        "a named param_attr would share one matrix across the projections"
+    size = size if size is not None else input.size
+    name = _name(name, "kda_layer")
+    d, H, dk = input.size, num_heads, head_dim
+    r = head_dim
+    cfg = LayerConfig(name=name, type="kda_attention", size=size,
+                      active_type="")
+    cfg.attrs.update(num_heads=H, head_dim=dk, conv_size=conv_size,
+                     rms_eps=rms_eps, causal=True)
+    if attn_impl is not None:
+        cfg.attrs["attn_impl"] = attn_impl
+    specs = [
+        ([d, H * dk], param_attr), ([d, H * dk], param_attr),
+        ([d, H * dk], param_attr),
+        ([conv_size, H * dk], param_attr), ([conv_size, H * dk], param_attr),
+        ([conv_size, H * dk], param_attr),
+        ([d, r], param_attr), ([r, H * dk], param_attr),
+        ([1, H], ParameterAttribute(initial_min=0.0, initial_max=2.7726)),
+        ([1, H * dk], ParameterAttribute(initial_min=-6.9073,
+                                         initial_max=-2.2522)),
+        ([d, H], param_attr),
+        ([d, r], param_attr), ([r, H * dk], param_attr),
+        ([1, dk], ParameterAttribute(initial_mean=1.0, initial_std=0.0)),
+        ([H * dk, size], param_attr),
+    ]
+    for i, (dims, attr) in enumerate(specs):
+        pname = _make_param(name, i, dims, attr)
+        cfg.inputs.append(LayerInput(input_layer_name=input.name,
+                                     input_parameter_name=pname))
+    _layer_attr_fields(cfg, layer_attr)
+    current_context().add_layer(cfg)
+    return LayerOutput(name, "kda_attention", size, parents=[input],
                        seq_level=input.seq_level)
 
 

@@ -377,11 +377,13 @@ def mla_attention_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
     whose cache row is one latent `[c_kv, k_pe]` a token.
 
     inputs (all the one data input): w_qa [d, q_rank], q_norm [1, q_rank],
-    w_qb [q_rank, H*(nope+rope)], w_kva [d, kv_rank+rope], kv_norm
-    [1, kv_rank], w_kvb [kv_rank, H*(nope+v)], w_o [H*v, d].
+    w_qb [q_rank, H*(nope+rope)] — or, with q_lora_rank 0, the one matrix
+    w_q [d, H*(nope+rope)] and no norm — then w_kva [d, kv_rank+rope],
+    kv_norm [1, kv_rank], w_kvb [kv_rank, H*(nope+v)], w_o [H*v, d].
     attrs: num_heads, q_lora_rank, kv_lora_rank, qk_nope_head_dim,
     qk_rope_head_dim, v_head_dim, rope_theta, rope_scaling (YaRN dict or
-    absent), rms_eps, attn_impl.
+    absent), use_rope (false: the rope columns of the query and of the
+    cache row are carried unrotated — NoPE), rms_eps, attn_impl.
 
     Three paths, picked by the state the executor hands in (as
     multi_head_attention): none = the whole sequence in the EXPANDED form;
@@ -393,9 +395,10 @@ def mla_attention_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
     from paddle_tpu.ops import mla
 
     x_arg = ctx.get_input(cfg, 0)
-    w_qa, g_q, w_qb, w_kva, g_kv, w_kvb, w_o = (
-        ctx.param_of(cfg, i) for i in range(7))
     a = cfg.attrs
+    q_params = 3 if int(a.get("q_lora_rank", 0) or 0) else 1
+    w_kva, g_kv, w_kvb, w_o = (
+        ctx.param_of(cfg, q_params + i) for i in range(4))
     H = int(a["num_heads"])
     kv_rank = int(a["kv_lora_rank"])
     nope, rdim = int(a["qk_nope_head_dim"]), int(a["qk_rope_head_dim"])
@@ -406,6 +409,8 @@ def mla_attention_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
                                  scaling)
     amp = mla.rope_amplitude(scaling)
     scale = mla.softmax_scale(nope + rdim, scaling)
+    rotate = mla.rotate if bool(a.get("use_rope", True)) \
+        else (lambda pe, *_: pe)
     assert bool(a.get("causal", True)), \
         f"layer {cfg.name!r}: latent attention is causal self-attention"
 
@@ -422,13 +427,17 @@ def mla_attention_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
         pos = jnp.arange(T)[None, :]
 
     with jax.named_scope("mla.project"):
-        c_q = rms_norm(x @ w_qa, g_q, eps)
-        q = (c_q @ w_qb).reshape(B, T, H, nope + rdim)
+        if q_params == 3:
+            w_qa, g_q, w_qb = (ctx.param_of(cfg, i) for i in range(3))
+            q = rms_norm(x @ w_qa, g_q, eps) @ w_qb
+        else:
+            q = x @ ctx.param_of(cfg, 0)
+        q = q.reshape(B, T, H, nope + rdim)
         q_nope = q[..., :nope]
-        q_pe = mla.rotate(q[..., nope:], pos[..., None], inv_freq, amp)
+        q_pe = rotate(q[..., nope:], pos[..., None], inv_freq, amp)
         ckv = x @ w_kva
         c_kv = rms_norm(ckv[..., :kv_rank], g_kv, eps)
-        k_pe = mla.rotate(ckv[..., kv_rank:], pos, inv_freq, amp)
+        k_pe = rotate(ckv[..., kv_rank:], pos, inv_freq, amp)
         rows = jnp.concatenate([c_kv, k_pe], axis=-1)      # [B, T, W]
         if paged or dense:       # a cache stores the row at whole lane tiles
             rows = mla.pad_lanes(

@@ -1,0 +1,149 @@
+"""The KDA token mixer (ops/kda.py): a gated delta-rule linear-attention
+layer whose context is a recurrent state a sequence, not keys and values a
+token.
+
+inputs (all the one data input): w_q [d, H*dk], w_k [d, H*dk], w_v
+[d, H*dv], conv_q conv_k conv_v [taps, H*dk | H*dv] (depthwise, no bias),
+w_fa [d, r], w_fb [r, H*dk] (the decay's low-rank projection), a_log
+[1, H], dt_bias [1, H*dk], w_b [d, H], w_ga [d, r], w_gb [r, H*dv] (the
+output gate), o_norm [1, dv], w_o [H*dv, size].
+attrs: num_heads, head_dim, conv_size, rms_eps, attn_impl.
+
+Three paths, picked by the state the executor hands in, as the attention
+layers do:
+
+  * none — the whole sequence in the chunkwise form from the zero state;
+  * a slot state with `pos` (and `run`) — the decode step: one rank-1
+    update a row; a row whose `run` is false leaves `state` and `conv` as
+    they were (a recurrence recomputed at a frozen position would advance
+    twice, where a K/V write is idempotent);
+  * a slot state with `row_slot` — the ragged mixed step.  THE PACKING
+    CONTRACT (serving/engine.py `_run_mixed_step`): with S slots, rows
+    [0, S) are single rows (decode rows, or padding aimed at trash row S)
+    and rows [S, T) hold the prompt chunks, each slot's run contiguous and
+    in order.  The first part is one batched rank-1 update; each run of the
+    second is one segment through the chunkwise form, starting from its
+    slot's state — from zero where it begins at position 0, so admission
+    dispatches nothing.  A touched slot's state is read once and written
+    once a layer a step.
+
+The slot state lives in the serving cache manager (serving/paged_kv.py,
+slot-indexed parts): `state` [S+1, H, dk, dv] float32 and `conv` [S+1,
+taps-1, H*(2dk+dv)], the inputs of the last taps-1 positions.  A caller
+that hands in a state gets back, beside the new parts, `rows` (rows that
+advanced a state) and `updates` (slot states read and written).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.config.schema import LayerConfig
+from paddle_tpu.graph.common import finish_layer
+from paddle_tpu.graph.context import ForwardContext
+from paddle_tpu.graph.registry import register_layer
+from paddle_tpu.ops import kda
+from paddle_tpu.parameter.argument import Argument
+
+
+def _use_kernel(cfg: LayerConfig) -> bool:
+    """The Pallas step kernel unless the config pins the jnp path
+    (attn_impl dense/blockwise, as for the attention layers)."""
+    from paddle_tpu.ops import pallas_kda
+
+    return pallas_kda.supported() and \
+        str(cfg.attrs.get("attn_impl", "auto")) not in ("dense", "blockwise")
+
+
+@register_layer("kda_attention")
+def kda_attention_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
+    x_arg = ctx.get_input(cfg, 0)
+    (w_q, w_k, w_v, c_q, c_k, c_v, w_fa, w_fb, a_log, dt_bias, w_b, w_ga,
+     w_gb, o_norm, w_o) = (ctx.param_of(cfg, i) for i in range(15))
+    a = cfg.attrs
+    H, dk = int(a["num_heads"]), int(a["head_dim"])
+    dv = w_v.shape[1] // H
+    eps = float(a.get("rms_eps", 1e-5))
+    x = x_arg.value                                       # [B, T, d]
+    B, T, _ = x.shape
+    cache = ctx.state_in.get(cfg.name)
+    slotted = isinstance(cache, dict) and "state" in cache
+    ragged = slotted and "row_slot" in cache
+    assert not slotted or ((B == 1) if ragged else (T == 1)), \
+        f"layer {cfg.name!r}: a slot-state step feeds one token a slot, " \
+        f"or one packed ragged row list (got {x.shape})"
+
+    with jax.named_scope("kda.project"):
+        xin = jnp.concatenate([x @ w_q, x @ w_k, x @ w_v], axis=-1)
+    with jax.named_scope("kda.gate"):
+        g = kda.decay(((x @ w_fa) @ w_fb).reshape(B, T, H, dk),
+                      a_log.reshape(H), dt_bias.reshape(H, dk))
+        beta = jax.nn.sigmoid((x @ w_b).astype(jnp.float32))    # [B, T, H]
+        gate = ((x @ w_ga) @ w_gb).reshape(B, T, H, dv)
+    w_conv = jnp.concatenate([c_q, c_k, c_v], axis=-1).astype(xin.dtype)
+
+    def split(y):
+        """conv output [..., C] -> q k [.., H, dk] l2-normed, v [.., H, dv]"""
+        y = jax.nn.silu(y)
+        lead = y.shape[:-1]
+        q = kda.l2norm(y[..., :H * dk].reshape(lead + (H, dk)))
+        k = kda.l2norm(y[..., H * dk:2 * H * dk].reshape(lead + (H, dk)))
+        v = y[..., 2 * H * dk:].reshape(lead + (H, dv)).astype(jnp.float32)
+        return q, k, v
+
+    if not slotted:
+        with jax.named_scope("kda.conv"):
+            q, k, v = split(kda.short_conv_whole(xin, w_conv))
+        with jax.named_scope("kda.scan"):
+            o, _ = kda.chunkwise(q, k, v, g, beta)
+    else:
+        state, conv = cache["state"], cache["conv"]
+        S = state.shape[0] - 1
+        R = B * T
+        xin, g, beta = xin.reshape(R, -1), g.reshape(R, H, dk), \
+            beta.reshape(R, H)
+        if ragged:
+            row_slot, row_pos = cache["row_slot"], cache["row_pos"]
+            assert R > S, f"layer {cfg.name!r}: the mixed step packs its " \
+                f"chunk rows from row {S} on (got {R} rows)"
+            live = row_slot < S
+            idx = jnp.arange(R, dtype=jnp.int32)
+            first = jnp.concatenate(
+                [jnp.ones((1,), bool), row_slot[1:] != row_slot[:-1]])
+            last = jnp.concatenate(
+                [row_slot[1:] != row_slot[:-1], jnp.ones((1,), bool)])
+            seg_off = idx - jax.lax.cummax(jnp.where(first, idx, 0))
+        else:
+            live = cache["run"]
+            row_slot = jnp.arange(S, dtype=jnp.int32)
+            row_pos, seg_off = cache["pos"], jnp.zeros((S,), jnp.int32)
+            last = jnp.ones((S,), bool)
+        with jax.named_scope("kda.conv"):
+            y, hist = kda.short_conv_rows(xin, w_conv, conv[row_slot],
+                                          seg_off, row_pos)
+            conv = conv.at[jnp.where(last & live, row_slot, S)].set(
+                hist.astype(conv.dtype))
+            q, k, v = split(y)
+        with jax.named_scope("kda.step"):
+            if ragged:
+                o_d, state = kda.step_rows(
+                    state, row_slot[:S], live[:S], q[:S], k[:S], v[:S],
+                    g[:S], beta[:S], use_kernel=_use_kernel(cfg))
+                o_c, state, n_seg = kda.segment_rows(
+                    state, row_slot[S:], row_pos[S:], q[S:], k[S:], v[S:],
+                    g[S:], beta[S:])
+                o = jnp.concatenate([o_d, o_c], axis=0)
+                updates = jnp.sum(live[:S], dtype=jnp.int32) + n_seg
+            else:
+                o, state = kda.step_rows(state, None, live, q, k, v, g,
+                                         beta, use_kernel=_use_kernel(cfg))
+                updates = jnp.sum(live, dtype=jnp.int32)
+        o = o.reshape(B, T, H, dv)
+        ctx.state_out[cfg.name] = dict(
+            cache, state=state, conv=conv,
+            rows=jnp.sum(live, dtype=jnp.int32), updates=updates)
+    with jax.named_scope("kda.project"):
+        o = kda.gated_out_norm(o, gate, o_norm.reshape(dv), eps)
+        out = o.reshape(B, T, H * dv).astype(x.dtype) @ w_o
+    return finish_layer(ctx, cfg, out, like=x_arg)

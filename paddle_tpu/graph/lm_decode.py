@@ -271,6 +271,12 @@ def init_kv_caches(executor: GraphExecutor, batch: int, total: int) -> dict:
         else jnp.float32
     state: dict = {}
     for l in executor.model.layers:
+        if l.type == "kda_attention":
+            raise ValueError(
+                f"layer {l.name!r}: a recurrent layer has no dense cache "
+                f"here — decode a model with recurrent layers through the "
+                f"serving engine (its slot state, serving/paged_kv.py) or "
+                f"with use_cache=False")
         if l.type == "multi_head_attention":
             heads = int(l.attrs["num_heads"])
             h_kv = int(l.attrs.get("num_kv_heads", 0) or heads)

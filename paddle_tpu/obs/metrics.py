@@ -75,6 +75,16 @@ CATALOG: dict[str, str] = {
         "MoE layers), summed over steps",
     "serving_moe_steps_total":
         "compiled steps whose routed pairs were counted",
+    # -- recurrent layers: slot states in the cache manager ---------------
+    "serving_recurrent_rows_total":
+        "token rows that advanced a recurrent slot state (one layer's worth)",
+    "serving_recurrent_slot_updates_total":
+        "slot states read and written by compiled steps, summed over the "
+        "recurrent layers",
+    "serving_recurrent_steps_total":
+        "compiled steps whose recurrent rows were counted",
+    "serving_slot_state_bytes":
+        "device bytes of the recurrent layers' slot-indexed state",
     # -- token delivery: a step's frames leave as one write a connection --
     "serving_token_frames_total":
         "streamed token frames written to client connections",

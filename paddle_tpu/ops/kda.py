@@ -1,0 +1,274 @@
+"""Kimi Delta Attention (KDA, arXiv:2510.26692): a gated delta-rule linear
+attention whose decay is per CHANNEL.  A head keeps a state S [dk, dv]:
+
+    S_t = (I - b_t k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t v_t^T
+    o_t = S_t^T (q_t * dk^-1/2)
+
+with a_t = exp(g_t) in (0, 1)^dk and b_t in (0, 1).  Written as a rank-1
+update: S' = Diag(a_t) S_{t-1}, u_t = b_t (v_t - S'^T k_t), S_t = S' + k_t
+u_t^T.  Everything here is float32 whatever the inputs' dtype, and the
+matmuls of the chunkwise form ask for full float32 precision (the TPU's
+default single bf16 pass is not the recurrence's arithmetic).
+
+Three forms, one result:
+  * `recurrent`  — the literal per-token scan: the CPU oracle of the tests
+    and of the step kernel (ops/pallas_kda.py);
+  * `chunkwise`  — the WY / UT-transform form, `CHUNK` tokens at a time:
+    inside a chunk the pseudo-values u solve (I + A) u = b (v - K~ S_0),
+    A strictly lower triangular, and the state moves once a chunk.  Decay
+    ratios are taken pairwise, exp(G_t - G_j) with j <= t, so no exponent
+    is ever positive.  Rows with g = 0 and b = 0 leave the state as it
+    was: that is how padding and the rows of other segments are masked;
+  * `step_rows`  — one token a row against a pool of slot states: the
+    decode step, and the decode rows of the ragged mixed step.
+
+`short_conv_*` are the causal depthwise convolution over the last
+`taps` positions in front of q, k and v.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+_HI = jax.lax.Precision.HIGHEST
+# tokens a chunk of the chunkwise form: part of the arithmetic (the order
+# the float32 recurrence is summed in), so a constant and no layer's knob
+CHUNK = 64
+
+
+def l2norm(x, eps: float = 1e-6):
+    """x / sqrt(sum(x^2) + eps) over the last dim, in float32."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+def decay(f, a_log, dt_bias):
+    """The log decay g = -exp(A_log_h) * softplus(f + dt_bias) <= 0:
+    f [..., H, dk] the gate projection, a_log [H], dt_bias [H, dk]."""
+    f = f.astype(jnp.float32) + dt_bias.astype(jnp.float32)
+    return -jnp.exp(a_log.astype(jnp.float32))[:, None] * jax.nn.softplus(f)
+
+
+# ---------------------------------------------------------------------------
+# the short convolution
+# ---------------------------------------------------------------------------
+
+def short_conv_whole(x, w):
+    """Causal depthwise convolution from an empty history: x [B, T, C],
+    w [taps, C] (w[-1] multiplies the current position) -> [B, T, C]: the
+    sum of `taps` shifted products."""
+    taps = w.shape[0]
+    T = x.shape[1]
+    y = x * w[taps - 1]
+    for j in range(1, taps):
+        shifted = jnp.pad(x, ((0, 0), (j, 0), (0, 0)))[:, :T]
+        y = y + shifted * w[taps - 1 - j]
+    return y
+
+
+def short_conv_rows(x, w, tail, seg_off, row_pos):
+    """The same convolution over a packed row list: x [R, C] in order,
+    `tail` [R, taps-1, C] each row's slot history (tail[:, -1] the most
+    recent position before the slot's first row of this step), `seg_off`
+    [R] the row's offset from that first row, `row_pos` [R] its global
+    position (taps reaching before position 0 read zero).  Returns
+    (y [R, C], hist [R, taps-1, C]): hist[r] is the history AFTER row r,
+    what the slot's tail becomes if r is its last row."""
+    taps = w.shape[0]
+    R = x.shape[0]
+    prev = []                                  # prev[j-1] = input at pos - j
+    for j in range(1, taps):
+        from_rows = jnp.pad(x, ((j, 0), (0, 0)))[:R]
+        # j - seg_off positions before the slot's first row: tail[-(j-off)]
+        idx = jnp.clip(taps - 1 - j + seg_off, 0, taps - 2)
+        from_tail = jnp.take_along_axis(
+            tail, idx[:, None, None], axis=1)[:, 0]
+        p = jnp.where((seg_off >= j)[:, None], from_rows, from_tail)
+        prev.append(jnp.where((row_pos >= j)[:, None], p, 0).astype(x.dtype))
+    y = x * w[taps - 1]
+    for j in range(1, taps):
+        y = y + prev[j - 1] * w[taps - 1 - j]
+    hist = jnp.stack(prev[::-1][1:] + [x], axis=1)
+    return y, hist
+
+
+# ---------------------------------------------------------------------------
+# the recurrence
+# ---------------------------------------------------------------------------
+
+def step(S, q, k, v, g, beta, scale: float):
+    """One token: S [..., dk, dv], q k g [..., dk], v [..., dv], beta [...]
+    -> (o [..., dv], S_new).  Elementwise products and reductions only."""
+    S = S * jnp.exp(g)[..., :, None]
+    u = beta[..., None] * (v - jnp.sum(S * k[..., :, None], axis=-2))
+    S = S + k[..., :, None] * u[..., None, :]
+    o = jnp.sum(S * (q * scale)[..., :, None], axis=-2)
+    return o, S
+
+
+def recurrent(q, k, v, g, beta, S0=None):
+    """The literal recurrence over T: q k g [B, T, H, dk], v [B, T, H, dv],
+    beta [B, T, H] -> (o [B, T, H, dv] float32, S [B, H, dk, dv])."""
+    f32 = lambda a: a.astype(jnp.float32)
+    q, k, v, g, beta = map(f32, (q, k, v, g, beta))
+    B, T, H, dk = q.shape
+    if S0 is None:
+        S0 = jnp.zeros((B, H, dk, v.shape[-1]), jnp.float32)
+    scale = dk ** -0.5
+
+    def body(S, xs):
+        o, S = step(S, *xs, scale)
+        return S, o
+
+    xs = tuple(jnp.moveaxis(a, 1, 0) for a in (q, k, v, g, beta))
+    S, o = jax.lax.scan(body, f32(S0), xs)
+    return jnp.moveaxis(o, 0, 1), S
+
+
+def chunkwise(q, k, v, g, beta, S0=None, live=None):
+    """The chunkwise form of `recurrent`, same arguments and results.  T
+    is padded to whole chunks with rows that leave the state alone.  `live`
+    [T] bool (shared by the batch) marks the rows that matter: a chunk with
+    no live row is skipped whole — its rows must already be masked (g = 0,
+    b = 0), its outputs are zeros — so a short segment of a long row list
+    costs its own chunks, not the list's."""
+    f32 = lambda a: a.astype(jnp.float32)
+    q, k, v, g, beta = map(f32, (q, k, v, g, beta))
+    B, T, H, dk = q.shape
+    dv = v.shape[-1]
+    C = min(CHUNK, T)
+    N = -(-T // C)
+    pad = N * C - T
+    if pad:
+        q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad)) +
+                                    ((0, 0),) * (a.ndim - 2))
+                            for a in (q, k, v, g, beta))
+    # [N, B, H, C, .]: the scan runs over chunks
+    cut = lambda a: jnp.moveaxis(
+        a.reshape((B, N, C) + a.shape[2:]), (1, 3), (0, 2))
+    q, k, v, g = map(cut, (q, k, v, g))
+    beta = cut(beta[..., None])                          # [N, B, H, C, 1]
+    any_live = jnp.ones((N,), bool) if live is None else \
+        jnp.any(jnp.pad(live, (0, pad)).reshape(N, C), axis=1)
+    t_idx = jnp.arange(C)
+    lower = t_idx[:, None] >= t_idx[None, :]
+    strict = lower & ~jnp.eye(C, dtype=bool)
+    eye = jnp.eye(C, dtype=jnp.float32)
+    scale = dk ** -0.5
+
+    def one_chunk(S, q, k, v, g, beta):
+        q = q * scale
+        G = jnp.cumsum(g, axis=2)                        # <= 0, falling
+        # pairwise decay exp(G_t - G_j) for j <= t, zero above the diagonal
+        D = G[..., :, None, :] - G[..., None, :, :]      # [B, H, C, C, dk]
+        E = jnp.exp(jnp.where(lower[:, :, None], D, -jnp.inf))
+        kk = jnp.sum(k[..., :, None, :] * k[..., None, :, :] * E, axis=-1)
+        qk = jnp.sum(q[..., :, None, :] * k[..., None, :, :] * E, axis=-1)
+        A = jnp.where(strict, beta * kk, 0.0)
+        k_in = k * jnp.exp(G)                            # decayed from S_0
+        WU = jax.scipy.linalg.solve_triangular(
+            eye + A, jnp.concatenate([beta * k_in, beta * v], axis=-1),
+            lower=True, unit_diagonal=True)
+        W, U = WU[..., :dk], WU[..., dk:]
+        G_last = G[..., -1:, :]                          # [B, H, 1, dk]
+        u = U - jnp.einsum("bhck,bhkv->bhcv", W, S, precision=_HI)
+        o = jnp.einsum("bhck,bhkv->bhcv", q * jnp.exp(G), S,
+                       precision=_HI) + \
+            jnp.einsum("bhcj,bhjv->bhcv", qk, u, precision=_HI)
+        S = jnp.exp(G_last[..., 0, :])[..., None] * S + \
+            jnp.einsum("bhck,bhcv->bhkv", k * jnp.exp(G_last - G), u,
+                       precision=_HI)                    # decayed to the end
+        return S, o
+
+    def body(S, xs):
+        some, rest = xs[0], xs[1:]
+        return jax.lax.cond(
+            some, lambda S: one_chunk(S, *rest),
+            lambda S: (S, jnp.zeros((B, H, C, dv), jnp.float32)), S)
+
+    if S0 is None:
+        S0 = jnp.zeros((B, H, dk, dv), jnp.float32)
+    S, o = jax.lax.scan(body, f32(S0), (any_live, q, k, v, g, beta))
+    o = jnp.moveaxis(o, (0, 2), (1, 3))                  # [B, N, C, H, dv]
+    return o.reshape(B, N * C, H, dv)[:, :T], S
+
+
+def step_rows(state, slot, live, q, k, v, g, beta, use_kernel: bool = False):
+    """One token a row against the slot states: state [S+1, H, dk, dv]
+    float32 (row S is trash), slot [R] int32 the state each row advances
+    (None: row r is slot r, the decode step), live [R] bool (a row that is
+    paused or padding leaves every state as it was), q k g [R, H, dk], v
+    [R, H, dv], beta [R, H] -> (o [R, H, dv] float32, state).  Each live
+    slot's state is read once and written once."""
+    f32 = lambda a: a.astype(jnp.float32)
+    q, k, v, g, beta = map(f32, (q, k, v, g, beta))
+    scale = q.shape[-1] ** -0.5
+    R, trash = q.shape[0], state.shape[0] - 1
+    if use_kernel:
+        from paddle_tpu.ops import pallas_kda
+        rows = jnp.arange(R, dtype=jnp.int32) if slot is None else slot
+        return pallas_kda.kda_step(state, jnp.where(live, rows, trash), live,
+                                   q, k, v, g, beta, scale)
+    if slot is None:
+        old = state[:R]
+        o, new = step(old, q, k, v, g, beta, scale)
+        return o, state.at[:R].set(
+            jnp.where(live[:, None, None, None], new, old))
+    slot = jnp.where(live, slot, trash)
+    o, new = step(state[slot], q, k, v, g, beta, scale)
+    return o, state.at[slot].set(new)
+
+
+def segment_rows(state, seg_slot, seg_pos, q, k, v, g, beta):
+    """The chunk rows of a ragged mixed step: P packed rows holding whole
+    runs of slots, contiguous and in order (`seg_slot` [P], trash row S =
+    padding; `seg_pos` [P] global positions).  Each run is one segment: it
+    starts from its slot's state — from zero where its first row is
+    position 0 — goes through `chunkwise` once, and leaves the state it
+    ends in.  One pass a segment present (a loop with a dynamic trip
+    count: a step usually holds one or two) over the chunks that hold its
+    rows, the others skipped.  Returns
+    (o [P, H, dv] float32, state, n_segments)."""
+    f32 = lambda a: a.astype(jnp.float32)
+    q, k, v, g, beta = map(f32, (q, k, v, g, beta))
+    S = state.shape[0] - 1
+    P = seg_slot.shape[0]
+    live = seg_slot < S
+    first = live & jnp.concatenate(
+        [jnp.ones((1,), bool), seg_slot[1:] != seg_slot[:-1]])
+    seg_id = jnp.cumsum(first.astype(jnp.int32)) - 1
+    n_seg = jnp.sum(first.astype(jnp.int32))
+
+    def body(i, carry):
+        state, o = carry
+        mine = live & (seg_id == i)
+        at = jnp.argmax(mine)                            # its first row
+        slot = seg_slot[at]
+        S0 = jnp.where(seg_pos[at] == 0, 0.0, state[slot])
+        m = mine[:, None]
+        o_i, S_end = chunkwise(
+            q[None], k[None], v[None], jnp.where(m[..., None], g, 0.0)[None],
+            jnp.where(m, beta, 0.0)[None], S0[None], live=mine)
+        return (state.at[slot].set(S_end[0]),
+                jnp.where(m[..., None], o_i[0], o))
+
+    o0 = jnp.zeros((P,) + v.shape[1:], jnp.float32)
+    state, o = jax.lax.fori_loop(0, n_seg, body, (state, o0))
+    return o, state, n_seg
+
+
+def gated_out_norm(o, gate, scale, eps: float):
+    """RMSNorm over each head's dv with a learned scale, times
+    sigmoid(gate): o gate [..., H, dv], scale [dv] -> float32."""
+    o = o.astype(jnp.float32)
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+    return o * scale.astype(jnp.float32) * jax.nn.sigmoid(
+        gate.astype(jnp.float32))
+
+
+def state_shapes(num_heads: int, head_dim: int, taps: int) -> dict:
+    """The row shapes of one layer's slot state: the recurrent state and
+    the convolution tail (q, k and v side by side)."""
+    return {"state": (num_heads, head_dim, head_dim),
+            "conv": (taps - 1, 3 * num_heads * head_dim)}

@@ -107,7 +107,9 @@ from paddle_tpu.obs.metrics import process_counters
 from paddle_tpu.obs.trace import get_tracer
 from paddle_tpu.parallel.mesh import MODEL_AXIS, axis_size
 from paddle_tpu.parameter.argument import Argument
-from paddle_tpu.serving.paged_kv import PagedKVCache
+from paddle_tpu.serving.paged_kv import (RECURRENT_REFUSALS, PagedKVCache,
+                                         refuse_for_recurrent,
+                                         slot_state_specs)
 from paddle_tpu.serving.prefix_tree import PrefixTree
 from paddle_tpu.serving.sampler import pick_next_chain, pick_next_per_slot
 
@@ -265,6 +267,7 @@ class ServingEngine:
         self._tp_ffn_pairs: list = []
         self._tp_lm_head: Optional[str] = None
         if self.tp > 1:
+            self._validate_tp(executor.model)
             if executor.mesh is not None and executor.mesh is not self.mesh:
                 raise ValueError(
                     "ServingEngine(mesh=...) conflicts with the executor's "
@@ -273,7 +276,6 @@ class ServingEngine:
             executor.mesh = self.mesh
             from jax.sharding import NamedSharding, PartitionSpec
             self._repl_sharding = NamedSharding(self.mesh, PartitionSpec())
-            self._validate_tp(executor.model)
             # params placed ONCE: attention projections sharded (w_q/w_k/
             # w_v by column = head, w_o by row), everything else
             # replicated — the tree is reused verbatim as the compiled
@@ -286,6 +288,20 @@ class ServingEngine:
                                pages_per_slot, num_pages,
                                mesh=self.mesh if self.tp > 1 else None,
                                spill_bytes_budget=spill_bytes_budget)
+        # RECURRENT LAYERS (graph/layers_kda.py): their context is a state
+        # a slot in the cache manager's slot-indexed parts, with no
+        # snapshot at a page boundary.  Everything that assumes the pages
+        # ARE the context is refused by name, each at one place
+        # (paged_kv.RECURRENT_REFUSALS: the mesh in _validate_tp above, the
+        # spill budget by the cache itself)
+        self._recurrent = list(self.kv.slot_specs)
+        if self._recurrent and prefix_cache:
+            import logging
+            logging.getLogger(__name__).info(
+                "model has %d recurrent layers: the prefix index is off "
+                "(%s) — every admission prefills from position 0",
+                len(self._recurrent), RECURRENT_REFUSALS["prefix"][1])
+            prefix_cache = False
         # the ONE canonical pool sharding, derived by the cache that owns
         # the pools — every jit that hands pools back pins to it
         self._pool_sharding = self.kv.pool_sharding
@@ -387,7 +403,8 @@ class ServingEngine:
         S = num_slots
         self._kk = self.kv.capacity_tokens     # keys per slot (> max_new)
         from paddle_tpu.ops.pallas_paged import block_tokens
-        pool = next(iter(next(iter(self.kv.pools.values())).values()))
+        pool = next(iter(next(iter(
+            self.kv.paged_pools().values())).values()))
         # a latent pool's row is one [W] vector: one KV "head" of width W
         h_kv = pool.shape[2] if pool.ndim == 4 else 1
         self._kv_block = block_tokens(
@@ -401,6 +418,12 @@ class ServingEngine:
         self.moe_pairs_total = 0       # routed pairs the held experts drew
         self.moe_pairs_max_sum = 0     # sum over steps of the busiest's
         self.moe_steps = 0             # steps counted
+        # recurrent-state counters, returned behind the tokens (and the
+        # MoE pairs) the same way: rows that advanced a state, slot states
+        # read and written summed over the recurrent layers, steps counted
+        self.recurrent_rows = 0
+        self.recurrent_slot_updates = 0
+        self.recurrent_steps = 0
         self._kv_synced = -1                   # kv.version last uploaded
         self._slots_dirty = True
         self._run_host: Optional[np.ndarray] = None
@@ -510,7 +533,9 @@ class ServingEngine:
     def _validate_tp(self, model) -> None:
         """Head counts must divide over the `model` axis: each device owns
         whole query heads and whole kv heads (the shard_map attention core
-        and the pool's kv-head partition both depend on it)."""
+        and the pool's kv-head partition both depend on it); recurrent and
+        latent layers have no sharded layout at all."""
+        refuse_for_recurrent(slot_state_specs(model), "mesh")
         for l in model.layers:
             if l.type == "mla_attention":
                 raise ValueError(
@@ -1270,6 +1295,10 @@ class ServingEngine:
             adv[s] = 1
             emit[s] = True
             r += 1
+        if self._recurrent:
+            # the recurrent layers' packing contract (graph/layers_kda.py):
+            # rows [0, S) are single rows, the chunks start at row S
+            r = S
         advanced, r = self._pack_chunk_rows(
             filling, row_ids, row_slot, row_pos, sample_row, adv, emit,
             r, T - r)
@@ -1824,6 +1853,7 @@ class ServingEngine:
         of `tokens` for a kv_push: returns (covered_tokens, meta, payload)
         or None when nothing is cached.  Pump thread only (walks the
         prefix tree and gathers from the pools between steps)."""
+        refuse_for_recurrent(self.kv.slot_specs, "export")
         if self.prefix is None:
             return None
         toks = np.asarray(tokens, np.int32).reshape(-1)
@@ -1843,6 +1873,7 @@ class ServingEngine:
         a malformed blob or page starvation; returns nodes newly added.
         Pump thread only: kv.pools is authoritative between steps, so the
         scatter is exactly as safe as an admission-time spill restore."""
+        refuse_for_recurrent(self.kv.slot_specs, "import")
         if self.prefix is None:
             raise ValueError("kv import: prefix cache is disabled")
         toks = np.asarray(tokens, np.int32).reshape(-1)
@@ -2056,6 +2087,8 @@ class ServingEngine:
         if spec_k < 0:
             raise ValueError(
                 f"spec_k must be >= 0 (0 = speculation off), got {spec_k}")
+        if spec_k > 0:
+            refuse_for_recurrent(self.kv.slot_specs, "spec")
         self.spec_k = spec_k
         if dynamic is not None:
             self.spec_dynamic = bool(dynamic)
@@ -2126,6 +2159,7 @@ class ServingEngine:
         if enabled == (self.prefix is not None):
             return
         if enabled:
+            refuse_for_recurrent(self.kv.slot_specs, "prefix")
             self.prefix = PrefixTree(self.kv)
             self.kv.on_page_pressure = self._evict_for
             return
@@ -2214,7 +2248,9 @@ class ServingEngine:
                        "spec_k": self.spec_k,
                        "prefix_cache": self.prefix is not None,
                        "spill_bytes_budget": kv.spill_bytes_budget,
-                       "layer_specs": dict(kv.layer_specs)},
+                       "layer_specs": dict(kv.layer_specs),
+                       **({"slot_specs": dict(kv.slot_specs)}
+                          if kv.slot_specs else {})},
             "pools": {name: {p: np.asarray(a).copy()
                              for p, a in pool.items()}
                       for name, pool in kv.pools.items()},
@@ -2285,7 +2321,9 @@ class ServingEngine:
                 "spec_k": self.spec_k,
                 "prefix_cache": self.prefix is not None,
                 "spill_bytes_budget": self.kv.spill_bytes_budget,
-                "layer_specs": dict(self.kv.layer_specs)}
+                "layer_specs": dict(self.kv.layer_specs),
+                **({"slot_specs": dict(self.kv.slot_specs)}
+                   if self.kv.slot_specs else {})}
         if mine != cfg:
             diff = {k: (cfg[k], mine[k]) for k in cfg if cfg[k] != mine[k]}
             raise ValueError(
@@ -2479,7 +2517,7 @@ class ServingEngine:
         batch-independent and their writes land in the trash page)."""
         S = st.toks.shape[0]
         table = st.table[:S]                  # drop the virtual trash row
-        state = self._layer_state(st, page_table=table, pos=st.pos)
+        state = self._layer_state(st, run, page_table=table, pos=st.pos)
         feed = {self.input_name: Argument(ids=st.toks[:, None],
                                           lengths=jnp.ones((S,), jnp.int32))}
         outputs, _, state_out = self.executor.forward(params, feed, state,
@@ -2488,7 +2526,7 @@ class ServingEngine:
         nxt = pick_next_per_slot(last, self._slot_keys(st), st.temp,
                                  st.topk, st.topp, is_probs=self._probs)
         new_pools = self._pools_out(st, state_out)
-        nxt = self._with_moe_pairs(nxt, state_out, run)
+        nxt = self._with_counts(nxt, state_out, run)
         runi = run.astype(jnp.int32)
         new_st = EngineState(pools=new_pools, table=st.table,
                              pos=st.pos + runi,
@@ -2550,7 +2588,7 @@ class ServingEngine:
         (mid-prefill, paused, empty) sample a padding/decode row's logits
         — computed and discarded, their state frozen by the masks."""
         T = row_ids.shape[0]
-        state = self._layer_state(st, page_table=st.table,
+        state = self._layer_state(st, None, page_table=st.table,
                                   row_slot=row_slot, row_pos=row_pos)
         feed = {self.input_name: Argument(
             ids=row_ids[None, :], lengths=jnp.full((1,), T, jnp.int32))}
@@ -2569,41 +2607,71 @@ class ServingEngine:
                              keys=st.keys, temp=st.temp, topk=st.topk,
                              topp=st.topp)
         # a padding row aims at the virtual trash table row S
-        return new_st, self._with_moe_pairs(nxt, state_out, row_slot < S)
+        return new_st, self._with_counts(nxt, state_out, row_slot < S)
 
-    def _layer_state(self, st: EngineState, **shared) -> dict:
+    def _layer_state(self, st: EngineState, run, **shared) -> dict:
         """The state dict a paged step hands the executor: each attention
         layer's pool parts as `<part>_pages` (k_pages and v_pages; a latent
-        layer's kv_pages) beside the step's shared operands, and an empty
-        entry for each MoE layer — the request for its routed pairs."""
+        layer's kv_pages) beside the step's shared operands; a recurrent
+        layer's slot-indexed parts under their own names, with the step's
+        `run` mask where the step has one (a paused slot's state must not
+        advance; the K/V layers never see it); and an empty entry for each
+        MoE layer — the request for its routed pairs."""
+        rec = set(self._recurrent)
         state = {name: dict({part + "_pages": a for part, a in pool.items()},
                             **shared)
-                 for name, pool in st.pools.items()}
+                 for name, pool in st.pools.items() if name not in rec}
+        if run is not None:
+            shared = dict(shared, run=run)
+        state.update({name: dict(st.pools[name], **shared) for name in rec})
         state.update({name: {} for name in self._moe_layers})
         return state
 
-    @staticmethod
-    def _pools_out(st: EngineState, state_out: dict) -> dict:
-        return {name: {part: state_out[name][part + "_pages"]
+    def _pools_out(self, st: EngineState, state_out: dict) -> dict:
+        rec = set(self._recurrent)
+        return {name: {part: state_out[name][
+                           part if name in rec else part + "_pages"]
                        for part in pool}
                 for name, pool in st.pools.items()}
 
-    def _with_moe_pairs(self, nxt, state_out: dict, live_rows):
-        """`nxt` with the routed pairs of the held experts behind it:
-        [S + E_held] int32, the pairs summed over the MoE layers and the
-        live rows — one array, one read-back.  `nxt` itself without MoE
-        layers."""
-        if not self._moe_layers:
+    def _with_counts(self, nxt, state_out: dict, live_rows):
+        """`nxt` with the step's device-side counts behind it, one array
+        and one read-back: [S + E_held (+ 2)] int32 — the routed pairs of
+        the held experts, summed over the MoE layers and the live rows,
+        then for a model with recurrent layers the rows that advanced a
+        state and the slot states read and written, summed over those
+        layers.  `nxt` itself for a model with neither."""
+        tail = []
+        if self._moe_layers:
+            live = live_rows.reshape(-1, 1)
+            tail.append(sum(
+                jnp.sum(jnp.logical_and(state_out[name]["pairs"], live),
+                        axis=0, dtype=jnp.int32)
+                for name in self._moe_layers))
+        if self._recurrent:
+            tail.append(jnp.stack([
+                state_out[self._recurrent[0]]["rows"],
+                sum(state_out[name]["updates"]
+                    for name in self._recurrent)]))
+        if not tail:
             return nxt
-        live = live_rows.reshape(-1, 1)
-        pairs = sum(jnp.sum(jnp.logical_and(state_out[name]["pairs"], live),
-                            axis=0, dtype=jnp.int32)
-                    for name in self._moe_layers)
-        return jnp.concatenate([nxt.astype(jnp.int32), pairs])
+        return jnp.concatenate([nxt.astype(jnp.int32)] + tail)
 
     def _count_moe(self, nxt: np.ndarray, n_rows: int) -> np.ndarray:
-        """Split a step's read-back into its tokens and the MoE pair
-        counts behind them; bank the counts.  Returns the tokens."""
+        """Split a step's read-back into its tokens and the counts behind
+        them (the MoE pairs, then the two recurrent counts); bank the
+        counts.  Returns the tokens."""
+        if self._recurrent:
+            rec = nxt[..., -2:].reshape(-1, 2)
+            nxt = nxt[..., :-2]
+            self.recurrent_rows += int(rec[:, 0].sum())
+            self.recurrent_slot_updates += int(rec[:, 1].sum())
+            self.recurrent_steps += rec.shape[0]
+            pc = process_counters()
+            pc.add("serving_recurrent_rows_total", int(rec[:, 0].sum()))
+            pc.add("serving_recurrent_slot_updates_total",
+                   int(rec[:, 1].sum()))
+            pc.add("serving_recurrent_steps_total", rec.shape[0])
         if nxt.shape[-1] > n_rows:
             pairs = nxt[..., n_rows:].reshape(-1, nxt.shape[-1] - n_rows)
             total, busiest = int(pairs.sum()), int(pairs.max(axis=1).sum())
@@ -2644,7 +2712,7 @@ class ServingEngine:
         T = row_ids.shape[0]
         S = st.toks.shape[0]
         K = draft_toks.shape[1]
-        state = self._layer_state(st, page_table=st.table,
+        state = self._layer_state(st, None, page_table=st.table,
                                   row_slot=row_slot, row_pos=row_pos)
         feed = {self.input_name: Argument(
             ids=row_ids[None, :], lengths=jnp.full((1,), T, jnp.int32))}

@@ -6,6 +6,22 @@ width HBM tiling gives the row anyway) — instead of a K and a V pool: its cach
 every head reads (ops/mla.py); allocation, sharing, COW, spill and transfer
 below walk each layer's own parts, so they are the same code for both.
 
+TWO FAMILIES OF PARTS.  The above are PAGE-indexed: a token's row lives in
+the page its slot's table maps.  A recurrent layer (kda_attention,
+graph/layers_kda.py) holds SLOT-indexed parts instead: `state` [S+1, H,
+dk, dv] float32 and `conv` [S+1, taps-1, C] in the compute dtype, one row
+a slot (row S is the trash row padding and paused rows aim at), whatever
+the context's length.  Both families sit in `self.pools` under the
+layer's name and thread through the engine's steps donated alike;
+`layer_specs` names the page-indexed layers, `slot_specs` the slot-indexed
+ones.  Allocation, COW, spill, export/import and `check()` walk the
+page-indexed parts (`paged_pools()`): a slot's state is never shared,
+copied or moved, and it holds no snapshot at a page boundary — which is
+why a model with recurrent layers serves without the prefix index, the
+spill tier and the transfer plane (`RECURRENT_REFUSALS`: one sentence and
+one place of refusal each).  The memory accounting covers both
+(`pool_bytes`, `slot_state_bytes`).
+
 Replaces the dense `lm_decode.init_kv_caches` layout for SERVING: a dense
 cache sizes every row at P+max_new whatever the row actually holds, and its
 [B, total, ...] shape bakes the request mix into the compiled program.
@@ -77,6 +93,57 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# What a model with recurrent layers cannot use, and the one sentence that
+# says why (refuse_for_recurrent): a slot's recurrent state has no snapshot
+# at a page boundary, so pages alone are not the context.  Each
+# is refused at ONE place: the spill budget here, where it is set; the mesh
+# (with the other layers' tensor-parallel checks), the prefix index,
+# speculation and the transfer plane's two ends in serving/engine.py; the
+# role in serving/server.py.
+RECURRENT_REFUSALS = {
+    "mesh": ("--mesh model=N (tensor-parallel serving)",
+             "the slot state has no head-sharded layout"),
+    "spill": ("the KV spill tier",
+              "a spilled page could not bring its slot's recurrent state "
+              "back"),
+    "prefix": ("the prefix index",
+               "a prefix hit would need the recurrent state at the hit's "
+               "last page boundary, and none is kept"),
+    "export": ("export_prefix (--role prefill)",
+               "exported pages would arrive without the recurrent state "
+               "that goes with them"),
+    "import": ("import_prefix (--role decode)",
+               "imported pages carry no recurrent state to resume from"),
+    "spec": ("--spec-k > 0 (speculative decoding)",
+             "a rejected draft would need the recurrent state rolled back, "
+             "and the verify step keeps no copy"),
+    "role": ("--role prefill|decode (disaggregated prefill/decode)",
+             "pages pushed between replicas carry no recurrent state"),
+}
+
+
+def slot_state_specs(model) -> dict[str, dict[str, tuple]]:
+    """{layer name: {part: row shape}} of the model's recurrent layers'
+    slot-indexed parts, in layer order (module docstring "TWO FAMILIES OF
+    PARTS"); empty for a model without recurrent layers."""
+    from paddle_tpu.ops.kda import state_shapes
+    return {l.name: state_shapes(int(l.attrs["num_heads"]),
+                                 int(l.attrs["head_dim"]),
+                                 int(l.attrs.get("conv_size", 4)))
+            for l in model.layers if l.type == "kda_attention"}
+
+
+def refuse_for_recurrent(slot_specs: dict, mechanism: str) -> None:
+    """Raise, for a model with recurrent layers (`slot_specs` not empty),
+    for a mechanism that assumes the pages are the whole context (a key of
+    RECURRENT_REFUSALS): one sentence naming what is missing."""
+    if slot_specs:
+        what, why = RECURRENT_REFUSALS[mechanism]
+        raise ValueError(
+            f"{what} is not available for a model with recurrent layers "
+            f"({len(slot_specs)} here): {why} (ROADMAP R5: state snapshots "
+            f"at page boundaries)")
+
 
 class PagedKVCache:
     """Device page pools + host page allocator for `num_slots` decode slots.
@@ -123,7 +190,16 @@ class PagedKVCache:
         # columns, so there is no second tensor).  Everything below walks
         # `self.pools[name]`'s own parts and shapes, never a fixed pair.
         self.layer_specs: dict[str, tuple] = {}
-        self.pools: dict[str, dict[str, jnp.ndarray]] = {}
+        # slot_specs[name] = {part: row shape} of a recurrent layer's
+        # slot-indexed parts (module docstring "TWO FAMILIES OF PARTS")
+        self.slot_specs = slot_state_specs(executor.model)
+        # the state is float32 whatever the compute dtype: it is what the
+        # recurrence accumulates in
+        self.pools: dict[str, dict[str, jnp.ndarray]] = {
+            name: {part: jnp.zeros((num_slots + 1,) + row,
+                                   jnp.float32 if part == "state" else dtype)
+                   for part, row in rows.items()}
+            for name, rows in self.slot_specs.items()}
         for l in executor.model.layers:
             if l.type == "multi_head_attention":
                 heads = int(l.attrs["num_heads"])
@@ -153,7 +229,9 @@ class PagedKVCache:
                     if self.pool_sharding is not None else z
 
             self.pools[l.name] = {part: _pool() for part in parts}
-        assert self.pools, "model has no attention layers to page"
+        assert self.layer_specs, \
+            "model has no attention layers to page (a model whose every " \
+            "layer is recurrent holds no page-indexed part: not supported)"
 
         # host allocator state: table[s, j] = physical page backing logical
         # page j of slot s (0 = unmapped -> trash)
@@ -178,7 +256,7 @@ class PagedKVCache:
         # hid -> {"gen", "nbytes", "data": {layer: {part: ndarray}}}; the
         # prefix index owns the POLICY (who spills, who drops) — this is
         # the mechanism + the byte accounting
-        self.spill_bytes_budget = int(spill_bytes_budget or 0)
+        self.spill_bytes_budget = spill_bytes_budget
         self._host: dict[int, dict] = {}
         self._next_hid = 1
         self._host_bytes = 0
@@ -234,10 +312,33 @@ class PagedKVCache:
         return int(np.sum((self._ref == 0) & self._cached))
 
     @property
+    def spill_bytes_budget(self) -> int:
+        return self._spill_bytes_budget
+
+    @spill_bytes_budget.setter
+    def spill_bytes_budget(self, nbytes: int) -> None:
+        if int(nbytes or 0) > 0:
+            refuse_for_recurrent(self.slot_specs, "spill")
+        self._spill_bytes_budget = int(nbytes or 0)
+
+    def paged_pools(self) -> dict:
+        """The page-indexed layers' pools: what allocation, COW, spill and
+        transfer walk (a recurrent layer's slot-indexed parts are not
+        theirs to touch)."""
+        return {name: self.pools[name] for name in self.layer_specs}
+
+    @property
     def pool_bytes(self) -> int:
         """Total device bytes of the K/V page pools (all shards)."""
         return sum(int(a.size) * a.dtype.itemsize
-                   for p in self.pools.values() for a in p.values())
+                   for p in self.paged_pools().values() for a in p.values())
+
+    @property
+    def slot_state_bytes(self) -> int:
+        """Total device bytes of the recurrent layers' slot-indexed parts."""
+        return sum(int(a.size) * a.dtype.itemsize
+                   for name in self.slot_specs
+                   for a in self.pools[name].values())
 
     @property
     def pool_bytes_per_shard(self) -> int:
@@ -444,7 +545,7 @@ class PagedKVCache:
     def page_nbytes(self) -> int:
         """Host bytes one spilled page costs: every part of every layer."""
         return sum(int(np.prod(a.shape[1:])) * a.dtype.itemsize
-                   for p in self.pools.values() for a in p.values())
+                   for p in self.paged_pools().values() for a in p.values())
 
     @property
     def host_page_count(self) -> int:
@@ -712,8 +813,11 @@ class PagedKVCache:
     # -- device page copy (COW) -------------------------------------------
     def _page_copy(self):
         if self._copy_fn is None:
+            paged = set(self.layer_specs)
+
             def copy(pools, dst, src):
                 return {name: {part: a.at[dst].set(a[src])
+                               if name in paged else a
                                for part, a in pool.items()}
                         for name, pool in pools.items()}
 

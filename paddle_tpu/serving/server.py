@@ -74,6 +74,7 @@ from paddle_tpu.obs.timeseries import (HistorySampler, MetricHistory,
 from paddle_tpu.obs.trace import annotation, trace_reply
 from paddle_tpu.serving import wire
 from paddle_tpu.serving.engine import Request, ServingEngine
+from paddle_tpu.serving.paged_kv import refuse_for_recurrent
 from paddle_tpu.utils.stat import StatSet
 
 
@@ -187,6 +188,8 @@ class ServingServer:
                  history_resolution_s: float = 5.0,
                  history_retention_s: float = 1800.0, slo_specs=None):
         assert role in ("prefill", "decode", "both"), role
+        if role != "both":
+            refuse_for_recurrent(engine.kv.slot_specs, "role")
         self.engine = engine
         self.host = host
         self.port = port
@@ -365,6 +368,16 @@ class ServingServer:
                  float(eng.moe_pairs_max_sum)),
                 ("serving_moe_steps_total", "counter", None,
                  float(eng.moe_steps)),
+                # recurrent layers: rows that advanced a slot state, slot
+                # states read and written, steps counted; the state's bytes
+                ("serving_recurrent_rows_total", "counter", None,
+                 float(eng.recurrent_rows)),
+                ("serving_recurrent_slot_updates_total", "counter", None,
+                 float(eng.recurrent_slot_updates)),
+                ("serving_recurrent_steps_total", "counter", None,
+                 float(eng.recurrent_steps)),
+                ("serving_slot_state_bytes", "gauge", None,
+                 float(eng.kv.slot_state_bytes)),
                 # multi-step decode: scan body iterations vs boundary
                 # flushes — steps/flushes ≈ decode_steps in steady state
                 ("serving_scan_steps_total", "counter", None,
@@ -871,6 +884,12 @@ class ServingServer:
             "tp_shards": int(eng.tp),
             "kv_pool_bytes_per_shard": _safe(
                 lambda: int(eng.kv.pool_bytes_per_shard)),
+            "slot_state_bytes": _safe(
+                lambda: int(eng.kv.slot_state_bytes)),
+            "layer_specs": _safe(lambda: {
+                "paged": {n: list(r) for n, r in eng.kv.layer_specs.items()},
+                "slot": {n: {p: list(r) for p, r in parts.items()}
+                         for n, parts in eng.kv.slot_specs.items()}}),
             "n_decode_steps": eng.n_decode_steps,
             "tokens_generated": eng.tokens_generated,
             "n_preemptions": eng.n_preemptions,
@@ -1557,6 +1576,11 @@ class ServingServer:
             "moe_pairs_total": eng.moe_pairs_total,
             "moe_pairs_max_sum": eng.moe_pairs_max_sum,
             "moe_steps": eng.moe_steps,
+            # recurrent layers (0 without them): rows that advanced a slot
+            # state, slot states read and written, steps counted
+            "recurrent_rows": eng.recurrent_rows,
+            "recurrent_slot_updates": eng.recurrent_slot_updates,
+            "recurrent_steps": eng.recurrent_steps,
             # speculative decoding: the A/B-able knobs + the counters the
             # accept rate reconciles from, plus the adaptive state
             # (drafter kind, dynamic-k flag, per-slot learned EWMAs)
@@ -1579,6 +1603,7 @@ class ServingServer:
             # sharding: model-axis shard count + per-device pool bytes
             "tp_shards": eng.tp,
             "kv_pool_bytes_per_shard": int(eng.kv.pool_bytes_per_shard),
+            "slot_state_bytes": int(eng.kv.slot_state_bytes),
         }
 
     def _stats_msg(self, engine_part: Optional[dict]) -> dict:

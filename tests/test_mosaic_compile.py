@@ -55,8 +55,9 @@ def mosaic(one_chip, no_persistent_cache, monkeypatch):
     in the result.  The kernels ask `jax.default_backend()` (sees `cpu`
     here) to pick interpret mode — steer that from the test."""
     from paddle_tpu.ops import (pallas_additive, pallas_attention,
-                                pallas_paged, pallas_rnn)
-    for mod in (pallas_additive, pallas_attention, pallas_paged, pallas_rnn):
+                                pallas_kda, pallas_paged, pallas_rnn)
+    for mod in (pallas_additive, pallas_attention, pallas_kda, pallas_paged,
+                pallas_rnn):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
     def compile_(fn, *shapes, donate=()):
@@ -350,6 +351,55 @@ def test_latent_paged_kernel_at_the_cells_shape(mosaic, rows):
     made_by = re.findall(r"= bf16\[16385,16,640\]\S* ([\w-]+)\(",
                          compiled.as_text())
     assert made_by and "copy" not in made_by, made_by
+
+
+# the hybrid linear-attention cell's own shapes (benchmark/configs/
+# kimi-linear-48b-a3b-serve.json: 128 slots, 32 KDA heads whose state is
+# 128 x 128 float32; 32 query heads against the 576-wide latent row)
+KDA = dict(S=128, H=32, D=128)
+
+
+@pytest.mark.parametrize("rows", ["decode", "mixed-rows"])
+def test_kda_step_kernel_at_the_cells_shape(mosaic, rows):
+    """The KDA decode step through the call the layer makes
+    (ops/kda.py: step_rows): one `kda_step` call, and — the state pool
+    donated and aliased — no copy of the 270 MB pool on its way in."""
+    from paddle_tpu.ops import kda
+    c = KDA
+    R, H, D = c["S"], c["H"], c["D"]
+
+    def step(state, slot, live, q, k, v, g, beta):
+        return kda.step_rows(state, None if rows == "decode" else slot,
+                             live, q, k, v, g, beta, use_kernel=True)
+
+    vec = ((R, H, D), f32)
+    compiled = mosaic(step, ((c["S"] + 1, H, D, D), f32), ((R,), i32),
+                      ((R,), jnp.bool_), vec, vec, vec, vec, ((R, H), f32),
+                      donate=(0,))
+    assert kernel_names(compiled) == ["kda_step.1"], kernel_names(compiled)
+    import re
+    made_by = re.findall(r"= f32\[129,32,128,128\]\S* ([\w-]+)\(",
+                         compiled.as_text())
+    assert made_by and "copy" not in made_by, made_by
+
+
+def test_latent_paged_kernel_at_32_heads(mosaic):
+    """`mla_paged_attn` at the hybrid cell's head count: 128 decode rows of
+    32 query heads against the same 640-lane latent row."""
+    from paddle_tpu.ops import mla
+    c = dict(LATENT, S=128, H=32, POOL=128 * 256 + 1)
+
+    def step(q, new, pool, table, row_slot, row_pos):
+        return mla.paged_latent_step(q, new, pool, table, row_slot, row_pos,
+                                     0.1, c["V"], use_kernel=True)
+
+    compiled = mosaic(
+        step, ((128, c["H"], c["WP"]), bf16), ((128, c["WP"]), bf16),
+        ((c["POOL"], c["PAGE"], c["WP"]), bf16),
+        ((c["S"] + 1, c["MAXP"]), i32), ((128,), i32), ((128,), i32),
+        donate=(2,))
+    assert kernel_names(compiled) == ["mla_paged_attn.1"], \
+        kernel_names(compiled)
 
 
 @pytest.mark.parametrize("form", ["decode", "mixed"])
