@@ -126,6 +126,20 @@ class GraphExecutor:
     def static_param_names(self) -> set[str]:
         return {p.name for p in self.model.parameters if p.is_static}
 
+    def cast_params(self, params: dict[str, Array]) -> dict[str, Array]:
+        """The parameters as the layers compute with them: floating leaves
+        in `compute_dtype`, everything else — and every leaf when no
+        compute dtype is set — as given.  A leaf that already has the
+        compute dtype IS the given leaf (no copy, and no op inside a jit),
+        so a caller whose weights do not change between steps casts once
+        outside the step (ServingEngine.params) and `prepare` then finds
+        nothing to do."""
+        if not self.compute_dtype:
+            return params
+        dt = jnp.dtype(self.compute_dtype)
+        return {k: (v.astype(dt) if jnp.issubdtype(v.dtype, jnp.floating)
+                    and v.dtype != dt else v) for k, v in params.items()}
+
     # -- forward ----------------------------------------------------------
     def prepare(self, params: dict[str, Array], feed: dict[str, Argument]):
         """Pre-forward transforms shared by every execution path (plain
@@ -135,10 +149,9 @@ class GraphExecutor:
         if static:
             params = {k: (jax.lax.stop_gradient(v) if k in static else v)
                       for k, v in params.items()}
+        params = self.cast_params(params)
         if self.compute_dtype:
             dt = jnp.dtype(self.compute_dtype)
-            params = {k: (v.astype(dt) if jnp.issubdtype(v.dtype, jnp.floating)
-                          else v) for k, v in params.items()}
             def _cast(arg):
                 if (arg.value is not None
                         and jnp.issubdtype(arg.value.dtype, jnp.floating)):

@@ -1,9 +1,10 @@
 """Device-memory accounting: HBM gauges with a CPU-safe fallback.
 
-The KV page pools and the parameter arrays are the two deliberate HBM
-tenants of a serving replica; everything else (prefill activations, a
-leaked buffer from a bug) shows up as the gap between them and the
-device's own accounting.  The production failure mode this makes visible
+The KV page pools and the parameter arrays (with the copies a serving
+engine derives from them in the compute dtype, `hbm_step_weight_bytes`)
+are the deliberate HBM tenants of a serving replica; everything else
+(prefill activations, a leaked buffer from a bug) shows up as the gap
+between them and the device's own accounting.  The production failure mode this makes visible
 is HBM exhaustion of the page pools (the headroom signal the TPU serving
 literature treats as first-class, arXiv:2605.25645): when
 `hbm_bytes_in_use` approaches `hbm_bytes_limit` while `hbm_kv_pool_bytes`
@@ -87,15 +88,19 @@ def kv_pool_bytes(kv) -> int:
 
 
 def hbm_collector(params_fn: Optional[Callable] = None,
-                  kv_fn: Optional[Callable] = None):
+                  kv_fn: Optional[Callable] = None,
+                  step_weight_bytes_fn: Optional[Callable] = None):
     """obs.metrics collector for the hbm_* gauges.
 
     `params_fn()` -> the live params pytree (a callable, not a snapshot —
-    donated buffers rebind every step); `kv_fn()` -> the PagedKVCache.
-    Either may be None (the trainer has no KV pool; a bare tool has no
-    params).  Backend gauges are EMITTED ONLY WHEN THE PROBE ANSWERS —
-    an absent `hbm_bytes_in_use` means "backend does not report", a zero
-    would lie."""
+    donated buffers rebind every step); `kv_fn()` -> the PagedKVCache;
+    `step_weight_bytes_fn()` -> the bytes of the copies a serving engine
+    derived from its params for the compiled steps (0 where the steps
+    take the params themselves).  Each may be None (the trainer has no KV
+    pool and no derived weights; a bare tool has no params).  Backend
+    gauges are EMITTED ONLY WHEN THE PROBE ANSWERS — an absent
+    `hbm_bytes_in_use` means "backend does not report", a zero would
+    lie."""
 
     def collect():
         out = []
@@ -115,6 +120,9 @@ def hbm_collector(params_fn: Optional[Callable] = None,
         if params_fn is not None:
             out.append(("hbm_param_bytes", "gauge", None,
                         float(tree_bytes(params_fn()))))
+        if step_weight_bytes_fn is not None:
+            out.append(("hbm_step_weight_bytes", "gauge", None,
+                        float(step_weight_bytes_fn())))
         if kv_fn is not None:
             out.append(("hbm_kv_pool_bytes", "gauge", None,
                         float(kv_pool_bytes(kv_fn()))))
@@ -123,7 +131,8 @@ def hbm_collector(params_fn: Optional[Callable] = None,
     return collect
 
 
-def hbm_snapshot(params=None, kv=None) -> dict:
+def hbm_snapshot(params=None, kv=None,
+                 step_weight_bytes: Optional[int] = None) -> dict:
     """One-shot dict of everything measurable — the postmortem-bundle
     shape (and a convenient REPL probe)."""
     out: dict = {}
@@ -135,6 +144,8 @@ def hbm_snapshot(params=None, kv=None) -> dict:
         out["live_array_bytes"], out["live_arrays"] = live
     if params is not None:
         out["param_bytes"] = tree_bytes(params)
+    if step_weight_bytes is not None:
+        out["step_weight_bytes"] = int(step_weight_bytes)
     if kv is not None:
         out["kv_pool_bytes"] = kv_pool_bytes(kv)
     return out

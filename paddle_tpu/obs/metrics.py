@@ -85,6 +85,13 @@ CATALOG: dict[str, str] = {
         "compiled steps whose recurrent rows were counted",
     "serving_slot_state_bytes":
         "device bytes of the recurrent layers' slot-indexed state",
+    # -- the weights a step reads: cast once when params is set -----------
+    "serving_step_weight_casts_total":
+        "weight trees derived for the compiled steps that copied at least "
+        "one leaf into the compute dtype (0 where the dtypes agree)",
+    "serving_step_weight_cast_bytes_total":
+        "bytes of the compute-dtype weight copies made for the compiled "
+        "steps",
     # -- token delivery: a step's frames leave as one write a connection --
     "serving_token_frames_total":
         "streamed token frames written to client connections",
@@ -299,6 +306,10 @@ CATALOG: dict[str, str] = {
     "hbm_live_array_bytes": "total nbytes over jax.live_arrays()",
     "hbm_live_arrays": "count of live device arrays",
     "hbm_param_bytes": "bytes held by the model parameter pytree",
+    "hbm_step_weight_bytes":
+        "bytes of the compute-dtype copies a serving engine derived from "
+        "its parameters for the compiled steps (0 where the steps take "
+        "the parameters themselves)",
     "hbm_kv_pool_bytes": "bytes held by the paged KV cache pools",
     # -- flight recorder (obs/flight.py) -----------------------------------
     "flight_events_recorded_total":

@@ -282,7 +282,7 @@ class ServingEngine:
             # steps' in_shardings, so placement and jit can never diverge
             self._param_shardings_tree = self._tp_param_shardings(params)
             params = jax.device_put(params, self._param_shardings_tree)
-        self.params = params
+        self.params = params        # the property: derives the steps' tree
         pages_per_slot = -(-int(max_context) // int(page_size))
         self.kv = PagedKVCache(executor, num_slots, page_size,
                                pages_per_slot, num_pages,
@@ -647,6 +647,39 @@ class ServingEngine:
                 if l.type in ("layer_norm", "addto"):
                     l.attrs.setdefault("tp_out", "replicated")
         return sh
+
+    # -- the weights a step reads -----------------------------------------
+    @property
+    def params(self):
+        """The weights exactly as given (dtype included): what checkpoints,
+        the drafter, `hbm_param_bytes` and an embedder's own checks read."""
+        return self._params
+
+    @params.setter
+    def params(self, params):
+        """Take a weight tree and derive the compiled steps' operands from
+        it ONCE, in the executor's compute dtype (`cast_params`, the
+        predicate `prepare` applies inside a step): weights do not change
+        between steps, so a cast inside the step would read the whole
+        given tree every step.  A leaf already in the compute dtype is
+        shared, not copied — given bf16 under bf16 compute, or no compute
+        dtype, the two trees are one.  Per-leaf `astype` keeps each leaf's
+        sharding, so `_param_shardings_tree` describes the derived tree
+        too.  The previous derived tree is dropped first: a swap peaks at
+        the old and new given trees plus one derived tree.  Pages the
+        prefix index cached under the previous weights are left alone:
+        assign before serving, or to an engine without the index."""
+        self._step_params = None
+        self._params = params
+        self._step_params = self.executor.cast_params(params)
+        self.step_weight_bytes = sum(
+            int(v.nbytes) for k, v in self._step_params.items()
+            if v is not params[k])
+        if self.step_weight_bytes:
+            pc = process_counters()
+            pc.add("serving_step_weight_casts_total", 1)
+            pc.add("serving_step_weight_cast_bytes_total",
+                   self.step_weight_bytes)
 
     def _state_shardings(self) -> "EngineState":
         pool = self.kv.pool_shardings()
@@ -1111,8 +1144,8 @@ class ServingEngine:
         with self._compiled_step("decode", live=len(live),
                                  step=self.n_decode_steps + 1):
             with self._phase("dispatch"):
-                st, nxt = self._decode_step(self.params, self._build_state(),
-                                            self._d_run)
+                st, nxt = self._decode_step(
+                    self._step_params, self._build_state(), self._d_run)
             self._unpack_state(st)
             self.n_decode_steps += 1
             self.occupancy_sum += len(live) / S
@@ -1214,7 +1247,7 @@ class ServingEngine:
                                  step=self.n_decode_steps + 1):
             with self._phase("dispatch"):
                 st, blk = scan_step(
-                    k, self.params, self._build_state(), self._d_run,
+                    k, self._step_params, self._build_state(), self._d_run,
                     self._d_eos, self._d_maxnew)
             self._unpack_state(st)
             self.n_decode_steps += 1
@@ -1312,7 +1345,8 @@ class ServingEngine:
                                  step=self.n_decode_steps + 1):
             with self._phase("dispatch"):
                 st, nxt = self._mixed_step(
-                    self.params, self._build_state(), self._stage(row_ids),
+                    self._step_params, self._build_state(),
+                    self._stage(row_ids),
                     self._stage(row_slot), self._stage(row_pos),
                     self._stage(sample_row), self._stage(adv),
                     self._stage(emit))
@@ -1642,7 +1676,8 @@ class ServingEngine:
                                  step=self.n_decode_steps + 1):
             with self._phase("dispatch"):
                 st, sampled, acc = self._spec_step(
-                    self.params, self._build_state(), self._stage(row_ids),
+                    self._step_params, self._build_state(),
+                    self._stage(row_ids),
                     self._stage(row_slot), self._stage(row_pos),
                     self._stage(first_row), self._stage(n_draft),
                     self._stage(draft_toks), self._stage(spec),

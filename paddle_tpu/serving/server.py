@@ -432,7 +432,8 @@ class ServingServer:
         # all render-time reads, nothing on the token hot path
         reg.register_collector(compile_collector())
         reg.register_collector(hbm_collector(
-            params_fn=lambda: eng.params, kv_fn=lambda: eng.kv))
+            params_fn=lambda: eng.params, kv_fn=lambda: eng.kv,
+            step_weight_bytes_fn=lambda: eng.step_weight_bytes))
         reg.register_collector(flight_collector(self.flight))
 
     def pump_alive(self) -> bool:
@@ -936,7 +937,8 @@ class ServingServer:
                 "restore_tokens_saved": eng.restore_tokens_saved,
             }),
             "compile_watch": get_compile_watch().snapshot(),
-            "hbm": hbm_snapshot(params=eng.params, kv=eng.kv),
+            "hbm": hbm_snapshot(params=eng.params, kv=eng.kv,
+                                step_weight_bytes=eng.step_weight_bytes),
         }
 
     def _config_snapshot(self) -> dict:

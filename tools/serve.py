@@ -343,8 +343,8 @@ def main(argv=None) -> int:
                          "a wedge and dumps a bundle")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--param-dtype", default="",
-                    help="dtype the weights are held and served in (e.g. "
-                         "bfloat16: no per-step cast, half the bytes); "
+                    help="dtype the weights are held in (e.g. bfloat16: "
+                         "one set, not float32 plus the steps' copies); "
                          "default: each parameter's own (float32)")
     # client mode
     ap.add_argument("--client", default="",
