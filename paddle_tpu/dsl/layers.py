@@ -35,7 +35,7 @@ from paddle_tpu.dsl.poolings import AvgPooling, BasePoolingType, FirstPooling, L
 
 __all__ = [
     "rms_norm_layer", "gated_ffn_layer", "mla_attention_layer",
-    "kda_attention_layer",
+    "kda_attention_layer", "short_conv_layer",
     "data_layer", "fc_layer", "embedding_layer", "mixed_layer", "addto_layer",
     "concat_layer", "dropout_layer", "full_matrix_projection",
     "trans_full_matrix_projection", "identity_projection", "table_projection",
@@ -978,6 +978,8 @@ def multi_head_attention_layer(
     window: Optional[int] = None,
     use_rope: bool = False,
     rope_theta: float = 10000.0,
+    qk_norm: bool = False,
+    rms_eps: float = 1e-6,
     name: Optional[str] = None,
     param_attr: Optional[Union[ParameterAttribute, list]] = None,
     bias_attr=False,
@@ -993,6 +995,10 @@ def multi_head_attention_layer(
     num_heads % seq_axis == 0); with a `seq`
     mesh axis the sequence is context-parallel via ring attention
     (parallel/context.py).
+
+    qk_norm: RMS-norm each head of q and of k, with a learned [head_dim]
+    scale each (parameters 4 and 5, starting at 1) and `rms_eps`, before the
+    rotation — in every path, so the K a cache holds is the normed one.
 
     param_attr: one attribute applied to all four projections (q/k/v/out), or
     a list of four.  A single NAMED attribute would tie all projections to
@@ -1047,6 +1053,14 @@ def multi_head_attention_layer(
         pname = _make_param(name, i, [dim_in, dim_out], attrs[i])
         cfg.inputs.append(LayerInput(input_layer_name=inp.name,
                                      input_parameter_name=pname))
+    if qk_norm:
+        cfg.attrs.update(qk_norm=True, rms_eps=rms_eps)
+        for i in (4, 5):                  # the q and the k head norm
+            pname = _make_param(
+                name, i, [1, size // num_heads],
+                ParameterAttribute(initial_mean=1.0, initial_std=0.0))
+            cfg.inputs.append(LayerInput(input_layer_name=query.name,
+                                         input_parameter_name=pname))
     cfg.bias_parameter_name = _bias_name(name, bias_attr, [1, size])
     _layer_attr_fields(cfg, layer_attr)
     current_context().add_layer(cfg)
@@ -1257,6 +1271,45 @@ def kda_attention_layer(
     _layer_attr_fields(cfg, layer_attr)
     current_context().add_layer(cfg)
     return LayerOutput(name, "kda_attention", size, parents=[input],
+                       seq_level=input.seq_level)
+
+
+def short_conv_layer(
+    input: LayerOutput,
+    *,
+    conv_size: int = 3,
+    size: Optional[int] = None,
+    name: Optional[str] = None,
+    param_attr: Optional[ParameterAttribute] = None,
+    layer_attr: Optional[ExtraLayerAttribute] = None,
+) -> LayerOutput:
+    """The gated short-convolution token mixer of the LFM2 family
+    (graph/layers_sconv.py): [B, C, x] = input W_in; a causal depthwise
+    convolution of `conv_size` taps over B * x, no bias and no activation;
+    (C * conv) W_out.  Its whole context is the last conv_size - 1 inputs
+    of the convolution.  `param_attr` initializes the two matrices; the
+    taps start uniform in +-conv_size^-1/2 (a depthwise Conv1d's default)."""
+    assert param_attr is None or not param_attr.name, \
+        "a named param_attr would share one matrix across the projections"
+    assert conv_size >= 2, f"conv_size {conv_size}: a tail needs >= 2 taps"
+    size = size if size is not None else input.size
+    name = _name(name, "short_conv")
+    d = input.size
+    cfg = LayerConfig(name=name, type="short_conv", size=size,
+                      active_type="")
+    cfg.attrs.update(conv_size=conv_size, conv_dim=d)
+    bound = conv_size ** -0.5
+    specs = [([d, 3 * d], param_attr),
+             ([conv_size, d], ParameterAttribute(initial_min=-bound,
+                                                 initial_max=bound)),
+             ([d, size], param_attr)]
+    for i, (dims, attr) in enumerate(specs):
+        pname = _make_param(name, i, dims, attr)
+        cfg.inputs.append(LayerInput(input_layer_name=input.name,
+                                     input_parameter_name=pname))
+    _layer_attr_fields(cfg, layer_attr)
+    current_context().add_layer(cfg)
+    return LayerOutput(name, "short_conv", size, parents=[input],
                        seq_level=input.seq_level)
 
 

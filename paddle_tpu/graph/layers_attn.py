@@ -41,6 +41,20 @@ from paddle_tpu.parameter.argument import Argument
 _BLOCKWISE_MIN_KEYS = 2048
 
 
+def _project(ctx: ForwardContext, cfg: LayerConfig) -> dict:
+    """The keywords of ops/attention.py:project_qkv a layer's attrs give,
+    the same in every path: grouped-query heads, the rotation, and with
+    `qk_norm` the two per-head RMSNorm scales (parameters 4 and 5)."""
+    a = cfg.attrs
+    kw = dict(num_kv_heads=int(a.get("num_kv_heads", 0) or a["num_heads"]),
+              use_rope=bool(a.get("use_rope", False)),
+              rope_theta=float(a.get("rope_theta", 10000.0)))
+    if a.get("qk_norm"):
+        kw["qk_norm"] = (ctx.param_of(cfg, 4), ctx.param_of(cfg, 5),
+                         float(a.get("rms_eps", 1e-6)))
+    return kw
+
+
 def _flash_blocks(cfg: LayerConfig) -> dict:
     """Flash-kernel block sizes a layer pins: its `block_q` / `block_k`
     attrs, else nothing — the kernel then derives its blocks from the shape
@@ -142,12 +156,9 @@ def multi_head_attention_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argumen
         w_q, w_k, w_v, w_o, num_heads,
         q_valid=q_valid, k_valid=k_valid, causal=causal,
         bias_o=ctx.bias_of(cfg), attn_fn=attn_fn,
-        num_kv_heads=(int(cfg.attrs["num_kv_heads"])
-                      if "num_kv_heads" in cfg.attrs else None),
         window=(int(cfg.attrs["window"])
                 if "window" in cfg.attrs else None),
-        use_rope=bool(cfg.attrs.get("use_rope", False)),
-        rope_theta=float(cfg.attrs.get("rope_theta", 10000.0)))
+        **_project(ctx, cfg))
     return finish_layer(ctx, cfg, out, like=q_arg)
 
 
@@ -164,21 +175,16 @@ def _cached_step(ctx: ForwardContext, cfg: LayerConfig, x_arg: Argument,
     from paddle_tpu.ops import pallas_attention
     from paddle_tpu.ops.attention import (blockwise_attention,
                                           cached_attention_step,
-                                          dot_product_attention, rope)
+                                          dot_product_attention,
+                                          project_qkv)
 
     x = x_arg.value                                   # [B, Tn, model_dim]
     B, Tn, _ = x.shape
     model_dim = w_q.shape[1]
-    Dh = model_dim // num_heads
-    h_kv = int(cfg.attrs.get("num_kv_heads", 0) or num_heads)
     pos = cache["pos"]
-    q = (x @ w_q).reshape(B, Tn, num_heads, Dh)
-    k = (x @ w_k).reshape(B, Tn, h_kv, Dh)
-    v = (x @ w_v).reshape(B, Tn, h_kv, Dh)
-    if bool(cfg.attrs.get("use_rope", False)):
-        qpos = pos[:, None] + jnp.arange(Tn)[None, :]
-        theta = float(cfg.attrs.get("rope_theta", 10000.0))
-        q, k = rope(q, qpos, theta), rope(k, qpos, theta)
+    qpos = pos[:, None] + jnp.arange(Tn)[None, :]
+    q, k, v = project_qkv(x, x, x, w_q, w_k, w_v, num_heads, q_pos=qpos,
+                          k_pos=qpos, **_project(ctx, cfg))
     n_new = (x_arg.lengths.astype(jnp.int32) if x_arg.lengths is not None
              else jnp.full((B,), Tn, jnp.int32))
     window = (int(cfg.attrs["window"]) if "window" in cfg.attrs else None)
@@ -250,7 +256,7 @@ def _paged_step(ctx: ForwardContext, cfg: LayerConfig, x_arg: Argument,
     the page table itself is host-managed and passes through untouched."""
     import jax.numpy as jnp
 
-    from paddle_tpu.ops.attention import paged_attention_step, rope
+    from paddle_tpu.ops.attention import paged_attention_step, project_qkv
 
     x = x_arg.value                                   # [S, 1, model_dim]
     S, Tn, _ = x.shape
@@ -258,16 +264,10 @@ def _paged_step(ctx: ForwardContext, cfg: LayerConfig, x_arg: Argument,
                      f"new token per slot (got {Tn}); prompts prefill "
                      f"through the dense per-request cache")
     model_dim = w_q.shape[1]
-    Dh = model_dim // num_heads
-    h_kv = int(cfg.attrs.get("num_kv_heads", 0) or num_heads)
     pos = cache["pos"]
-    q = (x @ w_q).reshape(S, 1, num_heads, Dh)
-    k = (x @ w_k).reshape(S, 1, h_kv, Dh)
-    v = (x @ w_v).reshape(S, 1, h_kv, Dh)
-    if bool(cfg.attrs.get("use_rope", False)):
-        theta = float(cfg.attrs.get("rope_theta", 10000.0))
-        qpos = pos[:, None]
-        q, k = rope(q, qpos, theta), rope(k, qpos, theta)
+    qpos = pos[:, None]
+    q, k, v = project_qkv(x, x, x, w_q, w_k, w_v, num_heads, q_pos=qpos,
+                          k_pos=qpos, **_project(ctx, cfg))
     window = (int(cfg.attrs["window"]) if "window" in cfg.attrs else None)
     # a mesh with a `model` axis > 1 = tensor-parallel serving: the op
     # runs the write+read core under shard_map over the head shards
@@ -298,22 +298,17 @@ def _paged_ragged_step(ctx: ForwardContext, cfg: LayerConfig, x_arg: Argument,
     Pallas row-indirected kernel when supported).  Emits the updated pool
     through ctx.state_out; table and row maps are host-managed and pass
     through untouched."""
-    from paddle_tpu.ops.attention import ragged_paged_attention_step, rope
+    from paddle_tpu.ops.attention import (project_qkv,
+                                          ragged_paged_attention_step)
 
     x = x_arg.value                                   # [1, T, model_dim]
     B, T, _ = x.shape
     assert B == 1, (f"layer {cfg.name!r}: the mixed paged step packs all "
                     f"query rows into one ragged batch row (got B={B})")
     model_dim = w_q.shape[1]
-    Dh = model_dim // num_heads
-    h_kv = int(cfg.attrs.get("num_kv_heads", 0) or num_heads)
     row_pos = cache["row_pos"]                        # [T] global positions
-    q = (x @ w_q).reshape(1, T, num_heads, Dh)
-    k = (x @ w_k).reshape(1, T, h_kv, Dh)
-    v = (x @ w_v).reshape(1, T, h_kv, Dh)
-    if bool(cfg.attrs.get("use_rope", False)):
-        theta = float(cfg.attrs.get("rope_theta", 10000.0))
-        q, k = rope(q, row_pos, theta), rope(k, row_pos, theta)
+    q, k, v = project_qkv(x, x, x, w_q, w_k, w_v, num_heads, q_pos=row_pos,
+                          k_pos=row_pos, **_project(ctx, cfg))
     window = (int(cfg.attrs["window"]) if "window" in cfg.attrs else None)
     # mesh `model` axis > 1 = tensor-parallel mixed step (shard_map core)
     out, ck, cv = ragged_paged_attention_step(

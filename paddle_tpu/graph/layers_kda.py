@@ -42,8 +42,8 @@ import jax.numpy as jnp
 from paddle_tpu.config.schema import LayerConfig
 from paddle_tpu.graph.common import finish_layer
 from paddle_tpu.graph.context import ForwardContext
-from paddle_tpu.graph.registry import register_layer
-from paddle_tpu.ops import kda
+from paddle_tpu.graph.registry import register_layer, register_slot_state
+from paddle_tpu.ops import kda, short_conv
 from paddle_tpu.parameter.argument import Argument
 
 
@@ -54,6 +54,17 @@ def _use_kernel(cfg: LayerConfig) -> bool:
 
     return pallas_kda.supported() and \
         str(cfg.attrs.get("attn_impl", "auto")) not in ("dense", "blockwise")
+
+
+@register_slot_state("kda_attention")
+def kda_slot_parts(cfg: LayerConfig, compute_dtype) -> dict:
+    """The recurrent state, float32 whatever the compute dtype (it is what
+    the recurrence accumulates in), and the convolution tail (q, k and v
+    side by side) in the compute dtype."""
+    H, dk = int(cfg.attrs["num_heads"]), int(cfg.attrs["head_dim"])
+    taps = int(cfg.attrs.get("conv_size", 4))
+    return {"state": ((H, dk, dk), jnp.float32),
+            "conv": ((taps - 1, 3 * H * dk), compute_dtype)}
 
 
 @register_layer("kda_attention")
@@ -94,7 +105,7 @@ def kda_attention_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
 
     if not slotted:
         with jax.named_scope("kda.conv"):
-            q, k, v = split(kda.short_conv_whole(xin, w_conv))
+            q, k, v = split(short_conv.short_conv_whole(xin, w_conv))
         with jax.named_scope("kda.scan"):
             o, _ = kda.chunkwise(q, k, v, g, beta)
     else:
@@ -103,27 +114,10 @@ def kda_attention_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
         R = B * T
         xin, g, beta = xin.reshape(R, -1), g.reshape(R, H, dk), \
             beta.reshape(R, H)
-        if ragged:
-            row_slot, row_pos = cache["row_slot"], cache["row_pos"]
-            assert R > S, f"layer {cfg.name!r}: the mixed step packs its " \
-                f"chunk rows from row {S} on (got {R} rows)"
-            live = row_slot < S
-            idx = jnp.arange(R, dtype=jnp.int32)
-            first = jnp.concatenate(
-                [jnp.ones((1,), bool), row_slot[1:] != row_slot[:-1]])
-            last = jnp.concatenate(
-                [row_slot[1:] != row_slot[:-1], jnp.ones((1,), bool)])
-            seg_off = idx - jax.lax.cummax(jnp.where(first, idx, 0))
-        else:
-            live = cache["run"]
-            row_slot = jnp.arange(S, dtype=jnp.int32)
-            row_pos, seg_off = cache["pos"], jnp.zeros((S,), jnp.int32)
-            last = jnp.ones((S,), bool)
+        row_slot, row_pos, _, _, live = runs = short_conv.slot_runs(
+            cache, S, R)
         with jax.named_scope("kda.conv"):
-            y, hist = kda.short_conv_rows(xin, w_conv, conv[row_slot],
-                                          seg_off, row_pos)
-            conv = conv.at[jnp.where(last & live, row_slot, S)].set(
-                hist.astype(conv.dtype))
+            y, conv = short_conv.short_conv_slots(xin, w_conv, conv, runs)
             q, k, v = split(y)
         with jax.named_scope("kda.step"):
             if ragged:

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.graph.builder import GraphExecutor
 from paddle_tpu.graph.context import TEST
+from paddle_tpu.graph.registry import slot_state_types
 from paddle_tpu.parameter.argument import Argument
 
 Array = jax.Array
@@ -271,7 +272,7 @@ def init_kv_caches(executor: GraphExecutor, batch: int, total: int) -> dict:
         else jnp.float32
     state: dict = {}
     for l in executor.model.layers:
-        if l.type == "kda_attention":
+        if l.type in slot_state_types:
             raise ValueError(
                 f"layer {l.name!r}: a recurrent layer has no dense cache "
                 f"here — decode a model with recurrent layers through the "

@@ -31,6 +31,23 @@ cost_layer_types: set[str] = set()
 validation_layer_types: set[str] = set()
 
 
+# Recurrent layer types and the SLOT-INDEXED state each keeps a sequence
+# (serving/paged_kv.py "TWO FAMILIES OF PARTS"): type -> fn(cfg, compute
+# dtype) -> {part: (row shape, dtype)}.  Declared beside the layer's
+# registration; the serving cache manager builds, counts, dumps and
+# checkpoints the parts from this and names no layer type or part itself.
+slot_state_types: dict[str, Callable] = {}
+
+
+def register_slot_state(type_name: str):
+    def deco(fn: Callable) -> Callable:
+        if type_name in slot_state_types:
+            raise ValueError(f"duplicate slot state for {type_name!r}")
+        slot_state_types[type_name] = fn
+        return fn
+    return deco
+
+
 def register_layer(*type_names: str, cost: bool = False,
                    validation: bool = False):
     def deco(fn: LayerFn) -> LayerFn:

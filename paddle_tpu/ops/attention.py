@@ -336,6 +336,38 @@ def _ring_flash(q, k, v, axis_name, idx, n, perm,
     return _finalize(num, den, q.dtype)
 
 
+def project_qkv(query: Array, key: Array, value: Array,
+                w_q: Array, w_k: Array, w_v: Array,
+                num_heads: int, num_kv_heads: int,
+                q_pos: Array, k_pos: Array,
+                use_rope: bool = False, rope_theta: float = 10000.0,
+                qk_norm: Optional[tuple] = None):
+    """The projections every attention path starts with: q [..., H, D], k
+    and v [..., H_kv, D] from inputs [..., T, d].  `qk_norm` (q scale [D],
+    k scale [D], eps) RMS-norms each head of q and k — statistics in
+    float32, times the learned scale — BEFORE the rotation (QK-norm as the
+    LFM2 / Qwen3 / OLMo-2 families apply it); `use_rope` then rotates q at
+    `q_pos` and k at `k_pos`.  What is written to a KV cache is this k."""
+    Dh = w_q.shape[1] // num_heads
+    q = (query @ w_q).reshape(query.shape[:-1] + (num_heads, Dh))
+    k = (key @ w_k).reshape(key.shape[:-1] + (num_kv_heads, Dh))
+    v = (value @ w_v).reshape(value.shape[:-1] + (num_kv_heads, Dh))
+    if qk_norm is not None:
+        q_scale, k_scale, eps = qk_norm
+
+        def norm(x, scale):
+            x32 = x.astype(jnp.float32)
+            x32 = x32 * jax.lax.rsqrt(
+                jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+            return (x32 * scale.astype(jnp.float32).reshape(-1)).astype(
+                x.dtype)
+
+        q, k = norm(q, q_scale), norm(k, k_scale)
+    if use_rope:
+        q, k = rope(q, q_pos, rope_theta), rope(k, k_pos, rope_theta)
+    return q, k, v
+
+
 def multi_head_attention(
     query: Array,                     # [B, Tq, Dq]
     key: Array,                       # [B, Tk, Dk]
@@ -351,24 +383,21 @@ def multi_head_attention(
     window: Optional[int] = None,
     use_rope: bool = False,
     rope_theta: float = 10000.0,
+    qk_norm: Optional[tuple] = None,
 ) -> Array:
     """Projected multi-head attention; attn_fn pluggable (dense / blockwise /
     flash / a ring closure from parallel/context.py).
 
     num_kv_heads < num_heads gives grouped-query attention (w_k/w_v project
     to num_kv_heads * head_dim); window gives sliding-window attention;
-    use_rope applies rotary position embeddings to q/k."""
+    use_rope applies rotary position embeddings to q/k; qk_norm RMS-norms
+    each head of q and k first (`project_qkv`)."""
     B, Tq, _ = query.shape
-    Tk = key.shape[1]
     model_dim = w_q.shape[1]
-    Dh = model_dim // num_heads
-    h_kv = num_kv_heads or num_heads
-    q = (query @ w_q).reshape(B, Tq, num_heads, Dh)
-    k = (key @ w_k).reshape(B, Tk, h_kv, Dh)
-    v = (value @ w_v).reshape(B, Tk, h_kv, Dh)
-    if use_rope:
-        q = rope(q, jnp.arange(Tq), rope_theta)
-        k = rope(k, jnp.arange(Tk), rope_theta)
+    q, k, v = project_qkv(query, key, value, w_q, w_k, w_v, num_heads,
+                          num_kv_heads or num_heads, jnp.arange(Tq),
+                          jnp.arange(key.shape[1]), use_rope, rope_theta,
+                          qk_norm)
     kw = {} if window is None else {"window": window}
     o = attn_fn(q, k, v, q_valid=q_valid, k_valid=k_valid, causal=causal,
                 **kw)
@@ -471,6 +500,15 @@ def _tp_paged_call(mesh, body, head_args, pool_args, repl_args,
     return fn(*head_args, *pool_args, *repl_args)
 
 
+def _stored_rows(new: Array, pages: Array) -> Array:
+    """New K or V rows [N, H_kv, D] in the shape and dtype the pool stores
+    a token's row in: as they are, or several narrow heads a 128-lane tile
+    (ops/pallas_paged.py:kv_row_shape) — the same values in the same
+    order, so the gather path below reads a pool of either layout by
+    reshaping what it gathered back to [H_kv, D]."""
+    return new.reshape(new.shape[:1] + pages.shape[2:]).astype(pages.dtype)
+
+
 def paged_attention_step(
     q_new: Array,          # [S, 1, H, D] one new-token query per slot
     k_new: Array,          # [S, 1, H_kv, D]
@@ -544,8 +582,8 @@ def paged_attention_step(
     phys = jnp.take_along_axis(page_table, (pos // page_size)[:, None],
                                axis=1)[:, 0]                     # [S]
     off = pos % page_size
-    ck = k_pages.at[phys, off].set(k_new[:, 0].astype(k_pages.dtype))
-    cv = v_pages.at[phys, off].set(v_new[:, 0].astype(v_pages.dtype))
+    ck = k_pages.at[phys, off].set(_stored_rows(k_new[:, 0], k_pages))
+    cv = v_pages.at[phys, off].set(_stored_rows(v_new[:, 0], v_pages))
 
     if use_kernel is None:
         from paddle_tpu.ops import pallas_paged
@@ -564,8 +602,8 @@ def paged_attention_step(
 
     # -- read: page-table gather -> [S, T_ctx] contiguous view -----------
     T_ctx = max_pages * page_size
-    kc = ck[page_table].reshape(S, T_ctx, *ck.shape[2:])
-    vc = cv[page_table].reshape(S, T_ctx, *cv.shape[2:])
+    kc = ck[page_table].reshape(S, T_ctx, *k_new.shape[2:])
+    vc = cv[page_table].reshape(S, T_ctx, *v_new.shape[2:])
     k_full, v_full = _expand_kv_heads(kc, vc, H)
     t = jnp.arange(T_ctx)
     mask = t[None, None, :] <= pos[:, None, None]                # causal
@@ -658,8 +696,8 @@ def ragged_paged_attention_step(
     # -- write: scatter every row's k/v into its slot's current page -----
     phys = page_table[row_slot, row_pos // page_size]             # [T]
     off = row_pos % page_size
-    ck = k_pages.at[phys, off].set(k_new.astype(k_pages.dtype))
-    cv = v_pages.at[phys, off].set(v_new.astype(v_pages.dtype))
+    ck = k_pages.at[phys, off].set(_stored_rows(k_new, k_pages))
+    cv = v_pages.at[phys, off].set(_stored_rows(v_new, v_pages))
 
     if use_kernel is None:
         from paddle_tpu.ops import pallas_paged
@@ -678,8 +716,8 @@ def ragged_paged_attention_step(
 
     # -- read: per-row page-table gather -> [T, T_ctx] contiguous view ---
     T_ctx = max_pages * page_size
-    kc = ck[page_table[row_slot]].reshape(T, T_ctx, *ck.shape[2:])
-    vc = cv[page_table[row_slot]].reshape(T, T_ctx, *cv.shape[2:])
+    kc = ck[page_table[row_slot]].reshape(T, T_ctx, *k_new.shape[1:])
+    vc = cv[page_table[row_slot]].reshape(T, T_ctx, *v_new.shape[1:])
     k_full, v_full = _expand_kv_heads(kc, vc, H)
     t = jnp.arange(T_ctx)
     mask = t[None, :] <= row_pos[:, None]                        # causal

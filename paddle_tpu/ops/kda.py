@@ -22,8 +22,9 @@ Three forms, one result:
   * `step_rows`  — one token a row against a pool of slot states: the
     decode step, and the decode rows of the ragged mixed step.
 
-`short_conv_*` are the causal depthwise convolution over the last
-`taps` positions in front of q, k and v.
+The causal depthwise convolution over the last `taps` positions in front
+of q, k and v is ops/short_conv.py's, shared with the gated
+short-convolution mixer (graph/layers_sconv.py).
 """
 
 from __future__ import annotations
@@ -48,49 +49,6 @@ def decay(f, a_log, dt_bias):
     f [..., H, dk] the gate projection, a_log [H], dt_bias [H, dk]."""
     f = f.astype(jnp.float32) + dt_bias.astype(jnp.float32)
     return -jnp.exp(a_log.astype(jnp.float32))[:, None] * jax.nn.softplus(f)
-
-
-# ---------------------------------------------------------------------------
-# the short convolution
-# ---------------------------------------------------------------------------
-
-def short_conv_whole(x, w):
-    """Causal depthwise convolution from an empty history: x [B, T, C],
-    w [taps, C] (w[-1] multiplies the current position) -> [B, T, C]: the
-    sum of `taps` shifted products."""
-    taps = w.shape[0]
-    T = x.shape[1]
-    y = x * w[taps - 1]
-    for j in range(1, taps):
-        shifted = jnp.pad(x, ((0, 0), (j, 0), (0, 0)))[:, :T]
-        y = y + shifted * w[taps - 1 - j]
-    return y
-
-
-def short_conv_rows(x, w, tail, seg_off, row_pos):
-    """The same convolution over a packed row list: x [R, C] in order,
-    `tail` [R, taps-1, C] each row's slot history (tail[:, -1] the most
-    recent position before the slot's first row of this step), `seg_off`
-    [R] the row's offset from that first row, `row_pos` [R] its global
-    position (taps reaching before position 0 read zero).  Returns
-    (y [R, C], hist [R, taps-1, C]): hist[r] is the history AFTER row r,
-    what the slot's tail becomes if r is its last row."""
-    taps = w.shape[0]
-    R = x.shape[0]
-    prev = []                                  # prev[j-1] = input at pos - j
-    for j in range(1, taps):
-        from_rows = jnp.pad(x, ((j, 0), (0, 0)))[:R]
-        # j - seg_off positions before the slot's first row: tail[-(j-off)]
-        idx = jnp.clip(taps - 1 - j + seg_off, 0, taps - 2)
-        from_tail = jnp.take_along_axis(
-            tail, idx[:, None, None], axis=1)[:, 0]
-        p = jnp.where((seg_off >= j)[:, None], from_rows, from_tail)
-        prev.append(jnp.where((row_pos >= j)[:, None], p, 0).astype(x.dtype))
-    y = x * w[taps - 1]
-    for j in range(1, taps):
-        y = y + prev[j - 1] * w[taps - 1 - j]
-    hist = jnp.stack(prev[::-1][1:] + [x], axis=1)
-    return y, hist
 
 
 # ---------------------------------------------------------------------------
@@ -265,10 +223,3 @@ def gated_out_norm(o, gate, scale, eps: float):
     o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
     return o * scale.astype(jnp.float32) * jax.nn.sigmoid(
         gate.astype(jnp.float32))
-
-
-def state_shapes(num_heads: int, head_dim: int, taps: int) -> dict:
-    """The row shapes of one layer's slot state: the recurrent state and
-    the convolution tail (q, k and v side by side)."""
-    return {"state": (num_heads, head_dim, head_dim),
-            "conv": (taps - 1, 3 * num_heads * head_dim)}

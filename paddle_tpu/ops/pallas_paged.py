@@ -126,13 +126,32 @@ _KV_VMEM_BUDGET = 512 << 10
 _BLOCK_TOKENS = (128, 512)       # floor and ceiling of a block, in tokens
 
 
+def kv_row_shape(h_kv: int, head_dim: int) -> tuple[int, int]:
+    """The shape a token's K (or V) row is STORED in, in a pool's last two
+    dimensions.  A head of 128 lanes or more is a row of the pool as it
+    is: (h_kv, head_dim).  A narrower head that divides 128 is packed,
+    128 // head_dim heads a lane tile: (h_kv * head_dim // 128, 128) — the
+    same bytes in the same order (a row-major reshape of [h_kv, head_dim]),
+    so nothing is moved to read it either way.  Stored [.., 8, 64] a bf16
+    pool's HBM tiles pad each head to 128 lanes, or XLA lays the pool out
+    pages-minor and the kernel's page copy is refused; stored [.., 4, 128]
+    it is lane-dense (T(4,128)(2,1): the bytes of its elements).  Where
+    the heads do not fill whole tiles (h_kv * head_dim not a multiple of
+    128) the row stays as it is and the kernel pads its lanes."""
+    if head_dim < 128 and 128 % head_dim == 0 and \
+            (h_kv * head_dim) % 128 == 0:
+        return h_kv * head_dim // 128, 128
+    return h_kv, head_dim
+
+
 def block_tokens(page_size: int, h_kv: int, head_dim: int, itemsize: int,
                  max_pages: int) -> int:
     """Tokens of KV one loop step of the kernel folds: a whole number of
     pages, derived from the operands' shapes alone — as many as
     `_KV_VMEM_BUDGET` holds (K and V, two buffers each) within
-    `_BLOCK_TOKENS`, never more than the table maps.  The engine calls
-    this with the pool's shapes to count what the kernel fetches."""
+    `_BLOCK_TOKENS`, never more than the table maps.  `h_kv` and
+    `head_dim` are the pool's STORED row (`kv_row_shape`).  The engine
+    calls this with the pool's shapes to count what the kernel fetches."""
     per_token = 2 * 2 * h_kv * _round_up(head_dim, 128) * itemsize
     lo, hi = _BLOCK_TOKENS
     tokens = max(lo, min(hi, _KV_VMEM_BUDGET // per_token))
@@ -287,8 +306,8 @@ def _call(name: str, kernel, qp: Array, pools: tuple, buf_shape: tuple,
 
 def paged_attention(
     q: Array,               # [R, H, D] one query token per ROW
-    k_pages: Array,         # [P, page_size, H_kv, D]
-    v_pages: Array,         # [P, page_size, H_kv, D]
+    k_pages: Array,         # [P, page_size, H_kv, D], or packed
+    v_pages: Array,         # [P, page_size, G, 128] (`kv_row_shape`)
     page_table: Array,      # [S, max_pages] int32 (0 = unmapped)
     lengths: Array,         # [R] int32 valid tokens per row (incl. the
                             # just-written one: attend t < lengths[r])
@@ -311,11 +330,21 @@ def paged_attention(
     lengths and the indirection ride the scalar-prefetch channel, so the
     kernel addresses `table[row_slot[r], ·]` itself and bounds each row's
     loop by `lengths[r]` at run time — one compiled program for any
-    prefill/decode mix and any fill of the pool."""
+    prefill/decode mix and any fill of the pool.
+
+    A PACKED pool (heads under 128 lanes, `kv_row_shape`: `pack` heads a
+    128-lane row, G rows a token) runs the same kernel by shape: a query
+    head's D values sit in the lanes its KV head has in the row and zeros
+    in the others, so its score against a packed row is its own head's
+    dot product; the H // G heads that share a row are one group of the
+    in-kernel mask; the weighted row comes back 128 wide and the head's
+    own lanes are taken from it here.  No pool is copied or padded."""
     R, H, D = q.shape
-    P, ps, h_kv, _ = k_pages.shape
+    P, ps, G, L = k_pages.shape
     maxp = page_table.shape[1]
-    assert H % h_kv == 0, f"heads {H} not divisible by kv heads {h_kv}"
+    pack = L // D                       # KV heads a stored row: 1 unpacked
+    assert L == pack * D and H % (G * pack) == 0, \
+        f"heads {H} x {D} do not read a pool row of {G} x {L}"
     if scale is None:
         scale = D ** -0.5
     if row_slot is None:
@@ -323,15 +352,25 @@ def paged_attention(
 
     itemsize = jnp.dtype(k_pages.dtype).itemsize
     Hp = _head_rows(H, q.dtype)
-    Dp = _round_up(D, 128)
-    npb = block_tokens(ps, h_kv, D, itemsize, maxp) // ps
-    qp = jnp.pad(q, ((0, 0), (0, Hp - H), (0, Dp - D)))
-    kp = jnp.pad(k_pages, ((0, 0), (0, 0), (0, 0), (0, Dp - D)))
-    vp = jnp.pad(v_pages, ((0, 0), (0, 0), (0, 0), (0, Dp - D)))
-    out = _call("paged_attn", functools.partial(_kernel, H, h_kv, scale, None),
-                qp, (kp, vp), (npb, ps, h_kv, Dp), Dp, page_table, lengths,
-                row_slot)
-    return out[:, :H, :D]
+    Dp = _round_up(L, 128)
+    npb = block_tokens(ps, G, L, itemsize, maxp) // ps
+    if pack > 1:
+        # head h reads KV head h // rep, lane tile (h // rep) % pack
+        rep = H // (G * pack)
+        lane = jax.nn.one_hot((jnp.arange(H) // rep) % pack, pack,
+                              dtype=q.dtype)                 # [H, pack]
+        q = (q[:, :, None, :] * lane[None, :, :, None]).reshape(R, H, L)
+    qp = jnp.pad(q, ((0, 0), (0, Hp - H), (0, Dp - L)))
+    if Dp != L:
+        k_pages, v_pages = (jnp.pad(p, ((0, 0),) * 3 + ((0, Dp - L),))
+                            for p in (k_pages, v_pages))
+    out = _call("paged_attn", functools.partial(_kernel, H, G, scale, None),
+                qp, (k_pages, v_pages), (npb, ps, G, Dp), Dp, page_table,
+                lengths, row_slot)[:, :H, :L]
+    if pack > 1:
+        out = jnp.sum(out.reshape(R, H, pack, D) *
+                      lane[None, :, :, None].astype(out.dtype), axis=2)
+    return out
 
 
 def latent_paged_attention(

@@ -28,12 +28,20 @@ every expert held it is the whole layer.
 
 Formulation: each held expert multiplies every row and a dense combine
 matrix `[B, E_held]` (zero off the routed pairs) weighs the results.  Work
-is B x E_held expert products, not the routed pairs: right where the rows a
-step are few (a decode or mixed step of <= a few hundred rows reads each
-expert's weights once whatever the rows, and the MXU is idle beside the
-HBM), wasteful for long whole-sequence calls — a sort by expert and a
-ragged product belongs there (ROADMAP).  Stacked expert weights shard over
-the `model` mesh axis as before; XLA partitions the einsums.
+is B x E_held expert products, not the routed pairs: right where rows x
+held experts is small — a step reads each expert's weights once whatever
+the rows, and while the products take less time than that read the MXU is
+idle beside the HBM (8 held x 64 rows and 16 held x 128-320 rows, the
+GigaChat and Kimi-Linear cells: 2 and 4 routed pairs an expert).  THE
+BOUND: with 64 held experts of 3 x 2048 x 1536 and 256-512 rows
+(`lfm2-24b-serve.long-output-256`, 16 pairs an expert) the products are
+1.24-2.47 TFLOP a step in four layers, 6.3-12.6 ms at the v5e's peak,
+against 5.9 ms to read the experts' 4.83 GB: the MXU sets the pace, at 16
+times the routed work (PERF.md section 5 has the measured cost).  Past
+that, and for long whole-sequence calls, a sort by expert and a ragged
+product belongs — to be claimed in that cell (ROADMAP D12, S14).  Stacked
+expert weights shard over the `model` mesh axis as before; XLA partitions
+the einsums.
 """
 
 from __future__ import annotations

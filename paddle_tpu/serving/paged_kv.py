@@ -1,5 +1,8 @@
 """Paged KV cache: a fixed pool of [num_pages, page_size, h_kv, dh] pages
-per attention layer plus per-slot page tables.  A latent-attention layer
+per attention layer plus per-slot page tables (heads narrower than 128
+lanes are stored several a lane tile, [.., h_kv * dh // 128, 128]:
+ops/pallas_paged.py:kv_row_shape — lane-dense in HBM, the same bytes in
+the same order).  A latent-attention layer
 (mla_attention) holds ONE tensor of [num_pages, page_size, W] — W =
 kv_lora_rank + qk_rope_head_dim rounded up to 128 lanes (576 -> 640, the
 width HBM tiling gives the row anyway) — instead of a K and a V pool: its cache row is the latent
@@ -7,11 +10,16 @@ every head reads (ops/mla.py); allocation, sharing, COW, spill and transfer
 below walk each layer's own parts, so they are the same code for both.
 
 TWO FAMILIES OF PARTS.  The above are PAGE-indexed: a token's row lives in
-the page its slot's table maps.  A recurrent layer (kda_attention,
-graph/layers_kda.py) holds SLOT-indexed parts instead: `state` [S+1, H,
-dk, dv] float32 and `conv` [S+1, taps-1, C] in the compute dtype, one row
-a slot (row S is the trash row padding and paused rows aim at), whatever
-the context's length.  Both families sit in `self.pools` under the
+the page its slot's table maps.  A recurrent layer holds SLOT-indexed
+parts instead, one row a slot (row S is the trash row padding and paused
+rows aim at), whatever the context's length.  Two kinds today, each
+declaring its parts, their row shapes and dtypes beside its registration
+(graph/registry.py:register_slot_state — nothing here names a layer type
+or a part to decide a shape or a dtype): kda_attention
+(graph/layers_kda.py) `state` [S+1, H, dk, dv] float32 and `conv` [S+1,
+taps-1, C] in the compute dtype; short_conv (graph/layers_sconv.py) `conv`
+[S+1, taps-1, d] alone, its whole context.  Both families sit in
+`self.pools` under the
 layer's name and thread through the engine's steps donated alike;
 `layer_specs` names the page-indexed layers, `slot_specs` the slot-indexed
 ones.  Allocation, COW, spill, export/import and `check()` walk the
@@ -122,15 +130,14 @@ RECURRENT_REFUSALS = {
 }
 
 
-def slot_state_specs(model) -> dict[str, dict[str, tuple]]:
-    """{layer name: {part: row shape}} of the model's recurrent layers'
-    slot-indexed parts, in layer order (module docstring "TWO FAMILIES OF
-    PARTS"); empty for a model without recurrent layers."""
-    from paddle_tpu.ops.kda import state_shapes
-    return {l.name: state_shapes(int(l.attrs["num_heads"]),
-                                 int(l.attrs["head_dim"]),
-                                 int(l.attrs.get("conv_size", 4)))
-            for l in model.layers if l.type == "kda_attention"}
+def slot_state_specs(model, compute_dtype=jnp.float32) -> dict:
+    """{layer name: {part: (row shape, dtype)}} of the model's recurrent
+    layers' slot-indexed parts, in layer order, as each layer type declares
+    them (module docstring "TWO FAMILIES OF PARTS"); empty for a model
+    without recurrent layers."""
+    from paddle_tpu.graph.registry import slot_state_types
+    return {l.name: slot_state_types[l.type](l, compute_dtype)
+            for l in model.layers if l.type in slot_state_types}
 
 
 def refuse_for_recurrent(slot_specs: dict, mechanism: str) -> None:
@@ -141,8 +148,9 @@ def refuse_for_recurrent(slot_specs: dict, mechanism: str) -> None:
         what, why = RECURRENT_REFUSALS[mechanism]
         raise ValueError(
             f"{what} is not available for a model with recurrent layers "
-            f"({len(slot_specs)} here): {why} (ROADMAP R5: state snapshots "
-            f"at page boundaries)")
+            f"({len(slot_specs)} here, of any kind that keeps a slot "
+            f"state): {why} (ROADMAP R5: state snapshots at page "
+            f"boundaries)")
 
 
 class PagedKVCache:
@@ -192,19 +200,25 @@ class PagedKVCache:
         self.layer_specs: dict[str, tuple] = {}
         # slot_specs[name] = {part: row shape} of a recurrent layer's
         # slot-indexed parts (module docstring "TWO FAMILIES OF PARTS")
-        self.slot_specs = slot_state_specs(executor.model)
-        # the state is float32 whatever the compute dtype: it is what the
-        # recurrence accumulates in
+        declared = slot_state_specs(executor.model, dtype)
+        self.slot_specs = {name: {part: row for part, (row, _) in ps.items()}
+                           for name, ps in declared.items()}
         self.pools: dict[str, dict[str, jnp.ndarray]] = {
-            name: {part: jnp.zeros((num_slots + 1,) + row,
-                                   jnp.float32 if part == "state" else dtype)
-                   for part, row in rows.items()}
-            for name, rows in self.slot_specs.items()}
+            name: {part: jnp.zeros((num_slots + 1,) + row, part_dtype)
+                   for part, (row, part_dtype) in ps.items()}
+            for name, ps in declared.items()}
+        from paddle_tpu.ops.pallas_paged import kv_row_shape
         for l in executor.model.layers:
             if l.type == "multi_head_attention":
                 heads = int(l.attrs["num_heads"])
                 h_kv = int(l.attrs.get("num_kv_heads", 0) or heads)
-                row, parts = (h_kv, int(l.size) // heads), ("k", "v")
+                # narrow heads packed a lane tile; under a model mesh the
+                # row axis is what shards, so only whole tiles a shard
+                # (each shard then holds its own kv heads, in order)
+                row = (h_kv, int(l.size) // heads)
+                if kv_row_shape(*row)[0] % self.tp_shards == 0:
+                    row = kv_row_shape(*row)
+                parts = ("k", "v")
             elif l.type == "mla_attention":
                 if self.tp_shards > 1:
                     raise ValueError(
@@ -231,7 +245,8 @@ class PagedKVCache:
             self.pools[l.name] = {part: _pool() for part in parts}
         assert self.layer_specs, \
             "model has no attention layers to page (a model whose every " \
-            "layer is recurrent holds no page-indexed part: not supported)"
+            "layer is recurrent — kda_attention, short_conv — holds no " \
+            "page-indexed part: not supported)"
 
         # host allocator state: table[s, j] = physical page backing logical
         # page j of slot s (0 = unmapped -> trash)

@@ -318,6 +318,60 @@ def test_paged_kernel_at_the_serve_cells_shapes(mosaic, form):
     assert made_by and "copy" not in made_by, made_by
 
 
+# lfm2-24b-serve.long-output-256's own shapes (benchmark/configs/
+# lfm2-24b-a2b-serve.json: 256 slots, page 16, context 4,096, 32 query heads
+# in 8 groups of 4 over 8 KV heads of 64 — stored two heads a 128-lane tile,
+# ops/pallas_paged.py:kv_row_shape — bf16)
+NARROW = dict(S=256, PAGE=16, MAXP=4096 // 16, H=32, H_KV=8, D=64,
+              POOL=256 * 256 + 1)
+
+
+@pytest.mark.parametrize("rows", [256, 512], ids=["decode", "mixed-512-rows"])
+def test_paged_kernel_at_head_64_in_groups_of_4(mosaic, rows):
+    """Decode (256 rows) and the mixed step (256 single rows + two chunks
+    of 128) through the calls the engine makes, the pool of 64-wide heads
+    packed [P, 16, 4, 128] as the cache manager holds it: one kernel, the
+    pools donated and never copied or padded, lane-dense in HBM."""
+    from paddle_tpu.ops.attention import (paged_attention_step,
+                                          ragged_paged_attention_step)
+    from paddle_tpu.ops.pallas_paged import kv_row_shape
+    c = NARROW
+    row = kv_row_shape(c["H_KV"], c["D"])
+    assert row == (4, 128)
+    pools = [((c["POOL"], c["PAGE"]) + row, bf16)] * 2
+    if rows == c["S"]:
+        def step(q, k, v, kp, vp, table, pos):
+            return paged_attention_step(q, k, v, kp, vp, table, pos,
+                                        use_kernel=True)
+        S = c["S"]
+        compiled = mosaic(
+            step, ((S, 1, c["H"], c["D"]), bf16),
+            ((S, 1, c["H_KV"], c["D"]), bf16),
+            ((S, 1, c["H_KV"], c["D"]), bf16), *pools,
+            ((S, c["MAXP"]), i32), ((S,), i32), donate=(3, 4))
+    else:
+        def step(q, k, v, kp, vp, table, row_slot, row_pos):
+            return ragged_paged_attention_step(q, k, v, kp, vp, table,
+                                               row_slot, row_pos,
+                                               use_kernel=True)
+        T = rows
+        compiled = mosaic(
+            step, ((T, c["H"], c["D"]), bf16), ((T, c["H_KV"], c["D"]), bf16),
+            ((T, c["H_KV"], c["D"]), bf16), *pools,
+            ((c["S"] + 1, c["MAXP"]), i32), ((T,), i32), ((T,), i32),
+            donate=(3, 4))
+    assert kernel_names(compiled) == ["paged_attn.1"], kernel_names(compiled)
+    import re
+    text = compiled.as_text()
+    made_by = re.findall(r"= bf16\[65537,16,4,128\]\S* ([\w-]+)\(", text)
+    assert made_by and "copy" not in made_by and "pad" not in made_by, made_by
+    # the pool's HBM layout holds its elements' bytes and no more
+    assert "bf16[65537,16,4,128]{3,2,1,0:T(4,128)(2,1)}" in text
+    pool_bytes = 65537 * 16 * 4 * 128 * 2
+    assert compiled.memory_analysis().argument_size_in_bytes < \
+        2 * pool_bytes * 1.01
+
+
 # the latent cell's own shapes (benchmark/configs/
 # gigachat3.1-702b-a36b-serve.json: 64 slots, page 16, context 4,096, 64
 # query heads against ONE 576-wide latent row stored 640 wide, its first 512

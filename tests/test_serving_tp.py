@@ -97,6 +97,29 @@ def test_tp_gqa_grouped_heads_stay_exact():
     _assert_same_results(base, tp, "model=2 (gqa)")
 
 
+@pytest.mark.parametrize("n,row", [(2, (1, 128)), (4, (1, 64))])
+def test_tp_heads_of_64_pack_whole_lane_tiles_a_shard(n, row):
+    """Heads under 128 lanes are stored several a 128-lane tile
+    (ops/pallas_paged.py:kv_row_shape) under a mesh too, wherever every
+    shard gets whole tiles: 4 kv heads of 64 are 2 tiles, so model=2
+    shards hold one tile (their own two heads, in order) and model=4
+    shards one head each in the row as it was; both give the
+    single-device (packed) engine's tokens."""
+    tr = _make("vocab=97,dim=256,layers=2,heads=4,batch_size=4,kv_heads=4")
+    prompts = _prompts((3, 9, 6), 97)
+    kw = dict(num_slots=2, page_size=8, max_context=64)
+    base_eng = _tp_engine(tr, 1, **kw)
+    assert set(base_eng.kv.layer_specs.values()) == {(2, 128)}
+    base = base_eng.run(
+        [Request(i, p, max_new=6) for i, p in enumerate(prompts)])
+    eng = _tp_engine(tr, n, **kw)
+    pool = next(iter(eng.kv.paged_pools().values()))["k"]
+    assert pool.addressable_shards[0].data.shape[2:] == row
+    tp = eng.run([Request(i, p, max_new=6) for i, p in enumerate(prompts)])
+    _assert_same_results(base, tp, f"model={n} (heads of 64)")
+    assert eng.kv.pool_bytes_per_shard == eng.kv.pool_bytes // n
+
+
 def test_tp_prefix_cache_hits_and_cow_stay_exact():
     """Prefix-cache hits under sharding: the second wave maps pages the
     first wave committed (including a mid-page COW boundary), and the
