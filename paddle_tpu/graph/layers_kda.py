@@ -18,7 +18,7 @@ layers do:
     they were (a recurrence recomputed at a frozen position would advance
     twice, where a K/V write is idempotent);
   * a slot state with `row_slot` — the ragged mixed step.  THE PACKING
-    CONTRACT (serving/engine.py `_run_mixed_step`): with S slots, rows
+    CONTRACT (serving/engine.py `_launch_mixed`): with S slots, rows
     [0, S) are single rows (decode rows, or padding aimed at trash row S)
     and rows [S, T) hold the prompt chunks, each slot's run contiguous and
     in order.  The first part is one batched rank-1 update; each run of the

@@ -98,6 +98,13 @@ CATALOG: dict[str, str] = {
     "serving_frame_writes_total":
         "transport writes that carried token frames (one per connection "
         "and engine step; frames/writes = how far a step's tokens coalesce)",
+    # -- one step in flight (docs/serving.md "The step loop") --------------
+    "serving_lookahead_steps_total":
+        "compiled steps launched while the previous one was still in "
+        "flight (over serving_decode_steps_total: the engaged share)",
+    "serving_lookahead_dropped_rows_total":
+        "rows of an in-flight step whose request had ended by the time "
+        "the step landed (computed, never banked or emitted)",
     # -- flash kernels, counted when a call is traced into a program ------
     "flash_grid_steps_total":
         "grid steps of the flash kernel calls traced so far (label kernel)",

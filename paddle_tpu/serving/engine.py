@@ -27,7 +27,12 @@ the serving answer (the slot configuration studied in arXiv:2605.25645):
 
 Scheduling is a host loop (numpy metadata, device pools): admit from the
 FIFO queue into free slots, run one compiled step over all S slots, retire
-finished slots, repeat.  A slot that cannot get its next page (overcommitted
+finished slots, repeat.  A decode or mixed step is two halves — LAUNCH
+(plan, pack, dispatch) and LAND (read back, bank, emit, retire) — and a
+driver that does not look at what step() banked (the server's pump:
+`lookahead` 1) gets step N+1 launched before step N is landed, so the host
+works beside the device instead of before it (docs/serving.md "The step
+loop").  A slot that cannot get its next page (overcommitted
 pool) is PAUSED — excluded from that step's key consumption and token
 banking — and resumes bit-identically once a page frees, because its key
 schedule is indexed by its own generation counter, not by wall-clock steps.
@@ -230,6 +235,26 @@ class _Slot:
         self.probe_tick = 0
 
 
+class _Pending:
+    """One compiled decode or mixed step between its LAUNCH and its LAND:
+    the device array of sampled tokens (the counts of `_with_counts`
+    behind them) and what the host needs to bank them without looking at
+    anything the next launch may have moved — per slot the `_Slot` that
+    owned it at launch, the decode rows, the chunk runs, and how far the
+    step advances each slot's pos and gen (`ServingEngine._cursor` adds
+    them to the banked cursors while the step is in flight)."""
+
+    __slots__ = ("nxt", "owners", "rows", "advanced", "adv", "emit")
+
+    def __init__(self, nxt, owners, rows, advanced, adv, emit):
+        self.nxt = nxt              # device [S (+ counts)] int32
+        self.owners = owners        # [S] the _Slot in each slot at launch
+        self.rows = rows            # slots that ran a decode row
+        self.advanced = advanced    # (slot, n_rows, final) per chunk run
+        self.adv = adv.tolist()     # [S] tokens each slot commits
+        self.emit = emit.tolist()   # [S] a sampled token is banked
+
+
 class ServingEngine:
     """Slot scheduler + paged KV + one compiled decode step.
 
@@ -392,6 +417,22 @@ class ServingEngine:
         self.kv_tokens_attended = 0
         self.kv_tokens_fetched = 0
         self._admit_seq = 0
+        # ONE STEP IN FLIGHT (docs/serving.md "The step loop"): a decode
+        # or mixed step is two halves, LAUNCH (plan, pack, dispatch) and
+        # LAND (read back, bank, emit, retire).  `lookahead` is how many
+        # compiled steps step() may leave in flight when it returns: 0
+        # (the default — a direct caller looks at what step() banked)
+        # lands each step where it was launched; 1 (set by ServingServer,
+        # which owns the pump and the loop thread that make the overlap
+        # worth having) launches step N+1 BEFORE it lands step N, so the
+        # host's emit/admit/plan run beside the device instead of before
+        # it.  `_pending` is the step in flight.
+        self.lookahead = 0
+        self._pending: Optional[_Pending] = None
+        self._landing = False       # inside _land: settle() is a no-op
+        self.n_lookahead_steps = 0  # steps launched with one in flight
+        self.n_lookahead_dropped_rows = 0   # rows of an in-flight step
+                                    # whose request had ended by its land
         # -- device-resident EngineState + its host sync machinery --------
         # The compiled steps advance pos/gen/toks on device, so the hot
         # path re-stages NOTHING: the page table re-uploads only when a
@@ -728,7 +769,18 @@ class ServingEngine:
         the page table when any allocator write bumped kv.version
         (admission/COW/preempt/retire), the per-slot arrays when a slot
         lifecycle event set _slots_dirty.  A steady pure-decode run
-        re-stages nothing."""
+        re-stages nothing.
+
+        With a step in flight the host is one step behind the device, so
+        the upload must not put back what that step moves: pos and gen go
+        up as `_cursor` has them (banked plus the step in flight — exact,
+        they advance by counts the host chose), and `toks` is not uploaded
+        at all once it exists.  Every compiled step writes `toks` where it
+        banks a token, so the device's copy is the newest for every
+        decoding slot; an admitted slot's is set by its final chunk before
+        any row reads it, an empty slot's is never used.  Only
+        `restore_state` makes the host's copy the authority (it drops
+        `_d_toks`)."""
         if self.kv.version != self._kv_synced:
             # the mixed step's virtual trash row (row S, all pages
             # unmapped -> physical page 0) rides permanently at the end
@@ -740,7 +792,6 @@ class ServingEngine:
         if self._slots_dirty:
             S = len(self.slots)
             pos = np.zeros(S, np.int32)
-            toks = np.zeros(S, np.int32)
             gen = np.zeros(S, np.int32)
             keys = np.zeros((S, self._kk, 2), np.uint32)
             temp = np.zeros(S, np.float32)
@@ -751,7 +802,7 @@ class ServingEngine:
             for s, sl in enumerate(self.slots):
                 if sl is None:
                     continue
-                pos[s], toks[s], gen[s] = sl.pos, sl.last_tok, sl.gen
+                pos[s], gen[s] = self._cursor(s)
                 keys[s, :sl.keys.shape[0]] = sl.keys
                 temp[s] = sl.req.temperature
                 topk[s] = sl.req.top_k
@@ -759,7 +810,10 @@ class ServingEngine:
                 eos[s] = sl.req.eos_id
                 maxnew[s] = sl.req.max_new
             self._d_pos = self._stage(pos)
-            self._d_toks = self._stage(toks)
+            if self._d_toks is None:
+                self._d_toks = self._stage(np.array(
+                    [0 if sl is None else sl.last_tok for sl in self.slots],
+                    np.int32))
             self._d_gen = self._stage(gen)
             self._d_keys = self._stage(keys)
             self._d_temp = self._stage(temp)
@@ -814,10 +868,13 @@ class ServingEngine:
 
     def _compiled_step(self, kind: str, **attrs):
         """The span of ONE compiled step — `pt.step.decode` / `.mixed` /
-        `.scan` / `.spec`, the kind the scheduler chose in the name — from
-        the call into the compiled program to the host token read (the
-        inter-token latency every live slot paid).  Closes the step's
-        `pt.step.plan` first: planning ends where the dispatch begins."""
+        `.scan` / `.spec`, the kind the scheduler chose in the name.  A
+        decode or mixed step's covers its LAUNCH: the call into the
+        compiled program and the bookkeeping behind it (its tokens are
+        read under a later `pt.step.readback`, see `_land`); a scanned or
+        verify step's still runs to the host token read.  Closes the
+        step's `pt.step.plan` first: planning ends where the dispatch
+        begins."""
         self._end_plan()
         return self._phase(kind, **attrs)
 
@@ -982,6 +1039,12 @@ class ServingEngine:
                      np.asarray(stash, np.int32)]).astype(np.int32)
                 self._finish(request_id, toks, reason)
                 return True
+        if self._pending is not None and any(
+                sl is not None and sl.req.req_id == request_id
+                for sl in self.slots):
+            # its row may be in flight: land it first, so the abort reports
+            # every token that was computed (and may find the request done)
+            self.settle()
         for s, sl in enumerate(self.slots):
             if sl is not None and sl.req.req_id == request_id:
                 gen = sl.generated
@@ -1028,9 +1091,10 @@ class ServingEngine:
             self.cancel(rid, reason="deadline")
 
     def step(self) -> bool:
-        """One scheduler iteration: sweep deadlines -> admit -> one
-        compiled step over all slots -> retire.  Returns False when idle
-        (nothing in flight and nothing admittable).
+        """One scheduler iteration: sweep deadlines -> admit -> plan ->
+        LAUNCH one compiled step over all slots -> LAND (read back, bank,
+        emit, retire).  Returns False when idle (nothing in flight and
+        nothing admittable).
 
         A step with any slot mid-prefill runs the MIXED step: decode rows
         and prompt-chunk rows pack into one ragged [max_step_tokens]
@@ -1038,15 +1102,24 @@ class ServingEngine:
         with only decoding slots keep the classic [S, 1] decode step —
         the steady state pays nothing for the chunk machinery.
 
+        What lands is the step just launched (`lookahead` 0) or the one
+        launched by the previous call (`lookahead` 1: the device starts
+        step N+1 the moment step N ends, and this thread banks N beside
+        it).  Everything that needs the tokens lands first — see
+        `settle`.
+
         Phases, each a span (docs/observability.md "The span model"):
         `pt.step.admit` -> `pt.step.plan` -> the compiled step under its
-        kind's name (`pt.step.dispatch`, `pt.step.readback` inside) ->
+        kind's name (`pt.step.dispatch` inside) -> `pt.step.readback` ->
         `pt.step.emit`."""
         with self._phase("admit"):
             self._sweep_deadlines()
             self._admit_from_queue()
         live = [s for s in range(len(self.slots)) if self.slots[s] is not None]
         if not live:
+            if self._pending is not None:
+                self.settle()            # a retired request's last row
+                return True
             self._t_prev_decode = None   # idle: don't charge the idle gap
             return False
         self._plan_span = self.tracer.begin("pt.step.plan", track="engine")
@@ -1056,17 +1129,57 @@ class ServingEngine:
             if self._plan_span is not None:  # no compiled step ran (preempt
                 self._end_plan()             # to empty, or an exception)
 
+    def settle(self) -> None:
+        """Land the step in flight, if there is one: afterwards the host
+        mirrors, the banked tokens and the device agree, as they do after
+        every step() at `lookahead` 0.  Whatever reads or moves what a
+        land moves calls this first — cancel and the deadline sweep (the
+        abort reports every computed token), preemption, the speculative
+        and scanned steps (the drafter reads tokens; the scan's window
+        starts at the banked cursor), checkpoint_state, the kv transfer
+        plane, every idle-engine knob, and the pump when it stops.  Inside
+        a land it does nothing: `on_finish` may export a prefix there, which
+        reads only donated pages, and those no row in flight writes."""
+        if self._pending is not None and not self._landing:
+            pend, self._pending = self._pending, None
+            self._land(pend)
+
+    def drop_pending(self) -> None:
+        """Wait for the step in flight and forget it, banking nothing: for
+        a pump that died mid-step, whose mirrors may be half-banked."""
+        pend, self._pending = self._pending, None
+        if pend is not None:
+            jax.block_until_ready(pend.nxt)
+
+    def _cursor(self, s: int) -> tuple:
+        """(pos, gen) of slot `s` once the step in flight has landed: the
+        banked cursors plus what that step advances them by, for the
+        request it was launched for.  Both advance by counts the host
+        chose at launch (1 a decode row, n a chunk), so planning step N+1
+        needs nothing of step N but its tokens — and those stay on the
+        device (`EngineState.toks`).  With nothing in flight: the banked
+        cursors."""
+        sl, pend = self.slots[s], self._pending
+        if pend is not None and pend.owners[s] is sl:
+            return sl.pos + pend.adv[s], sl.gen + pend.emit[s]
+        return sl.pos, sl.gen
+
     def _plan_and_run(self, live) -> bool:
         """step() after admission: secure pages (preempting on a wedged
-        pool), choose the step's kind, run it."""
-        while True:
-            # decode-mode slots need their next page; prefill-mode slots
-            # (gen == 0) had their prompt pages secured
-            # at reservation and can always take chunk rows
-            decoding = [s for s in live if self.slots[s].gen > 0]
-            filling = [s for s in live if self.slots[s].gen == 0]
+        pool), choose the step's kind, launch it, land what is due."""
+        while live:
+            cur = {s: self._cursor(s) for s in live}
+            # a slot whose LAST token is in flight (retirement by max_new
+            # is known at launch) takes no further row; decode-mode slots
+            # need their next page; prefill-mode slots (gen == 0) had
+            # their prompt pages secured at reservation and can always
+            # take chunk rows
+            going = [s for s in live
+                     if cur[s][1] < self.slots[s].req.max_new]
+            decoding = [s for s in going if cur[s][1] > 0]
+            filling = [s for s in going if cur[s][1] == 0]
             runnable = [s for s in decoding
-                        if self.kv.try_grow(s, self.slots[s].pos + 1)]
+                        if self.kv.try_grow(s, cur[s][0] + 1)]
             if runnable or (filling and not decoding):
                 # chunk-only steps are progress ONLY while nothing is
                 # decoding: if every decoding slot is page-starved, letting
@@ -1076,6 +1189,13 @@ class ServingEngine:
                 # preemption below would then evict the filler anyway,
                 # discarding a just-finished prefill
                 break
+            if self._pending is not None:
+                # nothing to launch until the step in flight lands: every
+                # slot is finishing in it, or its retirements hold the
+                # pages the others wait for
+                self.settle()
+                live = [s for s in live if self.slots[s] is not None]
+                continue
             # overcommitted-pool wedge: every decoding slot needs its next
             # page and the free list is dry (eviction included).  Preempt
             # the YOUNGEST live slot (the recompute policy of
@@ -1090,8 +1210,8 @@ class ServingEngine:
             victim = max(live, key=lambda s: self.slots[s].admit_seq)
             self._preempt(victim)
             live.remove(victim)
-            if not live:
-                return True        # pages freed; next step() re-admits
+        if not live:
+            return True            # pages freed; next step() re-admits
         if self.spec_k > 0:
             # speculative mode: the drafter proposes per decoding slot
             # (dynamic k may choose 0 for cold/low-accept slots); any
@@ -1103,14 +1223,14 @@ class ServingEngine:
             if drafts or filling:
                 return self._run_spec_step(live, runnable, filling,
                                            drafts)
-        elif filling:
+        if filling:
+            # (a speculative engine's chunks rode the verify step above)
             # mixed prefill/decode load drops to the mixed step PER
             # FLUSH WINDOW — a mid-flight admission is never stalled
             # behind a k-step scan (the scan gate below is only ever
             # reached with no prefill in flight)
-            return self._run_mixed_step(live, runnable, filling)
-
-        if self.decode_steps > 1 \
+            launched = self._launch_mixed(going, runnable, filling, cur)
+        elif self.decode_steps > 1 \
                 and self._scan_window_ok(runnable, self.decode_steps):
             # pure-decode steady state with multi-step on: ONE scanned
             # dispatch advances every runnable slot up to k tokens.  Any
@@ -1123,16 +1243,34 @@ class ServingEngine:
             # so the window is draft-free and the scan is the best
             # remaining dispatch.
             return self._run_scan_step(live, runnable, self.decode_steps)
+        else:
+            launched = self._launch_decode(going, runnable, cur)
+        # launch N+1, THEN land N: the device has its next step queued
+        # before this thread blocks on the last one's tokens
+        due, self._pending = self._pending, launched
+        if due is not None:
+            self._land(due)
+        if self.lookahead == 0 or self.spec_k > 0 or self.decode_steps > 1:
+            # a direct caller looks at what step() banked; the drafter
+            # reads banked tokens and the scan starts from banked cursors:
+            # such an engine keeps nothing in flight
+            self.settle()
+        return True
 
+    def _launch_decode(self, going, runnable, cur) -> _Pending:
+        """Launch ONE pure decode step: every runnable slot advances one
+        token from the device's own `toks`.  `going` are the slots the
+        step works for (occupancy counts them; a slot whose last token is
+        already in flight is not among them), `cur` the plan's cursors."""
         S = len(self.slots)
+        lengths = self._slot_lengths()
         for s in runnable:
-            sl = self.slots[s]
             # a shared page is never written: the page receiving this
             # step's K/V write must be private to the slot (admission's
             # COW guarantees it — this tripwire catches refcount bugs
             # before they corrupt a cached prefix)
-            assert self.kv.page_writable(
-                int(self.kv.table[s, sl.pos // self.kv.page_size])), \
+            assert self.kv.page_writable(int(self.kv.table[
+                s, cur[s][0] // self.kv.page_size])), \
                 f"slot {s} would write a shared page"
         # per-slot pos/toks/gen/keys/knobs already live on device; a
         # steady decode run enters the compiled step with ZERO host
@@ -1141,22 +1279,61 @@ class ServingEngine:
         # every component so no stale (deleted-buffer) aliases survive.
         self._sync_run_mask(runnable)
         self._sync_device_state()
-        with self._compiled_step("decode", live=len(live),
+        with self._compiled_step("decode", live=len(going),
                                  step=self.n_decode_steps + 1):
             with self._phase("dispatch"):
                 st, nxt = self._decode_step(
                     self._step_params, self._build_state(), self._d_run)
             self._unpack_state(st)
-            self.n_decode_steps += 1
-            self.occupancy_sum += len(live) / S
-            self._count_kv(self._slot_lengths())
-            with self._phase("readback"):
-                nxt = self._count_moe(np.asarray(nxt), S)  # host sync
+            self._count_launch(len(going) / S)
+            self._count_kv(lengths)
             self._note_step_metrics(len(runnable), decoded=True)
-        with self._phase("emit", n=len(runnable)):
-            for s in runnable:
-                self._bank_token(s, int(nxt[s]))
-        return True
+        adv = np.zeros(S, np.int32)
+        adv[runnable] = 1
+        return _Pending(nxt, list(self.slots), runnable, [], adv,
+                        adv.astype(bool))
+
+    def _count_launch(self, occupancy: float) -> None:
+        """The counters of one launched decode or mixed step."""
+        self.n_decode_steps += 1
+        self.occupancy_sum += occupancy
+        if self._pending is not None:
+            self.n_lookahead_steps += 1
+            process_counters().add("serving_lookahead_steps_total", 1)
+
+    def _land(self, pend: _Pending) -> None:
+        """The other half of a decode or mixed step: read its tokens back
+        (this is where the host waits for the device), bank and emit them,
+        advance the chunk cursors, retire.  A row whose slot no longer
+        holds the request it was launched for — the request ended on eos
+        at the previous land — is dropped: not banked, not emitted, not
+        counted.  Its K/V write went to a page the slot had secured and
+        `_donate` never offers (only whole pages strictly below the banked
+        `pos`), ahead in device order of any write by the page's next
+        owner; a recurrent slot state it moved is reset by the next
+        admission's first row (position 0)."""
+        S = len(self.slots)
+        self._landing = True
+        try:
+            with self._phase("readback"):
+                nxt = self._count_moe(np.asarray(pend.nxt), S)  # host sync
+            with self._phase("emit", n=len(pend.rows)):
+                for s in pend.rows:
+                    if self.slots[s] is pend.owners[s]:
+                        self._bank_token(s, int(nxt[s]))
+                        continue
+                    self.n_lookahead_dropped_rows += 1
+                    process_counters().add(
+                        "serving_lookahead_dropped_rows_total", 1)
+                    self.flight.record(
+                        "lookahead_drop", slot=s,
+                        req=str(pend.owners[s].req.req_id))
+                # a slot still committing its prompt cannot have ended
+                assert all(self.slots[s] is pend.owners[s]
+                           for s, _, _ in pend.advanced)
+                self._advance_chunks(pend.advanced, lambda s: int(nxt[s]))
+        finally:
+            self._landing = False
 
     def _bank_token(self, s: int, tok: int) -> None:
         """Record one decoded token for slot `s` (shared by the pure
@@ -1193,10 +1370,11 @@ class ServingEngine:
             self._t_prev_decode = now
 
     def _slot_lengths(self) -> np.ndarray:
-        """Tokens each slot's decode row attends: pos + 1 (an empty slot
-        sits at pos 0 and reads one token of the trash page)."""
-        return np.fromiter((1 if sl is None else sl.pos + 1
-                            for sl in self.slots), np.int64,
+        """Tokens each slot's decode row attends: pos + 1, past the step
+        in flight (an empty slot sits at pos 0 and reads one token of the
+        trash page)."""
+        return np.fromiter((1 if sl is None else self._cursor(s)[0] + 1
+                            for s, sl in enumerate(self.slots)), np.int64,
                            len(self.slots))
 
     def _count_kv(self, lengths: np.ndarray) -> None:
@@ -1284,16 +1462,20 @@ class ServingEngine:
             np.arange(k)[:, None], ran[None, :]))
         return True
 
-    def _run_mixed_step(self, live, runnable, filling) -> bool:
-        """ONE mixed prefill/decode dispatch: pack each runnable decode
-        slot's single row plus up to `prefill_chunk` prompt rows per
+    def _launch_mixed(self, going, runnable, filling, cur) -> _Pending:
+        """Launch ONE mixed prefill/decode dispatch: pack each runnable
+        decode slot's single row plus up to `prefill_chunk` prompt rows per
         mid-prefill slot into a flat [max_step_tokens] ragged row list
-        (padding rows aim at a virtual all-trash table row), run the
-        compiled mixed step, then bank decode tokens and advance chunk
-        cursors.  A slot whose FINAL chunk ran this step emits token 0
-        from the last prompt position's logits (keys[0] — the same key
-        schedule lm_generate consumes), so chunk rows emit nothing until
-        their final chunk.
+        (padding rows aim at a virtual all-trash table row) and run the
+        compiled mixed step; `_land` banks the decode tokens and advances
+        the chunk cursors.  A slot whose FINAL chunk ran this step emits
+        token 0 from the last prompt position's logits (keys[0] — the same
+        key schedule lm_generate consumes), so chunk rows emit nothing
+        until their final chunk.
+
+        A decode row's input token is the slot's last sampled one, which
+        the host may not have yet (its step can be in flight): the row is
+        staged as -1 and the compiled step takes `state.toks[slot]`.
 
         The per-step token budget is the HOL-blocking bound: decode rows
         are packed FIRST (every decoding slot advances every step it has
@@ -1316,14 +1498,14 @@ class ServingEngine:
         emit = np.zeros(S, bool)
         r = 0
         for s in runnable:
-            sl = self.slots[s]
+            pos = cur[s][0]
             # same shared-page write tripwire as the pure decode step
             assert self.kv.page_writable(
-                int(self.kv.table[s, sl.pos // ps])), \
+                int(self.kv.table[s, pos // ps])), \
                 f"slot {s} would write a shared page"
-            row_ids[r] = sl.last_tok
+            row_ids[r] = -1
             row_slot[r] = s
-            row_pos[r] = sl.pos
+            row_pos[r] = pos
             sample_row[s] = r
             adv[s] = 1
             emit[s] = True
@@ -1340,7 +1522,7 @@ class ServingEngine:
         # step's scheduling decision, so the six row/mask operands stage
         # per mixed step; the EngineState (donated, rebound) does not.
         self._sync_device_state()
-        with self._compiled_step("mixed", live=len(live), rows=r,
+        with self._compiled_step("mixed", live=len(going), rows=r,
                                  decode_rows=len(runnable),
                                  step=self.n_decode_steps + 1):
             with self._phase("dispatch"):
@@ -1351,18 +1533,12 @@ class ServingEngine:
                     self._stage(sample_row), self._stage(adv),
                     self._stage(emit))
             self._unpack_state(st)
-            self.n_decode_steps += 1
+            self._count_launch(len(going) / S)
             self.n_mixed_steps += 1
-            self.occupancy_sum += len(live) / S
             self._count_kv(row_pos + 1)           # a padding row reads 1
-            with self._phase("readback"):
-                nxt = self._count_moe(np.asarray(nxt), S)  # host sync
             self._note_step_metrics(r, decoded=bool(runnable))
-        with self._phase("emit", n=len(runnable)):
-            for s in runnable:
-                self._bank_token(s, int(nxt[s]))
-            self._advance_chunks(advanced, lambda s: int(nxt[s]))
-        return True
+        return _Pending(nxt, list(self.slots), runnable, advanced, adv,
+                        emit)
 
     def _pack_chunk_rows(self, filling, row_ids, row_slot, row_pos,
                          sample_row, adv, emit, r: int, budget: int):
@@ -1383,18 +1559,19 @@ class ServingEngine:
                 break
             sl = self.slots[s]
             p = sl.req.prompt_ids.size
+            pos = self._cursor(s)[0]        # past any chunk in flight
             n = self._chunk_rows_for(s, budget)
             # every page this chunk writes must be private to the slot
             # (reservation COW'd the shared boundary page; mapped prefix
             # pages below the cursor are never written)
-            for j in range(sl.pos // ps, (sl.pos + n - 1) // ps + 1):
+            for j in range(pos // ps, (pos + n - 1) // ps + 1):
                 assert self.kv.page_writable(int(self.kv.table[s, j])), \
                     f"slot {s} chunk would write shared page " \
                     f"{int(self.kv.table[s, j])}"
-            row_ids[r:r + n] = sl.req.prompt_ids[sl.pos:sl.pos + n]
+            row_ids[r:r + n] = sl.req.prompt_ids[pos:pos + n]
             row_slot[r:r + n] = s
-            row_pos[r:r + n] = np.arange(sl.pos, sl.pos + n)
-            final = sl.pos + n == p
+            row_pos[r:r + n] = np.arange(pos, pos + n)
+            final = pos + n == p
             adv[s] = n
             if final:
                 sample_row[s] = r + n - 1
@@ -1402,7 +1579,7 @@ class ServingEngine:
             self.n_prefill_chunks += 1
             self._bump_attr(sl.req.req_id, "chunks")
             self.flight.record("chunk_sched", req=str(sl.req.req_id),
-                               slot=s, start=int(sl.pos), tokens=int(n),
+                               slot=s, start=int(pos), tokens=int(n),
                                final=final)
             advanced.append((s, n, final))
             budget -= n
@@ -1414,9 +1591,8 @@ class ServingEngine:
         ONE scheduling formula, shared by _pack_chunk_rows and the
         verify step's chunk-reserve computation so the reserve can never
         under-count what the packing will actually schedule."""
-        sl = self.slots[s]
-        return min(sl.req.prompt_ids.size - sl.pos, self.prefill_chunk,
-                   budget)
+        return min(self.slots[s].req.prompt_ids.size - self._cursor(s)[0],
+                   self.prefill_chunk, budget)
 
     def _advance_chunks(self, advanced, tok0_of) -> None:
         """Post-step chunk bookkeeping shared by the mixed and verify
@@ -1891,6 +2067,7 @@ class ServingEngine:
         refuse_for_recurrent(self.kv.slot_specs, "export")
         if self.prefix is None:
             return None
+        self.settle()
         toks = np.asarray(tokens, np.int32).reshape(-1)
         pages, _ = self.prefix.match(toks)
         if not pages:
@@ -1911,6 +2088,7 @@ class ServingEngine:
         refuse_for_recurrent(self.kv.slot_specs, "import")
         if self.prefix is None:
             raise ValueError("kv import: prefix cache is disabled")
+        self.settle()
         toks = np.asarray(tokens, np.int32).reshape(-1)
         n = int(meta.get("n_pages", 0))
         ps = self.kv.page_size
@@ -1942,7 +2120,7 @@ class ServingEngine:
         """Chunk-granular admission — NO prefill dispatch: the slot enters
         PREFILL mode (gen=0) with its prompt pages already reserved, and
         the prompt commits in `prefill_chunk`-token rows inside the next
-        mixed steps (_run_mixed_step).  A prefix hit just means the first
+        mixed steps (_launch_mixed).  A prefix hit just means the first
         `C` tokens are already mapped — the chunk cursor starts at C, and
         a mid-page start writes into the boundary page _reserve COW'd.
         Token 0 is sampled by the step that runs the FINAL chunk; until
@@ -2004,6 +2182,7 @@ class ServingEngine:
             self._retire(s)
 
     def _preempt(self, s: int) -> None:
+        assert self._pending is None, "preempt with a step in flight"
         sl = self.slots[s]
         rid = sl.req.req_id
         self._tr_end(rid, tokens=sl.gen)      # decode/replay ends here
@@ -2048,6 +2227,14 @@ class ServingEngine:
         self.prefix.insert(seq[:full * self.kv.page_size],
                            [int(self.kv.table[s, j]) for j in range(full)])
 
+    def _assert_idle(self, what: str) -> None:
+        """The knobs that need an idle engine: nothing queued, no slot
+        held — and no step in flight (a request that ended on eos may
+        have left its last row there)."""
+        self.settle()
+        assert all(sl is None for sl in self.slots) and not self.queue, \
+            f"{what} requires an idle engine"
+
     def reset_prefix_cache(self) -> None:
         """Full allocator cold start (idle engine only): release every
         slot mapping, forget all prefix retention, rebuild the free list
@@ -2055,8 +2242,7 @@ class ServingEngine:
         placement afterwards is bit-reproducible across engine restarts
         (exactness tests and postmortem engine.json snapshots stay
         stable)."""
-        assert all(sl is None for sl in self.slots) and not self.queue, \
-            "reset_prefix_cache requires an idle engine"
+        self._assert_idle("reset_prefix_cache")
         self.kv.reset()
         if self.prefix is not None:
             self.prefix.clear()
@@ -2070,8 +2256,7 @@ class ServingEngine:
         decoding slot plus up to prefill_chunk rows per chunking prompt,
         never more than the budget per step — the p99 inter-token bound.  Each distinct max_step_tokens value
         is one mixed-step signature; hold it fixed in production."""
-        assert all(sl is None for sl in self.slots) and not self.queue, \
-            "set_chunking requires an idle engine"
+        self._assert_idle("set_chunking")
         if prefill_chunk is None:
             raise ValueError(_NO_UNCHUNKED)
         self._mst_explicit = max_step_tokens is not None
@@ -2116,8 +2301,7 @@ class ServingEngine:
         policy (see `_dyn_k`); it changes HOST-side slicing only, so it
         adds zero signatures and — by the verify step's exactness — zero
         token differences."""
-        assert all(sl is None for sl in self.slots) and not self.queue, \
-            "set_speculation requires an idle engine"
+        self._assert_idle("set_speculation")
         spec_k = int(spec_k)
         if spec_k < 0:
             raise ValueError(
@@ -2167,8 +2351,7 @@ class ServingEngine:
         tokens are IDENTICAL either way; only dispatches-per-token (and
         the streaming burst size) change.  Each distinct k is ONE scanned
         signature per slot count — hold it fixed in production."""
-        assert all(sl is None for sl in self.slots) and not self.queue, \
-            "set_decode_steps requires an idle engine"
+        self._assert_idle("set_decode_steps")
         decode_steps = int(decode_steps)
         if decode_steps < 1:
             raise ValueError(
@@ -2193,6 +2376,7 @@ class ServingEngine:
         match; enabling attaches a fresh empty index."""
         if enabled == (self.prefix is not None):
             return
+        self.settle()
         if enabled:
             refuse_for_recurrent(self.kv.slot_specs, "prefix")
             self.prefix = PrefixTree(self.kv)
@@ -2219,8 +2403,7 @@ class ServingEngine:
         Shrinking below current residency drops LRU HOST leaves until
         the tier fits (0 drains it entirely) — never device state, so
         an idle-engine flip is allocator-exact either way."""
-        assert all(sl is None for sl in self.slots) and not self.queue, \
-            "set_spill_budget requires an idle engine"
+        self._assert_idle("set_spill_budget")
         self.kv.spill_bytes_budget = int(spill_bytes_budget or 0)
         while self.prefix is not None and \
                 self.kv.host_bytes > self.kv.spill_bytes_budget:
@@ -2259,6 +2442,7 @@ class ServingEngine:
                                       else list(r._preempted_gen)),
                     "rng": np.asarray(r.rng).copy()}
 
+        self.settle()       # the mirrors below are one step behind else
         kv = self.kv
         prefix = None
         if self.prefix is not None:
@@ -2364,6 +2548,7 @@ class ServingEngine:
             raise ValueError(
                 f"restore_state: engine configuration mismatch "
                 f"(snapshot vs this engine): {diff}")
+        self.settle()
         if any(sl is not None for sl in self.slots) or self.queue:
             raise ValueError("restore_state requires an idle engine — it "
                              "replaces every slot and queue entry")
@@ -2454,6 +2639,7 @@ class ServingEngine:
                         for k, v in snap["results"].items()}
         self.finish_reasons = dict(snap["finish_reasons"])
         self._slots_dirty = True
+        self._d_toks = None             # the snapshot's last tokens rule
         self._run_host = None
         self._t_prev_decode = None
         # latency attribution across a migration: perf_counter epochs are
@@ -2623,6 +2809,11 @@ class ServingEngine:
         (mid-prefill, paused, empty) sample a padding/decode row's logits
         — computed and discarded, their state frozen by the masks."""
         T = row_ids.shape[0]
+        S = st.toks.shape[0]
+        # a decode row is staged as -1: its token is the slot's last
+        # sampled one, which only the device is sure to have
+        row_ids = jnp.where(row_ids < 0,
+                            st.toks[jnp.minimum(row_slot, S - 1)], row_ids)
         state = self._layer_state(st, None, page_table=st.table,
                                   row_slot=row_slot, row_pos=row_pos)
         feed = {self.input_name: Argument(
@@ -2634,7 +2825,6 @@ class ServingEngine:
         nxt = pick_next_per_slot(last, self._slot_keys(st), st.temp,
                                  st.topk, st.topp, is_probs=self._probs)
         new_pools = self._pools_out(st, state_out)
-        S = st.toks.shape[0]
         new_st = EngineState(pools=new_pools, table=st.table,
                              pos=st.pos + adv,
                              toks=jnp.where(emit, nxt, st.toks),

@@ -259,6 +259,12 @@ class ServingServer:
         self._bg_thread: Optional[threading.Thread] = None
         engine.on_token = self._on_token
         engine.on_finish = self._on_finish
+        # one step in flight (engine.py "ONE STEP IN FLIGHT"): the pump
+        # drives step() and looks at nothing it banked, and the loop
+        # thread's encoding wants the GIL the pump gives up while it waits
+        # for tokens — so the engine may launch step N+1 before it lands
+        # step N.  A direct caller of step() keeps the engine's default.
+        engine.lookahead = 1
         self._init_metrics()
         # the health plane (docs/observability.md "Health plane"): a
         # bounded time-series ring over the registry, fed by a background
@@ -361,6 +367,12 @@ class ServingServer:
                  float(eng.n_prefill_chunks)),
                 ("serving_mixed_steps_total", "counter", None,
                  float(eng.n_mixed_steps)),
+                # one step in flight: steps launched beside a pending
+                # one, and rows computed for a request that had ended
+                ("serving_lookahead_steps_total", "counter", None,
+                 float(eng.n_lookahead_steps)),
+                ("serving_lookahead_dropped_rows_total", "counter", None,
+                 float(eng.n_lookahead_dropped_rows)),
                 # mixture-of-experts: the load of the experts held here
                 ("serving_moe_pairs_total", "counter", None,
                  float(eng.moe_pairs_total)),
@@ -766,6 +778,10 @@ class ServingServer:
                             queue_depth=len(self.engine.queue),
                             inflight=self._inflight)
                     stop = self._drain_commands()
+                    if stop:
+                        # what the step in flight holds is banked (and
+                        # handed over) before the pump is gone
+                        self.engine.settle()
                     self._flush_outbox()
                     if stop:
                         return
@@ -792,6 +808,12 @@ class ServingServer:
                 self._write_bundle("pump_death",
                                    error=err + "\n" + traceback.format_exc())
             finally:
+                try:
+                    # the step in flight is waited for and forgotten: the
+                    # mirrors it would bank into may be half-written
+                    self.engine.drop_pending()
+                except Exception:                  # noqa: BLE001
+                    pass
                 if self._loop is not None:
                     # what the dying step banked goes out first, then
                     # every route still open is failed
@@ -892,6 +914,9 @@ class ServingServer:
                 "slot": {n: {p: list(r) for p, r in parts.items()}
                          for n, parts in eng.kv.slot_specs.items()}}),
             "n_decode_steps": eng.n_decode_steps,
+            # a step launched and not yet landed: the slots' pos/generated
+            # above are then one step behind the device
+            "step_in_flight": eng._pending is not None,
             "tokens_generated": eng.tokens_generated,
             "n_preemptions": eng.n_preemptions,
             "n_cancelled": eng.n_cancelled,
@@ -1570,6 +1595,12 @@ class ServingServer:
             "max_step_tokens": eng.max_step_tokens,
             "prefill_chunks": eng.n_prefill_chunks,
             "mixed_steps": eng.n_mixed_steps,
+            # one step in flight: the depth, how many steps were launched
+            # beside a pending one (over decode_steps: the engaged share),
+            # and rows computed for a request that had already ended
+            "lookahead": eng.lookahead,
+            "lookahead_steps": eng.n_lookahead_steps,
+            "lookahead_dropped_rows": eng.n_lookahead_dropped_rows,
             # the paged kernel's reads: tokens its rows attended against
             # tokens it fetched in whole blocks (their ratio = block fill)
             "kv_tokens_attended": eng.kv_tokens_attended,

@@ -1,5 +1,5 @@
 """Chunked prefill with mixed prefill/decode steps (serving/engine.py
-`_run_mixed_step` + ops/attention.py `ragged_paged_attention_step`).
+`_launch_mixed` + ops/attention.py `ragged_paged_attention_step`).
 
 The exactness contract is unchanged and non-negotiable: whatever the
 chunk size, token budget, prefix-cache state, or preemption schedule, a

@@ -1323,28 +1323,39 @@ def test_pump_and_engine_phase_spans_nest_on_the_profilers_clock(
                for st in by_name["pt.step.mixed"])
 
     # inside every busy pt.engine.step: admit, plan, ONE compiled step
-    # holding dispatch then readback, then emit — in that order
+    # holding its dispatch (the launch), then the readback and the emit of
+    # the step launched one call earlier (the land) — in that order.  The
+    # server runs the engine one step ahead, so a burst's first call
+    # launches and lands nothing, and its last lands and launches nothing.
     def inside(outer, name):
         return sorted((s, e) for n, s, e, _ in pump
                       if n == name and outer[0] <= s and e <= outer[1])
 
-    busy = 0
+    busy = lands = 0
     for n, s, e, _ in pump:
         if n != "pt.engine.step":
             continue
         kinds = inside((s, e), "pt.step.decode") + \
             inside((s, e), "pt.step.mixed")
+        read, emit = (inside((s, e), "pt.step." + p)
+                      for p in ("readback", "emit"))
+        assert len(read) == len(emit) <= 1
+        lands += len(read)
         if not kinds:
-            continue                   # an idle poll: admission only
+            continue                   # an idle poll, or a land alone
         busy += 1
         assert len(kinds) == 1
-        (admit,), (plan,), (emit,) = (inside((s, e), "pt.step." + p)
-                                      for p in ("admit", "plan", "emit"))
-        (disp,), (read,) = (inside(kinds[0], "pt.step." + p)
-                            for p in ("dispatch", "readback"))
-        order = [admit, plan, disp, read, emit]
-        assert all(a[1] <= b[0] + 1000 for a, b in zip(order, order[1:]))
+        (admit,), (plan,) = (inside((s, e), "pt.step." + p)
+                             for p in ("admit", "plan"))
+        (disp,) = inside(kinds[0], "pt.step.dispatch")
+        assert not inside(kinds[0], "pt.step.readback")
+        order = [admit, plan, disp, kinds[0]] + read + emit
+        assert all(a[1] <= b[1] + 1000 for a, b in zip(order, order[1:]))
+        assert all(a[1] <= b[0] + 1000
+                   for a, b in zip(order[3:], order[4:]))
     assert busy >= eng.n_decode_steps - 2      # the warm request's are out
+    assert lands >= busy                       # every launch was landed
+    assert eng.n_lookahead_steps >= busy - 4   # ... one call later
 
 
 @pytest.mark.parametrize("kind", ["decode", "mixed", "scan", "spec"])
@@ -1374,13 +1385,18 @@ def test_engine_ring_spans_name_the_step_kind(tiny_tr, kind):
     if kind == "spec":
         assert "pt.step.draft" in names
         assert names.count("pt.step.draft") >= eng.n_draft_steps > 0
-    # per step: admit, plan, dispatch, readback, <kind>, emit close in
-    # this order (a span is recorded when it ends)
+    # per step, in the order the spans close (a span is recorded when it
+    # ends): admit, plan, dispatch, then a decode or mixed step's own span
+    # (its launch) BEFORE the readback and the emit of its land; a scanned
+    # or verify step's span still holds its readback
     per_step = [n for n in names if n != "pt.step.draft"]
     i = per_step.index("pt.step.plan") - 1
+    first = steps[0]["name"]
+    land = ["pt.step.readback", first] if kind == "spec" else \
+        [first, "pt.step.readback"]
     assert per_step[i:i + 6] == [
-        "pt.step.admit", "pt.step.plan", "pt.step.dispatch",
-        "pt.step.readback", steps[0]["name"], "pt.step.emit"]
+        "pt.step.admit", "pt.step.plan", "pt.step.dispatch", *land,
+        "pt.step.emit"]
     import inspect
     import re
     src = inspect.getsource(ServingEngine)

@@ -166,7 +166,9 @@ def render(bundle: dict, n_events: int = 20) -> str:
                    f"tokens={eng.get('tokens_generated')} "
                    f"preempts={eng.get('n_preemptions')} "
                    f"cancelled={eng.get('n_cancelled')} "
-                   f"expired={eng.get('n_expired')}")
+                   f"expired={eng.get('n_expired')}"
+                   + ("  (a step in flight: slots are one step behind "
+                      "the device)" if eng.get("step_in_flight") else ""))
         if isinstance(slots, list):
             out.append(f"  slots: {len(live)}/{len(slots)} occupied")
             for s in live:
