@@ -30,6 +30,9 @@ undocumented, and the doc cannot drift from the code.
 from __future__ import annotations
 
 import threading
+import time
+from bisect import bisect_left, bisect_right
+from collections import deque
 from typing import Callable, Iterable, Optional
 
 #: every metric name this repo emits -> one-line help.  The single source
@@ -105,6 +108,25 @@ CATALOG: dict[str, str] = {
     "serving_lookahead_dropped_rows_total":
         "rows of an in-flight step whose request had ended by the time "
         "the step landed (computed, never banked or emitted)",
+    # -- the step clock: the pump's phases and a step's flight, always on
+    # (docs/observability.md "The step clock"; process counters) ----------
+    "serving_pump_seconds_total":
+        "seconds the pump thread spent inside each of its spans (label "
+        "span; inclusive: pt.kv.evict is also inside plan or admit, "
+        "pt.step.* inside pt.engine.step), fed by the span's own clock pair",
+    "serving_pump_spans_total":
+        "spans of the pump thread closed, by name (label span)",
+    "serving_step_flight_seconds_total":
+        "seconds from a compiled step's launch to its tokens on the host, "
+        "summed over landed steps (label kind)",
+    "serving_steps_landed_total":
+        "compiled steps whose tokens were read back (label kind)",
+    "serving_loop_send_seconds_total":
+        "seconds the loop thread spent encoding and writing frames "
+        "(what pt.loop.send annotates)",
+    "serving_loop_sends_total":
+        "transport writes the loop thread made (one frame, or one "
+        "connection's token frames of a step)",
     # -- flash kernels, counted when a call is traced into a program ------
     "flash_grid_steps_total":
         "grid steps of the flash kernel calls traced so far (label kernel)",
@@ -594,19 +616,83 @@ class ProcessCounters:
     """Cumulative counters of the PROCESS, not of one object: they outlive
     the engine or trainer that bumps them, so a harness can read them after
     the server has stopped (as it reads obs/compile_watch.py's).  Names are
-    CATALOG names; values only grow."""
+    CATALOG names (a labelled one as `name{label="value"}`); values only
+    grow.
+
+    `checkpoint()` keeps the last `CHECKPOINTS` (time, snapshot) pairs, so
+    `between()` gives any counter's growth over a WINDOW of the process —
+    not warm-up and ramp too — and with stretches cut out of it (the
+    profiler's slice of a traced run).  The serving pump checkpoints every
+    0.1 s; whoever else wants windows calls it at its own pace."""
+
+    CHECKPOINTS = 4096
 
     def __init__(self):
         self._lock = threading.Lock()
         self._values: dict[str, float] = {}
+        self._checkpoints: deque = deque(maxlen=self.CHECKPOINTS)
 
     def add(self, name: str, n: float) -> None:
         with self._lock:
             self._values[name] = self._values.get(name, 0) + n
 
+    def add_many(self, deltas: dict) -> None:
+        """Several counters under ONE lock (the pump's flush, once a step)."""
+        with self._lock:
+            values = self._values
+            for name, n in deltas.items():
+                values[name] = values.get(name, 0) + n
+
     def snapshot(self) -> dict:
         with self._lock:
             return dict(self._values)
+
+    def checkpoint(self, now: Optional[float] = None) -> None:
+        """Remember every counter's value at `now` (`time.perf_counter()`
+        unless given: the span tracer's clock)."""
+        if now is None:
+            now = time.perf_counter()
+        with self._lock:
+            self._checkpoints.append((now, dict(self._values)))
+
+    def between(self, t0: float, t1: float, exclude=(),
+                max_edge: float = 1.0) -> tuple[dict, float]:
+        """Each counter's growth inside [t0, t1] less the `exclude`d
+        stretches [(a, b), ...], and the seconds that growth was counted
+        over.  Every stretch that is left is read from the checkpoints
+        nearest INSIDE it, so what is lost is under one checkpoint period
+        an edge, and the seconds returned are those between the checkpoints
+        used: divide by them, not by t1 - t0.  Raises LookupError where
+        the checkpoints do not cover a stretch (none inside, or more than
+        `max_edge` seconds of an edge without one: checkpointing had not
+        begun, had stopped, or the ring has wrapped past it)."""
+        stretches, a = [], t0
+        for x0, x1 in sorted(exclude):
+            x0, x1 = max(x0, t0), min(x1, t1)
+            if x1 <= x0:
+                continue
+            if x0 > a:
+                stretches.append((a, x0))
+            a = max(a, x1)
+        if t1 > a:
+            stretches.append((a, t1))
+        with self._lock:
+            cps = list(self._checkpoints)
+        times = [t for t, _ in cps]
+        growth: dict = {}
+        seconds = 0.0
+        for a, b in stretches:
+            i, j = bisect_left(times, a), bisect_right(times, b) - 1
+            if i >= j or times[i] - a > max_edge or b - times[j] > max_edge:
+                raise LookupError(
+                    f"no checkpoints cover [{a:.3f}, {b:.3f}] (have "
+                    f"{len(times)}" + (f" from {times[0]:.3f} to "
+                                       f"{times[-1]:.3f})" if times else ")"))
+            first, last = cps[i][1], cps[j][1]
+            for name, v in last.items():
+                growth[name] = growth.get(name, 0) + v - first.get(name, 0)
+            seconds += times[j] - times[i]
+        return growth, seconds
 
 
 _PROCESS_COUNTERS = ProcessCounters()
@@ -614,6 +700,84 @@ _PROCESS_COUNTERS = ProcessCounters()
 
 def process_counters() -> ProcessCounters:
     return _PROCESS_COUNTERS
+
+
+class SpanSeconds:
+    """The always-on side of a thread's spans: `sink(span)` is what the
+    owner passes as `Tracer.span(..., sink=)`, so the span's one clock pair
+    also adds its seconds to `<seconds_metric>{span="<name>"}` and 1 to
+    `<spans_metric>{span="<name>"}`.  ONE thread owns an instance (the
+    serving pump): sinks and `add()` touch a plain dict, and `flush()` —
+    once a step — moves what gathered into the process's counters under
+    one lock.  Nothing is kept here past a flush: the counters are the
+    one place a total lives."""
+
+    def __init__(self, seconds_metric: str, spans_metric: str,
+                 counters: Optional[ProcessCounters] = None):
+        self.seconds_metric = seconds_metric
+        self.spans_metric = spans_metric
+        self.counters = counters if counters is not None \
+            else process_counters()
+        self._pending: dict = {}
+        self._sinks: dict = {}
+
+    def sink(self, span: str):
+        f = self._sinks.get(span)
+        if f is None:
+            pend = self._pending
+            ks = counter_key(self.seconds_metric, span=span)
+            kn = counter_key(self.spans_metric, span=span)
+
+            def f(seconds: float) -> None:
+                pend[ks] = pend.get(ks, 0.0) + seconds
+                pend[kn] = pend.get(kn, 0) + 1
+
+            self._sinks[span] = f
+        return f
+
+    def add(self, name: str, n: float) -> None:
+        """Any other counter of the owning thread, flushed with the rest."""
+        self._pending[name] = self._pending.get(name, 0) + n
+
+    def flush(self) -> None:
+        if self._pending:
+            self.counters.add_many(self._pending)
+            self._pending.clear()
+
+
+def counter_key(name: str, **labels) -> str:
+    """A labelled process counter's key: `name{k="v",...}`, as `render()`
+    writes a sample."""
+    return name + _fmt_labels(labels)
+
+
+def split_labels(key: str) -> tuple:
+    """`counter_key` back again: `name{k="v",...}` -> (name, labels|None)."""
+    name, brace, rest = key.partition("{")
+    if not brace:
+        return key, None
+    return name, dict((k, v.strip('"')) for k, _, v in
+                      (kv.partition("=") for kv in rest[:-1].split(",")))
+
+
+def process_counter_collector(names: Iterable[str],
+                              counters: Optional[ProcessCounters] = None):
+    """Expose the process counters of the families `names` (labelled keys
+    split back into labels).  For families that live ONLY there — the
+    step clock's; the ones an engine attribute also carries are rendered
+    from the attribute."""
+    names = frozenset(names)
+
+    def collect():
+        pc = counters if counters is not None else process_counters()
+        out = []
+        for key, value in sorted(pc.snapshot().items()):
+            name, labels = split_labels(key)
+            if name in names:
+                out.append((name, "counter", labels, float(value)))
+        return out
+
+    return collect
 
 
 def statset_collector(statset, metric: str, count_metric: str,

@@ -16,7 +16,11 @@ Design constraints, in order:
      built only while a profiler session runs (`is_enabled()`, 0.05 us
      to ask) — so one call site feeds the operator's ring and the
      profiler's timeline alike (see "Two sinks" below).  With both off a
-     span is its object and the thread's open-span stack: about 0.9 us.
+     span is its object and the thread's open-span stack: about 0.9 us;
+     one given a `sink=` also reads the clock twice and calls the sink
+     (the serving pump's spans all do: the always-on second counters of
+     docs/observability.md "The step clock"), and still writes no ring
+     record and builds no annotation.
   2. **Single-writer ring.**  Spans are appended by the owning thread
      only; `snapshot()` may run on another thread (drain, a test) and
      copies the list under the GIL, using each record's monotonic `seq`
@@ -287,11 +291,11 @@ class Tracer:
         global_stat / BarrierTimer sites are timed once)."""
         return _Span(self, name, track, attrs or None, sink)
 
-    def begin(self, name: str, track: str = "main", **attrs):
+    def begin(self, name: str, track: str = "main", sink=None, **attrs):
         """Open a span that a LATER call on the SAME thread (possibly in
         another method) closes via end(); spans opened in between must be
         closed first.  Returns an opaque handle."""
-        sp = _Span(self, name, track, attrs or None)
+        sp = _Span(self, name, track, attrs or None, sink)
         sp.__enter__()
         return sp
 

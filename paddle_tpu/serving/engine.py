@@ -108,7 +108,8 @@ from paddle_tpu.graph.context import TEST
 from paddle_tpu.graph.lm_decode import _is_probs, _resolve_io_names
 from paddle_tpu.obs.compile_watch import get_compile_watch
 from paddle_tpu.obs.flight import get_flight_recorder
-from paddle_tpu.obs.metrics import process_counters
+from paddle_tpu.obs.metrics import (SpanSeconds, counter_key,
+                                    process_counters)
 from paddle_tpu.obs.trace import get_tracer
 from paddle_tpu.parallel.mesh import MODEL_AXIS, axis_size
 from paddle_tpu.parameter.argument import Argument
@@ -235,6 +236,13 @@ class _Slot:
         self.probe_tick = 0
 
 
+#: a landed step's two counters, by its kind
+_FLIGHT_COUNTERS = {
+    kind: (counter_key("serving_step_flight_seconds_total", kind=kind),
+           counter_key("serving_steps_landed_total", kind=kind))
+    for kind in ("decode", "mixed", "scan", "spec")}
+
+
 class _Pending:
     """One compiled decode or mixed step between its LAUNCH and its LAND:
     the device array of sampled tokens (the counts of `_with_counts`
@@ -242,11 +250,18 @@ class _Pending:
     anything the next launch may have moved — per slot the `_Slot` that
     owned it at launch, the decode rows, the chunk runs, and how far the
     step advances each slot's pos and gen (`ServingEngine._cursor` adds
-    them to the banked cursors while the step is in flight)."""
+    them to the banked cursors while the step is in flight) — and the
+    step's clock: its kind, its number and when its launch span began, so
+    the land can say how long the step was in flight."""
 
-    __slots__ = ("nxt", "owners", "rows", "advanced", "adv", "emit")
+    __slots__ = ("nxt", "owners", "rows", "advanced", "adv", "emit",
+                 "kind", "step", "t_launch")
 
-    def __init__(self, nxt, owners, rows, advanced, adv, emit):
+    def __init__(self, nxt, owners, rows, advanced, adv, emit, kind, step,
+                 t_launch):
+        self.kind = kind            # "decode" | "mixed"
+        self.step = step            # n_decode_steps once launched
+        self.t_launch = t_launch    # its pt.step.<kind> span's own start
         self.nxt = nxt              # device [S (+ counts)] int32
         self.owners = owners        # [S] the _Slot in each slot at launch
         self.rows = rows            # slots that ran a decode row
@@ -388,6 +403,13 @@ class ServingEngine:
         self._obs_open: dict = {}   # req_id -> open span handle (one phase
                                     # open per request at any moment)
         self._plan_span = None      # the running step's open pt.step.plan
+        # the step clock (docs/observability.md "The step clock"): every
+        # span of the pump thread — this engine's, and the server's around
+        # them — hands its seconds to `step_clock` through the span's own
+        # sink, ring and profiler on or off; step() flushes it to the
+        # process's counters, once
+        self.step_clock = SpanSeconds("serving_pump_seconds_total",
+                                      "serving_pump_spans_total")
         self._req_trace: dict = {}  # req_id -> inbound trace context
         # per-request latency attribution (ALWAYS on — the phase
         # transitions below are a handful of clock reads per request
@@ -861,10 +883,18 @@ class ServingEngine:
         self._d_topp = st.topp
 
     # -- phase spans (the pump thread's `pt.step.*` / `pt.kv.*` family) ----
-    def _phase(self, name: str, **attrs):
+    def _phase(self, name: str, also=None, **attrs):
         """One phase of a step on the engine lane: `pt.step.<name>`, fed to
-        the ring and the profiler alike (obs/trace.py "Two sinks")."""
-        return self.tracer.span("pt.step." + name, track="engine", **attrs)
+        the ring and the profiler alike (obs/trace.py "Two sinks"), and to
+        the step clock whether or not either is on (`also`: one more taker
+        of the same seconds)."""
+        name = "pt.step." + name
+        sink = self.step_clock.sink(name)
+        if also is not None:
+            def sink(seconds, clock=sink):
+                clock(seconds)
+                also(seconds)
+        return self.tracer.span(name, track="engine", sink=sink, **attrs)
 
     def _compiled_step(self, kind: str, **attrs):
         """The span of ONE compiled step — `pt.step.decode` / `.mixed` /
@@ -891,7 +921,9 @@ class ServingEngine:
         pool under 256 pages), the coldest leaves first as ever.  What is
         given up is the prefix cache's 0.4% least-recently-used tail."""
         n_pages = max(int(n_pages), self.kv.num_pages // 256)
-        with self.tracer.span("pt.kv.evict", track="engine", pages=n_pages):
+        with self.tracer.span("pt.kv.evict", track="engine",
+                              sink=self.step_clock.sink("pt.kv.evict"),
+                              pages=n_pages):
             return self.prefix.evict_for(n_pages)
 
     # -- lifecycle tracing helpers ----------------------------------------
@@ -1111,7 +1143,13 @@ class ServingEngine:
         Phases, each a span (docs/observability.md "The span model"):
         `pt.step.admit` -> `pt.step.plan` -> the compiled step under its
         kind's name (`pt.step.dispatch` inside) -> `pt.step.readback` ->
-        `pt.step.emit`."""
+        `pt.step.emit`.  Each also feeds the step clock, flushed here."""
+        try:
+            return self._step()
+        finally:
+            self.step_clock.flush()
+
+    def _step(self) -> bool:
         with self._phase("admit"):
             self._sweep_deadlines()
             self._admit_from_queue()
@@ -1122,7 +1160,9 @@ class ServingEngine:
                 return True
             self._t_prev_decode = None   # idle: don't charge the idle gap
             return False
-        self._plan_span = self.tracer.begin("pt.step.plan", track="engine")
+        self._plan_span = self.tracer.begin(
+            "pt.step.plan", track="engine",
+            sink=self.step_clock.sink("pt.step.plan"))
         try:
             return self._plan_and_run(live)
         finally:
@@ -1280,7 +1320,7 @@ class ServingEngine:
         self._sync_run_mask(runnable)
         self._sync_device_state()
         with self._compiled_step("decode", live=len(going),
-                                 step=self.n_decode_steps + 1):
+                                 step=self.n_decode_steps + 1) as launch:
             with self._phase("dispatch"):
                 st, nxt = self._decode_step(
                     self._step_params, self._build_state(), self._d_run)
@@ -1291,7 +1331,8 @@ class ServingEngine:
         adv = np.zeros(S, np.int32)
         adv[runnable] = 1
         return _Pending(nxt, list(self.slots), runnable, [], adv,
-                        adv.astype(bool))
+                        adv.astype(bool), "decode", self.n_decode_steps,
+                        launch.t0)
 
     def _count_launch(self, occupancy: float) -> None:
         """The counters of one launched decode or mixed step."""
@@ -1299,7 +1340,6 @@ class ServingEngine:
         self.occupancy_sum += occupancy
         if self._pending is not None:
             self.n_lookahead_steps += 1
-            process_counters().add("serving_lookahead_steps_total", 1)
 
     def _land(self, pend: _Pending) -> None:
         """The other half of a decode or mixed step: read its tokens back
@@ -1315,16 +1355,17 @@ class ServingEngine:
         S = len(self.slots)
         self._landing = True
         try:
-            with self._phase("readback"):
+            with self._phase("readback", step=pend.step, kind=pend.kind):
                 nxt = self._count_moe(np.asarray(pend.nxt), S)  # host sync
-            with self._phase("emit", n=len(pend.rows)):
+            self._landed(pend.kind, pend.step, pend.t_launch,
+                         len(pend.rows))
+            with self._phase("emit", n=len(pend.rows), step=pend.step,
+                             kind=pend.kind):
                 for s in pend.rows:
                     if self.slots[s] is pend.owners[s]:
                         self._bank_token(s, int(nxt[s]))
                         continue
                     self.n_lookahead_dropped_rows += 1
-                    process_counters().add(
-                        "serving_lookahead_dropped_rows_total", 1)
                     self.flight.record(
                         "lookahead_drop", slot=s,
                         req=str(pend.owners[s].req.req_id))
@@ -1334,6 +1375,23 @@ class ServingEngine:
                 self._advance_chunks(pend.advanced, lambda s: int(nxt[s]))
         finally:
             self._landing = False
+
+    def _landed(self, kind: str, step: int, t_launch: float,
+                rows: int) -> None:
+        """A compiled step's tokens are on the host: close its flight —
+        launch to here — into the step clock, and while the ring is on as
+        a `pt.step.flight` record on a lane of its own (flights of
+        consecutive steps overlap at `lookahead` 1, so they cannot nest on
+        the engine's lane; `step=` pairs one with the launch span and with
+        the read-back and emit that landed it)."""
+        dur = time.perf_counter() - t_launch
+        seconds, landed = _FLIGHT_COUNTERS[kind]
+        self.step_clock.add(seconds, dur)
+        self.step_clock.add(landed, 1)
+        if self.tracer.enabled:
+            self.tracer.add("pt.step.flight", t_launch, dur, track="flight",
+                            attrs={"step": step, "kind": kind,
+                                   "rows": rows})
 
     def _bank_token(self, s: int, tok: int) -> None:
         """Record one decoded token for slot `s` (shared by the pure
@@ -1422,7 +1480,7 @@ class ServingEngine:
         self._sync_device_state()
         scan_step = self._scan_step_fn()
         with self._compiled_step("scan", live=len(live), k=k,
-                                 step=self.n_decode_steps + 1):
+                                 step=self.n_decode_steps + 1) as launch:
             with self._phase("dispatch"):
                 st, blk = scan_step(
                     k, self._step_params, self._build_state(), self._d_run,
@@ -1434,12 +1492,14 @@ class ServingEngine:
             self.occupancy_sum += len(live) / S
             base = self._slot_lengths()
             ran = np.zeros(S, np.int64)     # bodies each slot advanced in
-            with self._phase("readback"):
+            step = self.n_decode_steps
+            with self._phase("readback", step=step, kind="scan"):
                 blk = self._count_moe(np.asarray(blk), S)  # [k, S] sync
+            self._landed("scan", step, launch.t0, len(runnable))
             self._note_step_metrics(len(runnable), decoded=True)
         # per-flush, never per-token: one boundary event each k tokens
         self.flight.record("scan_flush", k=k, slots=len(runnable))
-        with self._phase("emit", n=len(runnable)):
+        with self._phase("emit", n=len(runnable), step=step, kind="scan"):
             for s in runnable:
                 sl = self.slots[s]
                 burst = []
@@ -1524,7 +1584,7 @@ class ServingEngine:
         self._sync_device_state()
         with self._compiled_step("mixed", live=len(going), rows=r,
                                  decode_rows=len(runnable),
-                                 step=self.n_decode_steps + 1):
+                                 step=self.n_decode_steps + 1) as launch:
             with self._phase("dispatch"):
                 st, nxt = self._mixed_step(
                     self._step_params, self._build_state(),
@@ -1538,7 +1598,7 @@ class ServingEngine:
             self._count_kv(row_pos + 1)           # a padding row reads 1
             self._note_step_metrics(r, decoded=bool(runnable))
         return _Pending(nxt, list(self.slots), runnable, advanced, adv,
-                        emit)
+                        emit, "mixed", self.n_decode_steps, launch.t0)
 
     def _pack_chunk_rows(self, filling, row_ids, row_slot, row_pos,
                          sample_row, adv, emit, r: int, budget: int):
@@ -1677,7 +1737,7 @@ class ServingEngine:
         if not want:
             return out
         took: list = []                  # the span's own clock pair
-        with self._phase("draft", sink=took.append, k=self.spec_k,
+        with self._phase("draft", also=took.append, k=self.spec_k,
                          drafter=self.drafter_kind):
             if hasattr(self.drafter, "propose_batch"):
                 out = self._propose_batched(want, W)
@@ -1849,7 +1909,7 @@ class ServingEngine:
         self._sync_device_state()
         with self._compiled_step("spec", live=len(live), rows=r,
                                  decode_rows=len(runnable),
-                                 step=self.n_decode_steps + 1):
+                                 step=self.n_decode_steps + 1) as launch:
             with self._phase("dispatch"):
                 st, sampled, acc = self._spec_step(
                     self._step_params, self._build_state(),
@@ -1865,11 +1925,13 @@ class ServingEngine:
                 self.n_mixed_steps += 1
             self.occupancy_sum += len(live) / S
             self._count_kv(row_pos + 1)           # a padding row reads 1
-            with self._phase("readback"):
+            step = self.n_decode_steps
+            with self._phase("readback", step=step, kind="spec"):
                 sampled = np.asarray(sampled)              # host sync
                 acc = np.asarray(acc)
+            self._landed("spec", step, launch.t0, len(runnable))
             self._note_step_metrics(r, decoded=bool(runnable))
-        with self._phase("emit", n=len(runnable)):
+        with self._phase("emit", n=len(runnable), step=step, kind="spec"):
             for s in runnable:
                 sl = self.slots[s]
                 a = int(acc[s])

@@ -67,7 +67,8 @@ from paddle_tpu.obs import (MetricsRegistry, statset_collector,
 from paddle_tpu.obs.compile_watch import compile_collector, get_compile_watch
 from paddle_tpu.obs.flight import flight_collector, get_flight_recorder
 from paddle_tpu.obs.hbm import hbm_collector, hbm_snapshot
-from paddle_tpu.obs.metrics import process_counters
+from paddle_tpu.obs.metrics import (process_counter_collector,
+                                    process_counters, split_labels)
 from paddle_tpu.obs.slo import SloEvaluator, default_serving_slos
 from paddle_tpu.obs.timeseries import (HistorySampler, MetricHistory,
                                        history_collector, history_reply)
@@ -117,15 +118,58 @@ class _Conn(wire.FrameConn):
     every WRITE (one frame, or a step's token frames for this connection)
     as `pt.loop.send` on the loop thread (here, not in wire.py, which the
     JAX-free client imports) — for the profiler alone: a span a write
-    would wrap the ring within minutes."""
+    would wrap the ring within minutes — and, always, into the step
+    clock's `serving_loop_send_seconds_total` / `serving_loop_sends_total`:
+    two clock reads a write, whatever the write carries."""
 
     def send(self, msg: dict) -> None:
+        t0 = time.perf_counter()
         with annotation("pt.loop.send"):
             self._write(wire.encode(msg))
+        _count_send(t0)
 
     def send_many(self, msgs: list) -> None:
+        t0 = time.perf_counter()
         with annotation("pt.loop.send"):
             super().send_many(msgs)
+        _count_send(t0)
+
+
+def _count_send(t0: float) -> None:
+    process_counters().add_many({
+        "serving_loop_send_seconds_total": time.perf_counter() - t0,
+        "serving_loop_sends_total": 1})
+
+
+#: the step clock's families: they live in the process's counters alone
+#: (docs/observability.md "The step clock"); `metrics` and the `stats`
+#: frame's `steps` block read them there
+STEP_CLOCK_COUNTERS = (
+    "serving_pump_seconds_total", "serving_pump_spans_total",
+    "serving_step_flight_seconds_total", "serving_steps_landed_total",
+    "serving_loop_send_seconds_total", "serving_loop_sends_total")
+
+#: the pump checkpoints the process's counters this often (seconds), so a
+#: reader can take any of them over a window (ProcessCounters.between)
+CHECKPOINT_EVERY_S = 0.1
+
+
+def step_clock_stats() -> dict:
+    """The `stats` frame's `steps` block: the step clock's counters, by
+    family and label value (cumulative, and the PROCESS's: two servers in
+    one process share them)."""
+    out: dict = {}
+    for key, value in process_counters().snapshot().items():
+        name, labels = split_labels(key)
+        if name not in STEP_CLOCK_COUNTERS:
+            continue
+        short = name[len("serving_"):-len("_total")]
+        value = round(value, 6) if isinstance(value, float) else value
+        if labels:
+            out.setdefault(short, {})[next(iter(labels.values()))] = value
+        else:
+            out[short] = value
+    return out
 
 
 def _kv_push_frames(cid, toks, meta: dict, payload: bytes) -> list[bytes]:
@@ -225,6 +269,7 @@ class ServingServer:
         self.wedge_threshold_s = float(wedge_threshold_s)
         self._wedge_dumped = False    # one bundle per wedge episode
         self._last_beat_event = 0.0   # flight beats sampled at ~1/s
+        self._last_checkpoint = 0.0   # process counters, every 0.1 s
         self._inflight = 0            # accepted, not finished (loop thread)
         self._draining = False
         # pump heartbeat: (monotonic time, engine step count) written by
@@ -435,6 +480,11 @@ class ServingServer:
               + eng.spec_k_hist.samples()
 
         reg.register_collector(engine_state)
+        # families that live in the process's counters alone: the step
+        # clock's, and the weight casts of `ServingEngine.params`
+        reg.register_collector(process_counter_collector(
+            STEP_CLOCK_COUNTERS + ("serving_step_weight_casts_total",
+                                   "serving_step_weight_cast_bytes_total")))
         reg.register_collector(statset_collector(
             self.stats, "serving_latency_seconds", "serving_latency_count"))
         reg.register_collector(tracer_collector(self.tracer))
@@ -746,9 +796,9 @@ class ServingServer:
         if writes:
             self.n_token_frames += frames
             self.n_frame_writes += writes
-            pc = process_counters()
-            pc.add("serving_token_frames_total", frames)
-            pc.add("serving_frame_writes_total", writes)
+            process_counters().add_many({
+                "serving_token_frames_total": frames,
+                "serving_frame_writes_total": writes})
 
     def _pump(self) -> None:
         """The pump loop.  Its phases are spans on the `pump` lane (names
@@ -757,16 +807,26 @@ class ServingServer:
         by what this thread was doing.  The outbox is flushed wherever the
         engine may have banked something: after the command drain (a
         cancel finishes a request there) and after the step — so it is
-        empty whenever the pump waits, stops or dies."""
+        empty whenever the pump waits, stops or dies.  Each span also
+        feeds the engine's step clock (always on), and every 0.1 s the
+        heartbeat checkpoints the process's counters, flushed first, so
+        any of them can be read over a window."""
         span = self.tracer.span
+        clock = self.engine.step_clock
+        commands, step, wait = (clock.sink(name) for name in (
+            "pt.pump.commands", "pt.engine.step", "pt.pump.wait"))
         try:
+            self._checkpoint()
             while True:
-                with span("pt.pump.commands", track="pump"):
+                with span("pt.pump.commands", track="pump", sink=commands):
                     # heartbeat FIRST: written once per loop iteration, so
                     # a wedge anywhere below (a hung compiled step, a stuck
                     # host sync) freezes it and pump_last_step_age_s grows
                     now = time.monotonic()
                     self._pump_beat = (now, self.engine.n_decode_steps)
+                    if now - self._last_checkpoint >= CHECKPOINT_EVERY_S:
+                        self._last_checkpoint = now
+                        self._checkpoint()
                     if now - self._last_beat_event >= 1.0:
                         # SAMPLED into the flight ring (~1/s): a postmortem
                         # shows how recently, and at what step, the pump
@@ -784,16 +844,17 @@ class ServingServer:
                         self.engine.settle()
                     self._flush_outbox()
                     if stop:
-                        return
-                with span("pt.engine.step", track="pump"):
+                        break
+                with span("pt.engine.step", track="pump", sink=step):
                     busy = self.engine.step()
                     self._flush_outbox()
                 if not busy:
                     # idle: nothing queued or in flight — sleep until a
                     # command arrives (bounded wait as a safety net)
-                    with span("pt.pump.wait", track="pump"):
+                    with span("pt.pump.wait", track="pump", sink=wait):
                         self._wake.wait(timeout=0.5)
                         self._wake.clear()
+            self._checkpoint()
         except BaseException as e:                     # noqa: BLE001
             self._pump_error = e
             # the black-box moment: the pump thread is dying with the
@@ -819,6 +880,13 @@ class ServingServer:
                     # every route still open is failed
                     self._post(None, self._pump_died_on_loop)
                     self._flush_outbox()
+
+    def _checkpoint(self) -> None:
+        """Pump thread: what the step clock holds goes to the process's
+        counters, and they are checkpointed (the pump's start, every 0.1 s
+        of its heartbeat, its stop)."""
+        self.engine.step_clock.flush()
+        process_counters().checkpoint()
 
     def _pump_died_on_loop(self) -> None:
         """A dead pump strands every accepted request — fail them all so
@@ -1660,6 +1728,9 @@ class ServingServer:
             # token delivery: frames / the writes that carried them
             "token_frames": self.n_token_frames,
             "frame_writes": self.n_frame_writes,
+            # the step clock: seconds and counts of the pump's spans, a
+            # step's flight by kind, the loop thread's sends
+            "steps": step_clock_stats(),
             "pump_alive": self.pump_alive(),
             "pump_last_step_age_s": round(self.pump_last_step_age(), 3),
             "latency_ms": lat,

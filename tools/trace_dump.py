@@ -12,7 +12,7 @@ as bars, instants (preempt/done/cancelled/deadline) as markers.
   # convert + eyeball
   python tools/trace_dump.py spans.jsonl -o trace.json
   python tools/trace_dump.py spans.jsonl --summary      # per-name table,
-                                  # per-lane counts, compile-lane breakdown
+                                  # per-lane counts, flight- and compile-lane breakdowns
 
 Distributed traces (docs/observability.md "Distributed tracing"): a
 fleet request crosses router and replica processes — and a training
@@ -131,10 +131,46 @@ def summarize(spans: list[dict]) -> str:
         lines.append(f"{track:<16} {lanes[track]:>7}")
 
     lines.append(f"{len(spans)} spans on {len(lanes)} lanes")
-    comp = compile_breakdown(spans)
-    if comp:
-        lines.append("")
-        lines.append(comp)
+    for part in (flight_breakdown(spans), compile_breakdown(spans)):
+        if part:
+            lines.append("")
+            lines.append(part)
+    return "\n".join(lines)
+
+
+def flight_breakdown(spans: list[dict]) -> str:
+    """The flight lane, by step kind: a compiled step from its launch to
+    its tokens on the host (`pt.step.flight`, docs/observability.md "The
+    step clock"), how many began before the one before had landed, and
+    how many pair with a `pt.step.readback` of the same `step=`.  Empty
+    string when the trace holds no flight."""
+    flights = sorted((s for s in spans if s.get("track") == "flight"),
+                     key=lambda s: s["ts"])
+    if not flights:
+        return ""
+    landed = {(s.get("attrs") or {}).get("step") for s in spans
+              if s["name"] == "pt.step.readback"}
+    kinds: dict[str, list] = {}      # kind -> [n, seconds, max, overlapped]
+    paired, prev_end = 0, None
+    for f in flights:
+        attrs = f.get("attrs") or {}
+        dur = float(f.get("dur", 0.0))
+        a = kinds.setdefault(str(attrs.get("kind", "?")), [0, 0.0, 0.0, 0])
+        a[0] += 1
+        a[1] += dur
+        a[2] = max(a[2], dur)
+        if prev_end is not None and f["ts"] < prev_end:
+            a[3] += 1
+        prev_end = f["ts"] + dur
+        paired += attrs.get("step") in landed
+    lines = [f"flight lane ({len(flights)} steps, {paired} paired with "
+             f"their read-back by step=):",
+             f"  {'kind':<8} {'steps':>7} {'mean_ms':>9} {'max_ms':>9} "
+             f"{'overlapped':>10}"]
+    for kind in sorted(kinds, key=lambda k: -kinds[k][1]):
+        n, tot, mx, over = kinds[kind]
+        lines.append(f"  {kind:<8} {n:>7} {tot / n * 1e3:>9.2f} "
+                     f"{mx * 1e3:>9.2f} {over:>10}")
     return "\n".join(lines)
 
 
