@@ -15,7 +15,15 @@ cost layer, scaled by attrs['aux_weight'].
 
 A caller that hands in a state entry for the layer (the serving engine)
 gets back `pairs` [rows, E_held]: which held experts each row was routed
-to — the load counters' source.
+to — the load counters' source.  The entry may say which rows are `live`
+([rows] bool; a mixed step's padding rows are not): the grouped form
+routes only those — the padding rows of a step are all alike, so they
+would all fill the same experts' slots.
+
+Which of the expert block's two formulations a program runs is
+`expert_form_of`: parallel/moe.py's rule given this layer's shapes, its
+mesh and its mode.  The layer asks it when it is traced; the serving engine
+asks the same function what its step programs were traced to.
 """
 
 from __future__ import annotations
@@ -27,8 +35,22 @@ from paddle_tpu.config.schema import LayerConfig
 from paddle_tpu.graph.common import finish_layer
 from paddle_tpu.graph.context import ForwardContext
 from paddle_tpu.graph.registry import register_layer
-from paddle_tpu.parallel.moe import moe_ffn
+from paddle_tpu.parallel.mesh import MODEL_AXIS, axis_size
+from paddle_tpu.parallel.moe import expert_form, moe_ffn
 from paddle_tpu.parameter.argument import Argument
+
+
+def expert_form_of(cfg: LayerConfig, params: dict, rows: int, mesh=None,
+                   training: bool = False) -> str:
+    """"dense" or "grouped": what this layer's expert block runs at `rows`
+    token rows (`params`: name -> array, or anything with its shape and
+    dtype)."""
+    w_router = params[cfg.inputs[0].input_parameter_name]
+    held = params[cfg.inputs[1].input_parameter_name]
+    return expert_form(rows, int(cfg.attrs.get("top_k", 2)),
+                       w_router.shape[-1], jnp.dtype(held.dtype).itemsize,
+                       partitioned=axis_size(mesh, MODEL_AXIS) > 1,
+                       training=training)
 
 
 @register_layer("moe")
@@ -53,9 +75,15 @@ def moe_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
         mask = x.mask()                  # padding never routed (cf. attention)
         if mask is not None:
             valid = mask.reshape(-1)
+    form = expert_form_of(cfg, ctx.params, v.shape[0], ctx.mesh,
+                          ctx.is_training)
+    live = (ctx.state_in.get(cfg.name) or {}).get("live")
+    if form == "grouped" and live is not None:
+        live = live.reshape(-1)
+        valid = live if valid is None else jnp.logical_and(valid, live)
     y, aux, pairs = moe_ffn(
         v, w_router, experts, top_k=int(a.get("top_k", 2)),
-        first_expert=int(a.get("first_expert", 0)), valid=valid,
+        first_expert=int(a.get("first_expert", 0)), valid=valid, form=form,
         scoring=str(a.get("scoring", "softmax")),
         n_group=int(a.get("n_group", 1)),
         topk_group=int(a.get("topk_group", 1)), select_bias=select_bias,

@@ -78,6 +78,11 @@ CATALOG: dict[str, str] = {
         "MoE layers), summed over steps",
     "serving_moe_steps_total":
         "compiled steps whose routed pairs were counted",
+    "serving_moe_grouped_steps_total":
+        "of those, the steps whose program ran the expert block's grouped "
+        "form: the routed pairs in their experts' slots, not every row "
+        "times every held expert (label kind; over "
+        "serving_moe_steps_total: the engaged share)",
     # -- recurrent layers: slot states in the cache manager ---------------
     "serving_recurrent_rows_total":
         "token rows that advanced a recurrent slot state (one layer's worth)",

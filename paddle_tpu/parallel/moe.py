@@ -26,22 +26,40 @@ held elsewhere contribute nothing here (their chips add them: expert
 parallelism without the exchange, which a one-chip share never runs).  With
 every expert held it is the whole layer.
 
-Formulation: each held expert multiplies every row and a dense combine
-matrix `[B, E_held]` (zero off the routed pairs) weighs the results.  Work
-is B x E_held expert products, not the routed pairs: right where rows x
-held experts is small — a step reads each expert's weights once whatever
-the rows, and while the products take less time than that read the MXU is
-idle beside the HBM (8 held x 64 rows and 16 held x 128-320 rows, the
-GigaChat and Kimi-Linear cells: 2 and 4 routed pairs an expert).  THE
-BOUND: with 64 held experts of 3 x 2048 x 1536 and 256-512 rows
-(`lfm2-24b-serve.long-output-256`, 16 pairs an expert) the products are
-1.24-2.47 TFLOP a step in four layers, 6.3-12.6 ms at the v5e's peak,
-against 5.9 ms to read the experts' 4.83 GB: the MXU sets the pace, at 16
-times the routed work (PERF.md section 5 has the measured cost).  Past
-that, and for long whole-sequence calls, a sort by expert and a ragged
-product belongs — to be claimed in that cell (ROADMAP D12, S14).  Stacked
-expert weights shard over the `model` mesh axis as before; XLA partitions
-the einsums.
+TWO FORMULATIONS of the same block, chosen from the operands' shapes when
+a program is traced (`expert_form`; nothing selects one from outside):
+
+  * DENSE: each held expert multiplies every row and a dense combine matrix
+    `[B, E_held]` (zero off the routed pairs) weighs the results.  Work is
+    B x E_held expert products, not the routed pairs.  A step reads each
+    expert's weights once whatever the rows and does 2 x B flops a weight,
+    so under the chip's ridge — `ridge_rows`: about 240 rows of 2-byte
+    weights on the v5e, 197 TFLOP/s over 819 GB/s — the MXU is idle beside
+    the HBM and the extra products cost nothing (8 held x 64-128 rows and
+    16 held x 128 rows: the GigaChat cell and Kimi-Linear's decode steps,
+    2 and 4 routed pairs an expert).  XLA partitions its einsums where the
+    stacked weights shard over the `model` mesh axis.
+  * GROUPED: each routed pair of a held expert takes a slot of that
+    expert (its rank among the expert's pairs, by a stable sort); the
+    slots' rows of `x` are gathered `[E_held, slots, D]`; each expert
+    multiplies its own slots — batched einsums against the stacked weights
+    exactly as they are stored —; each pair's output is weighed by its
+    routing weight and the top_k results of a row are added in float32.
+    `_GROUP_SLOTS` slots an expert a round and as many rounds as the
+    busiest expert needs, so nothing is dropped.  Work is slots x E_held a
+    round, under the ridge: a round takes the weights' read, whatever the
+    rows.
+
+THE RULE (`expert_form`): past the ridge the dense form is compute-bound
+on products of which E/top_k - 1 parts in E/top_k are multiplied by zero
+— with 64 held experts of 3 x 2048 x 1536 and 512 rows (`lfm2-24b-serve.
+long-output-256`'s mixed step, 32 pairs an expert) 3.7 ms a layer, 14.8
+of a step's 21.2, against 5.9 ms to read the experts' 4.83 GB — so the grouped form
+runs from `_GROUPED_OVER_RIDGE` times the ridge on for each round it
+expects to need (the measurement beside the constant), the dense form
+below it, under a `model`-axis mesh (XLA partitions the dense einsums; the
+grouped form's gathers have not been tried there) and in training (its
+loop over rounds has no reverse mode).
 """
 
 from __future__ import annotations
@@ -126,6 +144,128 @@ def combine_weights(idx: Array, weight: Array, first_expert: int,
     return jnp.sum(jnp.where(hit, weight[:, :, None], 0.0), axis=1)
 
 
+#: rows at which the dense form's 2 x rows flops a weight take as long as the
+#: weight's read, for each byte of a weight: 197 TFLOP/s over 2 x 819 GB/s,
+#: the v5e (the chip this repo is measured on; benchmark/peaks.json)
+_RIDGE_ROWS_PER_BYTE = 120
+
+# The grouped form runs where the rows are at least `_GROUPED_OVER_RIDGE`
+# times the ridge for each round it expects to need.  One layer's call on
+# the v5e, bf16 gated experts, ms dense -> grouped, beside the weights' read
+# at 819 GB/s (my chip runs, PR 38: tools/moe_forms.py, medians of 5 x 10
+# calls, the same to 0.01 ms in four calls of the tool):
+#   256 rows x 64 held of 3 x 2048 x 1536 (LFM2 decode, 1.07 x the ridge)
+#       1.926 -> 1.877   read 1.475   a tie: stays dense
+#   320 rows x 16 held of 3 x 2304 x 1024 (Kimi mixed, 1.33 x)
+#       0.589 -> 0.559   read 0.277   grouped, 5%
+#   512 rows x 64 held of 3 x 2048 x 1536 (LFM2 mixed, 2.13 x)
+#       3.707 -> 1.961   read 1.475   grouped, 47%: the two product fusions
+#       1.60 ms (755 GB/s of weights), the slots' gather 0.11, the weighted
+#       add 0.05, scatter, sorts and the rest 0.2
+#   768 rows x 64 held 5.880 -> 2.17;  128 rows x 64 held 1.677 -> 1.99
+# What else was tried in the products' place, same three shapes: the Pallas
+# grouped matmul (jax.experimental.pallas.ops.tpu.megablox.gmm, the whole
+# contraction a block, 64-row tile) 1.892 / 0.508 / 2.083 — no faster, and
+# the benchmark's paged-kernel reader counts every Pallas call of a serve
+# step as the paged kernel; `jax.lax.ragged_dot` 4.00 / 1.08 / 4.20 —
+# slower than the dense form.
+_GROUPED_OVER_RIDGE = 1.25
+
+#: slots an expert has in one round of the grouped form: one MXU tile's
+#: height.  Under the ridge a round is bound by the weights' read whatever
+#: its slots hold (ms a layer at 512 x 64 held: 64 slots 1.91, 96 1.95, 128
+#: 1.96, 192 2.14; my chip runs, PR 38) and a round more costs the whole
+#: read again, so the slots are sized for the busiest expert, not the mean:
+#: 128 is four times the mean load of LFM2's mixed step (32 pairs an expert)
+_GROUP_SLOTS = 128
+
+
+def ridge_rows(itemsize: int) -> int:
+    """Rows from which the dense form is bound by the MXU and no longer by
+    the weights' read (weights of `itemsize` bytes)."""
+    return _RIDGE_ROWS_PER_BYTE * itemsize
+
+
+def expert_form(rows: int, top_k: int, n_experts: int, itemsize: int, *,
+                partitioned: bool = False, training: bool = False) -> str:
+    """"dense" or "grouped": the formulation `moe_ffn` runs for `rows`
+    token rows routed top_k of `n_experts`, weights of `itemsize` bytes — a
+    pure function of what a trace sees (the experts held do not enter: both
+    forms' work is the same multiple of them).  `partitioned`: the stacked
+    weights shard over a `model` mesh axis; `training`: the program is
+    differentiated (the grouped form's loop has no reverse mode)."""
+    ridge = ridge_rows(itemsize)
+    if partitioned or training or _GROUP_SLOTS > ridge:
+        return "dense"
+    # each round reads the weights once, as `ridge` rows of the dense form
+    # do; the busiest expert is reckoned at twice the mean load
+    rounds = -(-2 * rows * top_k // (n_experts * _GROUP_SLOTS))
+    return "grouped" if rows >= _GROUPED_OVER_RIDGE * ridge * rounds \
+        else "dense"
+
+
+def _expert_products(xs, experts, activation):
+    """out[e] = expert e applied to xs[e] (`[h, rows, D]`; `[rows, D]`: the
+    same rows for every expert), `[h, rows, D_out]`: operands as stored,
+    float32 accumulation, `h` and `out` rounded to the einsums' result
+    type."""
+    lhs = "bd" if xs.ndim == 2 else "ebd"
+    if len(experts) == 3:
+        w_gate, w_up, w_down = experts
+        h = jax.nn.silu(jnp.einsum(f"{lhs},edh->ebh", xs, w_gate)) * \
+            jnp.einsum(f"{lhs},edh->ebh", xs, w_up)
+        return jnp.einsum("ebh,ehd->ebd", h, w_down)
+    w1, b1, w2, b2 = experts
+    h = activation(jnp.einsum(f"{lhs},edh->ebh", xs, w1) + b1[:, None, :])
+    return jnp.einsum("ebh,ehd->ebd", h, w2) + b2[:, None, :]
+
+
+def _experts_grouped(x, experts, idx, weight, first_expert, activation,
+                     valid):
+    """The held experts' part over the routed pairs alone: each held pair
+    takes a slot of its expert — its rank among that expert's pairs, by a
+    stable sort —, the slots' rows of `x` are gathered `[h, slots, D]`, the
+    experts multiply their own slots, and each pair's result is weighed and
+    added to its row in float32.  `_GROUP_SLOTS` slots an expert a round, as
+    many rounds as the busiest expert needs: nothing is dropped.  An empty
+    slot computes row 0 and is read by no pair."""
+    B, k = idx.shape
+    C = _GROUP_SLOTS
+    n_held = experts[0].shape[0]
+    e = idx - first_expert
+    held = jnp.logical_and(e >= 0, e < n_held)             # [B, k]
+    if valid is not None:
+        held = jnp.logical_and(held, valid[:, None])
+    # pairs of experts held elsewhere and padding rows sort last
+    key = jnp.where(held, e, n_held).reshape(-1)
+    sizes = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :], axis=0,
+                    dtype=jnp.int32)
+    e = jnp.clip(e, 0, n_held - 1)
+    place = jnp.argsort(jnp.argsort(key, stable=True))     # sorted position
+    rank = place.reshape(B, k) - jnp.take(jnp.cumsum(sizes) - sizes, e)
+    row_of_pair = jnp.arange(B * k, dtype=jnp.int32) // k
+
+    def one_round(carry):
+        r, y = carry
+        slot = rank - r * C
+        mine = jnp.logical_and(held, jnp.logical_and(slot >= 0, slot < C))
+        at = jnp.where(mine, e * C + slot, n_held * C).reshape(-1)
+        rows = jnp.zeros((n_held * C,), jnp.int32).at[at].set(
+            row_of_pair, mode="drop")
+        xs = jnp.take(x, rows, axis=0).reshape(n_held, C, -1)
+        out = _expert_products(xs, experts, activation)
+        out = jnp.take(out.reshape(n_held * C, -1), at, axis=0, mode="clip")
+        out = jnp.where(mine[:, :, None], out.reshape(B, k, -1).astype(
+            jnp.float32) * weight[:, :, None], 0.0)
+        return r + 1, y + jnp.sum(out, axis=1)
+
+    d_out = experts[-1].shape[-1]
+    _, y = jax.lax.while_loop(
+        lambda carry: carry[0] * C < jnp.max(sizes), one_round,
+        (jnp.int32(0), jnp.zeros((B, d_out), jnp.float32)))
+    return y.astype(jnp.result_type(x.dtype, experts[0].dtype))
+
+
 def moe_ffn(
     x: Array,                  # [B, D] tokens
     w_router: Array,           # [D, E]  E = ALL experts the router scores
@@ -137,31 +277,33 @@ def moe_ffn(
     first_expert: int = 0,     # the held experts are [first, first + h)
     activation=jax.nn.relu,    # plain experts' nonlinearity
     valid: Optional[Array] = None,
+    form: Optional[str] = None,   # None: `expert_form` of the shapes
     **routing,                 # moe_route's keywords
 ) -> tuple[Array, Array, Array]:
     """The routed experts' part of the layer over the held experts; returns
     (y [B, D_out], aux loss, pairs [B, h] bool — which held experts each
     token was routed to).  Stacked expert weights shard on the model axis
-    (['model', None, ...])."""
+    (['model', None, ...]).  `form` is the rule's answer where the caller
+    knows more than the shapes (the layer: its mesh, its mode)."""
+    n_held = experts[0].shape[0]
+    if form is None:
+        form = expert_form(x.shape[0], top_k, w_router.shape[-1],
+                           experts[0].dtype.itemsize)
     with jax.named_scope("moe.route"):
         logits = x.astype(jnp.float32) @ w_router.astype(jnp.float32)
         idx, weight, aux = moe_route(logits, top_k, valid=valid, **routing)
-        n_held = experts[0].shape[0]
-        comb = combine_weights(idx, weight, first_expert, n_held)
+        if form == "dense":
+            comb = combine_weights(idx, weight, first_expert, n_held)
         pairs = jnp.any(held_hits(idx, first_expert, n_held), axis=1)
         if valid is not None:
             pairs = jnp.logical_and(pairs, valid[:, None])
     with jax.named_scope("moe.experts"):
-        if len(experts) == 3:
-            w_gate, w_up, w_down = experts
-            h = jax.nn.silu(jnp.einsum("bd,edh->ebh", x, w_gate)) * \
-                jnp.einsum("bd,edh->ebh", x, w_up)
-            out = jnp.einsum("ebh,ehd->ebd", h, w_down)
+        if form == "dense":
+            out = _expert_products(x, experts, activation)
+            y = jnp.einsum("ebd,be->bd", out, comb.astype(out.dtype))
         else:
-            w1, b1, w2, b2 = experts
-            h = activation(jnp.einsum("bd,edh->ebh", x, w1) + b1[:, None, :])
-            out = jnp.einsum("ebh,ehd->ebd", h, w2) + b2[:, None, :]
-        y = jnp.einsum("ebd,be->bd", out, comb.astype(out.dtype))
+            y = _experts_grouped(x, experts, idx, weight, first_expert,
+                                 activation, valid)
     return y, aux, pairs
 
 

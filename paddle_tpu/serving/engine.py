@@ -105,6 +105,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.graph.context import TEST
+from paddle_tpu.graph.layers_moe import expert_form_of
 from paddle_tpu.graph.lm_decode import _is_probs, _resolve_io_names
 from paddle_tpu.obs.compile_watch import get_compile_watch
 from paddle_tpu.obs.flight import get_flight_recorder
@@ -481,6 +482,11 @@ class ServingEngine:
         self.moe_pairs_total = 0       # routed pairs the held experts drew
         self.moe_pairs_max_sum = 0     # sum over steps of the busiest's
         self.moe_steps = 0             # steps counted
+        # of those, the steps whose program runs the expert block's grouped
+        # form (parallel/moe.py: the rule answers from a step's rows when
+        # its program is traced), by step kind
+        self.moe_grouped_steps: dict[str, int] = {}
+        self._moe_grouped_at: dict[int, bool] = {}     # rows -> grouped
         # recurrent-state counters, returned behind the tokens (and the
         # MoE pairs) the same way: rows that advanced a state, slot states
         # read and written summed over the recurrent layers, steps counted
@@ -735,6 +741,7 @@ class ServingEngine:
         self._step_params = None
         self._params = params
         self._step_params = self.executor.cast_params(params)
+        self._moe_grouped_at = {}
         self.step_weight_bytes = sum(
             int(v.nbytes) for k, v in self._step_params.items()
             if v is not params[k])
@@ -1356,7 +1363,8 @@ class ServingEngine:
         self._landing = True
         try:
             with self._phase("readback", step=pend.step, kind=pend.kind):
-                nxt = self._count_moe(np.asarray(pend.nxt), S)  # host sync
+                nxt = self._count_moe(np.asarray(pend.nxt), S,
+                                      pend.kind)            # host sync
             self._landed(pend.kind, pend.step, pend.t_launch,
                          len(pend.rows))
             with self._phase("emit", n=len(pend.rows), step=pend.step,
@@ -1494,7 +1502,8 @@ class ServingEngine:
             ran = np.zeros(S, np.int64)     # bodies each slot advanced in
             step = self.n_decode_steps
             with self._phase("readback", step=step, kind="scan"):
-                blk = self._count_moe(np.asarray(blk), S)  # [k, S] sync
+                blk = self._count_moe(np.asarray(blk), S,
+                                      "scan")              # [k, S] sync
             self._landed("scan", step, launch.t0, len(runnable))
             self._note_step_metrics(len(runnable), decoded=True)
         # per-flush, never per-token: one boundary event each k tokens
@@ -2902,8 +2911,11 @@ class ServingEngine:
         layer's kv_pages) beside the step's shared operands; a recurrent
         layer's slot-indexed parts under their own names, with the step's
         `run` mask where the step has one (a paused slot's state must not
-        advance; the K/V layers never see it); and an empty entry for each
-        MoE layer — the request for its routed pairs."""
+        advance; the K/V layers never see it); and an entry for each MoE
+        layer — the request for its routed pairs — that holds, where the
+        step packs rows (`row_slot`), which of them are `live`: padding
+        rows all route alike, and the expert block's grouped form gives
+        them no slots."""
         rec = set(self._recurrent)
         state = {name: dict({part + "_pages": a for part, a in pool.items()},
                             **shared)
@@ -2911,7 +2923,9 @@ class ServingEngine:
         if run is not None:
             shared = dict(shared, run=run)
         state.update({name: dict(st.pools[name], **shared) for name in rec})
-        state.update({name: {} for name in self._moe_layers})
+        live = {"live": shared["row_slot"] < len(self.slots)} \
+            if "row_slot" in shared else {}
+        state.update({name: dict(live) for name in self._moe_layers})
         return state
 
     def _pools_out(self, st: EngineState, state_out: dict) -> dict:
@@ -2944,7 +2958,22 @@ class ServingEngine:
             return nxt
         return jnp.concatenate([nxt.astype(jnp.int32)] + tail)
 
-    def _count_moe(self, nxt: np.ndarray, n_rows: int) -> np.ndarray:
+    def _moe_grouped(self, kind: str) -> bool:
+        """Whether the step program of `kind` runs the expert block's
+        grouped form: the layers' own rule (graph/layers_moe.py:
+        expert_form_of) at the rows that program is traced with — the
+        slots for a decode step or a scan body, the token budget for a
+        mixed step."""
+        rows = self.max_step_tokens if kind == "mixed" else len(self.slots)
+        if rows not in self._moe_grouped_at:
+            self._moe_grouped_at[rows] = any(
+                expert_form_of(l, self._step_params, rows,
+                               self.mesh) == "grouped"
+                for l in self.executor.model.layers if l.type == "moe")
+        return self._moe_grouped_at[rows]
+
+    def _count_moe(self, nxt: np.ndarray, n_rows: int,
+                   kind: str) -> np.ndarray:
         """Split a step's read-back into its tokens and the counts behind
         them (the MoE pairs, then the two recurrent counts); bank the
         counts.  Returns the tokens."""
@@ -2969,6 +2998,11 @@ class ServingEngine:
             pc.add("serving_moe_pairs_total", total)
             pc.add("serving_moe_pairs_max_total", busiest)
             pc.add("serving_moe_steps_total", pairs.shape[0])
+            if self._moe_grouped(kind):
+                self.moe_grouped_steps[kind] = pairs.shape[0] + \
+                    self.moe_grouped_steps.get(kind, 0)
+                pc.add(counter_key("serving_moe_grouped_steps_total",
+                                   kind=kind), pairs.shape[0])
         return nxt[..., :n_rows]
 
     def _spec_impl(self, params, st: EngineState, row_ids, row_slot,

@@ -425,6 +425,9 @@ class ServingServer:
                  float(eng.moe_pairs_max_sum)),
                 ("serving_moe_steps_total", "counter", None,
                  float(eng.moe_steps)),
+                *(("serving_moe_grouped_steps_total", "counter",
+                   {"kind": kind}, float(n))
+                  for kind, n in sorted(eng.moe_grouped_steps.items())),
                 # recurrent layers: rows that advanced a slot state, slot
                 # states read and written, steps counted; the state's bytes
                 ("serving_recurrent_rows_total", "counter", None,
@@ -1677,6 +1680,9 @@ class ServingServer:
             "moe_pairs_total": eng.moe_pairs_total,
             "moe_pairs_max_sum": eng.moe_pairs_max_sum,
             "moe_steps": eng.moe_steps,
+            # of those, the steps whose program ran the grouped expert
+            # product, by step kind (the rule: parallel/moe.py)
+            "moe_grouped_steps": dict(eng.moe_grouped_steps),
             # recurrent layers (0 without them): rows that advanced a slot
             # state, slot states read and written, steps counted
             "recurrent_rows": eng.recurrent_rows,

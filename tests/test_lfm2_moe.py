@@ -481,6 +481,54 @@ def test_engine_greedy_tokens_match_lm_generate(model, chunk, kernel, k,
     assert eng.kv.slot_state_bytes == 4 * 3 * 2 * 256 * 4
 
 
+def test_engine_serves_the_same_tokens_on_the_grouped_form(model,
+                                                            monkeypatch):
+    """The expert block forced onto its grouped form (the rule's constant
+    lowered: every row count passes the ridge; 4 slots an expert, so the
+    steps run one round to several; all 16 experts held) serves
+    the greedy tokens the dense form serves, and
+    `serving_moe_grouped_steps_total{kind}` counts every step landed — none
+    where the rule keeps the dense form."""
+    import jax
+    from paddle_tpu.obs.metrics import counter_key, process_counters
+    from paddle_tpu.parallel import moe
+    from paddle_tpu.serving import ServingEngine
+    cfg, ex, w = model
+    keys = {k: counter_key("serving_moe_grouped_steps_total", kind=k)
+            for k in ("decode", "mixed")}
+    traced, grouped_form = [], moe._experts_grouped
+    monkeypatch.setattr(moe, "_experts_grouped", lambda x, *a, **kw: (
+        traced.append(x.shape[0]), grouped_form(x, *a, **kw))[1])
+
+    def serve():
+        before = process_counters().snapshot()
+        reqs = _requests((3, 19, 9, 17, 26))
+        with jax.default_matmul_precision("highest"):
+            eng = ServingEngine(ex, w, num_slots=2, page_size=4,
+                                max_context=48, prefill_chunk=5)
+            results = eng.run(reqs)
+        after = process_counters().snapshot()
+        return eng, results, {k: after.get(key, 0) - before.get(key, 0)
+                              for k, key in keys.items()}
+
+    dense, want, counted = serve()
+    assert dense.moe_grouped_steps == {} and not any(counted.values())
+    assert not traced
+    monkeypatch.setattr(moe, "_GROUPED_OVER_RIDGE", 0.0)
+    monkeypatch.setattr(moe, "_GROUP_SLOTS", 4)
+    grouped, got, counted = serve()
+    # the step programs themselves were traced to it: the decode step at
+    # the slots' rows, the mixed step at its token budget
+    assert {len(grouped.slots), grouped.max_step_tokens} <= set(traced)
+    assert set(got) == set(want)
+    for rid in want:
+        np.testing.assert_array_equal(got[rid], want[rid])
+    assert grouped.moe_steps == dense.moe_steps > 0
+    assert counted == grouped.moe_grouped_steps
+    assert counted["mixed"] == grouped.n_mixed_steps > 0
+    assert counted["decode"] == grouped.moe_steps - counted["mixed"] > 0
+
+
 def test_a_paused_slot_does_not_advance_in_the_scanned_step(model):
     """--decode-steps 4 with a request that ends inside a dispatch: the
     slot's remaining bodies run masked, and the request that takes the slot

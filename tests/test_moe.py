@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paddle_tpu.parallel import moe
 from paddle_tpu.parallel.moe import combine_weights, moe_ffn, moe_route
 
 
@@ -191,6 +192,141 @@ class TestMoeFfn:
         g = jax.grad(loss)(p)
         for v in [g["w_router"], *g["experts"]]:
             assert float(jnp.abs(v).max()) > 0.0
+
+
+#: the grouped form against the dense form: what each case varies
+GROUPED_CASES = {
+    "gated-all-held": dict(gated=True),
+    "plain-all-held": dict(gated=False),
+    # experts [2, 6) of 8 held: pairs of the others belong elsewhere
+    "gated-held-block": dict(gated=True, first=2, held=4),
+    "plain-held-block": dict(gated=False, first=2, held=4),
+    # expert 0 draws every row (24 pairs: 3 rounds of 8 slots), expert 1
+    # none
+    "hot-and-idle-expert": dict(gated=True, skew=True),
+    "plain-hot-and-idle-expert": dict(gated=False, skew=True, first=1,
+                                      held=5),
+    "valid-mask": dict(gated=True, masked=True),
+    "plain-valid-mask-held-block": dict(gated=False, masked=True, first=3,
+                                        held=5),
+    # 37 rows x top-3: neither the rows nor the pairs a multiple of 8
+    "rows-off-the-tile": dict(gated=True, B=37, k=3),
+    "one-row": dict(gated=False, B=1, k=2),
+    "no-row-valid": dict(gated=True, masked="all"),
+    "one-round-at-128-slots": dict(gated=True, slots=128, B=48, k=4),
+    "bf16": dict(gated=True, dtype="bfloat16", D=128, H=256, B=48, k=4),
+    "bf16-plain-held-block": dict(gated=False, dtype="bfloat16", D=128,
+                                  H=128, first=2, held=4),
+    "bf16-hot-and-idle": dict(gated=True, dtype="bfloat16", D=128, H=128,
+                              skew=True),
+}
+
+
+@pytest.mark.parametrize("case", list(GROUPED_CASES))
+def test_grouped_form_equals_the_dense_form(case, monkeypatch):
+    """The routed pairs in their experts' slots against every row times
+    every held expert: the same `y` within the operands' rounding, `pairs`
+    and `aux` identical.  8 slots an expert a round, so the cases run one
+    round to three."""
+    c = dict(dict(B=24, k=2, E=8, first=0, held=8, D=8, H=16,
+                  dtype="float32", skew=False, masked=False, slots=8),
+             **GROUPED_CASES[case])
+    monkeypatch.setattr(moe, "_GROUP_SLOTS", c["slots"])
+    rng = np.random.default_rng(sum(map(ord, case)))
+    dtype = jnp.dtype(c["dtype"])
+    B, E, h, D, H = c["B"], c["E"], c["held"], c["D"], c["H"]
+    x = rng.normal(size=(B, D))
+    w_r = rng.normal(size=(D, E))
+    if c["skew"]:
+        x[:, 0] = np.abs(x[:, 0]) + 3.0
+        w_r[0, 0], w_r[0, 1] = 10.0, -10.0
+    x = jnp.asarray(x, dtype)
+    shapes = [(h, D, H), (h, D, H), (h, H, D)] if c["gated"] \
+        else [(h, D, H), (h, H), (h, H, D), (h, D)]
+    experts = tuple(jnp.asarray(rng.normal(size=sh) * 0.3, dtype)
+                    for sh in shapes)
+    valid = None
+    if c["masked"]:
+        valid = jnp.asarray(rng.random(B) > (1.0 if c["masked"] == "all"
+                                             else 0.3))
+    kw = dict(top_k=c["k"], first_expert=c["first"], valid=valid)
+    y_d, aux_d, pairs_d = moe_ffn(x, jnp.asarray(w_r, jnp.float32), experts,
+                                  form="dense", **kw)
+    y_g, aux_g, pairs_g = moe_ffn(x, jnp.asarray(w_r, jnp.float32), experts,
+                                  form="grouped", **kw)
+    assert y_g.dtype == y_d.dtype and y_g.shape == y_d.shape
+    np.testing.assert_array_equal(pairs_g, pairs_d)
+    assert float(aux_g) == float(aux_d)
+    if c["skew"]:
+        lo = c["first"]
+        assert lo > 0 or bool(pairs_d[:, 0].all())      # draws every row
+        assert not bool(pairs_d[:, 1 - lo].any())       # draws none
+    y_d, y_g = (np.asarray(y, np.float32) for y in (y_d, y_g))
+    assert np.isfinite(y_g).all()
+    if c["masked"] == "all":
+        assert not y_g.any()
+    # one rounding of the result apart (the dense form also rounds the
+    # combine weights where the grouped form keeps them float32)
+    eps = float(jnp.finfo(dtype).eps)
+    np.testing.assert_allclose(y_g, y_d, rtol=4 * eps,
+                               atol=4 * eps * float(np.abs(y_d).max()))
+
+
+#: (rows, top_k, scored) x held: the MoE serve cells' step programs, bf16
+RULE_AT_THE_CELLS = {
+    "gigachat-decode-64x8": ((64, 8, 256), "dense"),
+    "gigachat-mixed-128x8": ((128, 8, 256), "dense"),
+    "kimi-decode-128x16": ((128, 8, 256), "dense"),
+    "kimi-mixed-320x16": ((320, 8, 256), None),
+    "lfm2-decode-256x64": ((256, 4, 64), None),
+    "lfm2-mixed-512x64": ((512, 4, 64), "grouped"),
+}
+
+
+@pytest.mark.parametrize("cell", list(RULE_AT_THE_CELLS))
+def test_the_rule_at_the_cells_shapes(cell):
+    """Dense under the ridge (the GigaChat cell's steps and Kimi's decode
+    step, whatever the constant), grouped for LFM2's 512-row mixed step;
+    the shapes in between go where `_GROUPED_OVER_RIDGE` says.  A
+    `model`-axis mesh and training keep the dense form at every shape."""
+    (rows, k, scored), want = RULE_AT_THE_CELLS[cell]
+    if want is None:
+        want = "grouped" if rows >= moe._GROUPED_OVER_RIDGE * \
+            moe.ridge_rows(2) else "dense"
+    assert moe.expert_form(rows, k, scored, 2) == want
+    assert moe.expert_form(rows, k, scored, 2, partitioned=True) == "dense"
+    assert moe.expert_form(rows, k, scored, 2, training=True) == "dense"
+
+
+def test_the_rule_as_the_layer_asks_it():
+    """graph/layers_moe.py:expert_form_of reads the shapes off the layer's
+    parameters; a mesh with a `model` axis keeps the dense form, a data
+    mesh does not; float32 weights double the ridge; where so few experts
+    are scored that the routed pairs would fill round after round, or
+    where a round's slots would themselves pass the ridge (1-byte weights),
+    dense stays."""
+    from types import SimpleNamespace as NS
+    from paddle_tpu.graph.layers_moe import expert_form_of
+    from paddle_tpu.parallel.mesh import make_mesh
+    cfg = NS(inputs=[NS(input_parameter_name="r"),
+                     NS(input_parameter_name="g")], attrs={"top_k": 4})
+
+    def params(dtype, scored=64):
+        return {"r": jax.ShapeDtypeStruct((2048, scored), jnp.float32),
+                "g": jax.ShapeDtypeStruct((scored, 2048, 1536), dtype)}
+
+    assert expert_form_of(cfg, params(jnp.bfloat16), 512) == "grouped"
+    assert expert_form_of(cfg, params(jnp.bfloat16), 512,
+                          training=True) == "dense"
+    assert expert_form_of(cfg, params(jnp.bfloat16), 512,
+                          make_mesh(data=2, model=4)) == "dense"
+    assert expert_form_of(cfg, params(jnp.bfloat16), 512,
+                          make_mesh(data=8, model=1)) == "grouped"
+    assert expert_form_of(cfg, params(jnp.float32), 512) == "dense"
+    assert expert_form_of(cfg, params(jnp.float32), 1024) == "grouped"
+    assert expert_form_of(cfg, params(jnp.bfloat16, scored=8),
+                          512) == "dense"
+    assert expert_form_of(cfg, params(jnp.float8_e4m3fn), 512) == "dense"
 
 
 class TestMoeLayer:

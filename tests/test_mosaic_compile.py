@@ -372,6 +372,45 @@ def test_paged_kernel_at_head_64_in_groups_of_4(mosaic, rows):
         2 * pool_bytes * 1.01
 
 
+@pytest.mark.parametrize("rows,form", [(512, "grouped"), (256, "dense")],
+                         ids=["mixed-512-rows", "decode-256-rows"])
+def test_expert_block_at_the_cells_shapes(one_chip, no_persistent_cache,
+                                          rows, form):
+    """The LFM2 cell's expert block as parallel/moe.py's rule forms it —
+    the 512-row mixed step grouped (2,048 routed pairs, 64 held experts of
+    3 x 2048 x 1536, bf16), the 256-row decode step dense — compiled for
+    the chip: the 1.2 GB weight stack is taken as it is stored (no copy,
+    transpose or pad of it), the grouped form's buffers are tens of MB, and
+    NO Pallas call is added (the benchmark's paged-kernel reader counts
+    every custom call of a serve step as the paged kernel)."""
+    import re
+    from paddle_tpu.parallel import moe
+    h, D, H, k = 64, 2048, 1536, 4
+    assert moe.expert_form(rows, k, h, 2) == form
+
+    def block(x, w_r, experts):
+        return moe.moe_ffn(x, w_r, experts, top_k=k, scoring="sigmoid")[0]
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(block).lower(
+        arg((rows, D), bf16), arg((D, h), f32),
+        (arg((h, D, H), bf16), arg((h, D, H), bf16), arg((h, H, D), bf16))
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert ("while(" in text) == (form == "grouped")
+    stack = [line for line in text.splitlines() if re.search(
+        rf"= bf16\[{h},(?:{D},{H}|{H},{D})\]", line)]
+    made_by = {re.search(r"\} ([\w-]+)\(", line).group(1) for line in stack}
+    assert made_by and not made_by & {"copy", "transpose", "pad"}, made_by
+    assert all("bitcast_fusion" in line for line in stack
+               if " fusion(" in line), stack
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 128 * 2 ** 20, temp
+
+
 # the latent cell's own shapes (benchmark/configs/
 # gigachat3.1-702b-a36b-serve.json: 64 slots, page 16, context 4,096, 64
 # query heads against ONE 576-wide latent row stored 640 wide, its first 512
