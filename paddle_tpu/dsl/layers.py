@@ -35,7 +35,7 @@ from paddle_tpu.dsl.poolings import AvgPooling, BasePoolingType, FirstPooling, L
 
 __all__ = [
     "rms_norm_layer", "gated_ffn_layer", "mla_attention_layer",
-    "kda_attention_layer", "short_conv_layer",
+    "kda_attention_layer", "short_conv_layer", "mamba2_layer",
     "data_layer", "fc_layer", "embedding_layer", "mixed_layer", "addto_layer",
     "concat_layer", "dropout_layer", "full_matrix_projection",
     "trans_full_matrix_projection", "identity_projection", "table_projection",
@@ -980,6 +980,7 @@ def multi_head_attention_layer(
     rope_theta: float = 10000.0,
     qk_norm: bool = False,
     rms_eps: float = 1e-6,
+    out_size: Optional[int] = None,
     name: Optional[str] = None,
     param_attr: Optional[Union[ParameterAttribute, list]] = None,
     bias_attr=False,
@@ -999,6 +1000,11 @@ def multi_head_attention_layer(
     qk_norm: RMS-norm each head of q and of k, with a learned [head_dim]
     scale each (parameters 4 and 5, starting at 1) and `rms_eps`, before the
     rotation — in every path, so the K a cache holds is the normed one.
+
+    size is the attention's own width, num_heads x head_dim (the layer's
+    `size`: the cache manager reads a head's width from it); out_size is
+    what the output projection gives back, `size` unless the heads' width
+    is not the model's (32 heads of 128 beside a hidden size of 2,688).
 
     param_attr: one attribute applied to all four projections (q/k/v/out), or
     a list of four.  A single NAMED attribute would tie all projections to
@@ -1049,7 +1055,8 @@ def multi_head_attention_layer(
         else (size // num_heads) * num_kv_heads
     for i, (inp, dim_in, dim_out) in enumerate(
             [(query, query.size, size), (key, key.size, kv_dim),
-             (value, value.size, kv_dim), (query, size, size)]):
+             (value, value.size, kv_dim),
+             (query, size, out_size if out_size is not None else size)]):
         pname = _make_param(name, i, [dim_in, dim_out], attrs[i])
         cfg.inputs.append(LayerInput(input_layer_name=inp.name,
                                      input_parameter_name=pname))
@@ -1061,10 +1068,13 @@ def multi_head_attention_layer(
                 ParameterAttribute(initial_mean=1.0, initial_std=0.0))
             cfg.inputs.append(LayerInput(input_layer_name=query.name,
                                          input_parameter_name=pname))
+    assert out_size in (None, size) or bias_attr is False, \
+        "the output bias is `size` wide: none with an out_size of its own"
     cfg.bias_parameter_name = _bias_name(name, bias_attr, [1, size])
     _layer_attr_fields(cfg, layer_attr)
     current_context().add_layer(cfg)
-    return LayerOutput(name, "multi_head_attention", size,
+    return LayerOutput(name, "multi_head_attention",
+                       out_size if out_size is not None else size,
                        parents=[query, key, value],
                        seq_level=query.seq_level)
 
@@ -1313,6 +1323,71 @@ def short_conv_layer(
                        seq_level=input.seq_level)
 
 
+def mamba2_layer(
+    input: LayerOutput,
+    *,
+    num_heads: int,
+    head_dim: int,
+    state_size: int,
+    n_groups: int = 1,
+    conv_size: int = 4,
+    chunk_size: int = 128,
+    size: Optional[int] = None,
+    rms_eps: float = 1e-5,
+    attn_impl: Optional[str] = None,
+    name: Optional[str] = None,
+    param_attr: Optional[ParameterAttribute] = None,
+    layer_attr: Optional[ExtraLayerAttribute] = None,
+) -> LayerOutput:
+    """The Mamba-2 token mixer (arXiv:2405.21060; ops/ssd.py,
+    graph/layers_ssm.py): a causal selective state-space layer whose
+    context is one recurrent state [head_dim, state_size] a head, moved by
+    a scalar decay a head — x, B and C (`n_groups` groups of heads share a
+    B and a C) through one depthwise causal convolution of `conv_size` taps
+    with a bias and SiLU, a gated RMSNorm over each group's channels in
+    front of the output projection.  `param_attr` initializes the two
+    matrices; A_log starts uniform in [0, log 16] and dt_bias in
+    softplus^-1 of [1e-3, 1e-1] (the ranges of the published initializers),
+    D and the norm's scale at 1, the taps and their bias uniform in
+    +-conv_size^-1/2 (a depthwise Conv1d's default)."""
+    assert param_attr is None or not param_attr.name, \
+        "a named param_attr would share one matrix across the projections"
+    assert conv_size >= 2, f"conv_size {conv_size}: a tail needs >= 2 taps"
+    assert num_heads % n_groups == 0, \
+        f"{num_heads} heads do not split in {n_groups} groups"
+    size = size if size is not None else input.size
+    name = _name(name, "mamba2")
+    d, H = input.size, num_heads
+    d_in, gn = H * head_dim, n_groups * state_size
+    cfg = LayerConfig(name=name, type="mamba2", size=size, active_type="")
+    cfg.attrs.update(num_heads=H, head_dim=head_dim, state_size=state_size,
+                     n_groups=n_groups, conv_size=conv_size,
+                     chunk_size=chunk_size, rms_eps=rms_eps, causal=True)
+    if attn_impl is not None:
+        cfg.attrs["attn_impl"] = attn_impl
+    bound = conv_size ** -0.5
+    taps = lambda: ParameterAttribute(initial_min=-bound, initial_max=bound)
+    one = lambda: ParameterAttribute(initial_mean=1.0, initial_std=0.0)
+    specs = [
+        ([d, 2 * d_in + 2 * gn + H], param_attr),
+        ([conv_size, d_in + 2 * gn], taps()), ([1, d_in + 2 * gn], taps()),
+        ([1, H], ParameterAttribute(initial_min=0.0, initial_max=2.7726)),
+        ([1, H], one()),
+        ([1, H], ParameterAttribute(initial_min=-6.9073,
+                                    initial_max=-2.2522)),
+        ([1, d_in], one()),
+        ([d_in, size], param_attr),
+    ]
+    for i, (dims, attr) in enumerate(specs):
+        pname = _make_param(name, i, dims, attr)
+        cfg.inputs.append(LayerInput(input_layer_name=input.name,
+                                     input_parameter_name=pname))
+    _layer_attr_fields(cfg, layer_attr)
+    current_context().add_layer(cfg)
+    return LayerOutput(name, "mamba2", size, parents=[input],
+                       seq_level=input.seq_level)
+
+
 def moe_layer(
     input: LayerOutput,
     *,
@@ -1331,6 +1406,8 @@ def moe_layer(
     shared_hidden: int = 0,
     experts_held: Optional[int] = None,
     first_expert: int = 0,
+    expert_act: Optional[str] = None,
+    expert_bias: bool = True,
     name: Optional[str] = None,
     param_attr: Optional[ParameterAttribute] = None,
     layer_attr: Optional[ExtraLayerAttribute] = None,
@@ -1341,11 +1418,18 @@ def moe_layer(
     mesh axis (expert parallelism).  size defaults to the input width
     (residual-friendly).
 
-    Defaults give softmax routing over plain ReLU experts with biases.
-    `gated` makes the experts bias-free SwiGLU; `scoring='sigmoid'`,
-    `n_group`/`topk_group`, `select_bias` (a [1, E] bias used for selection
-    only), `routed_scale` and `shared_hidden` (one always-on gated expert of
-    that width) give the DeepSeek-V3 layer.  `experts_held`/`first_expert`
+    THREE EXPERT FORMS (parallel/moe.py `_expert_products`).  The defaults
+    give softmax routing over plain ReLU experts with two biases, (w1, b1,
+    w2, b2).  `expert_act` names a plain expert's nonlinearity (`relu`,
+    `relu2` = relu(x)^2) and `expert_bias=False` drops the biases: the
+    stacked weights are then (w_up, w_down) alone, nothing zero stored or
+    read (the Nemotron-H experts).  `gated` (or `expert_act='gated'`) makes
+    the experts bias-free SwiGLU, (w_gate, w_up, w_down).
+    `scoring='sigmoid'`, `n_group`/`topk_group`, `select_bias` (a [1, E]
+    bias used for selection only), `routed_scale` and `shared_hidden` (one
+    always-on expert of that width, IN THE EXPERTS' OWN FORM: gated beside
+    gated experts, the plain bias-free product beside plain ones) give the
+    DeepSeek-V3 layer.  `experts_held`/`first_expert`
     cut the layer to one expert-parallel rank's share: the router still
     scores all `num_experts`, the weights hold only the experts
     [first_expert, first_expert + experts_held) and the layer computes
@@ -1361,11 +1445,23 @@ def moe_layer(
         f"experts [{first_expert}, {first_expert + held}) are not among {E}"
     assert E % n_group == 0 and topk_group <= n_group, \
         f"{E} experts in {n_group} groups, {topk_group} kept"
-    assert shared_hidden == 0 or gated, "a shared expert is a gated expert"
+    gated = gated or expert_act == "gated"
+    assert not gated or expert_act in (None, "gated"), \
+        f"gated experts are SwiGLU (expert_act {expert_act!r})"
+    assert expert_act in (None, "gated", "relu", "relu2"), \
+        f"expert_act {expert_act!r} (relu, relu2 or gated)"
+    assert shared_hidden == 0 or gated or not expert_bias, \
+        "a shared expert is bias-free: beside gated experts, or beside " \
+        "plain ones with expert_bias=False"
     cfg = LayerConfig(name=name, type="moe", size=size, active_type="")
     cfg.attrs.update(top_k=top_k, aux_weight=aux_weight, num_experts=E)
     if gated:
         cfg.attrs["gated"] = True
+    else:
+        if expert_act not in (None, "relu"):
+            cfg.attrs["expert_act"] = expert_act
+        if not expert_bias:
+            cfg.attrs["expert_bias"] = False
     if scoring != "softmax":
         cfg.attrs["scoring"] = scoring
     if n_group > 1:
@@ -1397,15 +1493,19 @@ def moe_layer(
     if gated:
         specs += [([held, D, H], w(D, espec)), ([held, D, H], w(D, espec)),
                   ([held, H, size], w(H, espec))]
-        if select_bias:
-            specs.append(([1, E], zero()))
-        if shared_hidden:
-            specs += [([D, shared_hidden], w(D)), ([D, shared_hidden], w(D)),
-                      ([shared_hidden, size], w(shared_hidden))]
-    else:
+    elif expert_bias:
         specs += [([held, D, H], w(D, espec)), ([held, H], zero(espec[:2])),
                   ([held, H, size], w(H, espec)),
                   ([held, size], zero(espec[:2]))]
+    else:
+        specs += [([held, D, H], w(D, espec)), ([held, H, size], w(H, espec))]
+    if gated or not expert_bias:
+        if select_bias:
+            specs.append(([1, E], zero()))
+        if shared_hidden:
+            specs += [([D, shared_hidden], w(D))
+                      for _ in range(2 if gated else 1)]
+            specs.append(([shared_hidden, size], w(shared_hidden)))
     for i, (dims, attr) in enumerate(specs):
         pname = _make_param(name, i, dims, attr)
         cfg.inputs.append(LayerInput(input_layer_name=input.name,

@@ -10,7 +10,9 @@ output gate), o_norm [1, dv], w_o [H*dv, size].
 attrs: num_heads, head_dim, conv_size, rms_eps, attn_impl.
 
 Three paths, picked by the state the executor hands in, as the attention
-layers do:
+layers do (the dispatch, the run mask and the convolution's tail are
+graph/slot_steps.py's, shared with the short-convolution and Mamba-2
+layers):
 
   * none — the whole sequence in the chunkwise form from the zero state;
   * a slot state with `pos` (and `run`) — the decode step: one rank-1
@@ -43,17 +45,9 @@ from paddle_tpu.config.schema import LayerConfig
 from paddle_tpu.graph.common import finish_layer
 from paddle_tpu.graph.context import ForwardContext
 from paddle_tpu.graph.registry import register_layer, register_slot_state
-from paddle_tpu.ops import kda, short_conv
+from paddle_tpu.graph import slot_steps
+from paddle_tpu.ops import kda
 from paddle_tpu.parameter.argument import Argument
-
-
-def _use_kernel(cfg: LayerConfig) -> bool:
-    """The Pallas step kernel unless the config pins the jnp path
-    (attn_impl dense/blockwise, as for the attention layers)."""
-    from paddle_tpu.ops import pallas_kda
-
-    return pallas_kda.supported() and \
-        str(cfg.attrs.get("attn_impl", "auto")) not in ("dense", "blockwise")
 
 
 @register_slot_state("kda_attention")
@@ -78,12 +72,7 @@ def kda_attention_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
     eps = float(a.get("rms_eps", 1e-5))
     x = x_arg.value                                       # [B, T, d]
     B, T, _ = x.shape
-    cache = ctx.state_in.get(cfg.name)
-    slotted = isinstance(cache, dict) and "state" in cache
-    ragged = slotted and "row_slot" in cache
-    assert not slotted or ((B == 1) if ragged else (T == 1)), \
-        f"layer {cfg.name!r}: a slot-state step feeds one token a slot, " \
-        f"or one packed ragged row list (got {x.shape})"
+    step = slot_steps.slot_step(ctx, cfg, x, "state")
 
     with jax.named_scope("kda.project"):
         xin = jnp.concatenate([x @ w_q, x @ w_k, x @ w_v], axis=-1)
@@ -103,27 +92,22 @@ def kda_attention_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
         v = y[..., 2 * H * dk:].reshape(lead + (H, dv)).astype(jnp.float32)
         return q, k, v
 
-    if not slotted:
-        with jax.named_scope("kda.conv"):
-            q, k, v = split(short_conv.short_conv_whole(xin, w_conv))
+    with jax.named_scope("kda.conv"):
+        y, conv = slot_steps.conv(step, xin, w_conv)
+        q, k, v = split(y)
+    if step is None:
         with jax.named_scope("kda.scan"):
             o, _ = kda.chunkwise(q, k, v, g, beta)
     else:
-        state, conv = cache["state"], cache["conv"]
-        S = state.shape[0] - 1
-        R = B * T
-        xin, g, beta = xin.reshape(R, -1), g.reshape(R, H, dk), \
-            beta.reshape(R, H)
-        row_slot, row_pos, _, _, live = runs = short_conv.slot_runs(
-            cache, S, R)
-        with jax.named_scope("kda.conv"):
-            y, conv = short_conv.short_conv_slots(xin, w_conv, conv, runs)
-            q, k, v = split(y)
+        state, S = step.cache["state"], step.slots
+        row_slot, row_pos, _, _, live = step.runs
+        rows = lambda a: a.reshape((B * T,) + a.shape[2:])
+        q, k, v, g, beta = map(rows, (q, k, v, g, beta))
         with jax.named_scope("kda.step"):
-            if ragged:
+            if step.ragged:
                 o_d, state = kda.step_rows(
                     state, row_slot[:S], live[:S], q[:S], k[:S], v[:S],
-                    g[:S], beta[:S], use_kernel=_use_kernel(cfg))
+                    g[:S], beta[:S], use_kernel=slot_steps.use_step_kernel(cfg))
                 o_c, state, n_seg = kda.segment_rows(
                     state, row_slot[S:], row_pos[S:], q[S:], k[S:], v[S:],
                     g[S:], beta[S:])
@@ -131,12 +115,10 @@ def kda_attention_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
                 updates = jnp.sum(live[:S], dtype=jnp.int32) + n_seg
             else:
                 o, state = kda.step_rows(state, None, live, q, k, v, g,
-                                         beta, use_kernel=_use_kernel(cfg))
+                                         beta, use_kernel=slot_steps.use_step_kernel(cfg))
                 updates = jnp.sum(live, dtype=jnp.int32)
         o = o.reshape(B, T, H, dv)
-        ctx.state_out[cfg.name] = dict(
-            cache, state=state, conv=conv,
-            rows=jnp.sum(live, dtype=jnp.int32), updates=updates)
+        slot_steps.finish(ctx, cfg, step, updates, state=state, conv=conv)
     with jax.named_scope("kda.project"):
         o = kda.gated_out_norm(o, gate, o_norm.reshape(dv), eps)
         out = o.reshape(B, T, H * dv).astype(x.dtype) @ w_o

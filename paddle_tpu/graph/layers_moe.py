@@ -2,10 +2,15 @@
 
 NEW capability beyond the reference (see parallel/moe.py).  The layer's
 parameters ride the standard input-parameter mechanism: LayerInputs all
-referencing the single data input.  Plain experts (the default): router,
-w1, b1, w2, b2.  Gated experts (attrs['gated']): router, w_gate, w_up,
-w_down, then the selection bias [1, E] if attrs['select_bias'], then the
-shared expert's gate, up, down if attrs['shared_hidden'] > 0.
+referencing the single data input.  THREE EXPERT FORMS (parallel/moe.py
+`_expert_products`).  Plain experts with biases (the default): router, w1,
+b1, w2, b2, their nonlinearity attrs['expert_act'] (`relu`, `relu2`).
+Bias-free plain experts (attrs['expert_bias'] false): router, w_up, w_down.
+Gated experts (attrs['gated']): router, w_gate, w_up, w_down.  After the
+two bias-free forms come the selection bias [1, E] if attrs['select_bias']
+and, if attrs['shared_hidden'] > 0, the shared expert IN THE EXPERTS' OWN
+FORM: gate, up, down beside gated experts, up, down (the same nonlinearity)
+beside plain ones.
 
 attrs['first_expert'] says which experts the stacked weights are: the layer
 routes over all attrs['num_experts'] and computes the held block's part
@@ -36,7 +41,8 @@ from paddle_tpu.graph.common import finish_layer
 from paddle_tpu.graph.context import ForwardContext
 from paddle_tpu.graph.registry import register_layer
 from paddle_tpu.parallel.mesh import MODEL_AXIS, axis_size
-from paddle_tpu.parallel.moe import expert_form, moe_ffn
+from paddle_tpu.parallel.moe import (expert_activation, expert_form,
+                                     moe_ffn)
 from paddle_tpu.parameter.argument import Argument
 
 
@@ -59,11 +65,14 @@ def moe_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
     a = cfg.attrs
     params = [ctx.param_of(cfg, i) for i in range(len(cfg.inputs))]
     w_router = params[0]
-    gated = bool(a.get("gated", False))
-    experts = tuple(params[1:4] if gated else params[1:5])
-    rest = params[1 + len(experts):]
+    n_mats = 3 if a.get("gated") else 4 if a.get("expert_bias", True) else 2
+    experts = tuple(params[1:1 + n_mats])
+    rest = params[1 + n_mats:]
     select_bias = rest.pop(0).reshape(-1) if a.get("select_bias") else None
-    shared = tuple(rest[:3]) if int(a.get("shared_hidden", 0) or 0) else None
+    # the shared expert's matrices, in the experts' own (bias-free) form
+    shared = tuple(rest[:n_mats]) \
+        if int(a.get("shared_hidden", 0) or 0) else None
+    activation = expert_activation(str(a.get("expert_act", "relu")))
     aux_w = float(a.get("aux_weight", 0.01))
 
     v = x.value
@@ -83,16 +92,20 @@ def moe_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
         valid = live if valid is None else jnp.logical_and(valid, live)
     y, aux, pairs = moe_ffn(
         v, w_router, experts, top_k=int(a.get("top_k", 2)),
-        first_expert=int(a.get("first_expert", 0)), valid=valid, form=form,
+        first_expert=int(a.get("first_expert", 0)), activation=activation,
+        valid=valid, form=form,
         scoring=str(a.get("scoring", "softmax")),
         n_group=int(a.get("n_group", 1)),
         topk_group=int(a.get("topk_group", 1)), select_bias=select_bias,
         norm_topk=bool(a.get("norm_topk", True)),
         scale=float(a.get("routed_scale", 1.0)))
     if shared is not None:
-        from paddle_tpu.graph.layers_misc import gated_ffn
         with jax.named_scope("moe.shared"):
-            y = y + gated_ffn(v, *shared)
+            if len(shared) == 3:
+                from paddle_tpu.graph.layers_misc import gated_ffn
+                y = y + gated_ffn(v, *shared)
+            else:
+                y = y + activation(v @ shared[0]) @ shared[1]
     if ctx.state_in.get(cfg.name) is not None:
         ctx.state_out[cfg.name] = {"pairs": pairs}
     if seq_shape is not None:

@@ -12,8 +12,10 @@ matrix state.
 inputs (all the one data input): w_in [d, 3*d], conv [taps, d], w_out
 [d, size].  attrs: conv_size.
 
-Three paths, picked by the state the executor hands in, as the KDA layer's
-are (graph/layers_kda.py has THE PACKING CONTRACT of the ragged one):
+Three paths, picked by the state the executor hands in — the dispatch, the
+run mask and the tail are graph/slot_steps.py's, shared with the KDA and
+Mamba-2 layers (graph/layers_kda.py has THE PACKING CONTRACT of the ragged
+one):
 
   * none — the whole sequence from an empty history;
   * a slot tail with `pos` and `run` — the decode step; a row whose `run`
@@ -39,7 +41,7 @@ from paddle_tpu.config.schema import LayerConfig
 from paddle_tpu.graph.common import finish_layer
 from paddle_tpu.graph.context import ForwardContext
 from paddle_tpu.graph.registry import register_layer, register_slot_state
-from paddle_tpu.ops import short_conv
+from paddle_tpu.graph import slot_steps
 from paddle_tpu.parameter.argument import Argument
 
 
@@ -56,32 +58,18 @@ def short_conv_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
     w_in, w_conv, w_out = (ctx.param_of(cfg, i) for i in range(3))
     d = w_conv.shape[1]
     x = x_arg.value                                       # [B, T, d_in]
-    B, T, _ = x.shape
-    cache = ctx.state_in.get(cfg.name)
-    slotted = isinstance(cache, dict) and "conv" in cache
-    assert not slotted or ((B == 1) if "row_slot" in cache else (T == 1)), \
-        f"layer {cfg.name!r}: a slot-state step feeds one token a slot, " \
-        f"or one packed ragged row list (got {x.shape})"
+    step = slot_steps.slot_step(ctx, cfg, x)
 
     with jax.named_scope("sconv.project"):
         bcx = x @ w_in
         gate_c = bcx[..., d:2 * d]
         u = bcx[..., :d] * bcx[..., 2 * d:]
-    w = w_conv.astype(u.dtype)
-    if not slotted:
-        with jax.named_scope("sconv.mix"):
-            y = short_conv.short_conv_whole(u, w)
-    else:
-        tails = cache["conv"]
-        runs = short_conv.slot_runs(cache, tails.shape[0] - 1, B * T)
-        with jax.named_scope("sconv.mix"):
-            y, tails = short_conv.short_conv_slots(
-                u.reshape(B * T, d), w, tails, runs)
-        y = y.reshape(B, T, d)
-        *_, last, live = runs
-        ctx.state_out[cfg.name] = dict(
-            cache, conv=tails, rows=jnp.sum(live, dtype=jnp.int32),
-            updates=jnp.sum(last & live, dtype=jnp.int32))
+    with jax.named_scope("sconv.mix"):
+        y, tails = slot_steps.conv(step, u, w_conv.astype(u.dtype))
+    if step is not None:
+        *_, last, live = step.runs
+        slot_steps.finish(ctx, cfg, step,
+                          jnp.sum(last & live, dtype=jnp.int32), conv=tails)
     with jax.named_scope("sconv.project"):
         out = (gate_c * y) @ w_out
     return finish_layer(ctx, cfg, out, like=x_arg)

@@ -32,6 +32,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.ops import slot_rows
+
 _HI = jax.lax.Precision.HIGHEST
 # tokens a chunk of the chunkwise form: part of the arithmetic (the order
 # the float32 recurrence is summed in), so a constant and no layer's knob
@@ -162,20 +164,14 @@ def step_rows(state, slot, live, q, k, v, g, beta, use_kernel: bool = False):
     f32 = lambda a: a.astype(jnp.float32)
     q, k, v, g, beta = map(f32, (q, k, v, g, beta))
     scale = q.shape[-1] ** -0.5
-    R, trash = q.shape[0], state.shape[0] - 1
     if use_kernel:
+        R, trash = q.shape[0], state.shape[0] - 1
         from paddle_tpu.ops import pallas_kda
         rows = jnp.arange(R, dtype=jnp.int32) if slot is None else slot
         return pallas_kda.kda_step(state, jnp.where(live, rows, trash), live,
                                    q, k, v, g, beta, scale)
-    if slot is None:
-        old = state[:R]
-        o, new = step(old, q, k, v, g, beta, scale)
-        return o, state.at[:R].set(
-            jnp.where(live[:, None, None, None], new, old))
-    slot = jnp.where(live, slot, trash)
-    o, new = step(state[slot], q, k, v, g, beta, scale)
-    return o, state.at[slot].set(new)
+    return slot_rows.advance_rows(
+        state, slot, live, lambda S: step(S, q, k, v, g, beta, scale))
 
 
 def segment_rows(state, seg_slot, seg_pos, q, k, v, g, beta):
@@ -184,36 +180,22 @@ def segment_rows(state, seg_slot, seg_pos, q, k, v, g, beta):
     padding; `seg_pos` [P] global positions).  Each run is one segment: it
     starts from its slot's state — from zero where its first row is
     position 0 — goes through `chunkwise` once, and leaves the state it
-    ends in.  One pass a segment present (a loop with a dynamic trip
-    count: a step usually holds one or two) over the chunks that hold its
-    rows, the others skipped.  Returns
+    ends in (ops/slot_rows.py `advance_segments`: one pass a segment
+    present) over the chunks that hold its rows, the others skipped.  Returns
     (o [P, H, dv] float32, state, n_segments)."""
     f32 = lambda a: a.astype(jnp.float32)
     q, k, v, g, beta = map(f32, (q, k, v, g, beta))
-    S = state.shape[0] - 1
-    P = seg_slot.shape[0]
-    live = seg_slot < S
-    first = live & jnp.concatenate(
-        [jnp.ones((1,), bool), seg_slot[1:] != seg_slot[:-1]])
-    seg_id = jnp.cumsum(first.astype(jnp.int32)) - 1
-    n_seg = jnp.sum(first.astype(jnp.int32))
 
-    def body(i, carry):
-        state, o = carry
-        mine = live & (seg_id == i)
-        at = jnp.argmax(mine)                            # its first row
-        slot = seg_slot[at]
-        S0 = jnp.where(seg_pos[at] == 0, 0.0, state[slot])
+    def one_segment(S0, mine):
         m = mine[:, None]
         o_i, S_end = chunkwise(
             q[None], k[None], v[None], jnp.where(m[..., None], g, 0.0)[None],
             jnp.where(m, beta, 0.0)[None], S0[None], live=mine)
-        return (state.at[slot].set(S_end[0]),
-                jnp.where(m[..., None], o_i[0], o))
+        return o_i[0], S_end[0]
 
-    o0 = jnp.zeros((P,) + v.shape[1:], jnp.float32)
-    state, o = jax.lax.fori_loop(0, n_seg, body, (state, o0))
-    return o, state, n_seg
+    return slot_rows.advance_segments(
+        state, seg_slot, seg_pos,
+        jnp.zeros(seg_slot.shape + v.shape[1:], jnp.float32), one_segment)
 
 
 def gated_out_norm(o, gate, scale, eps: float):

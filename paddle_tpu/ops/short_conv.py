@@ -1,10 +1,11 @@
 """The causal depthwise short convolution of the recurrent token mixers:
-the KDA layer's 4 taps in front of q, k and v (graph/layers_kda.py) and the
-gated short-convolution mixer's 3 taps (graph/layers_sconv.py).  Two forms,
-one result: a whole sequence from an empty history, and a packed row list
-against each slot's tail — the inputs of the last `taps - 1` positions,
-which the serving cache manager holds a slot (serving/paged_kv.py,
-slot-indexed parts)."""
+the KDA layer's 4 taps in front of q, k and v (graph/layers_kda.py), the
+gated short-convolution mixer's 3 taps (graph/layers_sconv.py) and the
+Mamba-2 mixer's 4 taps with a bias over x, B and C together
+(graph/layers_ssm.py).  Two forms, one result: a whole sequence from an
+empty history, and a packed row list against each slot's tail — the inputs
+of the last `taps - 1` positions, which the serving cache manager holds a
+slot (serving/paged_kv.py, slot-indexed parts)."""
 
 from __future__ import annotations
 
@@ -12,20 +13,21 @@ import jax
 import jax.numpy as jnp
 
 
-def short_conv_whole(x, w):
+def short_conv_whole(x, w, bias=None):
     """Causal depthwise convolution from an empty history: x [B, T, C],
-    w [taps, C] (w[-1] multiplies the current position) -> [B, T, C]: the
-    sum of `taps` shifted products."""
+    w [taps, C] (w[-1] multiplies the current position), `bias` [C] or
+    None -> [B, T, C]: the sum of `taps` shifted products (plus the
+    bias)."""
     taps = w.shape[0]
     T = x.shape[1]
     y = x * w[taps - 1]
     for j in range(1, taps):
         shifted = jnp.pad(x, ((0, 0), (j, 0), (0, 0)))[:, :T]
         y = y + shifted * w[taps - 1 - j]
-    return y
+    return y if bias is None else y + bias
 
 
-def short_conv_rows(x, w, tail, seg_off, row_pos):
+def short_conv_rows(x, w, tail, seg_off, row_pos, bias=None):
     """The same convolution over a packed row list: x [R, C] in order,
     `tail` [R, taps-1, C] each row's slot history (tail[:, -1] the most
     recent position before the slot's first row of this step), `seg_off`
@@ -47,6 +49,8 @@ def short_conv_rows(x, w, tail, seg_off, row_pos):
     y = x * w[taps - 1]
     for j in range(1, taps):
         y = y + prev[j - 1] * w[taps - 1 - j]
+    if bias is not None:
+        y = y + bias
     hist = jnp.stack(prev[::-1][1:] + [x], axis=1)
     return y, hist
 
@@ -75,14 +79,15 @@ def slot_runs(cache: dict, S: int, R: int):
             jnp.zeros((S,), jnp.int32), jnp.ones((S,), bool), cache["run"])
 
 
-def short_conv_slots(x, w, tails, runs):
+def short_conv_slots(x, w, tails, runs, bias=None):
     """`short_conv_rows` against the slot pool `tails` [S+1, taps-1, C]
     (row S is trash) for the rows `runs` describes (`slot_runs`): each
     live slot's tail is read once and written once, by its last row; a
     paused slot's and a padding row's write lands in the trash row.
     Returns (y [R, C], tails)."""
     row_slot, row_pos, seg_off, last, live = runs
-    y, hist = short_conv_rows(x, w, tails[row_slot], seg_off, row_pos)
+    y, hist = short_conv_rows(x, w, tails[row_slot], seg_off, row_pos,
+                              bias)
     trash = tails.shape[0] - 1
     return y, tails.at[jnp.where(last & live, row_slot, trash)].set(
         hist.astype(tails.dtype))

@@ -18,6 +18,13 @@ Routing (`moe_route`) covers the published families with one function:
     weights come from the UNBIASED scores, renormalized over the picks and
     multiplied by `scale`.
 
+THREE EXPERT FORMS, told apart by the stacked operands (`_expert_products`):
+gated SwiGLU without biases (w_gate, w_up, w_down); plain with two biases
+(w1, b1, w2, b2) and a nonlinearity by name (`expert_activation`: relu,
+relu2 = relu(x)^2); plain WITHOUT biases (w_up, w_down), the Nemotron-H
+experts — no zero biases stored or read.  A layer's shared expert follows
+its experts' form (graph/layers_moe.py).
+
 The expert block (`moe_ffn`) is dropless: every routed (token, expert) pair
 is computed, there is no capacity and nothing is dropped.  It computes the
 experts `[first_expert, first_expert + E_held)` — the stacked weights it is
@@ -204,16 +211,31 @@ def expert_form(rows: int, top_k: int, n_experts: int, itemsize: int, *,
         else "dense"
 
 
+def expert_activation(name: str):
+    """A plain expert's nonlinearity by the name a config gives it."""
+    try:
+        return {"relu": jax.nn.relu,
+                "relu2": lambda x: jnp.square(jax.nn.relu(x))}[name]
+    except KeyError:
+        raise ValueError(f"unknown expert activation {name!r} "
+                         f"(relu or relu2)") from None
+
+
 def _expert_products(xs, experts, activation):
     """out[e] = expert e applied to xs[e] (`[h, rows, D]`; `[rows, D]`: the
     same rows for every expert), `[h, rows, D_out]`: operands as stored,
     float32 accumulation, `h` and `out` rounded to the einsums' result
-    type."""
+    type.  The experts' form is the number of stacked operands: 3 gated
+    (SwiGLU), 2 plain without biases, 4 plain with two biases."""
     lhs = "bd" if xs.ndim == 2 else "ebd"
     if len(experts) == 3:
         w_gate, w_up, w_down = experts
         h = jax.nn.silu(jnp.einsum(f"{lhs},edh->ebh", xs, w_gate)) * \
             jnp.einsum(f"{lhs},edh->ebh", xs, w_up)
+        return jnp.einsum("ebh,ehd->ebd", h, w_down)
+    if len(experts) == 2:
+        w_up, w_down = experts
+        h = activation(jnp.einsum(f"{lhs},edh->ebh", xs, w_up))
         return jnp.einsum("ebh,ehd->ebd", h, w_down)
     w1, b1, w2, b2 = experts
     h = activation(jnp.einsum(f"{lhs},edh->ebh", xs, w1) + b1[:, None, :])
@@ -270,8 +292,9 @@ def moe_ffn(
     x: Array,                  # [B, D] tokens
     w_router: Array,           # [D, E]  E = ALL experts the router scores
     experts: tuple,            # (w1 [h,D,H], b1 [h,H], w2 [h,H,Do], b2 [h,Do])
-                               # plain, or (w_gate [h,D,H], w_up [h,D,H],
-                               # w_down [h,H,Do]) gated; h = experts held
+                               # plain, (w_up [h,D,H], w_down [h,H,Do])
+                               # plain without biases, or (w_gate [h,D,H],
+                               # w_up, w_down [h,H,Do]) gated; h = held
     top_k: int = 2,
     *,
     first_expert: int = 0,     # the held experts are [first, first + h)

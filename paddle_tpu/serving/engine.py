@@ -467,13 +467,17 @@ class ServingEngine:
         S = num_slots
         self._kk = self.kv.capacity_tokens     # keys per slot (> max_new)
         from paddle_tpu.ops.pallas_paged import block_tokens
-        pool = next(iter(next(iter(
-            self.kv.paged_pools().values())).values()))
-        # a latent pool's row is one [W] vector: one KV "head" of width W
-        h_kv = pool.shape[2] if pool.ndim == 4 else 1
-        self._kv_block = block_tokens(
-            self.kv.page_size, h_kv // self.kv.tp_shards,
-            pool.shape[-1], pool.dtype.itemsize, self.kv.pages_per_slot)
+        paged = self.kv.paged_pools()
+        if paged:
+            pool = next(iter(next(iter(paged.values())).values()))
+            # a latent pool's row is one [W] vector: one KV "head" of
+            # width W
+            h_kv = pool.shape[2] if pool.ndim == 4 else 1
+            self._kv_block = block_tokens(
+                self.kv.page_size, h_kv // self.kv.tp_shards,
+                pool.shape[-1], pool.dtype.itemsize, self.kv.pages_per_slot)
+        else:       # no page-indexed part: no kernel fetches any block
+            self._kv_block = self.kv.page_size
         # routed-pair counters of the held experts (docs/observability.md):
         # the steps return, behind the tokens they already read back, the
         # pairs each held expert drew, summed over the MoE layers

@@ -12,13 +12,19 @@ below walk each layer's own parts, so they are the same code for both.
 TWO FAMILIES OF PARTS.  The above are PAGE-indexed: a token's row lives in
 the page its slot's table maps.  A recurrent layer holds SLOT-indexed
 parts instead, one row a slot (row S is the trash row padding and paused
-rows aim at), whatever the context's length.  Two kinds today, each
+rows aim at), whatever the context's length.  Three kinds today, each
 declaring its parts, their row shapes and dtypes beside its registration
 (graph/registry.py:register_slot_state — nothing here names a layer type
 or a part to decide a shape or a dtype): kda_attention
 (graph/layers_kda.py) `state` [S+1, H, dk, dv] float32 and `conv` [S+1,
 taps-1, C] in the compute dtype; short_conv (graph/layers_sconv.py) `conv`
-[S+1, taps-1, d] alone, its whole context.  Both families sit in
+[S+1, taps-1, d] alone, its whole context; mamba2 (graph/layers_ssm.py)
+`state` [S+1, H, P, N] float32 (2 MiB a slot a layer at 64 x 64 x 128) and
+`conv` [S+1, taps-1, H P + 2 G N] in the compute dtype.  A stack may hold
+NO page-indexed part at all (every token mixer recurrent: one pipeline
+stage of a hybrid model): then no pool is built, the table and the
+allocator keep their logical pages, and admission is bound by slots and
+`max_context` as ever.  Both families sit in
 `self.pools` under the
 layer's name and thread through the engine's steps donated alike;
 `layer_specs` names the page-indexed layers, `slot_specs` the slot-indexed
@@ -243,10 +249,13 @@ class PagedKVCache:
                     if self.pool_sharding is not None else z
 
             self.pools[l.name] = {part: _pool() for part in parts}
-        assert self.layer_specs, \
-            "model has no attention layers to page (a model whose every " \
-            "layer is recurrent — kda_attention, short_conv — holds no " \
-            "page-indexed part: not supported)"
+        # A STACK WITH NO PAGE-INDEXED PART (every token mixer recurrent:
+        # a pipeline stage of a hybrid model can be exactly that) builds no
+        # pool; the page table and the allocator keep their logical pages,
+        # so admission is bound by slots and `max_context` as ever
+        assert self.layer_specs or self.slot_specs, \
+            "model has no attention layers to page and no recurrent " \
+            "layer's slot state to hold: nothing to serve from a cache"
 
         # host allocator state: table[s, j] = physical page backing logical
         # page j of slot s (0 = unmapped -> trash)

@@ -590,3 +590,78 @@ def test_additive_step(mosaic, dtype):
 
     mosaic(step, ((B, D), dtype), ((D, D), dtype), ((D,), dtype),
            ((B, T, D), dtype), ((B, T, D), dtype), ((B,), i32))
+
+
+# nemotron3-nano-30b-serve.long-output-256's own shapes (benchmark/configs/
+# nemotron3-nano-30b-a3b-serve.json: 256 slots; 64 Mamba-2 heads in 8 groups
+# whose state is 64 x 128 float32; 32 query heads in 2 groups of 16 over 2
+# KV heads of 128, page 16, context 4,096, bf16)
+SSD = dict(S=256, H=64, P=64, N=128, G=8)
+WIDE_GROUPS = dict(S=256, PAGE=16, MAXP=4096 // 16, H=32, H_KV=2, D=128,
+                   POOL=256 * 256 + 1)
+
+
+@pytest.mark.parametrize("rows", ["decode", "mixed-rows"])
+def test_ssd_step_kernel_at_the_cells_shape(mosaic, rows):
+    """The Mamba-2 decode step through the call the layer makes
+    (ops/ssd.py: step_rows): one call, under the name `ssd_step` — the
+    body it shares with `kda_step` shows under its own name, which the
+    benchmark's `ssd_step_roofline.serve` matches — and, the 539 MB state
+    pool donated and aliased, no copy of it on its way in."""
+    from paddle_tpu.ops import ssd
+    c = SSD
+    R, H, P, N, G = c["S"], c["H"], c["P"], c["N"], c["G"]
+
+    def step(state, slot, live, x, Bm, Cm, dt, A):
+        return ssd.step_rows(state, None if rows == "decode" else slot,
+                             live, x, Bm, Cm, dt, A, use_kernel=True)
+
+    compiled = mosaic(step, ((c["S"] + 1, H, P, N), f32), ((R,), i32),
+                      ((R,), jnp.bool_), ((R, H, P), f32), ((R, G, N), f32),
+                      ((R, G, N), f32), ((R, H), f32), ((H,), f32),
+                      donate=(0,))
+    assert kernel_names(compiled) == ["ssd_step.1"], kernel_names(compiled)
+    import re
+    made_by = re.findall(r"= f32\[257,64,64,128\]\S* ([\w-]+)\(",
+                         compiled.as_text())
+    assert made_by and "copy" not in made_by, made_by
+
+
+@pytest.mark.parametrize("rows", [256, 512], ids=["decode", "mixed-512-rows"])
+def test_paged_kernel_at_head_128_in_groups_of_16(mosaic, rows):
+    """`paged_attn` at 32 query heads over 2 KV heads of 128 (groups of 16;
+    StarCoder2 runs 12), at the decode step's 256 rows and the mixed step's
+    512: one kernel, the pools donated and never copied or padded."""
+    from paddle_tpu.ops.attention import (paged_attention_step,
+                                          ragged_paged_attention_step)
+    from paddle_tpu.ops.pallas_paged import kv_row_shape
+    c = WIDE_GROUPS
+    row = kv_row_shape(c["H_KV"], c["D"])
+    assert row == (2, 128)
+    pools = [((c["POOL"], c["PAGE"]) + row, bf16)] * 2
+    if rows == c["S"]:
+        def step(q, k, v, kp, vp, table, pos):
+            return paged_attention_step(q, k, v, kp, vp, table, pos,
+                                        use_kernel=True)
+        S = c["S"]
+        compiled = mosaic(
+            step, ((S, 1, c["H"], c["D"]), bf16),
+            ((S, 1, c["H_KV"], c["D"]), bf16),
+            ((S, 1, c["H_KV"], c["D"]), bf16), *pools,
+            ((S, c["MAXP"]), i32), ((S,), i32), donate=(3, 4))
+    else:
+        def step(q, k, v, kp, vp, table, row_slot, row_pos):
+            return ragged_paged_attention_step(q, k, v, kp, vp, table,
+                                               row_slot, row_pos,
+                                               use_kernel=True)
+        T = rows
+        compiled = mosaic(
+            step, ((T, c["H"], c["D"]), bf16), ((T, c["H_KV"], c["D"]), bf16),
+            ((T, c["H_KV"], c["D"]), bf16), *pools,
+            ((c["S"] + 1, c["MAXP"]), i32), ((T,), i32), ((T,), i32),
+            donate=(3, 4))
+    assert kernel_names(compiled) == ["paged_attn.1"], kernel_names(compiled)
+    import re
+    text = compiled.as_text()
+    made_by = re.findall(r"= bf16\[65537,16,2,128\]\S* ([\w-]+)\(", text)
+    assert made_by and "copy" not in made_by and "pad" not in made_by, made_by
