@@ -26,6 +26,24 @@ pool:
   carries, the recurrence of pallas_attention.py's flash kernel), block
   i+1's copies — or the NEXT row's first block — are in flight.
 
+A BLOCK IN VMEM is the matmul operand itself: a double buffer
+[2, pages*page_size*H_kv, D] a pool, each page's copy landing in its own
+page_size*H_kv rows, so K and V enter the two dots as dense tiles read
+once (a page of 16 x 2 x 128 bf16 is 2 + 2 vregs) and nothing is stored
+but the output.  The pool keeps its stored shape [P, page_size, H_kv, D]:
+Mosaic tiles its minor [H_kv, D] (2,128)(2,1) — a token's two bf16 heads
+are ONE 32-bit sublane row, a page 8 contiguous KB — and that is byte for
+byte a [page_size*H_kv, D] operand tiled (8,128)(2,1): rows 2t, 2t+1 are
+token t under heads 0, 1, the column order the mask below assumes.  The
+copy's source is the page reshaped to those rows (a view: the DMA crosses
+the two tilings, exact on the chip — tools/tpu_parity.py --only=paged).
+A buffer shaped like the pool, [pages, page_size, H_kv, D], keeps the
+(2,128) tiling, and `reshape(block*H_kv, D)` of it is then a relayout that
+moves no information: at 16 pages a block 512 loads of one live sublane
+and 512 strided stores through Mosaic's internal scratch, 1.43 us a block
+where this form takes 0.88 (my chip run, PR 42; tools/kernel_lowering.py
+counts the loads and stores, tests/test_mosaic_compile.py holds them).
+
 Grouped-query heads are handled in-kernel without a per-head gather: a
 block is one dense [block*H_kv, D] operand as the pool stores it, every
 query head is scored against every (token, kv head) column, and the
@@ -118,10 +136,19 @@ def _round_up(n: int, m: int) -> int:
 
 
 # VMEM a block's K and V may take together, both buffers counted.  On the
-# v5e the loop costs about 0.23 us a block and 0.08 us a page (my chip
-# run, PR 28), so a wider block amortizes the step and a narrower one
-# fetches fewer dead tokens past a short row's end (the block fill the
-# engine counts: kv_tokens_attended / kv_tokens_fetched).
+# v5e the loop costs about 0.28 us a block and 0.038 us a page of 16 KB of
+# K and V (0.020 at the HBM's rate), 0.33 and 0.040 a page of 32 KB (the
+# HBM's own 0.040) — my chip run, PR 42: tools/bench_paged.py --fills at
+# 512 KiB and 1 MiB, 0.88 / 1.48 us a block of 16 / 32 pages; read through
+# a (2,128) tiled buffer it was 0.23 and 0.075 (PR 28), 1.43 a block.  So a
+# wider block amortizes the step and a narrower one fetches fewer dead
+# tokens past a short row's end (the block fill the engine counts:
+# kv_tokens_attended / kv_tokens_fetched).  At the serve cells' fills, ms a
+# call at 256 KiB / 512 KiB / 1 MiB (same run): the mixed step of 128 rows
+# 0.312 / 0.240 / 0.268; 64 rows of which 62 are dead 0.046 / 0.063 /
+# 0.100; decode rows of 300-1,500 tokens tie between 512 KiB and 1 MiB —
+# 512 KiB stays.  A pool row of [4, 128] (heads of 64, packed) holds 8
+# pages a block at this budget and would take 22% less at 16 (ROADMAP S1).
 _KV_VMEM_BUDGET = 512 << 10
 _BLOCK_TOKENS = (128, 512)       # floor and ceiling of a block, in tokens
 
@@ -175,11 +202,12 @@ def _kernel(H, h_kv, scale, v_width, table_ref, len_ref, row_ref, q_ref,
         pools = ((k_hbm, kbuf),)
     r = pl.program_id(0)
     n_rows = pl.num_programs(0)
-    npb, ps = kbuf.shape[1:3]
-    Dp = kbuf.shape[-1]
+    ps = k_hbm.shape[1]
+    C = kbuf.shape[1]                   # score columns: (token, kv head)
+    bt = C // h_kv                      # tokens a block
+    npb = bt // ps
+    rows = ps * h_kv                    # a page's rows of the operand
     maxp = table_ref.shape[1]
-    bt = npb * ps                       # tokens a block
-    C = bt * h_kv                       # score columns: (token, kv head)
     Hp = q_ref.shape[1]
     Dv = o_ref.shape[-1]
     rep = H // h_kv
@@ -193,8 +221,11 @@ def _kernel(H, h_kv, scale, v_width, table_ref, len_ref, row_ref, q_ref,
         for i in range(npb):
             page = table_ref[s, jnp.minimum(blk * npb + i, maxp - 1)]
             for j, (hbm, buf) in enumerate(pools):
+                # the page as the operand's rows: the same bytes
                 pltpu.make_async_copy(
-                    hbm.at[page], buf.at[slot, i], sems.at[j, slot]).start()
+                    hbm.at[page].reshape(rows, buf.shape[-1]),
+                    buf.at[slot, pl.ds(i * rows, rows)],
+                    sems.at[j, slot]).start()
 
     def wait_fetch(slot):
         # one wait a buffer: a descriptor of the whole buffer's size takes
@@ -240,8 +271,8 @@ def _kernel(H, h_kv, scale, v_width, table_ref, len_ref, row_ref, q_ref,
             start_fetch(nrow, nb, 1 - slot)
 
         wait_fetch(slot)
-        k = kbuf[slot].reshape(C, Dp)
-        v = k[:, :Dv] if vbuf is None else vbuf[slot].reshape(C, Dp)
+        k = kbuf[slot]                                    # [C, Dp]
+        v = k[:, :Dv] if vbuf is None else vbuf[slot]
         sc = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # [Hp, C]
@@ -365,7 +396,7 @@ def paged_attention(
         k_pages, v_pages = (jnp.pad(p, ((0, 0),) * 3 + ((0, Dp - L),))
                             for p in (k_pages, v_pages))
     out = _call("paged_attn", functools.partial(_kernel, H, G, scale, None),
-                qp, (k_pages, v_pages), (npb, ps, G, Dp), Dp, page_table,
+                qp, (k_pages, v_pages), (npb * ps * G, Dp), Dp, page_table,
                 lengths, row_slot)[:, :H, :L]
     if pack > 1:
         out = jnp.sum(out.reshape(R, H, pack, D) *
@@ -406,6 +437,6 @@ def latent_paged_attention(
     qp = jnp.pad(q, ((0, 0), (0, Hp - H), (0, 0)))
     out = _call("mla_paged_attn",
                 functools.partial(_kernel, H, 1, scale, v_width), qp,
-                (kv_pages,), (npb, ps, W), v_width, page_table, lengths,
+                (kv_pages,), (npb * ps, W), v_width, page_table, lengths,
                 row_slot)
     return out[:, :H]

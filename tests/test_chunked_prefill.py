@@ -530,6 +530,10 @@ _ROW_CASES = {
                                (1, 9), (2, 260)],
     "padding-rows-beside-live": [(3, 0), (0, 255), (3, 0), (1, 0), (3, 0)],
     "final-row-is-padding": [(0, 128), (2, 319), (3, 0)],
+    # a chunk that fills a block's LAST page (the last row range of the
+    # kernel's buffer) and steps into the next block's first
+    "chunk-fills-a-blocks-last-page": [(1, p) for p in range(112, 130)]
+    + [(0, 255), (2, 127)],
 }
 
 

@@ -9,15 +9,51 @@ Rules this file keeps (on-chip-measurement guide §2): the topology is
 described inside a module-scoped fixture, never at import time; shapes and
 shardings are built in fixtures/tests; compiles run in the test's own
 process with the persistent compile cache off; all cases live in THIS one
-file so a single xdist worker owns the TPU library.
+file so a single xdist worker owns the TPU library.  The one exception is
+the first test: Mosaic's dump flag is read when the library loads, so
+`tools/kernel_lowering.py` is a process of its own — run BEFORE this
+worker's `topo` fixture takes the library's lock (file order), and skipped
+where the child cannot describe a topology either.
 """
 
+import json
 import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
+
+
+def test_paged_kernel_reads_a_block_as_dense_tiles():
+    """The lowering itself, at decode-saturated's shape (64 rows, 24 / 2
+    heads of 128, page 16, bf16): a block's pages land in the matmul
+    operand's own rows, so the kernel stores nothing but its output, loads
+    K and V once (a page is 2 + 2 vregs) and no load moves under half a
+    vreg.  Read through a `(2,128)` tiled buffer the same block was 580
+    loads of one live sublane and 516 stores (PR 42)."""
+    tool = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "kernel_lowering.py")
+    p = subprocess.run([sys.executable, tool, "paged_attn"], text=True,
+                       capture_output=True, timeout=300,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    lines = p.stdout.strip().splitlines()
+    assert lines, p.stderr[-2000:]
+    got = json.loads(lines[-1])
+    if p.returncode == 3:
+        pytest.skip(got["skipped"])
+    assert p.returncode == 0, got
+    pages = got["pages_per_block"]
+    assert pages == 16, got
+    # K's and V's copies of a block, at the two places a fetch starts
+    assert got["tpu.enqueue_dma"] == 2 * 2 * pages, got
+    # the output's [32, 128] float32 tiles, nothing through scratch
+    assert got["tpu.store"] <= 4, got
+    # 4 vregs a page (+ q's [32, 128] bf16), each load half a vreg or more
+    assert got["vregs_loaded"] <= 4 * pages + 2, got
+    assert got["tpu.load"] <= 2 * got["vregs_loaded"], got
 
 
 @pytest.fixture(scope="module")

@@ -515,11 +515,13 @@ def test_pallas_paged_kernel_matches_fallback():
 # the paged kernel's block loop (interpret mode) against the jnp fallback
 # ---------------------------------------------------------------------------
 
-def _paged_case(lengths, H, Hkv, D, ps, maxp, dtype, seed=0):
+def _paged_case(lengths, H, Hkv, D, ps, maxp, dtype, seed=0, row=None):
     """Pools, a table and queries for one kernel call: one slot a length
     (0 = a dead slot: an all-zero table row read at length 1), each live
-    slot's pages scattered over the pool.  Returns the kernel's output and
-    the fallback's (the decode step with use_kernel=False)."""
+    slot's pages scattered over the pool, a token's row stored [Hkv, D] or
+    as `row` (`kv_row_shape`: the same values in the same order).  Returns
+    the kernel's output and the fallback's (the decode step with
+    use_kernel=False)."""
     import jax.numpy as jnp
 
     from paddle_tpu.ops.attention import paged_attention_step
@@ -528,8 +530,9 @@ def _paged_case(lengths, H, Hkv, D, ps, maxp, dtype, seed=0):
     rng = np.random.default_rng(seed)
     S = len(lengths)
     P = 1 + S * maxp
-    kp = jnp.asarray(rng.normal(size=(P, ps, Hkv, D)), dtype)
-    vp = jnp.asarray(rng.normal(size=(P, ps, Hkv, D)), dtype)
+    row = (Hkv, D) if row is None else row
+    kp = jnp.asarray(rng.normal(size=(P, ps) + row), dtype)
+    vp = jnp.asarray(rng.normal(size=(P, ps) + row), dtype)
     table = np.zeros((S, maxp), np.int32)
     free = rng.permutation(np.arange(1, P)).tolist()
     for s, n in enumerate(lengths):
@@ -585,6 +588,18 @@ _SHAPE_CASES = {
     "rep2-d128-page8": (4, 2, 128, 8, 40, "float32", 2e-5),
     "one-kv-head": (4, 1, 32, 16, 20, "float32", 2e-5),
 }
+# a block is [pages x page_size x rows-a-token, lanes] in the kernel's VMEM
+# buffer, each page's copy landing in its own row range: rows that end in
+# the LAST page's range of a block, and a table of one page (a block of one
+# page, `max_pages` under a block), for a pool row as the heads are, a
+# packed one ([.., 4, 128], two heads of 64 a lane tile) and one KV head
+_EDGE_ROWS = {"unpacked": (4, 2, 128), "packed-4x128": (16, 8, 64),
+              "one-kv-head-d128": (4, 1, 128)}
+_EDGE_CASES = {f"{name}-{edge}": heads + (16, maxp, "float32", 2e-5)
+               for name, heads in _EDGE_ROWS.items()
+               for edge, maxp in (("last-page-of-a-block", 40),
+                                  ("block-of-one-page", 1))}
+_SHAPE_CASES.update(_EDGE_CASES)
 
 
 @pytest.mark.parametrize("case", list(_SHAPE_CASES))
@@ -595,9 +610,19 @@ def test_paged_kernel_shapes_match_fallback(case):
     precision, so only the output's rounding separates them)."""
     import jax.numpy as jnp
 
+    from paddle_tpu.ops.pallas_paged import block_tokens, kv_row_shape
+
     H, Hkv, D, ps, maxp, dtype, tol = _SHAPE_CASES[case]
-    got, want = _paged_case([1, 130, 0, 300, 256], H, Hkv, D, ps, maxp,
-                            jnp.dtype(dtype), seed=1)
+    lengths, row = [1, 130, 0, 300, 256], None
+    if case in _EDGE_CASES:
+        row = kv_row_shape(Hkv, D)
+        bt = block_tokens(ps, *row, jnp.dtype(dtype).itemsize, maxp)
+        # the last page's first and last token, of the first block and of
+        # the second; a row short of it; a dead row
+        lengths = [bt - ps + 1, bt, 0, 2 * bt - 3, bt - ps] if maxp > 1 \
+            else [1, ps, 0, ps // 2 + 1]
+    got, want = _paged_case(lengths, H, Hkv, D, ps, maxp, jnp.dtype(dtype),
+                            seed=1, row=row)
     np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
 
 

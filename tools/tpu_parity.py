@@ -151,60 +151,89 @@ def flash_cases():
 
 def paged_cases():
     """The serving engine's decode hot path: the Pallas ragged-paged kernel
-    against the jnp page-gather read of the SAME step function, at the
-    smoke's engine shape (16 slots, page 16, context 768, 8 kv heads x 64)
-    — one-token-per-slot decode and the row-indirected mixed form."""
+    against the jnp page-gather read of the SAME step function —
+    one-token-per-slot decode and the row-indirected mixed form — at the
+    smoke's engine shape (16 slots, page 16, context 768, 8 kv heads x 64
+    stored as they are: the kernel pads their lanes) and at the serve
+    cells' own pool rows (`kv_row_shape`): StarCoder2's and Nemotron's
+    [P, 16, 2, 128] (a page's copy crosses from the pool's (2,128) tiles to
+    the kernel's dense operand rows: only the chip can say it is exact),
+    LFM2's packed [P, 16, 4, 128], and a table shorter than a block."""
     from paddle_tpu.ops.attention import (paged_attention_step,
                                           ragged_paged_attention_step)
+    from paddle_tpu.ops.pallas_paged import kv_row_shape
 
-    S, ps, ctx, H, h_kv, D = 16, 16, 768, 8, 8, 64
-    maxp = ctx // ps
+    ps = 16
     dt, tol = jnp.bfloat16, 3e-2
 
-    def pool(rng):
-        P = S * maxp + 1                       # + the trash page 0
-        kp = jnp.asarray(rng.normal(size=(P, ps, h_kv, D)), dt)
-        vp = jnp.asarray(rng.normal(size=(P, ps, h_kv, D)), dt)
-        # every slot owns maxp distinct physical pages, shuffled
-        table = (rng.permutation(S * maxp) + 1).reshape(S, maxp)
-        return kp, vp, jnp.asarray(table, jnp.int32)
+    def build(S, ctx, H, h_kv, D, row, tag):
+        maxp = ctx // ps
 
-    def run_decode():
-        rng = np.random.default_rng(_seed("paged_decode"))
-        kp, vp, table = pool(rng)
-        pos = jnp.asarray(rng.integers(0, ctx, S), jnp.int32)
-        q, k, v = (jnp.asarray(rng.normal(size=(S, 1, h, D)), dt)
-                   for h in (H, h_kv, h_kv))
+        def pool(rng):
+            P = S * maxp + 1                       # + the trash page 0
+            kp = jnp.asarray(rng.normal(size=(P, ps) + row), dt)
+            vp = jnp.asarray(rng.normal(size=(P, ps) + row), dt)
+            # every slot owns maxp distinct physical pages, shuffled
+            table = (rng.permutation(S * maxp) + 1).reshape(S, maxp)
+            return kp, vp, jnp.asarray(table, jnp.int32)
 
-        def step(use_kernel):
-            return jax.jit(lambda *a: paged_attention_step(
-                *a, use_kernel=use_kernel)[0])(q, k, v, kp, vp, table, pos)
+        def edges(pos):
+            # a row of one token, rows that end on a page's and a block's
+            # last token and on the first of the next, a full table
+            for i, p in enumerate((0, ps - 1, 127, 128, 255, 256, ctx - 1)):
+                pos[i % len(pos)] = min(p, ctx - 1)
+            return pos
 
-        return {"out": _close(step(True), _oracle(lambda: step(False)), tol)}
+        def run_decode():
+            rng = np.random.default_rng(_seed(f"paged_decode{tag}"))
+            kp, vp, table = pool(rng)
+            pos = jnp.asarray(edges(rng.integers(0, ctx, S)), jnp.int32)
+            q, k, v = (jnp.asarray(rng.normal(size=(S, 1, h, D)), dt)
+                       for h in (H, h_kv, h_kv))
 
-    def run_mixed():
-        rng = np.random.default_rng(_seed("paged_mixed"))
-        kp, vp, table = pool(rng)
-        T = 4 * ps + S                          # default max_step_tokens
-        # one 64-token prompt chunk on slot 0 at positions 100.., then one
-        # decode row for every slot
-        row_slot = np.concatenate([np.zeros(4 * ps, np.int32),
-                                   np.arange(S, dtype=np.int32)])
-        row_pos = np.concatenate([100 + np.arange(4 * ps),
-                                  rng.integers(200, ctx, S)]).astype(np.int32)
-        q, k, v = (jnp.asarray(rng.normal(size=(T, h, D)), dt)
-                   for h in (H, h_kv, h_kv))
+            def step(use_kernel):
+                return jax.jit(lambda *a: paged_attention_step(
+                    *a, use_kernel=use_kernel)[0])(
+                        q, k, v, kp, vp, table, pos)
 
-        def step(use_kernel):
-            return jax.jit(lambda *a: ragged_paged_attention_step(
-                *a, use_kernel=use_kernel)[0])(
-                    q, k, v, kp, vp, table, jnp.asarray(row_slot),
-                    jnp.asarray(row_pos))
+            return {"out": _close(step(True), _oracle(lambda: step(False)),
+                                  tol)}
 
-        return {"out": _close(step(True), _oracle(lambda: step(False)), tol)}
+        def run_mixed():
+            rng = np.random.default_rng(_seed(f"paged_mixed{tag}"))
+            kp, vp, table = pool(rng)
+            T = 4 * ps + S                          # default max_step_tokens
+            # one 64-token prompt chunk on slot 0 (it crosses a block's
+            # edge where the context allows), then one decode row a slot
+            start = min(224, ctx - 4 * ps - 1)
+            row_slot = np.concatenate([np.zeros(4 * ps, np.int32),
+                                       np.arange(S, dtype=np.int32)])
+            dec = rng.integers(min(200, ctx - 1), ctx, S)
+            # slot 0's decode row is the token after its chunk
+            dec[0], dec[1:] = start + 4 * ps, edges(dec[1:])
+            row_pos = np.concatenate([start + np.arange(4 * ps),
+                                      dec]).astype(np.int32)
+            q, k, v = (jnp.asarray(rng.normal(size=(T, h, D)), dt)
+                       for h in (H, h_kv, h_kv))
 
-    return [(f"paged_decode_S{S}_ctx{ctx}_bf16", run_decode),
-            (f"paged_mixed_T{4 * ps + S}_ctx{ctx}_bf16", run_mixed)]
+            def step(use_kernel):
+                return jax.jit(lambda *a: ragged_paged_attention_step(
+                    *a, use_kernel=use_kernel)[0])(
+                        q, k, v, kp, vp, table, jnp.asarray(row_slot),
+                        jnp.asarray(row_pos))
+
+            return {"out": _close(step(True), _oracle(lambda: step(False)),
+                                  tol)}
+
+        return [(f"paged_decode{tag}_S{S}_ctx{ctx}_bf16", run_decode),
+                (f"paged_mixed{tag}_T{4 * ps + S}_ctx{ctx}_bf16", run_mixed)]
+
+    return (build(16, 768, 8, 8, 64, (8, 64), "")
+            + build(32, 2048, 24, 2, 128, kv_row_shape(2, 128), "_kv2x128")
+            + build(32, 2048, 32, 8, 64, kv_row_shape(8, 64), "_packed4x128")
+            + build(32, 2048, 32, 2, 128, kv_row_shape(2, 128),
+                    "_groups_of_16")
+            + build(8, 128, 24, 2, 128, kv_row_shape(2, 128), "_short_table"))
 
 
 def additive_cases():
