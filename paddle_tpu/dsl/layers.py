@@ -35,7 +35,7 @@ from paddle_tpu.dsl.poolings import AvgPooling, BasePoolingType, FirstPooling, L
 
 __all__ = [
     "rms_norm_layer", "gated_ffn_layer", "mla_attention_layer",
-    "kda_attention_layer", "short_conv_layer", "mamba2_layer",
+    "kda_attention_layer", "short_conv_layer", "mamba2_layer", "mamba_layer",
     "data_layer", "fc_layer", "embedding_layer", "mixed_layer", "addto_layer",
     "concat_layer", "dropout_layer", "full_matrix_projection",
     "trans_full_matrix_projection", "identity_projection", "table_projection",
@@ -1385,6 +1385,74 @@ def mamba2_layer(
     _layer_attr_fields(cfg, layer_attr)
     current_context().add_layer(cfg)
     return LayerOutput(name, "mamba2", size, parents=[input],
+                       seq_level=input.seq_level)
+
+
+def mamba_layer(
+    input: LayerOutput,
+    *,
+    d_inner: int,
+    state_size: int = 16,
+    dt_rank: Optional[int] = None,
+    conv_size: int = 4,
+    size: Optional[int] = None,
+    rms_eps: float = 1e-6,
+    attn_impl: Optional[str] = None,
+    name: Optional[str] = None,
+    param_attr: Optional[ParameterAttribute] = None,
+    layer_attr: Optional[ExtraLayerAttribute] = None,
+) -> LayerOutput:
+    """The Mamba-1 token mixer (arXiv:2312.00752, as the Jamba family runs
+    it, arXiv:2403.19887; ops/selective_scan.py, graph/layers_mamba.py): a
+    causal selective state-space layer over `d_inner` channels whose context
+    is one recurrent state [state_size, d_inner], every element with a decay
+    of its own — x through a depthwise causal convolution of `conv_size`
+    taps with a bias and SiLU, a low-rank (`dt_rank`, default d_inner / 16
+    rounded up) projection of the time step, RMSNorms with a learned scale
+    on the time step's rank, B and C (Jamba's three inner norms), a SiLU
+    gate in front of the output projection.  `param_attr` initializes the
+    four matrices; A_log (held [state_size, d_inner]) starts uniform in
+    [0, log 16] — the published initializer is log(1..16) a channel, which
+    a uniform attribute cannot say — and dt_bias in softplus^-1 of
+    [1e-3, 1e-1], the time step's projection uniform in +-dt_rank^-1/2, D
+    and the norms' scales at 1, the taps and their bias uniform in
+    +-conv_size^-1/2 (a depthwise Conv1d's default)."""
+    assert param_attr is None or not param_attr.name, \
+        "a named param_attr would share one matrix across the projections"
+    assert conv_size >= 2, f"conv_size {conv_size}: a tail needs >= 2 taps"
+    size = size if size is not None else input.size
+    name = _name(name, "mamba")
+    d, N = input.size, state_size
+    R = dt_rank if dt_rank is not None else -(-d_inner // 16)
+    cfg = LayerConfig(name=name, type="mamba", size=size, active_type="")
+    cfg.attrs.update(d_inner=d_inner, state_size=N, dt_rank=R,
+                     conv_size=conv_size, rms_eps=rms_eps, causal=True)
+    if attn_impl is not None:
+        cfg.attrs["attn_impl"] = attn_impl
+    bound = conv_size ** -0.5
+    taps = lambda: ParameterAttribute(initial_min=-bound, initial_max=bound)
+    one = lambda: ParameterAttribute(initial_mean=1.0, initial_std=0.0)
+    specs = [
+        ([d, 2 * d_inner], param_attr),
+        ([conv_size, d_inner], taps()), ([1, d_inner], taps()),
+        ([d_inner, R + 2 * N], param_attr),
+        ([1, R], one()), ([1, N], one()), ([1, N], one()),
+        ([R, d_inner], ParameterAttribute(initial_min=-R ** -0.5,
+                                          initial_max=R ** -0.5)),
+        ([1, d_inner], ParameterAttribute(initial_min=-6.9073,
+                                          initial_max=-2.2522)),
+        ([N, d_inner], ParameterAttribute(initial_min=0.0,
+                                          initial_max=2.7726)),
+        ([1, d_inner], one()),
+        ([d_inner, size], param_attr),
+    ]
+    for i, (dims, attr) in enumerate(specs):
+        pname = _make_param(name, i, dims, attr)
+        cfg.inputs.append(LayerInput(input_layer_name=input.name,
+                                     input_parameter_name=pname))
+    _layer_attr_fields(cfg, layer_attr)
+    current_context().add_layer(cfg)
+    return LayerOutput(name, "mamba", size, parents=[input],
                        seq_level=input.seq_level)
 
 

@@ -93,6 +93,10 @@ CATALOG: dict[str, str] = {
         "compiled steps whose recurrent rows were counted",
     "serving_slot_state_bytes":
         "device bytes of the recurrent layers' slot-indexed state",
+    "serving_recurrent_tokens_total":
+        "tokens the recurrent layers ran, one layer's worth a step (label "
+        "kind: step = a decode row, one token a slot state; segment = a "
+        "prompt chunk's rows, a run of tokens a slot state)",
     # -- the weights a step reads: cast once when params is set -----------
     "serving_step_weight_casts_total":
         "weight trees derived for the compiled steps that copied at least "

@@ -506,7 +506,29 @@ def _stored_rows(new: Array, pages: Array) -> Array:
     (ops/pallas_paged.py:kv_row_shape) — the same values in the same
     order, so the gather path below reads a pool of either layout by
     reshaping what it gathered back to [H_kv, D]."""
-    return new.reshape(new.shape[:1] + pages.shape[2:]).astype(pages.dtype)
+    row = pages.shape[3 if _tokens_a_row(new, pages) > 1 else 2:]
+    return new.reshape(new.shape[:1] + row).astype(pages.dtype)
+
+
+def _tokens_a_row(new: Array, pages: Array) -> int:
+    """Tokens one stored row of the pool holds: 1, or 2 where a lone KV
+    head of 128 lanes is stored two tokens a sublane row
+    (ops/pallas_paged.py:kv_page_shape)."""
+    return pages.shape[2] * pages.shape[3] // (new.shape[-2] * new.shape[-1])
+
+
+def _page_size(new: Array, pages: Array) -> int:
+    """Tokens a page of the pool holds, whatever shape it is stored in."""
+    return pages.shape[1] * _tokens_a_row(new, pages)
+
+
+def _write_rows(pages: Array, phys: Array, off: Array, new: Array) -> Array:
+    """Scatter the rows `new` [N, H_kv, D] to token `off` of page `phys`."""
+    tpr = _tokens_a_row(new, pages)
+    rows = _stored_rows(new, pages)
+    if tpr > 1:
+        return pages.at[phys, off // tpr, off % tpr].set(rows)
+    return pages.at[phys, off].set(rows)
 
 
 def paged_attention_step(
@@ -559,7 +581,7 @@ def paged_attention_step(
     """
     S, Tn, H, D = q_new.shape
     assert Tn == 1, "paged decode feeds exactly one new token per slot"
-    page_size = k_pages.shape[1]
+    page_size = _page_size(k_new, k_pages)
     max_pages = page_table.shape[1]
     if scale is None:
         scale = D ** -0.5
@@ -582,8 +604,8 @@ def paged_attention_step(
     phys = jnp.take_along_axis(page_table, (pos // page_size)[:, None],
                                axis=1)[:, 0]                     # [S]
     off = pos % page_size
-    ck = k_pages.at[phys, off].set(_stored_rows(k_new[:, 0], k_pages))
-    cv = v_pages.at[phys, off].set(_stored_rows(v_new[:, 0], v_pages))
+    ck = _write_rows(k_pages, phys, off, k_new[:, 0])
+    cv = _write_rows(v_pages, phys, off, v_new[:, 0])
 
     if use_kernel is None:
         from paddle_tpu.ops import pallas_paged
@@ -596,8 +618,9 @@ def paged_attention_step(
                 "None for auto, which already falls back) for window "
                 "attention")
         from paddle_tpu.ops import pallas_paged
-        out = pallas_paged.paged_attention(q_new[:, 0], ck, cv, page_table,
-                                           pos + 1, scale=scale)[:, None]
+        out = pallas_paged.paged_attention(
+            q_new[:, 0], ck, cv, page_table, pos + 1, scale=scale,
+            kv_heads=k_new.shape[-2])[:, None]
         return out, ck, cv
 
     # -- read: page-table gather -> [S, T_ctx] contiguous view -----------
@@ -674,7 +697,7 @@ def ragged_paged_attention_step(
     row->slot indirection (ops/pallas_paged.py); the jnp gather fallback
     is the exactness oracle (and the sliding-window path)."""
     T, H, D = q_new.shape
-    page_size = k_pages.shape[1]
+    page_size = _page_size(k_new, k_pages)
     max_pages = page_table.shape[1]
     if scale is None:
         scale = D ** -0.5
@@ -696,8 +719,8 @@ def ragged_paged_attention_step(
     # -- write: scatter every row's k/v into its slot's current page -----
     phys = page_table[row_slot, row_pos // page_size]             # [T]
     off = row_pos % page_size
-    ck = k_pages.at[phys, off].set(_stored_rows(k_new, k_pages))
-    cv = v_pages.at[phys, off].set(_stored_rows(v_new, v_pages))
+    ck = _write_rows(k_pages, phys, off, k_new)
+    cv = _write_rows(v_pages, phys, off, v_new)
 
     if use_kernel is None:
         from paddle_tpu.ops import pallas_paged
@@ -711,7 +734,8 @@ def ragged_paged_attention_step(
         from paddle_tpu.ops import pallas_paged
         out = pallas_paged.paged_attention(q_new, ck, cv, page_table,
                                            row_pos + 1, scale=scale,
-                                           row_slot=row_slot)
+                                           row_slot=row_slot,
+                                           kv_heads=k_new.shape[-2])
         return out, ck, cv
 
     # -- read: per-row page-table gather -> [T, T_ctx] contiguous view ---

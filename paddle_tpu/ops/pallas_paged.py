@@ -171,6 +171,24 @@ def kv_row_shape(h_kv: int, head_dim: int) -> tuple[int, int]:
     return h_kv, head_dim
 
 
+def kv_page_shape(page_size: int, h_kv: int, head_dim: int,
+                  itemsize: int) -> tuple[int, int, int]:
+    """The shape a PAGE of K (or V) is stored in, a pool's dimensions after
+    its first: (page_size,) + `kv_row_shape` — but for multi-query
+    attention's ONE KV head of 128 lanes in a 2-byte dtype, where a bf16
+    pool's HBM tile `(2,128)(2,1)` would pad the lone row to two and the
+    pool to twice its bytes (and Mosaic refuses the page's copy: a slice of
+    one row is not aligned to the tiling).
+    There TWO TOKENS share a sublane row: (page_size // 2, 2, 128), the
+    same bytes in the same order (a row-major reshape of [page_size, 1,
+    128]) and exactly the tiles of the kernel's dense operand."""
+    g, lanes = kv_row_shape(h_kv, head_dim)
+    if h_kv == 1 and (g, lanes) == (1, 128) and itemsize == 2 \
+            and page_size % 2 == 0:
+        return page_size // 2, 2, lanes
+    return page_size, g, lanes
+
+
 def block_tokens(page_size: int, h_kv: int, head_dim: int, itemsize: int,
                  max_pages: int) -> int:
     """Tokens of KV one loop step of the kernel folds: a whole number of
@@ -202,11 +220,14 @@ def _kernel(H, h_kv, scale, v_width, table_ref, len_ref, row_ref, q_ref,
         pools = ((k_hbm, kbuf),)
     r = pl.program_id(0)
     n_rows = pl.num_programs(0)
-    ps = k_hbm.shape[1]
+    # a page's rows of the operand, however the pool folds them (a lone
+    # head's tokens are stored two a row: `kv_page_shape`)
+    rows = k_hbm.shape[1] if len(k_hbm.shape) == 3 \
+        else k_hbm.shape[1] * k_hbm.shape[2]
+    ps = rows // h_kv                   # tokens a page
     C = kbuf.shape[1]                   # score columns: (token, kv head)
     bt = C // h_kv                      # tokens a block
     npb = bt // ps
-    rows = ps * h_kv                    # a page's rows of the operand
     maxp = table_ref.shape[1]
     Hp = q_ref.shape[1]
     Dv = o_ref.shape[-1]
@@ -346,6 +367,8 @@ def paged_attention(
     row_slot: Optional[Array] = None,   # [R] int32 page-table row each
                             # query row reads; None = rows ARE slots
                             # (the classic one-token-per-slot decode)
+    kv_heads: Optional[int] = None,     # the model's KV heads; None = the
+                            # pool's third dimension holds a token's rows
 ) -> Array:
     """Ragged paged attention -> [R, H, D].  Same math as the jnp
     fallback's gather path (online softmax re-association aside): q, k
@@ -369,11 +392,17 @@ def paged_attention(
     in the others, so its score against a packed row is its own head's
     dot product; the H // G heads that share a row are one group of the
     in-kernel mask; the weighted row comes back 128 wide and the head's
-    own lanes are taken from it here.  No pool is copied or padded."""
+    own lanes are taken from it here.  A pool that stores a lone head's
+    tokens TWO A ROW (`kv_page_shape`; told from `kv_heads`) is the same
+    kernel with one row a token: a page's copy lands in the same operand
+    rows.  No pool is copied or padded."""
     R, H, D = q.shape
     P, ps, G, L = k_pages.shape
     maxp = page_table.shape[1]
     pack = L // D                       # KV heads a stored row: 1 unpacked
+    if kv_heads is not None and kv_heads // pack != G:
+        # rows a page / rows a token: the lone head stored two tokens a row
+        ps, G = ps * G * pack // kv_heads, kv_heads // pack
     assert L == pack * D and H % (G * pack) == 0, \
         f"heads {H} x {D} do not read a pool row of {G} x {L}"
     if scale is None:

@@ -244,6 +244,12 @@ _FLIGHT_COUNTERS = {
     for kind in ("decode", "mixed", "scan", "spec")}
 
 
+def _is_abstract(params: dict) -> bool:
+    """A parameter tree of shapes, not arrays (tools/serve.py `--weights
+    deferred`)."""
+    return any(isinstance(v, jax.ShapeDtypeStruct) for v in params.values())
+
+
 class _Pending:
     """One compiled decode or mixed step between its LAUNCH and its LAND:
     the device array of sampled tokens (the counts of `_with_counts`
@@ -322,6 +328,11 @@ class ServingEngine:
             # replicated — the tree is reused verbatim as the compiled
             # steps' in_shardings, so placement and jit can never diverge
             self._param_shardings_tree = self._tp_param_shardings(params)
+            if _is_abstract(params):
+                raise ValueError(
+                    "an engine built around abstract parameters (tools/"
+                    "serve.py --weights deferred) places no weights: "
+                    "--mesh model=N needs them when it is built")
             params = jax.device_put(params, self._param_shardings_tree)
         self.params = params        # the property: derives the steps' tree
         pages_per_slot = -(-int(max_context) // int(page_size))
@@ -471,8 +482,9 @@ class ServingEngine:
         if paged:
             pool = next(iter(next(iter(paged.values())).values()))
             # a latent pool's row is one [W] vector: one KV "head" of
-            # width W
-            h_kv = pool.shape[2] if pool.ndim == 4 else 1
+            # width W; a page's rows over its tokens, however it folds them
+            h_kv = pool.shape[1] * pool.shape[2] // self.kv.page_size \
+                if pool.ndim == 4 else 1
             self._kv_block = block_tokens(
                 self.kv.page_size, h_kv // self.kv.tp_shards,
                 pool.shape[-1], pool.dtype.itemsize, self.kv.pages_per_slot)
@@ -497,6 +509,11 @@ class ServingEngine:
         self.recurrent_rows = 0
         self.recurrent_slot_updates = 0
         self.recurrent_steps = 0
+        # tokens the recurrent layers ran, one layer's worth a step, by the
+        # call that ran them: `step` one a decode row, `segment` a prompt
+        # chunk's consecutive rows (counted on the host, where the step is
+        # packed)
+        self.recurrent_tokens = {"step": 0, "segment": 0}
         self._kv_synced = -1                   # kv.version last uploaded
         self._slots_dirty = True
         self._run_host: Optional[np.ndarray] = None
@@ -741,11 +758,19 @@ class ServingEngine:
         too.  The previous derived tree is dropped first: a swap peaks at
         the old and new given trees plus one derived tree.  Pages the
         prefix index cached under the previous weights are left alone:
-        assign before serving, or to an engine without the index."""
+        assign before serving, or to an engine without the index.
+
+        An ABSTRACT tree (leaves `jax.ShapeDtypeStruct`: tools/serve.py
+        `--weights deferred`) is kept for its names, shapes and dtypes and
+        holds no bytes; the engine derives nothing from it and `step`
+        refuses by name until a real tree is assigned."""
         self._step_params = None
         self._params = params
-        self._step_params = self.executor.cast_params(params)
         self._moe_grouped_at = {}
+        self.step_weight_bytes = 0
+        if _is_abstract(params):
+            return
+        self._step_params = self.executor.cast_params(params)
         self.step_weight_bytes = sum(
             int(v.nbytes) for k, v in self._step_params.items()
             if v is not params[k])
@@ -1171,6 +1196,11 @@ class ServingEngine:
                 return True
             self._t_prev_decode = None   # idle: don't charge the idle gap
             return False
+        if self._step_params is None:
+            raise RuntimeError(
+                "this engine holds no weights: it was built around the "
+                "parameter tree's shapes (tools/serve.py --weights "
+                "deferred) — assign `engine.params` before the first step")
         self._plan_span = self.tracer.begin(
             "pt.step.plan", track="engine",
             sink=self.step_clock.sink("pt.step.plan"))
@@ -1339,6 +1369,7 @@ class ServingEngine:
             self._count_launch(len(going) / S)
             self._count_kv(lengths)
             self._note_step_metrics(len(runnable), decoded=True)
+            self._count_recurrent_tokens(len(runnable), 0)
         adv = np.zeros(S, np.int32)
         adv[runnable] = 1
         return _Pending(nxt, list(self.slots), runnable, [], adv,
@@ -1351,6 +1382,17 @@ class ServingEngine:
         self.occupancy_sum += occupancy
         if self._pending is not None:
             self.n_lookahead_steps += 1
+
+    def _count_recurrent_tokens(self, step: int, segment: int) -> None:
+        """The tokens one dispatch hands the recurrent layers (nothing for
+        a model without them): `step` its decode rows, one token a slot
+        state, `segment` its chunk rows, a run of tokens a slot state."""
+        if not self._recurrent:
+            return
+        for kind, n in (("step", int(step)), ("segment", int(segment))):
+            self.recurrent_tokens[kind] += n
+            process_counters().add(counter_key(
+                "serving_recurrent_tokens_total", kind=kind), n)
 
     def _land(self, pend: _Pending) -> None:
         """The other half of a decode or mixed step: read its tokens back
@@ -1533,6 +1575,7 @@ class ServingEngine:
         # paused slot recomputes at its frozen position
         self._count_kv(base[None, :] + np.minimum(
             np.arange(k)[:, None], ran[None, :]))
+        self._count_recurrent_tokens(ran.sum(), 0)
         return True
 
     def _launch_mixed(self, going, runnable, filling, cur) -> _Pending:
@@ -1610,6 +1653,8 @@ class ServingEngine:
             self.n_mixed_steps += 1
             self._count_kv(row_pos + 1)           # a padding row reads 1
             self._note_step_metrics(r, decoded=bool(runnable))
+            self._count_recurrent_tokens(
+                len(runnable), sum(n for _, n, _ in advanced))
         return _Pending(nxt, list(self.slots), runnable, advanced, adv,
                         emit, "mixed", self.n_decode_steps, launch.t0)
 

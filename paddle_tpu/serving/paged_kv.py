@@ -213,7 +213,8 @@ class PagedKVCache:
             name: {part: jnp.zeros((num_slots + 1,) + row, part_dtype)
                    for part, (row, part_dtype) in ps.items()}
             for name, ps in declared.items()}
-        from paddle_tpu.ops.pallas_paged import kv_row_shape
+        from paddle_tpu.ops.pallas_paged import kv_page_shape, kv_row_shape
+        itemsize = jnp.dtype(dtype).itemsize
         for l in executor.model.layers:
             if l.type == "multi_head_attention":
                 heads = int(l.attrs["num_heads"])
@@ -222,7 +223,10 @@ class PagedKVCache:
                 # row axis is what shards, so only whole tiles a shard
                 # (each shard then holds its own kv heads, in order)
                 row = (h_kv, int(l.size) // heads)
+                page = (page_size,) + row
                 if kv_row_shape(*row)[0] % self.tp_shards == 0:
+                    # a lone row of 128 lanes is stored two tokens a row
+                    page = kv_page_shape(page_size, *row, itemsize)
                     row = kv_row_shape(*row)
                 parts = ("k", "v")
             elif l.type == "mla_attention":
@@ -235,11 +239,12 @@ class PagedKVCache:
                 from paddle_tpu.ops.mla import lane_width
                 row = (lane_width(int(l.attrs["kv_lora_rank"]) +
                                   int(l.attrs["qk_rope_head_dim"])),)
+                page = (page_size,) + row
                 parts = ("kv",)
             else:
                 continue
             self.layer_specs[l.name] = row
-            shape = (self.num_pages, page_size) + row
+            shape = (self.num_pages,) + page
 
             def _pool():
                 # distinct buffers per part — parts are donated side by

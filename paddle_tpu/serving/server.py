@@ -438,6 +438,11 @@ class ServingServer:
                  float(eng.recurrent_steps)),
                 ("serving_slot_state_bytes", "gauge", None,
                  float(eng.kv.slot_state_bytes)),
+                # tokens the recurrent layers ran as decode rows (`step`)
+                # and as prompt chunks' runs (`segment`), one layer's worth
+                *(("serving_recurrent_tokens_total", "counter",
+                   {"kind": kind}, float(n))
+                  for kind, n in sorted(eng.recurrent_tokens.items())),
                 # multi-step decode: scan body iterations vs boundary
                 # flushes — steps/flushes ≈ decode_steps in steady state
                 ("serving_scan_steps_total", "counter", None,
@@ -1688,6 +1693,8 @@ class ServingServer:
             "recurrent_rows": eng.recurrent_rows,
             "recurrent_slot_updates": eng.recurrent_slot_updates,
             "recurrent_steps": eng.recurrent_steps,
+            # of those rows, the decode rows and the prompt chunks' rows
+            "recurrent_tokens": dict(eng.recurrent_tokens),
             # speculative decoding: the A/B-able knobs + the counters the
             # accept rate reconciles from, plus the adaptive state
             # (drafter kind, dynamic-k flag, per-slot learned EWMAs)

@@ -701,3 +701,120 @@ def test_paged_kernel_at_head_128_in_groups_of_16(mosaic, rows):
     text = compiled.as_text()
     made_by = re.findall(r"= bf16\[65537,16,2,128\]\S* ([\w-]+)\(", text)
     assert made_by and "copy" not in made_by and "pad" not in made_by, made_by
+
+
+# jamba2-3b-serve.long-output-256's own shapes (benchmark/configs/
+# jamba2-3b-serve.json: 256 slots, d_in 5,120 channels of 16 state elements,
+# float32 state [16, 5120] a slot; chunks of 128 in the 256 chunk rows of a
+# 512-token mixed step; page 16, context 4,096, 20 query heads over ONE KV
+# head of 128, bf16)
+SCAN = dict(S=256, N=16, D_IN=5120, P=256)
+MQA = dict(S=256, PAGE=16, MAXP=4096 // 16, H=20, H_KV=1, D=128,
+           POOL=256 * 256 + 1)
+
+
+@pytest.mark.parametrize("rows", ["decode", "mixed-rows"])
+def test_selective_scan_step_at_the_cells_shape(mosaic, rows):
+    """256 runs of one token through the call the layer makes
+    (ops/selective_scan.py: step_rows; the kernel asks ops/pallas_kda.py's
+    interpret switch, which `mosaic` steers): one kernel under the name
+    `selective_scan_step`, the 1.35 GB state pool donated and aliased —
+    no copy of it on its way in."""
+    from paddle_tpu.ops import selective_scan as ss
+    c = SCAN
+    R, N, d = c["S"], c["N"], c["D_IN"]
+
+    def step(state, slot, live, x, Bm, Cm, dt, A):
+        return ss.step_rows(state, None if rows == "decode" else slot, live,
+                            x, Bm, Cm, dt, A, use_kernel=True)
+
+    compiled = mosaic(
+        step, ((c["S"] + 1, N, d), f32), ((R,), i32), ((R,), jnp.bool_),
+        ((R, d), f32), ((R, N), f32), ((R, N), f32), ((R, d), f32),
+        ((N, d), f32), donate=(0,))
+    assert kernel_names(compiled) == ["selective_scan_step.1"], \
+        kernel_names(compiled)
+    import re
+    made_by = re.findall(r"= f32\[257,16,5120\]\S* ([\w-]+)\(",
+                         compiled.as_text())
+    assert made_by and "copy" not in made_by, made_by
+    # the pool is lane-dense in HBM: the bytes of its elements and no more
+    assert "f32[257,16,5120]{2,1,0:T(8,128)}" in compiled.as_text()
+
+
+def test_selective_scan_segments_at_the_cells_shape(mosaic):
+    """The 256 chunk rows of a 512-token mixed step (2-4 runs of up to 128
+    tokens, wherever they start) through the call the layer makes
+    (ops/selective_scan.py: segment_rows): the kernel with the time loop
+    inside it, under the name `selective_scan_seg`, inside a loop over the
+    step's runs four at a time; the state pool donated, never copied."""
+    from paddle_tpu.ops import selective_scan as ss
+    c = SCAN
+    P, N, d = c["P"], c["N"], c["D_IN"]
+
+    def seg(state, seg_slot, seg_pos, x, Bm, Cm, dt, A):
+        return ss.segment_rows(state, seg_slot, seg_pos, x, Bm, Cm, dt, A,
+                               use_kernel=True)
+
+    compiled = mosaic(
+        seg, ((c["S"] + 1, N, d), f32), ((P,), i32), ((P,), i32),
+        ((P, d), f32), ((P, N), f32), ((P, N), f32), ((P, d), f32),
+        ((N, d), f32), donate=(0,))
+    names = kernel_names(compiled)
+    assert len(names) == 1 and names[0].startswith("selective_scan_seg"), \
+        names
+    import re
+    made_by = re.findall(r"= f32\[257,16,5120\]\S* ([\w-]+)\(",
+                         compiled.as_text())
+    assert made_by and "copy" not in made_by, made_by
+
+
+@pytest.mark.parametrize("rows", [256, 512], ids=["decode", "mixed-512-rows"])
+def test_paged_kernel_at_one_kv_head_under_20_query_heads(mosaic, rows):
+    """`paged_attn` at 20 query heads over ONE KV head of 128 (one group of
+    20, padded to 32 query rows in the kernel), at the decode step's 256
+    rows and the mixed step's 512: one kernel, the pools donated and never
+    copied or padded.  Stored [P, 16, 1, 128] the bf16 pool's `(2,128)(2,1)`
+    tile pads the lone head to two rows (2,048 B a token a layer where 512
+    are stored) and Mosaic refuses the page's copy ("slice shape along
+    dimension 2 must be aligned to tiling (2), but is 1"); stored two
+    tokens a row, [P, 8, 2, 128] (ops/pallas_paged.py:kv_page_shape), it is
+    the bytes of its elements and no more."""
+    from paddle_tpu.ops.attention import (paged_attention_step,
+                                          ragged_paged_attention_step)
+    from paddle_tpu.ops.pallas_paged import kv_page_shape
+    c = MQA
+    page = kv_page_shape(c["PAGE"], c["H_KV"], c["D"], 2)
+    assert page == (8, 2, 128)          # two tokens a sublane row
+    pools = [((c["POOL"],) + page, bf16)] * 2
+    if rows == c["S"]:
+        def step(q, k, v, kp, vp, table, pos):
+            return paged_attention_step(q, k, v, kp, vp, table, pos,
+                                        use_kernel=True)
+        S = c["S"]
+        compiled = mosaic(
+            step, ((S, 1, c["H"], c["D"]), bf16),
+            ((S, 1, c["H_KV"], c["D"]), bf16),
+            ((S, 1, c["H_KV"], c["D"]), bf16), *pools,
+            ((S, c["MAXP"]), i32), ((S,), i32), donate=(3, 4))
+    else:
+        def step(q, k, v, kp, vp, table, row_slot, row_pos):
+            return ragged_paged_attention_step(q, k, v, kp, vp, table,
+                                               row_slot, row_pos,
+                                               use_kernel=True)
+        T = rows
+        compiled = mosaic(
+            step, ((T, c["H"], c["D"]), bf16), ((T, c["H_KV"], c["D"]), bf16),
+            ((T, c["H_KV"], c["D"]), bf16), *pools,
+            ((c["S"] + 1, c["MAXP"]), i32), ((T,), i32), ((T,), i32),
+            donate=(3, 4))
+    assert kernel_names(compiled) == ["paged_attn.1"], kernel_names(compiled)
+    import re
+    text = compiled.as_text()
+    shape = ",".join(str(n) for n in (c["POOL"],) + page)
+    made_by = re.findall(r"= bf16\[%s\]\S* ([\w-]+)\(" % shape, text)
+    assert made_by and "copy" not in made_by and "pad" not in made_by, made_by
+    assert "bf16[%s]{3,2,1,0:T(2,128)(2,1)}" % shape in text
+    pool_bytes = c["POOL"] * c["PAGE"] * c["H_KV"] * c["D"] * 2
+    assert compiled.memory_analysis().argument_size_in_bytes < \
+        2 * pool_bytes * 1.02

@@ -325,7 +325,7 @@ def test_slot_parts_are_declared_by_the_layer_type():
     from paddle_tpu.graph.registry import slot_state_types
     from paddle_tpu.serving import PagedKVCache
     from paddle_tpu.serving.paged_kv import slot_state_specs
-    assert sorted(slot_state_types) == ["kda_attention", "mamba2",
+    assert sorted(slot_state_types) == ["kda_attention", "mamba", "mamba2",
                                         "short_conv"]
     cfg = _cfg()
     ex = _build(cfg, compute_dtype="bfloat16")

@@ -106,10 +106,17 @@ def run_client(args) -> int:
 
 def build_model(args):
     """(executor, parameters) of the served model: the config's graph and
-    its parameters from the config's initializers — or a checkpoint — held
-    in `--param-dtype`.  No Trainer: nothing of an optimizer is built, so
-    start-up holds one copy of the weights in the dtype they are served
-    in."""
+    its parameters, held in `--param-dtype`.  No Trainer: nothing of an
+    optimizer is built.  Start-up holds ONE copy of the weights on every
+    road: `--weights init` (the default) makes each parameter from the
+    config's initializer; `--checkpoint` starts from the parameter tree's
+    SHAPES (`jax.eval_shape` of the initializers: no bytes) and puts each
+    loaded leaf into it, never over an initialised one; `--weights
+    deferred` hands the engine that abstract tree as it is — for a caller
+    that brings the weights itself (`engine.params = ...`) and would
+    otherwise hold its set beside an initialised one.  An engine built
+    around the abstract tree refuses a step by name until real weights are
+    assigned."""
     import jax
     import jax.numpy as jnp
 
@@ -122,8 +129,8 @@ def build_model(args):
         cfg.model_config,
         compute_dtype=FLAGS.compute_dtype or cfg.opt_config.compute_dtype)
     dtype = jnp.dtype(args.param_dtype) if args.param_dtype else None
-    params = executor.init_params(jax.random.PRNGKey(args.seed or 0),
-                                  dtype=dtype)
+    init = lambda: executor.init_params(jax.random.PRNGKey(args.seed or 0),
+                                        dtype=dtype)
     if args.checkpoint:
         from paddle_tpu.trainer.checkpoint import (latest_checkpoint,
                                                    load_checkpoint)
@@ -131,6 +138,7 @@ def build_model(args):
         path = latest_checkpoint(args.checkpoint) or args.checkpoint
         print(f"loading checkpoint {path}", file=sys.stderr)
         data = load_checkpoint(path)
+        params = jax.eval_shape(init)
         for name, cur in params.items():
             assert name in data["params"], \
                 f"checkpoint missing parameter {name!r}"
@@ -140,6 +148,10 @@ def build_model(args):
                 f"the model expects {cur.size}")
             # reference-format files are flat fp32: restore shape and dtype
             params[name] = arr.reshape(cur.shape).astype(cur.dtype)
+    elif args.weights == "deferred":
+        params = jax.eval_shape(init)
+    else:
+        params = init()
     return executor, params
 
 
@@ -342,6 +354,14 @@ def main(argv=None) -> int:
                     help="pump beat age past which the watchdog declares "
                          "a wedge and dumps a bundle")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--weights", choices=["init", "deferred"],
+                    default="init",
+                    help="'init': every parameter from the config's "
+                         "initializer (or --checkpoint). 'deferred': build "
+                         "the engine around the parameter tree's shapes "
+                         "and hold no weight bytes - the embedder assigns "
+                         "engine.params before the first step (a step "
+                         "before that is refused by name)")
     ap.add_argument("--param-dtype", default="",
                     help="dtype the weights are held in (e.g. bfloat16: "
                          "one set, not float32 plus the steps' copies); "

@@ -158,21 +158,23 @@ def paged_cases():
     cells' own pool rows (`kv_row_shape`): StarCoder2's and Nemotron's
     [P, 16, 2, 128] (a page's copy crosses from the pool's (2,128) tiles to
     the kernel's dense operand rows: only the chip can say it is exact),
-    LFM2's packed [P, 16, 4, 128], and a table shorter than a block."""
+    LFM2's packed [P, 16, 4, 128], a table shorter than a block, and
+    Jamba's lone head stored two tokens a row, [P, 8, 2, 128]."""
     from paddle_tpu.ops.attention import (paged_attention_step,
                                           ragged_paged_attention_step)
-    from paddle_tpu.ops.pallas_paged import kv_row_shape
+    from paddle_tpu.ops.pallas_paged import kv_page_shape, kv_row_shape
 
     ps = 16
     dt, tol = jnp.bfloat16, 3e-2
 
-    def build(S, ctx, H, h_kv, D, row, tag):
+    def build(S, ctx, H, h_kv, D, row, tag, page=None):
         maxp = ctx // ps
+        page = page or (ps,) + row
 
         def pool(rng):
             P = S * maxp + 1                       # + the trash page 0
-            kp = jnp.asarray(rng.normal(size=(P, ps) + row), dt)
-            vp = jnp.asarray(rng.normal(size=(P, ps) + row), dt)
+            kp = jnp.asarray(rng.normal(size=(P,) + page), dt)
+            vp = jnp.asarray(rng.normal(size=(P,) + page), dt)
             # every slot owns maxp distinct physical pages, shuffled
             table = (rng.permutation(S * maxp) + 1).reshape(S, maxp)
             return kp, vp, jnp.asarray(table, jnp.int32)
@@ -233,7 +235,11 @@ def paged_cases():
             + build(32, 2048, 32, 8, 64, kv_row_shape(8, 64), "_packed4x128")
             + build(32, 2048, 32, 2, 128, kv_row_shape(2, 128),
                     "_groups_of_16")
-            + build(8, 128, 24, 2, 128, kv_row_shape(2, 128), "_short_table"))
+            + build(8, 128, 24, 2, 128, kv_row_shape(2, 128), "_short_table")
+            # Jamba's lone KV head of 128 under 20 query heads, stored two
+            # tokens a sublane row ([P, 8, 2, 128]: kv_page_shape)
+            + build(32, 2048, 20, 1, 128, kv_row_shape(1, 128),
+                    "_lone_head_paired", page=kv_page_shape(ps, 1, 128, 2)))
 
 
 def additive_cases():
