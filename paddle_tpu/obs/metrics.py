@@ -60,6 +60,13 @@ CATALOG: dict[str, str] = {
         "prompt tokens skipped at prefill via cached prefixes",
     "serving_prefix_evictions_total":
         "prefix pages evicted by page-pool pressure (LRU, before pausing)",
+    "serving_prefix_evict_calls_total":
+        "calls of the page-pressure hook into the prefix index's eviction",
+    "serving_prefix_frontier_pops_total":
+        "entries that left the kept eviction frontier, by outcome "
+        "(victim / stale: re-keyed / ineligible: dropped)",
+    "serving_prefix_frontier_size":
+        "entries of the kept eviction frontier (at most one a node)",
     "serving_prefix_cow_total":
         "copy-on-write page copies (divergence inside a shared boundary page)",
     # -- host KV spill tier (docs/serving.md "KV spill tier") -------------

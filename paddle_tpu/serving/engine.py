@@ -950,12 +950,15 @@ class ServingEngine:
 
     def _evict_for(self, n_pages: int) -> int:
         """The allocator's page-pressure hook: PrefixTree.evict_for under
-        its own span.  A call walks the whole tree in Python (3.6 ms at
-        16 k nodes) whatever it frees, and a full pool asks for ONE page at
-        nearly every page boundary of every slot — so a call frees a batch:
-        at least 1/256 of the pool (64 pages of 16,385; nothing extra in a
-        pool under 256 pages), the coldest leaves first as ever.  What is
-        given up is the prefix cache's 0.4% least-recently-used tail."""
+        its own span.  A full pool asks for ONE page at nearly every page
+        boundary of every slot, so a call frees a batch: at least 1/256 of
+        the pool (64 pages of 16,385; nothing extra in a pool under 256
+        pages), the coldest leaves first as ever.  The tree keeps its
+        frontier, so a call costs its victims alone (PERF.md section 6:
+        0.5 ms for 64) and the floor saves only a span and a call a page;
+        it stays because with it the victims are the ones they always
+        were.  What it gives up is the prefix cache's 0.4%
+        least-recently-used tail (PERF.md section 7)."""
         n_pages = max(int(n_pages), self.kv.num_pages // 256)
         with self.tracer.span("pt.kv.evict", track="engine",
                               sink=self.step_clock.sink("pt.kv.evict"),
@@ -2516,6 +2519,7 @@ class ServingEngine:
                 self.kv.uncache_page(node.page)
         self.prefix = None
         self.kv.on_page_pressure = None
+        self.kv.on_cached_unmapped = None
 
     def set_spill_budget(self, spill_bytes_budget: int) -> None:
         """A/B knob (the same engine spill-off, then on): sets the host
@@ -2753,6 +2757,7 @@ class ServingEngine:
                 self.prefix.n_nodes = len(built) - 1
                 self.prefix._clock = snap["prefix"]["clock"]
                 self.prefix.n_evictions = snap["prefix"]["n_evictions"]
+                self.prefix.rebuild()
         for k, v in snap["counters"].items():
             setattr(self, k, v)
         self.results = {k: np.asarray(v).copy()
@@ -2786,6 +2791,8 @@ class ServingEngine:
                 self._req_trace[sl.req.req_id] = sl.req.trace
         kv.check()                      # allocator oracle on the restored
                                         # tables/refcounts — fail loudly
+        if self.prefix is not None:     # and the index's, on what it kept
+            self.prefix.check_invariants()
         self.flight.record("restore", slots=sum(
             1 for sl in self.slots if sl is not None),
             queued=len(self.queue))

@@ -68,7 +68,9 @@ prefix index, serving/prefix_tree.py, between requests).  The contract:
   * when the free list runs dry the allocator first asks
     `on_page_pressure(n)` (the prefix index's LRU eviction) to reclaim
     cached refcount-zero pages — eviction before pausing slots, preemption
-    stays last resort.
+    stays last resort.  So that the index need not search for those
+    pages, `_unref` tells it (`on_cached_unmapped(p)`) the moment a cached
+    page's last mapping goes.
 
 HOST SPILL TIER (docs/serving.md "KV spill tier"): with a non-zero
 `spill_bytes_budget`, a cold refcount-zero cached page that the prefix
@@ -279,6 +281,11 @@ class PagedKVCache:
         # returns pages reclaimed (the prefix index's LRU eviction —
         # serving/engine.py wires it).  None = no reclaimer, fail dry.
         self.on_page_pressure: Optional[Callable[[int], int]] = None
+        # called with a prefix-cached page when its last slot mapping
+        # goes (`_unref`): the page just became evictable, which the
+        # prefix index records instead of walking for it later
+        # (PrefixTree wires itself).  None = no index.
+        self.on_cached_unmapped: Optional[Callable[[int], None]] = None
         self.n_cow = 0                 # copy-on-write page copies performed
         self._copy_fn = None           # lazily-jitted device page copy
         # -- host spill tier (module docstring "HOST SPILL TIER") ----------
@@ -480,8 +487,11 @@ class PagedKVCache:
         assert self._ref[page] >= 1, \
             f"page {page} unreferenced below zero (double release?)"
         self._ref[page] -= 1
-        if self._ref[page] == 0 and not self._cached[page]:
-            self._free.append(page)
+        if self._ref[page] == 0:
+            if not self._cached[page]:
+                self._free.append(page)
+            elif self.on_cached_unmapped is not None:
+                self.on_cached_unmapped(page)
 
     def release(self, slot: int) -> None:
         """Drop every mapping of `slot` (retire/abort): each page's

@@ -29,6 +29,23 @@ children), and refcount-zero-first means eviction never steals a page out
 from under a live slot.  Eviction runs BEFORE the engine pauses slots;
 preemption stays last resort.
 
+THE KEPT FRONTIER: the set `evict_for` draws from — DEVICE nodes with no
+DEVICE child whose page no slot maps — lives BETWEEN calls as a min-heap
+on `last_use`, at most one entry a node (`_Node.queued`), so a call
+costs its victims and not a walk of every node.  An entry is pushed by
+the only events that can make a node eligible: its page's last mapping
+went (`kv.on_cached_unmapped`, out of `PagedKVCache._unref`, finds the
+node through `_by_page`), its last DEVICE child was evicted, or it
+received a page nobody maps (an adopted insert, a promote).  Nothing
+removes an entry early: `_touch`, a new mapping and a new child leave it
+where it is, and the pop validates — an ineligible node is dropped (the
+event that makes it eligible again pushes it again), a node touched
+since the push goes back in under its present `last_use`.  A recorded
+key is never larger than the true one, so eligible nodes still leave in
+exact LRU order.  `_evictable_leaves()`, the walk, is the oracle
+(`check_invariants`, the tests) and the rebuild where the tree is
+loaded from other state (`rebuild`); it is not on the step path.
+
 TWO-LEVEL EVICTION (the KV spill tier, docs/serving.md): with a non-zero
 `kv.spill_bytes_budget`, a device-eviction victim is first offered to the
 host tier — the node keeps its tokens but trades `page` for `host_id`
@@ -51,6 +68,7 @@ thread (the pump), like the rest of the scheduler state.
 
 from __future__ import annotations
 
+import heapq
 from typing import Optional
 
 import numpy as np
@@ -60,7 +78,7 @@ from paddle_tpu.obs.flight import get_flight_recorder
 
 class _Node:
     __slots__ = ("run", "page", "parent", "children", "by_first",
-                 "last_use", "host_id")
+                 "last_use", "host_id", "queued")
 
     def __init__(self, run: tuple, page: int, parent: Optional["_Node"]):
         self.run = run                  # page_size token ids (() for root)
@@ -75,6 +93,7 @@ class _Node:
         # admission cost on the hottest prefix exactly
         self.by_first: dict[int, dict[tuple, _Node]] = {}
         self.last_use = 0
+        self.queued = False             # holds an entry of the kept frontier
 
     def add_child(self, child: "_Node") -> None:
         self.children[child.run] = child
@@ -99,11 +118,26 @@ class PrefixTree:
         self.flight = get_flight_recorder()
         self.n_nodes = 0
         self.n_evictions = 0
+        # the kept eviction frontier (module docstring): heap entries are
+        # (last_use at push, push sequence, node); `_by_page` names the
+        # DEVICE node of a physical page for the allocator's notification
+        self._frontier: list = []
+        self._seq = 0
+        self._by_page: dict[int, _Node] = {}
+        self.n_evict_calls = 0
+        self.frontier_pops = {"victim": 0, "stale": 0, "ineligible": 0}
+        kv.on_cached_unmapped = self._page_unmapped
         # the engine's restore path sets this while it allocates fresh
         # device pages: pressure eviction then destroys instead of
         # spilling, so the host tier (and the hids mid-restore) stays
         # stable under the restore's own allocation
         self._spill_inhibit = False
+
+    @property
+    def frontier_size(self) -> int:
+        """Entries of the kept eviction frontier, valid or not yet
+        validated: never more than `n_nodes`."""
+        return len(self._frontier)
 
     # -- LRU ---------------------------------------------------------------
     def _touch(self, node: _Node) -> None:
@@ -190,14 +224,15 @@ class PrefixTree:
         toks = np.asarray(tokens).reshape(-1)
         assert toks.size >= len(pages) * self.ps
         node, added = self.root, 0
+        unmapped = []           # nodes handed a page no slot maps (adopted)
         for j, page in enumerate(pages):
+            page = int(page)
             run = tuple(int(t) for t in toks[j * self.ps:(j + 1) * self.ps])
             child = node.children.get(run)
+            takes_page = True
             if child is None:
-                child = _Node(run, int(page), node)
+                child = _Node(run, page, node)
                 node.add_child(child)
-                if not adopted:
-                    self.kv.cache_page(int(page))
                 self.n_nodes += 1
                 added += 1
             elif child.host_id is not None:
@@ -209,25 +244,87 @@ class PrefixTree:
                 # in this same call: the residency invariant holds.
                 self.kv.drop_host_page(child.host_id, reason="drain")
                 child.host_id = None
-                child.page = int(page)
-                if not adopted:
-                    self.kv.cache_page(int(page))
-            elif adopted:
-                # the run is already DEVICE-resident: the imported copy is
-                # bit-identical (same token path, deterministic prefill),
-                # keep the incumbent and free the duplicate now
-                self.kv.uncache_page(int(page))
+                child.page = page
+            else:
+                takes_page = False
+                if adopted:
+                    # the run is already DEVICE-resident: the imported
+                    # copy is bit-identical (same token path,
+                    # deterministic prefill), keep the incumbent and free
+                    # the duplicate now
+                    self.kv.uncache_page(page)
+            if takes_page:
+                self._by_page[page] = child
+                if adopted:
+                    unmapped.append(child)
+                else:
+                    self.kv.cache_page(page)
             self._touch(child)
             node = child
+        # a donated page is still mapped by its donor, whose release
+        # offers the node (`_page_unmapped`); an adopted one is mapped by
+        # nobody, so the node may be on the frontier from this moment
+        for nd in unmapped:
+            self._offer(nd)
         return added
 
     # -- eviction (the allocator's page-pressure hook) ----------------------
+    def _evictable(self, node: _Node) -> bool:
+        """`node` is on the device-eviction frontier: DEVICE (the root,
+        HOST and destroyed nodes carry no page), mapped by no slot, no
+        DEVICE child."""
+        if node.page <= 0 or self.kv._ref[node.page] != 0:
+            return False
+        for ch in node.children.values():
+            if ch.host_id is None:
+                return False
+        return True
+
+    def _offer(self, node: _Node) -> None:
+        """Give `node` its entry of the kept frontier if an event just
+        made it evictable and it holds none."""
+        if not node.queued and self._evictable(node):
+            node.queued = True
+            self._seq += 1
+            heapq.heappush(self._frontier,
+                           (node.last_use, self._seq, node))
+
+    def _page_unmapped(self, page: int) -> None:
+        """`kv.on_cached_unmapped`: the last slot mapping of a
+        prefix-cached page went (release, a COW off it)."""
+        node = self._by_page.get(page)
+        if node is not None:
+            self._offer(node)
+
+    def _pop_victim(self) -> Optional[_Node]:
+        """The least-recently-used evictable node, validated as it leaves
+        the heap (module docstring); None when the frontier is empty."""
+        heap, pops = self._frontier, self.frontier_pops
+        while heap:
+            key, _, node = heap[0]
+            if not self._evictable(node):
+                heapq.heappop(heap)
+                node.queued = False
+                pops["ineligible"] += 1
+            elif key != node.last_use:
+                self._seq += 1
+                heapq.heapreplace(heap, (node.last_use, self._seq, node))
+                pops["stale"] += 1
+            else:
+                heapq.heappop(heap)
+                node.queued = False
+                pops["victim"] += 1
+                return node
+        return None
+
     def _evictable_leaves(self):
-        """The device-eviction frontier: DEVICE nodes whose page no slot
-        maps and with no DEVICE children.  By the residency invariant a
-        HOST child has a HOST subtree, so "no DEVICE child" is "no DEVICE
-        descendant" — spilling (or destroying, host subtree included) a
-        frontier node keeps parents outliving device children."""
+        """The device-eviction frontier BY A WALK of the whole tree:
+        DEVICE nodes whose page no slot maps and with no DEVICE children.
+        By the residency invariant a HOST child has a HOST subtree, so
+        "no DEVICE child" is "no DEVICE descendant" — spilling (or
+        destroying, host subtree included) a frontier node keeps parents
+        outliving device children.  The oracle of the kept frontier and
+        its rebuild; `evict_for` does not come here."""
         out = []
         stack = list(self.root.children.values())
         while stack:
@@ -299,14 +396,15 @@ class PrefixTree:
             return False
         victim.host_id = hid
         victim.page = -1
+        del self._by_page[page]
         self.flight.record("spill", page=int(page),
                            host_pages=kv.host_page_count,
                            host_bytes=kv.host_bytes)
         return True
 
     def evict_for(self, n_pages: int) -> int:
-        """Reclaim up to `n_pages` DEVICE pages by walking the LRU
-        eviction frontier.  Returns pages actually freed.  Wired as
+        """Reclaim up to `n_pages` DEVICE pages off the LRU end of the
+        kept eviction frontier.  Returns pages actually freed.  Wired as
         `kv.on_page_pressure`, so try_grow/COW call here before failing —
         eviction before pausing slots, preemption last resort.
 
@@ -317,43 +415,34 @@ class PrefixTree:
         (an orphaned spilled run could never restore: the tree would no
         longer spell its prefix).  Either way one device page frees.
 
-        One tree walk per CALL, not per freed page: the frontier goes
-        into a min-heap on last_use, and a victim's parent enters the
-        heap the moment it has no device children and no slot mapping —
-        the multi-page reclaim an overcommitted admission needs is
-        O(nodes + freed·log nodes), not O(freed·nodes), precisely when
-        the pool is under the pressure eviction exists to relieve.
-        Single-threaded with the allocator, so no heap entry goes stale
-        mid-call; ties on last_use (never-touched nodes share 0) break by
-        insertion order."""
-        import heapq
-
-        freed = 0
-        heap = []
-        for i, nd in enumerate(self._evictable_leaves()):
-            heap.append((nd.last_use, i, nd))
-        heapq.heapify(heap)
-        seq = len(heap)
-        while freed < int(n_pages) and heap:
-            _, _, victim = heapq.heappop(heap)
+        NO tree walk: a call costs O((victims + entries dropped or
+        re-keyed on the way) · log frontier) and touches no node it does
+        not evict, however many the tree holds.  A victim's parent enters
+        the heap the moment it has no device children and no slot mapping,
+        so the multi-page reclaim an overcommitted admission needs can
+        take a whole cold chain in one call.  `frontier_pops` counts what
+        left the heap: victims over all pops is the share of the heap's
+        work that freed a page."""
+        self.n_evict_calls += 1
+        n_pages, freed = int(n_pages), 0
+        while freed < n_pages:
+            victim = self._pop_victim()
+            if victim is None:
+                break
             parent = victim.parent
             if not self._try_spill(victim):
                 for ch in list(victim.children.values()):
                     self.drop_host_subtree(ch)
                 parent.drop_child(victim)
                 page, victim.page = victim.page, -1
+                del self._by_page[page]
                 self.kv.uncache_page(page)
                 self.n_nodes -= 1
                 self.flight.record("prefix_evict", page=int(page),
                                    nodes_left=self.n_nodes)
             self.n_evictions += 1
             freed += 1
-            if parent is not self.root and \
-                    not any(c.host_id is None
-                            for c in parent.children.values()) and \
-                    self.kv._ref[parent.page] == 0:
-                heapq.heappush(heap, (parent.last_use, seq, parent))
-                seq += 1
+            self._offer(parent)         # its last DEVICE child may be gone
         return freed
 
     # -- restore (the engine's spilled-prefix-hit admission epilogue) -------
@@ -367,7 +456,12 @@ class PrefixTree:
             assert nd.host_id is not None
             nd.host_id = None
             nd.page = int(page)
+            self._by_page[nd.page] = nd
             self._touch(nd)
+        # restored pages are mapped by nobody until the admission's
+        # map_shared: the deepest of them is on the frontier meanwhile
+        for nd in nodes:
+            self._offer(nd)
 
     def clear(self) -> None:
         """Forget everything WITHOUT touching device-allocator state —
@@ -385,3 +479,66 @@ class PrefixTree:
                 node.host_id = None
         self.root = _Node((), -1, None)
         self.n_nodes = 0
+        self.rebuild()
+
+    def rebuild(self) -> None:
+        """Derive `_by_page` and the kept frontier from the nodes and the
+        allocator's refcounts by a walk — wherever the tree was put
+        together from other state (`clear`, the engine's `load_state`)."""
+        self._by_page = {}
+        stack = list(self.root.children.values())
+        while stack:
+            node = stack.pop()
+            stack.extend(node.children.values())
+            if node.host_id is None:
+                self._by_page[node.page] = node
+        self._reset_frontier()
+
+    def _reset_frontier(self) -> None:
+        """The kept frontier as one walk gives it: exactly the evictable
+        nodes, every key current."""
+        for _, _, node in self._frontier:
+            node.queued = False
+        self._frontier = []
+        for node in self._evictable_leaves():
+            self._seq += 1
+            node.queued = True
+            self._frontier.append((node.last_use, self._seq, node))
+        heapq.heapify(self._frontier)
+
+    def check_invariants(self) -> None:
+        """The kept frontier against the walk (tests, `load_state`): every
+        evictable node holds an entry, an entry's node is attached and
+        DEVICE and flagged, a recorded key is never ahead of `last_use`,
+        one entry a node at most — so the heap never outgrows the tree."""
+        device, stack = {}, list(self.root.children.values())
+        n_nodes = 0
+        while stack:
+            node = stack.pop()
+            stack.extend(node.children.values())
+            n_nodes += 1
+            if node.host_id is None:
+                assert node.page > 0 and node.page not in device, \
+                    f"device page {node.page} named twice or not a page"
+                device[node.page] = node
+            else:
+                assert node.page == -1 and not node.queued, \
+                    "a HOST node holds a page or a frontier entry"
+                assert all(c.host_id is not None
+                           for c in node.children.values()), \
+                    "a HOST node has a DEVICE child"
+        assert n_nodes == self.n_nodes, \
+            f"n_nodes {self.n_nodes} but {n_nodes} attached"
+        assert device == self._by_page, "page index disagrees with the tree"
+        entries = [node for _, _, node in self._frontier]
+        assert len(set(map(id, entries))) == len(entries) <= n_nodes, \
+            "a node holds two frontier entries"
+        for key, _, node in self._frontier:
+            assert node.queued and device.get(node.page) is node, \
+                "a frontier entry names a node that is detached or HOST"
+            assert key <= node.last_use, "a frontier key ahead of last_use"
+        queued = sum(1 for nd in device.values() if nd.queued)
+        assert queued == len(entries), "a queued flag without its entry"
+        missing = [nd.page for nd in self._evictable_leaves()
+                   if not nd.queued]
+        assert not missing, f"evictable pages off the frontier: {missing}"

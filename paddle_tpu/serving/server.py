@@ -393,6 +393,17 @@ class ServingServer:
                  float(eng.prefill_tokens_saved)),
                 ("serving_prefix_evictions_total", "counter", None,
                  float(eng.prefix.n_evictions if eng.prefix else 0)),
+                # the kept eviction frontier: calls, what left the heap
+                # (victims over all pops = its hit share), entries held
+                ("serving_prefix_evict_calls_total", "counter", None,
+                 float(eng.prefix.n_evict_calls if eng.prefix else 0)),
+                *(("serving_prefix_frontier_pops_total", "counter",
+                   {"outcome": outcome}, float(n))
+                  for outcome, n in sorted(
+                      (eng.prefix.frontier_pops if eng.prefix
+                       else {}).items())),
+                ("serving_prefix_frontier_size", "gauge", None,
+                 float(eng.prefix.frontier_size if eng.prefix else 0)),
                 ("serving_prefix_cow_total", "counter", None,
                  float(eng.kv.n_cow)),
                 # KV spill tier: device->host spills, host->device
@@ -1025,6 +1036,12 @@ class ServingServer:
                 "misses": eng.n_prefix_misses,
                 "tokens_saved": eng.prefill_tokens_saved,
                 "evictions": eng.prefix.n_evictions if eng.prefix else 0,
+                "evict_calls": (eng.prefix.n_evict_calls
+                                if eng.prefix else 0),
+                "frontier_pops": (dict(eng.prefix.frontier_pops)
+                                  if eng.prefix else {}),
+                "frontier_size": (eng.prefix.frontier_size
+                                  if eng.prefix else 0),
                 "cow": int(eng.kv.n_cow),
                 # KV spill tier (docs/serving.md): host-resident pages/
                 # bytes + the spill/restore lifecycle counters

@@ -406,9 +406,10 @@ def test_prefix_tree_match_insert_evict(tr):
 
 
 def test_page_pressure_frees_a_batch_a_walk_in_a_large_pool(tr):
-    """A full pool asks for one page at a time; each call of the pressure
-    hook walks the whole tree, so it frees 1/256 of the pool a call (the
-    coldest leaves first) — and exactly what was asked in a small pool."""
+    """A full pool asks for one page at a time; the pressure hook frees
+    1/256 of the pool a call (the coldest leaves first; the floor dates
+    from when a call walked the whole tree, and stays) — and exactly what
+    was asked in a small pool."""
     big = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
                         max_context=16, num_pages=1025)
     rng = np.random.default_rng(5)
@@ -425,3 +426,310 @@ def test_page_pressure_frees_a_batch_a_walk_in_a_large_pool(tr):
     small.run([Request("s", rng.integers(2, 23, 9).astype(np.int32),
                        max_new=3)])
     assert small._evict_for(1) == 1
+
+
+# ---------------------------------------------------------------------------
+# the kept eviction frontier (ISSUE 44): the heap against the walk
+# ---------------------------------------------------------------------------
+
+def _forbid_walk(monkeypatch):
+    def no_walk(self):
+        raise AssertionError("the step path walked the whole tree")
+    monkeypatch.setattr(PrefixTree, "_evictable_leaves", no_walk)
+
+
+def _walk_victims(tree, n):
+    """The pages `evict_for(n)` freed, in order, when every call walked:
+    the frontier by `_evictable_leaves()` into a heap on last_use, a
+    victim's parent entering it once its last DEVICE child went and no
+    slot maps it.  Reads only; the oracle of the kept frontier's order."""
+    import heapq
+
+    heap = [(nd.last_use, i, nd)
+            for i, nd in enumerate(tree._evictable_leaves())]
+    heapq.heapify(heap)
+    seq, gone, pages = len(heap), set(), []
+    while len(pages) < n and heap:
+        _, _, victim = heapq.heappop(heap)
+        pages.append(victim.page)
+        gone.add(id(victim))
+        parent = victim.parent
+        if parent is not tree.root and \
+                not any(c.host_id is None and id(c) not in gone
+                        for c in parent.children.values()) and \
+                tree.kv._ref[parent.page] == 0:
+            heapq.heappush(heap, (parent.last_use, seq, parent))
+            seq += 1
+    return pages
+
+
+class _Traffic:
+    """Seeded random allocator/index traffic at the unit level, the way
+    the engine drives the two: admissions that match, map, grow and COW,
+    retirements that donate and release, aborts, direct pressure calls,
+    imports of adopted pages, and — with a spill budget — restores of a
+    spilled tail.  EVERY eviction, asked for or raised by the allocator,
+    is held to the walking oracle's pages in its order; after every
+    operation the kept frontier is held to the walk."""
+
+    SLOTS, PS, PER_SLOT = 3, 2, 6
+
+    def __init__(self, tr, seed, spill, num_pages=20):
+        self.rng = np.random.default_rng(seed)
+        self.kv = PagedKVCache(tr.executor, num_slots=self.SLOTS,
+                               page_size=self.PS,
+                               pages_per_slot=self.PER_SLOT,
+                               num_pages=num_pages)
+        if spill:
+            self.kv.spill_bytes_budget = 5 * self.kv.page_nbytes
+        self.tree = PrefixTree(self.kv)
+        self.kv.on_page_pressure = self.evict
+        self.live = {}                       # slot -> its token sequence
+        self.evictions = self.compared = 0
+
+    def evict(self, n):
+        want = _walk_victims(self.tree, n)
+        tail = len(self.kv._free)
+        freed = self.tree.evict_for(n)
+        assert self.kv._free[tail:] == want and freed == len(want), \
+            f"evict_for({n}) freed {self.kv._free[tail:]}, the walk {want}"
+        self.evictions += freed
+        self.compared += 1
+        return freed
+
+    def _tokens(self):
+        # a vocabulary of 3 over runs of 2: prefixes collide all the time
+        n = int(self.rng.integers(2, self.PS * self.PER_SLOT + 1))
+        return self.rng.integers(0, 3, n).astype(np.int32)
+
+    def _restore(self, path):
+        """The engine's `_restore_spilled`, host tail of a matched path."""
+        kv, tree = self.kv, self.tree
+        tail = [nd for nd in path if nd.host_id is not None]
+        if not tail:
+            return True
+        tree._spill_inhibit = True
+        try:
+            pages = kv.take_pages(len(tail))
+        finally:
+            tree._spill_inhibit = False
+        if pages is None:
+            return False
+        if any(nd.page <= 0 for nd in path if nd not in tail) or \
+                not all(nd.host_id is not None and
+                        kv.host_entry_live(nd.host_id) for nd in tail):
+            kv.untake_pages(pages)
+            return False
+        kv.restore_pages([nd.host_id for nd in tail], pages)
+        kv.adopt_restored(pages)
+        tree.promote(tail, pages)
+        tree.check_invariants()              # before anything maps them
+        return True
+
+    def admit(self, s):
+        kv, tree = self.kv, self.tree
+        toks = self._tokens()
+        nodes, partial = tree.match_nodes(toks[:-1])
+        path = nodes + ([partial[0]] if partial else [])
+        if path and not self._restore(path):
+            path, partial = [], None
+        ok = True
+        if path:
+            kv.map_shared(s, [nd.page for nd in path])
+            ok = kv.try_grow(s, toks.size)
+            if ok and partial:
+                ok = kv.ensure_writable(s, len(path) - 1) is not None
+        else:
+            ok = kv.try_grow(s, toks.size)
+        if ok:
+            self.live[s] = toks
+        else:
+            kv.release(s)
+
+    def retire(self, s, donate):
+        toks = self.live.pop(s)
+        full = (toks.size - 1) // self.PS
+        if donate and full:
+            self.tree.insert(toks[:full * self.PS],
+                             [int(self.kv.table[s, j])
+                              for j in range(full)])
+        self.kv.release(s)
+
+    def mount(self):
+        """An import of adopted pages (`ServingEngine.import_prefix`)."""
+        toks = self._tokens()
+        n = toks.size // self.PS
+        pages = self.kv.take_pages(n)
+        if pages is not None:
+            self.kv.adopt_restored(pages)
+            self.tree.insert(toks[:n * self.PS], pages, adopted=True)
+
+    def step(self):
+        op = self.rng.choice(["admit", "retire", "abort", "evict", "match",
+                              "mount"], p=[.3, .3, .05, .15, .1, .1])
+        free = [s for s in range(self.SLOTS) if s not in self.live]
+        if op == "admit" and free:
+            self.admit(free[0])
+        elif op in ("retire", "abort") and self.live:
+            s = list(self.live)[int(self.rng.integers(len(self.live)))]
+            self.retire(s, donate=op == "retire")
+        elif op == "evict":
+            self.evict(int(self.rng.integers(1, 5)))
+        elif op == "match":
+            self.tree.match(self._tokens())
+        elif op == "mount":
+            self.mount()
+        self.tree.check_invariants()
+        self.kv.check()
+
+
+@pytest.mark.parametrize("spill", [False, True], ids=["destroy", "spill"])
+@pytest.mark.parametrize("seed", range(6))
+def test_kept_frontier_is_the_walks_under_random_traffic(tr, seed, spill):
+    """Completeness, the bound of one entry a node, and the parent's
+    victims to the page and in its order: 400 seeded operations, the
+    host tier off and on."""
+    t = _Traffic(tr, seed, spill)
+    for _ in range(400):
+        t.step()
+    assert t.compared >= 20 and t.evictions >= 20, \
+        "the traffic never came under page pressure"
+    assert t.tree.frontier_size <= t.tree.n_nodes
+    pops = t.tree.frontier_pops
+    assert pops["victim"] == t.tree.n_evictions == t.evictions
+    assert pops["stale"] > 0 and pops["ineligible"] > 0, \
+        f"the lazy validation was never exercised: {pops}"
+    if spill:
+        assert t.kv.n_spilled > 0 and t.kv.n_restored > 0
+    for s in list(t.live):
+        t.retire(s, donate=False)
+    t.evict(t.kv.num_pages)                  # everything left is evictable
+    assert t.tree.n_nodes == t.kv.host_page_count
+    assert t.tree.frontier_size == 0
+    t.kv.check_reclaimed()
+
+
+def _engine_under_pressure(tr, **kw):
+    """A tight engine whose index holds retired prefixes, some evicted."""
+    rng = np.random.default_rng(11)
+    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
+                        max_context=16, num_pages=9, **kw)
+    for i in range(5):
+        eng.run([Request(f"p{i}", rng.integers(2, 23, 7).astype(np.int32),
+                         max_new=4)])
+    assert eng.prefix.n_evictions > 0 and eng.prefix.n_nodes > 0
+    eng.prefix.check_invariants()
+    return eng, rng
+
+
+@pytest.mark.parametrize("path", ["clear", "kv_reset", "toggle",
+                                  "checkpoint"])
+def test_kept_frontier_survives_every_rebuild_path(tr, path):
+    """Where the tree is put together from other state the frontier is
+    rebuilt by the walk, and eviction goes on from it as from a kept one:
+    `clear`, `kv.reset` (with the clear that must follow it),
+    `set_prefix_cache(False / True)`, and a checkpoint loaded into a
+    second engine."""
+    eng, rng = _engine_under_pressure(tr)
+    if path == "clear":
+        for nd in list(eng.prefix._by_page.values()):
+            eng.kv.uncache_page(nd.page)     # what clear leaves to its caller
+        eng.prefix.clear()
+    elif path == "kv_reset":
+        eng.reset_prefix_cache()
+    elif path == "toggle":
+        eng.set_prefix_cache(False)
+        assert eng.kv.on_cached_unmapped is None
+        eng.set_prefix_cache(True)
+        assert eng.kv.on_cached_unmapped is not None
+    else:
+        snap = eng.checkpoint_state()
+        kept = sorted(nd.page for nd in eng.prefix._evictable_leaves())
+        assert kept, "nothing evictable to carry over"
+        eng = ServingEngine(tr.executor, tr.params, num_slots=2,
+                            page_size=4, max_context=16, num_pages=9)
+        eng.restore_state(snap)              # runs check_invariants itself
+        assert sorted(nd.page for _, _, nd in eng.prefix._frontier) == kept
+    if path != "checkpoint":
+        assert eng.prefix.n_nodes == eng.prefix.frontier_size == 0
+        assert not eng.prefix._by_page
+    eng.prefix.check_invariants()
+    eng.kv.check()
+    # and the index goes on working from the rebuilt frontier
+    ev0 = eng.prefix.n_evictions
+    reqs = [Request(f"q{i}", rng.integers(2, 23, 7).astype(np.int32),
+                    max_new=4) for i in range(5)]
+    results = {}
+    for r in reqs:
+        results.update(eng.run([r]))
+        eng.prefix.check_invariants()
+    assert eng.prefix.n_evictions > ev0
+    _assert_exact(tr, reqs, results)
+    _pool_reclaimed(eng)
+
+
+def test_eviction_cost_is_its_victims_not_the_tree(tr, monkeypatch):
+    """A count, not a timing: at 16 k nodes a call of 64 pops its 64
+    victims plus the entries it finds stale or ineligible on the way —
+    read from `frontier_pops` — and the walk is never taken, neither by
+    the call nor by the events that feed the frontier."""
+    ps, per = 4, 16
+    kv = PagedKVCache(tr.executor, num_slots=2, page_size=ps,
+                      pages_per_slot=per, num_pages=16 * 1024 + 65)
+    tree = PrefixTree(kv)
+    kv.on_page_pressure = tree.evict_for
+    rng = np.random.default_rng(0)
+    seqs = []
+    for _ in range(1024):                    # 1,024 chains of 16 nodes
+        toks = rng.integers(0, 1 << 30, ps * per).astype(np.int32)
+        assert kv.try_grow(0, toks.size)
+        tree.insert(toks, [int(kv.table[0, j]) for j in range(per)])
+        kv.release(0)
+        seqs.append(toks)
+    assert tree.n_nodes == 16 * 1024 and tree.frontier_size == 1024
+    tree.check_invariants()
+    _forbid_walk(monkeypatch)
+    # the 8 coldest chains are hit again (their entries go stale), and the
+    # next 4 are mapped by a slot (theirs ineligible)
+    for toks in seqs[:8]:
+        tree.match(toks)
+    kv.map_shared(1, tree.match(seqs[8])[0])
+    pops0 = dict(tree.frontier_pops)
+    nodes0 = tree.n_nodes
+    assert tree.evict_for(64) == 64
+    pops = {k: v - pops0[k] for k, v in tree.frontier_pops.items()}
+    assert pops == {"victim": 64, "stale": 8, "ineligible": 1}, pops
+    assert tree.n_nodes == nodes0 - 64
+    # whole chains went, coldest first: four chains of 16, leaf to top
+    for toks in seqs[9:13]:
+        assert tree.match(toks) == ([], None)
+    assert len(tree.match(seqs[13])[0]) == per
+    # a second call pays for nothing the first already settled
+    kv.release(1)
+    assert tree.evict_for(64) == 64
+    pops2 = {k: v - pops0[k] for k, v in tree.frontier_pops.items()}
+    assert pops2["victim"] == 128 and \
+        pops2["stale"] + pops2["ineligible"] <= 9 + 1, pops2
+    assert tree.frontier_size <= tree.n_nodes
+    assert tree.n_evict_calls == 2
+
+
+def test_step_path_under_page_pressure_never_walks(tr, monkeypatch):
+    """The engine's own steps, admission and growth under a dry free
+    list, with the walk patched to raise: evictions happen, outputs stay
+    exact."""
+    _forbid_walk(monkeypatch)
+    rng = np.random.default_rng(3)
+    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
+                        max_context=16, num_pages=7)
+    reqs = [Request(f"r{i}", rng.integers(2, 23, 6 + i % 3).astype(np.int32),
+                    max_new=5) for i in range(6)]
+    results = {}
+    for i in range(0, 6, 2):
+        results.update(eng.run(reqs[i:i + 2]))
+    assert eng.prefix.n_evictions > 0 and eng.prefix.n_evict_calls > 0
+    assert eng.prefix.frontier_pops["victim"] == eng.prefix.n_evictions
+    _assert_exact(tr, reqs, results)
+    monkeypatch.undo()
+    eng.prefix.check_invariants()
+    _pool_reclaimed(eng)

@@ -228,6 +228,16 @@ def test_metrics_frame_and_consistent_stats_over_tcp(tiny_tr):
                         '{quantile="p50",stat="request_latency"}'] > 0.0
             assert vals['serving_latency_count'
                         '{stat="first_token_latency"}'] == 1.0
+            # the kept eviction frontier's families (ISSUE 44): a pool
+            # that never came under pressure made no call and popped
+            # nothing, and the request's one donated leaf holds the
+            # frontier's one entry
+            assert vals["serving_prefix_evict_calls_total"] == 0.0
+            for outcome in ("victim", "stale", "ineligible"):
+                assert vals['serving_prefix_frontier_pops_total'
+                            f'{{outcome="{outcome}"}}'] == 0.0
+            assert 0.0 <= vals["serving_prefix_frontier_size"] <= \
+                vals["serving_prefix_nodes"]
             # consistent (pump round-trip) vs stale_ok (loop fast path)
             s = c.stats()
             assert s["consistent"] is True and s["pump_alive"] is True
@@ -1447,8 +1457,9 @@ def test_kv_read_counters_follow_each_step_kind(tiny_tr, kind):
 
 
 def test_prefix_eviction_has_its_own_span(tiny_tr):
-    """`pt.kv.evict` times PrefixTree.evict_for, the walk that costs a
-    full pool 25-30% of its decode rate (PERF.md section 6)."""
+    """`pt.kv.evict` times PrefixTree.evict_for, the call a full pool
+    makes before nearly every launch (a walk of the whole tree until the
+    tree kept its frontier: PERF.md section 6)."""
     from paddle_tpu.obs import Tracer
 
     t = Tracer()
@@ -1465,6 +1476,9 @@ def test_prefix_eviction_has_its_own_span(tiny_tr):
     evicts = [s for s in t.snapshot() if s["name"] == "pt.kv.evict"]
     assert evicts and all(s["attrs"]["pages"] >= 1 for s in evicts)
     assert {s["track"] for s in evicts} == {"engine"}
+    # one span a call of the hook, and every eviction a victim pop
+    assert len(evicts) == eng.prefix.n_evict_calls
+    assert eng.prefix.frontier_pops["victim"] == eng.prefix.n_evictions
 
 
 # -- one hand-off a step, one write a connection (ISSUE 30) ------------------

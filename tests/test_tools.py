@@ -535,3 +535,24 @@ def test_serve_refuses_the_retired_fork_selectors(argv, capsys):
         _serve_parse(argv)
     assert ei.value.code == 2
     assert argv[0] in capsys.readouterr().err
+
+
+def test_bench_prefix_evict_walk_and_kept_free_the_same_pages(
+        tmp_path, monkeypatch, capsys):
+    """tools/bench_prefix_evict.py end to end at a small size: the kept
+    frontier and the walking reference evict the same pages call for
+    call, and the table's line lands where the chip tool collects it."""
+    import json
+
+    from tools import bench_prefix_evict
+
+    monkeypatch.chdir(tmp_path)
+    assert bench_prefix_evict.main(
+        ["--nodes", "256", "--calls", "4", "--chain", "8",
+         "--victims", "16"]) == 0
+    row = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert row["same_victims"] is True and row["nodes"] == 256
+    assert row["pops"]["victim"] == 4 * 16
+    assert row["walk_ms"] > 0 and row["kept_ms"] > 0
+    with open(tmp_path / "chiprun_out" / "bench_prefix_evict.jsonl") as f:
+        assert json.loads(f.read().strip()) == row
