@@ -199,6 +199,17 @@ CATALOG: dict[str, str] = {
         "latency bound)",
     "serving_prefill_chunks_total":
         "prompt chunks scheduled into mixed prefill/decode steps",
+    "serving_chunk_rows_total":
+        "prompt token rows packed into mixed and verify steps (over "
+        "serving_prefill_chunks_total: the mean run; over "
+        "serving_mixed_steps_total: the prompt rows a mixed step carries)",
+    "serving_chunk_extra_rows_total":
+        "of those, rows given past a filling slot's prefill_chunk share "
+        "from the step's free rows (0: no step had rows to spare)",
+    "serving_step_pad_rows_total":
+        "rows of mixed and verify steps that carried no token "
+        "(max_step_tokens less the decode, draft and prompt rows): "
+        "computed by every layer, committed by none",
     "serving_mixed_steps_total":
         "compiled steps that carried at least one prefill chunk row",
     "serving_scan_steps_total":

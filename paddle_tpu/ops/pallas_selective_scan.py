@@ -1,7 +1,8 @@
 """The Mamba-1 selective scan in Pallas (TPU): the recurrence of
 ops/selective_scan.py with THE TIME LOOP INSIDE THE KERNEL.  A run is a
-slot's consecutive tokens in one step — one token for a decode row, up to
-`prefill_chunk` for a prompt chunk; the run's slot rides the scalar-prefetch
+slot's consecutive tokens in one step — one token for a decode row, a
+prompt chunk's rows (its `prefill_chunk` share of the step and the rows the
+step had free); the run's slot rides the scalar-prefetch
 channel and addresses its state block, which comes into VMEM once, is moved
 through every token of the run
 

@@ -540,6 +540,11 @@ class ServingEngine:
         self.set_chunking(4 * self.kv.page_size if prefill_chunk == -1
                           else prefill_chunk, max_step_tokens)
         self.n_prefill_chunks = 0
+        # prompt rows packed, those of them given past a slot's share, and
+        # rows of a mixed or verify step that carried nothing
+        self.n_chunk_rows = 0
+        self.n_chunk_extra_rows = 0
+        self.n_step_pad_rows = 0
         self.n_mixed_steps = 0
         # SPECULATIVE DECODING (the verify step): ONE extra compiled
         # signature per (token budget, spec_k) — created lazily like the
@@ -1583,8 +1588,9 @@ class ServingEngine:
 
     def _launch_mixed(self, going, runnable, filling, cur) -> _Pending:
         """Launch ONE mixed prefill/decode dispatch: pack each runnable
-        decode slot's single row plus up to `prefill_chunk` prompt rows per
-        mid-prefill slot into a flat [max_step_tokens] ragged row list
+        decode slot's single row plus the mid-prefill slots' prompt rows
+        (`_pack_chunk_rows`: a share of `prefill_chunk` each, then the
+        step's free rows) into a flat [max_step_tokens] ragged row list
         (padding rows aim at a virtual all-trash table row) and run the
         compiled mixed step; `_land` banks the decode tokens and advances
         the chunk cursors.  A slot whose FINAL chunk ran this step emits
@@ -1654,34 +1660,42 @@ class ServingEngine:
             self._unpack_state(st)
             self._count_launch(len(going) / S)
             self.n_mixed_steps += 1
+            chunk_rows = sum(n for _, n, _ in advanced)
+            self.n_step_pad_rows += T - len(runnable) - chunk_rows
             self._count_kv(row_pos + 1)           # a padding row reads 1
             self._note_step_metrics(r, decoded=bool(runnable))
-            self._count_recurrent_tokens(
-                len(runnable), sum(n for _, n, _ in advanced))
+            self._count_recurrent_tokens(len(runnable), chunk_rows)
         return _Pending(nxt, list(self.slots), runnable, advanced, adv,
                         emit, "mixed", self.n_decode_steps, launch.t0)
 
     def _pack_chunk_rows(self, filling, row_ids, row_slot, row_pos,
                          sample_row, adv, emit, r: int, budget: int):
-        """Pack up to `prefill_chunk` prompt rows per mid-prefill slot
-        (admit order) into the ragged row list, starting at row `r`,
-        never exceeding `budget` rows — the chunk-scheduling half SHARED
-        by the mixed and speculative verify steps, so the final-chunk
+        """Share `budget` rows out among the mid-prefill slots and pack
+        each slot's prompt rows as ONE contiguous run into the ragged row
+        list, starting at row `r` — the chunk-scheduling half SHARED by
+        the mixed and speculative verify steps, so the final-chunk
         emission rule, the shared-page tripwire, and the chunk_sched
-        accounting can never diverge between them.  A slot whose FINAL
-        chunk lands this step gets its sampling row pointed at the last
-        prompt position (`sample_row[s]`; the verify step's chain
-        position 0) and `emit[s]` set — token 0 sampled with keys[gen=0].
-        Returns (advanced, r')."""
+        accounting can never diverge between them.  `prefill_chunk` is a
+        filling slot's SHARE of a step, not a cap on its run: every slot
+        gets its share first (`_chunk_shares`), then what the step still
+        has left goes, oldest admission first, to the same slots up to
+        the rest of their prompts — the oldest finishes soonest and emits
+        its first token soonest, and a row left over would be computed as
+        padding anyway.  A slot whose FINAL chunk lands this step gets its
+        sampling row pointed at the last prompt position (`sample_row[s]`;
+        the verify step's chain position 0) and `emit[s]` set — token 0
+        sampled with keys[gen=0].  Returns (advanced, r')."""
         ps = self.kv.page_size
+        shares = self._chunk_shares(filling, budget)
+        left = budget - sum(n for _, n in shares)
         advanced = []                        # (slot, n_rows, final)
-        for s in sorted(filling, key=lambda s: self.slots[s].admit_seq):
-            if budget <= 0:
-                break
+        for s, n in shares:
             sl = self.slots[s]
             p = sl.req.prompt_ids.size
             pos = self._cursor(s)[0]        # past any chunk in flight
-            n = self._chunk_rows_for(s, budget)
+            extra = min(p - pos - n, left)
+            left -= extra
+            n += extra
             # every page this chunk writes must be private to the slot
             # (reservation COW'd the shared boundary page; mapped prefix
             # pages below the cursor are never written)
@@ -1698,20 +1712,34 @@ class ServingEngine:
                 sample_row[s] = r + n - 1
                 emit[s] = True
             self.n_prefill_chunks += 1
+            self.n_chunk_rows += n
+            self.n_chunk_extra_rows += extra
             self._bump_attr(sl.req.req_id, "chunks")
             self.flight.record("chunk_sched", req=str(sl.req.req_id),
                                slot=s, start=int(pos), tokens=int(n),
                                final=final)
             advanced.append((s, n, final))
-            budget -= n
             r += n
         return advanced, r
 
+    def _chunk_shares(self, filling, budget: int) -> list:
+        """[(slot, rows)] in admit order: each mid-prefill slot's share of
+        a step with `budget` rows for chunks, until the budget runs out —
+        what the verify step reserves ahead of its drafts, and the first
+        pass of `_pack_chunk_rows`, so the reserve can never under-count
+        what the packing will schedule."""
+        shares = []
+        for s in sorted(filling, key=lambda s: self.slots[s].admit_seq):
+            if budget <= 0:
+                break
+            n = self._chunk_rows_for(s, budget)
+            shares.append((s, n))
+            budget -= n
+        return shares
+
     def _chunk_rows_for(self, s: int, budget: int) -> int:
-        """Rows slot `s`'s next prefill chunk takes under `budget` — the
-        ONE scheduling formula, shared by _pack_chunk_rows and the
-        verify step's chunk-reserve computation so the reserve can never
-        under-count what the packing will actually schedule."""
+        """Rows of slot `s`'s share of a step under `budget`: the rest of
+        its prompt, up to `prefill_chunk` — the ONE formula of a share."""
         return min(self.slots[s].req.prompt_ids.size - self._cursor(s)[0],
                    self.prefill_chunk, budget)
 
@@ -1862,9 +1890,9 @@ class ServingEngine:
         plus up to k draft rows at pos+1..pos+k — and mid-prefill slots'
         chunk rows share the same dispatch (mode-aware packing).  Budget
         priority: decode base rows first (every decoder advances), then
-        the chunk rows' RESERVE (exactly what the mixed step would have
-        scheduled — drafting can never starve a prompt's first token),
-        and drafts spend only what is left.  The
+        the chunk rows' RESERVE (each filling slot's share of a step, the
+        mixed step's first pass — drafting can never starve a prompt's
+        first token), and drafts spend only what is left.  The
         ragged attention core scatters ALL rows' K/V before reading, so
         draft row i attends the committed context plus drafts 1..i-1
         under the causal mask — precisely the context the sequential
@@ -1908,18 +1936,8 @@ class ServingEngine:
         # speculation spends only what prefill leaves over, so drafting
         # decoders can never starve a mid-prefill prompt's chunks — the
         # first-token HOL bound chunked prefill exists for.  The reserve
-        # is exactly what the mixed step would have scheduled them.
-        chunk_reserve = 0
-        if filling:
-            left = budget
-            for s in sorted(filling,
-                            key=lambda s: self.slots[s].admit_seq):
-                if left <= 0:
-                    break
-                n = self._chunk_rows_for(s, left)
-                chunk_reserve += n
-                left -= n
-        budget -= chunk_reserve
+        # is each filling slot's share of a step, as the mixed step's.
+        budget -= sum(n for _, n in self._chunk_shares(filling, budget))
         for s in runnable:
             sl = self.slots[s]
             d = drafts.get(s)
@@ -1984,6 +2002,7 @@ class ServingEngine:
             self.n_spec_steps += 1
             if advanced:
                 self.n_mixed_steps += 1
+            self.n_step_pad_rows += T - r
             self.occupancy_sum += len(live) / S
             self._count_kv(row_pos + 1)           # a padding row reads 1
             step = self.n_decode_steps
@@ -2242,8 +2261,9 @@ class ServingEngine:
                n_pp: int = 0) -> None:
         """Chunk-granular admission — NO prefill dispatch: the slot enters
         PREFILL mode (gen=0) with its prompt pages already reserved, and
-        the prompt commits in `prefill_chunk`-token rows inside the next
-        mixed steps (_launch_mixed).  A prefix hit just means the first
+        the prompt commits in runs of rows inside the next mixed steps
+        (_launch_mixed: `prefill_chunk` a step at least, more where the
+        step has rows free).  A prefix hit just means the first
         `C` tokens are already mapped — the chunk cursor starts at C, and
         a mid-page start writes into the boundary page _reserve COW'd.
         Token 0 is sampled by the step that runs the FINAL chunk; until
@@ -2373,12 +2393,13 @@ class ServingEngine:
     def set_chunking(self, prefill_chunk: int,
                      max_step_tokens: Optional[int] = None) -> None:
         """Configure chunked prefill (idle engine only — a live slot may
-        be mid-chunk).  Prompts commit in `prefill_chunk`-token rows
-        (default 4*page_size) inside the mixed step under
-        `max_step_tokens` (default prefill_chunk + num_slots): one row per
-        decoding slot plus up to prefill_chunk rows per chunking prompt,
-        never more than the budget per step — the p99 inter-token bound.  Each distinct max_step_tokens value
-        is one mixed-step signature; hold it fixed in production."""
+        be mid-chunk).  `prefill_chunk` (default 4*page_size) is a
+        filling prompt's SHARE of a mixed step and `max_step_tokens`
+        (default prefill_chunk + num_slots) the step's bound: one row per
+        decoding slot, then each chunking prompt's share, then the rows
+        still free to the oldest prompt — never more than the budget per
+        step, the p99 inter-token bound.  Each distinct max_step_tokens
+        value is one mixed-step signature; hold it fixed in production."""
         self._assert_idle("set_chunking")
         if prefill_chunk is None:
             raise ValueError(_NO_UNCHUNKED)
@@ -2638,6 +2659,7 @@ class ServingEngine:
                 "n_prefix_hits", "n_prefix_misses",
                 "prefill_tokens_saved", "n_restore_hits",
                 "restore_tokens_saved", "n_prefill_chunks",
+                "n_chunk_rows", "n_chunk_extra_rows", "n_step_pad_rows",
                 "n_mixed_steps", "n_spec_steps", "n_spec_chains",
                 "n_spec_drafted", "n_spec_accepted", "n_spec_tokens",
                 "n_scan_steps", "n_scan_flushes", "n_draft_steps")},

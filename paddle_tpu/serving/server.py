@@ -421,6 +421,15 @@ class ServingServer:
                 # splice straight into the frame)
                 ("serving_prefill_chunks_total", "counter", None,
                  float(eng.n_prefill_chunks)),
+                # how a step's rows were shared out: prompt rows packed,
+                # those given past a slot's prefill_chunk share, and rows
+                # of a mixed or verify step that carried nothing
+                ("serving_chunk_rows_total", "counter", None,
+                 float(eng.n_chunk_rows)),
+                ("serving_chunk_extra_rows_total", "counter", None,
+                 float(eng.n_chunk_extra_rows)),
+                ("serving_step_pad_rows_total", "counter", None,
+                 float(eng.n_step_pad_rows)),
                 ("serving_mixed_steps_total", "counter", None,
                  float(eng.n_mixed_steps)),
                 # one step in flight: steps launched beside a pending

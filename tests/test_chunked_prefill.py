@@ -315,11 +315,14 @@ def test_set_chunking_validates_and_toggles(tr):
         "a refused set_chunking must leave the engine as it was"
     prompt = rng.integers(2, 23, 9).astype(np.int32)
     wide = eng.run([Request("r", prompt.copy(), max_new=5)])["r"]
-    assert eng.n_prefill_chunks == 1
+    assert eng.n_prefill_chunks == 1                # 9 rows of a step's 18
     eng.set_chunking(4)
     assert eng.prefill_chunk == 4 and eng.max_step_tokens == 6
     narrow = eng.run([Request("r", prompt.copy(), max_new=5)])["r"]
-    assert eng.n_prefill_chunks == 1 + 3
+    # a lone prompt takes the step's free rows, its share of 4 and the 2
+    # beside it: 6 + 3 rows, where a chunk capped at 4 made ceil(9 / 4)
+    assert eng.n_prefill_chunks == 1 + 2
+    assert eng.n_chunk_rows == 9 + 9 and eng.n_chunk_extra_rows == 2
     np.testing.assert_array_equal(wide, narrow)
 
 
@@ -377,7 +380,12 @@ def test_chunk_boundary_lengths_exact_cold_and_on_a_prefix_hit(tr, length):
     res = eng.run([cold])
     _assert_exact(tr, [cold], res)
     assert eng.n_prefix_hits == 0
-    assert eng.n_prefill_chunks == -(-p // chunk)
+    # nothing else runs, so the step's 10 rows (chunk + 2 slots) are all
+    # free: ceil(p / 10) runs, the first longer than `prefill_chunk`
+    assert eng.n_prefill_chunks == -(-p // eng.max_step_tokens)
+    assert eng.n_chunk_rows == p
+    assert eng.n_chunk_extra_rows == sum(
+        max(0, min(10, p - at) - chunk) for at in range(0, p, 10))
     chunks0 = eng.n_prefill_chunks
     warm = Request("warm", prompt.copy(), max_new=6, temperature=0.8,
                    top_k=5, rng=jax.random.PRNGKey(p))
@@ -390,6 +398,174 @@ def test_chunk_boundary_lengths_exact_cold_and_on_a_prefix_hit(tr, length):
     assert eng.n_prefix_hits == 1 and eng.prefill_tokens_saved == p - 1
     assert eng.n_prefill_chunks - chunks0 == 1
     assert eng.kv.n_cow == (1 if (p - 1) % ps else 0)
+    _assert_sigs(eng)
+    eng.kv.check_reclaimed()
+
+
+# ---------------------------------------------------------------------------
+# the share-out: prefill_chunk is a filling slot's share, the step's free
+# rows go to the oldest prompt
+# ---------------------------------------------------------------------------
+
+def _spy_packing(eng, monkeypatch):
+    """Record every `_pack_chunk_rows` call: (first row, budget, the runs
+    as (slot, start, rows, prompt length), the packed rows' slots)."""
+    calls = []
+    inner = eng._pack_chunk_rows
+
+    def spy(filling, row_ids, row_slot, row_pos, sample_row, adv, emit, r,
+            budget):
+        advanced, r1 = inner(filling, row_ids, row_slot, row_pos,
+                             sample_row, adv, emit, r, budget)
+        runs, at = [], r
+        for s, n, _ in advanced:
+            runs.append((s, int(row_pos[at]), n,
+                         int(eng.slots[s].req.prompt_ids.size)))
+            at += n
+        calls.append((r, budget, runs, row_slot[r:r1].copy()))
+        return advanced, r1
+
+    monkeypatch.setattr(eng, "_pack_chunk_rows", spy)
+    return calls
+
+
+@pytest.mark.parametrize("decoding", [False, True],
+                         ids=["alone", "beside-a-decode-row"])
+def test_a_lone_prompt_takes_the_steps_free_rows(tr, decoding, monkeypatch):
+    """One filling slot gets min(rest of its prompt, the step's free
+    rows) a step — its share of 4 and every row no one else wants: 25
+    prompt tokens go in 12 + 12 + 1 (11 + 11 + 3 beside one decode row)
+    where a chunk capped at 4 took 7 steps; the counters say how many rows
+    were given past the share and how many went empty."""
+    rng = np.random.default_rng(21)
+    eng = ServingEngine(tr.executor, tr.params, num_slots=3, page_size=4,
+                        max_context=48, prefill_chunk=4, max_step_tokens=12,
+                        prefix_cache=False)
+    reqs = []
+    if decoding:
+        reqs.append(Request("short", rng.integers(2, 23, 3).astype(np.int32),
+                            max_new=16))
+        eng.add_request(reqs[0])
+        eng.step()                   # short: its one chunk, token 0
+        eng.step()                   # short decodes alone
+    rows0, extra0, pad0, mixed0 = (eng.n_chunk_rows, eng.n_chunk_extra_rows,
+                                   eng.n_step_pad_rows, eng.n_mixed_steps)
+    calls = _spy_packing(eng, monkeypatch)
+    long_ = Request("long", rng.integers(2, 23, 25).astype(np.int32),
+                    max_new=3)
+    reqs.append(long_)
+    eng.add_request(long_)
+    for _ in range(3):
+        eng.step()
+    free = 11 if decoding else 12
+    want = [free, free, 25 - 2 * free]
+    assert [[n for _, _, n, _ in runs] for _, _, runs, _ in calls] == \
+        [[n] for n in want]
+    assert [b for _, b, _, _ in calls] == [free] * 3
+    assert eng.n_mixed_steps - mixed0 == 3
+    assert eng.n_chunk_rows - rows0 == 25
+    assert eng.n_chunk_extra_rows - extra0 == 2 * (free - 4)
+    # the last step alone had rows to spare
+    assert eng.n_step_pad_rows - pad0 == free - want[-1]
+    results = dict(eng.results)
+    results.update(eng.run())
+    assert len(calls) == 3
+    _assert_exact(tr, reqs, results)
+    _assert_sigs(eng)
+
+
+def test_two_filling_slots_get_their_share_and_the_older_the_rest(
+        tr, monkeypatch):
+    """Two prompts of 20 admitted together under a step of 16 rows and a
+    share of 4: both get 4 first, the 8 rows left go to the OLDER (12 + 4);
+    then the older's last 8 and the younger's 4 + 4; then the younger's
+    last 8 beside the older's decode row.  Each slot's rows are one
+    contiguous run, the older's first."""
+    rng = np.random.default_rng(22)
+    eng = ServingEngine(tr.executor, tr.params, num_slots=3, page_size=4,
+                        max_context=32, prefill_chunk=4, max_step_tokens=16,
+                        prefix_cache=False)
+    calls = _spy_packing(eng, monkeypatch)
+    a = Request("a", rng.integers(2, 23, 20).astype(np.int32), max_new=4)
+    b = Request("b", rng.integers(2, 23, 20).astype(np.int32), max_new=4)
+    results = eng.run([a, b])
+    sa, sb = 0, 1                    # admit order = slot order here
+    assert [[run[:3] for run in runs] for _, _, runs, _ in calls] == [
+        [(sa, 0, 12), (sb, 0, 4)],
+        [(sa, 12, 8), (sb, 4, 8)],
+        [(sb, 12, 8)]]
+    assert calls[2][:2] == (1, 15)   # behind a's decode row
+    for _, _, runs, slots in calls:
+        assert slots.tolist() == [s for s, _, n, _ in runs for _ in range(n)]
+    assert eng.n_prefill_chunks == 5 and eng.n_chunk_rows == 40
+    assert eng.n_chunk_extra_rows == 8 + (4 + 4) + 4
+    _assert_exact(tr, [a, b], results)
+    _assert_sigs(eng)
+
+
+@pytest.mark.parametrize("chunk,budget,slots", [(4, 7, 3), (4, 16, 3),
+                                                (8, 24, 4), (3, 5, 4)])
+def test_no_step_packs_more_than_its_budget_and_a_slot_is_one_run(
+        tr, chunk, budget, slots, monkeypatch):
+    """Whatever the share and the step: the chunk rows end inside the step
+    and inside the budget they were given, every slot appears as ONE
+    contiguous run a step (the recurrent layers' packing contract), no
+    slot gets less than its share while it has prompt left, a row goes
+    empty only when no prompt is left to fill it — and the tokens are the
+    oracle's."""
+    rng = np.random.default_rng(chunk * 100 + budget)
+    reqs = [Request(f"r{i}", rng.integers(2, 23, n).astype(np.int32),
+                    max_new=5, rng=jax.random.PRNGKey(60 + i))
+            for i, n in enumerate((13, 3, 22, 9, 17, 30, 6))]
+    eng = ServingEngine(tr.executor, tr.params, num_slots=slots,
+                        page_size=4, max_context=40, prefill_chunk=chunk,
+                        max_step_tokens=budget, prefix_cache=False)
+    calls = _spy_packing(eng, monkeypatch)
+    results = eng.run(reqs)
+    assert calls and any(n > chunk for _, _, runs, _ in calls
+                         for _, _, n, _ in runs)
+    for r0, b, runs, row_slots in calls:
+        n_rows = sum(n for _, _, n, _ in runs)
+        assert n_rows <= b and r0 + n_rows <= budget
+        assert row_slots.size == n_rows
+        changes = 1 + int(np.count_nonzero(np.diff(row_slots)))
+        assert changes == len(runs) == len({s for s, _, _, _ in runs})
+        left = b
+        for s, start, n, p in runs:
+            assert n >= min(chunk, left, p - start)
+            left -= n
+        if n_rows < b:
+            assert all(start + n == p for _, start, n, p in runs)
+    assert sum(n for _, _, runs, _ in calls for _, _, n, _ in runs) == \
+        sum(r.prompt_ids.size for r in reqs) == eng.n_chunk_rows
+    _assert_exact(tr, reqs, results)
+    _assert_sigs(eng)
+
+
+def test_a_run_longer_than_the_share_is_exact_on_a_prefix_hit(tr):
+    """A prefix hit whose uncached suffix is LONGER than `prefill_chunk`:
+    the suffix starts mid-page on the copy reservation made and goes in
+    one run of 14 rows (share 4, 16 rows free) — the tokens are the cold
+    oracle's, and so is an exact repeat of the donor afterwards."""
+    rng = np.random.default_rng(23)
+    base = rng.integers(2, 23, 13).astype(np.int32)
+    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
+                        max_context=40, prefill_chunk=4, max_step_tokens=16)
+    a = Request("a", base.copy(), max_new=5)
+    results = eng.run([a])
+    assert eng.n_prefill_chunks == 1 and eng.n_chunk_extra_rows == 9
+    chunks0, rows0 = eng.n_prefill_chunks, eng.n_chunk_rows
+    b = Request("b", np.concatenate(
+        [base[:10], rng.integers(2, 23, 14)]).astype(np.int32), max_new=5,
+        temperature=0.8, top_k=5, rng=jax.random.PRNGKey(9))
+    results.update(eng.run([b]))
+    assert eng.n_prefix_hits == 1 and eng.prefill_tokens_saved == 10
+    assert eng.kv.n_cow == 1                    # 10 % 4: mid-page
+    assert eng.n_prefill_chunks - chunks0 == 1
+    assert eng.n_chunk_rows - rows0 == 14
+    c = Request("c", base.copy(), max_new=5)
+    results.update(eng.run([c]))
+    _assert_exact(tr, [a, b, c], results)
     _assert_sigs(eng)
     eng.kv.check_reclaimed()
 

@@ -372,15 +372,18 @@ def _check_against_lm_generate(ex, w, reqs, results):
             results[r.req_id])
 
 
-@pytest.mark.parametrize("chunk,kernel,k", [(5, False, 1), (5, True, 1),
-                                            (32, False, 1), (5, False, 4)],
-                         ids=["chunked-jnp", "chunked-kernel", "one-chunk",
-                              "scanned-k4"])
+@pytest.mark.parametrize("chunk,kernel,k,mst", [
+    (5, False, 1, None), (5, True, 1, None), (32, False, 1, None),
+    (5, False, 4, None), (5, False, 1, 34)],
+    ids=["chunked-jnp", "chunked-kernel", "one-chunk", "scanned-k4",
+         "free-rows"])
 def test_engine_greedy_tokens_match_lm_generate(model, chunk, kernel, k,
-                                                monkeypatch):
+                                                mst, monkeypatch):
     """Greedy tokens of the engine — chunked prefill through mixed steps,
     slots re-admitted after other requests, the scanned step (k = 4 bodies
-    a dispatch = four single steps) — are lm_generate's."""
+    a dispatch = four single steps), a step with free rows for a whole
+    prompt (32 chunk rows: a run of 26 tokens where the share is 5, one
+    segment of `kda.segment_rows`) — are lm_generate's."""
     import jax
     from paddle_tpu.serving import ServingEngine
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1" if kernel else "0")
@@ -390,11 +393,16 @@ def test_engine_greedy_tokens_match_lm_generate(model, chunk, kernel, k,
     reqs = _requests((3, 19, 9, 17, 26))
     with jax.default_matmul_precision("highest"):
         eng = ServingEngine(ex, w, num_slots=2, page_size=4, max_context=48,
-                            prefill_chunk=chunk, decode_steps=k)
+                            prefill_chunk=chunk, decode_steps=k,
+                            max_step_tokens=mst)
         assert eng.prefix is None
         results = eng.run(reqs)
         _check_against_lm_generate(ex, w, reqs, results)
     eng.kv.check_reclaimed()
+    if mst:
+        # every prompt went in one run: 51 of the 74 rows past a share of 5
+        assert eng.n_prefill_chunks == 5 and eng.n_chunk_rows == 74
+        assert eng.n_chunk_extra_rows == 14 + 4 + 12 + 21
     if k > 1:
         assert eng.n_scan_flushes > 0
     # the recurrent counters came back with the tokens: every counted step,

@@ -37,15 +37,18 @@ def _margin(ref, cfg, w, reqs, results):
     return served_margin(jax, ref, cfg, w, served, 48)
 
 
-@pytest.mark.parametrize("chunk,kernel,k", [(5, False, 1), (5, True, 1),
-                                            (32, False, 1), (5, False, 2)],
-                         ids=["chunked-jnp", "chunked-kernel", "one-chunk",
-                              "decode-steps-2"])
+@pytest.mark.parametrize("chunk,kernel,k,mst", [
+    (5, False, 1, None), (5, True, 1, None), (32, False, 1, None),
+    (5, False, 2, None), (5, False, 1, 34)],
+    ids=["chunked-jnp", "chunked-kernel", "one-chunk", "decode-steps-2",
+         "free-rows"])
 def test_engine_prefill_in_chunks_then_decode_against_the_reference(
-        model, ref, chunk, kernel, k, monkeypatch):
+        model, ref, chunk, kernel, k, mst, monkeypatch):
     """A real ServingEngine — chunked prefill through mixed steps, slots
     re-admitted after other requests, the state through the interpreted
-    `ssd_step`, the scanned step (--decode-steps 2): every served token is
+    `ssd_step`, the scanned step (--decode-steps 2), a step with free rows
+    for a whole prompt (32 chunk rows: a run of 26 tokens where the share
+    is 5, one segment of `ssd.segment_rows`): every served token is
     the argmax of the reference's ONE full forward over prompt + served
     tokens to within the logits' tolerance, and the tokens are
     lm_generate's whole-sequence ones."""
@@ -59,7 +62,8 @@ def test_engine_prefill_in_chunks_then_decode_against_the_reference(
     reqs = _requests((3, 19, 9, 17, 26))
     with jax.default_matmul_precision("highest"):
         eng = ServingEngine(ex, w, num_slots=2, page_size=4, max_context=48,
-                            prefill_chunk=chunk, decode_steps=k)
+                            prefill_chunk=chunk, decode_steps=k,
+                            max_step_tokens=mst)
         assert eng.prefix is None
         results = eng.run(reqs)
         for r in reqs:
@@ -71,6 +75,10 @@ def test_engine_prefill_in_chunks_then_decode_against_the_reference(
     m = _margin(ref, cfg, w, reqs, results)
     assert m["worst_nats"] < TOL and m["tokens"] == 30, m
     eng.kv.check_reclaimed()
+    if mst:
+        # every prompt went in one run: 51 of the 74 rows past a share of 5
+        assert eng.n_prefill_chunks == 5 and eng.n_chunk_rows == 74
+        assert eng.n_chunk_extra_rows == 14 + 4 + 12 + 21
     if k > 1:
         assert eng.n_scan_flushes > 0
     # the recurrent counters are fed by this kind too: every counted step,

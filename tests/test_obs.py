@@ -431,6 +431,39 @@ def test_request_lifecycle_phases_incl_preempt_replay(lifecycle_tracer):
         assert i > ph.index("preempt")
 
 
+@pytest.fixture(scope="module")
+def shared_out_server():
+    """A prompt of 21 served under a share of 4 and a step of 12: runs of
+    12 and 9, 13 rows past the share, 3 rows of the second step empty."""
+    from paddle_tpu.config.parser import parse_config
+    from paddle_tpu.serving import Request, ServingEngine
+    from paddle_tpu.serving.server import ServingServer
+    from paddle_tpu.trainer.trainer import Trainer
+
+    cfg = parse_config("demo/model_zoo/transformer_lm.py",
+                       "vocab=31,dim=16,layers=1,heads=2,batch_size=4")
+    tr = Trainer(cfg, seed=7)
+    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
+                        max_context=32, prefill_chunk=4, max_step_tokens=12)
+    eng.run([Request("r", np.arange(2, 23, dtype=np.int32), max_new=1)])
+    return eng, ServingServer(eng).metrics.render()
+
+
+@pytest.mark.parametrize("name,attr,want", [
+    ("serving_chunk_rows_total", "n_chunk_rows", 21),
+    ("serving_chunk_extra_rows_total", "n_chunk_extra_rows", 8 + 5),
+    ("serving_step_pad_rows_total", "n_step_pad_rows", 3)])
+def test_share_out_counters_reach_the_metrics_frame(shared_out_server, name,
+                                                    attr, want):
+    """How a step's rows were shared out, as the engine counts it and as
+    the server's collector renders it under a catalogued name."""
+    eng, text = shared_out_server
+    assert name in CATALOG
+    assert getattr(eng, attr) == want
+    assert f"# HELP {name} " in text and f"# TYPE {name} counter" in text
+    assert f"\n{name} {want}" in text
+
+
 def test_cancel_and_deadline_terminal_phases(lifecycle_tracer):
     """Aborted requests close their open phase and mark the right
     terminal event: cancelled (client abort while decoding) and deadline

@@ -266,6 +266,68 @@ def test_spec_chains_coexist_with_prefill_chunks_under_budget(tr):
     _assert_sigs(eng)
 
 
+def test_verify_step_reserves_the_shares_and_chunks_take_no_draft_row(
+        tr, monkeypatch):
+    """The verify step's budget, in order: the decode base rows, the chunk
+    RESERVE — each filling slot's share of `prefill_chunk`, what it was
+    before a chunk could take free rows —, then the drafts, and the chunks
+    get their reserve plus what the drafts LEFT.  A drafter that always
+    proposes k = 2 beside a prompt of 25 under 8 step rows: every verify
+    step reserves 4, verifies both drafts (a chunk that took the step's
+    free rows first would have left them 0), and the chunk run is 4 + the
+    one row no draft wanted."""
+    class Two:
+        def propose(self, ctx, k):
+            return np.full(k, 3, np.int32)
+
+    rng = np.random.default_rng(12)
+    short = Request("short", rng.integers(2, 23, 4).astype(np.int32),
+                    max_new=24)
+    long_ = Request("long", rng.integers(2, 23, 25).astype(np.int32),
+                    max_new=4)
+    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
+                        max_context=32, prefill_chunk=4, max_step_tokens=8,
+                        spec_k=2, drafter=Two(), prefix_cache=False)
+    eng.add_request(short)
+    eng.step()                        # short: final chunk + token 0
+    eng.step()                        # short decodes (a verify step)
+    reserves, packs = [], []
+    inner_shares, inner_pack = eng._chunk_shares, eng._pack_chunk_rows
+
+    def shares(filling, budget):
+        out = inner_shares(filling, budget)
+        reserves.append((budget, [n for _, n in out]))
+        return out
+
+    def pack(filling, *a):
+        advanced, r1 = inner_pack(filling, *a)
+        packs.append((a[-1], [n for _, n, _ in advanced]))
+        return advanced, r1
+
+    monkeypatch.setattr(eng, "_chunk_shares", shares)
+    monkeypatch.setattr(eng, "_pack_chunk_rows", pack)
+    eng.add_request(long_)
+    done = 0
+    while done < 25:
+        drafted0 = eng.n_spec_drafted
+        reserves.clear()
+        packs.clear()
+        eng.step()
+        rest = 25 - done
+        # the reserve is computed first, from the rows the decoders left:
+        # one share, whatever the step has free
+        assert reserves[0] == (7, [min(rest, 4)])
+        assert eng.n_spec_drafted - drafted0 == 2, \
+            "a draft row was taken by the chunk's share-out"
+        # the chunks then get T - r = 8 - (1 base + 2 drafts) rows
+        assert packs == [(5, [min(rest, 5)])]
+        done += packs[0][1][0]
+    results = dict(eng.results)
+    results.update(eng.run())
+    _assert_exact(tr, [short, long_], results)
+    _assert_sigs(eng)
+
+
 def test_spec_preempt_replay_with_drafts_in_flight_stays_exact(tr):
     """Preempt/replay under an overcommitted pool with speculation on:
     victims roll back (their chain tails uncommitted), replay through

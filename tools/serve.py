@@ -297,8 +297,9 @@ def main(argv=None) -> int:
                          "cold cached pages spill instead of evicting and "
                          "restore on prefix hits (docs/serving.md)")
     ap.add_argument("--prefill-chunk", type=int, default=0,
-                    help="chunked-prefill chunk size in tokens "
-                         "(0 = engine default 4*page_size)")
+                    help="a filling prompt's share of a mixed step, in "
+                         "tokens; a step's free rows go to the oldest "
+                         "prompt on top (0 = engine default 4*page_size)")
     ap.add_argument("--max-step-tokens", type=int, default=0,
                     help="per-step token budget for mixed prefill/decode "
                          "steps (0 = prefill_chunk + slots)")
