@@ -1401,17 +1401,20 @@ class ParameterServer:
                        "error": "server not initialized — ps_init first"})
             return
         target = int(msg.get("pass_id", 0))
-        if self.engine.pass_id >= target:
-            conn.send({"type": "barrier", "kind": "pass",
-                       "pass_id": self.engine.pass_id,
+        # ONE read: the update thread moves pass_id while a relay's job is
+        # in flight, so a second read can see the pass the first one did
+        # not.  A relay that read `target - 1` then is answered by that
+        # job's `done`, which runs on this thread after it.
+        at = self.engine.pass_id
+        if at >= target:
+            conn.send({"type": "barrier", "kind": "pass", "pass_id": at,
                        "window": self._next_window})
             return
-        if self.engine.pass_id != target - 1:
+        if at != target - 1:
             conn.send({"type": "error", "op": "barrier",
                        "error": f"pass relay for {target} but this shard "
-                                f"is at pass {self.engine.pass_id} — a "
-                                f"boundary was skipped (restarted "
-                                f"shard?)"})
+                                f"is at pass {at} — a boundary was skipped "
+                                f"(restarted shard?)"})
             return
         self._pass_relay_waiters.append(conn)
         tr = wire.get_trace(msg)

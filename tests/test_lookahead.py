@@ -7,7 +7,6 @@ contract here: the two depths bank the SAME tokens, finish reasons, counts
 and prefix-cache donations — the host merely learns them one call later —
 and both match `lm_generate` run on each request alone."""
 
-import importlib
 import os
 import time
 
@@ -231,9 +230,10 @@ def test_recurrent_and_moe_models_ride_the_same_tokens(family):
     state of a slot whose next admission starts at position 0, which
     resets it; the counts come back with every landed step."""
     from benchmark.lib.spec import Benchmark
-    mod = importlib.import_module(f"tests.test_{family}")
-    cfg = mod._cfg()
-    ex = mod._build(cfg)
+    from tests import model_parity as parity
+    case = parity.CASES[family]
+    cfg = parity.cfg(case)
+    ex = parity.build(case, cfg)
     w = Benchmark(ROOT).reference(family).make_weights(cfg, 7)
 
     def reqs(eos=-1):

@@ -849,3 +849,28 @@ def test_wedged_update_thread_stale_ok_one_bundle_per_episode(tmp_path):
         fr.enabled = was_enabled
         for s in srvs:
             s.stop_background(drain=False)
+
+
+def test_pass_relay_reads_the_pass_once_while_its_job_is_in_flight():
+    """Two trainers' pass relays reach a non-coordinator shard a moment
+    apart: the second lands while the first's finish_pass job runs on the
+    update thread, which moves `pass_id` between the handler's statements
+    (tests/test_train_dist_trace.py's K=2 x 2-shard run hit it once in a
+    dozen runs on a loaded machine).  ONE read decides: a relay that saw
+    the pass before the boundary waits for the job's `done`; it is never
+    told that a boundary was skipped."""
+    import types
+
+    srv = ParameterServer(port=0, shard_index=1, n_shards=2)
+    reads = iter([0, 1, 1, 1])
+    srv.engine = type("Moving", (), {
+        "pass_id": property(lambda self: next(reads))})()
+    srv._pass_relaying = True           # the first relay's job is running
+    sent = []
+    conn = types.SimpleNamespace(send=sent.append)
+    srv._handle_pass_relay(conn, {"type": "pass_relay", "pass_id": 1})
+    assert sent == [] and srv._pass_relay_waiters == [conn]
+    # and a relay that arrives after the job is told the pass it reached
+    srv._handle_pass_relay(conn, {"type": "pass_relay", "pass_id": 1})
+    assert [m["type"] for m in sent] == ["barrier"]
+    assert sent[0]["pass_id"] == 1
