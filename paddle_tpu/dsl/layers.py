@@ -981,6 +981,7 @@ def multi_head_attention_layer(
     qk_norm: bool = False,
     rms_eps: float = 1e-6,
     out_size: Optional[int] = None,
+    out_gate: bool = False,
     name: Optional[str] = None,
     param_attr: Optional[Union[ParameterAttribute, list]] = None,
     bias_attr=False,
@@ -1006,9 +1007,15 @@ def multi_head_attention_layer(
     what the output projection gives back, `size` unless the heads' width
     is not the model's (32 heads of 128 beside a hidden size of 2,688).
 
+    out_gate: an elementwise sigmoid gate from the layer's input in front of
+    the output projection, y = (attn * sigmoid(x w_g)) w_o with w_g
+    [query.size, size] — the LAST parameter (4, or 6 behind qk_norm's two),
+    in every path (ops/attention.py:project_out).  Self-attention only.
+
     param_attr: one attribute applied to all four projections (q/k/v/out), or
-    a list of four.  A single NAMED attribute would tie all projections to
-    one parameter, which is never what you want — pass a list instead."""
+    a list of four (five with out_gate: q/k/v/out/gate).  A single NAMED
+    attribute would tie all projections to one parameter, which is never
+    what you want — pass a list instead."""
     key = key if key is not None else query
     value = value if value is not None else key
     assert size % num_heads == 0, "size must divide evenly into heads"
@@ -1025,14 +1032,19 @@ def multi_head_attention_layer(
         "use_rope requires self-attention: rotating decoder queries and " \
         "unrelated encoder keys by their own arange positions imposes a " \
         "spurious relative-position bias in cross-attention"
+    assert not out_gate or key is query, \
+        "out_gate is computed from the layer's input: self-attention only"
+    n_attrs = 5 if out_gate else 4
     if isinstance(param_attr, ParameterAttribute):
         assert not param_attr.name, \
             "a single named param_attr would share ONE matrix across the " \
             "q/k/v/out projections; pass a list of 4 ParameterAttributes"
-        attrs = [param_attr] * 4
+        attrs = [param_attr] * n_attrs
     else:
-        attrs = list(param_attr) if param_attr else [None] * 4
-        assert len(attrs) == 4, "param_attr list must have 4 entries (q,k,v,out)"
+        attrs = list(param_attr) if param_attr else [None] * n_attrs
+        assert len(attrs) == n_attrs, \
+            f"param_attr list must have {n_attrs} entries (q,k,v,out" \
+            f"{',gate' if out_gate else ''})"
     name = _name(name, "mha_layer")
     cfg = LayerConfig(name=name, type="multi_head_attention", size=size,
                       active_type="")
@@ -1068,6 +1080,12 @@ def multi_head_attention_layer(
                 ParameterAttribute(initial_mean=1.0, initial_std=0.0))
             cfg.inputs.append(LayerInput(input_layer_name=query.name,
                                          input_parameter_name=pname))
+    if out_gate:
+        cfg.attrs["out_gate"] = len(cfg.inputs)   # the gate's parameter index
+        pname = _make_param(name, len(cfg.inputs), [query.size, size],
+                            attrs[4])
+        cfg.inputs.append(LayerInput(input_layer_name=query.name,
+                                     input_parameter_name=pname))
     assert out_size in (None, size) or bias_attr is False, \
         "the output bias is `size` wide: none with an out_size of its own"
     cfg.bias_parameter_name = _bias_name(name, bias_attr, [1, size])
@@ -1234,6 +1252,7 @@ def kda_attention_layer(
     conv_size: int = 4,
     size: Optional[int] = None,
     rms_eps: float = 1e-5,
+    allow_neg_eigval: bool = False,
     attn_impl: Optional[str] = None,
     name: Optional[str] = None,
     param_attr: Optional[ParameterAttribute] = None,
@@ -1244,7 +1263,11 @@ def kda_attention_layer(
     recurrent state [head_dim, head_dim] a head, moved by a gated delta
     rule with a per-channel decay — q, k and v through a depthwise causal
     convolution of `conv_size` taps and SiLU, q and k l2-normed a head, the
-    decay and the output gate through rank-`head_dim` projections, a gated RMSNorm a head in front of the output projection.
+    decay and the output gate through rank-`head_dim` projections, a gated
+    RMSNorm a head in front of the output projection.  `allow_neg_eigval`
+    makes the write strength beta = 2 sigmoid(x w_b) in (0, 2) where it is
+    sigmoid(x w_b) in (0, 1): a transition I - beta k k^T then has an
+    eigenvalue in (-1, 1).
     `param_attr` initializes the matrices and the convolutions; A_log starts
     uniform in [0, log 16] and dt_bias in softplus^-1 of [1e-3, 1e-1] (the
     ranges of the published initializers), the norm's scale at 1."""
@@ -1258,6 +1281,8 @@ def kda_attention_layer(
                       active_type="")
     cfg.attrs.update(num_heads=H, head_dim=dk, conv_size=conv_size,
                      rms_eps=rms_eps, causal=True)
+    if allow_neg_eigval:
+        cfg.attrs["allow_neg_eigval"] = True
     if attn_impl is not None:
         cfg.attrs["attn_impl"] = attn_impl
     specs = [

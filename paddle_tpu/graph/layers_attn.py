@@ -31,6 +31,7 @@ from paddle_tpu.ops.attention import (
     blockwise_attention,
     dot_product_attention,
     multi_head_attention,
+    project_out,
 )
 from paddle_tpu.parameter.argument import Argument
 
@@ -53,6 +54,21 @@ def _project(ctx: ForwardContext, cfg: LayerConfig) -> dict:
         kw["qk_norm"] = (ctx.param_of(cfg, 4), ctx.param_of(cfg, 5),
                          float(a.get("rms_eps", 1e-6)))
     return kw
+
+
+def _gate(ctx: ForwardContext, cfg: LayerConfig):
+    """The output gate's matrix where the layer has one (`out_gate`: its
+    parameter's index), else None."""
+    gate = cfg.attrs.get("out_gate")
+    return None if gate is None else ctx.param_of(cfg, int(gate))
+
+
+def _out(ctx: ForwardContext, cfg: LayerConfig, o, x):
+    """The end of the three cached paths (ops/attention.py:project_out, as
+    the whole-sequence path's): the output projection and bias, behind the
+    sigmoid gate where the layer has one."""
+    return project_out(o, x, ctx.param_of(cfg, 3), ctx.bias_of(cfg),
+                       _gate(ctx, cfg))
 
 
 def _flash_blocks(cfg: LayerConfig) -> dict:
@@ -86,17 +102,15 @@ def multi_head_attention_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argumen
         # addressing its own table row at its own position
         assert causal, f"layer {cfg.name!r}: paged decode requires causal"
         if "row_slot" in cache:
-            return _paged_ragged_step(ctx, cfg, q_arg, w_q, w_k, w_v, w_o,
-                                      num_heads, cache)
-        return _paged_step(ctx, cfg, q_arg, w_q, w_k, w_v, w_o, num_heads,
-                           cache)
+            return _paged_ragged_step(ctx, cfg, q_arg, w_q, w_k, w_v, num_heads,
+                                      cache)
+        return _paged_step(ctx, cfg, q_arg, w_q, w_k, w_v, num_heads, cache)
     if isinstance(cache, dict) and "k" in cache:
         # incremental decode against a KV cache (lm_decode use_cache path):
         # the input carries only NEW tokens; per-row positions come from the
         # cache, so caches ride the same state threading as BN moving stats
         assert causal, f"layer {cfg.name!r}: KV-cache decode requires causal"
-        return _cached_step(ctx, cfg, q_arg, w_q, w_k, w_v, w_o, num_heads,
-                            cache)
+        return _cached_step(ctx, cfg, q_arg, w_q, w_k, w_v, num_heads, cache)
 
     q_valid = q_arg.mask()
     k_valid = k_arg.mask()
@@ -158,13 +172,13 @@ def multi_head_attention_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argumen
         bias_o=ctx.bias_of(cfg), attn_fn=attn_fn,
         window=(int(cfg.attrs["window"])
                 if "window" in cfg.attrs else None),
+        w_g=_gate(ctx, cfg),
         **_project(ctx, cfg))
     return finish_layer(ctx, cfg, out, like=q_arg)
 
 
 def _cached_step(ctx: ForwardContext, cfg: LayerConfig, x_arg: Argument,
-                 w_q, w_k, w_v, w_o, num_heads: int,
-                 cache: dict) -> Argument:
+                 w_q, w_k, w_v, num_heads: int, cache: dict) -> Argument:
     """One incremental self-attention call: project the new tokens, fold
     them into this layer's KV cache, attend causally on global positions.
     Emits the updated cache through ctx.state_out."""
@@ -180,7 +194,6 @@ def _cached_step(ctx: ForwardContext, cfg: LayerConfig, x_arg: Argument,
 
     x = x_arg.value                                   # [B, Tn, model_dim]
     B, Tn, _ = x.shape
-    model_dim = w_q.shape[1]
     pos = cache["pos"]
     qpos = pos[:, None] + jnp.arange(Tn)[None, :]
     q, k, v = project_qkv(x, x, x, w_q, w_k, w_v, num_heads, q_pos=qpos,
@@ -238,16 +251,11 @@ def _cached_step(ctx: ForwardContext, cfg: LayerConfig, x_arg: Argument,
         out, ck, cv, newpos = cached_attention_step(
             q, k, v, cache["k"], cache["v"], pos, n_new, window=window)
     ctx.state_out[cfg.name] = {"k": ck, "v": cv, "pos": newpos}
-    o = out.reshape(B, Tn, model_dim) @ w_o
-    bias = ctx.bias_of(cfg)
-    if bias is not None:
-        o = o + bias
-    return finish_layer(ctx, cfg, o, like=x_arg)
+    return finish_layer(ctx, cfg, _out(ctx, cfg, out, x), like=x_arg)
 
 
 def _paged_step(ctx: ForwardContext, cfg: LayerConfig, x_arg: Argument,
-                w_q, w_k, w_v, w_o, num_heads: int,
-                cache: dict) -> Argument:
+                w_q, w_k, w_v, num_heads: int, cache: dict) -> Argument:
     """One serving decode micro-step: project each slot's single new token,
     scatter its k/v into the slot's current page of the shared pool, attend
     causally over the slot's paged context (ops/attention.py:
@@ -259,11 +267,10 @@ def _paged_step(ctx: ForwardContext, cfg: LayerConfig, x_arg: Argument,
     from paddle_tpu.ops.attention import paged_attention_step, project_qkv
 
     x = x_arg.value                                   # [S, 1, model_dim]
-    S, Tn, _ = x.shape
+    Tn = x.shape[1]
     assert Tn == 1, (f"layer {cfg.name!r}: paged decode feeds exactly one "
                      f"new token per slot (got {Tn}); prompts prefill "
                      f"through the dense per-request cache")
-    model_dim = w_q.shape[1]
     pos = cache["pos"]
     qpos = pos[:, None]
     q, k, v = project_qkv(x, x, x, w_q, w_k, w_v, num_heads, q_pos=qpos,
@@ -280,15 +287,11 @@ def _paged_step(ctx: ForwardContext, cfg: LayerConfig, x_arg: Argument,
     ctx.state_out[cfg.name] = {"k_pages": ck, "v_pages": cv,
                                "page_table": cache["page_table"],
                                "pos": pos + 1}
-    o = out.reshape(S, 1, model_dim) @ w_o
-    bias = ctx.bias_of(cfg)
-    if bias is not None:
-        o = o + bias
-    return finish_layer(ctx, cfg, o, like=x_arg)
+    return finish_layer(ctx, cfg, _out(ctx, cfg, out, x), like=x_arg)
 
 
 def _paged_ragged_step(ctx: ForwardContext, cfg: LayerConfig, x_arg: Argument,
-                       w_q, w_k, w_v, w_o, num_heads: int,
+                       w_q, w_k, w_v, num_heads: int,
                        cache: dict) -> Argument:
     """One MIXED prefill/decode step against the paged pool: the input is
     a packed ragged token list [1, T, model_dim] where row r is one token
@@ -302,10 +305,9 @@ def _paged_ragged_step(ctx: ForwardContext, cfg: LayerConfig, x_arg: Argument,
                                           ragged_paged_attention_step)
 
     x = x_arg.value                                   # [1, T, model_dim]
-    B, T, _ = x.shape
+    B = x.shape[0]
     assert B == 1, (f"layer {cfg.name!r}: the mixed paged step packs all "
                     f"query rows into one ragged batch row (got B={B})")
-    model_dim = w_q.shape[1]
     row_pos = cache["row_pos"]                        # [T] global positions
     q, k, v = project_qkv(x, x, x, w_q, w_k, w_v, num_heads, q_pos=row_pos,
                           k_pos=row_pos, **_project(ctx, cfg))
@@ -321,11 +323,7 @@ def _paged_ragged_step(ctx: ForwardContext, cfg: LayerConfig, x_arg: Argument,
                                "page_table": cache["page_table"],
                                "row_slot": cache["row_slot"],
                                "row_pos": row_pos}
-    o = out.reshape(1, T, model_dim) @ w_o
-    bias = ctx.bias_of(cfg)
-    if bias is not None:
-        o = o + bias
-    return finish_layer(ctx, cfg, o, like=x_arg)
+    return finish_layer(ctx, cfg, _out(ctx, cfg, out, x), like=x_arg)
 
 
 @register_layer("additive_attention_step")

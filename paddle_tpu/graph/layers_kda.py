@@ -7,7 +7,8 @@ inputs (all the one data input): w_q [d, H*dk], w_k [d, H*dk], w_v
 w_fa [d, r], w_fb [r, H*dk] (the decay's low-rank projection), a_log
 [1, H], dt_bias [1, H*dk], w_b [d, H], w_ga [d, r], w_gb [r, H*dv] (the
 output gate), o_norm [1, dv], w_o [H*dv, size].
-attrs: num_heads, head_dim, conv_size, rms_eps, attn_impl.
+attrs: num_heads, head_dim, conv_size, rms_eps, attn_impl,
+allow_neg_eigval (beta = 2 sigmoid(x w_b) in (0, 2), else sigmoid in (0, 1)).
 
 Three paths, picked by the state the executor hands in, as the attention
 layers do (the dispatch, the run mask and the convolution's tail are
@@ -80,6 +81,8 @@ def kda_attention_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
         g = kda.decay(((x @ w_fa) @ w_fb).reshape(B, T, H, dk),
                       a_log.reshape(H), dt_bias.reshape(H, dk))
         beta = jax.nn.sigmoid((x @ w_b).astype(jnp.float32))    # [B, T, H]
+        if a.get("allow_neg_eigval"):
+            beta = 2.0 * beta
         gate = ((x @ w_ga) @ w_gb).reshape(B, T, H, dv)
     w_conv = jnp.concatenate([c_q, c_k, c_v], axis=-1).astype(xin.dtype)
 

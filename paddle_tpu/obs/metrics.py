@@ -100,6 +100,9 @@ CATALOG: dict[str, str] = {
         "compiled steps whose recurrent rows were counted",
     "serving_slot_state_bytes":
         "device bytes of the recurrent layers' slot-indexed state",
+    "serving_attn_gated_layers":
+        "attention layers whose result passes a sigmoid gate from the "
+        "layer's input in front of the output projection (dsl out_gate)",
     "serving_recurrent_tokens_total":
         "tokens the recurrent layers ran, one layer's worth a step (label "
         "kind: step = a decode row, one token a slot state; segment = a "

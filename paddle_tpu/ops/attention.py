@@ -368,6 +368,21 @@ def project_qkv(query: Array, key: Array, value: Array,
     return q, k, v
 
 
+def project_out(o: Array, x: Array, w_o: Array,
+                bias_o: Optional[Array] = None,
+                w_g: Optional[Array] = None) -> Array:
+    """What every attention path ends with: the heads' results o [..., H, D]
+    of inputs x [..., d] through the output projection.  With `w_g` [d, H*D]
+    an elementwise sigmoid gate from the layer's input comes first, y = (o *
+    sigmoid(x w_g)) w_o (gated attention, arXiv:2505.06708): it multiplies
+    whatever computed o, so no kernel knows of it."""
+    o = o.reshape(x.shape[:-1] + (w_o.shape[0],))
+    if w_g is not None:
+        o = o * jax.nn.sigmoid(x @ w_g).astype(o.dtype)
+    out = o @ w_o
+    return out if bias_o is None else out + bias_o
+
+
 def multi_head_attention(
     query: Array,                     # [B, Tq, Dq]
     key: Array,                       # [B, Tk, Dk]
@@ -384,6 +399,7 @@ def multi_head_attention(
     use_rope: bool = False,
     rope_theta: float = 10000.0,
     qk_norm: Optional[tuple] = None,
+    w_g: Optional[Array] = None,
 ) -> Array:
     """Projected multi-head attention; attn_fn pluggable (dense / blockwise /
     flash / a ring closure from parallel/context.py).
@@ -391,9 +407,9 @@ def multi_head_attention(
     num_kv_heads < num_heads gives grouped-query attention (w_k/w_v project
     to num_kv_heads * head_dim); window gives sliding-window attention;
     use_rope applies rotary position embeddings to q/k; qk_norm RMS-norms
-    each head of q and k first (`project_qkv`)."""
-    B, Tq, _ = query.shape
-    model_dim = w_q.shape[1]
+    each head of q and k first (`project_qkv`); w_g gates the result in
+    front of the output projection (`project_out`)."""
+    Tq = query.shape[1]
     q, k, v = project_qkv(query, key, value, w_q, w_k, w_v, num_heads,
                           num_kv_heads or num_heads, jnp.arange(Tq),
                           jnp.arange(key.shape[1]), use_rope, rope_theta,
@@ -401,10 +417,7 @@ def multi_head_attention(
     kw = {} if window is None else {"window": window}
     o = attn_fn(q, k, v, q_valid=q_valid, k_valid=k_valid, causal=causal,
                 **kw)
-    out = o.reshape(B, Tq, model_dim) @ w_o
-    if bias_o is not None:
-        out = out + bias_o
-    return out
+    return project_out(o, query, w_o, bias_o, w_g)
 
 
 def cached_attention_step(

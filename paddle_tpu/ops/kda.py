@@ -4,7 +4,10 @@ attention whose decay is per CHANNEL.  A head keeps a state S [dk, dv]:
     S_t = (I - b_t k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t v_t^T
     o_t = S_t^T (q_t * dk^-1/2)
 
-with a_t = exp(g_t) in (0, 1)^dk and b_t in (0, 1).  Written as a rank-1
+with a_t = exp(g_t) in (0, 1)^dk and b_t in (0, 1) — in (0, 2) where the
+layer asks for negative eigenvalues (`allow_neg_eigval`: with unit-norm k a
+transition I - b k k^T then has an eigenvalue 1 - b in (-1, 1), still a
+contraction; every form here takes b as data).  Written as a rank-1
 update: S' = Diag(a_t) S_{t-1}, u_t = b_t (v_t - S'^T k_t), S_t = S' + k_t
 u_t^T.  Everything here is float32 whatever the inputs' dtype, and the
 matmuls of the chunkwise form ask for full float32 precision (the TPU's

@@ -347,6 +347,12 @@ class ServingEngine:
         # (paged_kv.RECURRENT_REFUSALS: the mesh in _validate_tp above, the
         # spill budget by the cache itself)
         self._recurrent = list(self.kv.slot_specs)
+        # attention layers whose result passes a sigmoid gate in front of
+        # the output projection (dsl `out_gate`): a gauge, for a reader
+        # that holds a configuration's layer table to the program
+        self.attn_gated_layers = sum(
+            1 for l in executor.model.layers
+            if l.attrs.get("out_gate") is not None)
         if self._recurrent and prefix_cache:
             import logging
             logging.getLogger(__name__).info(

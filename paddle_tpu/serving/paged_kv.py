@@ -364,6 +364,14 @@ class PagedKVCache:
         return {name: self.pools[name] for name in self.layer_specs}
 
     @property
+    def bytes_by_part(self) -> dict:
+        """Device bytes (all shards) of every pool, `<layer>.<part>`: a
+        page-indexed layer's pools and a recurrent layer's slot-indexed
+        parts alike — what a configuration's residency table is held to."""
+        return {f"{name}.{part}": int(a.size) * a.dtype.itemsize
+                for name, p in self.pools.items() for part, a in p.items()}
+
+    @property
     def pool_bytes(self) -> int:
         """Total device bytes of the K/V page pools (all shards)."""
         return sum(int(a.size) * a.dtype.itemsize

@@ -458,6 +458,8 @@ class ServingServer:
                  float(eng.recurrent_steps)),
                 ("serving_slot_state_bytes", "gauge", None,
                  float(eng.kv.slot_state_bytes)),
+                ("serving_attn_gated_layers", "gauge", None,
+                 float(eng.attn_gated_layers)),
                 # tokens the recurrent layers ran as decode rows (`step`)
                 # and as prompt chunks' runs (`segment`), one layer's worth
                 *(("serving_recurrent_tokens_total", "counter",
@@ -1744,6 +1746,10 @@ class ServingServer:
             "tp_shards": eng.tp,
             "kv_pool_bytes_per_shard": int(eng.kv.pool_bytes_per_shard),
             "slot_state_bytes": int(eng.kv.slot_state_bytes),
+            # every pool's bytes, `<layer>.<part>`; attention layers whose
+            # result is gated in front of the output projection
+            "cache_bytes_by_part": eng.kv.bytes_by_part,
+            "attn_gated_layers": eng.attn_gated_layers,
         }
 
     def _stats_msg(self, engine_part: Optional[dict]) -> dict:

@@ -283,6 +283,51 @@ CASES = {c.name: c for c in (
         depths=(({}, "MAMMM", "ddddd"),
                 ({"num_hidden_layers": 2}, "MA", "dd"),
                 ({"num_hidden_layers": 9}, "MAMMMAMMM", "d" * 9))),
+    ModelCase(
+        name="solar_open2", json="solar-open2-250b-serve.json",
+        dsl="solar_open2.py",
+        # the published ratios: 4 query heads over 2 KV heads of 16, a width
+        # (64) twice the hidden size (32); 4 KDA heads of 8; 16 experts of
+        # which rank 1 of 4 holds 4, top-4; one period: GQA, KDA, KDA, KDA
+        tiny=dict(hidden_size=32, intermediate_size=64, num_attention_heads=4,
+                  num_key_value_heads=2, head_dim=16, num_hidden_layers=4,
+                  vocab_size=64, moe_intermediate_size=16,
+                  n_routed_experts=16, experts_held=4, ep_rank=1,
+                  num_experts_per_tok=4, param_dtype="float32", init_std=0.3,
+                  select_bias_std=0.3,
+                  linear_attn_config=dict(head_dim=8, num_heads=4)),
+        dsl_keys=("head_dim", "use_rope", "use_gqa_gate",
+                  "kda_allow_neg_eigval", "moe_intermediate_size",
+                  "n_routed_experts", "experts_held", "ep_rank",
+                  "num_experts_per_tok", "n_shared_experts", "norm_topk_prob",
+                  "routed_scaling_factor", "first_k_dense_replace",
+                  "rms_norm_eps"),
+        renamed=dict(KV, kda_head_dim="linear_attn_config.head_dim",
+                     kda_num_heads="linear_attn_config.num_heads"),
+        derived=dict(gqa_layers=lambda c: ";".join(map(str, c["gqa_layers"]))),
+        tol=1e-4, whole_len=150, ragged_chunks=(7, 9, 4, 3),
+        # the gate's matrix gone (sigmoid(0): every gate one half); the
+        # reference with no gate; the reference with beta in (0, 1)
+        zeroed=("_blk0_attn.w4",),
+        ref_controls=({"use_gqa_gate": False},
+                      {"kda_allow_neg_eigval": False}), state_control=True,
+        recurrent=("blk1_kda", "blk2_kda", "blk3_kda"),
+        recurrent_type="kda_attention",
+        slot_parts={"state": ((4, 8, 8), "float32"), "conv": ((3, 96), "")},
+        paged={"blk0_attn": (2, 16)}, margin=True,
+        engines=tuple(e for e in RECURRENT_ENGINES if e.id != "one-chunk"),
+        letters={"kda_attention": "K", "multi_head_attention": "A"},
+        depths=(({}, "AKKK", "eeee"),
+                ({"num_hidden_layers": 2}, "AK", "ee"),
+                ({"num_hidden_layers": 9}, "AKKKAKKKA", "e" * 9)),
+        catalog="Solar-Open2-250B", scored_whole="n_routed_experts",
+        reduced=frozenset({"num_hidden_layers", "n_routed_experts",
+                           "vocab_size"}),
+        dsl_nested=dict(
+            kda_num_heads="linear_attn_config.num_heads",
+            kda_head_dim="linear_attn_config.head_dim",
+            short_conv_kernel_size="linear_attn_config.short_conv_kernel_size"),
+        dsl_defaults=20),
 )}
 
 
@@ -728,7 +773,7 @@ def test_slot_parts_are_declared_by_the_layer_type(case):
     from paddle_tpu.serving import PagedKVCache
     from paddle_tpu.serving.paged_kv import slot_state_specs
     assert sorted(slot_state_types) == sorted(
-        c.recurrent_type for c in CASES.values() if c.recurrent)
+        {c.recurrent_type for c in CASES.values() if c.recurrent})
     c = cfg(case)
     ex = build(case, c, compute_dtype="bfloat16")
     dtypes = {part: jnp.dtype(dt or "bfloat16")
@@ -935,7 +980,7 @@ def test_configuration_file_is_the_catalog_row_cut_as_it_says(case):
         for k, v in row["config"].items():
             if k in c["reduced"] and k != case.scored_whole:
                 assert c[k] != v and c["published"][k] == v, k
-            else:
+            else:                   # a nested group whole, and no key more
                 assert c[k] == v, k
     assert set(c["reduced"]) == case.reduced
     assert c["server_flags"]["param_dtype"] == c["param_dtype"] == "bfloat16"

@@ -818,3 +818,93 @@ def test_paged_kernel_at_one_kv_head_under_20_query_heads(mosaic, rows):
     pool_bytes = c["POOL"] * c["PAGE"] * c["H_KV"] * c["D"] * 2
     assert compiled.memory_analysis().argument_size_in_bytes < \
         2 * pool_bytes * 1.02
+
+
+# solar-open2-250b-serve.long-output-128's own shapes (benchmark/configs/
+# solar-open2-250b-serve.json: 128 slots; 64 KDA heads whose state is
+# 128 x 128 float32, 4 MiB a slot a layer; 64 query heads in 8 groups of 8
+# over 8 KV heads of 128, page 16, context 4,096, bf16; 320 step tokens)
+KDA_64 = dict(S=128, H=64, D=128)
+GQA_64_8 = dict(S=128, PAGE=16, MAXP=4096 // 16, H=64, H_KV=8, D=128,
+                POOL=128 * 256 + 1)
+
+
+def _kda_step_shapes(c):
+    R, H, D = c["S"], c["H"], c["D"]
+    vec = ((R, H, D), f32)
+    return (((c["S"] + 1, H, D, D), f32), ((R,), i32), ((R,), jnp.bool_),
+            vec, vec, vec, vec, ((R, H), f32))
+
+
+def test_kda_step_kernel_at_64_heads(mosaic):
+    """`kda_step` at 128 rows x 64 heads (`head_block(64)` = 16: four grid
+    steps a row, where Kimi's 32 heads take two): one call, the 541 MB
+    state pool [129, 64, 128, 128] donated, aliased and never copied."""
+    from paddle_tpu.ops import kda, pallas_kda
+    assert pallas_kda.head_block(KDA_64["H"]) == 16
+
+    def step(state, slot, live, q, k, v, g, beta):
+        return kda.step_rows(state, None, live, q, k, v, g, beta,
+                             use_kernel=True)
+
+    compiled = mosaic(step, *_kda_step_shapes(KDA_64), donate=(0,))
+    assert kernel_names(compiled) == ["kda_step.1"], kernel_names(compiled)
+    import re
+    made_by = re.findall(r"= f32\[129,64,128,128\]\S* ([\w-]+)\(",
+                         compiled.as_text())
+    assert made_by and "copy" not in made_by, made_by
+
+
+@pytest.mark.parametrize("rows", [128, 320], ids=["decode", "mixed-320-rows"])
+def test_paged_kernel_at_64_query_heads_over_8_kv_heads(mosaic, rows):
+    """`paged_attn` at 64 query heads over 8 KV heads of 128 (8 groups of
+    8, 64 query rows a slot; the cells so far ran 24 / 2, 32 / 8 at head
+    64, 32 / 2 and 20 / 1), at the decode step's 128 rows and the mixed
+    step's 320 — beside `kda_step` in ONE compiled program, as the model's
+    step holds them: two kernels under their own names, the K/V pools and
+    the state pool donated and never copied or padded."""
+    from paddle_tpu.ops import kda
+    from paddle_tpu.ops.attention import (paged_attention_step,
+                                          ragged_paged_attention_step)
+    from paddle_tpu.ops.pallas_paged import kv_row_shape
+    c = GQA_64_8
+    row = kv_row_shape(c["H_KV"], c["D"])
+    assert row == (8, 128)
+    pools = [((c["POOL"], c["PAGE"]) + row, bf16)] * 2
+    recurrent = _kda_step_shapes(KDA_64)
+
+    def kda_part(state, slot, live, q, k, v, g, beta):
+        return kda.step_rows(state, None, live, q, k, v, g, beta,
+                             use_kernel=True)
+
+    if rows == c["S"]:
+        def step(q, k, v, kp, vp, table, pos, *rec):
+            return (paged_attention_step(q, k, v, kp, vp, table, pos,
+                                         use_kernel=True), kda_part(*rec))
+        S = c["S"]
+        compiled = mosaic(
+            step, ((S, 1, c["H"], c["D"]), bf16),
+            ((S, 1, c["H_KV"], c["D"]), bf16),
+            ((S, 1, c["H_KV"], c["D"]), bf16), *pools,
+            ((S, c["MAXP"]), i32), ((S,), i32), *recurrent,
+            donate=(3, 4, 7))
+    else:
+        def step(q, k, v, kp, vp, table, row_slot, row_pos, *rec):
+            return (ragged_paged_attention_step(q, k, v, kp, vp, table,
+                                                row_slot, row_pos,
+                                                use_kernel=True),
+                    kda_part(*rec))
+        T = rows
+        compiled = mosaic(
+            step, ((T, c["H"], c["D"]), bf16), ((T, c["H_KV"], c["D"]), bf16),
+            ((T, c["H_KV"], c["D"]), bf16), *pools,
+            ((c["S"] + 1, c["MAXP"]), i32), ((T,), i32), ((T,), i32),
+            *recurrent, donate=(3, 4, 8))
+    assert kernel_names(compiled) == ["kda_step.1", "paged_attn.1"], \
+        kernel_names(compiled)
+    import re
+    text = compiled.as_text()
+    made_by = re.findall(r"= bf16\[32769,16,8,128\]\S* ([\w-]+)\(", text)
+    assert made_by and "copy" not in made_by and "pad" not in made_by, made_by
+    made_by = re.findall(r"= f32\[129,64,128,128\]\S* ([\w-]+)\(", text)
+    assert made_by and "copy" not in made_by, made_by

@@ -158,8 +158,9 @@ def paged_cases():
     cells' own pool rows (`kv_row_shape`): StarCoder2's and Nemotron's
     [P, 16, 2, 128] (a page's copy crosses from the pool's (2,128) tiles to
     the kernel's dense operand rows: only the chip can say it is exact),
-    LFM2's packed [P, 16, 4, 128], a table shorter than a block, and
-    Jamba's lone head stored two tokens a row, [P, 8, 2, 128]."""
+    LFM2's packed [P, 16, 4, 128], a table shorter than a block,
+    Jamba's lone head stored two tokens a row, [P, 8, 2, 128], and
+    Solar-Open2's 64 query heads over 8 KV heads of 128, [P, 16, 8, 128]."""
     from paddle_tpu.ops.attention import (paged_attention_step,
                                           ragged_paged_attention_step)
     from paddle_tpu.ops.pallas_paged import kv_page_shape, kv_row_shape
@@ -239,7 +240,47 @@ def paged_cases():
             # Jamba's lone KV head of 128 under 20 query heads, stored two
             # tokens a sublane row ([P, 8, 2, 128]: kv_page_shape)
             + build(32, 2048, 20, 1, 128, kv_row_shape(1, 128),
-                    "_lone_head_paired", page=kv_page_shape(ps, 1, 128, 2)))
+                    "_lone_head_paired", page=kv_page_shape(ps, 1, 128, 2))
+            # Solar-Open2's 8 groups of 8: 64 query rows a slot
+            + build(32, 2048, 64, 8, 128, kv_row_shape(8, 128),
+                    "_64q_over_8kv"))
+
+
+def kda_cases():
+    """`kda_step` (ops/pallas_kda.py) against the jnp step of ops/kda.py at
+    the two cells' head counts, 128 rows of 128 x 128 float32 state: Kimi's
+    32 heads with beta in (0, 1) and Solar-Open2's 64 (`head_block` 16:
+    four grid steps a row) with beta near 2 — unit-norm q and k, a paused
+    row and a slot indirection among the rows."""
+    from paddle_tpu.ops import kda
+
+    def build(H, neg_eigval, tag):
+        def run():
+            R, d = 128, 128
+            rng = np.random.default_rng(_seed(f"kda_step{tag}"))
+            f = lambda *shape: jnp.asarray(rng.normal(size=shape),
+                                           jnp.float32)
+            state = f(R + 1, H, d, d)
+            q, k = kda.l2norm(f(R, H, d)), kda.l2norm(f(R, H, d))
+            v, g = f(R, H, d), -jnp.exp(f(R, H, d) - 3.0)
+            beta = (2.0 * jax.nn.sigmoid(6.0 + 0.5 * f(R, H)) if neg_eigval
+                    else jax.nn.sigmoid(f(R, H)))
+            live = jnp.asarray(rng.random(R) > 0.1)
+            slot = jnp.asarray(rng.permutation(R), jnp.int32)
+
+            def step(use_kernel):
+                return jax.jit(lambda *a: kda.step_rows(
+                    *a, use_kernel=use_kernel))(state, slot, live, q, k, v,
+                                                g, beta)
+
+            got, want = step(True), _oracle(lambda: step(False))
+            rows = np.asarray(live)
+            return {"out": _close(np.asarray(got[0])[rows], want[0][rows],
+                                  2e-4),
+                    "state": _close(got[1][:R], want[1][:R], 2e-4)}
+        return [(f"kda_step{tag}_R128_H{H}_f32", run)]
+
+    return build(32, False, "_beta_under_1") + build(64, True, "_beta_near_2")
 
 
 def additive_cases():
@@ -382,8 +423,8 @@ def rnn_cases():
 
 def _build_selected(only):
     selected = [(name, fn)
-                for build in (flash_cases, paged_cases, additive_cases,
-                              rnn_cases)
+                for build in (flash_cases, paged_cases, kda_cases,
+                              additive_cases, rnn_cases)
                 for name, fn in build()
                 if not only or any(name.startswith(o) for o in only)]
     names = [n for n, _ in selected]
