@@ -1781,7 +1781,15 @@ def _cost_layer(type_: str, inputs: list[LayerOutput], name, coeff: float = 1.0,
 def classification_cost(input: LayerOutput, label: LayerOutput, weight=None,
                         name=None, evaluator=None, coeff: float = 1.0) -> LayerOutput:
     """Softmax classification cost + classification_error evaluator
-    (ref: layers.py classification_cost — attaches default evaluators)."""
+    (ref: layers.py classification_cost — attaches default evaluators).
+
+    Where `input` is an `fc_layer(act=SoftmaxActivation())` that nothing
+    else reads, the executor's `loss` runs the pair as one float32
+    log-sum-exp op and never builds the probabilities
+    (graph/layers_cost.py:fused_softmax_cost).  A second reading layer, an
+    `outputs(input)`, any evaluator other than this one on `input`, dropout
+    on it, or a place inside a recurrent group keeps the two layers as
+    written; so does every `forward` (inference, generation, serving)."""
     inputs = [input, label] + ([weight] if weight is not None else [])
     out = _cost_layer("multi-class-cross-entropy", inputs, name, coeff,
                       prefix="classification_cost")

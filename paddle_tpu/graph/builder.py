@@ -16,6 +16,7 @@ executes the sub-model's layers, with memories as the scan carry.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Any, Optional
 
@@ -24,6 +25,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.config.schema import LayerConfig, ModelConfig, SubModelConfig
 from paddle_tpu.graph.context import ForwardContext, TRAIN
+from paddle_tpu.graph.layers_cost import fused_softmax_cost
 from paddle_tpu.graph.registry import get_layer_fn, register_layer
 from paddle_tpu.parameter.argument import Argument
 from paddle_tpu.parameter.init import init_parameter
@@ -178,7 +180,18 @@ class GraphExecutor:
         gradient — how the gradient_printer evaluator observes what the
         reference reads from Layer::getOutputGrad() (ref: Evaluator.cpp
         GradientPrinter; hand-written backward buffers replaced by autodiff).
+
+        Every layer's output is the caller's to read, so every layer runs
+        as configured; `loss` is the entry point that may fuse a softmax
+        head with its cost.
         """
+        return self._run(params, feed, state, mode, rng, probes, {})
+
+    def _run(self, params, feed, state, mode, rng, probes,
+             heads: dict[str, LayerConfig]):
+        """The plan, layer by layer.  `heads` maps an fc's name to the cost
+        layer it may run with as one op (`_fusable_softmax_costs`): the fc
+        is passed over at its own place and the pair runs at the cost's."""
         params, feed = self.prepare(params, feed)
         ctx = ForwardContext(
             model=self.model, params=params, mode=mode, rng=rng,
@@ -186,9 +199,18 @@ class GraphExecutor:
         )
         for name, arg in feed.items():
             ctx.outputs[name] = arg
+        held: dict[str, LayerConfig] = {}       # cost name -> its fc
         for kind, item in self._plan:
             if kind == "layer":
                 cfg: LayerConfig = item
+                cost = heads.get(cfg.name)
+                if cost is not None and not (probes and cfg.name in probes) \
+                        and self._fused_inputs_ready(ctx, cfg, cost):
+                    held[cost.name] = cfg
+                    continue
+                if cfg.name in held:
+                    fused_softmax_cost(ctx, held[cfg.name], cfg)
+                    continue
                 if any(inp.input_layer_name not in ctx.outputs for inp in cfg.inputs):
                     # depends on a generator group's output — only produced by
                     # generate(); skip in plain forward
@@ -204,6 +226,64 @@ class GraphExecutor:
                 self._run_scan(ctx, sm)
         return ctx.outputs, ctx.costs, ctx.state_out
 
+    # -- softmax head + cost as one op -------------------------------------
+    def _fusable_softmax_costs(self) -> dict[str, LayerConfig]:
+        """fc name -> cost layer for every `multi-class-cross-entropy`
+        whose input is a top-level `fc` with softmax activation that
+        nothing else reads: then ops/softmax_ce.py gives the cost (and the
+        rows' argmax for `classification_error`) from the fc's input and
+        weight, and the probabilities are never built.
+
+        Read from the graph alone.  Any other reader keeps the layer as
+        configured: a second consuming layer, a recurrent group's link or
+        boot, a declared output, an evaluator that is not a
+        `classification_error` over (this layer, the cost's label) — host
+        evaluators and `gradient_printer` probes among them.  So does a
+        layer with several inputs, dropout, a `tp_out` stamp (stamped by
+        the serving engine after construction, hence read at each trace),
+        one class (read by `classification_error` as a binary score), or a
+        place inside a recurrent group."""
+        readers = collections.Counter(
+            inp.input_layer_name for l in self.model.layers for inp in l.inputs)
+        linked = set(self.model.output_layer_names)
+        for sm in self.model.sub_models:
+            linked.update(sm.in_links, sm.static_links,
+                          (m.boot_layer_name for m in sm.memories))
+        pairs = {}
+        for cost in self.model.layers:
+            if cost.type != "multi-class-cross-entropy" \
+                    or cost.name in self._sub_of:
+                continue
+            fc = self.layer_map.get(cost.inputs[0].input_layer_name)
+            if fc is None or fc.type != "fc" or fc.name in self._sub_of \
+                    or fc.active_type != "softmax" or len(fc.inputs) != 1 \
+                    or fc.size < 2 or fc.drop_rate > 0.0 \
+                    or fc.attrs.get("tp_out") or readers[fc.name] != 1 \
+                    or fc.name in linked:
+                continue
+            label = cost.inputs[1].input_layer_name
+            if any(fc.name in ev.input_layer_names
+                   and (ev.type != "classification_error"
+                        or list(ev.input_layer_names[:2]) != [fc.name, label])
+                   for ev in self.model.evaluators):
+                continue
+            pairs[fc.name] = cost
+        return pairs
+
+    @staticmethod
+    def _fused_inputs_ready(ctx: ForwardContext, fc: LayerConfig,
+                            cost: LayerConfig) -> bool:
+        """What only the trace shows, asked at the fc's place in the plan:
+        its input is there as dense rows or a flat sequence of them, and
+        the cost's label ids (and weight) are there already."""
+        names = [fc.inputs[0].input_layer_name] + [
+            inp.input_layer_name for inp in cost.inputs[1:]]
+        if any(n not in ctx.outputs for n in names):
+            return False
+        src, lbl = ctx.outputs[names[0]], ctx.outputs[names[1]]
+        return (src.value is not None and not src.sparse_dim
+                and src.sub_lengths is None and lbl.ids is not None)
+
     def loss(
         self,
         params: dict[str, Array],
@@ -215,9 +295,15 @@ class GraphExecutor:
     ) -> tuple[Array, tuple[dict[str, Argument], dict[str, Array], dict[str, Any]]]:
         """Mean summed cost over the batch (ref: Argument::sumCosts / the
         reference divides by batch size at the updater via batch_size scaling —
-        here the loss is per-sample mean, and the optimizer LR semantics match)."""
-        outputs, costs, new_state = self.forward(params, feed, state, mode, rng,
-                                                 probes)
+        here the loss is per-sample mean, and the optimizer LR semantics match).
+
+        A softmax `fc` that only its `multi-class-cross-entropy` reads
+        (`_fusable_softmax_costs`) runs with it as one op: in the returned
+        outputs that layer's entry then holds the rows' argmax as `ids` and
+        no `value` — what `classification_error` reads."""
+        outputs, costs, new_state = self._run(
+            params, feed, state, mode, rng, probes,
+            self._fusable_softmax_costs())
         assert costs, "model has no cost layers"
         from paddle_tpu.utils.dtypes import promote_compute
         total = None

@@ -11,6 +11,8 @@ autodiff).
 
 from __future__ import annotations
 
+import logging
+
 import jax
 import jax.numpy as jnp
 
@@ -21,6 +23,7 @@ from paddle_tpu.parameter.argument import Argument
 
 Array = jax.Array
 _EPS = 1e-10
+log = logging.getLogger("paddle_tpu.graph")
 
 
 def _record(ctx: ForwardContext, cfg: LayerConfig, cost: Array) -> Argument:
@@ -46,7 +49,11 @@ def _flatten_seq(out: Argument, lbl: Argument):
 def multi_class_cross_entropy(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
     """-log p[label]; input is a probability distribution (softmax already
     applied as the previous layer's activation, matching the reference's
-    classification_cost composition) (ref: MultiClassCrossEntropy::forwardImp)."""
+    classification_cost composition) (ref: MultiClassCrossEntropy::forwardImp).
+
+    This is the path of every graph in which something else reads the
+    probabilities, and of `forward`; where a softmax `fc` feeds only this
+    cost, `loss` runs the pair as `fused_softmax_cost` instead."""
     out, lbl = ctx.get_input(cfg, 0), ctx.get_input(cfg, 1)
     probs = out.value
     labels = lbl.ids
@@ -61,6 +68,39 @@ def multi_class_cross_entropy(ctx: ForwardContext, cfg: LayerConfig) -> Argument
     else:
         cost = -picked
     return _record(ctx, cfg, cost)
+
+
+def fused_softmax_cost(ctx: ForwardContext, fc: LayerConfig,
+                       cfg: LayerConfig) -> None:
+    """`fc(act=softmax)` and its `multi-class-cross-entropy` as one op
+    (ops/softmax_ce.py): the cost from the fc's input and weight by a
+    float32 log-sum-exp, no probabilities built.  The executor calls this
+    in `loss` for the pairs its graph walk found
+    (GraphExecutor._fusable_softmax_costs); it publishes the cost as
+    `multi_class_cross_entropy` does and, under the fc's name, the rows'
+    argmax as an ids-only Argument for `classification_error`."""
+    from paddle_tpu.obs.metrics import process_counters
+    from paddle_tpu.ops.softmax_ce import linear_softmax_ce, time_chunks
+    from paddle_tpu.parallel.mesh import DATA_AXIS, axis_size
+
+    src = ctx.get_input(fc, 0)
+    chunks = 1
+    if src.value.ndim == 3:
+        B, T, _ = src.value.shape
+        chunks = time_chunks(-(-B // axis_size(ctx.mesh, DATA_AXIS)), T,
+                             fc.size)
+    nll, pred = linear_softmax_ce(src.value, ctx.param_of(fc, 0),
+                                  ctx.bias_of(fc), ctx.get_input(cfg, 1).ids,
+                                  chunks)
+    out = Argument(ids=pred, lengths=src.lengths if pred.ndim >= 2 else None)
+    cost = jnp.sum(nll * out.mask(nll.dtype), axis=-1) \
+        if out.is_sequence else nll
+    ctx.outputs[fc.name] = out
+    ctx.outputs[cfg.name] = _record(ctx, cfg, cost)
+    process_counters().add("graph_fused_softmax_cost_total", 1)
+    log.info("fused softmax cost: fc %r + %r as one op (rows %s in %d "
+             "piece(s), %d classes, %s)", fc.name, cfg.name, list(pred.shape),
+             chunks, fc.size, src.value.dtype.name)
 
 
 @register_layer("multi_class_cross_entropy_with_selfnorm", cost=True)

@@ -151,6 +151,11 @@ CATALOG: dict[str, str] = {
         "grid steps of the flash kernel calls traced so far (label kernel)",
     "flash_live_tiles_total":
         "of those grid steps, tiles causality and the window leave alive",
+    # -- the executor's fused softmax head, counted when it is traced ------
+    "graph_fused_softmax_cost_total":
+        "fc(softmax) + multi-class-cross-entropy pairs an executor traced "
+        "as one log-sum-exp op (ops/softmax_ce.py) instead of building "
+        "the probabilities",
     # -- cross-replica KV transfer (docs/serving.md "Disaggregated
     # prefill/decode") ----------------------------------------------------
     "serving_kv_xfer_pushes_total":

@@ -46,7 +46,11 @@ def _cls_err_batch(cfg: EvaluatorConfig, outputs, feed):
     out = _get(outputs, cfg.input_layer_names[0])
     lbl = _get(outputs, cfg.input_layer_names[1])
     pred = out.value
-    if pred.shape[-1] == 1:
+    if pred is None:
+        # a softmax head the executor fused with its cost publishes the
+        # rows' argmax itself (graph/layers_cost.py:fused_softmax_cost)
+        err = (out.ids != lbl.ids).astype(jnp.float32)
+    elif pred.shape[-1] == 1:
         err = (pred[..., 0] > cfg.classification_threshold).astype(jnp.float32) \
             != lbl.ids.astype(jnp.float32)
         err = err.astype(jnp.float32)
