@@ -339,6 +339,13 @@ CATALOG: dict[str, str] = {
     "trainer_samples_per_sec": "throughput of the last finished pass",
     "trainer_batches_total": "batches trained since process start",
     "trainer_samples_total": "samples trained since process start",
+    "trainer_step_collectives":
+        "collectives of the compiled train step where the mesh gives it "
+        "compile options (a data axis of TPUs), by form: async "
+        "(-start/-done, compute runs beside the wire) or sync (the core "
+        "waits); read from the executable when first collected",
+    "trainer_step_collective_bytes":
+        "bytes those collectives move a step a chip (labels: form)",
     "trainer_host_phase_seconds":
         "host-phase duration quantiles from the global StatSet "
         "(labels: phase, quantile)",

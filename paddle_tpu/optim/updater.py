@@ -264,8 +264,13 @@ class ParameterUpdater:
             # dropped, the same convention as the feeder's drop_last
             state["grad_accum"] = jax.tree.map(jnp.zeros_like,
                                                state["grad_accum"])
-            state["grad_accum_count"] = jnp.zeros((), jnp.int32)
-            state["grad_accum_samples"] = jnp.zeros((), jnp.int32)
+            # zeros_like, not fresh zeros: under a mesh the counters are
+            # placed on it (parallel/dp.py:shard_train_objects), and a fresh
+            # scalar would give the next pass's step another signature
+            state["grad_accum_count"] = jnp.zeros_like(
+                state["grad_accum_count"])
+            state["grad_accum_samples"] = jnp.zeros_like(
+                state["grad_accum_samples"])
         return state
 
     def averaged_params(self, params, state):

@@ -17,7 +17,6 @@ from __future__ import annotations
 import json
 import os
 import time
-from functools import partial
 from typing import Any, Iterator, Optional
 
 import jax
@@ -152,8 +151,7 @@ class Trainer:
         from paddle_tpu.obs.compile_watch import get_compile_watch
         _cw = get_compile_watch()
         self._train_step = _cw.wrap_jit(
-            "trainer.train_step",
-            jax.jit(self._train_step_fn, donate_argnums=(0, 1)))
+            "trainer.train_step", self._jit_step(self._train_step_fn))
         self._fused_step = _cw.wrap_jit("trainer.fused_step",
                                         self._build_fused_step())
         # benchmark twin: same scanned step, losses only (no [iters, ...]
@@ -202,6 +200,16 @@ class Trainer:
             "trainer_host_phase_count", label="phase",
             total_metric="trainer_host_phase_seconds_total"))
         self.metrics.register_collector(barrier_collector(self.barrier_stat))
+        # what the compiled step does about its collectives, where the mesh
+        # asks the compiler for something (parallel/dp.py:
+        # step_compile_options): a new signature leaves its shapes here,
+        # and the executable is read when the gauges are first collected
+        self._step_collectives = None
+        from paddle_tpu.parallel.dp import step_compile_options
+        if step_compile_options(mesh):
+            from paddle_tpu.parallel.schedule import StepCollectives
+            self._step_collectives = StepCollectives()
+            self.metrics.register_collector(self._step_collectives)
         self.metrics.register_collector(tracer_collector(self._tracer))
         # compile events + device-memory accounting ride the same registry
         # (and therefore metrics.jsonl): per-site jit compile counters from
@@ -261,6 +269,17 @@ class Trainer:
                 if n not in names:
                     names.append(n)
         return names
+
+    def _jit_step(self, fn):
+        """jit of a step that updates (params, opt_state) in place.  Every
+        step built on `_train_step_fn` compiles with the options its mesh
+        asks for (parallel/dp.py:step_compile_options -- the gradient
+        all-reduces asynchronous under a `data` axis of TPUs; none anywhere
+        else, and then this is `jax.jit(fn, donate_argnums=(0, 1))`)."""
+        from paddle_tpu.parallel.dp import step_compile_options
+        return jax.jit(fn, donate_argnums=(0, 1),
+                       compiler_options=step_compile_options(self.mesh)
+                       or None)
 
     def _build_train_step_fn(self):
         executor, updater, evaluators = self.executor, self.updater, self.evaluators
@@ -328,10 +347,9 @@ class Trainer:
                 (loss, (outputs, costs, new_net)), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(params)
                 grads = constrain_grads(grads)
-            if self.mesh is not None:
-                # grads are averaged across data shards by XLA automatically
-                # via sharding propagation; nothing to do here.
-                pass
+            # under a mesh GSPMD sums the gradients over the data shards
+            # here, between the backward and the update; _jit_step's compile
+            # options decide whether those all-reduces wait or run beside it
             bsz = _batch_size(batch)
             if remote:
                 # parameter-server mode: the jitted step computes
@@ -382,7 +400,6 @@ class Trainer:
 
         step_fn = self._train_step_fn
 
-        @partial(jax.jit, donate_argnums=(0, 1))
         def fused_step(params, opt_state, net_state, stacked, keys):
             def body(carry, xs):
                 p, o, n = carry
@@ -397,7 +414,7 @@ class Trainer:
                 body, (params, opt_state, net_state), (stacked, keys))
             return p, o, n, losses, partials, host_outs
 
-        return fused_step
+        return self._jit_step(fused_step)
 
     def _build_test_step(self):
         executor, evaluators = self.executor, self.evaluators
@@ -490,9 +507,9 @@ class Trainer:
         # net_state pytree change after batch 1)
         seen = self._seen_sigs()
         with self.barrier_stat.time_dispatch(windowed=sig in seen):
-            seen.add(sig)
-            out = self._train_step(self.params, self.opt_state,
-                                   self.net_state, batch, key)
+            out = self._call_step(
+                "trainer.train_step", self._train_step, sig, self.params,
+                self.opt_state, self.net_state, batch, key)
         (self.params, self.opt_state, new_net, loss, partials,
          host_out) = out[:6]
         if new_net:
@@ -529,14 +546,26 @@ class Trainer:
         fsig = ("fused", int(keys.shape[0]), sig)
         seen = self._seen_sigs()
         with self.barrier_stat.time_scan(windowed=fsig in seen):
-            seen.add(fsig)
-            out = self._fused_step(self.params, self.opt_state,
-                                   self.net_state, staged, keys)
+            out = self._call_step(
+                "trainer.fused_step", self._fused_step, fsig, self.params,
+                self.opt_state, self.net_state, staged, keys)
         (self.params, self.opt_state, new_net, losses, partials, host_outs) = out
         if new_net:
             self.net_state = new_net
         self._n_fused_dispatches += 1
         return losses, partials, host_outs
+
+    def _call_step(self, site: str, step, sig, *args):
+        """Call a compiled step.  A signature's FIRST call, where the mesh
+        gives the step compile options, leaves the arguments' shapes with
+        `_step_collectives` (before the call donates the arrays); nothing
+        is lowered or read here."""
+        seen = self._seen_sigs()
+        if sig not in seen:
+            seen.add(sig)
+            if self._step_collectives is not None:
+                self._step_collectives.note(site, step, args)
+        return step(*args)
 
     def _validate_batch(self, batch: dict[str, Argument]) -> None:
         """Clear errors for the common feed mistakes BEFORE tracing: a

@@ -208,6 +208,7 @@ def test_build_engine_holds_no_optimizer_state_and_serves_bf16(monkeypatch):
     """tools/serve.py:build_engine for the new model: no Trainer is built
     (its import would fail the test), parameters come out in --param-dtype,
     and the engine serves."""
+    import gc
     import sys
 
     import jax
@@ -218,6 +219,10 @@ def test_build_engine_holds_no_optimizer_state_and_serves_bf16(monkeypatch):
     eng = tool.build_engine(parse(serve_argv(
         CASE, cfg(CASE), "--param-dtype", "bfloat16", compute_dtype="")))
     assert {str(v.dtype) for v in eng.params.values()} == {"bfloat16"}
+    # live_arrays() is the whole process's: collect what earlier tests of
+    # this worker left in cycles (their trainers, engines), or the sum
+    # depends on which files ran before this one
+    gc.collect()
     live = sum(x.nbytes for x in jax.live_arrays())
     weights = sum(v.nbytes for v in eng.params.values())
     assert live < weights + eng.kv.pool_bytes + (1 << 20), \

@@ -16,6 +16,7 @@ worker's `topo` fixture takes the library's lock (file order), and skipped
 where the child cannot describe a topology either.
 """
 
+import gc
 import json
 import os
 import subprocess
@@ -908,3 +909,66 @@ def test_paged_kernel_at_64_query_heads_over_8_kv_heads(mosaic, rows):
     assert made_by and "copy" not in made_by and "pad" not in made_by, made_by
     made_by = re.findall(r"= f32\[129,64,128,128\]\S* ([\w-]+)\(", text)
     assert made_by and "copy" not in made_by, made_by
+
+
+# ---------------------------------------------------------------------------
+# the data-parallel train step's SCHEDULE: which gradient all-reduces the
+# trainer's compile options (parallel/dp.py:step_compile_options) make
+# asynchronous, read by parallel/schedule.py from a compile for four
+# described chips.  One StarCoder2 layer at dim 1024, where every matrix is
+# at or over the combiner's 1 MiB (tools/step_schedule.py is the same at the
+# dp4 cell's own shape)
+# ---------------------------------------------------------------------------
+
+SCHEDULE_SHAPE = dict(vocab=8192, dim=1024, layers=1, heads=8, kv_heads=4,
+                      ffn=4096, batch_size=8, seq_len=1025,
+                      compute_dtype="bfloat16", attn_impl="flash")
+#: the gradients that must cross beside work, by their matrix's dims
+CROSSING = {"lm_head": "[1024,8192]", "embedding": "[8192,1024]",
+            "ffn1": "[1024,4096]", "ffn2": "[4096,1024]",
+            "attention q/o": "[1024,1024]"}
+
+
+@pytest.mark.parametrize("mesh", ["data:4", "none", "model:4"])
+def test_train_step_all_reduces_run_beside_work(topo, no_persistent_cache,
+                                                monkeypatch, mesh):
+    """Under `data:4` each matrix's gradient crosses in an
+    `async-collective-start/-done` pair with work scheduled between, and
+    what stays synchronous is the combiner's tuples of vectors and scalars;
+    one chip and a `model`-only mesh get no options and nothing
+    asynchronous."""
+    from paddle_tpu.ops import pallas_attention
+    from paddle_tpu.parallel.dp import (ASYNC_ALL_REDUCE_OPTIONS,
+                                        step_compile_options)
+    from paddle_tpu.parallel.schedule import read_collectives, summarize
+    from tools.step_schedule import compile_step, described_trainer
+
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")  # "supported" here
+    monkeypatch.setattr(pallas_attention, "_interpret", lambda: False)
+    config = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs", "starcoder2.py")
+    tr, args = described_trainer(config, dict(SCHEDULE_SHAPE), mesh,
+                                 topo.devices)
+    options = step_compile_options(tr.mesh)
+    text = compile_step(tr, args).as_text()
+    # the trainer holds 0.2 GB of host parameters and Adam slots in cycles:
+    # give them back before the next case builds its own
+    del tr, args
+    gc.collect()
+    assert "tpu_custom_call" in text
+    found = read_collectives(text)
+    forms = summarize(found)
+    if mesh != "data:4":
+        assert options == {}
+        assert forms["async"] == {"count": 0, "bytes": 0}, found
+        return
+    assert options == ASYNC_ALL_REDUCE_OPTIONS
+    beside = {c["shape"].split("{")[0].split("[", 1)[1]: c for c in found
+              if c["form"] == "async" and c["kind"] == "all-reduce"
+              and c["between"]["work"] >= 1 and "done" not in c["between"]}
+    for what, dims in CROSSING.items():
+        assert dims[1:] in beside, (what, found)
+    total = forms["async"]["bytes"] + forms["sync"]["bytes"]
+    assert forms["sync"]["bytes"] < 0.03 * total, forms
+    assert all(c["bytes"] <= 1 << 20 for c in found if c["form"] == "sync"), \
+        [c for c in found if c["form"] == "sync"]
