@@ -978,10 +978,13 @@ def multi_head_attention_layer(
     window: Optional[int] = None,
     use_rope: bool = False,
     rope_theta: float = 10000.0,
+    rotary_dim: Optional[int] = None,
+    rope_scaling: Optional[dict] = None,
+    attention_factor: float = 1.0,
     qk_norm: bool = False,
     rms_eps: float = 1e-6,
     out_size: Optional[int] = None,
-    out_gate: bool = False,
+    out_gate: Union[bool, str] = False,
     name: Optional[str] = None,
     param_attr: Optional[Union[ParameterAttribute, list]] = None,
     bias_attr=False,
@@ -1011,6 +1014,16 @@ def multi_head_attention_layer(
     the output projection, y = (attn * sigmoid(x w_g)) w_o with w_g
     [query.size, size] — the LAST parameter (4, or 6 behind qk_norm's two),
     in every path (ops/attention.py:project_out).  Self-attention only.
+    out_gate="head": one gate value a head, w_g [query.size, num_heads],
+    y = concat_h(attn_h * sigmoid(x w_g)_h) w_o — the same parameter index,
+    the same helper.
+
+    rotary_dim: rotate only the first rotary_dim columns of each head
+    (rotate-half pairing within them; the rest carry no position).
+    rope_scaling: a YaRN dict (factor, original_max_position_embeddings,
+    beta_fast, beta_slow) for the rotation's frequencies
+    (ops/mla.py:yarn_inv_freq); attention_factor multiplies cos and sin of
+    the rotated columns.  All three need use_rope, and hold in every path.
 
     param_attr: one attribute applied to all four projections (q/k/v/out), or
     a list of four (five with out_gate: q/k/v/out/gate).  A single NAMED
@@ -1034,6 +1047,16 @@ def multi_head_attention_layer(
         "spurious relative-position bias in cross-attention"
     assert not out_gate or key is query, \
         "out_gate is computed from the layer's input: self-attention only"
+    assert out_gate in (False, True, "head"), \
+        f"out_gate is True (a gate a column) or 'head' (got {out_gate!r})"
+    assert use_rope or (rotary_dim is None and not rope_scaling
+                        and attention_factor == 1.0), \
+        "rotary_dim, rope_scaling and attention_factor shape the rotation: " \
+        "they need use_rope"
+    assert rotary_dim is None or (
+        rotary_dim % 2 == 0 and 0 < rotary_dim <= size // num_heads), \
+        f"rotary_dim must be even and within the head " \
+        f"(got {rotary_dim} of {size // num_heads})"
     n_attrs = 5 if out_gate else 4
     if isinstance(param_attr, ParameterAttribute):
         assert not param_attr.name, \
@@ -1063,6 +1086,12 @@ def multi_head_attention_layer(
     if use_rope:                     # rotary position embeddings
         cfg.attrs["use_rope"] = True
         cfg.attrs["rope_theta"] = rope_theta
+        if rotary_dim is not None:
+            cfg.attrs["rotary_dim"] = rotary_dim
+        if rope_scaling:
+            cfg.attrs["rope_scaling"] = dict(rope_scaling)
+        if attention_factor != 1.0:
+            cfg.attrs["attention_factor"] = attention_factor
     kv_dim = size if num_kv_heads is None \
         else (size // num_heads) * num_kv_heads
     for i, (inp, dim_in, dim_out) in enumerate(
@@ -1082,8 +1111,10 @@ def multi_head_attention_layer(
                                          input_parameter_name=pname))
     if out_gate:
         cfg.attrs["out_gate"] = len(cfg.inputs)   # the gate's parameter index
-        pname = _make_param(name, len(cfg.inputs), [query.size, size],
-                            attrs[4])
+        pname = _make_param(
+            name, len(cfg.inputs),
+            [query.size, num_heads if out_gate == "head" else size],
+            attrs[4])
         cfg.inputs.append(LayerInput(input_layer_name=query.name,
                                      input_parameter_name=pname))
     assert out_size in (None, size) or bias_attr is False, \

@@ -50,15 +50,44 @@ def _project(ctx: ForwardContext, cfg: LayerConfig) -> dict:
     kw = dict(num_kv_heads=int(a.get("num_kv_heads", 0) or a["num_heads"]),
               use_rope=bool(a.get("use_rope", False)),
               rope_theta=float(a.get("rope_theta", 10000.0)))
+    # a partly rotated head, YaRN's frequencies, its factor on cos and sin
+    if "rotary_dim" in a:
+        kw["rotary_dim"] = int(a["rotary_dim"])
+    if a.get("rope_scaling"):
+        kw["rope_scaling"] = dict(a["rope_scaling"])
+    if "attention_factor" in a:
+        kw["attention_factor"] = float(a["attention_factor"])
     if a.get("qk_norm"):
         kw["qk_norm"] = (ctx.param_of(cfg, 4), ctx.param_of(cfg, 5),
                          float(a.get("rms_eps", 1e-6)))
     return kw
 
 
+def _window(cfg: LayerConfig):
+    """The layer's sliding window (keys i - window < j <= i), or None."""
+    return int(cfg.attrs["window"]) if "window" in cfg.attrs else None
+
+
+def _scope(cfg: LayerConfig):
+    """The named scope of the layer's attention proper — `attn.window` or
+    `attn.full` — so a device trace tells the two kinds' ops apart."""
+    return jax.named_scope(
+        "attn.window" if "window" in cfg.attrs else "attn.full")
+
+
+def _table(cache: dict) -> dict:
+    """The page table a paged step reads: the slots' logical table, or for
+    a window layer the slots' RINGS (`ring_table`: serving/paged_kv.py
+    "WINDOW LAYERS") with `ring` set."""
+    if "ring_table" in cache:
+        return {"page_table": cache["ring_table"], "ring": True}
+    return {"page_table": cache["page_table"]}
+
+
 def _gate(ctx: ForwardContext, cfg: LayerConfig):
     """The output gate's matrix where the layer has one (`out_gate`: its
-    parameter's index), else None."""
+    parameter's index; [d, heads * head_dim], or [d, heads] for the gate a
+    head), else None."""
     gate = cfg.attrs.get("out_gate")
     return None if gate is None else ctx.param_of(cfg, int(gate))
 
@@ -170,9 +199,7 @@ def multi_head_attention_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argumen
         w_q, w_k, w_v, w_o, num_heads,
         q_valid=q_valid, k_valid=k_valid, causal=causal,
         bias_o=ctx.bias_of(cfg), attn_fn=attn_fn,
-        window=(int(cfg.attrs["window"])
-                if "window" in cfg.attrs else None),
-        w_g=_gate(ctx, cfg),
+        window=_window(cfg), w_g=_gate(ctx, cfg),
         **_project(ctx, cfg))
     return finish_layer(ctx, cfg, out, like=q_arg)
 
@@ -200,7 +227,7 @@ def _cached_step(ctx: ForwardContext, cfg: LayerConfig, x_arg: Argument,
                           k_pos=qpos, **_project(ctx, cfg))
     n_new = (x_arg.lengths.astype(jnp.int32) if x_arg.lengths is not None
              else jnp.full((B,), Tn, jnp.int32))
-    window = (int(cfg.attrs["window"]) if "window" in cfg.attrs else None)
+    window = _window(cfg)
     if Tn > 1:
         # prefill contract: a multi-token cached call starts from an EMPTY
         # cache (lm_decode feeds the whole prompt once), so attention over
@@ -275,18 +302,17 @@ def _paged_step(ctx: ForwardContext, cfg: LayerConfig, x_arg: Argument,
     qpos = pos[:, None]
     q, k, v = project_qkv(x, x, x, w_q, w_k, w_v, num_heads, q_pos=qpos,
                           k_pos=qpos, **_project(ctx, cfg))
-    window = (int(cfg.attrs["window"]) if "window" in cfg.attrs else None)
     # a mesh with a `model` axis > 1 = tensor-parallel serving: the op
     # runs the write+read core under shard_map over the head shards
-    out, ck, cv = paged_attention_step(
-        q, k, v, cache["k_pages"], cache["v_pages"], cache["page_table"],
-        pos, window=window,
-        use_kernel=(False if str(cfg.attrs.get("attn_impl", "auto"))
-                    in ("dense", "blockwise") else None),
-        mesh=ctx.mesh)
-    ctx.state_out[cfg.name] = {"k_pages": ck, "v_pages": cv,
-                               "page_table": cache["page_table"],
-                               "pos": pos + 1}
+    with _scope(cfg):
+        out, ck, cv = paged_attention_step(
+            q, k, v, cache["k_pages"], cache["v_pages"], pos=pos,
+            window=_window(cfg),
+            use_kernel=(False if str(cfg.attrs.get("attn_impl", "auto"))
+                        in ("dense", "blockwise") else None),
+            mesh=ctx.mesh, **_table(cache))
+    ctx.state_out[cfg.name] = dict(cache, k_pages=ck, v_pages=cv,
+                                   pos=pos + 1)
     return finish_layer(ctx, cfg, _out(ctx, cfg, out, x), like=x_arg)
 
 
@@ -311,18 +337,16 @@ def _paged_ragged_step(ctx: ForwardContext, cfg: LayerConfig, x_arg: Argument,
     row_pos = cache["row_pos"]                        # [T] global positions
     q, k, v = project_qkv(x, x, x, w_q, w_k, w_v, num_heads, q_pos=row_pos,
                           k_pos=row_pos, **_project(ctx, cfg))
-    window = (int(cfg.attrs["window"]) if "window" in cfg.attrs else None)
     # mesh `model` axis > 1 = tensor-parallel mixed step (shard_map core)
-    out, ck, cv = ragged_paged_attention_step(
-        q[0], k[0], v[0], cache["k_pages"], cache["v_pages"],
-        cache["page_table"], cache["row_slot"], row_pos, window=window,
-        use_kernel=(False if str(cfg.attrs.get("attn_impl", "auto"))
-                    in ("dense", "blockwise") else None),
-        mesh=ctx.mesh)
-    ctx.state_out[cfg.name] = {"k_pages": ck, "v_pages": cv,
-                               "page_table": cache["page_table"],
-                               "row_slot": cache["row_slot"],
-                               "row_pos": row_pos}
+    with _scope(cfg):
+        out, ck, cv = ragged_paged_attention_step(
+            q[0], k[0], v[0], cache["k_pages"], cache["v_pages"],
+            row_slot=cache["row_slot"], row_pos=row_pos,
+            window=_window(cfg),
+            use_kernel=(False if str(cfg.attrs.get("attn_impl", "auto"))
+                        in ("dense", "blockwise") else None),
+            mesh=ctx.mesh, **_table(cache))
+    ctx.state_out[cfg.name] = dict(cache, k_pages=ck, v_pages=cv)
     return finish_layer(ctx, cfg, _out(ctx, cfg, out, x), like=x_arg)
 
 

@@ -103,6 +103,23 @@ CATALOG: dict[str, str] = {
     "serving_attn_gated_layers":
         "attention layers whose result passes a sigmoid gate from the "
         "layer's input in front of the output projection (dsl out_gate)",
+    "serving_window_pages_recycled_total":
+        "pages of the window layers' rings written over by a later logical "
+        "page of the same slot, summed over the window layers (0 for a "
+        "model without them)",
+    "serving_window_rows_total":
+        "token rows compiled steps sent through a window layer, summed "
+        "over the window layers",
+    "serving_window_steps_total":
+        "compiled steps that ran window layers",
+    "serving_kv_pages_resident":
+        "pages that hold live tokens (label kind: full = the allocator's "
+        "pages in use, each backing every full layer; window = the slots' "
+        "ring pages in use, each backing every window layer)",
+    "serving_kv_pool_bytes":
+        "device bytes of the K/V page pools, all shards (label kind: full "
+        "= layers that hold a whole context a slot; window = layers held "
+        "as rings of window + step pages a slot)",
     "serving_recurrent_tokens_total":
         "tokens the recurrent layers ran, one layer's worth a step (label "
         "kind: step = a decode row, one token a slot state; segment = a "

@@ -68,7 +68,9 @@ def _score_mask(
     return mask
 
 
-def rope(x: Array, positions: Array, theta: float = 10000.0) -> Array:
+def rope(x: Array, positions: Array, theta: float = 10000.0,
+         rotary_dim: Optional[int] = None, rope_scaling: Optional[dict] = None,
+         attention_factor: float = 1.0) -> Array:
     """Rotary position embedding (RoPE, Su et al. 2021) — NEW capability
     beyond the reference.  x [B, T, H, D] with D even, positions [T] (or
     [B, T]) absolute token positions; rotate-half convention (feature i
@@ -77,18 +79,35 @@ def rope(x: Array, positions: Array, theta: float = 10000.0) -> Array:
     depends only on relative offsets.
     Applied to q/k BEFORE attention, it composes with every implementation
     (dense/blockwise/flash/ring) — for ring/context-parallel shards pass the
-    shard's global positions."""
-    D = x.shape[-1]
-    assert D % 2 == 0, f"rope needs an even head dim, got {D}"
+    shard's global positions.
+
+    `rotary_dim` rotates the FIRST rotary_dim columns of each head only (the
+    pairing is then i with i + rotary_dim/2; the other columns pass through:
+    partial rotation, `partial_rotary_factor` of the published configs);
+    `rope_scaling` (a YaRN dict: factor, original_max_position_embeddings,
+    beta_fast, beta_slow) blends the frequencies as ops/mla.py:yarn_inv_freq
+    does, and `attention_factor` multiplies cos and sin — of the rotated
+    columns only, so a partly rotated head is scaled in that part alone."""
+    D = x.shape[-1] if rotary_dim is None else int(rotary_dim)
+    assert D % 2 == 0 and D <= x.shape[-1], \
+        f"rope needs an even rotated width within the head, got {D}"
     half = D // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if rope_scaling:
+        from paddle_tpu.ops.mla import yarn_inv_freq
+        freqs = jnp.asarray(yarn_inv_freq(D, float(theta), rope_scaling))
+    else:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     ang = positions[..., None].astype(jnp.float32) * freqs     # [..., T, half]
     if ang.ndim == 2:                                          # [T, half]
         ang = ang[None]                                        # [1, T, half]
     cos = jnp.cos(ang)[:, :, None, :]                          # [B|1, T, 1, half]
     sin = jnp.sin(ang)[:, :, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    rot = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    if attention_factor != 1.0:
+        cos, sin = cos * attention_factor, sin * attention_factor
+    x1, x2 = x[..., :half], x[..., half:D]
+    rot = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos]
+                          + ([] if D == x.shape[-1] else [x[..., D:]]),
+                          axis=-1)
     return rot.astype(x.dtype)
 
 
@@ -341,13 +360,14 @@ def project_qkv(query: Array, key: Array, value: Array,
                 num_heads: int, num_kv_heads: int,
                 q_pos: Array, k_pos: Array,
                 use_rope: bool = False, rope_theta: float = 10000.0,
-                qk_norm: Optional[tuple] = None):
+                qk_norm: Optional[tuple] = None, **rope_kw):
     """The projections every attention path starts with: q [..., H, D], k
     and v [..., H_kv, D] from inputs [..., T, d].  `qk_norm` (q scale [D],
     k scale [D], eps) RMS-norms each head of q and k — statistics in
     float32, times the learned scale — BEFORE the rotation (QK-norm as the
     LFM2 / Qwen3 / OLMo-2 families apply it); `use_rope` then rotates q at
-    `q_pos` and k at `k_pos`.  What is written to a KV cache is this k."""
+    `q_pos` and k at `k_pos` (`rope_kw`: `rope`'s rotary_dim, rope_scaling,
+    attention_factor).  What is written to a KV cache is this k."""
     Dh = w_q.shape[1] // num_heads
     q = (query @ w_q).reshape(query.shape[:-1] + (num_heads, Dh))
     k = (key @ w_k).reshape(key.shape[:-1] + (num_kv_heads, Dh))
@@ -364,7 +384,8 @@ def project_qkv(query: Array, key: Array, value: Array,
 
         q, k = norm(q, q_scale), norm(k, k_scale)
     if use_rope:
-        q, k = rope(q, q_pos, rope_theta), rope(k, k_pos, rope_theta)
+        q = rope(q, q_pos, rope_theta, **rope_kw)
+        k = rope(k, k_pos, rope_theta, **rope_kw)
     return q, k, v
 
 
@@ -375,10 +396,15 @@ def project_out(o: Array, x: Array, w_o: Array,
     of inputs x [..., d] through the output projection.  With `w_g` [d, H*D]
     an elementwise sigmoid gate from the layer's input comes first, y = (o *
     sigmoid(x w_g)) w_o (gated attention, arXiv:2505.06708): it multiplies
-    whatever computed o, so no kernel knows of it."""
+    whatever computed o, so no kernel knows of it.  A `w_g` of [d, H] is the
+    gate PER HEAD (one value a head, the dsl's out_gate="head"): the same
+    product with the gate broadcast over the head's D columns."""
     o = o.reshape(x.shape[:-1] + (w_o.shape[0],))
     if w_g is not None:
-        o = o * jax.nn.sigmoid(x @ w_g).astype(o.dtype)
+        g = jax.nn.sigmoid(x @ w_g).astype(o.dtype)
+        if w_g.shape[1] != w_o.shape[0]:                   # one value a head
+            g = jnp.repeat(g, w_o.shape[0] // w_g.shape[1], axis=-1)
+        o = o * g
     out = o @ w_o
     return out if bias_o is None else out + bias_o
 
@@ -400,6 +426,7 @@ def multi_head_attention(
     rope_theta: float = 10000.0,
     qk_norm: Optional[tuple] = None,
     w_g: Optional[Array] = None,
+    **rope_kw,
 ) -> Array:
     """Projected multi-head attention; attn_fn pluggable (dense / blockwise /
     flash / a ring closure from parallel/context.py).
@@ -413,7 +440,7 @@ def multi_head_attention(
     q, k, v = project_qkv(query, key, value, w_q, w_k, w_v, num_heads,
                           num_kv_heads or num_heads, jnp.arange(Tq),
                           jnp.arange(key.shape[1]), use_rope, rope_theta,
-                          qk_norm)
+                          qk_norm, **rope_kw)
     kw = {} if window is None else {"window": window}
     o = attn_fn(q, k, v, q_valid=q_valid, k_valid=k_valid, causal=causal,
                 **kw)
@@ -544,6 +571,77 @@ def _write_rows(pages: Array, phys: Array, off: Array, new: Array) -> Array:
     return pages.at[phys, off].set(rows)
 
 
+def window_pages(window: int, page_size: int) -> int:
+    """The most pages `window` consecutive tokens touch, wherever the first
+    one sits in its page: what a window layer's read fetches a row — at
+    most window + page_size tokens, whatever the context."""
+    return (window + page_size - 2) // page_size + 1
+
+
+def _window_view(page_table: Array, slot: Array, pos: Array, window: int,
+                 page_size: int, ring: bool):
+    """What a query row of a WINDOW layer reads: the pages that hold keys
+    pos - window + 1 .. pos of its slot, as a table of that row's own —
+    `view` [T, window_pages] physical pages, `base` [T], the position of
+    the first token of view[:, 0], and `lo` [T], the window's first key
+    counted from there.  Logical page j of a slot sits in
+    column j of its table row, or with `ring` in column j % R of a ring of
+    R pages that the slot recycles in place as it advances
+    (serving/paged_kv.py "WINDOW LAYERS"); a column past the row's
+    position re-reads the last one, and is masked by position."""
+    R = page_table.shape[1]
+    oldest = jnp.maximum(pos - (window - 1), 0)                      # [T]
+    base = oldest // page_size * page_size
+    logical = (oldest // page_size)[:, None] + jnp.arange(
+        window_pages(window, page_size))
+    logical = jnp.minimum(logical, (pos // page_size)[:, None])
+    col = logical % R if ring else jnp.minimum(logical, R - 1)
+    return page_table[slot[:, None], col], base, oldest - base
+
+
+def _paged_read(q: Array, ck: Array, cv: Array, k_new: Array,
+                page_table: Array, slot: Array, pos: Array, scale: float,
+                window: Optional[int], ring: bool,
+                use_kernel: Optional[bool]) -> Array:
+    """The read of both paged steps, AFTER their writes: query rows q
+    [T, H, D], row r at position pos[r] of table row slot[r], causally over
+    that slot's pages — all of them, or with `window` the last `window`
+    keys through `_window_view`.  The Pallas kernels (ops/pallas_paged.py)
+    when supported, else — and as their oracle — a gather of the pages into
+    a contiguous view and a masked softmax."""
+    from paddle_tpu.ops import pallas_paged
+    T, H, D = q.shape
+    page_size = _page_size(k_new, ck)
+    if use_kernel is None:
+        use_kernel = pallas_paged.supported()
+    if window is not None:
+        table, base, lo = _window_view(page_table, slot, pos, window,
+                                       page_size, ring)
+        rows = jnp.arange(T, dtype=jnp.int32)
+    else:
+        assert not ring, "a ring of pages holds a window layer's keys only"
+        table, rows, base, lo = page_table, slot, 0, None
+    if use_kernel:
+        return pallas_paged.paged_attention(
+            q, ck, cv, table, pos + 1 - base, scale=scale, row_slot=rows,
+            kv_heads=k_new.shape[-2], first=lo)
+    # -- per-row page gather -> [T, T_ctx] contiguous view -----------------
+    T_ctx = table.shape[1] * page_size
+    kc = ck[table[rows]].reshape(T, T_ctx, *k_new.shape[-2:])
+    vc = cv[table[rows]].reshape(T, T_ctx, *k_new.shape[-2:])
+    k_full, v_full = _expand_kv_heads(kc, vc, H)
+    t = jnp.arange(T_ctx)[None, :] + jnp.reshape(base, (-1, 1))
+    mask = t <= pos[:, None]                                     # causal
+    if window is not None:
+        mask = jnp.logical_and(mask, t > pos[:, None] - window)
+    s = jnp.einsum("qhd,qkhd->qhk", q, k_full) * scale
+    from paddle_tpu.utils.dtypes import promote_compute
+    s = promote_compute(s)
+    s = jnp.where(mask[:, None, :], s, _NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(v_full.dtype)
+    return jnp.einsum("qhk,qkhd->qhd", p, v_full)
+
+
 def paged_attention_step(
     q_new: Array,          # [S, 1, H, D] one new-token query per slot
     k_new: Array,          # [S, 1, H_kv, D]
@@ -557,6 +655,7 @@ def paged_attention_step(
     window: Optional[int] = None,
     use_kernel: Optional[bool] = None,
     mesh=None,
+    ring: bool = False,
 ) -> tuple[Array, Array, Array]:
     """One continuous-batching decode micro-step against a PAGED KV cache —
     the serving analog of `cached_attention_step`: instead of one dense
@@ -579,9 +678,15 @@ def paged_attention_step(
 
     Returns (out [S, 1, H, D], new_k_pages, new_v_pages).  `use_kernel`
     routes the read through the Pallas ragged-paged kernel
-    (ops/pallas_paged.py) — default: auto (kernel when supported and no
-    sliding window); False forces the jnp gather fallback (the oracle in
-    tests and the exactness anchor of the serving engine).
+    (ops/pallas_paged.py) — default: auto (the kernel when supported, with
+    or without a window); False forces the jnp gather fallback (the oracle
+    in tests and the exactness anchor of the serving engine).
+
+    `window` reads the last `window` keys of each slot (key j for the query
+    at i iff 0 <= i - j < window) and fetches only the pages that hold them
+    (`_window_view`); with `ring`, `page_table` is [S, R] and logical page j
+    of a slot is its column j % R — the slot's ring, written and read in
+    place (serving/paged_kv.py "WINDOW LAYERS").
 
     SCAN-BODY SAFE: the write+read core is pure in its operands (no
     host callback, no per-call state — including the shard_map TP path,
@@ -594,65 +699,11 @@ def paged_attention_step(
     """
     S, Tn, H, D = q_new.shape
     assert Tn == 1, "paged decode feeds exactly one new token per slot"
-    page_size = _page_size(k_new, k_pages)
-    max_pages = page_table.shape[1]
-    if scale is None:
-        scale = D ** -0.5
-
-    if _tp_shards(mesh) > 1:
-        # tensor-parallel decode: heads partition over the mesh `model`
-        # axis — the whole write+read core runs per head shard under
-        # shard_map (each device's local H/h_kv keep the same grouped-
-        # query ratio; the engine validated divisibility)
-        def body(q, k, v, kp, vp, tbl, p):
-            return paged_attention_step(q, k, v, kp, vp, tbl, p,
-                                        scale=scale, window=window,
-                                        use_kernel=use_kernel, mesh=None)
-
-        return _tp_paged_call(mesh, body, (q_new, k_new, v_new),
-                              (k_pages, v_pages), (page_table, pos),
-                              head_axis=2)
-
-    # -- write: scatter each slot's new k/v into its current page --------
-    phys = jnp.take_along_axis(page_table, (pos // page_size)[:, None],
-                               axis=1)[:, 0]                     # [S]
-    off = pos % page_size
-    ck = _write_rows(k_pages, phys, off, k_new[:, 0])
-    cv = _write_rows(v_pages, phys, off, v_new[:, 0])
-
-    if use_kernel is None:
-        from paddle_tpu.ops import pallas_paged
-        use_kernel = pallas_paged.supported() and window is None
-    if use_kernel:
-        if window is not None:
-            raise ValueError(
-                "paged_attention_step: the Pallas ragged-paged kernel has "
-                "no sliding-window support — pass use_kernel=False (or "
-                "None for auto, which already falls back) for window "
-                "attention")
-        from paddle_tpu.ops import pallas_paged
-        out = pallas_paged.paged_attention(
-            q_new[:, 0], ck, cv, page_table, pos + 1, scale=scale,
-            kv_heads=k_new.shape[-2])[:, None]
-        return out, ck, cv
-
-    # -- read: page-table gather -> [S, T_ctx] contiguous view -----------
-    T_ctx = max_pages * page_size
-    kc = ck[page_table].reshape(S, T_ctx, *k_new.shape[2:])
-    vc = cv[page_table].reshape(S, T_ctx, *v_new.shape[2:])
-    k_full, v_full = _expand_kv_heads(kc, vc, H)
-    t = jnp.arange(T_ctx)
-    mask = t[None, None, :] <= pos[:, None, None]                # causal
-    if window is not None:
-        mask = jnp.logical_and(mask,
-                               t[None, None, :] > pos[:, None, None] - window)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q_new, k_full) * scale
-    from paddle_tpu.utils.dtypes import promote_compute
-    s = promote_compute(s)
-    s = jnp.where(mask[:, None, :, :], s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1).astype(v_full.dtype)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p, v_full)
-    return out, ck, cv
+    out, ck, cv = ragged_paged_attention_step(
+        q_new[:, 0], k_new[:, 0], v_new[:, 0], k_pages, v_pages, page_table,
+        jnp.arange(S, dtype=jnp.int32), pos, scale=scale, window=window,
+        use_kernel=use_kernel, mesh=mesh, ring=ring)
+    return out[:, None], ck, cv
 
 
 def ragged_paged_attention_step(
@@ -669,6 +720,7 @@ def ragged_paged_attention_step(
     window: Optional[int] = None,
     use_kernel: Optional[bool] = None,
     mesh=None,
+    ring: bool = False,
 ) -> tuple[Array, Array, Array]:
     """RAGGED paged attention — the mixed prefill/decode step of the
     serving engine (the full Ragged Paged Attention shape of
@@ -707,66 +759,40 @@ def ragged_paged_attention_step(
 
     Returns (out [T, H, D], new_k_pages, new_v_pages).  `use_kernel`
     routes the read through the Pallas ragged-paged kernel with the
-    row->slot indirection (ops/pallas_paged.py); the jnp gather fallback
-    is the exactness oracle (and the sliding-window path)."""
+    row->slot indirection (ops/pallas_paged.py), with a `window` the
+    kernel's windowed form over the pages `_window_view` names (`ring`: as
+    for `paged_attention_step`; a chunk's rows are written before any is
+    read, so a ring holds window + chunk tokens and a page more); the jnp
+    gather fallback is the exactness oracle of both."""
     T, H, D = q_new.shape
     page_size = _page_size(k_new, k_pages)
-    max_pages = page_table.shape[1]
     if scale is None:
         scale = D ** -0.5
 
     if _tp_shards(mesh) > 1:
-        # mixed prefill/decode under tensor parallelism: same head-shard
-        # partition as the decode step, row indirection replicated
+        # tensor parallelism: heads partition over the mesh `model` axis —
+        # the whole write+read core runs per head shard under shard_map
+        # (each device's local H/h_kv keep the same grouped-query ratio;
+        # the engine validated divisibility), row indirection replicated
         def body(q, k, v, kp, vp, tbl, rs, rp):
             return ragged_paged_attention_step(q, k, v, kp, vp, tbl, rs,
                                                rp, scale=scale,
                                                window=window,
                                                use_kernel=use_kernel,
-                                               mesh=None)
+                                               mesh=None, ring=ring)
 
         return _tp_paged_call(mesh, body, (q_new, k_new, v_new),
                               (k_pages, v_pages),
                               (page_table, row_slot, row_pos), head_axis=1)
 
     # -- write: scatter every row's k/v into its slot's current page -----
-    phys = page_table[row_slot, row_pos // page_size]             # [T]
+    col = row_pos // page_size
+    phys = page_table[row_slot, col % page_table.shape[1] if ring else col]
     off = row_pos % page_size
     ck = _write_rows(k_pages, phys, off, k_new)
     cv = _write_rows(v_pages, phys, off, v_new)
-
-    if use_kernel is None:
-        from paddle_tpu.ops import pallas_paged
-        use_kernel = pallas_paged.supported() and window is None
-    if use_kernel:
-        if window is not None:
-            raise ValueError(
-                "ragged_paged_attention_step: the Pallas ragged-paged "
-                "kernel has no sliding-window support — pass "
-                "use_kernel=False (or None for auto) for window attention")
-        from paddle_tpu.ops import pallas_paged
-        out = pallas_paged.paged_attention(q_new, ck, cv, page_table,
-                                           row_pos + 1, scale=scale,
-                                           row_slot=row_slot,
-                                           kv_heads=k_new.shape[-2])
-        return out, ck, cv
-
-    # -- read: per-row page-table gather -> [T, T_ctx] contiguous view ---
-    T_ctx = max_pages * page_size
-    kc = ck[page_table[row_slot]].reshape(T, T_ctx, *k_new.shape[1:])
-    vc = cv[page_table[row_slot]].reshape(T, T_ctx, *v_new.shape[1:])
-    k_full, v_full = _expand_kv_heads(kc, vc, H)
-    t = jnp.arange(T_ctx)
-    mask = t[None, :] <= row_pos[:, None]                        # causal
-    if window is not None:
-        mask = jnp.logical_and(mask,
-                               t[None, :] > row_pos[:, None] - window)
-    s = jnp.einsum("qhd,qkhd->qhk", q_new, k_full) * scale
-    from paddle_tpu.utils.dtypes import promote_compute
-    s = promote_compute(s)
-    s = jnp.where(mask[:, None, :], s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1).astype(v_full.dtype)
-    out = jnp.einsum("qhk,qkhd->qhd", p, v_full)
+    out = _paged_read(q_new, ck, cv, k_new, page_table, row_slot, row_pos,
+                      scale, window, ring, use_kernel)
     return out, ck, cv
 
 

@@ -51,7 +51,10 @@ columns of the other groups are masked — scores and weights are
 [H, block*H_kv], 128 or more lanes wide where a page gave 16.  q, k and v enter the dots in
 the dtype they are stored in (bf16 in the cells), accumulated in
 float32, the weights cast to v's dtype: the jnp fallback's precision.
-Sliding-window decode stays on the jnp fallback.  Interpret-mode parity
+A sliding-window layer runs the same kernel under the name
+`window_attn` (`paged_attention(first=)`): over a table of each row's
+own that names only the pages its window intersects, with the window's
+lower edge in the mask.  Interpret-mode parity
 with the fallback is the CPU oracle (tests/test_serving.py,
 tests/test_chunked_prefill.py); tests/test_mosaic_compile.py asks the
 chip's compiler at the serve cells' shapes.
@@ -204,13 +207,20 @@ def block_tokens(page_size: int, h_kv: int, head_dim: int, itemsize: int,
     return pages * page_size
 
 
-def _kernel(H, h_kv, scale, v_width, table_ref, len_ref, row_ref, q_ref,
+def _kernel(H, h_kv, scale, v_width, windowed, table_ref, len_ref, row_ref,
             *rest):
     """One query row against its slot's live KV.  `v_width` None: K and V
     pools of [P, ps, h_kv, Dp] (grouped-query heads).  `v_width` set: ONE
     latent pool of [P, ps, W] whose rows are both — every query head scores
     the whole row and weighs its first `v_width` columns (ops/mla.py), so a
-    block is fetched once and there are no groups to mask."""
+    block is fetched once and there are no groups to mask.  `windowed`: a
+    fourth prefetched operand, the first token of the row's table row that
+    the query may see (the window's lower edge, below the row's length:
+    the mask is first <= t < length)."""
+    first_ref = None
+    if windowed:
+        first_ref, *rest = rest
+    q_ref, *rest = rest
     if v_width is None:
         k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, slot_ref = rest
         pools = ((k_hbm, kbuf), (v_hbm, vbuf))
@@ -298,6 +308,8 @@ def _kernel(H, h_kv, scale, v_width, table_ref, len_ref, row_ref, q_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # [Hp, C]
         valid = tok < length - b * bt
+        if first_ref is not None:
+            valid = jnp.logical_and(valid, tok >= first_ref[r] - b * bt)
         if own_group is not None:
             valid = jnp.logical_and(own_group, valid)
         sc = jnp.where(valid, sc, _NEG_INF)
@@ -327,14 +339,16 @@ def _head_rows(H: int, dtype) -> int:
 
 def _call(name: str, kernel, qp: Array, pools: tuple, buf_shape: tuple,
           out_width: int, page_table: Array, lengths: Array,
-          row_slot: Array) -> Array:
+          row_slot: Array, first: Optional[Array] = None) -> Array:
     """The one pallas_call of the family: grid over query rows, the pools
-    in HBM, table / lengths / row->slot on the scalar-prefetch channel, a
-    double buffer of `buf_shape` a pool."""
+    in HBM, table / lengths / row->slot (and a windowed call's `first`) on
+    the scalar-prefetch channel, a double buffer of `buf_shape` a pool."""
     R, Hp, Dq = qp.shape
-    index = lambda r, tbl, lens, rows: (r, 0, 0)
+    index = lambda r, *prefetched: (r, 0, 0)
+    scalars = (page_table, lengths, row_slot) + \
+        (() if first is None else (first,))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,               # page_table, lengths, row_slot
+        num_scalar_prefetch=len(scalars),
         grid=(R,),
         in_specs=[pl.BlockSpec((1, Hp, Dq), index)]
         + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),  # stay in HBM
@@ -352,8 +366,7 @@ def _call(name: str, kernel, qp: Array, pools: tuple, buf_shape: tuple,
         compiler_params=pallas_tpu_compiler_params(
             dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      row_slot.astype(jnp.int32), qp, *pools)
+    )(*(a.astype(jnp.int32) for a in scalars), qp, *pools)
 
 
 def paged_attention(
@@ -369,6 +382,9 @@ def paged_attention(
                             # (the classic one-token-per-slot decode)
     kv_heads: Optional[int] = None,     # the model's KV heads; None = the
                             # pool's third dimension holds a token's rows
+    first: Optional[Array] = None,      # [R] int32: a WINDOWED call — row r
+                            # attends first[r] <= t < lengths[r] of its
+                            # table row
 ) -> Array:
     """Ragged paged attention -> [R, H, D].  Same math as the jnp
     fallback's gather path (online softmax re-association aside): q, k
@@ -395,7 +411,16 @@ def paged_attention(
     own lanes are taken from it here.  A pool that stores a lone head's
     tokens TWO A ROW (`kv_page_shape`; told from `kv_heads`) is the same
     kernel with one row a token: a page's copy lands in the same operand
-    rows.  No pool is copied or padded."""
+    rows.  No pool is copied or padded.
+
+    A WINDOWED call (`first`, the sliding-window layers' decode and chunk
+    rows) is the same program with one more prefetched operand and one more
+    comparison in the mask: the caller (ops/attention.py:_window_view)
+    hands a table of each row's OWN — the pages that intersect its window,
+    window // page_size + 1 columns at most, `row_slot` the identity — so
+    the block loop, bounded by `lengths`, fetches at most window + page_size
+    tokens a row whatever the context.  It is named `window_attn` in
+    a device trace, so a reader tells it from the full layers' calls."""
     R, H, D = q.shape
     P, ps, G, L = k_pages.shape
     maxp = page_table.shape[1]
@@ -414,6 +439,10 @@ def paged_attention(
     Hp = _head_rows(H, q.dtype)
     Dp = _round_up(L, 128)
     npb = block_tokens(ps, G, L, itemsize, maxp) // ps
+    if first is not None:
+        # a window's few pages in blocks of one size: as many blocks, and
+        # fewer copies past the view's end (33 pages: 5 x 7, not 5 x 8)
+        npb = -(-maxp // -(-maxp // npb))
     if pack > 1:
         # head h reads KV head h // rep, lane tile (h // rep) % pack
         rep = H // (G * pack)
@@ -424,9 +453,11 @@ def paged_attention(
     if Dp != L:
         k_pages, v_pages = (jnp.pad(p, ((0, 0),) * 3 + ((0, Dp - L),))
                             for p in (k_pages, v_pages))
-    out = _call("paged_attn", functools.partial(_kernel, H, G, scale, None),
+    out = _call("paged_attn" if first is None else "window_attn",
+                functools.partial(_kernel, H, G, scale, None,
+                                  first is not None),
                 qp, (k_pages, v_pages), (npb * ps * G, Dp), Dp, page_table,
-                lengths, row_slot)[:, :H, :L]
+                lengths, row_slot, first)[:, :H, :L]
     if pack > 1:
         out = jnp.sum(out.reshape(R, H, pack, D) *
                       lane[None, :, :, None].astype(out.dtype), axis=2)
@@ -465,7 +496,7 @@ def latent_paged_attention(
                        maxp) // ps
     qp = jnp.pad(q, ((0, 0), (0, Hp - H), (0, 0)))
     out = _call("mla_paged_attn",
-                functools.partial(_kernel, H, 1, scale, v_width), qp,
+                functools.partial(_kernel, H, 1, scale, v_width, False), qp,
                 (kv_pages,), (npb * ps, W), v_width, page_table, lengths,
                 row_slot)
     return out[:, :H]

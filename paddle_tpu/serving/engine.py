@@ -114,7 +114,8 @@ from paddle_tpu.obs.metrics import (SpanSeconds, counter_key,
 from paddle_tpu.obs.trace import get_tracer
 from paddle_tpu.parallel.mesh import MODEL_AXIS, axis_size
 from paddle_tpu.parameter.argument import Argument
-from paddle_tpu.serving.paged_kv import (RECURRENT_REFUSALS, PagedKVCache,
+from paddle_tpu.serving.paged_kv import (RECURRENT_REFUSALS, RING_REFUSALS,
+                                         PagedKVCache,
                                          refuse_for_recurrent,
                                          slot_state_specs)
 from paddle_tpu.serving.prefix_tree import PrefixTree
@@ -336,10 +337,20 @@ class ServingEngine:
             params = jax.device_put(params, self._param_shardings_tree)
         self.params = params        # the property: derives the steps' tree
         pages_per_slot = -(-int(max_context) // int(page_size))
+        # the most rows one slot can get in one step (`set_chunking`'s own
+        # budget, by its own rule where none is given): what a window
+        # layer's ring of pages must hold beside its window
+        if prefill_chunk == -1:
+            prefill_chunk = 4 * int(page_size)
+        step_tokens = int(max_step_tokens) if max_step_tokens is not None \
+            else self._default_budget(
+                min(int(prefill_chunk or 0), pages_per_slot * int(page_size)),
+                num_slots, spec_k)
         self.kv = PagedKVCache(executor, num_slots, page_size,
                                pages_per_slot, num_pages,
                                mesh=self.mesh if self.tp > 1 else None,
-                               spill_bytes_budget=spill_bytes_budget)
+                               spill_bytes_budget=spill_bytes_budget,
+                               step_tokens=step_tokens)
         # RECURRENT LAYERS (graph/layers_kda.py): their context is a state
         # a slot in the cache manager's slot-indexed parts, with no
         # snapshot at a page boundary.  Everything that assumes the pages
@@ -353,12 +364,24 @@ class ServingEngine:
         self.attn_gated_layers = sum(
             1 for l in executor.model.layers
             if l.attrs.get("out_gate") is not None)
-        if self._recurrent and prefix_cache:
+        # WINDOW LAYERS held as rings of pages (paged_kv "WINDOW LAYERS"):
+        # their tables, constants of the compiled steps; and like a
+        # recurrent state a ring is not the whole context
+        self._ring_tables = {name: self.kv.ring_table(name)
+                             for name in self.kv.ring_specs}
+        self.n_window_pages_recycled = 0
+        self.n_window_rows = 0
+        self.n_window_steps = 0
+        if prefix_cache and (self._recurrent or self._ring_tables):
             import logging
+            what, why = (
+                (f"{len(self._recurrent)} recurrent layers",
+                 RECURRENT_REFUSALS["prefix"][1]) if self._recurrent else
+                (f"{len(self._ring_tables)} window layers held as rings",
+                 RING_REFUSALS["prefix"]))
             logging.getLogger(__name__).info(
-                "model has %d recurrent layers: the prefix index is off "
-                "(%s) — every admission prefills from position 0",
-                len(self._recurrent), RECURRENT_REFUSALS["prefix"][1])
+                "model has %s: the prefix index is off (%s) — every "
+                "admission prefills from position 0", what, why)
             prefix_cache = False
         # the ONE canonical pool sharding, derived by the cache that owns
         # the pools — every jit that hands pools back pins to it
@@ -543,8 +566,7 @@ class ServingEngine:
                           **self._step_sharding_kwargs(n_extra=6))
         self._mixed_step = get_compile_watch().wrap_jit(
             "serving.mixed_step", mix_jit)
-        self.set_chunking(4 * self.kv.page_size if prefill_chunk == -1
-                          else prefill_chunk, max_step_tokens)
+        self.set_chunking(prefill_chunk, max_step_tokens)
         self.n_prefill_chunks = 0
         # prompt rows packed, those of them given past a slot's share, and
         # rows of a mixed or verify step that carried nothing
@@ -1386,6 +1408,7 @@ class ServingEngine:
             self._count_recurrent_tokens(len(runnable), 0)
         adv = np.zeros(S, np.int32)
         adv[runnable] = 1
+        self._count_window(cur, adv, len(runnable))
         return _Pending(nxt, list(self.slots), runnable, [], adv,
                         adv.astype(bool), "decode", self.n_decode_steps,
                         launch.t0)
@@ -1671,6 +1694,7 @@ class ServingEngine:
             self._count_kv(row_pos + 1)           # a padding row reads 1
             self._note_step_metrics(r, decoded=bool(runnable))
             self._count_recurrent_tokens(len(runnable), chunk_rows)
+            self._count_window(cur, adv, len(runnable) + chunk_rows)
         return _Pending(nxt, list(self.slots), runnable, advanced, adv,
                         emit, "mixed", self.n_decode_steps, launch.t0)
 
@@ -2212,7 +2236,7 @@ class ServingEngine:
         of `tokens` for a kv_push: returns (covered_tokens, meta, payload)
         or None when nothing is cached.  Pump thread only (walks the
         prefix tree and gathers from the pools between steps)."""
-        refuse_for_recurrent(self.kv.slot_specs, "export")
+        self.kv.refuse("export")
         if self.prefix is None:
             return None
         self.settle()
@@ -2233,7 +2257,7 @@ class ServingEngine:
         a malformed blob or page starvation; returns nodes newly added.
         Pump thread only: kv.pools is authoritative between steps, so the
         scatter is exactly as safe as an admission-time spill restore."""
-        refuse_for_recurrent(self.kv.slot_specs, "import")
+        self.kv.refuse("import")
         if self.prefix is None:
             raise ValueError("kv import: prefix cache is disabled")
         self.settle()
@@ -2416,7 +2440,8 @@ class ServingEngine:
                 f"prefill_chunk must be positive, got {prefill_chunk}")
         prefill_chunk = min(prefill_chunk, self.kv.capacity_tokens)
         S = len(self.slots)
-        mst = self._default_budget(prefill_chunk) \
+        mst = self._default_budget(prefill_chunk, S,
+                                   getattr(self, "spec_k", 0)) \
             if max_step_tokens is None else int(max_step_tokens)
         if mst <= S:
             raise ValueError(
@@ -2424,17 +2449,25 @@ class ServingEngine:
                 f"decoding slot takes one row per step, and prefill "
                 f"chunks need at least one row of headroom to ever make "
                 f"progress")
+        if self.kv.ring_specs and mst > self.kv.step_tokens:
+            raise ValueError(
+                f"max_step_tokens {mst} exceeds the {self.kv.step_tokens} "
+                f"rows a step the window layers' rings of pages were sized "
+                f"for when the engine was built "
+                f"({max(self.kv.ring_specs.values())} pages a slot): build "
+                f"the engine with the larger budget")
         self.prefill_chunk = prefill_chunk
         self.max_step_tokens = mst
 
-    def _default_budget(self, prefill_chunk: int) -> int:
+    @staticmethod
+    def _default_budget(prefill_chunk: int, num_slots: int,
+                        spec_k: int = 0) -> int:
         """The defaulted token budget: one chunk of prefill headroom
         plus a FULL chain per slot — `chunk + S` with speculation off
         (the classic default), `chunk + S*(spec_k+1)` with it on, so a
         default deployment's draft depth is never silently throttled to
         the chunk headroom."""
-        return prefill_chunk + len(self.slots) * (
-            int(getattr(self, "spec_k", 0)) + 1)
+        return int(prefill_chunk) + int(num_slots) * (int(spec_k) + 1)
 
     def set_speculation(self, spec_k: int, drafter=None,
                         dynamic: Optional[bool] = None) -> None:
@@ -2457,7 +2490,7 @@ class ServingEngine:
             raise ValueError(
                 f"spec_k must be >= 0 (0 = speculation off), got {spec_k}")
         if spec_k > 0:
-            refuse_for_recurrent(self.kv.slot_specs, "spec")
+            self.kv.refuse("spec")
         self.spec_k = spec_k
         if dynamic is not None:
             self.spec_dynamic = bool(dynamic)
@@ -2467,7 +2500,7 @@ class ServingEngine:
             # throttle draft rows to the chunk headroom.
             # An explicit budget is the operator's pin — untouched.
             self.max_step_tokens = self._default_budget(
-                self.prefill_chunk)
+                self.prefill_chunk, len(self.slots), spec_k)
         if drafter is not None:
             self.drafter = drafter
         elif self.drafter is None and spec_k > 0:
@@ -2528,7 +2561,7 @@ class ServingEngine:
             return
         self.settle()
         if enabled:
-            refuse_for_recurrent(self.kv.slot_specs, "prefix")
+            self.kv.refuse("prefix")
             self.prefix = PrefixTree(self.kv)
             self.kv.on_page_pressure = self._evict_for
             return
@@ -2666,6 +2699,7 @@ class ServingEngine:
                 "prefill_tokens_saved", "n_restore_hits",
                 "restore_tokens_saved", "n_prefill_chunks",
                 "n_chunk_rows", "n_chunk_extra_rows", "n_step_pad_rows",
+                "n_window_pages_recycled", "n_window_rows", "n_window_steps",
                 "n_mixed_steps", "n_spec_steps", "n_spec_chains",
                 "n_spec_drafted", "n_spec_accepted", "n_spec_tokens",
                 "n_scan_steps", "n_scan_flushes", "n_draft_steps")},
@@ -3004,6 +3038,13 @@ class ServingEngine:
         state = {name: dict({part + "_pages": a for part, a in pool.items()},
                             **shared)
                  for name, pool in st.pools.items() if name not in rec}
+        for name, ring in self._ring_tables.items():
+            # a window layer reads its slots' rings where the others read
+            # the logical table (as many rows of it: the decode step has
+            # dropped the trash row)
+            del state[name]["page_table"]
+            state[name]["ring_table"] = jnp.asarray(
+                ring[:shared["page_table"].shape[0]])
         if run is not None:
             shared = dict(shared, run=run)
         state.update({name: dict(st.pools[name], **shared) for name in rec})
@@ -3055,6 +3096,33 @@ class ServingEngine:
                                self.mesh) == "grouped"
                 for l in self.executor.model.layers if l.type == "moe")
         return self._moe_grouped_at[rows]
+
+    def _count_window(self, cur: dict, adv: np.ndarray, rows: int) -> None:
+        """One launched step's share of the window layers' counters
+        (nothing for a model without rings): the ring pages its rows write
+        over — `cur` the plan's cursors, `adv` the tokens each slot commits
+        — and the rows it sends through the window layers."""
+        if not self._ring_tables:
+            return
+        before = np.fromiter((cur[s][0] if s in cur else 0
+                              for s in range(len(adv))), np.int64, len(adv))
+        recycled = self.kv.ring_pages_recycled(before, before + adv)
+        rows *= len(self._ring_tables)
+        self.n_window_pages_recycled += recycled
+        self.n_window_rows += rows
+        self.n_window_steps += 1
+        pc = process_counters()
+        pc.add("serving_window_pages_recycled_total", recycled)
+        pc.add("serving_window_rows_total", rows)
+        pc.add("serving_window_steps_total", 1)
+
+    def kv_pages_resident(self) -> dict:
+        """Pages that hold live tokens, by kind: `full` = the allocator's
+        pages in use (each backs every full layer), `window` = the slots'
+        ring pages in use (each backs every window layer)."""
+        lengths = [0 if sl is None else sl.pos for sl in self.slots]
+        return {"full": self.kv.pages_in_use,
+                "window": self.kv.ring_pages_resident(lengths)}
 
     def _count_moe(self, nxt: np.ndarray, n_rows: int,
                    kind: str) -> np.ndarray:

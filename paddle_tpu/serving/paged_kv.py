@@ -36,6 +36,31 @@ spill tier and the transfer plane (`RECURRENT_REFUSALS`: one sentence and
 one place of refusal each).  The memory accounting covers both
 (`pool_bytes`, `slot_state_bytes`).
 
+WINDOW LAYERS.  A `multi_head_attention` layer with a `window` reads the
+last `window` keys only, so it need not hold a slot's whole context: built
+with `step_tokens` (the most rows one slot can get in one step — the
+engine's `max_step_tokens`), such a layer's pool is `1 + num_slots *
+ring_pages` pages, a RING of `ring_pages` a slot: logical page j of slot s
+is physical page `ring_table[s, j % ring_pages]` = 1 + s * ring_pages + j %
+ring_pages, written and read in place, the oldest page recycled as the slot
+advances.  The ring holds window + step_tokens tokens and a page more (a
+chunk's rows are all written before any is read, and neither end of that
+span sits on a page boundary); where that is `pages_per_slot` or more the
+ring would drop no page, so the layer stays under the logical table with
+the full layers and nothing below refuses it.  The
+assignment is static: a ring page is never on the free list, the table of
+rings is a constant the compiled steps fold in (`ring_table`: row S the
+all-trash row padding aims at), and release, preemption and
+`uncommit_tail` have nothing of a ring to undo — what a slot leaves in its
+ring is overwritten by the next request before any of its queries can see
+it.  The FULL layers keep the logical table, the allocator and the prefix
+index as they are.  A ring's pages are not the whole context, so what
+assumes they are refuses a model with rings as it refuses one with
+recurrent state (`RING_REFUSALS` beside `RECURRENT_REFUSALS`, one
+function, the same places).  Without `step_tokens` (a cache built by hand)
+a window layer holds whole contexts under the logical table and the window
+is a mask of its read.
+
 Replaces the dense `lm_decode.init_kv_caches` layout for SERVING: a dense
 cache sizes every row at P+max_new whatever the row actually holds, and its
 [B, total, ...] shape bakes the request mix into the compiled program.
@@ -138,6 +163,23 @@ RECURRENT_REFUSALS = {
 }
 
 
+# The same for a model whose WINDOW layers hold rings of pages (module
+# docstring "WINDOW LAYERS"): the ring is not the whole context either.
+# Refused at the same places through the same function; `--mesh model=N` is
+# not among them (a ring shards on its kv-head axis like any pool).
+RING_REFUSALS = {
+    "spill": "a spilled page could not bring the slot's ring back",
+    "prefix": "a prefix hit would need the ring as it stood at the hit's "
+              "last page boundary, and only its newest state is kept",
+    "export": "exported pages would arrive without the ring that goes with "
+              "them",
+    "import": "imported pages carry no ring to resume from",
+    "spec": "a rejected draft's rows have already recycled pages of the "
+            "ring that the accepted prefix still needs",
+    "role": "pages pushed between replicas carry no ring",
+}
+
+
 def slot_state_specs(model, compute_dtype=jnp.float32) -> dict:
     """{layer name: {part: (row shape, dtype)}} of the model's recurrent
     layers' slot-indexed parts, in layer order, as each layer type declares
@@ -148,17 +190,25 @@ def slot_state_specs(model, compute_dtype=jnp.float32) -> dict:
             for l in model.layers if l.type in slot_state_types}
 
 
-def refuse_for_recurrent(slot_specs: dict, mechanism: str) -> None:
-    """Raise, for a model with recurrent layers (`slot_specs` not empty),
-    for a mechanism that assumes the pages are the whole context (a key of
-    RECURRENT_REFUSALS): one sentence naming what is missing."""
+def refuse_for_recurrent(slot_specs: dict, mechanism: str,
+                         ring_specs: Optional[dict] = None) -> None:
+    """Raise, for a model some of whose layers' pages are NOT the whole
+    context — recurrent layers (`slot_specs` not empty) or window layers
+    held as rings (`ring_specs`) — for a mechanism that assumes they are (a
+    key of RECURRENT_REFUSALS): one sentence naming what is missing."""
+    what, why = RECURRENT_REFUSALS[mechanism]
     if slot_specs:
-        what, why = RECURRENT_REFUSALS[mechanism]
         raise ValueError(
             f"{what} is not available for a model with recurrent layers "
             f"({len(slot_specs)} here, of any kind that keeps a slot "
             f"state): {why} (ROADMAP R5: state snapshots at page "
             f"boundaries)")
+    if ring_specs and mechanism in RING_REFUSALS:
+        raise ValueError(
+            f"{what} is not available for a model with window layers held "
+            f"as rings of pages ({len(ring_specs)} here): "
+            f"{RING_REFUSALS[mechanism]} (ROADMAP R2: ring snapshots at "
+            f"page boundaries)")
 
 
 class PagedKVCache:
@@ -172,7 +222,8 @@ class PagedKVCache:
 
     def __init__(self, executor, num_slots: int, page_size: int,
                  pages_per_slot: int, num_pages: Optional[int] = None,
-                 mesh=None, spill_bytes_budget: int = 0):
+                 mesh=None, spill_bytes_budget: int = 0,
+                 step_tokens: Optional[int] = None):
         assert page_size > 0 and pages_per_slot > 0
         self.page_size = int(page_size)
         self.pages_per_slot = int(pages_per_slot)
@@ -206,6 +257,10 @@ class PagedKVCache:
         # columns, so there is no second tensor).  Everything below walks
         # `self.pools[name]`'s own parts and shapes, never a fixed pair.
         self.layer_specs: dict[str, tuple] = {}
+        # ring_specs[name] = ring_pages of a WINDOW layer held as rings
+        # (module docstring "WINDOW LAYERS"): a subset of layer_specs
+        self.ring_specs: dict[str, int] = {}
+        self.step_tokens = None if step_tokens is None else int(step_tokens)
         # slot_specs[name] = {part: row shape} of a recurrent layer's
         # slot-indexed parts (module docstring "TWO FAMILIES OF PARTS")
         declared = slot_state_specs(executor.model, dtype)
@@ -246,7 +301,16 @@ class PagedKVCache:
             else:
                 continue
             self.layer_specs[l.name] = row
-            shape = (self.num_pages,) + page
+            n_pages = self.num_pages
+            if "window" in l.attrs and l.type == "multi_head_attention" \
+                    and self.step_tokens is not None:
+                ring = self.ring_pages_for(int(l.attrs["window"]))
+                # a ring as large as a context drops no page: such a
+                # layer stays under the logical table, and nothing refuses
+                if ring < self.pages_per_slot:
+                    self.ring_specs[l.name] = ring
+                    n_pages = 1 + self.num_slots * ring
+            shape = (n_pages,) + page
 
             def _pool():
                 # distinct buffers per part — parts are donated side by
@@ -308,6 +372,47 @@ class PagedKVCache:
         self.n_exported = 0            # pages exported to wire bytes (ever)
         self.n_imported = 0            # pages imported from wire bytes (ever)
 
+    def ring_pages_for(self, window: int) -> int:
+        """Pages a slot's ring holds for a layer of `window`: window +
+        step_tokens tokens (the oldest key the step's first row reads to
+        the newest the step writes) and a page more (neither end of that
+        span sits on a page boundary).  Only a ring SMALLER than a whole
+        context is built (`ring_specs`)."""
+        return -(-(window + self.step_tokens) // self.page_size) + 1
+
+    def ring_table(self, name: str) -> np.ndarray:
+        """[num_slots + 1, ring_pages] int32: the physical page of each
+        column of each slot's ring in window layer `name`'s pool — static,
+        so the compiled steps take it as a constant; row num_slots is all
+        trash (page 0), for padding rows."""
+        R = self.ring_specs[name]
+        t = np.zeros((self.num_slots + 1, R), np.int32)
+        t[:-1] = 1 + np.arange(self.num_slots)[:, None] * R + np.arange(R)
+        return t
+
+    def ring_pages_resident(self, lengths) -> int:
+        """Ring pages that hold live tokens, one window layer's worth:
+        each slot's pages up to its ring's size (`lengths`: tokens a slot
+        holds, 0 for an empty one).  0 without rings."""
+        if not self.ring_specs:
+            return 0
+        R = max(self.ring_specs.values())
+        pages = -(-np.asarray(lengths, np.int64) // self.page_size)
+        return int(np.minimum(pages, R).sum())
+
+    def ring_pages_recycled(self, before, after) -> int:
+        """Ring pages written over while slots went from `before` to
+        `after` tokens, summed over the window layers: a logical page past
+        the ring's size lands on the page of the one ring_pages before it."""
+        n = 0
+        for R in self.ring_specs.values():
+            a = np.maximum(-(-np.asarray(before, np.int64) // self.page_size),
+                           R)
+            b = np.maximum(-(-np.asarray(after, np.int64) // self.page_size),
+                           R)
+            n += int(np.maximum(b - a, 0).sum())
+        return n
+
     def _canonical_free(self) -> list:
         """The free list in its construction-time canonical order (pop()
         hands out page 1 first) — reset() rebuilds exactly this, so page
@@ -354,8 +459,14 @@ class PagedKVCache:
     @spill_bytes_budget.setter
     def spill_bytes_budget(self, nbytes: int) -> None:
         if int(nbytes or 0) > 0:
-            refuse_for_recurrent(self.slot_specs, "spill")
+            self.refuse("spill")
         self._spill_bytes_budget = int(nbytes or 0)
+
+    def refuse(self, mechanism: str) -> None:
+        """`refuse_for_recurrent` for THIS cache's layers: raises where a
+        recurrent layer or a window layer's ring makes the pages less than
+        the context."""
+        refuse_for_recurrent(self.slot_specs, mechanism, self.ring_specs)
 
     def paged_pools(self) -> dict:
         """The page-indexed layers' pools: what allocation, COW, spill and
@@ -376,6 +487,16 @@ class PagedKVCache:
         """Total device bytes of the K/V page pools (all shards)."""
         return sum(int(a.size) * a.dtype.itemsize
                    for p in self.paged_pools().values() for a in p.values())
+
+    @property
+    def pool_bytes_by_kind(self) -> dict:
+        """`pool_bytes` by page kind: `full` = layers under the logical
+        table (a whole context a slot), `window` = layers held as rings."""
+        out = {"full": 0, "window": 0}
+        for name, p in self.paged_pools().items():
+            out["window" if name in self.ring_specs else "full"] += sum(
+                int(a.size) * a.dtype.itemsize for a in p.values())
+        return out
 
     @property
     def slot_state_bytes(self) -> int:
@@ -860,7 +981,9 @@ class PagedKVCache:
     # -- device page copy (COW) -------------------------------------------
     def _page_copy(self):
         if self._copy_fn is None:
-            paged = set(self.layer_specs)
+            # the allocator's pages are the FULL layers': a ring is its
+            # slot's own and never shared
+            paged = set(self.layer_specs) - set(self.ring_specs)
 
             def copy(pools, dst, src):
                 return {name: {part: a.at[dst].set(a[src])
@@ -902,6 +1025,13 @@ class PagedKVCache:
             f"free list {sorted(free)} != unreferenced pages {sorted(expect)}"
         assert not self._cached[0] and self._ref[0] == 0, \
             "trash page 0 must never be referenced or cached"
+        for name, R in self.ring_specs.items():
+            # a ring is static: its pool is the slots' rings and the trash
+            # page, whatever the allocator did
+            for part, a in self.pools[name].items():
+                assert a.shape[0] == 1 + self.num_slots * R, \
+                    f"window layer {name!r} part {part!r}: {a.shape[0]} " \
+                    f"pages, not 1 + {self.num_slots} slots x {R}"
         # host-tier accounting: bytes agree with the entries, every entry
         # belongs to the CURRENT generation (reset drains wholesale, so a
         # stale-gen entry means a drain was skipped), and the tier honors

@@ -75,7 +75,7 @@ from paddle_tpu.obs.timeseries import (HistorySampler, MetricHistory,
 from paddle_tpu.obs.trace import annotation, trace_reply
 from paddle_tpu.serving import wire
 from paddle_tpu.serving.engine import Request, ServingEngine
-from paddle_tpu.serving.paged_kv import refuse_for_recurrent
+
 from paddle_tpu.utils.stat import StatSet
 
 
@@ -233,7 +233,7 @@ class ServingServer:
                  history_retention_s: float = 1800.0, slo_specs=None):
         assert role in ("prefill", "decode", "both"), role
         if role != "both":
-            refuse_for_recurrent(engine.kv.slot_specs, "role")
+            engine.kv.refuse("role")
         self.engine = engine
         self.host = host
         self.port = port
@@ -460,6 +460,22 @@ class ServingServer:
                  float(eng.kv.slot_state_bytes)),
                 ("serving_attn_gated_layers", "gauge", None,
                  float(eng.attn_gated_layers)),
+                # window layers held as rings of pages: pages written
+                # over, rows sent through them, and by kind the pages that
+                # hold live tokens and the pools' bytes
+                ("serving_window_pages_recycled_total", "counter", None,
+                 float(eng.n_window_pages_recycled)),
+                ("serving_window_rows_total", "counter", None,
+                 float(eng.n_window_rows)),
+                ("serving_window_steps_total", "counter", None,
+                 float(eng.n_window_steps)),
+                *(("serving_kv_pages_resident", "gauge", {"kind": kind},
+                   float(n))
+                  for kind, n in sorted(eng.kv_pages_resident().items())),
+                *(("serving_kv_pool_bytes", "gauge", {"kind": kind},
+                   float(n))
+                  for kind, n in sorted(
+                      eng.kv.pool_bytes_by_kind.items())),
                 # tokens the recurrent layers ran as decode rows (`step`)
                 # and as prompt chunks' runs (`segment`), one layer's worth
                 *(("serving_recurrent_tokens_total", "counter",
@@ -1749,6 +1765,12 @@ class ServingServer:
             # every pool's bytes, `<layer>.<part>`; attention layers whose
             # result is gated in front of the output projection
             "cache_bytes_by_part": eng.kv.bytes_by_part,
+            # the page pools' bytes by kind (full / window rings), the
+            # rings' pages a slot, and what the rings recycled
+            "kv_pool_bytes_by_kind": eng.kv.pool_bytes_by_kind,
+            "ring_pages": dict(eng.kv.ring_specs),
+            "window_pages_recycled": eng.n_window_pages_recycled,
+            "window_rows": eng.n_window_rows,
             "attn_gated_layers": eng.attn_gated_layers,
         }
 

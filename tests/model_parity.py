@@ -130,6 +130,18 @@ def nemotron_pattern(c):
         first:first + c["num_hidden_layers"]]
 
 
+# the Laguna reference with the whole head rotated in a full layer: the
+# published `rope_parameters`, the full layers' partial_rotary_factor 1
+LAGUNA_WHOLE_HEAD_ROTATED = {"rope_parameters": {
+    "full_attention": {
+        "rope_theta": 500000, "rope_type": "yarn", "factor": 64,
+        "original_max_position_embeddings": 4096, "beta_slow": 1,
+        "beta_fast": 64, "attention_factor": 1.4158883083359672,
+        "partial_rotary_factor": 1.0},
+    "sliding_attention": {"rope_type": "default", "rope_theta": 10000,
+                          "partial_rotary_factor": 1}}}
+
+
 CASES = {c.name: c for c in (
     ModelCase(
         name="gigachat3", json="gigachat3.1-702b-a36b-serve.json",
@@ -328,6 +340,66 @@ CASES = {c.name: c for c in (
             kda_head_dim="linear_attn_config.head_dim",
             short_conv_kernel_size="linear_attn_config.short_conv_kernel_size"),
         dsl_defaults=20),
+    ModelCase(
+        name="laguna", json="laguna-xs2-33b-serve.json", dsl="laguna.py",
+        # the published ratios: full layers of 6 query heads and window
+        # layers of 8 over 2 KV heads of 16 (48 : 64 over 8), a window of 8
+        # tokens = 2 pages of 4; 16 experts, top-4, every one held; layers
+        # 0-4: full + dense, three window + sparse, full + sparse
+        tiny=dict(hidden_size=32, intermediate_size=64, num_attention_heads=6,
+                  num_key_value_heads=2, head_dim=16, num_hidden_layers=5,
+                  vocab_size=64, sliding_window=8, moe_intermediate_size=16,
+                  shared_expert_intermediate_size=16, num_experts=16,
+                  num_experts_per_tok=4, param_dtype="float32", init_std=0.3),
+        dsl_keys=("head_dim", "sliding_window", "gating",
+                  "moe_intermediate_size", "shared_expert_intermediate_size",
+                  "num_experts", "num_experts_per_tok",
+                  "moe_routed_scaling_factor", "rms_norm_eps"),
+        renamed=dict(
+            kv_heads="num_key_value_heads", rope_theta="rope_theta",
+            partial_rotary_factor=
+            "rope_parameters.full_attention.partial_rotary_factor"),
+        derived=dict(
+            layer_types=lambda c: ";".join(t[0] for t in c["layer_types"]),
+            mlp_layer_types=lambda c: ";".join(
+                t[0] for t in c["mlp_layer_types"]),
+            num_attention_heads_per_layer=lambda c: ";".join(
+                map(str, c["num_attention_heads_per_layer"]))),
+        tol=1e-4, whole_len=40,
+        # the gate's matrix gone (sigmoid(0): every gate one half); the
+        # reference without the window; with the whole head rotated in a
+        # full layer; with no gate
+        zeroed=("_blk1_attn.w4",),
+        ref_controls=({"sliding_window": 0}, LAGUNA_WHOLE_HEAD_ROTATED,
+                      {"gating": False}),
+        ragged_kernels=(False, True),
+        paged={f"blk{i}_attn": (2, 16) for i in range(5)}, margin=True,
+        engines=(EngineCase("chunked-jnp", 5),
+                 EngineCase("chunked-kernel", 5, True, build=AUTO),
+                 EngineCase("free-rows", 5, mst=34)),
+        letters={"multi_head_attention": "A"},
+        depths=(({}, "AAAAA", "deeee"),
+                ({"num_hidden_layers": 2}, "AA", "de"),
+                ({"num_hidden_layers": 9}, "A" * 9, "d" + "e" * 8)),
+        catalog="Laguna-XS.2", reduced=frozenset({"num_hidden_layers"}),
+        dsl_nested=dict(
+            layer_types=lambda c, ref: [t[0] for t in c["layer_types"]],
+            mlp_layer_types=lambda c, ref: [
+                t[0] for t in c["mlp_layer_types"]],
+            partial_rotary_factor=
+            "rope_parameters.full_attention.partial_rotary_factor",
+            yarn_factor="rope_parameters.full_attention.factor",
+            original_max_position_embeddings=
+            "rope_parameters.full_attention."
+            "original_max_position_embeddings",
+            beta_fast="rope_parameters.full_attention.beta_fast",
+            beta_slow="rope_parameters.full_attention.beta_slow",
+            attention_factor=
+            "rope_parameters.full_attention.attention_factor",
+            window_rope_theta="rope_parameters.sliding_attention.rope_theta",
+            window_partial_rotary_factor=
+            "rope_parameters.sliding_attention.partial_rotary_factor"),
+        dsl_defaults=21),
 )}
 
 

@@ -482,3 +482,24 @@ def test_allocator_spill_restore_roundtrip_unit(tr):
     assert kv.host_page_count == kv.n_spilled - kv.n_restored - \
         kv.n_host_evicted - kv._host_drained
     kv.check()
+
+
+def test_the_spill_tier_refuses_window_rings_by_name():
+    """A spilled page could not bring the slot's ring back: the budget is
+    refused where it is set, at construction and later."""
+    from paddle_tpu.serving.paged_kv import RING_REFUSALS
+    cfg = parse_config("demo/model_zoo/transformer_lm.py",
+                       "vocab=23,dim=16,layers=2,heads=2,batch_size=4,"
+                       "window=6")
+    wtr = Trainer(cfg, seed=7)
+    kw = dict(num_slots=2, page_size=4, max_context=32)
+    for make in (lambda: ServingEngine(wtr.executor, wtr.params,
+                                       spill_bytes_budget=BIG, **kw),
+                 lambda: ServingEngine(wtr.executor, wtr.params,
+                                       **kw).set_spill_budget(BIG)):
+        with pytest.raises(ValueError) as e:
+            make()
+        assert "the KV spill tier" in str(e.value)
+        assert RING_REFUSALS["spill"] in str(e.value)
+    # a cache built by hand without rings spills as ever
+    PagedKVCache(wtr.executor, 2, 4, 8, spill_bytes_budget=BIG).check()

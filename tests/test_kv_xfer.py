@@ -245,3 +245,22 @@ def test_import_prefix_requires_prefix_cache(tr):
     with pytest.raises(ValueError, match="prefix cache"):
         b.import_prefix(toks, meta, payload)
     assert b.export_prefix(prompt) is None
+
+
+def test_export_and_import_refuse_window_rings_by_name():
+    """Pushed pages carry no ring: both ends of the transfer plane refuse a
+    model whose window layers hold rings, by name."""
+    from paddle_tpu.serving.paged_kv import RING_REFUSALS
+    cfg = parse_config("demo/model_zoo/transformer_lm.py",
+                       "vocab=23,dim=16,layers=2,heads=2,batch_size=4,"
+                       "window=6")
+    wtr = Trainer(cfg, seed=7)
+    eng = ServingEngine(wtr.executor, wtr.params, num_slots=2, page_size=4,
+                        max_context=32)
+    with pytest.raises(ValueError) as e:
+        eng.export_prefix([1, 2, 3, 4])
+    assert RING_REFUSALS["export"] in str(e.value)
+    with pytest.raises(ValueError) as e:
+        eng.import_prefix([1, 2, 3, 4], {"n_pages": 1}, b"")
+    assert RING_REFUSALS["import"] in str(e.value)
+    eng.kv.check()

@@ -972,3 +972,56 @@ def test_train_step_all_reduces_run_beside_work(topo, no_persistent_cache,
     assert forms["sync"]["bytes"] < 0.03 * total, forms
     assert all(c["bytes"] <= 1 << 20 for c in found if c["form"] == "sync"), \
         [c for c in found if c["form"] == "sync"]
+
+
+# laguna-xs2-33b-serve.long-context-64's own shapes (benchmark/configs/
+# laguna-xs2-33b-serve.json: 64 slots, page 16, context 8,192, a window of
+# 512 in rings of 53 pages a slot; 64 query heads over 8 KV heads of 128 in
+# a window layer, 48 in a full layer; 320 rows a mixed step, bf16)
+LAGUNA = dict(S=64, PAGE=16, MAXP=8192 // 16, RING=53, WINDOW=512, H_KV=8,
+              D=128, ROWS=320)
+
+
+@pytest.mark.parametrize("kind,heads", [("window", 64), ("full", 48)])
+@pytest.mark.parametrize("form", ["decode", "mixed-320-rows"])
+def test_paged_kernels_at_the_laguna_cells_shapes(mosaic, form, kind, heads):
+    """A window layer's call — `window_attn`, over the slots' rings: one
+    Pallas call a layer a step under a name of its own, and the ring's pool
+    (1 + 64 x 53 pages) donated without a copy — and a full layer's
+    `paged_attn` at a table of 512 pages a slot, by the calls the engine
+    makes."""
+    from paddle_tpu.ops.attention import (paged_attention_step,
+                                          ragged_paged_attention_step)
+    c = LAGUNA
+    window = kind == "window"
+    n_pages = 1 + c["S"] * (c["RING"] if window else c["MAXP"])
+    cols = c["RING"] if window else c["MAXP"]
+    kw = dict(window=c["WINDOW"], ring=True) if window else {}
+    pools = [((n_pages, c["PAGE"], c["H_KV"], c["D"]), bf16)] * 2
+    if form == "decode":
+        def step(q, k, v, kp, vp, table, pos):
+            return paged_attention_step(q, k, v, kp, vp, table, pos,
+                                        use_kernel=True, **kw)
+        S = c["S"]
+        compiled = mosaic(
+            step, ((S, 1, heads, c["D"]), bf16),
+            ((S, 1, c["H_KV"], c["D"]), bf16),
+            ((S, 1, c["H_KV"], c["D"]), bf16), *pools,
+            ((S, cols), i32), ((S,), i32), donate=(3, 4))
+    else:
+        def step(q, k, v, kp, vp, table, row_slot, row_pos):
+            return ragged_paged_attention_step(q, k, v, kp, vp, table,
+                                               row_slot, row_pos,
+                                               use_kernel=True, **kw)
+        T = c["ROWS"]
+        compiled = mosaic(
+            step, ((T, heads, c["D"]), bf16), ((T, c["H_KV"], c["D"]), bf16),
+            ((T, c["H_KV"], c["D"]), bf16), *pools,
+            ((c["S"] + 1, cols), i32), ((T,), i32), ((T,), i32),
+            donate=(3, 4))
+    name = "window_attn.1" if window else "paged_attn.1"
+    assert kernel_names(compiled) == [name], kernel_names(compiled)
+    import re
+    made_by = re.findall(r"= bf16\[%d,16,8,128\]\S* ([\w-]+)\(" % n_pages,
+                         compiled.as_text())
+    assert made_by and "copy" not in made_by, made_by

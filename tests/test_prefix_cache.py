@@ -733,3 +733,56 @@ def test_step_path_under_page_pressure_never_walks(tr, monkeypatch):
     monkeypatch.undo()
     eng.prefix.check_invariants()
     _pool_reclaimed(eng)
+
+
+def test_a_model_with_window_rings_serves_without_the_prefix_index():
+    """A window layer's ring is not the whole context: the engine serves
+    such a model with the prefix index off — a shared prefix is served
+    correctly as independent requests — and turning it on raises by name,
+    through the recurrent models' one function."""
+    from paddle_tpu.serving.paged_kv import RING_REFUSALS
+    cfg = parse_config("demo/model_zoo/transformer_lm.py",
+                       "vocab=23,dim=16,layers=2,heads=2,batch_size=4,"
+                       "window=6")
+    wtr = Trainer(cfg, seed=7)
+    eng = ServingEngine(wtr.executor, wtr.params, num_slots=2, page_size=4,
+                        max_context=32)
+    assert eng.prefix is None and eng.kv.ring_specs
+    shared = np.arange(2, 14, dtype=np.int32)
+    reqs = [Request(i, np.concatenate([shared, [3 + i]]).astype(np.int32),
+                    max_new=5) for i in range(3)]
+    _assert_exact(wtr, reqs, eng.run(reqs))
+    assert eng.n_prefix_hits == 0
+    with pytest.raises(ValueError) as e:
+        eng.set_prefix_cache(True)
+    assert "the prefix index" in str(e.value)
+    assert RING_REFUSALS["prefix"] in str(e.value)
+    eng.kv.check_reclaimed()
+
+
+@pytest.mark.parametrize("window", [24, 64])
+def test_a_window_that_drops_no_page_keeps_prefix_hits_and_speculation(
+        window):
+    """A ring is built only where it is smaller than a context: a window
+    of a whole context or more (64 against 32), or one whose ring of window
+    + a step's rows would hold every page (24 + 18 rows = 12 pages against
+    8), stays under the logical table — the prefix index hits, speculation
+    runs, nothing refuses, and the tokens are the windowed oracle's (29
+    tokens against the window of 24)."""
+    cfg = parse_config("demo/model_zoo/transformer_lm.py",
+                       f"vocab=23,dim=16,layers=2,heads=2,batch_size=4,"
+                       f"window={window}")
+    wtr = Trainer(cfg, seed=7)
+    eng = ServingEngine(wtr.executor, wtr.params, num_slots=2, page_size=4,
+                        max_context=32, spec_k=2)
+    assert not eng.kv.ring_specs and eng.prefix is not None
+    assert {a.shape[0] for p in eng.kv.pools.values() for a in p.values()} \
+        == {eng.kv.num_pages}
+    shared = (np.arange(20) % 19 + 2).astype(np.int32)
+    reqs = [Request(i, np.concatenate([shared, [3 + i]]).astype(np.int32),
+                    max_new=8) for i in range(3)]
+    _assert_exact(wtr, reqs, eng.run(reqs))
+    assert eng.n_prefix_hits > 0 and eng.n_spec_steps > 0
+    for mechanism in ("prefix", "spill", "export", "import", "spec", "role"):
+        eng.kv.refuse(mechanism)
+    eng.kv.check()

@@ -644,3 +644,42 @@ def test_paged_kernel_bounds_its_loop_by_the_table():
                                        jnp.asarray([n], jnp.int32)))
             for n in (maxp * ps, 2 ** 31 - 1)]
     np.testing.assert_array_equal(outs[0], outs[1])
+
+
+# -- two kinds of page: window layers hold rings --------------------------------
+
+def test_window_layers_hold_rings_and_wrap_them_exactly():
+    """A model of window layers alone (window 5, pages of 4, 4 + 2 = 6 rows
+    a step): every layer's pool is 1 + slots x ring_pages pages — the ring
+    ceil((5 + 6) / 4) + 1 = 4 pages — whatever `num_pages` the allocator
+    has; contexts of 30 tokens lap the ring of 16 twice and the tokens are
+    lm_generate's; release, preemption's release and `uncommit_tail` have
+    nothing of a ring to undo and leave `check()` clean."""
+    tr = _make("vocab=97,dim=32,layers=2,heads=4,batch_size=4,window=5")
+    prompts = _prompts((22, 9, 17), 97)
+    reqs = [Request(i, p, max_new=8) for i, p in enumerate(prompts)]
+    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
+                        max_context=32, prefill_chunk=4)
+    kv = eng.kv
+    assert eng.max_step_tokens == kv.step_tokens == 6
+    assert set(kv.ring_specs.values()) == {4} and len(kv.ring_specs) == 2
+    for name in kv.ring_specs:
+        assert {a.shape[0] for a in kv.pools[name].values()} == {1 + 2 * 4}
+    assert kv.pool_bytes_by_kind["full"] == 0
+    assert kv.pool_bytes_by_kind["window"] == kv.pool_bytes
+    assert eng.prefix is None
+    _assert_all_match(tr, reqs, eng.run(reqs))
+    assert eng.n_window_pages_recycled > 0
+    kv.check_reclaimed()
+    # the allocator's calls by hand: the logical table moves, a ring never
+    assert kv.try_grow(0, 30) and kv.try_grow(1, 9)
+    assert kv.uncommit_tail(0, 17) == 3
+    kv.check()
+    kv.release(0)
+    kv.release(1)
+    kv.check_reclaimed()
+    # a cache built by hand, without a step size: whole contexts as before
+    whole = PagedKVCache(tr.executor, 2, 4, 8)
+    assert not whole.ring_specs
+    assert {a.shape[0] for p in whole.pools.values() for a in p.values()} \
+        == {whole.num_pages}
