@@ -112,6 +112,12 @@ CATALOG: dict[str, str] = {
         "over the window layers",
     "serving_window_steps_total":
         "compiled steps that ran window layers",
+    "serving_kv_rows_total":
+        "query rows the paged attention kernel's calls carried, one "
+        "layer's worth a compiled step, padding rows included",
+    "serving_kv_shared_rows_total":
+        "of those, rows in a tile whose rows all read one slot, which "
+        "walks that slot's K/V blocks once for all of them",
     "serving_kv_pages_resident":
         "pages that hold live tokens (label kind: full = the allocator's "
         "pages in use, each backing every full layer; window = the slots' "

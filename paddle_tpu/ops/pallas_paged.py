@@ -9,22 +9,28 @@ contiguous [S, max_pages*page_size] view every step — a transient HBM copy
 of the whole context.  This kernel reads LIVE KV only, straight from the
 pool:
 
-  grid (rows,): one query row a grid step.  The pools stay in HBM
-  (memory_space=pl.ANY) in the layout the engine holds them,
-  [P, page_size, H_kv, D]; the page table, the lengths and the row->slot
-  indirection ride the scalar-prefetch channel.  Inside a step the kernel
-  loops over the row's own KV in BLOCKS of several pages (128-512 tokens;
-  `block_tokens` derives the count from page_size, H_kv, D and the dtype's
-  bytes against a VMEM budget — one algorithm adapted by shape, nothing
-  selects it), with the trip count cdiv(lengths[r], block) a run-time
-  value: a page past the row's length is never stepped, a dead or padding
-  row costs one grid step and one block of the trash page, and one
-  compiled program serves any fill of the pool.  A block's pages are
-  fetched by the kernel's own async copies addressed through
-  table[row_slot[r], ·], double-buffered: while block i is folded into
-  the running online softmax (max, sum, accumulator: float32 loop
-  carries, the recurrence of pallas_attention.py's flash kernel), block
-  i+1's copies — or the NEXT row's first block — are in flight.
+  grid (tiles,): a TILE of consecutive query rows a grid step (`tile_rows`
+  derives how many from the heads, the score columns a block, the row's
+  width and the dtype against a VMEM budget: 8 at most, 1 where nothing
+  more fits).  The pools stay in HBM (memory_space=pl.ANY) in the layout
+  the engine holds them, [P, page_size, H_kv, D]; the page table, the
+  lengths and the row->slot indirection ride the scalar-prefetch channel.
+  Inside a step the kernel WALKS a row's own KV in BLOCKS of several pages
+  (128-512 tokens; `block_tokens` derives the count from page_size, H_kv,
+  D and the dtype's bytes against a VMEM budget — one algorithm adapted by
+  shape, nothing selects it), with the trip count cdiv(lengths[r], block)
+  a run-time value: a page past the row's length is never stepped, a dead
+  or padding row costs one block of the trash page, and one compiled
+  program serves any fill of the pool.  A block's pages are fetched by the
+  kernel's own async copies addressed through table[row_slot[r], ·],
+  double-buffered: while block i is folded into the running online softmax
+  (max, sum, accumulator: float32 loop carries, the recurrence of
+  pallas_attention.py's flash kernel), block i+1's copies — or the NEXT
+  walk's first block — are in flight.  A tile whose rows read different
+  table rows (decode rows, the ragged ends of a prompt chunk's run) walks
+  one row after another; a tile whose rows all read ONE table row walks
+  that slot's blocks once, every block scored against all the tile's rows
+  (MIXED, below).
 
 A BLOCK IN VMEM is the matmul operand itself: a double buffer
 [2, pages*page_size*H_kv, D] a pool, each page's copy landing in its own
@@ -64,10 +70,23 @@ generalizes the query dimension from one-token-per-slot to a packed
 ragged row list — row r attends table row `row_slot[r]` up to
 `lengths[r]` tokens, so a prompt chunk (several consecutive rows, same
 slot) and live decode rows share one grid.  `row_slot` rides the same
-scalar-prefetch channel as the page table; everything else (the block
-loop over live KV, the online softmax, in-kernel GQA) is unchanged.  A
-chunk's rows each walk their slot's blocks again: sharing a block among
-the rows of one slot is not done here.
+scalar-prefetch channel as the page table.  A chunk's rows differ in
+nothing but where their causal mask ends, so a tile that lies inside a
+chunk's run SHARES ONE WALK: each fetched block enters the two dots once,
+as [Bq*Hp, D] x [C, D]^T against the whole tile's heads, one online-softmax
+carry a row, each row masked at its own `lengths[r]`, the loop run to the
+tile's longest row — Bq rows for the copies (and the loop step) of one.
+Which walk a tile takes is read from `row_slot` itself (`_tile_walks`: a
+few comparisons under the step's jit, one more prefetched operand, one
+branch a tile — never one a page); the arithmetic of a row is the same
+either way.  At the Laguna full layer (48 heads over 8 KV heads of 128,
+1,024 score columns a block) a shared block of 8 rows costs 2.2 us where
+8 rows' own walks cost 8 x 0.83, and the 64 decode rows of a step cost
+what they did (my chip run, PR 53: tools/bench_paged.py --shapes
+laguna-mixed --tile-rows 1,8 — 6.91 -> 3.42 ms a call).  A windowed call's
+rows each read a table row of their own, so its tiles all walk row by row;
+`walked_blocks` is the count the engine keeps of all this
+(kv_tokens_fetched, serving_kv_shared_rows_total).
 
 SPECULATIVE verify rows (the engine's `--spec-k` draft chains) are the
 same row-indirected shape from this kernel's point of view: a chain is
@@ -88,7 +107,7 @@ here are the per-device counts (H/N and h_kv/N of the model; the engine
 validates divisibility, and the grouped-query ratio H/h_kv is shard-
 invariant).  The kernel itself needs no collective and no change: page
 tables and lengths arrive replicated, every DMA stays on-chip, and the
-head padding and the block size below follow the LOCAL counts.
+head padding, the block size and the tile below follow the LOCAL counts.
 
 MULTI-STEP decode (the engine's `--decode-steps K` scanned dispatch):
 the kernel is scan-body safe — pure in its operands with no host
@@ -207,17 +226,82 @@ def block_tokens(page_size: int, h_kv: int, head_dim: int, itemsize: int,
     return pages * page_size
 
 
-def _kernel(H, h_kv, scale, v_width, windowed, table_ref, len_ref, row_ref,
-            *rest):
-    """One query row against its slot's live KV.  `v_width` None: K and V
-    pools of [P, ps, h_kv, Dp] (grouped-query heads).  `v_width` set: ONE
-    latent pool of [P, ps, W] whose rows are both — every query head scores
-    the whole row and weighs its first `v_width` columns (ops/mla.py), so a
-    block is fetched once and there are no groups to mask.  `windowed`: a
-    fourth prefetched operand, the first token of the row's table row that
-    the query may see (the window's lower edge, below the row's length:
-    the mask is first <= t < length)."""
-    first_ref = None
+def _head_rows(H: int, dtype) -> int:
+    """q's rows fill whole sublane tiles of its dtype (8 rows of 32 bits)."""
+    return _round_up(H, 8 * max(1, 4 // jnp.dtype(dtype).itemsize))
+
+
+#: VMEM a tile of query rows may take beside the K/V buffers: its float32
+#: scores and their weights, q and the output (two buffers each, the grid's
+#: pipeline) and the float32 accumulator.  At the Laguna full layer (48
+#: heads against 1,024 score columns) 8 rows are 3.7 MB, and a shared block
+#: then costs 2.2 us of vector and MXU work against 0.64 of copies (4 rows:
+#: 1.3 — my chip run, PR 53), so a wider tile buys nothing: the ceiling.
+_TILE_VMEM_BUDGET = 4 << 20
+_TILE_ROWS = 8
+
+
+def tile_rows(rows: int, heads: int, cols: int, width: int, dtype) -> int:
+    """Query rows one grid step holds (a TILE): a power of two derived from
+    the shapes alone, as `block_tokens` is — `heads` query heads (padded to
+    q's sublane tiles) against `cols` score columns a block and a row of
+    `width` lanes of `dtype`, within `_TILE_VMEM_BUDGET`, at most
+    `_TILE_ROWS` and never more than the call's `rows` rounded up.  The engine calls this to count what the kernel
+    fetches (`walked_blocks`)."""
+    a_row = _head_rows(heads, dtype) * (
+        2 * 4 * cols
+        + _round_up(width, 128) * (4 + 4 * jnp.dtype(dtype).itemsize))
+    bq = 1
+    while 2 * bq <= min(_TILE_ROWS, _TILE_VMEM_BUDGET // a_row) \
+            and bq < rows:
+        bq *= 2
+    return bq
+
+
+def _tile_walks(xp, lengths, row_slot, bq: int):
+    """[tiles] the length a tile's SHARED walk runs to — its longest row's —
+    where all its rows read one table row, else -1: the tile walks row by
+    row.  `xp` is jnp under the step's jit and numpy on the host."""
+    slots = row_slot.reshape(-1, bq)
+    uniform = (slots == slots[:, :1]).all(axis=1)
+    return xp.where(uniform, lengths.reshape(-1, bq).max(axis=1), -1)
+
+
+def walked_blocks(lengths, row_slot, bq: int, bt: int):
+    """(blocks fetched, rows on a shared walk) of one call over host arrays:
+    a tile whose rows read one table row folds each block once, to its
+    longest row; every other row walks its own, a dead one a block.
+    `row_slot` None: the rows are the slots, and none shares."""
+    import numpy as np
+    lengths = np.asarray(lengths).reshape(-1)
+    alone = np.maximum(-(-lengths // bt), 1)
+    if bq == 1 or row_slot is None:
+        return int(alone.sum()), 0
+    pad = -lengths.size % bq            # as `_call` pads: whole tiles
+    walk = _tile_walks(np, np.pad(lengths, (0, pad)),
+                       np.pad(np.asarray(row_slot), (0, pad), mode="edge"),
+                       bq)
+    shared = np.repeat(walk >= 0, bq)[:lengths.size]
+    return int(alone[~shared].sum() + pad * (walk[-1] < 0)
+               + np.maximum(-(-walk[walk >= 0] // bt), 1).sum()), \
+        int(shared.sum())
+
+
+def _kernel(H, h_kv, scale, v_width, windowed, tiled, table_ref, len_ref,
+            row_ref, *rest):
+    """One TILE of query rows against their slots' live KV.  `v_width` None:
+    K and V pools of [P, ps, h_kv, Dp] (grouped-query heads).  `v_width`
+    set: ONE latent pool of [P, ps, W] whose rows are both — every query
+    head scores the whole row and weighs its first `v_width` columns
+    (ops/mla.py), so a block is fetched once and there are no groups to
+    mask.  `tiled`: a fourth prefetched operand, `_tile_walks` — where it
+    is not negative the tile's rows read one table row and walk its blocks
+    ONCE, together.  `windowed`: a prefetched operand more, the first token
+    of the row's table row that the query may see (the window's lower
+    edge, below the row's length: the mask is first <= t < length)."""
+    walk_ref = first_ref = None
+    if tiled:
+        walk_ref, *rest = rest
     if windowed:
         first_ref, *rest = rest
     q_ref, *rest = rest
@@ -228,8 +312,9 @@ def _kernel(H, h_kv, scale, v_width, windowed, table_ref, len_ref, row_ref,
         k_hbm, o_ref, kbuf, sems, slot_ref = rest
         vbuf = None
         pools = ((k_hbm, kbuf),)
-    r = pl.program_id(0)
-    n_rows = pl.num_programs(0)
+    t = pl.program_id(0)
+    Bq, Hp, _ = q_ref.shape
+    n_rows = pl.num_programs(0) * Bq
     # a page's rows of the operand, however the pool folds them (a lone
     # head's tokens are stored two a row: `kv_page_shape`)
     rows = k_hbm.shape[1] if len(k_hbm.shape) == 3 \
@@ -239,7 +324,6 @@ def _kernel(H, h_kv, scale, v_width, windowed, table_ref, len_ref, row_ref,
     bt = C // h_kv                      # tokens a block
     npb = bt // ps
     maxp = table_ref.shape[1]
-    Hp = q_ref.shape[1]
     Dv = o_ref.shape[-1]
     rep = H // h_kv
 
@@ -265,108 +349,175 @@ def _kernel(H, h_kv, scale, v_width, windowed, table_ref, len_ref, row_ref,
             pltpu.make_async_copy(
                 buf.at[slot], buf.at[slot], sems.at[j, slot]).wait()
 
-    @pl.when(r == 0)
+    @pl.when(t == 0)
     def _():
         slot_ref[0] = 0
         start_fetch(0, 0, 0)
 
-    # never past what the table maps, whatever `lengths` holds: the trip
-    # count is a run-time value, and a corrupted one would be device time
-    length = jnp.minimum(len_ref[r], maxp * ps)
-    # every row folds at least one block (a dead or padding row: one block
-    # of the trash page), so each row's last block can always prefetch the
-    # next row's first
-    nblk = jnp.maximum(pl.cdiv(length, bt), 1)
-    q = q_ref[0]                                          # [Hp, Dp]
-    # column c of a block is token c // h_kv under kv head c % h_kv; query
-    # head h reads kv head h // rep.  Scoring every head against every
-    # column and masking the other groups keeps the block one dense
-    # [bt*h_kv, Dp] operand as the pool stores it — no per-head gather —
-    # and costs the MXU nothing it was not already paying to load K.
-    col = jax.lax.broadcasted_iota(jnp.int32, (Hp, C), 1)
-    tok = col // h_kv
-    # one KV head under unpadded query heads: every column is every
-    # head's own, nothing to mask but the row's length
-    own_group = None if (h_kv == 1 and Hp == H) else \
-        (col % h_kv) == (jax.lax.broadcasted_iota(jnp.int32, (Hp, C), 0)
-                         // rep)
+    def live(r):
+        # never past what the table maps, whatever `lengths` holds: the
+        # trip count is a run-time value, and a corrupted one would be
+        # device time
+        return jnp.minimum(len_ref[r], maxp * ps)
 
-    def fold(b, carry):
-        m_prev, l_prev, acc, slot = carry
-        last = b == nblk - 1
-        nrow = jnp.where(last, r + 1, r)
-        nb = jnp.where(last, 0, b + 1)
+    def walk(q, r, nxt, length, longest, first, slot):
+        """Fold the blocks of row `r`'s table row into q's rows [M, Dp] —
+        M = Hp, one query row, or a whole tile's Bq * Hp — each masked at
+        its own `length` (a scalar, or [M, 1]), the loop run to `longest`;
+        the last block prefetches row `nxt`'s first.  -> (out [M, Dv], the
+        buffer that prefetch lands in)."""
+        M = q.shape[0]
+        # every walk folds at least one block (a dead or padding row: one
+        # block of the trash page), so its last block can always prefetch
+        # the next walk's first
+        nblk = jnp.maximum(pl.cdiv(longest, bt), 1)
+        # column c of a block is token c // h_kv under kv head c % h_kv;
+        # query head h reads kv head h // rep.  Scoring every head against
+        # every column and masking the other groups keeps the block one
+        # dense [bt*h_kv, Dp] operand as the pool stores it — no per-head
+        # gather — and costs the MXU nothing it was not already paying to
+        # load K.
+        col = jax.lax.broadcasted_iota(jnp.int32, (M, C), 1)
+        tok = col // h_kv
+        # a tile's rows are Hp heads a query row
+        head = jax.lax.broadcasted_iota(jnp.int32, (M, C), 0) if M == Hp \
+            else jax.lax.broadcasted_iota(jnp.int32, (M, 1), 0) % Hp
+        # one KV head under unpadded query heads: every column is every
+        # head's own, nothing to mask but the row's length
+        own_group = None if (h_kv == 1 and Hp == H) else \
+            (col % h_kv) == head // rep
 
-        @pl.when(nrow < n_rows)
-        def _():
-            start_fetch(nrow, nb, 1 - slot)
+        def fold(b, carry):
+            m_prev, l_prev, acc, slot = carry
+            last = b == nblk - 1
+            nrow = jnp.where(last, nxt, r)
+            nb = jnp.where(last, 0, b + 1)
 
-        wait_fetch(slot)
-        k = kbuf[slot]                                    # [C, Dp]
-        v = k[:, :Dv] if vbuf is None else vbuf[slot]
-        sc = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [Hp, C]
-        valid = tok < length - b * bt
-        if first_ref is not None:
-            valid = jnp.logical_and(valid, tok >= first_ref[r] - b * bt)
-        if own_group is not None:
-            valid = jnp.logical_and(own_group, valid)
-        sc = jnp.where(valid, sc, _NEG_INF)
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-        w = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = corr * l_prev + jnp.sum(w, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            w.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [Hp, Dv]
-        return m_new, l_new, acc * corr + pv, 1 - slot
+            @pl.when(nrow < n_rows)
+            def _():
+                start_fetch(nrow, nb, 1 - slot)
 
-    _, l, acc, slot = jax.lax.fori_loop(
-        0, nblk, fold,
-        (jnp.full((Hp, 1), _NEG_INF, jnp.float32),
-         jnp.zeros((Hp, 1), jnp.float32),
-         jnp.zeros((Hp, Dv), jnp.float32),
-         slot_ref[0]))
-    slot_ref[0] = slot
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+            wait_fetch(slot)
+            k = kbuf[slot]                                    # [C, Dp]
+            v = k[:, :Dv] if vbuf is None else vbuf[slot]
+            sc = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # [M, C]
+            valid = tok < length - b * bt
+            if first is not None:
+                valid = jnp.logical_and(valid, tok >= first - b * bt)
+            if own_group is not None:
+                valid = jnp.logical_and(own_group, valid)
+            sc = jnp.where(valid, sc, _NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+            w = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_new = corr * l_prev + jnp.sum(w, axis=-1, keepdims=True)
+            pv = jax.lax.dot_general(
+                w.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)           # [M, Dv]
+            return m_new, l_new, acc * corr + pv, 1 - slot
+
+        _, l, acc, slot = jax.lax.fori_loop(
+            0, nblk, fold,
+            (jnp.full((M, 1), _NEG_INF, jnp.float32),
+             jnp.zeros((M, 1), jnp.float32),
+             jnp.zeros((M, Dv), jnp.float32),
+             slot))
+        return (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype), slot
+
+    r0 = t * Bq
+
+    def one_row(i, slot):
+        r = r0 + i
+        length = live(r)
+        o_ref[i], slot = walk(
+            q_ref[i], r, r + 1, length, length,
+            None if first_ref is None else first_ref[r], slot)
+        return slot
+
+    def by_row():
+        slot_ref[0] = jax.lax.fori_loop(0, Bq, one_row, slot_ref[0])
+
+    if not tiled:
+        by_row()
+        return
+    pl.when(walk_ref[t] < 0)(by_row)
+
+    @pl.when(walk_ref[t] >= 0)
+    def _():
+        # the tile's rows as one operand; each row's length down its heads
+        at = jax.lax.broadcasted_iota(jnp.int32, (Bq * Hp, 1), 0)
+        length = jnp.zeros((Bq * Hp, 1), jnp.int32)
+        for i in range(Bq):
+            length = jnp.where(at >= i * Hp, live(r0 + i), length)
+        out, slot_ref[0] = walk(
+            q_ref[...].reshape(Bq * Hp, q_ref.shape[-1]), r0, r0 + Bq,
+            length, jnp.minimum(walk_ref[t], maxp * ps), None, slot_ref[0])
+        o_ref[...] = out.reshape(Bq, Hp, Dv)
 
 
-def _head_rows(H: int, dtype) -> int:
-    """q's rows fill whole sublane tiles of its dtype (8 rows of 32 bits)."""
-    return _round_up(H, 8 * max(1, 4 // jnp.dtype(dtype).itemsize))
-
-
-def _call(name: str, kernel, qp: Array, pools: tuple, buf_shape: tuple,
-          out_width: int, page_table: Array, lengths: Array,
-          row_slot: Array, first: Optional[Array] = None) -> Array:
-    """The one pallas_call of the family: grid over query rows, the pools
-    in HBM, table / lengths / row->slot (and a windowed call's `first`) on
-    the scalar-prefetch channel, a double buffer of `buf_shape` a pool."""
-    R, Hp, Dq = qp.shape
-    index = lambda r, *prefetched: (r, 0, 0)
-    scalars = (page_table, lengths, row_slot) + \
-        (() if first is None else (first,))
+@functools.lru_cache(maxsize=None)
+def _program(name: str, kernel_args: tuple, bq: int, tiles: int,
+             q_row: tuple, pools: tuple, buf_shape: tuple, out_width: int,
+             dtype, interpret: bool):
+    """The family's one pallas_call for one set of shapes, built ONCE: the
+    layers of a step (and the steps of a process) that call with the same
+    shapes share the callable, so jit traces the kernel's two walks once for
+    all of them (a trace and a lowering a layer was 5.6 s of the Laguna
+    cell's warm start-up: my chip runs, PR 53).  `kernel_args` are
+    `_kernel`'s statics; `pools` the pools' dtypes."""
+    _, _, _, _, windowed, tiled = kernel_args
+    Hp, Dq = q_row
+    index = lambda t, *prefetched: (t, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(scalars),
-        grid=(R,),
-        in_specs=[pl.BlockSpec((1, Hp, Dq), index)]
+        num_scalar_prefetch=3 + tiled + windowed,
+        grid=(tiles,),
+        in_specs=[pl.BlockSpec((bq, Hp, Dq), index)]
         + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),  # stay in HBM
-        out_specs=pl.BlockSpec((1, Hp, out_width), index),
-        scratch_shapes=[pltpu.VMEM((2,) + buf_shape, p.dtype) for p in pools]
+        out_specs=pl.BlockSpec((bq, Hp, out_width), index),
+        scratch_shapes=[pltpu.VMEM((2,) + buf_shape, p) for p in pools]
         + [pltpu.SemaphoreType.DMA((len(pools), 2)),      # (pool, buffer)
-           pltpu.SMEM((1,), jnp.int32)],          # buffer the next row reads
+           pltpu.SMEM((1,), jnp.int32)],         # buffer the next walk reads
     )
     return pl.pallas_call(
-        kernel,
+        functools.partial(_kernel, *kernel_args),
         name=name,              # the device op's name in a profiler trace
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, Hp, out_width), qp.dtype),
-        # rows run in order: each prefetches its successor's first block
+        out_shape=jax.ShapeDtypeStruct((tiles * bq, Hp, out_width), dtype),
+        # tiles run in order: each prefetches its successor's first block
         compiler_params=pallas_tpu_compiler_params(
             dimension_semantics=("arbitrary",)),
-        interpret=_interpret(),
-    )(*(a.astype(jnp.int32) for a in scalars), qp, *pools)
+        interpret=interpret,
+    )
+
+
+def _call(name: str, kernel_args: tuple, bq: int, qp: Array, pools: tuple,
+          buf_shape: tuple, out_width: int, page_table: Array,
+          lengths: Array, row_slot: Array,
+          first: Optional[Array] = None) -> Array:
+    """The one pallas_call of the family: grid over TILES of `bq` query
+    rows, the pools in HBM, table / lengths / row->slot (with the tiles'
+    shared walks, or a windowed call's `first`) on the scalar-prefetch
+    channel, a double buffer of `buf_shape` a pool.  `kernel_args`:
+    `_kernel`'s H, h_kv, scale and v_width."""
+    R, Hp, Dq = qp.shape
+    pad = -R % bq
+    if pad:     # whole tiles: dead rows of the last row's slot
+        qp = jnp.pad(qp, ((0, pad), (0, 0), (0, 0)))
+        lengths = jnp.pad(lengths, (0, pad))
+        row_slot = jnp.pad(row_slot, (0, pad), mode="edge")
+    scalars = (page_table, lengths, row_slot)
+    tiled = first is None and bq > 1
+    if tiled:
+        scalars += (_tile_walks(jnp, lengths, row_slot, bq),)
+    if first is not None:
+        scalars += (jnp.pad(first, (0, pad)),)
+    program = _program(
+        name, kernel_args + (first is not None, tiled), bq, (R + pad) // bq,
+        (Hp, Dq), tuple(p.dtype for p in pools), buf_shape, out_width,
+        qp.dtype, _interpret())
+    return program(*(a.astype(jnp.int32) for a in scalars), qp, *pools)[:R]
 
 
 def paged_attention(
@@ -454,8 +605,8 @@ def paged_attention(
         k_pages, v_pages = (jnp.pad(p, ((0, 0),) * 3 + ((0, Dp - L),))
                             for p in (k_pages, v_pages))
     out = _call("paged_attn" if first is None else "window_attn",
-                functools.partial(_kernel, H, G, scale, None,
-                                  first is not None),
+                (H, G, float(scale), None),
+                tile_rows(R, H, npb * ps * G, Dp, q.dtype),
                 qp, (k_pages, v_pages), (npb * ps * G, Dp), Dp, page_table,
                 lengths, row_slot, first)[:, :H, :L]
     if pack > 1:
@@ -492,11 +643,11 @@ def latent_paged_attention(
     if row_slot is None:
         row_slot = jnp.arange(R, dtype=jnp.int32)
     Hp = _head_rows(H, q.dtype)
-    npb = block_tokens(ps, 1, W, jnp.dtype(kv_pages.dtype).itemsize,
-                       maxp) // ps
+    itemsize = jnp.dtype(kv_pages.dtype).itemsize
+    npb = block_tokens(ps, 1, W, itemsize, maxp) // ps
     qp = jnp.pad(q, ((0, 0), (0, Hp - H), (0, 0)))
-    out = _call("mla_paged_attn",
-                functools.partial(_kernel, H, 1, scale, v_width, False), qp,
+    out = _call("mla_paged_attn", (H, 1, float(scale), v_width),
+                tile_rows(R, H, npb * ps, W, q.dtype), qp,
                 (kv_pages,), (npb * ps, W), v_width, page_table, lengths,
                 row_slot)
     return out[:, :H]

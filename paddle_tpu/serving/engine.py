@@ -476,9 +476,13 @@ class ServingEngine:
         # what the paged kernel reads, summed over compiled steps (one
         # layer's worth a step): the KV tokens its rows attend, and the
         # tokens it fetches for them in whole blocks (ops/pallas_paged.py
-        # block_tokens).  attended / fetched is the block fill.
+        # block_tokens), a block once a TILE of rows where the tile's rows
+        # are one slot's (tile_rows).  attended / fetched is the block
+        # fill; kv_shared_rows of kv_rows rode such a shared walk.
         self.kv_tokens_attended = 0
         self.kv_tokens_fetched = 0
+        self.n_kv_rows = 0
+        self.n_kv_shared_rows = 0
         self._admit_seq = 0
         # ONE STEP IN FLIGHT (docs/serving.md "The step loop"): a decode
         # or mixed step is two halves, LAUNCH (plan, pack, dispatch) and
@@ -507,18 +511,28 @@ class ServingEngine:
         S = num_slots
         self._kk = self.kv.capacity_tokens     # keys per slot (> max_new)
         from paddle_tpu.ops.pallas_paged import block_tokens
-        paged = self.kv.paged_pools()
+        # the kernel's shapes at the first layer under the logical table (a
+        # ring's rows each read a table row of their own: no tile is shared)
+        paged = [l for l in executor.model.layers
+                 if l.name in self.kv.layer_specs]
         if paged:
-            pool = next(iter(next(iter(paged.values())).values()))
+            layer = min(paged, key=lambda l: l.name in self.kv.ring_specs)
+            pool = next(iter(self.kv.pools[layer.name].values()))
             # a latent pool's row is one [W] vector: one KV "head" of
             # width W; a page's rows over its tokens, however it folds them
             h_kv = pool.shape[1] * pool.shape[2] // self.kv.page_size \
                 if pool.ndim == 4 else 1
+            h_kv //= self.kv.tp_shards
             self._kv_block = block_tokens(
-                self.kv.page_size, h_kv // self.kv.tp_shards,
-                pool.shape[-1], pool.dtype.itemsize, self.kv.pages_per_slot)
+                self.kv.page_size, h_kv, pool.shape[-1],
+                pool.dtype.itemsize, self.kv.pages_per_slot)
+            # what `tile_rows` takes after the call's rows
+            self._kv_tile = (
+                int(layer.attrs["num_heads"]) // self.kv.tp_shards,
+                self._kv_block * h_kv, pool.shape[-1], pool.dtype)
         else:       # no page-indexed part: no kernel fetches any block
             self._kv_block = self.kv.page_size
+            self._kv_tile = None
         # routed-pair counters of the held experts (docs/observability.md):
         # the steps return, behind the tokens they already read back, the
         # pairs each held expert drew, summed over the MoE layers
@@ -1526,11 +1540,22 @@ class ServingEngine:
                             for s, sl in enumerate(self.slots)), np.int64,
                            len(self.slots))
 
-    def _count_kv(self, lengths: np.ndarray) -> None:
-        """Add one compiled step's rows to the kernel's two counters."""
-        bt = self._kv_block
+    def _count_kv(self, lengths: np.ndarray,
+                  row_slot: Optional[np.ndarray] = None) -> None:
+        """Add one compiled step's rows to the kernel's counters: `lengths`
+        the tokens each row attends, `row_slot` the table row it reads
+        (None: the rows are the slots, and no two share a walk)."""
+        from paddle_tpu.ops.pallas_paged import tile_rows, walked_blocks
+        bq = 1 if row_slot is None or self._kv_tile is None else \
+            tile_rows(lengths.size, *self._kv_tile)
+        blocks, shared = walked_blocks(lengths, row_slot, bq, self._kv_block)
         self.kv_tokens_attended += int(lengths.sum())
-        self.kv_tokens_fetched += int((-(-lengths // bt)).sum()) * bt
+        self.kv_tokens_fetched += blocks * self._kv_block
+        self.n_kv_rows += lengths.size
+        self.n_kv_shared_rows += shared
+        process_counters().add_many({
+            "serving_kv_rows_total": lengths.size,
+            "serving_kv_shared_rows_total": shared})
 
     def _scan_window_ok(self, runnable, k: int) -> bool:
         """Page precondition for ONE k-step scanned dispatch: every
@@ -1691,7 +1716,7 @@ class ServingEngine:
             self.n_mixed_steps += 1
             chunk_rows = sum(n for _, n, _ in advanced)
             self.n_step_pad_rows += T - len(runnable) - chunk_rows
-            self._count_kv(row_pos + 1)           # a padding row reads 1
+            self._count_kv(row_pos + 1, row_slot)  # a padding row reads 1
             self._note_step_metrics(r, decoded=bool(runnable))
             self._count_recurrent_tokens(len(runnable), chunk_rows)
             self._count_window(cur, adv, len(runnable) + chunk_rows)
@@ -2034,7 +2059,7 @@ class ServingEngine:
                 self.n_mixed_steps += 1
             self.n_step_pad_rows += T - r
             self.occupancy_sum += len(live) / S
-            self._count_kv(row_pos + 1)           # a padding row reads 1
+            self._count_kv(row_pos + 1, row_slot)  # a padding row reads 1
             step = self.n_decode_steps
             with self._phase("readback", step=step, kind="spec"):
                 sampled = np.asarray(sampled)              # host sync
@@ -2695,6 +2720,7 @@ class ServingEngine:
                 "_admit_seq", "n_decode_steps", "n_preemptions",
                 "n_cancelled", "n_expired", "tokens_generated",
                 "occupancy_sum", "kv_tokens_attended", "kv_tokens_fetched",
+                "n_kv_rows", "n_kv_shared_rows",
                 "n_prefix_hits", "n_prefix_misses",
                 "prefill_tokens_saved", "n_restore_hits",
                 "restore_tokens_saved", "n_prefill_chunks",

@@ -469,6 +469,12 @@ class ServingServer:
                  float(eng.n_window_rows)),
                 ("serving_window_steps_total", "counter", None,
                  float(eng.n_window_steps)),
+                # query rows the paged kernel's calls carried, and those
+                # whose tile walked its slot's blocks once for all its rows
+                ("serving_kv_rows_total", "counter", None,
+                 float(eng.n_kv_rows)),
+                ("serving_kv_shared_rows_total", "counter", None,
+                 float(eng.n_kv_shared_rows)),
                 *(("serving_kv_pages_resident", "gauge", {"kind": kind},
                    float(n))
                   for kind, n in sorted(eng.kv_pages_resident().items())),
@@ -1725,6 +1731,9 @@ class ServingServer:
             # tokens it fetched in whole blocks (their ratio = block fill)
             "kv_tokens_attended": eng.kv_tokens_attended,
             "kv_tokens_fetched": eng.kv_tokens_fetched,
+            # its query rows, and those that shared a tile's one walk
+            "kv_rows": eng.n_kv_rows,
+            "kv_shared_rows": eng.n_kv_shared_rows,
             # routed pairs the held experts drew (0 without MoE layers)
             "moe_pairs_total": eng.moe_pairs_total,
             "moe_pairs_max_sum": eng.moe_pairs_max_sum,

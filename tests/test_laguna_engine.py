@@ -250,10 +250,15 @@ def test_stats_and_metrics_hold_the_two_kinds(model, engines):
     assert st["ring_pages"] == {n: RING for n in WINDOW_LAYERS}
     assert st["window_pages_recycled"] == eng.n_window_pages_recycled > 0
     assert st["window_rows"] == eng.n_window_rows > 0
+    # the full layers' call: its rows, and those on a tile's shared walk
+    # (none here: a step is under a tile's 8 rows of one slot)
+    assert st["kv_rows"] == eng.n_kv_rows > 0
+    assert st["kv_shared_rows"] == eng.n_kv_shared_rows < eng.n_kv_rows
     assert st["attn_gated_layers"] == 5
     text = srv.metrics.render()
     for family in ("serving_window_pages_recycled_total",
                    "serving_window_rows_total", "serving_window_steps_total",
+                   "serving_kv_rows_total", "serving_kv_shared_rows_total",
                    "serving_kv_pages_resident", "serving_kv_pool_bytes"):
         assert f"# HELP {family}" in text and f"# TYPE {family}" in text
     assert 'serving_kv_pages_resident{kind="window"} 0' in text

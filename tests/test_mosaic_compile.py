@@ -28,16 +28,27 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 
-def test_paged_kernel_reads_a_block_as_dense_tiles():
+@pytest.mark.parametrize("shape,heads,kv_heads,pages", [
+    ("", 24, 2, 16),
+    ("heads=48,kv_heads=8,rows=320,max_pages=512,pages=32769", 48, 8, 8),
+], ids=["decode-saturated", "laguna-full-mixed-320-rows"])
+def test_paged_kernel_reads_a_block_as_dense_tiles(shape, heads, kv_heads,
+                                                   pages):
     """The lowering itself, at decode-saturated's shape (64 rows, 24 / 2
-    heads of 128, page 16, bf16): a block's pages land in the matmul
-    operand's own rows, so the kernel stores nothing but its output, loads
-    K and V once (a page is 2 + 2 vregs) and no load moves under half a
-    vreg.  Read through a `(2,128)` tiled buffer the same block was 580
-    loads of one live sublane and 516 stores (PR 42)."""
+    heads of 128, page 16, bf16) and at the Laguna full layer's mixed step
+    (320 rows, 48 / 8 heads, 512 pages a table row): a block's pages land in
+    the matmul operand's own rows, so the kernel stores nothing but its
+    output, loads K and V once a walk (a page of 16 x 2 heads is 2 + 2
+    vregs) and no load moves under half a vreg.  Read through a `(2,128)`
+    tiled buffer the same block was 580 loads of one live sublane and 516
+    stores (PR 42).  The program holds two walks — one query row at a
+    time, and a TILE of rows against one fetch of each block — and the
+    tile's is held to the row's counts: the same loads of a block, q's and
+    the output's tiles once a row of the tile."""
     tool = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "tools", "kernel_lowering.py")
-    p = subprocess.run([sys.executable, tool, "paged_attn"], text=True,
+    p = subprocess.run([sys.executable, tool, "paged_attn"] + [shape] *
+                       bool(shape), text=True,
                        capture_output=True, timeout=300,
                        env=dict(os.environ, JAX_PLATFORMS="cpu"))
     lines = p.stdout.strip().splitlines()
@@ -46,14 +57,20 @@ def test_paged_kernel_reads_a_block_as_dense_tiles():
     if p.returncode == 3:
         pytest.skip(got["skipped"])
     assert p.returncode == 0, got
-    pages = got["pages_per_block"]
-    assert pages == 16, got
-    # K's and V's copies of a block, at the two places a fetch starts
-    assert got["tpu.enqueue_dma"] == 2 * 2 * pages, got
-    # the output's [32, 128] float32 tiles, nothing through scratch
-    assert got["tpu.store"] <= 4, got
-    # 4 vregs a page (+ q's [32, 128] bf16), each load half a vreg or more
-    assert got["vregs_loaded"] <= 4 * pages + 2, got
+    assert got["pages_per_block"] == pages and got["tile_rows"] == 8, got
+    # K's and V's copies of a block, at the three places a fetch starts:
+    # the call's first, and each walk's next
+    assert got["tpu.enqueue_dma"] == 3 * 2 * pages, got
+    # vregs (4 KB): K and V of a block, and q / the float32 output of one
+    # query row, its heads padded to 16
+    hp = -(-heads // 16) * 16
+    kv = 2 * pages * 16 * kv_heads * 128 * 2 // 4096
+    q_row, out_row = hp * 128 * 2 // 4096, hp * 128 * 4 // 4096
+    rows = 1 + got["tile_rows"]             # the row walk's + the tile's
+    # the output's tiles, nothing through scratch
+    assert got["tpu.store"] <= rows * out_row, got
+    # a block once a walk, each load half a vreg or more
+    assert got["vregs_loaded"] <= 2 * kv + rows * q_row, got
     assert got["tpu.load"] <= 2 * got["vregs_loaded"], got
 
 
@@ -1025,3 +1042,57 @@ def test_paged_kernels_at_the_laguna_cells_shapes(mosaic, form, kind, heads):
     made_by = re.findall(r"= bf16\[%d,16,8,128\]\S* ([\w-]+)\(" % n_pages,
                          compiled.as_text())
     assert made_by and "copy" not in made_by, made_by
+
+
+# the TILE form of the paged kernels (ops/pallas_paged.py:tile_rows): where a
+# call's rows may share a slot it carries the tiles' walks as a fourth
+# prefetched operand, one entry a tile
+TILE_CASES = {
+    # name: (rows, heads, kv heads, pages a table row, slots, tile)
+    "laguna-full-mixed": (320, 48, 8, 512, 64, 8),
+    "decode-saturated-mixed": (128, 24, 2, 256, 64, 8),
+}
+
+
+@pytest.mark.parametrize("case", list(TILE_CASES))
+def test_paged_kernel_tiles_at_the_cells_shapes(mosaic, case):
+    """The Laguna full layer's mixed step (48 / 8 heads of 128, 320 rows,
+    512 pages a table row: a tile of 8 is a [384, 1024] float32 score
+    block) and decode-saturated's (24 / 2 heads, 128 rows): the program
+    with both walks compiles, in 40 and 16 tiles, under the one name."""
+    import re
+    from paddle_tpu.ops import pallas_paged
+    R, H, h_kv, maxp, S, tile = TILE_CASES[case]
+    bt = pallas_paged.block_tokens(16, h_kv, 128, 2, maxp)
+    assert pallas_paged.tile_rows(R, H, bt * h_kv, 128, bf16) == tile
+
+    def call(q, kp, vp, table, lengths, row_slot):
+        return pallas_paged.paged_attention(q, kp, vp, table, lengths,
+                                            row_slot=row_slot)
+
+    pool = ((1 + S * maxp, 16, h_kv, 128), bf16)
+    compiled = mosaic(call, ((R, H, 128), bf16), pool, pool,
+                      ((S + 1, maxp), i32), ((R,), i32), ((R,), i32))
+    assert kernel_names(compiled) == ["paged_attn.1"], kernel_names(compiled)
+    assert re.search(r"s32\[%d\]" % (R // tile), compiled.as_text()), \
+        "no operand of one entry a tile"
+
+
+def test_latent_kernel_tiles_at_gigachats_shape(mosaic):
+    """The latent call's tile comes from its shapes too: 64 heads against
+    rows of 640 lanes leave room for 4 query rows a tile (GigaChat's mixed
+    step of 128 rows: 32 tiles), Kimi's 32 heads for 8."""
+    import re
+    from paddle_tpu.ops import pallas_paged
+    assert pallas_paged.tile_rows(128, 64, 128, 640, bf16) == 4
+    assert pallas_paged.tile_rows(320, 32, 128, 640, bf16) == 8
+
+    def call(q, pool, table, lengths, row_slot):
+        return pallas_paged.latent_paged_attention(
+            q, pool, table, lengths, 0.1, row_slot=row_slot, v_width=512)
+
+    compiled = mosaic(call, ((128, 64, 640), bf16),
+                      ((16385, 16, 640), bf16), ((65, 256), i32),
+                      ((128,), i32), ((128,), i32))
+    assert kernel_names(compiled) == ["mla_paged_attn.1"]
+    assert re.search(r"s32\[32\]", compiled.as_text())

@@ -1377,6 +1377,7 @@ def test_engine_ring_spans_name_the_step_kind(tiny_tr, kind):
 
     t = Tracer()
     t.enabled = True
+    from paddle_tpu.ops.pallas_paged import tile_rows
     kw = {"scan": {"decode_steps": 4}, "spec": {"spec_k": 2}}.get(kind, {})
     eng = ServingEngine(tiny_tr.executor, tiny_tr.params, num_slots=2,
                         page_size=8, max_context=64, tracer=t, **kw)
@@ -1419,7 +1420,9 @@ def test_kv_read_counters_follow_each_step_kind(tiny_tr, kind):
     rows read: pos + 1 a decode row (1 for an empty slot), every body of
     a scanned dispatch at the position it had then, every packed row of a
     mixed or verify step (a padding row reads 1), and a whole block a
-    row — here the 64 tokens the table maps."""
+    row — here the 64 tokens the table maps — or once a tile of rows where
+    the tile's rows are one slot's (`kv_shared_rows` of `kv_rows`)."""
+    from paddle_tpu.ops.pallas_paged import tile_rows
     kw = {"scan": {"decode_steps": 4}, "spec": {"spec_k": 2}}.get(kind, {})
     eng = ServingEngine(tiny_tr.executor, tiny_tr.params, num_slots=2,
                         page_size=8, max_context=64, **kw)
@@ -1432,10 +1435,13 @@ def test_kv_read_counters_follow_each_step_kind(tiny_tr, kind):
         pos = [None if sl is None else sl.pos for sl in eng.slots]
         before = (eng.kv_tokens_attended, eng.kv_tokens_fetched,
                   eng.n_decode_steps, eng.n_mixed_steps, eng.n_spec_steps,
-                  eng.n_scan_flushes, eng.tokens_generated)
+                  eng.n_scan_flushes, eng.tokens_generated,
+                  eng.n_kv_rows, eng.n_kv_shared_rows)
         eng.step()
         att = eng.kv_tokens_attended - before[0]
         fetched = eng.kv_tokens_fetched - before[1]
+        rows = eng.n_kv_rows - before[7]
+        shared = eng.n_kv_shared_rows - before[8]
         if eng.n_decode_steps == before[2]:
             assert att == fetched == 0          # no compiled step ran
         elif eng.n_scan_flushes > before[5]:
@@ -1443,17 +1449,25 @@ def test_kv_read_counters_follow_each_step_kind(tiny_tr, kind):
             ran = eng.tokens_generated - before[6]
             want = sum(pos[0] + min(i, ran) + 1 for i in range(4)) + 4
             assert (att, fetched) == (want, 4 * S * 64)
+            assert (rows, shared) == (4 * S, 0)
         elif eng.n_mixed_steps > before[3] or eng.n_spec_steps > before[4]:
             seen.add("spec" if eng.n_spec_steps > before[4] else "mixed")
-            assert T <= att <= T * 64 and fetched == T * 64
+            # the prompt's run and the padding behind it fill whole tiles,
+            # but for the call's last rows
+            bq = tile_rows(T, *eng._kv_tile)
+            assert rows == T and 0 < shared <= T
+            assert T <= att <= T * 64
+            assert fetched == (T - shared - -shared // bq) * 64
         else:
             seen.add("decode")
             want = sum(1 if p is None else p + 1 for p in pos)
             assert (att, fetched) == (want, S * 64)
+            assert (rows, shared) == (S, 0)
     assert kind in seen, seen
     snap = eng.checkpoint_state()["counters"]
     assert snap["kv_tokens_attended"] == eng.kv_tokens_attended
     assert snap["kv_tokens_fetched"] == eng.kv_tokens_fetched
+    assert snap["n_kv_shared_rows"] == eng.n_kv_shared_rows
 
 
 def test_prefix_eviction_has_its_own_span(tiny_tr):

@@ -5,19 +5,22 @@ where `ops/pallas_paged.py`'s cost a block and a page come from.
     chiprun -- python3 tools/bench_paged.py --shapes lfm2-decode,nemotron-decode
     chiprun -- python3 tools/bench_paged.py --budgets 512,1024  # VMEM budget, KiB
     chiprun -- python3 tools/bench_paged.py --fills            # rows of 1 / 256 / 512 / ... tokens
+    chiprun -- python3 tools/bench_paged.py --shapes laguna-mixed --tile-rows 1,8  # every row alone / tiles of 8
 
 One line of JSON a reading (also appended to chiprun_out/bench_paged.jsonl):
 `ms` is the DEVICE time of one `paged_attn` call, the mean of the profiler's
 `tpu_custom_call` events over `--calls` calls.  `blocks` and `pages`
-are what the call's rows walk (a dead row walks one block), so two fills
-give the cost a block and a page (`--fills` fits them).  Fails off a TPU: a
-CPU time is no device number.
+are what the call fetches (`pallas_paged.walked_blocks`: a tile of one
+slot's rows walks its blocks once, a dead row walks one), so two fills
+give the cost a block and a page (`--fills` fits them); `shared_rows` are
+the rows on such a walk.  Fails off a TPU: a CPU time is no device number.
 """
 
 from __future__ import annotations
 
 import argparse
 import glob
+import itertools
 import json
 import os
 import sys
@@ -26,16 +29,23 @@ import tempfile
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 #: rows, slots, query heads, KV heads, head size, live-token range of a
-#: decode row, rows of one prompt chunk (bf16, page 16, context 4,096): the
-#: serve cells' decode and mixed steps at the fill their windows hold
+#: decode row, the prompt chunks' runs as (rows, first position) — each the
+#: consecutive rows of a slot of its own, the last slots — and the pages a
+#: table row maps (bf16, page 16): the serve cells' decode and mixed steps
+#: at the fill their windows hold
 SHAPES = {
-    "sc2-decode": (64, 64, 24, 2, 128, (300, 1060), 0),
-    "sc2-mixed": (128, 64, 24, 2, 128, (300, 1060), 64),
-    "sc2-chat": (64, 64, 24, 2, 128, (0, 0), 0),     # dead rows but two
-    "lfm2-decode": (256, 256, 32, 8, 64, (600, 2000), 0),
-    "nemotron-decode": (256, 256, 32, 2, 128, (600, 2000), 0),
+    "sc2-decode": (64, 64, 24, 2, 128, (300, 1060), (), 256),
+    "sc2-mixed": (128, 64, 24, 2, 128, (300, 1060), ((64, 192),), 256),
+    "sc2-chat": (64, 64, 24, 2, 128, (0, 0), (), 256),  # dead rows but two
+    "lfm2-decode": (256, 256, 32, 8, 64, (600, 2000), (), 256),
+    "nemotron-decode": (256, 256, 32, 2, 128, (600, 2000), (), 256),
+    # laguna-xs2-33b-serve.long-context-64's full layer: 64 decode rows at
+    # 4,000 tokens beside two chunks deep in 8k prompts
+    "laguna-mixed": (320, 66, 48, 8, 128, (4000, 4000),
+                     ((128, 1000), (128, 5000)), 512),
+    "laguna-decode": (64, 64, 48, 8, 128, (4000, 4000), (), 512),
 }
-PAGE, MAXP = 16, 256
+PAGE = 16
 
 
 def _operands(name, seed, tokens=None):
@@ -43,34 +53,30 @@ def _operands(name, seed, tokens=None):
     import jax.numpy as jnp
     import numpy as np
     from paddle_tpu.ops.pallas_paged import kv_row_shape
-    R, S, H, h_kv, D, (lo, hi), chunk = SHAPES[name]
+    R, S, H, h_kv, D, (lo, hi), runs, maxp = SHAPES[name]
     rng = np.random.default_rng(seed)
-    P = S * MAXP + 1                                # + the trash page 0
+    P = S * maxp + 1                                # + the trash page 0
     keys = jax.random.split(jax.random.PRNGKey(seed), 3)
     kp, vp = (jax.random.normal(k, (P, PAGE) + kv_row_shape(h_kv, D),
                                 jnp.bfloat16) for k in keys[:2])
     q = jax.random.normal(keys[2], (R, H, D), jnp.bfloat16)
     # every slot owns its pages, scattered over the pool; row S is the
     # all-zero row the padding rows read
-    table = np.zeros((S + 1, MAXP), np.int32)
-    table[:S] = (rng.permutation(S * MAXP) + 1).reshape(S, MAXP)
+    table = np.zeros((S + 1, maxp), np.int32)
+    table[:S] = (rng.permutation(S * maxp) + 1).reshape(S, maxp)
     lengths = np.zeros(R, np.int32)
     row_slot = np.full(R, S, np.int32)
-    n_dec = R - chunk if hi else 2
+    n_dec = R - sum(n for n, _ in runs) if hi else 2
     lengths[:n_dec] = tokens if tokens is not None else \
         rng.integers(lo, hi + 1, n_dec) if hi else (40, 300)
     row_slot[:n_dec] = np.arange(n_dec)
-    if chunk:       # one prompt chunk: consecutive rows of the last slot
-        lengths[n_dec:] = 192 + 1 + np.arange(chunk)
-        row_slot[n_dec:] = S - 1
+    r = n_dec
+    for i, (n, pos) in enumerate(runs):
+        lengths[r:r + n] = pos + 1 + np.arange(n)
+        row_slot[r:r + n] = S - len(runs) + i
+        r += n
     return (q, kp, vp, jnp.asarray(table), jnp.asarray(lengths),
-            jnp.asarray(row_slot)), lengths
-
-
-def _walked(lengths, bt):
-    """(blocks, pages fetched) of one call: every row folds at least one."""
-    blocks = int(sum(max(1, -(-int(n) // bt)) for n in lengths))
-    return blocks, blocks * (bt // PAGE)
+            jnp.asarray(row_slot)), lengths, row_slot
 
 
 def _device_ms(fn, args, calls):
@@ -102,6 +108,9 @@ def main():
                          "the cost a block and a page by least squares")
     ap.add_argument("--calls", type=int, default=20)
     ap.add_argument("--seed", type=int, default=42)
+    ap.add_argument("--tile-rows", default="",
+                    help="ceilings of a tile's rows to try (default: the "
+                         "kernel's; 1 = every row walks alone)")
     a = ap.parse_args()
 
     import jax
@@ -119,25 +128,32 @@ def main():
         sink.write(line + "\n")
         sink.flush()
 
-    was = pallas_paged._KV_VMEM_BUDGET
-    budgets = [int(b) << 10 for b in a.budgets.split(",") if b] or [was]
+    was = pallas_paged._KV_VMEM_BUDGET, pallas_paged._TILE_ROWS
+    budgets = [int(b) << 10 for b in a.budgets.split(",") if b] or was[:1]
+    ceilings = [int(n) for n in a.tile_rows.split(",") if n] or was[1:]
     for name in a.shapes.split(","):
-        h_kv, D = SHAPES[name][3:5]
+        R, _, H, h_kv, D, _, _, maxp = SHAPES[name]
         row = pallas_paged.kv_row_shape(h_kv, D)
-        for budget in budgets:
+        for budget, ceiling in itertools.product(budgets, ceilings):
             pallas_paged._KV_VMEM_BUDGET = budget
-            bt = pallas_paged.block_tokens(PAGE, row[0], row[1], 2, MAXP)
-            # a function of its own a budget: jit's cache is keyed by it
+            pallas_paged._TILE_ROWS = ceiling
+            bt = pallas_paged.block_tokens(PAGE, row[0], row[1], 2, maxp)
+            bq = pallas_paged.tile_rows(R, H, bt * row[0], row[1],
+                                        "bfloat16")
+            # a function of its own a setting: jit's cache is keyed by it
             fn = jax.jit(lambda q, kp, vp, table, lengths, row_slot:
                          pallas_paged.paged_attention(
                              q, kp, vp, table, lengths, row_slot=row_slot))
             points = []
             for tokens in (1, 256, 512, 1024, 2048) if a.fills else (None,):
-                args, lengths = _operands(name, a.seed, tokens)
-                blocks, pages = _walked(lengths, bt)
+                args, lengths, row_slot = _operands(name, a.seed, tokens)
+                blocks, shared = pallas_paged.walked_blocks(
+                    lengths, row_slot, bq, bt)
+                pages = blocks * (bt // PAGE)
                 ms = _device_ms(fn, args, a.calls)
                 points.append((blocks, pages, ms))
                 say(shape=name, budget_kib=budget >> 10, block_tokens=bt,
+                    tile_rows=bq, shared_rows=shared,
                     rows=len(lengths), live_tokens=int(lengths.sum()),
                     blocks=blocks, pages=pages, ms=round(ms, 4),
                     hbm_ms=round(int(lengths.sum()) * 2 * row[0] * row[1] * 2
@@ -149,9 +165,9 @@ def main():
                 b, _, t = map(np.asarray, zip(*points))
                 slope, fixed = np.polyfit(b, t, 1)
                 say(shape=name, budget_kib=budget >> 10, block_tokens=bt,
-                    us_a_block=round(slope * 1e3, 4),
+                    tile_rows=bq, us_a_block=round(slope * 1e3, 4),
                     ms_fixed_a_call=round(float(fixed), 4))
-    pallas_paged._KV_VMEM_BUDGET = was
+    pallas_paged._KV_VMEM_BUDGET, pallas_paged._TILE_ROWS = was
 
 
 if __name__ == "__main__":

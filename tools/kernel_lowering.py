@@ -9,6 +9,7 @@ buffer cost 512 one-sublane loads and 512 strided stores through
 
     python tools/kernel_lowering.py paged_attn                 # decode-saturated
     python tools/kernel_lowering.py paged_attn rows=128        # its mixed step
+    python tools/kernel_lowering.py paged_attn heads=48,kv_heads=8,rows=320,max_pages=512,pages=32769  # Laguna's full layer
     python tools/kernel_lowering.py paged_attn kv_heads=8,head_dim=64,heads=32,rows=256
     python tools/kernel_lowering.py mla_paged_attn             # GigaChat's decode
 
@@ -109,8 +110,13 @@ def lowering_counts(kernel: str, shape: dict, dump_dir: str) -> dict:
     # pages a block: what the loads are held against (4 vregs a page at most)
     row = pallas_paged.kv_row_shape(shape["kv_heads"], shape["head_dim"]) \
         if kernel == "paged_attn" else (1, shape["width"])
-    out["pages_per_block"] = pallas_paged.block_tokens(
-        shape["page"], row[0], row[1], 2, shape["max_pages"]) // shape["page"]
+    block = pallas_paged.block_tokens(
+        shape["page"], row[0], row[1], 2, shape["max_pages"])
+    out["pages_per_block"] = block // shape["page"]
+    # rows a grid step holds: the program has a walk one row at a time and
+    # a walk of the whole tile, each with its own loads of a block
+    out["tile_rows"] = pallas_paged.tile_rows(
+        shape["rows"], shape["heads"], block * row[0], row[1], "bfloat16")
     return out
 
 
