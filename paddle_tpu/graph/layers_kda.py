@@ -25,7 +25,8 @@ layers):
     [0, S) are single rows (decode rows, or padding aimed at trash row S)
     and rows [S, T) hold the prompt chunks, each slot's run contiguous and
     in order.  The first part is one batched rank-1 update; each run of the
-    second is one segment through the chunkwise form, starting from its
+    second is one segment through the chunkwise form (on the TPU all of
+    them in one `kda_seg` call, ops/pallas_kda_seg.py), starting from its
     slot's state — from zero where it begins at position 0, so admission
     dispatches nothing.  A touched slot's state is read once and written
     once a layer a step.
@@ -106,19 +107,20 @@ def kda_attention_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
         row_slot, row_pos, _, _, live = step.runs
         rows = lambda a: a.reshape((B * T,) + a.shape[2:])
         q, k, v, g, beta = map(rows, (q, k, v, g, beta))
+        kernel = slot_steps.use_step_kernel(cfg)    # kda_step and kda_seg
         with jax.named_scope("kda.step"):
             if step.ragged:
                 o_d, state = kda.step_rows(
                     state, row_slot[:S], live[:S], q[:S], k[:S], v[:S],
-                    g[:S], beta[:S], use_kernel=slot_steps.use_step_kernel(cfg))
+                    g[:S], beta[:S], use_kernel=kernel)
                 o_c, state, n_seg = kda.segment_rows(
                     state, row_slot[S:], row_pos[S:], q[S:], k[S:], v[S:],
-                    g[S:], beta[S:])
+                    g[S:], beta[S:], use_kernel=kernel)
                 o = jnp.concatenate([o_d, o_c], axis=0)
                 updates = jnp.sum(live[:S], dtype=jnp.int32) + n_seg
             else:
                 o, state = kda.step_rows(state, None, live, q, k, v, g,
-                                         beta, use_kernel=slot_steps.use_step_kernel(cfg))
+                                         beta, use_kernel=kernel)
                 updates = jnp.sum(live, dtype=jnp.int32)
         o = o.reshape(B, T, H, dv)
         slot_steps.finish(ctx, cfg, step, updates, state=state, conv=conv)

@@ -130,6 +130,11 @@ CATALOG: dict[str, str] = {
         "tokens the recurrent layers ran, one layer's worth a step (label "
         "kind: step = a decode row, one token a slot state; segment = a "
         "prompt chunk's rows, a run of tokens a slot state)",
+    "serving_recurrent_segment_chunks_total":
+        "chunks of 64 rows the KDA layers' segment kernel (kda_seg) folded "
+        "into slot states, one layer's worth a step: cdiv(rows, 64) a run "
+        "of a prompt chunk's rows; tokens{kind=segment} over 64 x this is "
+        "the chunks' fill; 0 where no KDA layer runs the kernel",
     # -- the weights a step reads: cast once when params is set -----------
     "serving_step_weight_casts_total":
         "weight trees derived for the compiled steps that copied at least "

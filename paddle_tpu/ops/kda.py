@@ -13,17 +13,29 @@ u_t^T.  Everything here is float32 whatever the inputs' dtype, and the
 matmuls of the chunkwise form ask for full float32 precision (the TPU's
 default single bf16 pass is not the recurrence's arithmetic).
 
-Three forms, one result:
+Four forms, one result:
   * `recurrent`  — the literal per-token scan: the CPU oracle of the tests
-    and of the step kernel (ops/pallas_kda.py);
+    and of both kernels (ops/pallas_kda.py, ops/pallas_kda_seg.py);
   * `chunkwise`  — the WY / UT-transform form, `CHUNK` tokens at a time:
     inside a chunk the pseudo-values u solve (I + A) u = b (v - K~ S_0),
     A strictly lower triangular, and the state moves once a chunk.  Decay
     ratios are taken pairwise, exp(G_t - G_j) with j <= t, so no exponent
     is ever positive.  Rows with g = 0 and b = 0 leave the state as it
-    was: that is how padding and the rows of other segments are masked;
+    was: that is how padding and the rows of other segments are masked.
+    The whole-sequence path (graph/layers_kda.py with no slot state:
+    training, any graph run without slots — it has the backward), and the
+    body of `segment_rows` where the kernel is not used;
   * `step_rows`  — one token a row against a pool of slot states: the
-    decode step, and the decode rows of the ragged mixed step.
+    decode step, and the decode rows of the ragged mixed step (`kda_step`
+    on the TPU);
+  * `segment_rows` — the chunk rows of the ragged mixed step, each slot's
+    run of rows one segment from its slot's state.  On the TPU one
+    `kda_seg` call a layer (ops/pallas_kda_seg.py: the same chunkwise
+    mathematics, the runs' and chunks' loops inside the kernel, the state
+    resident in VMEM, the pairwise decays never in HBM; forward only);
+    elsewhere `chunkwise` once a run under ops/slot_rows.py
+    `advance_segments`.  The two share `CHUNK`, the mathematics and
+    nothing else.
 
 The causal depthwise convolution over the last `taps` positions in front
 of q, k and v is ops/short_conv.py's, shared with the gated
@@ -177,17 +189,26 @@ def step_rows(state, slot, live, q, k, v, g, beta, use_kernel: bool = False):
         state, slot, live, lambda S: step(S, q, k, v, g, beta, scale))
 
 
-def segment_rows(state, seg_slot, seg_pos, q, k, v, g, beta):
+def segment_rows(state, seg_slot, seg_pos, q, k, v, g, beta,
+                 use_kernel: bool = False):
     """The chunk rows of a ragged mixed step: P packed rows holding whole
     runs of slots, contiguous and in order (`seg_slot` [P], trash row S =
     padding; `seg_pos` [P] global positions).  Each run is one segment: it
     starts from its slot's state — from zero where its first row is
-    position 0 — goes through `chunkwise` once, and leaves the state it
-    ends in (ops/slot_rows.py `advance_segments`: one pass a segment
-    present) over the chunks that hold its rows, the others skipped.  Returns
-    (o [P, H, dv] float32, state, n_segments)."""
+    position 0 — goes through the chunkwise form once, chunks of `CHUNK`
+    rows from its own first row, and leaves the state it ends in.  On the
+    TPU that is one `kda_seg` call (ops/pallas_kda_seg.py: the runs and
+    their chunks loops inside the kernel, the state resident); elsewhere
+    `chunkwise` over the whole list once a run present with the other rows
+    masked (ops/slot_rows.py `advance_segments`), the chunks that hold none
+    of its rows skipped.  Returns (o [P, H, dv] float32, state,
+    n_segments)."""
     f32 = lambda a: a.astype(jnp.float32)
     q, k, v, g, beta = map(f32, (q, k, v, g, beta))
+    if use_kernel:
+        from paddle_tpu.ops import pallas_kda_seg
+        return pallas_kda_seg.kda_segments(state, seg_slot, seg_pos, q, k, v,
+                                           g, beta, q.shape[-1] ** -0.5)
 
     def one_segment(S0, mine):
         m = mine[:, None]

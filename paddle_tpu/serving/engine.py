@@ -557,6 +557,14 @@ class ServingEngine:
         # chunk's consecutive rows (counted on the host, where the step is
         # packed)
         self.recurrent_tokens = {"step": 0, "segment": 0}
+        # chunks the KDA layers' segment kernel folded (`kda_seg`: a run's
+        # cdiv(rows, 64)), one layer's worth a step; stays 0 where no KDA
+        # layer's chunk rows go through the kernel
+        self.recurrent_segment_chunks = 0
+        from paddle_tpu.graph.slot_steps import use_step_kernel
+        self._kda_seg = any(
+            l.type == "kda_attention" and use_step_kernel(l)
+            for l in executor.model.layers)
         self._kv_synced = -1                   # kv.version last uploaded
         self._slots_dirty = True
         self._run_host: Optional[np.ndarray] = None
@@ -1719,6 +1727,12 @@ class ServingEngine:
             self._count_kv(row_pos + 1, row_slot)  # a padding row reads 1
             self._note_step_metrics(r, decoded=bool(runnable))
             self._count_recurrent_tokens(len(runnable), chunk_rows)
+            if self._kda_seg and advanced:
+                from paddle_tpu.ops.pallas_kda_seg import folded_chunks
+                chunks = folded_chunks((n for _, n, _ in advanced), T - S)
+                self.recurrent_segment_chunks += chunks
+                process_counters().add(
+                    "serving_recurrent_segment_chunks_total", chunks)
             self._count_window(cur, adv, len(runnable) + chunk_rows)
         return _Pending(nxt, list(self.slots), runnable, advanced, adv,
                         emit, "mixed", self.n_decode_steps, launch.t0)

@@ -487,6 +487,8 @@ class ServingServer:
                 *(("serving_recurrent_tokens_total", "counter",
                    {"kind": kind}, float(n))
                   for kind, n in sorted(eng.recurrent_tokens.items())),
+                ("serving_recurrent_segment_chunks_total", "counter", None,
+                 float(eng.recurrent_segment_chunks)),
                 # multi-step decode: scan body iterations vs boundary
                 # flushes — steps/flushes ≈ decode_steps in steady state
                 ("serving_scan_steps_total", "counter", None,
@@ -1748,6 +1750,8 @@ class ServingServer:
             "recurrent_steps": eng.recurrent_steps,
             # of those rows, the decode rows and the prompt chunks' rows
             "recurrent_tokens": dict(eng.recurrent_tokens),
+            # chunks of 64 the KDA segment kernel folded (0 without it)
+            "recurrent_segment_chunks": eng.recurrent_segment_chunks,
             # speculative decoding: the A/B-able knobs + the counters the
             # accept rate reconciles from, plus the adaptive state
             # (drafter kind, dynamic-k flag, per-slot learned EWMAs)

@@ -191,11 +191,14 @@ CASES = {c.name: c for c in (
         renamed=dict(kda_head_dim="linear_attn_config.head_dim",
                      kda_num_heads="linear_attn_config.num_heads"),
         tol=1e-4, whole_len=150, ragged_chunks=(7, 9, 4, 3),
+        ragged_kernels=(False, True),       # `kda_step` and `kda_seg`
         recurrent=("blk0_kda", "blk1_kda", "blk2_kda"),
         recurrent_type="kda_attention",
         slot_parts={"state": ((4, 8, 8), "float32"), "conv": ((3, 96), "")},
         paged={"blk3_attn": (128,)},
-        engines=RECURRENT_ENGINES + (EngineCase("scanned-k4", 5, k=4),),
+        engines=RECURRENT_ENGINES + (
+            EngineCase("scanned-k4", 5, k=4),
+            EngineCase("free-rows-kernel", 5, True, mst=34, build=AUTO)),
         letters={"kda_attention": "K", "mla_attention": "A"},
         depths=(({"num_hidden_layers": 13}, "KKKAKKKAKKKAK", "d" + "e" * 12),
                 ({"num_hidden_layers": 2}, "KA", "de"),
@@ -323,11 +326,13 @@ CASES = {c.name: c for c in (
         zeroed=("_blk0_attn.w4",),
         ref_controls=({"use_gqa_gate": False},
                       {"kda_allow_neg_eigval": False}), state_control=True,
+        ragged_kernels=(False, True),       # `kda_step` and `kda_seg`
         recurrent=("blk1_kda", "blk2_kda", "blk3_kda"),
         recurrent_type="kda_attention",
         slot_parts={"state": ((4, 8, 8), "float32"), "conv": ((3, 96), "")},
         paged={"blk0_attn": (2, 16)}, margin=True,
-        engines=tuple(e for e in RECURRENT_ENGINES if e.id != "one-chunk"),
+        engines=tuple(e for e in RECURRENT_ENGINES if e.id != "one-chunk")
+        + (EngineCase("free-rows-kernel", 5, True, mst=34, build=AUTO),),
         letters={"kda_attention": "K", "multi_head_attention": "A"},
         depths=(({}, "AKKK", "eeee"),
                 ({"num_hidden_layers": 2}, "AK", "ee"),
@@ -663,6 +668,7 @@ def counted(eng, since=None):
     """The engine's counters, or what they grew by since an earlier read."""
     now = {k: getattr(eng, k) for k in COUNTERS}
     now.update({"tokens_" + k: v for k, v in eng.recurrent_tokens.items()})
+    now["segment_chunks"] = eng.recurrent_segment_chunks
     return now if since is None else {k: v - since.get(k, 0)
                                       for k, v in now.items()}
 
@@ -931,6 +937,12 @@ def test_engine_serves_lm_generates_tokens(case, model, ref, engines,
         assert n["tokens_segment"] == prompt_rows
         assert n["tokens_step"] == len(reqs) * (reqs[0].max_new - 1)
         assert n["tokens_step"] + n["tokens_segment"] == n["recurrent_rows"]
+        # the chunks `kda_seg` folded: counted only where a KDA layer's
+        # chunk rows go through the kernel, a run's cdiv(rows, chunk) —
+        # every run here is shorter than a chunk, so one a run
+        through_seg = e.kernel and case.recurrent_type == "kda_attention"
+        assert n["segment_chunks"] == \
+            (n["n_prefill_chunks"] if through_seg else 0)
     assert eng.kv.slot_state_bytes == 3 * case.slot_row_bytes
 
 

@@ -4,7 +4,8 @@ parity tests of tests/model_parity.py over its case — the whole sequence
 (chunkwise), the decode step and the ragged mixed step through the cache
 manager's slot state, the slot parts, paused slots, re-admission, the
 configuration file — and what is this model's own: the chunkwise form
-against the recurrence, the Pallas step kernel interpreted, NoPE latent
+against the recurrence, the Pallas step kernel and the Pallas segment kernel
+(`kda_seg`) interpreted, NoPE latent
 attention without a query rank, the expert-parallel share.  Its engines are
 tests/test_kimi_linear_engine.py's."""
 
@@ -90,6 +91,63 @@ def test_step_kernel_interpreted_equals_the_jnp_step(rows, monkeypatch):
     for s in (s1, s2):                   # dead rows and untouched slots
         keep = np.setdiff1d(np.arange(R + 2), idx[lv])
         assert bool((s[keep] == state[keep]).all())
+
+
+# the runs of `kda_seg`'s cases: (slot, first row, rows, first position)
+SEG_RUNS = {
+    # a run from position 0 (its slot's state is NOT read) beside one that
+    # continues its slot's state
+    "from-zero-beside-continued": (192, [(2, 0, 64, 0), (0, 64, 64, 37)]),
+    # lengths that are no multiple of the chunk, a run of one row, starts
+    # at no multiple of 8
+    "three-ragged-runs": (192, [(1, 0, 70, 0), (3, 70, 1, 5),
+                                (0, 71, 100, 9)]),
+    "padding-only": (192, []),
+    # PR 46: a chunk takes the step's free rows — three chunks in one run
+    "a-run-of-150": (192, [(2, 3, 150, 40)]),
+    # a row list that is no multiple of 8 and shorter than a chunk
+    "a-short-list": (21, [(0, 0, 5, 0), (1, 5, 16, 3)]),
+}
+
+
+@pytest.mark.parametrize("runs", list(SEG_RUNS))
+def test_segment_kernel_interpreted_equals_the_recurrence(runs):
+    """`kda_seg` in interpret mode at 32 heads (two head blocks of 16)
+    against the literal recurrence over each run from the state it should
+    start from, and against the jnp form of `segment_rows`: outputs, the
+    states the runs leave; rows of no run read zeros; the trash row and the
+    states of slots with no run are bit-equal to what they were."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import kda
+    P, table = SEG_RUNS[runs]
+    S, H, d = 4, 32, 16
+    ks = jax.random.split(jax.random.PRNGKey(len(runs)), 6)
+    q = kda.l2norm(jax.random.normal(ks[0], (P, H, d)))
+    k = kda.l2norm(jax.random.normal(ks[1], (P, H, d)))
+    v = jax.random.normal(ks[2], (P, H, d))
+    g = -jnp.exp(jax.random.uniform(ks[3], (P, H, d), minval=-6, maxval=1))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (P, H)))
+    state = jax.random.normal(ks[5], (S + 1, H, d, d))
+    slot, pos = np.full(P, S, np.int32), np.zeros(P, np.int32)
+    for s, at, n, p0 in table:
+        slot[at:at + n], pos[at:at + n] = s, np.arange(p0, p0 + n)
+    xs = (q, k, v, g, beta)
+    o1, s1, n1 = jax.jit(kda.segment_rows)(state, slot, pos, *xs)
+    o2, s2, n2 = jax.jit(lambda *a: kda.segment_rows(*a, use_kernel=True))(
+        state, slot, pos, *xs)
+    assert int(n1) == int(n2) == len(table)
+    assert float(jnp.abs(o1 - o2).max()) < 2e-5
+    assert float(jnp.abs(s1 - s2).max()) < 2e-5
+    for s, at, n, p0 in table:
+        S0 = None if p0 == 0 else state[s][None]
+        want_o, want_S = kda.recurrent(
+            *(a[None, at:at + n] for a in xs), S0)
+        assert float(jnp.abs(o2[at:at + n] - want_o[0]).max()) < 2e-5
+        assert float(jnp.abs(s2[s] - want_S[0]).max()) < 2e-5
+    idle = np.setdiff1d(np.arange(S + 1), [s for s, *_ in table])
+    assert bool((s2[idle] == state[idle]).all())
+    assert not bool(o2[slot == S].any())
 
 
 def test_nope_mla_without_a_query_rank_against_a_literal_loop(ref):

@@ -74,6 +74,40 @@ def test_paged_kernel_reads_a_block_as_dense_tiles(shape, heads, kv_heads,
     assert got["tpu.load"] <= 2 * got["vregs_loaded"], got
 
 
+@pytest.mark.parametrize("heads", [32, 64])
+def test_kda_seg_kernel_moves_whole_vregs_and_only_its_state(heads):
+    """What Mosaic made of `kda_seg` at the two KDA cells' chunk rows (192 x
+    32 heads, x 64; tools/kernel_lowering.py, a process of its own as
+    above): two copies and no more — a run's state in, the state out —, every
+    load a whole vreg (the chunk's windows at a run's own first row are
+    dynamic sublane offsets of one-lane-tile operands: a relayout would show
+    as hundreds of loads of single sublanes), the loads a chunk's operands,
+    its sums' ones and the solve's rows and columns, and the stores the
+    call's zeros (the output block, a run's state from position 0) plus a
+    chunk's results and the solve's rows."""
+    tool = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "kernel_lowering.py")
+    p = subprocess.run([sys.executable, tool, "kda_seg", f"heads={heads}"],
+                       text=True, capture_output=True, timeout=300,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    lines = p.stdout.strip().splitlines()
+    assert lines, p.stderr[-2000:]
+    got = json.loads(lines[-1])
+    if p.returncode == 3:
+        pytest.skip(got["skipped"])
+    assert p.returncode == 0, got
+    assert got["tpu.enqueue_dma"] == 2, got
+    # whole vregs but for the solve's 63 final rows u_j, one sublane each
+    assert got["tpu.load"] - got["vregs_loaded"] <= 63 * 7 / 8 + 1e-6, got
+    # 5 operands of 8 vregs, the state 16, the sums' ones 56, and a step of
+    # the solve its rows below, their column of A and u_j: 751 at this
+    # writing
+    assert got["tpu.load"] <= 800, got
+    # the output block's zeros 512, a state's 256, the ones 56, a chunk's
+    # A, state and output 32, the solve's rows 288: 1,144 at this writing
+    assert got["tpu.store"] <= 1200, got
+
+
 @pytest.fixture(scope="module")
 def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -871,6 +905,44 @@ def test_kda_step_kernel_at_64_heads(mosaic):
     made_by = re.findall(r"= f32\[129,64,128,128\]\S* ([\w-]+)\(",
                          compiled.as_text())
     assert made_by and "copy" not in made_by, made_by
+
+
+@pytest.mark.parametrize("c", [KDA, KDA_64], ids=["32-heads", "64-heads"])
+def test_kda_mixed_step_holds_both_kernels_and_no_loop(mosaic, c):
+    """A KDA layer's part of the ragged mixed step at both cells' shapes
+    (128 decode rows and 192 chunk rows of 32 heads, of 64; 129 slot states
+    of 128 x 128 float32) as graph/layers_kda.py makes it — `step_rows` then
+    `segment_rows` through the kernels — in ONE compiled program: `kda_step`
+    and `kda_seg` each under its own name (the roofline reader sums
+    `kda_step.*`: the segment kernel's name must not hold it), the state
+    pool donated, aliased through both and made by no copy, and nothing of
+    the jnp chunkwise form left: no loop, no branch, no triangular solve."""
+    from paddle_tpu.ops import kda
+    S, H, D, P = c["S"], c["H"], c["D"], 192
+
+    def mixed(state, row_slot, live, seg_slot, seg_pos, q, k, v, g, beta):
+        o_d, state = kda.step_rows(state, row_slot, live, q[:S], k[:S],
+                                   v[:S], g[:S], beta[:S], use_kernel=True)
+        o_c, state, n_seg = kda.segment_rows(
+            state, seg_slot, seg_pos, q[S:], k[S:], v[S:], g[S:], beta[S:],
+            use_kernel=True)
+        return jnp.concatenate([o_d, o_c]), state, n_seg
+
+    vec = ((S + P, H, D), f32)
+    compiled = mosaic(mixed, ((S + 1, H, D, D), f32), ((S,), i32),
+                      ((S,), jnp.bool_), ((P,), i32), ((P,), i32), vec, vec,
+                      vec, vec, ((S + P, H), f32), donate=(0,))
+    names = kernel_names(compiled)
+    assert [n.split(".")[0] for n in names] == ["kda_seg", "kda_step"], names
+    assert not any("kda_step" in n for n in names if n.startswith("kda_seg"))
+    import re
+    text = compiled.as_text()
+    made_by = re.findall(r"= f32\[129,%d,128,128\]\S* ([\w-]+)\(" % H, text)
+    assert made_by and "copy" not in made_by, made_by
+    assert "input_output_alias" in text
+    for gone in (" while(", " conditional(", "InvertDiagBlocks",
+                 "triangular"):
+        assert gone not in text, gone
 
 
 @pytest.mark.parametrize("rows", [128, 320], ids=["decode", "mixed-320-rows"])

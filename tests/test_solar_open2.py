@@ -10,6 +10,7 @@ the configuration file — and what is this model's own: the KDA forms at
 beta near 2, the gate over the dense cache, the eight ranks' shares, the
 cut's arithmetic.  Its engines are tests/test_solar_open2_engine.py's."""
 
+import functools
 import json
 
 import numpy as np
@@ -61,13 +62,15 @@ def near_two():
     return (q, k, v, g, beta), o[0], S[0]
 
 
-@pytest.mark.parametrize("form", ["chunkwise", "step_rows", "segment_rows"])
+@pytest.mark.parametrize("form", ["chunkwise", "step_rows", "segment_rows",
+                                  "segment_rows_kernel"])
 def test_kda_forms_agree_at_beta_near_two_over_4096_tokens(near_two, form):
     """`allow_neg_eigval`: every form takes beta as data, and each agrees
     with the literal recurrence where beta is all but 2 — the chunkwise
     form's unit-lower solve with entries twice as large, the decode step a
     token at a time beside a paused row, and the mixed step's segments of
-    uneven length continued from the slot's state."""
+    uneven length continued from the slot's state — by the jnp form and by
+    `kda_seg` interpreted (ten chunks a run, the ragged tenth masked)."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops import kda
@@ -90,7 +93,8 @@ def test_kda_forms_agree_at_beta_near_two_over_4096_tokens(near_two, form):
         assert not bool(state[1].any())         # the paused row's state
     else:
         state = jnp.full((3, H, d, d), 5.0)     # position 0 starts from zero
-        seg = jax.jit(kda.segment_rows)
+        seg = jax.jit(functools.partial(
+            kda.segment_rows, use_kernel=form == "segment_rows_kernel"))
         P, outs, p = 600, [], 0
         while p < T:
             n = min(P - 7, T - p)

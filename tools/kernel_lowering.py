@@ -12,6 +12,8 @@ buffer cost 512 one-sublane loads and 512 strided stores through
     python tools/kernel_lowering.py paged_attn heads=48,kv_heads=8,rows=320,max_pages=512,pages=32769  # Laguna's full layer
     python tools/kernel_lowering.py paged_attn kv_heads=8,head_dim=64,heads=32,rows=256
     python tools/kernel_lowering.py mla_paged_attn             # GigaChat's decode
+    python tools/kernel_lowering.py kda_seg                    # Kimi's chunk rows
+    python tools/kernel_lowering.py kda_seg heads=64           # Solar-Open2's
 
 One line of JSON.  A process of its own: the dump flag is read when the
 TPU's library loads (`LIBTPU_INIT_ARGS`), so it cannot be set around one
@@ -38,6 +40,9 @@ SHAPES = {
                        max_pages=256, pages=16384),
     "mla_paged_attn": dict(rows=64, heads=64, width=640, v_width=512,
                            page=16, max_pages=256, pages=16385),
+    # the KDA cells' chunk rows (kimi-linear-48b-a3b-serve.json: 320 step
+    # tokens - 128 slots; float32)
+    "kda_seg": dict(rows=192, heads=32, head_dim=128, slots=128),
 }
 #: op families counted, by the name the count is printed under: every kind
 #: of vector load (`tpu.load`, `tpu.shuffled_load`, `tpu.strided_load`) is
@@ -48,10 +53,22 @@ COUNTED = {"tpu.load": r"\btpu\.(?:\w+_)?load\b",
 
 
 def _build(kernel: str, s: dict):
-    """(fn, argument shapes) of one bare kernel call, bf16."""
+    """(fn, argument shapes) of one bare kernel call (bf16; the KDA
+    segment kernel float32)."""
     import jax.numpy as jnp
     from paddle_tpu.ops import pallas_paged
     bf16, i32 = jnp.bfloat16, jnp.int32
+    if kernel == "kda_seg":
+        from paddle_tpu.ops import pallas_kda_seg
+        f32 = jnp.float32
+        P, H, d = s["rows"], s["heads"], s["head_dim"]
+
+        def fn(state, slot, pos, q, k, v, g, beta):
+            return pallas_kda_seg.kda_segments(state, slot, pos, q, k, v, g,
+                                               beta, d ** -0.5)
+        vec = ((P, H, d), f32)
+        return fn, [((s["slots"] + 1, H, d, d), f32), ((P,), i32),
+                    ((P,), i32), vec, vec, vec, vec, ((P, H), f32)]
     R, ps, maxp = s["rows"], s["page"], s["max_pages"]
     tail = [((R + 1, maxp), i32), ((R,), i32), ((R,), i32)]
     if kernel == "paged_attn":
@@ -79,7 +96,7 @@ def lowering_counts(kernel: str, shape: dict, dump_dir: str) -> dict:
     import jax
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
-    from paddle_tpu.ops import pallas_paged
+    from paddle_tpu.ops import pallas_kda, pallas_paged
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
@@ -87,7 +104,7 @@ def lowering_counts(kernel: str, shape: dict, dump_dir: str) -> dict:
         return {"skipped": f"no v5e:2x2 topology can be described here: "
                            f"{str(e)[:200]}"}
     # compiled for the chip whatever backend this process has
-    pallas_paged._interpret = lambda: False
+    pallas_paged._interpret = pallas_kda._interpret = lambda: False
     jax.config.update("jax_enable_compilation_cache", False)
     one_chip = SingleDeviceSharding(topo.devices[0])
     fn, shapes = _build(kernel, shape)
@@ -107,6 +124,8 @@ def lowering_counts(kernel: str, shape: dict, dump_dir: str) -> dict:
     out["vregs_loaded"] = sum(
         mask.count("true") for mask in re.findall(
             r"tpu\.(?:\w+_)?load\b[^\n]*?sublanes \[([^\]]*)\]", text)) / 8
+    if kernel == "kda_seg":
+        return out
     # pages a block: what the loads are held against (4 vregs a page at most)
     row = pallas_paged.kv_row_shape(shape["kv_heads"], shape["head_dim"]) \
         if kernel == "paged_attn" else (1, shape["width"])

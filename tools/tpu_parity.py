@@ -247,7 +247,8 @@ def paged_cases():
 
 
 def kda_cases():
-    """`kda_step` (ops/pallas_kda.py) against the jnp step of ops/kda.py at
+    """`kda_seg` against the literal recurrence (below), and
+    `kda_step` (ops/pallas_kda.py) against the jnp step of ops/kda.py at
     the two cells' head counts, 128 rows of 128 x 128 float32 state: Kimi's
     32 heads with beta in (0, 1) and Solar-Open2's 64 (`head_block` 16:
     four grid steps a row) with beta near 2 — unit-norm q and k, a paused
@@ -280,7 +281,52 @@ def kda_cases():
                     "state": _close(got[1][:R], want[1][:R], 2e-4)}
         return [(f"kda_step{tag}_R128_H{H}_f32", run)]
 
-    return build(32, False, "_beta_under_1") + build(64, True, "_beta_near_2")
+    def build_seg(H, neg_eigval, tag):
+        """`kda_seg` (ops/pallas_kda_seg.py) against the literal recurrence
+        (`kda.recurrent`, the host's float32) over each run: 192 chunk rows
+        in three runs — 70 rows from position 0, one row, 100 rows
+        continuing a state — and 21 rows of padding."""
+        def run():
+            P, S, d = 192, 128, 128
+            rng = np.random.default_rng(_seed(f"kda_seg{tag}"))
+            f = lambda *shape: jnp.asarray(rng.normal(size=shape),
+                                           jnp.float32)
+            state = f(S + 1, H, d, d)
+            q, k = kda.l2norm(f(P, H, d)), kda.l2norm(f(P, H, d))
+            v, g = f(P, H, d), -jnp.exp(f(P, H, d) - 3.0)
+            beta = (2.0 * jax.nn.sigmoid(6.0 + 0.5 * f(P, H)) if neg_eigval
+                    else jax.nn.sigmoid(f(P, H)))
+            runs = [(5, 0, 70, 0), (77, 70, 1, 9), (2, 71, 100, 300)]
+            slot, pos = np.full(P, S, np.int32), np.zeros(P, np.int32)
+            for s, at, n, p0 in runs:
+                slot[at:at + n], pos[at:at + n] = s, np.arange(p0, p0 + n)
+            o, new, n_seg = jax.jit(lambda *a: kda.segment_rows(
+                *a, use_kernel=True))(state, jnp.asarray(slot),
+                                      jnp.asarray(pos), q, k, v, g, beta)
+            assert int(n_seg) == len(runs)
+            o, new = np.asarray(o), np.asarray(new)
+            out = {"out": 0.0, "state": 0.0}
+            for s, at, n, p0 in runs:
+                xs = [np.asarray(a)[None, at:at + n]
+                      for a in (q, k, v, g, beta)]
+                S0 = None if p0 == 0 else np.asarray(state)[s][None]
+                want_o, want_S = _oracle(
+                    lambda: kda.recurrent(*map(jnp.asarray, xs),
+                                          None if S0 is None
+                                          else jnp.asarray(S0)))
+                out["out"] = max(out["out"],
+                                 _close(o[at:at + n], want_o[0], 2e-4))
+                out["state"] = max(out["state"],
+                                   _close(new[s], want_S[0], 2e-4))
+            idle = np.setdiff1d(np.arange(S + 1), [r[0] for r in runs])
+            assert (new[idle] == np.asarray(state)[idle]).all()
+            assert not o[slot == S].any()
+            return out
+        return [(f"kda_seg{tag}_P192_H{H}_f32", run)]
+
+    return build(32, False, "_beta_under_1") + build(64, True, "_beta_near_2") \
+        + build_seg(32, False, "_beta_under_1") \
+        + build_seg(64, True, "_beta_near_2")
 
 
 def additive_cases():
