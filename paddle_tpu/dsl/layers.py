@@ -35,6 +35,8 @@ from paddle_tpu.dsl.poolings import AvgPooling, BasePoolingType, FirstPooling, L
 
 __all__ = [
     "rms_norm_layer", "gated_ffn_layer", "mla_attention_layer",
+    "hyper_expand_layer", "hyper_read_layer", "hyper_write_layer",
+    "hyper_collapse_layer",
     "kda_attention_layer", "short_conv_layer", "mamba2_layer", "mamba_layer",
     "data_layer", "fc_layer", "embedding_layer", "mixed_layer", "addto_layer",
     "concat_layer", "dropout_layer", "full_matrix_projection",
@@ -1273,6 +1275,82 @@ def mla_attention_layer(
     current_context().add_layer(cfg)
     return LayerOutput(name, "mla_attention", size, parents=[input],
                        seq_level=input.seq_level)
+
+
+def hyper_expand_layer(input: LayerOutput, *, streams: int,
+                       name: Optional[str] = None) -> LayerOutput:
+    """The embedding copied into `streams` residual streams: one flat layer
+    of size streams x input.size (graph/layers_hc.py; hyper-connections,
+    arXiv:2409.19606 section 3)."""
+    return _simple_layer("hyper_expand", [input], streams * input.size,
+                         name=name, cfg_extra={"streams": int(streams)})
+
+
+def hyper_collapse_layer(input: LayerOutput, *, streams: int,
+                         name: Optional[str] = None) -> LayerOutput:
+    """The `streams` residual streams summed into one hidden state, what
+    the final norm reads (graph/layers_hc.py)."""
+    assert input.size % streams == 0
+    return _simple_layer("hyper_collapse", [input], input.size // streams,
+                         name=name, cfg_extra={"streams": int(streams)})
+
+
+def hyper_read_layer(
+    input: LayerOutput,
+    *,
+    streams: int,
+    sinkhorn_iters: int = 20,
+    eps: float = 1e-6,
+    res_clamp=(-30.0, 30.0),
+    name: Optional[str] = None,
+    param_attr: Optional[ParameterAttribute] = None,
+) -> LayerOutput:
+    """What ONE sublayer reads from the residual streams X (`input`, flat,
+    streams x C): u = sum_i H_pre[i] X[i], of size C — manifold-constrained
+    hyper-connections (arXiv:2512.24880; ops/hyper_conn.py).  The sublayer's
+    three maps come from its own parameters (phi [streams C, 2 n + n^2]
+    initialized by `param_attr`, biases at 0, the gates alpha at 0.01) in a
+    layer `<name>_maps` of size 2 n + n^2, float32 whatever the compute
+    dtype: H_res through `sinkhorn_iters` Sinkhorn-Knopp iterations with
+    `eps` in the denominators after exp(clip(., *res_clamp)).  The write
+    that closes the sublayer is `hyper_write_layer(X, y, read=<this>)`."""
+    n = int(streams)
+    assert input.size % n == 0, "the streams do not divide the layer"
+    assert param_attr is None or not param_attr.name, \
+        "a named param_attr would tie the maps of different sublayers"
+    name = _name(name, "hyper_read")
+    width = 2 * n + n * n
+    # a parameter hangs on an input slot (LayerInput.input_parameter_name),
+    # so the one input is named once a parameter: phi, the bias row, the
+    # gates; the graph layer reads slot 0 alone
+    maps = _simple_layer(
+        "hyper_maps", [input] * 3, width, name=name + "_maps",
+        cfg_extra={"streams": n, "sinkhorn_iters": int(sinkhorn_iters),
+                   "eps": float(eps), "res_clamp": [float(res_clamp[0]),
+                                                    float(res_clamp[1])]},
+        params=[([input.size, width], param_attr),
+                ([1, width], ParameterAttribute(initial_mean=0.0,
+                                                initial_std=0.0)),
+                ([1, 3], ParameterAttribute(initial_mean=0.01,
+                                            initial_std=0.0))])
+    return _simple_layer("hyper_read", [input, maps], input.size // n,
+                         name=name, cfg_extra={"streams": n})
+
+
+def hyper_write_layer(input: LayerOutput, output: LayerOutput, *,
+                      read: LayerOutput,
+                      name: Optional[str] = None) -> LayerOutput:
+    """The stream pass that closes a sublayer: X' = H_res X + H_post^T y
+    with X = `input` (the streams the sublayer's `read` was taken from), y =
+    `output` (the sublayer's result) and the maps of `read`
+    (`hyper_read_layer`'s result).  On the TPU it is the Pallas kernel
+    `mhc_mix` (ops/pallas_hyper_conn.py), forward only."""
+    assert read.layer_type == "hyper_read"
+    maps = read.parents[1]
+    n = input.size // output.size
+    assert n * output.size == input.size and maps.size == 2 * n + n * n
+    return _simple_layer("hyper_write", [input, output, maps], input.size,
+                         name=name, cfg_extra={"streams": n})
 
 
 def kda_attention_layer(

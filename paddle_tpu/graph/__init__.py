@@ -13,3 +13,4 @@ from paddle_tpu.graph import layers_kda  # noqa: F401
 from paddle_tpu.graph import layers_sconv  # noqa: F401
 from paddle_tpu.graph import layers_ssm  # noqa: F401
 from paddle_tpu.graph import layers_mamba  # noqa: F401
+from paddle_tpu.graph import layers_hc  # noqa: F401

@@ -112,12 +112,30 @@ CATALOG: dict[str, str] = {
         "over the window layers",
     "serving_window_steps_total":
         "compiled steps that ran window layers",
+    "serving_mhc_rows_total":
+        "token rows compiled steps sent through a hyper-connected "
+        "sublayer's stream pass, padding rows included, summed over the "
+        "sublayers (over serving_mhc_calls_total: the rows ONE call "
+        "carries; 0 for a model whose blocks add into one stream)",
+    "serving_mhc_calls_total":
+        "stream passes (`mhc_mix` calls on the TPU) compiled steps ran: "
+        "two a layer a step, attention's and the MLP's",
+    "serving_residual_streams":
+        "residual streams a block's sublayers read from and write to "
+        "(hyper-connections; 1 for the plain residual)",
     "serving_kv_rows_total":
         "query rows the paged attention kernel's calls carried, one "
         "layer's worth a compiled step, padding rows included",
     "serving_kv_shared_rows_total":
         "of those, rows in a tile whose rows all read one slot, which "
         "walks that slot's K/V blocks once for all of them",
+    "serving_kv_tokens_attended_total":
+        "cached tokens the paged kernel's rows attend, one layer's worth "
+        "a compiled step: the sum of the rows' lengths (1 a padding row)",
+    "serving_kv_tokens_fetched_total":
+        "cached tokens the paged kernel copies for them, in whole blocks, "
+        "a tile that shares its walk counted once (over the attended: the "
+        "block fill; under 1 where rows share their fetches)",
     "serving_kv_pages_resident":
         "pages that hold live tokens (label kind: full = the allocator's "
         "pages in use, each backing every full layer; window = the slots' "

@@ -372,6 +372,15 @@ class ServingEngine:
         self.n_window_pages_recycled = 0
         self.n_window_rows = 0
         self.n_window_steps = 0
+        # HYPER-CONNECTIONS (graph/layers_hc.py): the sublayers whose
+        # residual path is a stream pass (`mhc_mix`), and the streams they
+        # mix — 0 and 1 for a model whose blocks add into one stream
+        self._mhc_writes = [l for l in executor.model.layers
+                            if l.type == "hyper_write"]
+        self.residual_streams = max(
+            [int(l.attrs["streams"]) for l in self._mhc_writes] or [1])
+        self.n_mhc_rows = 0
+        self.n_mhc_calls = 0
         if prefix_cache and (self._recurrent or self._ring_tables):
             import logging
             what, why = (
@@ -1549,21 +1558,35 @@ class ServingEngine:
                            len(self.slots))
 
     def _count_kv(self, lengths: np.ndarray,
-                  row_slot: Optional[np.ndarray] = None) -> None:
+                  row_slot: Optional[np.ndarray] = None,
+                  also: Optional[dict] = None) -> None:
         """Add one compiled step's rows to the kernel's counters: `lengths`
         the tokens each row attends, `row_slot` the table row it reads
-        (None: the rows are the slots, and no two share a walk)."""
+        (None: the rows are the slots, and no two share a walk); `also` the
+        step's other process-wide counts, added under the same lock."""
         from paddle_tpu.ops.pallas_paged import tile_rows, walked_blocks
         bq = 1 if row_slot is None or self._kv_tile is None else \
             tile_rows(lengths.size, *self._kv_tile)
         blocks, shared = walked_blocks(lengths, row_slot, bq, self._kv_block)
-        self.kv_tokens_attended += int(lengths.sum())
-        self.kv_tokens_fetched += blocks * self._kv_block
+        attended, fetched = int(lengths.sum()), blocks * self._kv_block
+        self.kv_tokens_attended += attended
+        self.kv_tokens_fetched += fetched
         self.n_kv_rows += lengths.size
         self.n_kv_shared_rows += shared
-        process_counters().add_many({
-            "serving_kv_rows_total": lengths.size,
-            "serving_kv_shared_rows_total": shared})
+        counts = {"serving_kv_rows_total": lengths.size,
+                  "serving_kv_shared_rows_total": shared,
+                  "serving_kv_tokens_attended_total": attended,
+                  "serving_kv_tokens_fetched_total": fetched, **(also or {})}
+        if self._mhc_writes:
+            # every row of the step, padding included, passes each
+            # sublayer's stream pass; a scanned dispatch runs its bodies'
+            writes = len(self._mhc_writes)
+            calls = writes * (lengths.shape[0] if lengths.ndim == 2 else 1)
+            self.n_mhc_rows += writes * lengths.size
+            self.n_mhc_calls += calls
+            counts.update(serving_mhc_rows_total=writes * lengths.size,
+                          serving_mhc_calls_total=calls)
+        process_counters().add_many(counts)
 
     def _scan_window_ok(self, runnable, k: int) -> bool:
         """Page precondition for ONE k-step scanned dispatch: every
@@ -1724,7 +1747,11 @@ class ServingEngine:
             self.n_mixed_steps += 1
             chunk_rows = sum(n for _, n, _ in advanced)
             self.n_step_pad_rows += T - len(runnable) - chunk_rows
-            self._count_kv(row_pos + 1, row_slot)  # a padding row reads 1
+            self._count_kv(row_pos + 1, row_slot, {  # a padding row reads 1
+                "serving_mixed_steps_total": 1,
+                "serving_chunk_rows_total": chunk_rows,
+                "serving_step_pad_rows_total":
+                    T - len(runnable) - chunk_rows})
             self._note_step_metrics(r, decoded=bool(runnable))
             self._count_recurrent_tokens(len(runnable), chunk_rows)
             if self._kda_seg and advanced:
@@ -2073,7 +2100,10 @@ class ServingEngine:
                 self.n_mixed_steps += 1
             self.n_step_pad_rows += T - r
             self.occupancy_sum += len(live) / S
-            self._count_kv(row_pos + 1, row_slot)  # a padding row reads 1
+            self._count_kv(row_pos + 1, row_slot, {  # a padding row reads 1
+                "serving_mixed_steps_total": int(bool(advanced)),
+                "serving_chunk_rows_total": sum(n for _, n, _ in advanced),
+                "serving_step_pad_rows_total": T - r})
             step = self.n_decode_steps
             with self._phase("readback", step=step, kind="spec"):
                 sampled = np.asarray(sampled)              # host sync
@@ -2740,6 +2770,7 @@ class ServingEngine:
                 "restore_tokens_saved", "n_prefill_chunks",
                 "n_chunk_rows", "n_chunk_extra_rows", "n_step_pad_rows",
                 "n_window_pages_recycled", "n_window_rows", "n_window_steps",
+                "n_mhc_rows", "n_mhc_calls",
                 "n_mixed_steps", "n_spec_steps", "n_spec_chains",
                 "n_spec_drafted", "n_spec_accepted", "n_spec_tokens",
                 "n_scan_steps", "n_scan_flushes", "n_draft_steps")},

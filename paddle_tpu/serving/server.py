@@ -469,12 +469,23 @@ class ServingServer:
                  float(eng.n_window_rows)),
                 ("serving_window_steps_total", "counter", None,
                  float(eng.n_window_steps)),
+                # hyper-connections: rows and calls of the stream pass
+                ("serving_mhc_rows_total", "counter", None,
+                 float(eng.n_mhc_rows)),
+                ("serving_mhc_calls_total", "counter", None,
+                 float(eng.n_mhc_calls)),
+                ("serving_residual_streams", "gauge", None,
+                 float(eng.residual_streams)),
                 # query rows the paged kernel's calls carried, and those
                 # whose tile walked its slot's blocks once for all its rows
                 ("serving_kv_rows_total", "counter", None,
                  float(eng.n_kv_rows)),
                 ("serving_kv_shared_rows_total", "counter", None,
                  float(eng.n_kv_shared_rows)),
+                ("serving_kv_tokens_attended_total", "counter", None,
+                 float(eng.kv_tokens_attended)),
+                ("serving_kv_tokens_fetched_total", "counter", None,
+                 float(eng.kv_tokens_fetched)),
                 *(("serving_kv_pages_resident", "gauge", {"kind": kind},
                    float(n))
                   for kind, n in sorted(eng.kv_pages_resident().items())),
@@ -1784,6 +1795,9 @@ class ServingServer:
             "ring_pages": dict(eng.kv.ring_specs),
             "window_pages_recycled": eng.n_window_pages_recycled,
             "window_rows": eng.n_window_rows,
+            "residual_streams": eng.residual_streams,
+            "mhc_rows": eng.n_mhc_rows,
+            "mhc_calls": eng.n_mhc_calls,
             "attn_gated_layers": eng.attn_gated_layers,
         }
 

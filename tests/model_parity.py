@@ -405,6 +405,47 @@ CASES = {c.name: c for c in (
             window_partial_rotary_factor=
             "rope_parameters.sliding_attention.partial_rotary_factor"),
         dsl_defaults=21),
+    ModelCase(
+        name="xing4", json="xing4.0-29b-a4b-serve.json", dsl="xing4.py",
+        # the published ratios: 4 residual streams; 4 heads of 8 + 4 against
+        # a latent of 16; 16 experts, top-4 of ALL of them (one group), every
+        # one held; one dense layer and two sparse ones
+        tiny=dict(hidden_size=32, intermediate_size=64, num_attention_heads=4,
+                  num_hidden_layers=3, vocab_size=64, q_lora_rank=24,
+                  kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+                  v_head_dim=12, moe_intermediate_size=16,
+                  n_routed_experts=16, experts_held=16, num_experts_per_tok=4,
+                  param_dtype="float32", init_std=0.3, select_bias_std=0.3),
+        dsl_keys=("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                  "qk_rope_head_dim", "v_head_dim", "moe_intermediate_size",
+                  "n_routed_experts", "num_experts_per_tok",
+                  "first_k_dense_replace", "hc_mult", "hc_sinkhorn_iters"),
+        # float32 sums in another order: the 20 Sinkhorn iterations and the
+        # 4 x 4 stream sums on top of the GigaChat case's 2e-5
+        tol=1e-4, whole_len=24, ragged_tol=2e-4,
+        # a map's bias gone (H_post of the first sublayer = 1 everywhere is
+        # still a model: its matrix phi gone instead, every map static); the
+        # four controls of the configuration file's `limits`
+        zeroed=("_blk0_hc1_maps.w0",),
+        ref_controls=({"hc_sinkhorn_iters": 1}, {"hc_post_scale": 1.0},
+                      {"hc_dynamic": False}, {"hc_plain_residual": True}),
+        ragged_kernels=(False, True),       # `mhc_mix`, `mla_paged_attn`
+        paged={f"blk{i}_attn": (128,) for i in range(3)}, margin=True,
+        engines=(EngineCase("chunked-jnp", 4, mst=7),
+                 EngineCase("chunked-kernel", 4, True, mst=7, build=AUTO),
+                 EngineCase("one-chunk", 32)),
+        prompts=(3, 19, 9, 17, 26), max_context=32,
+        letters={"mla_attention": "A"},
+        depths=(({}, "AAA", "dee"),
+                ({"num_hidden_layers": 1}, "A", "d"),
+                ({"num_hidden_layers": 6}, "A" * 6, "d" + "e" * 5)),
+        catalog="Xing4.0-29B-A4B",
+        reduced=frozenset({"num_hidden_layers", "first_k_dense_replace",
+                           "num_nextn_predict_layers"}),
+        dsl_nested={"rope_" + k: "rope_scaling." + k for k in (
+            "factor", "original_max_position_embeddings", "beta_fast",
+            "beta_slow", "mscale", "mscale_all_dim")},
+        dsl_defaults=26),
 )}
 
 

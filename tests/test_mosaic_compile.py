@@ -143,9 +143,10 @@ def mosaic(one_chip, no_persistent_cache, monkeypatch):
     in the result.  The kernels ask `jax.default_backend()` (sees `cpu`
     here) to pick interpret mode — steer that from the test."""
     from paddle_tpu.ops import (pallas_additive, pallas_attention,
-                                pallas_kda, pallas_paged, pallas_rnn)
-    for mod in (pallas_additive, pallas_attention, pallas_kda, pallas_paged,
-                pallas_rnn):
+                                pallas_hyper_conn, pallas_kda, pallas_paged,
+                                pallas_rnn)
+    for mod in (pallas_additive, pallas_attention, pallas_hyper_conn,
+                pallas_kda, pallas_paged, pallas_rnn):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
     def compile_(fn, *shapes, donate=()):
@@ -1168,3 +1169,55 @@ def test_latent_kernel_tiles_at_gigachats_shape(mosaic):
                       ((128,), i32), ((128,), i32))
     assert kernel_names(compiled) == ["mla_paged_attn.1"]
     assert re.search(r"s32\[32\]", compiled.as_text())
+
+
+# xing4.0-29b-serve.long-prompt-48's own shapes (benchmark/configs/
+# xing4.0-29b-a4b-serve.json: 48 slots, page 16, context 8,192 = 512 pages a
+# table row, a mixed step of 1,088 rows; 4 residual streams of 3,584; 32
+# query heads against the 640-lane latent row)
+XING = dict(S=48, ROWS=1088, N=4, C=3584, H=32, WP=640, V=512, PAGE=16,
+            MAXP=512, POOL=48 * 512 + 1)
+
+
+@pytest.mark.parametrize("rows", [XING["ROWS"], XING["S"]],
+                         ids=["mixed-1088-rows", "decode-48-rows"])
+def test_mhc_mix_at_the_xing_cells_shapes(mosaic, rows):
+    """The hyper-connections' stream pass through the call the layer makes
+    (ops/hyper_conn.py: write): ONE `mhc_mix` call, tiles of 32 rows where
+    they divide the step (34 tiles of 1,088) and of 16 at the 48 decode
+    rows; the streams, the sublayer's output and the float32 maps in, the
+    new streams out."""
+    from paddle_tpu.ops import hyper_conn, pallas_hyper_conn
+    c = XING
+    assert pallas_hyper_conn.tile_rows(rows) == (32 if rows == 1088 else 16)
+
+    def step(x, y, m):
+        return hyper_conn.write(x, y, m, c["N"], kernel=True)
+
+    compiled = mosaic(step, ((rows, c["N"] * c["C"]), bf16),
+                      ((rows, c["C"]), bf16),
+                      ((rows, hyper_conn.map_width(c["N"])), f32))
+    assert kernel_names(compiled) == ["mhc_mix.1"], kernel_names(compiled)
+
+
+@pytest.mark.parametrize("rows", [XING["ROWS"], XING["S"]],
+                         ids=["mixed-1088-rows", "decode-48-rows"])
+def test_latent_paged_kernel_at_the_xing_cells_shapes(mosaic, rows):
+    """`mla_paged_attn` at a size no other cell reaches: 1,088 rows a mixed
+    step (tiles of 8 rows: 136 a call) of 32 heads against tables of 512
+    pages a slot, and the 48 decode rows."""
+    from paddle_tpu.ops import mla, pallas_paged
+    c = XING
+    assert pallas_paged.tile_rows(c["ROWS"], c["H"], 128, c["WP"], bf16) == 8
+
+    def step(q, new, pool, table, row_slot, row_pos):
+        return mla.paged_latent_step(q, new, pool, table, row_slot, row_pos,
+                                     0.1, c["V"], use_kernel=True)
+
+    compiled = mosaic(
+        step, ((rows, c["H"], c["WP"]), bf16), ((rows, c["WP"]), bf16),
+        ((c["POOL"], c["PAGE"], c["WP"]), bf16),
+        ((c["S"] + 1, c["MAXP"]), i32), ((rows,), i32), ((rows,), i32),
+        donate=(2,))
+    assert kernel_names(compiled) == ["mla_paged_attn.1"], \
+        kernel_names(compiled)

@@ -329,6 +329,39 @@ def kda_cases():
         + build_seg(64, True, "_beta_near_2")
 
 
+def mhc_cases():
+    """`mhc_mix` (ops/pallas_hyper_conn.py) against the jnp stream pass of
+    ops/hyper_conn.py at the Xing4.0 cell's shapes: a mixed step's 1,088
+    rows (tiles of 32) and a decode step's 48 (tiles of 16) of 4 streams of
+    3,584, bfloat16 streams under float32 maps from the seeded spread, and
+    float32 streams."""
+    from paddle_tpu.ops import hyper_conn, pallas_hyper_conn
+
+    def build(rows, dtype):
+        name = f"mhc_mix_R{rows}_n4_C3584_{dtype}"
+
+        def run():
+            n, c = 4, 3584
+            rng = np.random.default_rng(_seed(name))
+            f = lambda *shape: jnp.asarray(rng.normal(size=shape),
+                                           jnp.float32)
+            x, y = f(rows, n * c).astype(dtype), f(rows, c).astype(dtype)
+            m = hyper_conn.maps(
+                x, 0.02 * f(n * c, 24), f(1, 24),
+                jnp.asarray([[1.0, 0.9, 1.1]], jnp.float32), n=n, iters=20,
+                eps=1e-6, clamp=(-30.0, 30.0))
+            got = pallas_hyper_conn.mhc_mix(x, y, m, n)
+            want = _oracle(hyper_conn.mix, x, y, m, n)
+            assert got.dtype == x.dtype
+            # float32: the same five terms in another order; bfloat16: one
+            # rounding of a sum of magnitude up to 4
+            return _close(got, want, 1e-5 if dtype == "float32" else 2e-2)
+        return name, run
+
+    return [build(r, d) for r in (1088, 48)
+            for d in ("bfloat16", "float32")]
+
+
 def additive_cases():
     from paddle_tpu.ops import pallas_additive
     from paddle_tpu.ops.attention import additive_attention_step as ref
@@ -469,7 +502,7 @@ def rnn_cases():
 
 def _build_selected(only):
     selected = [(name, fn)
-                for build in (flash_cases, paged_cases, kda_cases,
+                for build in (flash_cases, paged_cases, kda_cases, mhc_cases,
                               additive_cases, rnn_cases)
                 for name, fn in build()
                 if not only or any(name.startswith(o) for o in only)]
