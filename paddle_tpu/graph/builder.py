@@ -17,6 +17,7 @@ executes the sub-model's layers, with memories as the scan carry.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 from typing import Any, Optional
 
@@ -172,6 +173,8 @@ class GraphExecutor:
         mode: str = TRAIN,
         rng: Optional[jax.Array] = None,
         probes: Optional[dict[str, Array]] = None,
+        rows: Optional[dict[str, Array]] = None,
+        scopes: Optional[dict[str, str]] = None,
     ) -> tuple[dict[str, Argument], dict[str, Array], dict[str, Any]]:
         """Run the graph. Returns (layer outputs, per-sample costs, new state).
 
@@ -181,17 +184,36 @@ class GraphExecutor:
         reference reads from Layer::getOutputGrad() (ref: Evaluator.cpp
         GradientPrinter; hand-written backward buffers replaced by autodiff).
 
+        `rows` maps a top-level layer's name to a 1-D int index array: that
+        layer runs on those positions of its inputs' token axis alone
+        ([B, T, d] -> [B, R, d], lengths R) and its output has R rows —
+        how a serving step keeps a vocabulary head off the rows it does
+        not sample.  Refused (ValueError) for a layer inside a recurrent
+        group, an input that is not a dense sequence, and a layer that
+        would read the cut-down output in this same forward.
+
+        `scopes` maps a top-level layer's name to the `jax.named_scope`
+        its ops are traced under (a profiler trace then names them).
+
         Every layer's output is the caller's to read, so every layer runs
         as configured; `loss` is the entry point that may fuse a softmax
         head with its cost.
         """
-        return self._run(params, feed, state, mode, rng, probes, {})
+        return self._run(params, feed, state, mode, rng, probes, {},
+                         rows, scopes)
 
     def _run(self, params, feed, state, mode, rng, probes,
-             heads: dict[str, LayerConfig]):
+             heads: dict[str, LayerConfig], rows=None, scopes=None):
         """The plan, layer by layer.  `heads` maps an fc's name to the cost
         layer it may run with as one op (`_fusable_softmax_costs`): the fc
         is passed over at its own place and the pair runs at the cost's."""
+        rows, scopes = rows or {}, scopes or {}
+        for name in (*rows, *scopes):
+            if name in self._sub_of or name not in self.layer_map \
+                    or self.layer_map[name].type == "data":
+                raise ValueError(
+                    f"rows/scopes name {name!r}: not a top-level layer the "
+                    f"plan runs (a recurrent group's layers run in its scan)")
         params, feed = self.prepare(params, feed)
         ctx = ForwardContext(
             model=self.model, params=params, mode=mode, rng=rng,
@@ -215,7 +237,16 @@ class GraphExecutor:
                     # depends on a generator group's output — only produced by
                     # generate(); skip in plain forward
                     continue
-                out = get_layer_fn(cfg.type)(ctx, cfg)
+                if rows:
+                    self._refuse_cut_input(
+                        rows, f"layer {cfg.name!r}",
+                        (inp.input_layer_name for inp in cfg.inputs))
+                with jax.named_scope(scopes[cfg.name]) \
+                        if cfg.name in scopes else contextlib.nullcontext():
+                    if cfg.name in rows:
+                        out = self._layer_on_rows(ctx, cfg, rows[cfg.name])
+                    else:
+                        out = get_layer_fn(cfg.type)(ctx, cfg)
                 if probes and cfg.name in probes and out.value is not None:
                     out = out.replace(value=out.value + probes[cfg.name])
                 ctx.outputs[cfg.name] = out
@@ -223,8 +254,52 @@ class GraphExecutor:
                 sm: SubModelConfig = item
                 if sm.generator is not None and not sm.in_links:
                     continue  # generation-only group: run via generate()
+                if rows:
+                    self._refuse_cut_input(
+                        rows, f"recurrent group {sm.name!r}",
+                        (*sm.in_links, *sm.static_links,
+                         *(m.boot_layer_name for m in sm.memories)))
                 self._run_scan(ctx, sm)
         return ctx.outputs, ctx.costs, ctx.state_out
+
+    @staticmethod
+    def _refuse_cut_input(rows: dict, reader: str, reads) -> None:
+        """A layer that `rows` cut down to its chosen rows no longer lines
+        up with the sequence: nothing that runs may read it."""
+        for name in reads:
+            if name in rows:
+                raise ValueError(
+                    f"{reader} reads {name!r}, which `rows` cut down to "
+                    f"its chosen rows of the sequence")
+
+    @staticmethod
+    def _layer_on_rows(ctx: ForwardContext, cfg: LayerConfig,
+                       idx: Array) -> Argument:
+        """`cfg`'s layer on positions `idx` of its inputs' token axis: the
+        inputs are swapped for their gathered rows while the layer's
+        function runs, and put back for the layers that read them whole."""
+        idx = jnp.asarray(idx)
+        if idx.ndim != 1 or not jnp.issubdtype(idx.dtype, jnp.integer):
+            raise ValueError(
+                f"rows[{cfg.name!r}] must be a 1-D integer index array, "
+                f"got {idx.dtype}{list(idx.shape)}")
+        whole = {inp.input_layer_name: ctx.outputs[inp.input_layer_name]
+                 for inp in cfg.inputs}
+        cut = {}
+        for n, arg in whole.items():
+            if arg.value is None or arg.value.ndim != 3 or arg.sparse_dim \
+                    or arg.lengths is None or arg.sub_lengths is not None:
+                raise ValueError(
+                    f"rows[{cfg.name!r}]: input {n!r} is not a dense "
+                    f"[B, T, d] sequence of rows")
+            cut[n] = Argument(
+                value=arg.value[:, idx],
+                lengths=jnp.full_like(arg.lengths, idx.shape[0]))
+        ctx.outputs.update(cut)
+        try:
+            return get_layer_fn(cfg.type)(ctx, cfg)
+        finally:
+            ctx.outputs.update(whole)
 
     # -- softmax head + cost as one op -------------------------------------
     def _fusable_softmax_costs(self) -> dict[str, LayerConfig]:

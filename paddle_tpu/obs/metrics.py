@@ -129,6 +129,10 @@ CATALOG: dict[str, str] = {
     "serving_kv_shared_rows_total":
         "of those, rows in a tile whose rows all read one slot, which "
         "walks that slot's K/V blocks once for all of them",
+    "serving_head_rows_total":
+        "rows compiled steps ran the vocabulary head on: the rows they "
+        "sample (over serving_kv_rows_total: the share of a step's rows "
+        "that reach the head)",
     "serving_kv_tokens_attended_total":
         "cached tokens the paged kernel's rows attend, one layer's worth "
         "a compiled step: the sum of the rows' lengths (1 a padding row)",

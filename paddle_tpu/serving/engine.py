@@ -303,6 +303,8 @@ class ServingEngine:
         self.input_name, self.logits_name = _resolve_io_names(
             executor.model, input_name, logits_name)
         self._probs = _is_probs(executor.model, self.logits_name)
+        # the head's ops carry this scope in every step program's trace
+        self._head_scope = {self.logits_name: "lm.head"}
         # tensor parallelism: a mesh whose `model` axis exceeds 1 shards
         # attention heads + KV pools over it (docs/serving.md "Sharded
         # decode").  The executor must see the same mesh — layers_attn
@@ -492,6 +494,9 @@ class ServingEngine:
         self.kv_tokens_fetched = 0
         self.n_kv_rows = 0
         self.n_kv_shared_rows = 0
+        # rows compiled steps ran the vocabulary head on: the rows they
+        # sample (over n_kv_rows: the share of a step's rows that reach it)
+        self.n_head_rows = 0
         self._admit_seq = 0
         # ONE STEP IN FLIGHT (docs/serving.md "The step loop"): a decode
         # or mixed step is two halves, LAUNCH (plan, pack, dispatch) and
@@ -1559,11 +1564,14 @@ class ServingEngine:
 
     def _count_kv(self, lengths: np.ndarray,
                   row_slot: Optional[np.ndarray] = None,
-                  also: Optional[dict] = None) -> None:
+                  also: Optional[dict] = None,
+                  head_rows: Optional[int] = None) -> None:
         """Add one compiled step's rows to the kernel's counters: `lengths`
         the tokens each row attends, `row_slot` the table row it reads
         (None: the rows are the slots, and no two share a walk); `also` the
-        step's other process-wide counts, added under the same lock."""
+        step's other process-wide counts, added under the same lock;
+        `head_rows` the rows that reach the vocabulary head (None: every
+        row samples — a decode step, a scanned window's bodies)."""
         from paddle_tpu.ops.pallas_paged import tile_rows, walked_blocks
         bq = 1 if row_slot is None or self._kv_tile is None else \
             tile_rows(lengths.size, *self._kv_tile)
@@ -1573,7 +1581,11 @@ class ServingEngine:
         self.kv_tokens_fetched += fetched
         self.n_kv_rows += lengths.size
         self.n_kv_shared_rows += shared
+        if head_rows is None:
+            head_rows = lengths.size
+        self.n_head_rows += head_rows
         counts = {"serving_kv_rows_total": lengths.size,
+                  "serving_head_rows_total": head_rows,
                   "serving_kv_shared_rows_total": shared,
                   "serving_kv_tokens_attended_total": attended,
                   "serving_kv_tokens_fetched_total": fetched, **(also or {})}
@@ -1751,7 +1763,7 @@ class ServingEngine:
                 "serving_mixed_steps_total": 1,
                 "serving_chunk_rows_total": chunk_rows,
                 "serving_step_pad_rows_total":
-                    T - len(runnable) - chunk_rows})
+                    T - len(runnable) - chunk_rows}, head_rows=S)
             self._note_step_metrics(r, decoded=bool(runnable))
             self._count_recurrent_tokens(len(runnable), chunk_rows)
             if self._kda_seg and advanced:
@@ -2103,7 +2115,8 @@ class ServingEngine:
             self._count_kv(row_pos + 1, row_slot, {  # a padding row reads 1
                 "serving_mixed_steps_total": int(bool(advanced)),
                 "serving_chunk_rows_total": sum(n for _, n, _ in advanced),
-                "serving_step_pad_rows_total": T - r})
+                "serving_step_pad_rows_total": T - r},
+                head_rows=S * (K + 1))
             step = self.n_decode_steps
             with self._phase("readback", step=step, kind="spec"):
                 sampled = np.asarray(sampled)              # host sync
@@ -2764,7 +2777,7 @@ class ServingEngine:
                 "_admit_seq", "n_decode_steps", "n_preemptions",
                 "n_cancelled", "n_expired", "tokens_generated",
                 "occupancy_sum", "kv_tokens_attended", "kv_tokens_fetched",
-                "n_kv_rows", "n_kv_shared_rows",
+                "n_kv_rows", "n_kv_shared_rows", "n_head_rows",
                 "n_prefix_hits", "n_prefix_misses",
                 "prefill_tokens_saved", "n_restore_hits",
                 "restore_tokens_saved", "n_prefill_chunks",
@@ -3001,8 +3014,8 @@ class ServingEngine:
         state = self._layer_state(st, run, page_table=table, pos=st.pos)
         feed = {self.input_name: Argument(ids=st.toks[:, None],
                                           lengths=jnp.ones((S,), jnp.int32))}
-        outputs, _, state_out = self.executor.forward(params, feed, state,
-                                                      TEST, None)
+        outputs, _, state_out = self.executor.forward(
+            params, feed, state, TEST, None, scopes=self._head_scope)
         last = outputs[self.logits_name].value[:, 0, :]
         nxt = pick_next_per_slot(last, self._slot_keys(st), st.temp,
                                  st.topk, st.topp, is_probs=self._probs)
@@ -3078,10 +3091,11 @@ class ServingEngine:
                                   row_slot=row_slot, row_pos=row_pos)
         feed = {self.input_name: Argument(
             ids=row_ids[None, :], lengths=jnp.full((1,), T, jnp.int32))}
-        outputs, _, state_out = self.executor.forward(params, feed, state,
-                                                      TEST, None)
-        logits = outputs[self.logits_name].value[0]    # [T, V]
-        last = logits[sample_row]                      # [S, V]
+        # the head runs on the S rows the step samples, not on its T rows
+        outputs, _, state_out = self.executor.forward(
+            params, feed, state, TEST, None,
+            rows={self.logits_name: sample_row}, scopes=self._head_scope)
+        last = outputs[self.logits_name].value[0]      # [S, V]
         nxt = pick_next_per_slot(last, self._slot_keys(st), st.temp,
                                  st.topk, st.topp, is_probs=self._probs)
         new_pools = self._pools_out(st, state_out)
@@ -3260,12 +3274,14 @@ class ServingEngine:
                                   row_slot=row_slot, row_pos=row_pos)
         feed = {self.input_name: Argument(
             ids=row_ids[None, :], lengths=jnp.full((1,), T, jnp.int32))}
-        outputs, _, state_out = self.executor.forward(params, feed, state,
-                                                      TEST, None)
-        logits = outputs[self.logits_name].value[0]    # [T, V]
         idx = jnp.clip(first_row[:, None] + jnp.arange(K + 1)[None, :],
                        0, T - 1)
-        chain = logits[idx]                            # [S, K+1, V]
+        # the head runs on the chains' S * (K + 1) rows alone
+        outputs, _, state_out = self.executor.forward(
+            params, feed, state, TEST, None,
+            rows={self.logits_name: idx.reshape(-1)},
+            scopes=self._head_scope)
+        chain = outputs[self.logits_name].value[0].reshape(S, K + 1, -1)
         g = jnp.clip(st.gen[:, None] + jnp.arange(K + 1)[None, :], 0,
                      st.keys.shape[1] - 1)
         keys = st.keys[jnp.arange(S)[:, None], g]      # [S, K+1, 2]

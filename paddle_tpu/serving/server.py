@@ -482,6 +482,9 @@ class ServingServer:
                  float(eng.n_kv_rows)),
                 ("serving_kv_shared_rows_total", "counter", None,
                  float(eng.n_kv_shared_rows)),
+                # rows the steps ran the vocabulary head on (those sampled)
+                ("serving_head_rows_total", "counter", None,
+                 float(eng.n_head_rows)),
                 ("serving_kv_tokens_attended_total", "counter", None,
                  float(eng.kv_tokens_attended)),
                 ("serving_kv_tokens_fetched_total", "counter", None,
@@ -1747,6 +1750,8 @@ class ServingServer:
             # its query rows, and those that shared a tile's one walk
             "kv_rows": eng.n_kv_rows,
             "kv_shared_rows": eng.n_kv_shared_rows,
+            # rows that reached the vocabulary head: the rows steps sample
+            "head_rows": eng.n_head_rows,
             # routed pairs the held experts drew (0 without MoE layers)
             "moe_pairs_total": eng.moe_pairs_total,
             "moe_pairs_max_sum": eng.moe_pairs_max_sum,
