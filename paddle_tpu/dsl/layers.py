@@ -983,7 +983,7 @@ def multi_head_attention_layer(
     rotary_dim: Optional[int] = None,
     rope_scaling: Optional[dict] = None,
     attention_factor: float = 1.0,
-    qk_norm: bool = False,
+    qk_norm: Union[bool, str] = False,
     rms_eps: float = 1e-6,
     out_size: Optional[int] = None,
     out_gate: Union[bool, str] = False,
@@ -1006,6 +1006,9 @@ def multi_head_attention_layer(
     qk_norm: RMS-norm each head of q and of k, with a learned [head_dim]
     scale each (parameters 4 and 5, starting at 1) and `rms_eps`, before the
     rotation — in every path, so the K a cache holds is the normed one.
+    qk_norm="whole": one RMS-norm over the WHOLE projected q and one over
+    the whole k, before the heads are split (OLMo 2's; scales [size] and
+    [num_kv_heads x head_dim]) — the same two parameters, the same paths.
 
     size is the attention's own width, num_heads x head_dim (the layer's
     `size`: the cache manager reads a head's width from it); out_size is
@@ -1103,11 +1106,15 @@ def multi_head_attention_layer(
         pname = _make_param(name, i, [dim_in, dim_out], attrs[i])
         cfg.inputs.append(LayerInput(input_layer_name=inp.name,
                                      input_parameter_name=pname))
+    assert qk_norm in (False, True, "whole"), \
+        f"qk_norm is True (a norm a head) or 'whole' (got {qk_norm!r})"
     if qk_norm:
-        cfg.attrs.update(qk_norm=True, rms_eps=rms_eps)
-        for i in (4, 5):                  # the q and the k head norm
+        cfg.attrs.update(qk_norm=qk_norm, rms_eps=rms_eps)
+        widths = (size, kv_dim) if qk_norm == "whole" else \
+            (size // num_heads,) * 2
+        for i, width in zip((4, 5), widths):    # the q and the k norm
             pname = _make_param(
-                name, i, [1, size // num_heads],
+                name, i, [1, width],
                 ParameterAttribute(initial_mean=1.0, initial_std=0.0))
             cfg.inputs.append(LayerInput(input_layer_name=query.name,
                                          input_parameter_name=pname))
@@ -1358,55 +1365,81 @@ def kda_attention_layer(
     *,
     num_heads: int,
     head_dim: int,
+    value_dim: Optional[int] = None,
     conv_size: int = 4,
     size: Optional[int] = None,
     rms_eps: float = 1e-5,
     allow_neg_eigval: bool = False,
+    decay: str = "channel",
+    full_proj: bool = False,
+    gate_act: str = "sigmoid",
     attn_impl: Optional[str] = None,
     name: Optional[str] = None,
     param_attr: Optional[ParameterAttribute] = None,
     layer_attr: Optional[ExtraLayerAttribute] = None,
 ) -> LayerOutput:
-    """Kimi Delta Attention (arXiv:2510.26692; ops/kda.py,
-    graph/layers_kda.py): a causal token mixer whose context is one
-    recurrent state [head_dim, head_dim] a head, moved by a gated delta
-    rule with a per-channel decay — q, k and v through a depthwise causal
-    convolution of `conv_size` taps and SiLU, q and k l2-normed a head, the
-    decay and the output gate through rank-`head_dim` projections, a gated
-    RMSNorm a head in front of the output projection.  `allow_neg_eigval`
-    makes the write strength beta = 2 sigmoid(x w_b) in (0, 2) where it is
-    sigmoid(x w_b) in (0, 1): a transition I - beta k k^T then has an
-    eigenvalue in (-1, 1).
+    """The gated delta-rule token mixer (ops/kda.py, graph/layers_kda.py):
+    a causal token mixer whose context is one recurrent state [head_dim,
+    value_dim] a head — q, k and v through a depthwise causal convolution
+    of `conv_size` taps and SiLU, q and k l2-normed a head, a gated RMSNorm
+    a head in front of the output projection.  As published twice:
+
+      * Kimi Delta Attention (arXiv:2510.26692), the defaults: a decay a
+        CHANNEL, a square state, the decay and the output gate through
+        rank-`head_dim` projections, a sigmoid gate;
+      * Gated DeltaNet (arXiv:2412.06464): `decay="head"` (one decay a
+        head: a_log, dt_bias and the decay's projection are H wide),
+        `value_dim` its own (Olmo-Hybrid: 96 x 192), `full_proj=True` (the
+        decay and the gate through one matrix each), `gate_act="silu"`.
+
+    `allow_neg_eigval` makes the write strength beta = 2 sigmoid(x w_b) in
+    (0, 2) where it is sigmoid(x w_b) in (0, 1): a transition I - beta k
+    k^T then has an eigenvalue in (-1, 1).
     `param_attr` initializes the matrices and the convolutions; A_log starts
     uniform in [0, log 16] and dt_bias in softplus^-1 of [1e-3, 1e-1] (the
     ranges of the published initializers), the norm's scale at 1."""
     assert param_attr is None or not param_attr.name, \
         "a named param_attr would share one matrix across the projections"
+    assert decay in ("channel", "head"), decay
+    assert gate_act in ("sigmoid", "silu"), gate_act
     size = size if size is not None else input.size
     name = _name(name, "kda_layer")
     d, H, dk = input.size, num_heads, head_dim
+    dv = value_dim if value_dim is not None else head_dim
     r = head_dim
+    f = H if decay == "head" else H * dk         # the decay's width
     cfg = LayerConfig(name=name, type="kda_attention", size=size,
                       active_type="")
     cfg.attrs.update(num_heads=H, head_dim=dk, conv_size=conv_size,
                      rms_eps=rms_eps, causal=True)
+    # what is not Kimi's is said; a KDA layer's attrs stay as they were
+    if dv != dk:
+        cfg.attrs["value_dim"] = dv
+    if decay != "channel":
+        cfg.attrs["decay"] = decay
+    if full_proj:
+        cfg.attrs["full_proj"] = True
+    if gate_act != "sigmoid":
+        cfg.attrs["gate_act"] = gate_act
     if allow_neg_eigval:
         cfg.attrs["allow_neg_eigval"] = True
     if attn_impl is not None:
         cfg.attrs["attn_impl"] = attn_impl
+    proj = lambda out: [([d, out], param_attr)] if full_proj else \
+        [([d, r], param_attr), ([r, out], param_attr)]
     specs = [
         ([d, H * dk], param_attr), ([d, H * dk], param_attr),
-        ([d, H * dk], param_attr),
+        ([d, H * dv], param_attr),
         ([conv_size, H * dk], param_attr), ([conv_size, H * dk], param_attr),
-        ([conv_size, H * dk], param_attr),
-        ([d, r], param_attr), ([r, H * dk], param_attr),
+        ([conv_size, H * dv], param_attr),
+        *proj(f),
         ([1, H], ParameterAttribute(initial_min=0.0, initial_max=2.7726)),
-        ([1, H * dk], ParameterAttribute(initial_min=-6.9073,
-                                         initial_max=-2.2522)),
+        ([1, f], ParameterAttribute(initial_min=-6.9073,
+                                    initial_max=-2.2522)),
         ([d, H], param_attr),
-        ([d, r], param_attr), ([r, H * dk], param_attr),
-        ([1, dk], ParameterAttribute(initial_mean=1.0, initial_std=0.0)),
-        ([H * dk, size], param_attr),
+        *proj(H * dv),
+        ([1, dv], ParameterAttribute(initial_mean=1.0, initial_std=0.0)),
+        ([H * dv, size], param_attr),
     ]
     for i, (dims, attr) in enumerate(specs):
         pname = _make_param(name, i, dims, attr)

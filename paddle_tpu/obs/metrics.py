@@ -153,7 +153,8 @@ CATALOG: dict[str, str] = {
         "kind: step = a decode row, one token a slot state; segment = a "
         "prompt chunk's rows, a run of tokens a slot state)",
     "serving_recurrent_segment_chunks_total":
-        "chunks of 64 rows the KDA layers' segment kernel (kda_seg) folded "
+        "chunks of 64 rows the delta-rule layers' segment kernel (kda_seg, "
+        "gdn_seg for a decay a head) folded "
         "into slot states, one layer's worth a step: cdiv(rows, 64) a run "
         "of a prompt chunk's rows; tokens{kind=segment} over 64 x this is "
         "the chunks' fill; 0 where no KDA layer runs the kernel",

@@ -365,7 +365,9 @@ def project_qkv(query: Array, key: Array, value: Array,
     and v [..., H_kv, D] from inputs [..., T, d].  `qk_norm` (q scale [D],
     k scale [D], eps) RMS-norms each head of q and k — statistics in
     float32, times the learned scale — BEFORE the rotation (QK-norm as the
-    LFM2 / Qwen3 / OLMo-2 families apply it); `use_rope` then rotates q at
+    LFM2 / Qwen3 families apply it); scales as wide as the WHOLE projection
+    (q [H D], k [H_kv D]) norm over all of it before the heads are split
+    (OLMo 2's); `use_rope` then rotates q at
     `q_pos` and k at `k_pos` (`rope_kw`: `rope`'s rotary_dim, rope_scaling,
     attention_factor).  What is written to a KV cache is this k."""
     Dh = w_q.shape[1] // num_heads
@@ -377,10 +379,12 @@ def project_qkv(query: Array, key: Array, value: Array,
 
         def norm(x, scale):
             x32 = x.astype(jnp.float32)
+            if scale.size != x.shape[-1]:       # over the whole projection
+                x32 = x32.reshape(x.shape[:-2] + (-1,))
             x32 = x32 * jax.lax.rsqrt(
                 jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-            return (x32 * scale.astype(jnp.float32).reshape(-1)).astype(
-                x.dtype)
+            return (x32 * scale.astype(jnp.float32).reshape(-1)).reshape(
+                x.shape).astype(x.dtype)
 
         q, k = norm(q, q_scale), norm(k, k_scale)
     if use_rope:
@@ -547,7 +551,17 @@ def _stored_rows(new: Array, pages: Array) -> Array:
     order, so the gather path below reads a pool of either layout by
     reshaping what it gathered back to [H_kv, D]."""
     row = pages.shape[3 if _tokens_a_row(new, pages) > 1 else 2:]
+    if _whole_tiles_of_heads(new, pages):
+        # the heads past the model's own are zeros no query reads
+        new = jnp.pad(new, ((0, 0), (0, row[0] - new.shape[-2]), (0, 0)))
     return new.reshape(new.shape[:1] + row).astype(pages.dtype)
+
+
+def _whole_tiles_of_heads(new: Array, pages: Array) -> bool:
+    """Whether the pool stores the rows' MORE THAN 8 KV heads in whole
+    tiles of 8 heads (30 as 32: ops/pallas_paged.py:kv_row_shape)."""
+    from paddle_tpu.ops.pallas_paged import heads_padded
+    return heads_padded(new.shape[-2], new.shape[-1], pages.shape)
 
 
 def _tokens_a_row(new: Array, pages: Array) -> int:
@@ -627,6 +641,8 @@ def _paged_read(q: Array, ck: Array, cv: Array, k_new: Array,
             kv_heads=k_new.shape[-2], first=lo)
     # -- per-row page gather -> [T, T_ctx] contiguous view -----------------
     T_ctx = table.shape[1] * page_size
+    if _whole_tiles_of_heads(k_new, ck):
+        ck, cv = (p[..., :k_new.shape[-2], :] for p in (ck, cv))   # padding
     kc = ck[table[rows]].reshape(T, T_ctx, *k_new.shape[-2:])
     vc = cv[table[rows]].reshape(T, T_ctx, *k_new.shape[-2:])
     k_full, v_full = _expand_kv_heads(kc, vc, H)

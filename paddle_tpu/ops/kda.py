@@ -1,10 +1,15 @@
-"""Kimi Delta Attention (KDA, arXiv:2510.26692): a gated delta-rule linear
-attention whose decay is per CHANNEL.  A head keeps a state S [dk, dv]:
+"""The gated delta rule, a linear attention whose context is a state a
+head: Kimi Delta Attention (KDA, arXiv:2510.26692), whose decay is per
+CHANNEL, and Gated DeltaNet (arXiv:2412.06464), whose decay is ONE number a
+head — `g` [..., H, dk] or [..., H], read from its rank in every form here,
+nothing else differs.  A head keeps a state S [dk, dv] (dk != dv is fine:
+Olmo-Hybrid's is 96 x 192):
 
     S_t = (I - b_t k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t v_t^T
     o_t = S_t^T (q_t * dk^-1/2)
 
-with a_t = exp(g_t) in (0, 1)^dk and b_t in (0, 1) — in (0, 2) where the
+with a_t = exp(g_t) in (0, 1)^dk (one number repeated, for a decay a
+head) and b_t in (0, 1) — in (0, 2) where the
 layer asks for negative eigenvalues (`allow_neg_eigval`: with unit-norm k a
 transition I - b k k^T then has an eigenvalue 1 - b in (-1, 1), still a
 contraction; every form here takes b as data).  Written as a rank-1
@@ -27,10 +32,11 @@ Four forms, one result:
     body of `segment_rows` where the kernel is not used;
   * `step_rows`  — one token a row against a pool of slot states: the
     decode step, and the decode rows of the ragged mixed step (`kda_step`
-    on the TPU);
+    on the TPU, `gdn_step` for a decay a head);
   * `segment_rows` — the chunk rows of the ragged mixed step, each slot's
     run of rows one segment from its slot's state.  On the TPU one
-    `kda_seg` call a layer (ops/pallas_kda_seg.py: the same chunkwise
+    `kda_seg` call a layer (`gdn_seg` for a decay a head, whose pairwise
+    decays are one [CHUNK, CHUNK] matrix; ops/pallas_kda_seg.py: the same chunkwise
     mathematics, the runs' and chunks' loops inside the kernel, the state
     resident in VMEM, the pairwise decays never in HBM; forward only);
     elsewhere `chunkwise` once a run under ops/slot_rows.py
@@ -61,11 +67,19 @@ def l2norm(x, eps: float = 1e-6):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
+def per_head(g, k) -> bool:
+    """Whether `g` is one log decay a head ([..., H]) beside k [..., H, dk],
+    not one a channel ([..., H, dk])."""
+    return g.ndim == k.ndim - 1
+
+
 def decay(f, a_log, dt_bias):
-    """The log decay g = -exp(A_log_h) * softplus(f + dt_bias) <= 0:
-    f [..., H, dk] the gate projection, a_log [H], dt_bias [H, dk]."""
+    """The log decay g = -exp(A_log_h) * softplus(f + dt_bias) <= 0: a_log
+    [H]; f [..., H, dk] the gate projection and dt_bias [H, dk] for a decay
+    a channel, f [..., H] and dt_bias [H] for one a head."""
     f = f.astype(jnp.float32) + dt_bias.astype(jnp.float32)
-    return -jnp.exp(a_log.astype(jnp.float32))[:, None] * jax.nn.softplus(f)
+    a = -jnp.exp(a_log.astype(jnp.float32))
+    return (a if dt_bias.ndim == 1 else a[:, None]) * jax.nn.softplus(f)
 
 
 # ---------------------------------------------------------------------------
@@ -73,9 +87,11 @@ def decay(f, a_log, dt_bias):
 # ---------------------------------------------------------------------------
 
 def step(S, q, k, v, g, beta, scale: float):
-    """One token: S [..., dk, dv], q k g [..., dk], v [..., dv], beta [...]
-    -> (o [..., dv], S_new).  Elementwise products and reductions only."""
-    S = S * jnp.exp(g)[..., :, None]
+    """One token: S [..., dk, dv], q k [..., dk], g [..., dk] or [...] (a
+    decay a head), v [..., dv], beta [...] -> (o [..., dv], S_new).
+    Elementwise products and reductions only."""
+    a = jnp.exp(g)
+    S = S * (a[..., None, None] if per_head(g, k) else a[..., :, None])
     u = beta[..., None] * (v - jnp.sum(S * k[..., :, None], axis=-2))
     S = S + k[..., :, None] * u[..., None, :]
     o = jnp.sum(S * (q * scale)[..., :, None], axis=-2)
@@ -83,8 +99,9 @@ def step(S, q, k, v, g, beta, scale: float):
 
 
 def recurrent(q, k, v, g, beta, S0=None):
-    """The literal recurrence over T: q k g [B, T, H, dk], v [B, T, H, dv],
-    beta [B, T, H] -> (o [B, T, H, dv] float32, S [B, H, dk, dv])."""
+    """The literal recurrence over T: q k [B, T, H, dk], g [B, T, H, dk] or
+    [B, T, H], v [B, T, H, dv], beta [B, T, H] -> (o [B, T, H, dv] float32,
+    S [B, H, dk, dv])."""
     f32 = lambda a: a.astype(jnp.float32)
     q, k, v, g, beta = map(f32, (q, k, v, g, beta))
     B, T, H, dk = q.shape
@@ -112,6 +129,9 @@ def chunkwise(q, k, v, g, beta, S0=None, live=None):
     q, k, v, g, beta = map(f32, (q, k, v, g, beta))
     B, T, H, dk = q.shape
     dv = v.shape[-1]
+    scalar = per_head(g, k)
+    if scalar:
+        g = g[..., None]                # [B, T, H, 1]: broadcasts over dk
     C = min(CHUNK, T)
     N = -(-T // C)
     pad = N * C - T
@@ -136,10 +156,15 @@ def chunkwise(q, k, v, g, beta, S0=None, live=None):
         q = q * scale
         G = jnp.cumsum(g, axis=2)                        # <= 0, falling
         # pairwise decay exp(G_t - G_j) for j <= t, zero above the diagonal
-        D = G[..., :, None, :] - G[..., None, :, :]      # [B, H, C, C, dk]
+        D = G[..., :, None, :] - G[..., None, :, :]      # [B, H, C, C, dk|1]
         E = jnp.exp(jnp.where(lower[:, :, None], D, -jnp.inf))
-        kk = jnp.sum(k[..., :, None, :] * k[..., None, :, :] * E, axis=-1)
-        qk = jnp.sum(q[..., :, None, :] * k[..., None, :, :] * E, axis=-1)
+        if scalar:
+            # a decay a head: ONE [C, C] matrix scales both products
+            kk = jnp.einsum("bhtd,bhjd->bhtj", k, k, precision=_HI) * E[..., 0]
+            qk = jnp.einsum("bhtd,bhjd->bhtj", q, k, precision=_HI) * E[..., 0]
+        else:
+            kk = jnp.sum(k[..., :, None, :] * k[..., None, :, :] * E, axis=-1)
+            qk = jnp.sum(q[..., :, None, :] * k[..., None, :, :] * E, axis=-1)
         A = jnp.where(strict, beta * kk, 0.0)
         k_in = k * jnp.exp(G)                            # decayed from S_0
         WU = jax.scipy.linalg.solve_triangular(
@@ -173,8 +198,9 @@ def step_rows(state, slot, live, q, k, v, g, beta, use_kernel: bool = False):
     """One token a row against the slot states: state [S+1, H, dk, dv]
     float32 (row S is trash), slot [R] int32 the state each row advances
     (None: row r is slot r, the decode step), live [R] bool (a row that is
-    paused or padding leaves every state as it was), q k g [R, H, dk], v
-    [R, H, dv], beta [R, H] -> (o [R, H, dv] float32, state).  Each live
+    paused or padding leaves every state as it was), q k [R, H, dk], g
+    [R, H, dk] or [R, H], v [R, H, dv], beta [R, H] -> (o [R, H, dv]
+    float32, state).  Each live
     slot's state is read once and written once."""
     f32 = lambda a: a.astype(jnp.float32)
     q, k, v, g, beta = map(f32, (q, k, v, g, beta))
@@ -213,7 +239,8 @@ def segment_rows(state, seg_slot, seg_pos, q, k, v, g, beta,
     def one_segment(S0, mine):
         m = mine[:, None]
         o_i, S_end = chunkwise(
-            q[None], k[None], v[None], jnp.where(m[..., None], g, 0.0)[None],
+            q[None], k[None], v[None],
+            jnp.where(m if per_head(g, k) else m[..., None], g, 0.0)[None],
             jnp.where(m, beta, 0.0)[None], S0[None], live=mine)
         return o_i[0], S_end[0]
 
@@ -222,10 +249,10 @@ def segment_rows(state, seg_slot, seg_pos, q, k, v, g, beta,
         jnp.zeros(seg_slot.shape + v.shape[1:], jnp.float32), one_segment)
 
 
-def gated_out_norm(o, gate, scale, eps: float):
-    """RMSNorm over each head's dv with a learned scale, times
-    sigmoid(gate): o gate [..., H, dv], scale [dv] -> float32."""
+def gated_out_norm(o, gate, scale, eps: float, act=jax.nn.sigmoid):
+    """RMSNorm over each head's dv with a learned scale, times act(gate)
+    (KDA's sigmoid, Gated DeltaNet's silu): o gate [..., H, dv], scale [dv]
+    -> float32."""
     o = o.astype(jnp.float32)
     o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
-    return o * scale.astype(jnp.float32) * jax.nn.sigmoid(
-        gate.astype(jnp.float32))
+    return o * scale.astype(jnp.float32) * act(gate.astype(jnp.float32))

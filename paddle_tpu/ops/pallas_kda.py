@@ -1,8 +1,11 @@
 """The recurrent layers' decode steps in Pallas (TPU): one token a row
 against the slot states, the state moved in place — `kda_step` (the KDA
-delta rule, below) and `ssd_step` (Mamba-2's scalar-decay step, ops/ssd.py:
-the same body without the correction term, one static switch; the trace
-shows each under its own name).
+delta rule, below), `gdn_step` (the same body for Gated DeltaNet's decay a
+head: `a` is one number a head repeated down its column, the state a
+rectangular [96, 192] at Olmo-Hybrid's widths; its own name so that a trace
+tells the two rules apart) and `ssd_step` (Mamba-2's scalar-decay step,
+ops/ssd.py: the same body without the correction term, one static switch;
+the trace shows each under its own name).
 
 The jnp form (ops/kda.py `step`) is a reduction over the state (S'^T k)
 followed by an update that needs its result, so XLA passes over the 2 MiB a
@@ -69,13 +72,15 @@ def _interpret() -> bool:
 def head_block(num_heads: int) -> int:
     """Heads a grid step holds: the largest of 16 and 8 that divides the
     head count (1 MiB of state a block at 16 heads of 128 x 128; in and out
-    double-buffered: 4 MiB of VMEM), else all of them.  On the chip at 128
-    rows x 32 heads (PERF.md section 6, PR 33): 4 heads a step 1.13 ms a
-    call, 8 0.95, 16 0.90, 32 0.89 against 0.66 at the HBM's rate."""
+    double-buffered: 4 MiB of VMEM), else its largest divisor under 16 (15
+    of Olmo-Hybrid's 30 heads of 96 x 192: 1.4 MiB a block as VMEM lays it
+    out).  On the chip at 128 rows x 32 heads (PERF.md section 6, PR 33): 4
+    heads a step 1.13 ms a call, 8 0.95, 16 0.90, 32 0.89 against 0.66 at
+    the HBM's rate."""
     for hb in (16, 8):
         if num_heads % hb == 0:
             return hb
-    return num_heads
+    return max(hb for hb in range(1, 16) if num_heads % hb == 0)
 
 
 def _kernel(hb: int, delta: bool, slot_ref, live_ref, cols_ref, rows_ref,
@@ -156,20 +161,25 @@ def _step_call(name: str, delta: bool, hb: int, state, slot, live, cols, rows,
 def kda_step(state: Array, slot: Array, live: Array, q: Array, k: Array,
              v: Array, g: Array, beta: Array, scale: float):
     """state [S+1, H, dk, dv] float32; slot [R] int32 (a dead row's is the
-    trash row S), live [R] bool; q k g [R, H, dk], v [R, H, dv], beta
-    [R, H], all float32 -> (o [R, H, dv], state)."""
+    trash row S), live [R] bool; q k [R, H, dk], g [R, H, dk] — or [R, H],
+    one decay a head: the call is then named `gdn_step` —, v [R, H, dv],
+    beta [R, H], all float32 -> (o [R, H, dv], state)."""
     R, H, dk = q.shape
     dv = v.shape[-1]
     hb = head_block(H)
     nb = H // hb
+    per_head = g.ndim == 2
+    a = jnp.exp(g)
+    if per_head:
+        a = jnp.broadcast_to(a[..., None], q.shape)
     # [R, H, dk] -> [R, nb, dk, hb]: dk along sublanes, a head a lane
     col = lambda x: jnp.swapaxes(x.reshape(R, nb, hb, dk), 2, 3)
-    cols = jnp.concatenate([col(jnp.exp(g)), col(k), col(q * scale)], -1)
+    cols = jnp.concatenate([col(a), col(k), col(q * scale)], -1)
     rows = jnp.concatenate(
         [v.reshape(R, nb, hb, dv),
          jnp.broadcast_to(beta.reshape(R, nb, hb, 1), (R, nb, hb, dv))], 2)
-    o, state = _step_call("kda_step", True, hb, state, slot, live, cols,
-                          rows, (hb, dv))
+    o, state = _step_call("gdn_step" if per_head else "kda_step", True, hb,
+                          state, slot, live, cols, rows, (hb, dv))
     return o.reshape(R, H, dv), state
 
 

@@ -94,17 +94,19 @@ _TILE = 8               # float32 rows a vreg holds
 _VMEM_LIMIT = 64 * 2 ** 20
 
 
-def head_block(num_heads: int, rows: int, d: int) -> int:
+def head_block(num_heads: int, rows: int, lanes: int) -> int:
     """Heads a grid step holds: `pallas_kda.head_block`'s (16 of 32 or 64
-    heads), halved while the rows' block and the output's, double-buffered
-    (2 x 6 x rows x d float32 a head: 25 MiB at 16 heads of 256 rows), would
-    pass half the kernel's VMEM.  On the chip at 192 chunk rows x 32 heads
-    (my chip runs, PR 56): 4 heads a step 0.343 ms a call, 8 0.334, 16
-    0.331, 32 0.330 — the block is not what binds it."""
-    hb = pallas_kda.head_block(num_heads)
-    while hb % 2 == 0 and 2 * 6 * rows * d * 4 * hb > _VMEM_LIMIT // 2:
-        hb //= 2
-    return hb
+    heads, 15 of 30), or the largest divisor of the head count under it
+    whose rows' blocks and output's, double-buffered (`lanes` float32 a row
+    a head as VMEM lays them out — 6 d for KDA's five operands and its
+    output: 25 MiB at 16 heads of 256 rows), stay inside half the kernel's
+    VMEM.  On the chip at 192 chunk rows x 32 heads (my chip runs, PR 56):
+    4 heads a step 0.343 ms a call, 8 0.334, 16 0.331, 32 0.330 — the
+    block is not what binds it."""
+    fits = lambda hb: 2 * lanes * rows * 4 * hb <= _VMEM_LIMIT // 2
+    top = pallas_kda.head_block(num_heads)
+    return max([hb for hb in range(1, top + 1)
+                if num_heads % hb == 0 and fits(hb)] or [1])
 
 
 def chunk_rows(rows: int) -> int:
@@ -130,6 +132,12 @@ def _dot_nt(a, b):
                                preferred_element_type=jnp.float32)
 
 
+def _dot_tn(a, b):
+    """a [C, M] x b [C, N] -> [M, N]: contracted over the rows of both."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())), precision=_HI,
+                               preferred_element_type=jnp.float32)
+
+
 def _levels(C: int) -> int:
     """Bits a row index of a chunk holds: the levels the pairs part at."""
     return (C - 1).bit_length()
@@ -149,11 +157,62 @@ def _sums(C: int):
     return jnp.concatenate(parts, axis=0).astype(jnp.float32)
 
 
-def _chunk(C: int, x, S, sums_ref, a_ref, u_ref, n):
-    """One chunk of one head: x = (q, k, g, bk, bv) [C, d] each (q scaled,
-    bk = b k, bv = b v), S [d, d] the state it starts from, n the rows of
-    it that are the run's -> (o [C, d], S_new).  `sums_ref` holds
-    `_sums(C)`; `a_ref` [C, C] is VMEM scratch."""
+def _solve(C: int, A, rhs, a_ref, u_ref):
+    """(I + A) u = rhs by columns, A [C, C] zero on and above the diagonal:
+    once row j is final, every later row takes A[., j] u_j off — so the
+    rows of j's own tile that come before it are left as they were."""
+    a_ref[...] = A
+    u_ref[...] = rhs
+    for j in range(C - 1):
+        low = slice((j + 1) // _TILE * _TILE, C)    # whole tiles from j + 1
+        u_ref[low, :] = u_ref[low, :] - a_ref[low, j:j + 1] * u_ref[j:j + 1, :]
+    return u_ref[...]
+
+
+def _chunk_head(C: int, x, S, scratch, n):
+    """One chunk of one head under a decay a HEAD (`gdn_seg`): x = (q, k,
+    vx) with q (scaled) and k [C, dk], vx [C, dv + 2] holding v, then g and
+    beta a column each (they ride in on v's last lane tile), S [dk, dv] the
+    state it starts from, n the rows that are the run's -> (o [C, dv],
+    S_new).  The pairwise decays are ONE [C, C] matrix, E[t, j] = exp(G_t -
+    G_j) for j <= t: the exponent sum_{j < i <= t} g_i is a product of two
+    triangles of ones with g on the second's rows (never positive, and no
+    row of g ever lies along lanes), and E multiplies K K^T and Q K^T
+    entrywise — no tree of levels, no exponential a channel."""
+    a_ref, u_ref = scratch
+    q, k, vx = x
+    dv = S.shape[1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    valid = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0) < n
+    v = vx[:, :dv]
+    # the ragged tail: g = 0 and b = 0 leave the state as it was
+    g = jnp.where(valid, vx[:, dv:dv + 1], 0.0)              # [C, 1]
+    b = jnp.where(valid, vx[:, dv + 1:dv + 2], 0.0)
+    D = _dot((col <= row).astype(jnp.float32), jnp.where(row > col, g, 0.0))
+    E = jnp.where(col <= row, jnp.exp(jnp.minimum(D, 0.0)), 0.0)
+    G = D[:, 0:1] + g[0:1, :]                                # cumsum, [C, 1]
+    eG = jnp.exp(G)
+    bk = b * k
+    # both products against the state the chunk starts from, one pass of S
+    kq_S = _dot(jnp.concatenate([bk * eG, q * eG], axis=0), S)
+    P = _dot_nt(jnp.concatenate([bk, q], axis=0), k)         # [2C, C]
+    u = _solve(C, jnp.where(row > col, P[:C] * E, 0.0), b * v - kq_S[:C],
+               a_ref, u_ref)
+    o = kq_S[C:] + _dot(P[C:] * E, u)
+    g_last = G[C - 1:C]                                      # [1, 1]
+    # (a [1, 1] goes along lanes first: Mosaic broadcasts one way at a time)
+    S = jnp.exp(jnp.broadcast_to(g_last, (1, dv))) * S + \
+        _dot_tn(k * jnp.exp(g_last - G), u)
+    return o, S
+
+
+def _chunk(C: int, x, S, scratch, n):
+    """One chunk of one head under a decay a CHANNEL (`kda_seg`): x = (q,
+    k, g, bk, bv) [C, d] each (q scaled, bk = b k, bv = b v), S [d, d] the
+    state it starts from, n the rows of it that are the run's -> (o [C, d],
+    S_new).  `sums_ref` holds `_sums(C)`; `a_ref` [C, C] is VMEM scratch."""
+    sums_ref, a_ref, u_ref = scratch
     row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
     valid = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0) < n
@@ -187,16 +246,7 @@ def _chunk(C: int, x, S, sums_ref, a_ref, u_ref, n):
             & ((col & s) == 0)
         A = jnp.where(mine, P[:C], A)   # b_t kk[t, j]
         qk = jnp.where(mine, P[C:], qk)
-    a_ref[...] = A                      # zero on and above the diagonal
-
-    # (I + A) u = rhs by columns: once row j is final, every later row
-    # takes A[., j] u_j off — A is zero on and above the diagonal, so the
-    # rows of j's own tile that come before it are left as they were
-    u_ref[...] = rhs
-    for j in range(C - 1):
-        low = slice((j + 1) // _TILE * _TILE, C)    # whole tiles from j + 1
-        u_ref[low, :] = u_ref[low, :] - a_ref[low, j:j + 1] * u_ref[j:j + 1, :]
-    u = u_ref[...]                                           # [C, d]
+    u = _solve(C, A, rhs, a_ref, u_ref)     # A: zero on and above the diag
 
     o = kq_S[C:] + _dot(qk, u)
     g_last = G[C - 1:C]                                      # [1, d]
@@ -238,7 +288,7 @@ def _kernel(C: int, hb: int, n_ref, slot_ref, start_ref,
                 # at a sublane offset it cannot prove a multiple of 8
                 x = tuple(ref[i, rows, :] for ref in
                           (q_ref, k_ref, g_ref, bk_ref, bv_ref))
-                o, S = _chunk(C, x, s_buf[i], sums_ref, a_ref, u_ref, left)
+                o, S = _chunk(C, x, s_buf[i], (sums_ref, a_ref, u_ref), left)
                 s_buf[i] = S
                 o_ref[i, rows, :] = jnp.where(valid_rows < left, o,
                                               o_ref[i, rows, :])
@@ -254,6 +304,78 @@ def _kernel(C: int, hb: int, n_ref, slot_ref, start_ref,
         return 0
 
     jax.lax.fori_loop(0, n_ref[0], run, 0)
+
+
+def _tiles(d: int) -> list:
+    """[(first lane, lanes)] of a row of d: pieces one lane tile wide.  A
+    rows' operand of `gdn_seg` wider than a tile goes in (and its output
+    comes out) one operand a piece: Mosaic takes a load or a store at a
+    sublane offset it cannot prove a multiple of 8 — a chunk starts at its
+    run's own first row — only on a buffer ONE lane tile wide (asked at
+    1,024 chunk rows of 194 lanes, PR 59: "cannot statically prove that
+    index in dimension 1 is a multiple of 8")."""
+    return [(at, min(128, d - at)) for at in range(0, d, 128)]
+
+
+def _kernel_head(C: int, hb: int, widths: tuple, dv: int, n_ref, slot_ref,
+                 start_ref, len_ref, zero_ref, *refs):
+    """`gdn_seg`: grid (head blocks, runs).  Where `kda_seg` copies a run's
+    state itself, here the PIPELINE moves it, a [1, hb, dk, dv] block
+    addressed by the run's slot as `gdn_step`'s is by its row's: a pool
+    whose rows are not whole lane tiles (96 x 192) cannot be sliced for a
+    copy of the kernel's own (Mosaic, PR 59: "Slice shape along dimension 3
+    must be aligned to tiling (128), but is 192"), and a block of the whole
+    last two dims can.  A step is one run of one head block: the state's
+    block is the working buffer, zeroed where the run starts at position 0;
+    the runs past the table's live ones have no rows and leave the trash
+    row as it was.  The rows' operands and the output are blocks a head
+    block, resident across its runs."""
+    del n_ref, slot_ref                 # the slot addressed the state block
+    refs, x_refs = list(refs), []
+    for d in widths:
+        x_refs.append([refs.pop(0) for _ in _tiles(d)])
+    s_ref = refs.pop(0)
+    o_refs = [refs.pop(0) for _ in _tiles(dv)]
+    s_out, *scratch = refs
+    r = pl.program_id(1)
+    row0, n = start_ref[r], len_ref[r]
+
+    @pl.when(r == 0)
+    def _():
+        # rows no run holds (padding) read zeros, as the jnp form's
+        for o_ref in o_refs:
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+    from_zero = zero_ref[r] != 0
+
+    @pl.when(from_zero)
+    def _():
+        s_out[...] = jnp.zeros_like(s_out)
+
+    @pl.when(jnp.logical_not(from_zero))
+    def _():
+        s_out[...] = s_ref[...]
+
+    valid_rows = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+
+    def chunk(c, _):
+        left = n - c * C
+        rows = pl.ds(row0 + c * C, C)
+
+        def head(i, _):
+            x = tuple(group[0][i, rows, :] if len(group) == 1 else
+                      jnp.concatenate([ref[i, rows, :] for ref in group],
+                                      axis=1) for group in x_refs)
+            o, S = _chunk_head(C, x, s_out[0, i], scratch, left)
+            s_out[0, i] = S
+            for o_ref, (lane, w) in zip(o_refs, _tiles(dv)):
+                o_ref[i, rows, :] = jnp.where(
+                    valid_rows < left, o[:, lane:lane + w], o_ref[i, rows, :])
+            return 0
+
+        return jax.lax.fori_loop(0, hb, head, 0)
+
+    jax.lax.fori_loop(0, (n + C - 1) // C, chunk, 0)
 
 
 @functools.lru_cache(maxsize=None)
@@ -291,27 +413,77 @@ def _program(H: int, hb: int, rows: int, C: int, d: int, state_shape: tuple,
     )
 
 
+@functools.lru_cache(maxsize=None)
+def _program_head(H: int, hb: int, rows: int, runs: int, C: int,
+                  state_shape: tuple, interpret: bool):
+    """`gdn_seg`'s pallas_call for one set of shapes, built once as
+    `_program`: q, k and v (g and beta a column each behind it) and the
+    output in pieces one lane tile wide, the state a block a run."""
+    dk, dv = state_shape[2:]
+    widths = (dk, dk, dv + 2)
+    by_head = lambda h, r, *_: (h, 0, 0)
+    by_slot = lambda h, r, n, slot, *_: (slot[r], h, 0, 0)
+    pieces = lambda d: [pl.BlockSpec((hb, rows, w), by_head)
+                        for _, w in _tiles(d)]
+    ins = [spec for d in widths for spec in pieces(d)]
+    state = pl.BlockSpec((1, hb, dk, dv), by_slot)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,          # n_runs, slot, start, length, zero
+        grid=(H // hb, runs),
+        in_specs=ins + [state],
+        out_specs=pieces(dv) + [state],
+        scratch_shapes=[pltpu.VMEM((C, C), jnp.float32),
+                        pltpu.VMEM((C, dv), jnp.float32)])
+    return pl.pallas_call(
+        functools.partial(_kernel_head, C, hb, widths, dv),
+        name="gdn_seg",         # the device op's name in a profiler trace
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((H, rows, w), jnp.float32)
+                   for _, w in _tiles(dv)]
+        + [jax.ShapeDtypeStruct(state_shape, jnp.float32)],
+        # operands count the five prefetched scalars: the state is the last
+        input_output_aliases={5 + len(ins): len(_tiles(dv))},
+        compiler_params=pallas_tpu_compiler_params(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )
+
+
 def kda_segments(state: Array, seg_slot: Array, seg_pos: Array, q: Array,
                  k: Array, v: Array, g: Array, beta: Array, scale: float):
     """The chunk rows of a ragged mixed step (ops/kda.py `segment_rows`):
     state [S+1, H, dk, dv] float32; seg_slot seg_pos [P] int32 (padding aims
-    at trash row S); q k g v [P, H, d] (dk = dv, as the layer's state is
-    square), beta [P, H], all float32 -> (o [P, H, d], zeros outside the
-    runs; state; n_segments)."""
-    P, H, d = q.shape
-    assert v.shape == q.shape, (q.shape, v.shape)
+    at trash row S); q k [P, H, dk], v [P, H, dv], beta [P, H], and g
+    [P, H, dk] (a decay a channel: `kda_seg`, dk = dv as KDA's state is
+    square) or [P, H] (a decay a head: `gdn_seg`, any dk and dv; a slot
+    holds ONE run a step, the packing contract's), all float32 ->
+    (o [P, H, dv], zeros outside the runs; state; n_segments)."""
+    P, H, dk = q.shape
+    dv = v.shape[-1]
     trash = state.shape[0] - 1
     start, length, slot, zero, n_seg = segment_table(seg_slot, seg_pos, trash)
+    table = (n_seg.reshape(1).astype(jnp.int32),
+             *(a.astype(jnp.int32) for a in (slot, start, length, zero)))
     C = chunk_rows(P)
     rows = -(-P // _TILE) * _TILE + C   # a chunk's window stays inside
     b = beta[..., None]
     # [P, H, d] -> [H, rows, d]: a head's rows contiguous, zeros past P
-    xs = (jnp.swapaxes(jnp.pad(a, ((0, rows - P), (0, 0), (0, 0))), 0, 1)
-          for a in (q * scale, k, g, b * k, b * v))
-    program = _program(H, head_block(H, rows, d), rows, C, d, state.shape,
-                       pallas_kda._interpret())
-    o, state = program(
-        n_seg.reshape(1).astype(jnp.int32),
-        *(a.astype(jnp.int32) for a in (slot, start, length, zero)),
-        *xs, state)
+    by_head = lambda a: jnp.swapaxes(
+        jnp.pad(a, ((0, rows - P), (0, 0), (0, 0))), 0, 1)
+    if g.ndim == 2:
+        xs = (q * scale, k, jnp.concatenate([v, g[..., None], b], axis=-1))
+        xs = [by_head(a[..., lane:lane + w]) for a in xs
+              for lane, w in _tiles(a.shape[-1])]
+        hb = head_block(H, rows, 128 * (len(xs) + len(_tiles(dv))))
+        program = _program_head(H, hb, rows, min(P, trash), C, state.shape,
+                                pallas_kda._interpret())
+        *o, state = program(*table, *xs, state)
+        o = o[0] if len(o) == 1 else jnp.concatenate(o, axis=-1)
+    else:
+        assert v.shape == q.shape, (q.shape, v.shape)
+        program = _program(H, head_block(H, rows, 6 * dk), rows, C, dk,
+                           state.shape, pallas_kda._interpret())
+        o, state = program(*table, *map(by_head, (q * scale, k, g, b * k,
+                                                  b * v)), state)
     return jnp.swapaxes(o[:, :P], 0, 1), state, n_seg

@@ -173,6 +173,15 @@ def _round_up(n: int, m: int) -> int:
 # pages a block at this budget and would take 22% less at 16 (ROADMAP S1).
 _KV_VMEM_BUDGET = 512 << 10
 _BLOCK_TOKENS = (128, 512)       # floor and ceiling of a block, in tokens
+# A row of MORE THAN 8 stored heads: the kernel scores a tile's query heads
+# against every head's rows of a block (`cols` = tokens x heads, the other
+# groups masked), so at the 128-token floor a row of 32 heads is 4,096
+# score columns, 1 MB of float32 scores a query row — `tile_rows` then
+# holds 2 rows of its 8 and a prompt chunk's run re-walks its context every
+# second row (61.6% of the Olmo-Hybrid cell's busy time, at the HBM's rate:
+# PERF.md section 6, PR 59).  Such a row's block holds the score columns
+# of the 8-head cells' instead: 1,024 // heads tokens, whole pages.
+_WIDE_ROW_COLS = 1024
 
 
 def kv_row_shape(h_kv: int, head_dim: int) -> tuple[int, int]:
@@ -186,11 +195,27 @@ def kv_row_shape(h_kv: int, head_dim: int) -> tuple[int, int]:
     pages-minor and the kernel's page copy is refused; stored [.., 4, 128]
     it is lane-dense (T(4,128)(2,1): the bytes of its elements).  Where
     the heads do not fill whole tiles (h_kv * head_dim not a multiple of
-    128) the row stays as it is and the kernel pads its lanes."""
+    128) the row stays as it is and the kernel pads its lanes.  MORE THAN
+    8 heads of 128 lanes or more are stored in whole tiles of 8 heads (30
+    as 32): a bf16 pool's HBM tile `(8,128)(2,1)` holds them so in any case
+    — [.., 30, 128] TAKES the bytes of [.., 32, 128] — and Mosaic refuses
+    the page's copy out of a row that is no whole number of tiles ("Slice
+    shape along dimension 2 must be aligned to tiling (8), but is 30", PR
+    59); the heads past the model's own hold zeros that no query reads."""
     if head_dim < 128 and 128 % head_dim == 0 and \
             (h_kv * head_dim) % 128 == 0:
         return h_kv * head_dim // 128, 128
+    if head_dim >= 128 and h_kv > 8:
+        return _round_up(h_kv, 8), head_dim
     return h_kv, head_dim
+
+
+def heads_padded(h_kv: int, head_dim: int, pool_shape: tuple) -> bool:
+    """Whether a pool of this shape stores a token's `h_kv` heads in whole
+    tiles of 8 (`kv_row_shape`'s last rule): its row holds heads of zeros
+    past the model's own."""
+    return tuple(pool_shape[-2:]) == kv_row_shape(h_kv, head_dim) and \
+        pool_shape[-2] > h_kv
 
 
 def kv_page_shape(page_size: int, h_kv: int, head_dim: int,
@@ -222,6 +247,8 @@ def block_tokens(page_size: int, h_kv: int, head_dim: int, itemsize: int,
     per_token = 2 * 2 * h_kv * _round_up(head_dim, 128) * itemsize
     lo, hi = _BLOCK_TOKENS
     tokens = max(lo, min(hi, _KV_VMEM_BUDGET // per_token))
+    if h_kv > 8:
+        tokens = min(tokens, _WIDE_ROW_COLS // h_kv)
     pages = max(1, min(tokens // page_size, max_pages))
     return pages * page_size
 
@@ -576,6 +603,15 @@ def paged_attention(
     P, ps, G, L = k_pages.shape
     maxp = page_table.shape[1]
     pack = L // D                       # KV heads a stored row: 1 unpacked
+    if kv_heads is not None and heads_padded(kv_heads, D, k_pages.shape):
+        # the query heads of the heads that are not there are zeros, dropped
+        # from the result
+        pad = (G - kv_heads) * (H // kv_heads)
+        out = paged_attention(
+            jnp.pad(q, ((0, 0), (0, pad), (0, 0))), k_pages, v_pages,
+            page_table, lengths, scale if scale is not None else D ** -0.5,
+            row_slot, G, first)
+        return out[:, :H]
     if kv_heads is not None and kv_heads // pack != G:
         # rows a page / rows a token: the lone head stored two tokens a row
         ps, G = ps * G * pack // kv_heads, kv_heads // pack

@@ -446,6 +446,56 @@ CASES = {c.name: c for c in (
             "factor", "original_max_position_embeddings", "beta_fast",
             "beta_slow", "mscale", "mscale_all_dim")},
         dsl_defaults=26),
+    ModelCase(
+        name="olmo_hybrid", json="olmo-hybrid-7b-serve.json",
+        dsl="olmo_hybrid.py",
+        # the published ratios: 6 heads (no power of two) on as many KV
+        # heads, of 8; 6 linear heads whose state is 8 x 16 (dk != dv, 1 : 2
+        # as published); one period: L L L F
+        tiny=dict(hidden_size=48, intermediate_size=64, num_attention_heads=6,
+                  num_key_value_heads=6, num_hidden_layers=4, vocab_size=64,
+                  linear_num_key_heads=6, linear_num_value_heads=6,
+                  linear_key_head_dim=8, linear_value_head_dim=16,
+                  param_dtype="float32", init_std=0.3),
+        dsl_keys=("linear_num_key_heads", "linear_num_value_heads",
+                  "linear_key_head_dim", "linear_value_head_dim",
+                  "linear_conv_kernel_dim", "linear_allow_neg_eigval",
+                  "rms_norm_eps", "norm_after_sublayer", "use_qk_norm",
+                  "qk_norm_whole", "use_rope"),
+        renamed=dict(KV),
+        derived=dict(layer_types=lambda c: ";".join(
+            t[0] for t in c["layer_types"])),
+        # an output norm brings a sublayer's result to unit scale whatever
+        # its size, its float32 rounding with it, and the log-probabilities
+        # spread over 16 nats: the median position reads 1.5e-5 between the
+        # chunkwise form and the reference's recurrence, the worst of 600
+        # over four seeds 4.5e-4 (measured here).  Twice that; every control
+        # moves a log-probability by 4.7 nats or more
+        tol=1e-3, whole_len=70, ragged_chunks=(7, 9, 4, 3),
+        # the output gate's matrix gone (silu(0) = 0: the mixer silent); the
+        # reference with beta in (0, 1), with no decay, with the pre-norm
+        # block, with no QK-norm: the configuration file's controls
+        zeroed=("_blk0_gdn.w10",),
+        ref_controls=({"linear_allow_neg_eigval": False},
+                      {"linear_decay": False},
+                      {"norm_after_sublayer": False},
+                      {"use_qk_norm": False}), state_control=True,
+        ragged_kernels=(False, True),       # `gdn_step` and `gdn_seg`
+        recurrent=("blk0_gdn", "blk1_gdn", "blk2_gdn"),
+        recurrent_type="kda_attention",
+        slot_parts={"state": ((6, 8, 16), "float32"),
+                    "conv": ((3, 192), "")},
+        paged={"blk3_attn": (6, 8)}, moe=False, margin=True,
+        engines=(EngineCase("chunked-jnp", 5),
+                 EngineCase("free-rows-kernel", 5, True, mst=34, build=AUTO)),
+        letters={"kda_attention": "L", "multi_head_attention": "F"},
+        depths=(({}, "LLLF", "dddd"),
+                ({"num_hidden_layers": 2}, "LL", "dd"),
+                ({"num_hidden_layers": 8}, "LLLFLLLF", "d" * 8)),
+        catalog="Olmo-Hybrid-7B", reduced=frozenset({"num_hidden_layers"}),
+        dsl_nested=dict(
+            layer_types=lambda c, ref: [t[0] for t in c["layer_types"]]),
+        dsl_defaults=13),
 )}
 
 

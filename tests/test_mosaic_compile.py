@@ -1221,3 +1221,103 @@ def test_latent_paged_kernel_at_the_xing_cells_shapes(mosaic, rows):
         donate=(2,))
     assert kernel_names(compiled) == ["mla_paged_attn.1"], \
         kernel_names(compiled)
+
+
+# the Olmo-Hybrid cell (benchmark/configs/olmo-hybrid-7b-serve.json): 24
+# slots; Gated DeltaNet layers of 30 heads whose float32 state is 96 x 192
+# (neither a lane tile: the pool holds a row of 192 as two tiles), ONE decay
+# a head; a mixed step's 1,024 chunk rows; full layers of 30 heads on 30 KV
+# heads of 128 under contexts of 9,216
+OLMO = dict(S=24, H=30, DK=96, DV=192, P=1024, PAGE=16, MAXP=9216 // 16,
+            POOL=24 * 576 + 1, D=128)
+
+
+def test_gdn_mixed_step_holds_both_kernels_and_moves_only_its_state(mosaic):
+    """A Gated DeltaNet layer's part of the ragged mixed step at the Olmo-
+    Hybrid cell's shapes (24 decode rows, 1,024 chunk rows, 30 heads — which
+    neither 16 nor 8 divides: `head_block` takes 15 — of 96 x 192) as
+    graph/layers_kda.py makes it, in ONE compiled program: `gdn_step` and
+    `gdn_seg` under their own names (the KDA readers' patterns `kda_step.*`
+    and `kda_seg.*` match neither), the [25, 30, 96, 192] pool donated,
+    aliased through both and made by no copy, nothing of the jnp chunkwise
+    form left, and the decode step alone."""
+    from paddle_tpu.ops import kda, pallas_kda, pallas_kda_seg
+    c = OLMO
+    S, H, dk, dv, P = c["S"], c["H"], c["DK"], c["DV"], c["P"]
+    assert pallas_kda.head_block(H) == 15
+    assert pallas_kda.head_block(32) == pallas_kda.head_block(64) == 16
+    # q, k, v (+ g, beta) and o in pieces one lane tile wide: 6 tiles a row
+    assert pallas_kda_seg.head_block(H, P + 64, 128 * 6) == 5
+    assert pallas_kda_seg.head_block(32, 192 + 64, 6 * 128) == 16   # Kimi's
+
+    def mixed(state, row_slot, live, seg_slot, seg_pos, q, k, v, g, beta):
+        o_d, state = kda.step_rows(state, row_slot, live, q[:S], k[:S],
+                                   v[:S], g[:S], beta[:S], use_kernel=True)
+        o_c, state, n_seg = kda.segment_rows(
+            state, seg_slot, seg_pos, q[S:], k[S:], v[S:], g[S:], beta[S:],
+            use_kernel=True)
+        return jnp.concatenate([o_d, o_c]), state, n_seg
+
+    qk, head = ((S + P, H, dk), f32), ((S + P, H), f32)
+    pool = ((S + 1, H, dk, dv), f32)
+    compiled = mosaic(mixed, pool, ((S,), i32), ((S,), jnp.bool_),
+                      ((P,), i32), ((P,), i32), qk, qk, ((S + P, H, dv), f32),
+                      head, head, donate=(0,))
+    names = kernel_names(compiled)
+    assert [n.split(".")[0] for n in names] == ["gdn_seg", "gdn_step"], names
+    import re
+    text = compiled.as_text()
+    made_by = re.findall(r"= f32\[25,30,96,192\]\S* ([\w-]+)\(", text)
+    assert made_by and "copy" not in made_by, made_by
+    assert "input_output_alias" in text
+    for gone in (" while(", " conditional(", "InvertDiagBlocks",
+                 "triangular"):
+        assert gone not in text, gone
+
+    def decode(state, slot, live, q, k, v, g, beta):
+        return kda.step_rows(state, None, live, q, k, v, g, beta,
+                             use_kernel=True)
+
+    qk, head = ((S, H, dk), f32), ((S, H), f32)
+    compiled = mosaic(decode, pool, ((S,), i32), ((S,), jnp.bool_), qk, qk,
+                      ((S, H, dv), f32), head, head, donate=(0,))
+    assert kernel_names(compiled) == ["gdn_step.1"], kernel_names(compiled)
+
+
+@pytest.mark.parametrize("rows", [24, 1048], ids=["decode", "mixed-1048-rows"])
+def test_paged_kernel_at_30_query_heads_on_30_kv_heads(mosaic, rows):
+    """`paged_attn` at group size ONE — 30 query heads on 30 KV heads of 128
+    (15,360 B of K or V a token: 3.75 times the 8-KV-head cells'), tables of
+    576 pages a slot — at the decode step's 24 rows and a mixed step's
+    1,048: one call under its own name, the 3.4 GB pools donated and never
+    copied."""
+    from paddle_tpu.ops.attention import (paged_attention_step,
+                                          ragged_paged_attention_step)
+    from paddle_tpu.ops.pallas_paged import kv_row_shape
+    c = OLMO
+    row = kv_row_shape(c["H"], c["D"])
+    assert row == (32, 128)             # whole tiles of 8 heads
+    assert kv_row_shape(8, 128) == (8, 128) and kv_row_shape(2, 128) == \
+        (2, 128) and kv_row_shape(8, 64) == (4, 128)
+    pools = [((c["POOL"], c["PAGE"]) + row, bf16)] * 2
+    if rows == c["S"]:
+        def step(q, k, v, kp, vp, table, pos):
+            return paged_attention_step(q, k, v, kp, vp, table, pos,
+                                        use_kernel=True)
+        hd = ((rows, 1, c["H"], c["D"]), bf16)
+        compiled = mosaic(step, hd, hd, hd, *pools, ((rows, c["MAXP"]), i32),
+                          ((rows,), i32), donate=(3, 4))
+    else:
+        def step(q, k, v, kp, vp, table, row_slot, row_pos):
+            return ragged_paged_attention_step(q, k, v, kp, vp, table,
+                                               row_slot, row_pos,
+                                               use_kernel=True)
+        hd = ((rows, c["H"], c["D"]), bf16)
+        compiled = mosaic(step, hd, hd, hd, *pools,
+                          ((c["S"] + 1, c["MAXP"]), i32), ((rows,), i32),
+                          ((rows,), i32), donate=(3, 4))
+    assert kernel_names(compiled) == ["paged_attn.1"], kernel_names(compiled)
+    import re
+    made_by = re.findall(r"= bf16\[13825,16,32,128\]\S* ([\w-]+)\(",
+                         compiled.as_text())
+    assert made_by and "copy" not in made_by and "pad" not in made_by, made_by

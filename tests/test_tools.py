@@ -556,3 +556,25 @@ def test_bench_prefix_evict_walk_and_kept_free_the_same_pages(
     assert row["walk_ms"] > 0 and row["kept_ms"] > 0
     with open(tmp_path / "chiprun_out" / "bench_prefix_evict.jsonl") as f:
         assert json.loads(f.read().strip()) == row
+
+
+@pytest.mark.parametrize("seconds", [40.0, 120.0])
+def test_closed_loop_spread_a_longer_window_swings_less(seconds, capsys):
+    """tools/closed_loop_spread.py on the Olmo-Hybrid cell's own mix: the
+    simulated loop serves what the chip served (800-900 tokens/s, four steps
+    in five mixed, `itl_p95_ms` a mixed step's), tokens/s spreads by more
+    than the tail, and a window three times as long by less than half."""
+    import json
+
+    from tools import closed_loop_spread
+
+    assert closed_loop_spread.main(["--seeds", "24", "--seconds",
+                                    str(seconds)]) == 0
+    row = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert row["simulated"] is True and row["seeds"] == 24
+    assert 800 < row["tokens_per_s"]["median"] < 900
+    assert 30 < row["itl_p95_ms"]["median"] < 40
+    assert 0.75 < row["mixed"] / (row["mixed"] + row["decode"]) < 0.9
+    assert row["tokens_per_s"]["spread_pct"] > row["itl_p95_ms"]["spread_pct"]
+    lo, hi = (2.0, 6.0) if seconds == 40.0 else (0.4, 2.0)
+    assert lo < row["tokens_per_s"]["spread_pct"] < hi

@@ -329,6 +329,69 @@ def kda_cases():
         + build_seg(64, True, "_beta_near_2")
 
 
+def gdn_cases():
+    """`gdn_step` and `gdn_seg` — the delta rule with ONE decay a head on a
+    rectangular state — at the Olmo-Hybrid cell's shapes: 24 slots, 30 heads
+    of 96 x 192 float32, beta near 2.  The step against the jnp step (a
+    paused row and a slot indirection among its 24 rows); the segments
+    against the literal recurrence (`kda.recurrent`, the host's float32)
+    over each run of a mixed step's 1,024 chunk rows: 512 rows continuing a
+    state, 300 rows from position 0, one row, and 211 rows of padding."""
+    from paddle_tpu.ops import kda
+    H, dk, dv, S = 30, 96, 192, 24
+
+    def operands(name, rows):
+        rng = np.random.default_rng(_seed(name))
+        f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+        state = f(S + 1, H, dk, dv)
+        q, k = kda.l2norm(f(rows, H, dk)), kda.l2norm(f(rows, H, dk))
+        v, g = f(rows, H, dv), -jnp.exp(f(rows, H) - 3.0)
+        beta = 2.0 * jax.nn.sigmoid(6.0 + 0.5 * f(rows, H))
+        return rng, state, (q, k, v, g, beta)
+
+    def step_case():
+        rng, state, xs = operands("gdn_step", S)
+        live = jnp.asarray(rng.random(S) > 0.1)
+        slot = jnp.asarray(rng.permutation(S), jnp.int32)
+
+        def step(use_kernel):
+            return jax.jit(lambda *a: kda.step_rows(
+                *a, use_kernel=use_kernel))(state, slot, live, *xs)
+
+        got, want = step(True), _oracle(lambda: step(False))
+        rows = np.asarray(live)
+        return {"out": _close(np.asarray(got[0])[rows], want[0][rows], 2e-4),
+                "state": _close(got[1][:S], want[1][:S], 2e-4)}
+
+    def seg_case():
+        P = 1024
+        _, state, xs = operands("gdn_seg", P)
+        runs = [(5, 0, 512, 4096), (17, 512, 300, 0), (2, 812, 1, 77)]
+        slot, pos = np.full(P, S, np.int32), np.zeros(P, np.int32)
+        for s, at, n, p0 in runs:
+            slot[at:at + n], pos[at:at + n] = s, np.arange(p0, p0 + n)
+        o, new, n_seg = jax.jit(lambda *a: kda.segment_rows(
+            *a, use_kernel=True))(state, jnp.asarray(slot), jnp.asarray(pos),
+                                  *xs)
+        assert int(n_seg) == len(runs)
+        o, new = np.asarray(o), np.asarray(new)
+        out = {"out": 0.0, "state": 0.0}
+        for s, at, n, p0 in runs:
+            part = [np.asarray(a)[None, at:at + n] for a in xs]
+            S0 = None if p0 == 0 else jnp.asarray(np.asarray(state)[s][None])
+            want_o, want_S = _oracle(
+                lambda: kda.recurrent(*map(jnp.asarray, part), S0))
+            out["out"] = max(out["out"], _close(o[at:at + n], want_o[0], 2e-4))
+            out["state"] = max(out["state"], _close(new[s], want_S[0], 2e-4))
+        idle = np.setdiff1d(np.arange(S + 1), [r[0] for r in runs])
+        assert (new[idle] == np.asarray(state)[idle]).all()
+        assert not o[slot == S].any()
+        return out
+
+    return [("gdn_step_R24_H30_96x192_f32", step_case),
+            ("gdn_seg_P1024_H30_96x192_f32", seg_case)]
+
+
 def mhc_cases():
     """`mhc_mix` (ops/pallas_hyper_conn.py) against the jnp stream pass of
     ops/hyper_conn.py at the Xing4.0 cell's shapes: a mixed step's 1,088
@@ -502,7 +565,8 @@ def rnn_cases():
 
 def _build_selected(only):
     selected = [(name, fn)
-                for build in (flash_cases, paged_cases, kda_cases, mhc_cases,
+                for build in (flash_cases, paged_cases, kda_cases, gdn_cases,
+                              mhc_cases,
                               additive_cases, rnn_cases)
                 for name, fn in build()
                 if not only or any(name.startswith(o) for o in only)]
