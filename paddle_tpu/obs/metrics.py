@@ -127,8 +127,9 @@ CATALOG: dict[str, str] = {
         "query rows the paged attention kernel's calls carried, one "
         "layer's worth a compiled step, padding rows included",
     "serving_kv_shared_rows_total":
-        "of those, rows in a tile whose rows all read one slot, which "
-        "walks that slot's K/V blocks once for all of them",
+        "of those, rows in a run — the consecutive rows of a tile that "
+        "read one slot — which walks that slot's K/V blocks once for all "
+        "of them",
     "serving_head_rows_total":
         "rows compiled steps ran the vocabulary head on: the rows they "
         "sample (over serving_kv_rows_total: the share of a step's rows "

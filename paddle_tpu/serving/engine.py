@@ -487,9 +487,9 @@ class ServingEngine:
         # what the paged kernel reads, summed over compiled steps (one
         # layer's worth a step): the KV tokens its rows attend, and the
         # tokens it fetches for them in whole blocks (ops/pallas_paged.py
-        # block_tokens), a block once a TILE of rows where the tile's rows
-        # are one slot's (tile_rows).  attended / fetched is the block
-        # fill; kv_shared_rows of kv_rows rode such a shared walk.
+        # block_tokens), a block once a RUN of rows — a tile's (tile_rows)
+        # consecutive rows that are one slot's.  attended / fetched is the
+        # block fill; kv_shared_rows of kv_rows rode such a shared walk.
         self.kv_tokens_attended = 0
         self.kv_tokens_fetched = 0
         self.n_kv_rows = 0
@@ -524,7 +524,7 @@ class ServingEngine:
         self.n_host_stages = 0
         S = num_slots
         self._kk = self.kv.capacity_tokens     # keys per slot (> max_new)
-        from paddle_tpu.ops.pallas_paged import block_tokens
+        from paddle_tpu.ops.pallas_paged import block_tokens, query_tile
         # the kernel's shapes at the first layer under the logical table (a
         # ring's rows each read a table row of their own: no tile is shared)
         paged = [l for l in executor.model.layers
@@ -541,9 +541,13 @@ class ServingEngine:
                 self.kv.page_size, h_kv, pool.shape[-1],
                 pool.dtype.itemsize, self.kv.pages_per_slot)
             # what `tile_rows` takes after the call's rows
-            self._kv_tile = (
-                int(layer.attrs["num_heads"]) // self.kv.tp_shards,
-                self._kv_block * h_kv, pool.shape[-1], pool.dtype)
+            heads = int(layer.attrs["num_heads"])
+            kv_heads = int(layer.attrs.get("num_kv_heads", 0) or heads) \
+                if pool.ndim == 4 else 1
+            self._kv_tile = query_tile(
+                heads // self.kv.tp_shards,
+                max(kv_heads // self.kv.tp_shards, 1),
+                (h_kv, pool.shape[-1]), self._kv_block, pool.dtype)
         else:       # no page-indexed part: no kernel fetches any block
             self._kv_block = self.kv.page_size
             self._kv_tile = None
@@ -1573,8 +1577,10 @@ class ServingEngine:
         `head_rows` the rows that reach the vocabulary head (None: every
         row samples — a decode step, a scanned window's bodies)."""
         from paddle_tpu.ops.pallas_paged import tile_rows, walked_blocks
-        bq = 1 if row_slot is None or self._kv_tile is None else \
-            tile_rows(lengths.size, *self._kv_tile)
+        # the tile of one call's rows (a scanned window's bodies are a
+        # call each): a decode call's last tile is padded with dead rows
+        bq = 1 if self._kv_tile is None else \
+            tile_rows(lengths.shape[-1], *self._kv_tile)
         blocks, shared = walked_blocks(lengths, row_slot, bq, self._kv_block)
         attended, fetched = int(lengths.sum()), blocks * self._kv_block
         self.kv_tokens_attended += attended

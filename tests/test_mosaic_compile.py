@@ -28,27 +28,43 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 
-@pytest.mark.parametrize("shape,heads,kv_heads,pages", [
+@pytest.mark.parametrize("shape,heads,stored,pages", [
     ("", 24, 2, 16),
     ("heads=48,kv_heads=8,rows=320,max_pages=512,pages=32769", 48, 8, 8),
-], ids=["decode-saturated", "laguna-full-mixed-320-rows"])
-def test_paged_kernel_reads_a_block_as_dense_tiles(shape, heads, kv_heads,
+    ("rows=280,heads=30,kv_heads=30,max_pages=576,pages=13825", 32, 32, 8),
+    ("mla_paged_attn", 64, 1, 8),
+], ids=["decode-saturated", "laguna-full-mixed-320-rows",
+        "olmo-hybrid-mixed-280-rows", "latent-one-dense-operand"])
+def test_paged_kernel_reads_a_block_as_dense_tiles(shape, heads, stored,
                                                    pages):
     """The lowering itself, at decode-saturated's shape (64 rows, 24 / 2
-    heads of 128, page 16, bf16) and at the Laguna full layer's mixed step
-    (320 rows, 48 / 8 heads, 512 pages a table row): a block's pages land in
-    the matmul operand's own rows, so the kernel stores nothing but its
-    output, loads K and V once a walk (a page of 16 x 2 heads is 2 + 2
-    vregs) and no load moves under half a vreg.  Read through a `(2,128)`
-    tiled buffer the same block was 580 loads of one live sublane and 516
-    stores (PR 42).  The program holds two walks — one query row at a
-    time, and a TILE of rows against one fetch of each block — and the
-    tile's is held to the row's counts: the same loads of a block, q's and
-    the output's tiles once a row of the tile."""
+    heads of 128, page 16, bf16), at the Laguna full layer's mixed step
+    (320 rows, 48 / 8 heads, 512 pages a table row) and at Olmo-Hybrid's
+    (280 rows, 30 / 30 heads stored as 32, 576 pages): a block's pages land
+    once in the matmul operand's own rows (the copies of PR 42: a page of 16
+    x 2 heads is 2 + 2 vregs) and the kernel stores nothing but its output.
+    The program holds two walks.  A ROW's reads K and V once as the dense
+    operand they land as, its scores [heads, tokens x stored heads] with
+    the other groups' columns masked.  A RUN of one slot's rows does the
+    same with the tile's rows where a token's row holds four heads or fewer
+    (decode-saturated's two: the parent's loads and stores plus the select's
+    read-back); where it holds more it reads K and
+    V once ONE STORED HEAD AT A TIME — sublane-strided loads of the
+    buffer's uint32 view, every one a whole vreg of whole 32-bit rows (a
+    pair of heads' even tokens, or odd) — and scores a block's tokens a
+    dot: no value of the program is a tile's rows by the dense columns
+    (Olmo's would be [1,024, 4,096] float32).  Olmo's K and V buffers are
+    4 MB (two of 128 tokens x 32 heads x 128 lanes a pool) of the 16 MB
+    Mosaic scopes a kernel on the v5e: it compiles.  ONE stored head (the
+    latent kernel at GigaChat's 64 heads of 640 lanes) is one dense operand
+    in both walks and no strided load: the parent's loads, stores and
+    copies, 216 / 80 / 24, and a run's output stored under a select beside
+    the tile's other runs' (the tile's 64 vregs read back)."""
     tool = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "tools", "kernel_lowering.py")
-    p = subprocess.run([sys.executable, tool, "paged_attn"] + [shape] *
-                       bool(shape), text=True,
+    argv = [shape] if shape == "mla_paged_attn" else \
+        ["paged_attn"] + [shape] * bool(shape)
+    p = subprocess.run([sys.executable, tool] + argv, text=True,
                        capture_output=True, timeout=300,
                        env=dict(os.environ, JAX_PLATFORMS="cpu"))
     lines = p.stdout.strip().splitlines()
@@ -57,21 +73,51 @@ def test_paged_kernel_reads_a_block_as_dense_tiles(shape, heads, kv_heads,
     if p.returncode == 3:
         pytest.skip(got["skipped"])
     assert p.returncode == 0, got
-    assert got["pages_per_block"] == pages and got["tile_rows"] == 8, got
+    assert got["pages_per_block"] == pages, got
+    if stored == 1:
+        tile = 4 * 64 * 512 * 2 // 4096         # 4 rows x 64 heads x 512
+        assert (got["tpu.load"] - tile, got["tpu.store"],
+                got["tpu.enqueue_dma"], got["strided_loads"],
+                got["tile_rows"]) == (216, 80, 24, 0, 4), got
+        return
+    tokens, hp = pages * 16, -(-heads // 16) * 16
+    kv = 2 * tokens * stored * 128 * 2 // 4096      # K and V of a block
+    if stored == 2:
+        # one dense operand in both walks, as the parent's program: the
+        # run's scores are the tile's rows by every column, K and V are
+        # loaded once a walk, q a row and a tile, and the tile's output is
+        # read back under the runs' select
+        assert (got["tile_rows"], got["strided_loads"]) == (8, 0), got
+        assert got["tpu.enqueue_dma"] == 3 * 2 * pages, got
+        assert (got["f32_cols"], got["f32_rows_at_cols"]) == \
+            (tokens * stored, 8 * hp), got
+        assert got["tpu.store"] <= 9 * hp * 128 * 4 // 4096, got
+        assert got["vregs_loaded"] <= 2 * kv + (9 + 8) * hp * 128 * 2 \
+            // 4096, got
+        assert got["tpu.load"] <= 2 * got["vregs_loaded"], got
+        return
+    # the rows that fill a dot's 128 (32 in Olmo's budget), 8 at least
+    bq = got["tile_rows"]
+    assert bq == min(32, max(8, 1 << (128 * stored // heads).bit_length()
+                             - 1)), got
     # K's and V's copies of a block, at the three places a fetch starts:
     # the call's first, and each walk's next
     assert got["tpu.enqueue_dma"] == 3 * 2 * pages, got
-    # vregs (4 KB): K and V of a block, and q / the float32 output of one
-    # query row, its heads padded to 16
-    hp = -(-heads // 16) * 16
-    kv = 2 * pages * 16 * kv_heads * 128 * 2 // 4096
-    q_row, out_row = hp * 128 * 2 // 4096, hp * 128 * 4 // 4096
-    rows = 1 + got["tile_rows"]             # the row walk's + the tile's
-    # the output's tiles, nothing through scratch
-    assert got["tpu.store"] <= rows * out_row, got
-    # a block once a walk, each load half a vreg or more
-    assert got["vregs_loaded"] <= 2 * kv + rows * q_row, got
-    assert got["tpu.load"] <= 2 * got["vregs_loaded"], got
+    assert (got["f32_cols"], got["f32_rows_at_cols"]) == \
+        (tokens * stored, hp), got
+    # vregs (4 KB): K and V of a block; a row's heads of q and of the
+    # output, a run's groups — each the tile's rows x the group's heads in
+    # whole tiles of 16 —, stored under a select beside the tile's other
+    # runs' (read back)
+    run = stored * -(-bq * heads // stored // 16) * 16
+    # the output's tiles (half a vreg a store at most), nothing through
+    # scratch
+    assert got["tpu.store"] <= (hp + run) * 128 * 4 // 4096, got
+    # a block once a walk; the run's in whole vregs
+    assert got["strided_loads"] == got["strided_loads_whole"] == kv, got
+    assert got["vregs_loaded"] <= 2 * kv + \
+        (hp + 2 * run) * 128 * 2 // 4096, got
+    assert got["tpu.load"] <= kv + 2 * (got["vregs_loaded"] - kv), got
 
 
 @pytest.mark.parametrize("heads", [32, 64])
@@ -1118,26 +1164,39 @@ def test_paged_kernels_at_the_laguna_cells_shapes(mosaic, form, kind, heads):
 
 
 # the TILE form of the paged kernels (ops/pallas_paged.py:tile_rows): where a
-# call's rows may share a slot it carries the tiles' walks as a fourth
-# prefetched operand, one entry a tile
+# call's rows may share a slot it carries the tiles' RUNS as two prefetched
+# operands more, one entry a row (a run's rows and its longest row's length
+# at its first row)
 TILE_CASES = {
     # name: (rows, heads, kv heads, pages a table row, slots, tile)
-    "laguna-full-mixed": (320, 48, 8, 512, 64, 8),
+    "laguna-full-mixed": (320, 48, 8, 512, 64, 16),
     "decode-saturated-mixed": (128, 24, 2, 256, 64, 8),
 }
+
+
+def _row_operands(compiled, rows) -> int:
+    """The s32[rows] operands of the program's one Pallas call."""
+    import re
+    call, = re.findall(r"custom_call_target=\"tpu_custom_call\", "
+                       r"operand_layout_constraints=(.*?), frontend_attr",
+                       compiled.as_text())
+    return len(re.findall(r"s32\[%d\]" % rows, call))
 
 
 @pytest.mark.parametrize("case", list(TILE_CASES))
 def test_paged_kernel_tiles_at_the_cells_shapes(mosaic, case):
     """The Laguna full layer's mixed step (48 / 8 heads of 128, 320 rows,
-    512 pages a table row: a tile of 8 is a [384, 1024] float32 score
-    block) and decode-saturated's (24 / 2 heads, 128 rows): the program
-    with both walks compiles, in 40 and 16 tiles, under the one name."""
+    512 pages a table row: a run in a tile of 16 rows is 8 score blocks of
+    [96, 128] float32, a stored head's each) and decode-saturated's (24 / 2
+    heads, 128 rows): the program with both walks compiles, in 20 and 16
+    tiles, under the one name — q in a second time by stored head where a
+    run reads a block so, once where a few heads stay one dense operand."""
     import re
     from paddle_tpu.ops import pallas_paged
     R, H, h_kv, maxp, S, tile = TILE_CASES[case]
     bt = pallas_paged.block_tokens(16, h_kv, 128, 2, maxp)
-    assert pallas_paged.tile_rows(R, H, bt * h_kv, 128, bf16) == tile
+    assert pallas_paged.tile_rows(R, *pallas_paged.query_tile(
+        H, h_kv, (h_kv, 128), bt, bf16)) == tile
 
     def call(q, kp, vp, table, lengths, row_slot):
         return pallas_paged.paged_attention(q, kp, vp, table, lengths,
@@ -1147,15 +1206,18 @@ def test_paged_kernel_tiles_at_the_cells_shapes(mosaic, case):
     compiled = mosaic(call, ((R, H, 128), bf16), pool, pool,
                       ((S + 1, maxp), i32), ((R,), i32), ((R,), i32))
     assert kernel_names(compiled) == ["paged_attn.1"], kernel_names(compiled)
-    assert re.search(r"s32\[%d\]" % (R // tile), compiled.as_text()), \
-        "no operand of one entry a tile"
+    # lengths, row -> slot, and the runs' two
+    assert _row_operands(compiled, R) == 4
+    by_group = re.search(r"bf16\[%d,%d,%d,128\]" % (
+        R // tile, h_kv, tile * H // h_kv), compiled.as_text())
+    assert bool(by_group) == pallas_paged.split_heads(h_kv, 2) == \
+        (h_kv > 4), "q of a group's rows together, where runs split"
 
 
 def test_latent_kernel_tiles_at_gigachats_shape(mosaic):
     """The latent call's tile comes from its shapes too: 64 heads against
     rows of 640 lanes leave room for 4 query rows a tile (GigaChat's mixed
     step of 128 rows: 32 tiles), Kimi's 32 heads for 8."""
-    import re
     from paddle_tpu.ops import pallas_paged
     assert pallas_paged.tile_rows(128, 64, 128, 640, bf16) == 4
     assert pallas_paged.tile_rows(320, 32, 128, 640, bf16) == 8
@@ -1168,7 +1230,7 @@ def test_latent_kernel_tiles_at_gigachats_shape(mosaic):
                       ((16385, 16, 640), bf16), ((65, 256), i32),
                       ((128,), i32), ((128,), i32))
     assert kernel_names(compiled) == ["mla_paged_attn.1"]
-    assert re.search(r"s32\[32\]", compiled.as_text())
+    assert _row_operands(compiled, 128) == 4
 
 
 # xing4.0-29b-serve.long-prompt-48's own shapes (benchmark/configs/

@@ -1420,8 +1420,8 @@ def test_kv_read_counters_follow_each_step_kind(tiny_tr, kind):
     rows read: pos + 1 a decode row (1 for an empty slot), every body of
     a scanned dispatch at the position it had then, every packed row of a
     mixed or verify step (a padding row reads 1), and a whole block a
-    row — here the 64 tokens the table maps — or once a tile of rows where
-    the tile's rows are one slot's (`kv_shared_rows` of `kv_rows`)."""
+    row — here the 64 tokens the table maps — or once a run of one slot's
+    rows in a tile (`kv_shared_rows` of `kv_rows`)."""
     from paddle_tpu.ops.pallas_paged import tile_rows
     kw = {"scan": {"decode_steps": 4}, "spec": {"spec_k": 2}}.get(kind, {})
     eng = ServingEngine(tiny_tr.executor, tiny_tr.params, num_slots=2,
@@ -1431,6 +1431,10 @@ def test_kv_read_counters_follow_each_step_kind(tiny_tr, kind):
     eng.add_request(Request("a", np.tile(rng.integers(2, 31, 4), 5),
                             max_new=9))
     S, T, seen = 2, eng.max_step_tokens, set()
+    counted, count_kv = [], eng._count_kv
+    eng._count_kv = lambda lengths, row_slot=None, *a, **k: (
+        counted.append((lengths, row_slot)), count_kv(
+            lengths, row_slot, *a, **k))[1]
     while eng.queue or any(sl is not None for sl in eng.slots):
         pos = [None if sl is None else sl.pos for sl in eng.slots]
         before = (eng.kv_tokens_attended, eng.kv_tokens_fetched,
@@ -1452,12 +1456,23 @@ def test_kv_read_counters_follow_each_step_kind(tiny_tr, kind):
             assert (rows, shared) == (4 * S, 0)
         elif eng.n_mixed_steps > before[3] or eng.n_spec_steps > before[4]:
             seen.add("spec" if eng.n_spec_steps > before[4] else "mixed")
-            # the prompt's run and the padding behind it fill whole tiles,
-            # but for the call's last rows
+            # a block a row alone, and ONE a run of one slot's rows in a
+            # tile (the prompt's, the padding's behind it): a plain count
+            # over the rows the step really packed
             bq = tile_rows(T, *eng._kv_tile)
-            assert rows == T and 0 < shared <= T
-            assert T <= att <= T * 64
-            assert fetched == (T - shared - -shared // bq) * 64
+            lengths, slots = counted[-1]
+            assert rows == T == lengths.size and T <= att <= T * 64
+            walks = in_runs = 0
+            for t in range(0, T, bq):
+                tile = list(slots[t:t + bq])
+                tile += tile[-1:] * (bq - len(tile))    # the call's padding
+                starts = [i for i in range(bq)
+                          if i == 0 or tile[i] != tile[i - 1]]
+                walks += len(starts)
+                in_runs += sum(min(j, T - t) - i for i, j in zip(
+                    starts, starts[1:] + [bq]) if j - i > 1)
+            assert (fetched, shared) == (walks * 64, in_runs)
+            assert 0 < shared <= T and walks < T
         else:
             seen.add("decode")
             want = sum(1 if p is None else p + 1 for p in pos)

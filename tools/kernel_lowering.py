@@ -11,6 +11,7 @@ buffer cost 512 one-sublane loads and 512 strided stores through
     python tools/kernel_lowering.py paged_attn rows=128        # its mixed step
     python tools/kernel_lowering.py paged_attn heads=48,kv_heads=8,rows=320,max_pages=512,pages=32769  # Laguna's full layer
     python tools/kernel_lowering.py paged_attn kv_heads=8,head_dim=64,heads=32,rows=256
+    python tools/kernel_lowering.py paged_attn rows=280,heads=30,kv_heads=30,max_pages=576,pages=13825  # Olmo-Hybrid's mixed step
     python tools/kernel_lowering.py mla_paged_attn             # GigaChat's decode
     python tools/kernel_lowering.py kda_seg                    # Kimi's chunk rows
     python tools/kernel_lowering.py kda_seg heads=64           # Solar-Open2's
@@ -77,7 +78,8 @@ def _build(kernel: str, s: dict):
 
         def fn(q, kp, vp, table, lengths, row_slot):
             return pallas_paged.paged_attention(q, kp, vp, table, lengths,
-                                                row_slot=row_slot)
+                                                row_slot=row_slot,
+                                                kv_heads=s["kv_heads"])
         return fn, [((R, s["heads"], s["head_dim"]), bf16), pool, pool] + tail
 
     def fn(q, pool, table, lengths, row_slot):
@@ -126,6 +128,23 @@ def lowering_counts(kernel: str, shape: dict, dump_dir: str) -> dict:
             r"tpu\.(?:\w+_)?load\b[^\n]*?sublanes \[([^\]]*)\]", text)) / 8
     if kernel == "kda_seg":
         return out
+    # a head's rows of a block are read by sublane-STRIDED loads (one stored
+    # head at a time: `split_heads`): how many, and how many of them fill
+    # their vreg — a whole uint32 sublane row a sublane
+    strided = re.findall(
+        r"tpu\.load\b[^\n]*?sublanes \[([^\]]*)\] sublane_stride "
+        r"(?!1 )\d+ : memref<[^>]*xi32", text)
+    out["strided_loads"] = len(strided)
+    out["strided_loads_whole"] = sum("false" not in m for m in strided)
+    # the widest float32 value of the program before the layout pass — a
+    # ROW's scores against a block as one dense operand, tokens x stored
+    # heads wide — and the most rows a value that wide has: a query row's
+    # heads, never a tile's (a run of rows scores a stored head at a time,
+    # a block's tokens wide)
+    original = glob.glob(os.path.join(dump_dir, f"*{kernel}-original.txt"))
+    f32 = [(int(c), int(r)) for r, c in re.findall(
+        r"vector<(\d+)x(\d+)xf32>", open(original[0]).read())]
+    out["f32_cols"], out["f32_rows_at_cols"] = max(f32)
     # pages a block: what the loads are held against (4 vregs a page at most)
     row = pallas_paged.kv_row_shape(shape["kv_heads"], shape["head_dim"]) \
         if kernel == "paged_attn" else (1, shape["width"])
@@ -135,7 +154,9 @@ def lowering_counts(kernel: str, shape: dict, dump_dir: str) -> dict:
     # rows a grid step holds: the program has a walk one row at a time and
     # a walk of the whole tile, each with its own loads of a block
     out["tile_rows"] = pallas_paged.tile_rows(
-        shape["rows"], shape["heads"], block * row[0], row[1], "bfloat16")
+        shape["rows"], *pallas_paged.query_tile(
+            shape["heads"], shape.get("kv_heads", 1), row, block,
+            "bfloat16"))
     return out
 
 

@@ -159,8 +159,14 @@ def paged_cases():
     [P, 16, 2, 128] (a page's copy crosses from the pool's (2,128) tiles to
     the kernel's dense operand rows: only the chip can say it is exact),
     LFM2's packed [P, 16, 4, 128], a table shorter than a block,
-    Jamba's lone head stored two tokens a row, [P, 8, 2, 128], and
-    Solar-Open2's 64 query heads over 8 KV heads of 128, [P, 16, 8, 128]."""
+    Jamba's lone head stored two tokens a row, [P, 8, 2, 128],
+    Solar-Open2's 64 query heads over 8 KV heads of 128, [P, 16, 8, 128],
+    Laguna's 48 over 8 (groups of 6) with its window layers' call, and
+    Olmo-Hybrid's group size ONE, 30 heads on 30 stored as [P, 16, 32,
+    128].  Wherever a row holds several heads a RUN of rows (the mixed
+    cases' chunk) reads them one at a time through strided uint32 loads of
+    the block in VMEM (`split_heads`): only the chip can say those are
+    exact."""
     from paddle_tpu.ops.attention import (paged_attention_step,
                                           ragged_paged_attention_step)
     from paddle_tpu.ops.pallas_paged import kv_page_shape, kv_row_shape
@@ -168,7 +174,7 @@ def paged_cases():
     ps = 16
     dt, tol = jnp.bfloat16, 3e-2
 
-    def build(S, ctx, H, h_kv, D, row, tag, page=None):
+    def build(S, ctx, H, h_kv, D, row, tag, page=None, **kw):
         maxp = ctx // ps
         page = page or (ps,) + row
 
@@ -196,7 +202,7 @@ def paged_cases():
 
             def step(use_kernel):
                 return jax.jit(lambda *a: paged_attention_step(
-                    *a, use_kernel=use_kernel)[0])(
+                    *a, use_kernel=use_kernel, **kw)[0])(
                         q, k, v, kp, vp, table, pos)
 
             return {"out": _close(step(True), _oracle(lambda: step(False)),
@@ -221,7 +227,7 @@ def paged_cases():
 
             def step(use_kernel):
                 return jax.jit(lambda *a: ragged_paged_attention_step(
-                    *a, use_kernel=use_kernel)[0])(
+                    *a, use_kernel=use_kernel, **kw)[0])(
                         q, k, v, kp, vp, table, jnp.asarray(row_slot),
                         jnp.asarray(row_pos))
 
@@ -243,7 +249,15 @@ def paged_cases():
                     "_lone_head_paired", page=kv_page_shape(ps, 1, 128, 2))
             # Solar-Open2's 8 groups of 8: 64 query rows a slot
             + build(32, 2048, 64, 8, 128, kv_row_shape(8, 128),
-                    "_64q_over_8kv"))
+                    "_64q_over_8kv")
+            # Laguna's full layers (groups of 6) and its window layers' call
+            + build(32, 2048, 48, 8, 128, kv_row_shape(8, 128),
+                    "_48q_over_8kv")
+            + build(32, 2048, 64, 8, 128, kv_row_shape(8, 128),
+                    "_64q_over_8kv_window512", window=512)
+            # Olmo-Hybrid's group size one: 30 heads stored as 32
+            + build(24, 2048, 30, 30, 128, kv_row_shape(30, 128),
+                    "_30q_on_30kv"))
 
 
 def kda_cases():
