@@ -93,8 +93,8 @@ class _RoutedReq:
 
     __slots__ = ("conn", "cid", "msg", "grid", "rid", "stream", "streamed",
                  "retries", "t_submit", "trace_id", "span_id",
-                 "client_parent", "t0", "t_last_tok", "burst_left",
-                 "burst_share", "phase", "decode_rid", "disagg_pages")
+                 "client_parent", "t0", "t_last_tok", "phase", "decode_rid",
+                 "disagg_pages")
 
     def __init__(self, conn, cid, msg, grid):
         self.conn = conn
@@ -114,16 +114,7 @@ class _RoutedReq:
         self.decode_rid = None         # planned decode replica
         self.disagg_pages = 0          # pages shipped for this request
         self.t_submit = time.monotonic()
-        # burst-aware relay inter-token latency (multi-step decode): a
-        # replica running decode_steps=k relays ≤k token frames back to
-        # back, each stamped with `burst` = fresh tokens remaining in its
-        # burst including itself — the router divides the inter-burst
-        # arrival gap by the burst size so relay ITL percentiles stay
-        # comparable across decode_steps settings (one arrival is k
-        # tokens of progress, not one)
         self.t_last_tok = 0.0          # last relayed-token arrival
-        self.burst_left = 0            # burst tokens still to charge
-        self.burst_share = 0.0         # per-token share of the burst gap
         # distributed-trace identity, stamped at ingress: one trace_id per
         # request (adopted from the client's frame when it sent one), and
         # the router's ingress span id — the `parent` every router-side
@@ -319,8 +310,8 @@ class FleetRouter:
         self.flight = get_flight_recorder()
         self.flight.enabled = True
         # router-side latency stats (utils/stat.py): today one stat —
-        # relay_token_latency, the burst-honest inter-token gap clients
-        # actually observed at the router tier
+        # relay_token_latency, the inter-token gap clients actually
+        # observed at the router tier
         self.stats = StatSet("fleet_router")
         self._routes: dict[str, _RoutedReq] = {}
         self._seq = 0
@@ -806,23 +797,14 @@ class FleetRouter:
             # it forwards per-token — but only st.stream clients receive)
             if st.stream:
                 st.streamed += 1
-                # relay ITL, burst-honest: charge each token of a ≤k
-                # burst an equal share of the inter-burst gap.  Kept to
+                # relay ITL: the gap between relayed tokens.  Kept to
                 # arithmetic + one Stat.add (~100ns lock) — per-token
                 # loop-thread work beyond that measurably costs tok/s
                 # (see the tracer note below).
                 now = time.monotonic()
                 if st.streamed > 1:
-                    if st.burst_left > 0:
-                        st.burst_left -= 1
-                        self.stats.get("relay_token_latency").add(
-                            st.burst_share)
-                    else:
-                        b = max(1, int(msg.get("burst") or 1))
-                        st.burst_share = (now - st.t_last_tok) / b
-                        st.burst_left = b - 1
-                        self.stats.get("relay_token_latency").add(
-                            st.burst_share)
+                    self.stats.get("relay_token_latency").add(
+                        now - st.t_last_tok)
                 st.t_last_tok = now
                 if self.tracer.enabled and st.streamed == 1:
                     # FIRST-token relay only: the router-side TTFT stitch
@@ -1367,10 +1349,7 @@ class FleetRouter:
             "kv_push_failures": self._m_kv_push_fail.value(),
             "kv_fallbacks": self._m_kv_fallbacks.value(),
             "kv_pages_shipped": self._m_kv_pages.value(),
-            # burst-honest relay inter-token latency (ms): one scanned
-            # k-token burst is k tokens of progress, each charged an
-            # equal share of the inter-burst gap — comparable across
-            # replicas running different decode_steps
+            # relay inter-token latency (ms)
             "relay_itl_ms": {k: round(v * 1e3, 3) for k, v in
                              self.stats.percentiles(
                                  "relay_token_latency",

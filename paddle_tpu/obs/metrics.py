@@ -272,12 +272,6 @@ CATALOG: dict[str, str] = {
         "computed by every layer, committed by none",
     "serving_mixed_steps_total":
         "compiled steps that carried at least one prefill chunk row",
-    "serving_scan_steps_total":
-        "decode bodies run inside scanned multi-step dispatches "
-        "(decode_steps per flush; see serving_scan_flushes_total)",
-    "serving_scan_flushes_total":
-        "scanned multi-step dispatches (host boundaries) — steps/flushes "
-        "reads back the effective decode_steps",
     "serving_decode_gap_ms":
         "pump-step gap decoding slots saw (ms between consecutive steps "
         "advancing decode rows — HOL-blocking prefill shows here)",
@@ -299,8 +293,7 @@ CATALOG: dict[str, str] = {
     "fleet_requests_accepted_total": "generate requests the router placed",
     "fleet_relay_latency_seconds":
         "router-tier relay latency quantiles (labels: stat, quantile; "
-        "relay_token_latency = burst-honest inter-token gap — a scanned "
-        "k-token burst charges each token gap/k)",
+        "relay_token_latency = inter-token gap)",
     "fleet_relay_latency_count":
         "samples recorded per router relay stat (label: stat)",
     "fleet_placements_total":

@@ -706,12 +706,11 @@ def paged_attention_step(
 
     SCAN-BODY SAFE: the write+read core is pure in its operands (no
     host callback, no per-call state — including the shard_map TP path,
-    whose collective set is fixed per call), so the engine's multi-step
-    decode (`decode_steps=k`) may trace it inside a `lax.scan` body
-    with `pos`/`page_table`-addressed writes riding the scan carry —
-    body i+1 reads exactly the pool state body i's scatter produced,
-    and the body appears ONCE in the lowered HLO
-    (tools/hlo_shard_check.py's "scan" step is the proof).
+    whose collective set is fixed per call), so a caller may trace it
+    inside a `lax.scan` body with `pos`/`page_table`-addressed writes
+    riding the scan carry — body i+1 reads exactly the pool state body
+    i's scatter produced (tests/test_chunked_prefill.py:
+    test_paged_kernel_inside_scan_matches_fallback).
     """
     S, Tn, H, D = q_new.shape
     assert Tn == 1, "paged decode feeds exactly one new token per slot"

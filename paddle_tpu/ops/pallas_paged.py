@@ -140,15 +140,13 @@ invariant).  The kernel itself needs no collective and no change: page
 tables and lengths arrive replicated, every DMA stays on-chip, and the
 head padding, the block size and the tile below follow the LOCAL counts.
 
-MULTI-STEP decode (the engine's `--decode-steps K` scanned dispatch):
-the kernel is scan-body safe — pure in its operands with no host
-callbacks, no side channels, and no per-call state, so `lax.scan`
+SCAN-BODY SAFE: the kernel is pure in its operands with no host
+callbacks, no side channels, and no per-call state, so a `lax.scan`
 tracing it K times produces ONE kernel instance in the loop body (the
 body appears once in the HLO).  Positions/lengths arriving as scan
 carries instead of host-staged arrays change nothing here: each body's
-DMA addressing reads whatever `table`/`lengths` values the carry holds,
-and under shard_map the same holds per shard (hlo_shard_check lowers
-the scanned program and proves the collective set matches one body).
+DMA addressing reads whatever `table`/`lengths` values the carry holds
+(tests/test_chunked_prefill.py holds it to that).
 """
 
 from __future__ import annotations
@@ -372,17 +370,14 @@ def walked_blocks(lengths, row_slot, bq: int, bt: int):
     a run of one slot's rows in a tile folds each block once, to its
     longest row; a row alone walks its own, a dead one a block — the rows
     `_call` pads the last tile with among them.  `row_slot` None: the rows
-    are the slots, none shares, and `lengths` may hold several calls' rows,
-    [calls, rows]."""
+    are the slots and none shares."""
     import numpy as np
     lengths = np.asarray(lengths)
     alone = int(np.maximum(-(-lengths // bt), 1).sum())
     if row_slot is None:
-        rows = lengths.shape[-1]
-        return alone + lengths.size // rows * (-rows % bq), 0
+        return alone + -lengths.size % bq, 0
     if bq == 1:
         return alone, 0
-    lengths = lengths.reshape(-1)
     pad = -lengths.size % bq            # as `_call` pads: whole tiles
     rows, longest, shared = _runs(
         np, np.pad(lengths, (0, pad)),

@@ -242,7 +242,7 @@ class _Slot:
 _FLIGHT_COUNTERS = {
     kind: (counter_key("serving_step_flight_seconds_total", kind=kind),
            counter_key("serving_steps_landed_total", kind=kind))
-    for kind in ("decode", "mixed", "scan", "spec")}
+    for kind in ("decode", "mixed", "spec")}
 
 
 def _is_abstract(params: dict) -> bool:
@@ -297,7 +297,6 @@ class ServingEngine:
                  max_step_tokens: Optional[int] = None,
                  spec_k: int = 0, drafter=None,
                  spec_dynamic: bool = False,
-                 decode_steps: int = 1,
                  mesh=None, tracer=None):
         self.executor = executor
         self.input_name, self.logits_name = _resolve_io_names(
@@ -589,7 +588,6 @@ class ServingEngine:
         self._d_run = None
         self._d_table = self._d_pos = self._d_toks = self._d_gen = None
         self._d_keys = self._d_temp = self._d_topk = self._d_topp = None
-        self._d_eos = self._d_maxnew = None
         # every engine jit reports to the compile watcher (obs/
         # compile_watch.py): the decode step must stay at ONE signature,
         # the mixed step at one per max_step_tokens value
@@ -640,30 +638,6 @@ class ServingEngine:
                                     # truncated a chain (reconciliation)
         self.n_draft_steps = 0      # draft passes that proposed anything
         self.set_speculation(spec_k, drafter, dynamic=spec_dynamic)
-        # MULTI-STEP DECODE (the scanned step): when every live slot is in
-        # pure-decode mode, step() runs ONE jitted lax.scan of
-        # `decode_steps` identical per-step bodies over the donated
-        # EngineState — pos/gen/toks/KV writes advance on device for up to
-        # k tokens per dispatch, eos/max_new enforced by an on-device run
-        # mask INSIDE the scan (a finished slot's later iterations become
-        # no-ops, mirroring lm_generate's early-exit chunks), and the host
-        # unpacks a [k, S] token block at the boundary where admission,
-        # streaming, cancel/deadline sweeps, and preemption still happen.
-        # Compiled signatures: ONE scanned program per (S, k) — k is a
-        # static argument of one lazily-built jit, alongside the k=1 step
-        # (which mixed/spec steps and page-starved windows fall back to).
-        # Tokens are bit-identical to k=1: the body IS _decode_impl and
-        # the device mask mirrors _bank_token's retirement rule exactly.
-        self._scan_step = None
-        self.decode_steps = 1
-        self.n_scan_steps = 0       # scan body iterations run (k per flush)
-        self.n_scan_flushes = 0     # scanned dispatches (boundaries seen)
-        # tokens banked for the slot currently being unpacked arrive in a
-        # burst of cur_burst (> 1 only inside a scan flush): on_token
-        # consumers divide inter-arrival gaps by it so inter-token latency
-        # stays honest across decode_steps settings (serving/server.py)
-        self.cur_burst = 1
-        self.set_decode_steps(decode_steps)
         # token-budget observability: per-step scheduled-token histogram
         # and the pump-step gap decoding slots actually saw (ms) — the
         # HOL-blocking number chunking exists to bound.  Standalone
@@ -928,8 +902,6 @@ class ServingEngine:
             temp = np.zeros(S, np.float32)
             topk = np.zeros(S, np.int32)
             topp = np.zeros(S, np.float32)
-            eos = np.full(S, -1, np.int32)
-            maxnew = np.zeros(S, np.int32)
             for s, sl in enumerate(self.slots):
                 if sl is None:
                     continue
@@ -938,8 +910,6 @@ class ServingEngine:
                 temp[s] = sl.req.temperature
                 topk[s] = sl.req.top_k
                 topp[s] = sl.req.top_p
-                eos[s] = sl.req.eos_id
-                maxnew[s] = sl.req.max_new
             self._d_pos = self._stage(pos)
             if self._d_toks is None:
                 self._d_toks = self._stage(np.array(
@@ -950,10 +920,6 @@ class ServingEngine:
             self._d_temp = self._stage(temp)
             self._d_topk = self._stage(topk)
             self._d_topp = self._stage(topp)
-            # the scanned step's on-device retirement operands: eos id and
-            # max_new per slot — same lifecycle cadence as the knobs above
-            self._d_eos = self._stage(eos)
-            self._d_maxnew = self._stage(maxnew)
             self._slots_dirty = False
 
     def _sync_run_mask(self, runnable) -> None:
@@ -1007,13 +973,12 @@ class ServingEngine:
 
     def _compiled_step(self, kind: str, **attrs):
         """The span of ONE compiled step — `pt.step.decode` / `.mixed` /
-        `.scan` / `.spec`, the kind the scheduler chose in the name.  A
-        decode or mixed step's covers its LAUNCH: the call into the
-        compiled program and the bookkeeping behind it (its tokens are
-        read under a later `pt.step.readback`, see `_land`); a scanned or
-        verify step's still runs to the host token read.  Closes the
-        step's `pt.step.plan` first: planning ends where the dispatch
-        begins."""
+        `.spec`, the kind the scheduler chose in the name.  A decode or
+        mixed step's covers its LAUNCH: the call into the compiled program
+        and the bookkeeping behind it (its tokens are read under a later
+        `pt.step.readback`, see `_land`); a verify step's still runs to
+        the host token read.  Closes the step's `pt.step.plan` first:
+        planning ends where the dispatch begins."""
         self._end_plan()
         return self._phase(kind, **attrs)
 
@@ -1292,8 +1257,7 @@ class ServingEngine:
         every step() at `lookahead` 0.  Whatever reads or moves what a
         land moves calls this first — cancel and the deadline sweep (the
         abort reports every computed token), preemption, the speculative
-        and scanned steps (the drafter reads tokens; the scan's window
-        starts at the banked cursor), checkpoint_state, the kv transfer
+        step (the drafter reads tokens), checkpoint_state, the kv transfer
         plane, every idle-engine knob, and the pump when it stops.  Inside
         a land it does nothing: `on_finish` may export a prefix there, which
         reads only donated pages, and those no row in flight writes."""
@@ -1373,33 +1337,16 @@ class ServingEngine:
             # speculative mode: the drafter proposes per decoding slot
             # (dynamic k may choose 0 for cold/low-accept slots); any
             # drafts (or chunk rows) route through the verify step — a
-            # zero-draft pure-decode step keeps the cheap [S, 1] or
-            # scanned signature, so an unhelpful drafter costs nothing
-            # steady-state beyond the draft pass itself
+            # zero-draft pure-decode step keeps the cheap [S, 1]
+            # signature, so an unhelpful drafter costs nothing steady-state
+            # beyond the draft pass itself
             drafts = self._propose_drafts(runnable)
             if drafts or filling:
                 return self._run_spec_step(live, runnable, filling,
                                            drafts)
         if filling:
             # (a speculative engine's chunks rode the verify step above)
-            # mixed prefill/decode load drops to the mixed step PER
-            # FLUSH WINDOW — a mid-flight admission is never stalled
-            # behind a k-step scan (the scan gate below is only ever
-            # reached with no prefill in flight)
             launched = self._launch_mixed(going, runnable, filling, cur)
-        elif self.decode_steps > 1 \
-                and self._scan_window_ok(runnable, self.decode_steps):
-            # pure-decode steady state with multi-step on: ONE scanned
-            # dispatch advances every runnable slot up to k tokens.  Any
-            # slot that cannot secure pages for its whole window drops
-            # THIS dispatch back to the k=1 step below (progress without
-            # livelock); mixed/spec steps never scan — the engine returns
-            # to the scanned path once it is pure-decode again.  This is
-            # how speculation and multi-step COMPOSE: the drafter already
-            # had its say at this boundary (above) and proposed nothing,
-            # so the window is draft-free and the scan is the best
-            # remaining dispatch.
-            return self._run_scan_step(live, runnable, self.decode_steps)
         else:
             launched = self._launch_decode(going, runnable, cur)
         # launch N+1, THEN land N: the device has its next step queued
@@ -1407,10 +1354,9 @@ class ServingEngine:
         due, self._pending = self._pending, launched
         if due is not None:
             self._land(due)
-        if self.lookahead == 0 or self.spec_k > 0 or self.decode_steps > 1:
-            # a direct caller looks at what step() banked; the drafter
-            # reads banked tokens and the scan starts from banked cursors:
-            # such an engine keeps nothing in flight
+        if self.lookahead == 0 or self.spec_k > 0:
+            # a direct caller looks at what step() banked and the drafter
+            # reads banked tokens: such an engine keeps nothing in flight
             self.settle()
         return True
 
@@ -1575,12 +1521,12 @@ class ServingEngine:
         (None: the rows are the slots, and no two share a walk); `also` the
         step's other process-wide counts, added under the same lock;
         `head_rows` the rows that reach the vocabulary head (None: every
-        row samples — a decode step, a scanned window's bodies)."""
+        row samples — a decode step)."""
         from paddle_tpu.ops.pallas_paged import tile_rows, walked_blocks
-        # the tile of one call's rows (a scanned window's bodies are a
-        # call each): a decode call's last tile is padded with dead rows
+        # the tile of the call's rows: a decode call's last tile is padded
+        # with dead rows
         bq = 1 if self._kv_tile is None else \
-            tile_rows(lengths.shape[-1], *self._kv_tile)
+            tile_rows(lengths.size, *self._kv_tile)
         blocks, shared = walked_blocks(lengths, row_slot, bq, self._kv_block)
         attended, fetched = int(lengths.sum()), blocks * self._kv_block
         self.kv_tokens_attended += attended
@@ -1597,97 +1543,13 @@ class ServingEngine:
                   "serving_kv_tokens_fetched_total": fetched, **(also or {})}
         if self._mhc_writes:
             # every row of the step, padding included, passes each
-            # sublayer's stream pass; a scanned dispatch runs its bodies'
+            # sublayer's stream pass
             writes = len(self._mhc_writes)
-            calls = writes * (lengths.shape[0] if lengths.ndim == 2 else 1)
             self.n_mhc_rows += writes * lengths.size
-            self.n_mhc_calls += calls
+            self.n_mhc_calls += writes
             counts.update(serving_mhc_rows_total=writes * lengths.size,
-                          serving_mhc_calls_total=calls)
+                          serving_mhc_calls_total=writes)
         process_counters().add_many(counts)
-
-    def _scan_window_ok(self, runnable, k: int) -> bool:
-        """Page precondition for ONE k-step scanned dispatch: every
-        runnable slot must hold pages for its whole window — min(k,
-        tokens it can still emit) positions from pos (a slot that hits
-        eos earlier simply stops writing; a retired slot's one frozen
-        recompute lands at most one position past its last token, still
-        inside the window).  Any shortfall reports False and the caller
-        falls back to the k=1 step for this dispatch — the +1 page every
-        runnable slot already secured guarantees progress, and the next
-        boundary retries after retires/eviction free pages."""
-        ok = True
-        for s in runnable:
-            sl = self.slots[s]
-            need = min(k, sl.req.max_new - sl.gen)
-            if not self.kv.try_grow(s, sl.pos + need):
-                ok = False
-        return ok
-
-    def _run_scan_step(self, live, runnable, k: int) -> bool:
-        """ONE scanned dispatch: k identical decode bodies advance every
-        runnable slot on device (pos/gen/toks/KV writes all inside the
-        scan), the host unpacking a [k, S] token block at the boundary.
-        Per-slot banking cuts each slot's column at its own eos/max_new —
-        the exact retirement the device run mask applied — so host
-        mirrors re-converge with device state without any readback."""
-        S = len(self.slots)
-        psize = self.kv.page_size
-        for s in runnable:
-            sl = self.slots[s]
-            # every page the window can touch must be private (the k=1
-            # tripwire, widened to the window span)
-            last = sl.pos + min(k, sl.req.max_new - sl.gen) - 1
-            for j in range(sl.pos // psize, last // psize + 1):
-                assert self.kv.page_writable(int(self.kv.table[s, j])), \
-                    f"slot {s} scan window would write a shared page"
-        self._sync_run_mask(runnable)
-        self._sync_device_state()
-        scan_step = self._scan_step_fn()
-        with self._compiled_step("scan", live=len(live), k=k,
-                                 step=self.n_decode_steps + 1) as launch:
-            with self._phase("dispatch"):
-                st, blk = scan_step(
-                    k, self._step_params, self._build_state(), self._d_run,
-                    self._d_eos, self._d_maxnew)
-            self._unpack_state(st)
-            self.n_decode_steps += 1
-            self.n_scan_flushes += 1
-            self.n_scan_steps += k
-            self.occupancy_sum += len(live) / S
-            base = self._slot_lengths()
-            ran = np.zeros(S, np.int64)     # bodies each slot advanced in
-            step = self.n_decode_steps
-            with self._phase("readback", step=step, kind="scan"):
-                blk = self._count_moe(np.asarray(blk), S,
-                                      "scan")              # [k, S] sync
-            self._landed("scan", step, launch.t0, len(runnable))
-            self._note_step_metrics(len(runnable), decoded=True)
-        # per-flush, never per-token: one boundary event each k tokens
-        self.flight.record("scan_flush", k=k, slots=len(runnable))
-        with self._phase("emit", n=len(runnable), step=step, kind="scan"):
-            for s in runnable:
-                sl = self.slots[s]
-                burst = []
-                for i in range(k):
-                    t = int(blk[i, s])
-                    burst.append(t)
-                    if t == sl.req.eos_id or sl.gen + len(burst) >= \
-                            sl.req.max_new:
-                        break            # device run mask froze here too
-                ran[s] = len(burst)
-                self.cur_burst = len(burst)
-                try:
-                    for t in burst:
-                        self._bank_token(s, t)
-                finally:
-                    self.cur_burst = 1
-        # body i reads a slot at pos + min(i, bodies it ran): a retired or
-        # paused slot recomputes at its frozen position
-        self._count_kv(base[None, :] + np.minimum(
-            np.arange(k)[:, None], ran[None, :]))
-        self._count_recurrent_tokens(ran.sum(), 0)
-        return True
 
     def _launch_mixed(self, going, runnable, filling, cur) -> _Pending:
         """Launch ONE mixed prefill/decode dispatch: pack each runnable
@@ -1907,14 +1769,13 @@ class ServingEngine:
 
     def _propose_drafts(self, runnable) -> dict:
         """Ask the drafter for lookahead tokens per decoding slot (host
-        side, between steps — the scan/flush boundary).  The per-slot
-        cap is exact-by-construction: a chain emits at most k+1 tokens,
-        so k never exceeds the tokens the request may still emit
-        (max_new - gen - 1), and the deepest draft write (pos + k) never
+        side, between steps).  The per-slot cap is exact-by-construction:
+        a chain emits at most k+1 tokens, so k never exceeds the tokens
+        the request may still emit (max_new - gen - 1), and the deepest
+        draft write (pos + k) never
         exceeds slot capacity — the same `p + max_new - 2` bound
         validate() already guarantees pages for.  Empty proposals drop
-        out entirely (their slot rides the plain decode row or the
-        scanned window).
+        out entirely (their slot rides the plain decode row).
 
         Drafters exposing `propose_batch` (ModelDrafter) get ALL slots'
         windowed contexts in ONE call — one jitted [S, W] -> [S, spec_k]
@@ -2282,7 +2143,7 @@ class ServingEngine:
         batched scatter, re-mark cached, promote the nodes.  False = full
         rollback happened and the caller admits cold.  Page counts here
         ride a bucketed jit at the admission boundary — the decode/mixed/
-        spec/scan step signatures never move (the compile-watch oracle)."""
+        spec step signatures never move (the compile-watch oracle)."""
         kv, tree = self.kv, self.prefix
         if not all(kv.host_entry_live(nd.host_id) for nd in host_tail):
             # a dead generation (kv.reset without tree.clear — the
@@ -2614,22 +2475,6 @@ class ServingEngine:
         return getattr(self.drafter, "kind", None) \
             if self.drafter is not None else None
 
-    def set_decode_steps(self, decode_steps: int) -> None:
-        """Configure multi-step decode (idle engine only — a live slot's
-        host mirrors must be at a scan boundary).  `decode_steps=1`
-        disables; k > 1 runs up to k decode bodies per dispatch inside ONE
-        jitted lax.scan whenever the engine is pure-decode.  Emitted
-        tokens are IDENTICAL either way; only dispatches-per-token (and
-        the streaming burst size) change.  Each distinct k is ONE scanned
-        signature per slot count — hold it fixed in production."""
-        self._assert_idle("set_decode_steps")
-        decode_steps = int(decode_steps)
-        if decode_steps < 1:
-            raise ValueError(
-                f"decode_steps must be >= 1 (1 = multi-step off), got "
-                f"{decode_steps}")
-        self.decode_steps = decode_steps
-
     @property
     def spec_accept_rate(self) -> float:
         """Accepted / drafted over the engine lifetime (0.0 before any
@@ -2685,6 +2530,23 @@ class ServingEngine:
                 min(leaves, key=lambda n: n.last_use))
 
     # -- serving-state checkpoint/restore (fleet-migration primitive) ------
+    #: the scheduling counters a snapshot carries; restore_state sets these
+    #: and no other key an older snapshot's "counters" may hold
+    _SNAPSHOT_COUNTERS = (
+        "_admit_seq", "n_decode_steps", "n_preemptions",
+        "n_cancelled", "n_expired", "tokens_generated",
+        "occupancy_sum", "kv_tokens_attended", "kv_tokens_fetched",
+        "n_kv_rows", "n_kv_shared_rows", "n_head_rows",
+        "n_prefix_hits", "n_prefix_misses",
+        "prefill_tokens_saved", "n_restore_hits",
+        "restore_tokens_saved", "n_prefill_chunks",
+        "n_chunk_rows", "n_chunk_extra_rows", "n_step_pad_rows",
+        "n_window_pages_recycled", "n_window_rows", "n_window_steps",
+        "n_mhc_rows", "n_mhc_calls",
+        "n_mixed_steps", "n_spec_steps", "n_spec_chains",
+        "n_spec_drafted", "n_spec_accepted", "n_spec_tokens",
+        "n_draft_steps")
+
     def checkpoint_state(self) -> dict:
         """Freeze the ENTIRE serving state MID-FLIGHT — device pytree
         (pools as host copies), allocator, slots, queue, prefix index,
@@ -2695,15 +2557,7 @@ class ServingEngine:
         preemption order, free-list order and page placement all survive.
         Call between steps on the step()-driving thread (the pump), like
         every other scheduler access.  This is the checkpoint/restore +
-        live-replica-migration unit the EngineState refactor unlocks.
-
-        Multi-step decode needs no special handling: a scanned dispatch
-        is atomic INSIDE step(), so between steps the engine is always at
-        a scan boundary — host mirrors converged, no mid-window state
-        exists to freeze.  `decode_steps` is deliberately NOT part of the
-        config-match dict: it is an A/B dispatch knob, and a snapshot
-        taken under k restores bit-exactly onto an engine running any
-        other k (tests/test_multi_step.py proves it)."""
+        live-replica-migration unit the EngineState refactor unlocks."""
 
         def req_snap(r: Request) -> dict:
             return {"req_id": r.req_id, "prompt_ids": r.prompt_ids.copy(),
@@ -2779,20 +2633,8 @@ class ServingEngine:
                       for sl in self.slots],
             "queue": [req_snap(r) for r in self.queue],
             "prefix": prefix,
-            "counters": {k: getattr(self, k) for k in (
-                "_admit_seq", "n_decode_steps", "n_preemptions",
-                "n_cancelled", "n_expired", "tokens_generated",
-                "occupancy_sum", "kv_tokens_attended", "kv_tokens_fetched",
-                "n_kv_rows", "n_kv_shared_rows", "n_head_rows",
-                "n_prefix_hits", "n_prefix_misses",
-                "prefill_tokens_saved", "n_restore_hits",
-                "restore_tokens_saved", "n_prefill_chunks",
-                "n_chunk_rows", "n_chunk_extra_rows", "n_step_pad_rows",
-                "n_window_pages_recycled", "n_window_rows", "n_window_steps",
-                "n_mhc_rows", "n_mhc_calls",
-                "n_mixed_steps", "n_spec_steps", "n_spec_chains",
-                "n_spec_drafted", "n_spec_accepted", "n_spec_tokens",
-                "n_scan_steps", "n_scan_flushes", "n_draft_steps")},
+            "counters": {k: getattr(self, k)
+                         for k in self._SNAPSHOT_COUNTERS},
             "results": {k: np.asarray(v).copy()
                         for k, v in self.results.items()},
             "finish_reasons": dict(self.finish_reasons),
@@ -2910,8 +2752,9 @@ class ServingEngine:
                 self.prefix._clock = snap["prefix"]["clock"]
                 self.prefix.n_evictions = snap["prefix"]["n_evictions"]
                 self.prefix.rebuild()
-        for k, v in snap["counters"].items():
-            setattr(self, k, v)
+        for k in self._SNAPSHOT_COUNTERS:
+            if k in snap["counters"]:
+                setattr(self, k, snap["counters"][k])
         self.results = {k: np.asarray(v).copy()
                         for k, v in snap["results"].items()}
         self.finish_reasons = dict(snap["finish_reasons"])
@@ -3035,45 +2878,6 @@ class ServingEngine:
                              topk=st.topk, topp=st.topp)
         return new_st, nxt
 
-    def _scan_impl(self, k: int, params, st: EngineState, run, eos,
-                   maxnew):
-        """THE scanned decode step — one signature per (S, k): k
-        applications of the EXACT k=1 body (_decode_impl) chained through
-        the donated EngineState by lax.scan, with per-slot retirement ON
-        DEVICE: after each body, a slot whose sampled token hit its eos
-        id or whose generation count reached max_new drops out of the run
-        mask, so its later iterations recompute with frozen pos/toks —
-        batch-independent garbage whose K/V write lands at the one
-        uncommitted position after its last token (never read, never
-        donated to the prefix index).  The [k, S] stacked samples are the
-        host boundary's token block; rows past a slot's retirement are
-        discarded by the host cut that mirrors this very mask."""
-        def body(carry, _):
-            st, run = carry
-            new_st, nxt = self._decode_impl(params, st, run)
-            S = run.shape[0]          # behind the tokens: the MoE pairs
-            run = run & (nxt[:S] != eos) & (new_st.gen < maxnew)
-            return (new_st, run), nxt
-        (new_st, _), toks = jax.lax.scan(body, (st, run), None, length=k)
-        return new_st, toks
-
-    def _scan_step_fn(self):
-        """The jitted scanned step (signature discipline: ONE scanned
-        program per (S, k)) — `k` rides as a STATIC leading argument so
-        one jit object holds every window length, its cache size counts
-        the programs directly, and the compile watcher's signature at
-        site `serving.scan_step` distinguishes k (static ints are part
-        of the call signature, where a partial-bound k would vanish
-        from the aval-only view) — the recompile-storm detector sees a
-        knob-churning deployment the same way it sees budget churn."""
-        if self._scan_step is None:
-            scan_jit = jax.jit(self._scan_impl, static_argnums=(0,),
-                               donate_argnums=(2,),
-                               **self._step_sharding_kwargs(n_extra=3))
-            self._scan_step = get_compile_watch().wrap_jit(
-                "serving.scan_step", scan_jit)
-        return self._scan_step
-
     def _mixed_impl(self, params, st: EngineState, row_ids, row_slot,
                     row_pos, sample_row, adv, emit):
         """THE mixed prefill/decode step — one signature per
@@ -3178,8 +2982,7 @@ class ServingEngine:
         """Whether the step program of `kind` runs the expert block's
         grouped form: the layers' own rule (graph/layers_moe.py:
         expert_form_of) at the rows that program is traced with — the
-        slots for a decode step or a scan body, the token budget for a
-        mixed step."""
+        slots for a decode step, the token budget for a mixed step."""
         rows = self.max_step_tokens if kind == "mixed" else len(self.slots)
         if rows not in self._moe_grouped_at:
             self._moe_grouped_at[rows] = any(
@@ -3221,32 +3024,31 @@ class ServingEngine:
         them (the MoE pairs, then the two recurrent counts); bank the
         counts.  Returns the tokens."""
         if self._recurrent:
-            rec = nxt[..., -2:].reshape(-1, 2)
-            nxt = nxt[..., :-2]
-            self.recurrent_rows += int(rec[:, 0].sum())
-            self.recurrent_slot_updates += int(rec[:, 1].sum())
-            self.recurrent_steps += rec.shape[0]
+            rows, updates = int(nxt[-2]), int(nxt[-1])
+            nxt = nxt[:-2]
+            self.recurrent_rows += rows
+            self.recurrent_slot_updates += updates
+            self.recurrent_steps += 1
             pc = process_counters()
-            pc.add("serving_recurrent_rows_total", int(rec[:, 0].sum()))
-            pc.add("serving_recurrent_slot_updates_total",
-                   int(rec[:, 1].sum()))
-            pc.add("serving_recurrent_steps_total", rec.shape[0])
-        if nxt.shape[-1] > n_rows:
-            pairs = nxt[..., n_rows:].reshape(-1, nxt.shape[-1] - n_rows)
-            total, busiest = int(pairs.sum()), int(pairs.max(axis=1).sum())
+            pc.add("serving_recurrent_rows_total", rows)
+            pc.add("serving_recurrent_slot_updates_total", updates)
+            pc.add("serving_recurrent_steps_total", 1)
+        if nxt.size > n_rows:
+            pairs = nxt[n_rows:]
+            total, busiest = int(pairs.sum()), int(pairs.max())
             self.moe_pairs_total += total
             self.moe_pairs_max_sum += busiest
-            self.moe_steps += pairs.shape[0]
+            self.moe_steps += 1
             pc = process_counters()
             pc.add("serving_moe_pairs_total", total)
             pc.add("serving_moe_pairs_max_total", busiest)
-            pc.add("serving_moe_steps_total", pairs.shape[0])
+            pc.add("serving_moe_steps_total", 1)
             if self._moe_grouped(kind):
-                self.moe_grouped_steps[kind] = pairs.shape[0] + \
+                self.moe_grouped_steps[kind] = 1 + \
                     self.moe_grouped_steps.get(kind, 0)
                 pc.add(counter_key("serving_moe_grouped_steps_total",
-                                   kind=kind), pairs.shape[0])
-        return nxt[..., :n_rows]
+                                   kind=kind), 1)
+        return nxt[:n_rows]
 
     def _spec_impl(self, params, st: EngineState, row_ids, row_slot,
                    row_pos, first_row, n_draft, draft_toks, spec, emit,

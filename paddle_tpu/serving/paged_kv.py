@@ -111,7 +111,7 @@ bucketed to powers of two, pad rows writing zeros to trash page 0, so
 signatures stay bounded), and `adopt_restored` re-marks them cached
 before the slot maps them read-only.  Restores are MOVES — the host copy
 is dropped, a later re-spill re-copies.  All of it is admission-boundary
-host/allocator work: the decode/mixed/spec/scan step signatures never
+host/allocator work: the decode/mixed/spec step signatures never
 see the tier.  `_host_gen` stamps every entry and bumps on reset(), so a
 stale spilled page can never restore tokens from a dead tree generation.
 

@@ -83,7 +83,7 @@ class _ReqState:
     """Server-side lifecycle of one accepted request."""
 
     __slots__ = ("conn", "cid", "stream", "t_submit", "t_last", "next_idx",
-                 "burst_left", "burst_share", "push_to", "prompt")
+                 "push_to", "prompt")
 
     def __init__(self, conn, cid, stream):
         self.conn = conn
@@ -101,13 +101,6 @@ class _ReqState:
                                       # preempted request replays identical
                                       # tokens from 0; indexes below this
                                       # are dropped, not re-streamed
-        # burst-honest inter-token latency (multi-step decode): a scanned
-        # dispatch banks up to k tokens back-to-back, so the first token
-        # of a burst divides the whole inter-arrival gap by the burst size
-        # and the rest charge the SAME share — token_latency percentiles
-        # stay comparable across decode_steps settings
-        self.burst_left = 0           # burst tokens still to charge
-        self.burst_share = 0.0        # per-token share of the burst gap
 
 
 class _Conn(wire.FrameConn):
@@ -503,12 +496,6 @@ class ServingServer:
                   for kind, n in sorted(eng.recurrent_tokens.items())),
                 ("serving_recurrent_segment_chunks_total", "counter", None,
                  float(eng.recurrent_segment_chunks)),
-                # multi-step decode: scan body iterations vs boundary
-                # flushes — steps/flushes ≈ decode_steps in steady state
-                ("serving_scan_steps_total", "counter", None,
-                 float(eng.n_scan_steps)),
-                ("serving_scan_flushes_total", "counter", None,
-                 float(eng.n_scan_flushes)),
                 # speculative decoding: drafted/accepted counters + the
                 # lifetime accept rate (the throughput-multiplier dial)
                 ("serving_spec_drafted_total", "counter", None,
@@ -1122,7 +1109,6 @@ class ServingServer:
             "spec_k": int(self.engine.spec_k),
             "spec_dynamic": bool(self.engine.spec_dynamic),
             "drafter": self.engine.drafter_kind,
-            "decode_steps": int(self.engine.decode_steps),
             "role": self.role,
             "wedge_threshold_s": self.wedge_threshold_s,
             "postmortem_dir": self.postmortem_dir,
@@ -1167,28 +1153,11 @@ class ServingServer:
         if st is None:
             return
         now = time.monotonic()
-        # burst bookkeeping counts EVERY banked token (replays included —
-        # within one burst replayed indexes precede fresh ones), so the
-        # position within the engine's current ≤k-token burst is exact
-        if st.burst_left > 0:
-            st.burst_left -= 1
-        else:                                  # first token of a new burst
-            st.burst_left = max(1, int(self.engine.cur_burst)) - 1
-            st.burst_share = -1.0
         if idx >= st.next_idx:                 # fresh, not a preempt replay
             if idx == 0:
                 self.stats.get("first_token_latency").add(now - st.t_submit)
             else:
-                if st.burst_share < 0.0:
-                    # first FRESH token since t_last: the gap since then
-                    # covers this token and the burst_left still to come
-                    # (all fresh — replays sort first), so each owns an
-                    # equal share.  At decode_steps=1 the burst is one
-                    # token and this is the classic per-token charge;
-                    # at k>1 this keeps token_latency percentiles
-                    # comparable across decode_steps settings.
-                    st.burst_share = (now - st.t_last) / (st.burst_left + 1)
-                self._tok_lat.append(st.burst_share)
+                self._tok_lat.append(now - st.t_last)
             # t_last advances on FRESH tokens only: replayed (deduped)
             # emissions reach no client, so the first post-replay fresh
             # token must charge the whole preempt+re-prefill+replay stall
@@ -1199,8 +1168,7 @@ class ServingServer:
             if st.stream:
                 self._outbox.append(
                     (st.conn, None, {"type": "token", "id": st.cid,
-                                     "token": int(tok), "index": int(idx),
-                                     "burst": st.burst_left + 1}))
+                                     "token": int(tok), "index": int(idx)}))
 
     def _on_finish(self, rid: str, toks: np.ndarray, reason: str) -> None:
         # the server owns delivery — keep the engine's archive empty so a
@@ -1782,11 +1750,6 @@ class ServingServer:
                 None if sl is None or sl.accept_ewma is None
                 else round(float(sl.accept_ewma), 4)
                 for sl in eng.slots],
-            # multi-step decode: the A/B-able knobs + scan dispatch
-            # counters (flushes = boundaries, steps = body iterations)
-            "decode_steps_k": eng.decode_steps,
-            "scan_steps": eng.n_scan_steps,
-            "scan_flushes": eng.n_scan_flushes,
             # sharding: model-axis shard count + per-device pool bytes
             "tp_shards": eng.tp,
             "kv_pool_bytes_per_shard": int(eng.kv.pool_bytes_per_shard),

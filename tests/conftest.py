@@ -44,3 +44,71 @@ def pytest_collection_modifyitems(config, items):
                 reason="pins its cell as BENCHMARK.json's last; a cell was "
                        "appended behind it and the file is a benchmark "
                        "PR's to edit"))
+
+
+def interpreted() -> str:
+    """What a traced program depends on beside its executor."""
+    return os.environ.get("PADDLE_TPU_PALLAS_INTERPRET", "0")
+
+
+@pytest.fixture(scope="module")
+def engines():
+    """`engine(ex, w, **kw)`: ONE ServingEngine a set of constructor
+    arguments a module (two slots, pages of 4, a share of 5 rows, one step a
+    dispatch unless `kw` says otherwise), so tests that ask for the same
+    engine share its compiled steps.  It is handed on only idle, with every
+    page back and no result of an earlier caller left in its archive; its
+    counters run on, so a test reads them as differences.  A test that
+    changes an engine's settings, or leaves it mid-flight, builds its own."""
+    from paddle_tpu.serving import ServingEngine
+    built = {}
+
+    def engine(ex, w, **kw):
+        kw = {"num_slots": 2, "page_size": 4, "max_context": 48,
+              "prefill_chunk": 5, "max_step_tokens": None, **kw}
+        key = (id(ex), id(w), interpreted(), tuple(sorted(kw.items())))
+        if key not in built:
+            built[key] = ServingEngine(ex, w, **kw)
+        eng = built[key]
+        assert not eng.queue and all(s is None for s in eng.slots)
+        eng.kv.check_reclaimed()
+        for archive in (eng.results, eng.finish_reasons, eng.finish_timing):
+            archive.clear()
+        return eng
+    return engine
+
+
+COUNTERS = ("n_decode_steps", "n_prefill_chunks", "n_chunk_rows",
+            "n_chunk_extra_rows", "recurrent_steps",
+            "recurrent_slot_updates", "recurrent_rows", "moe_steps",
+            "moe_pairs_total", "moe_pairs_max_sum")
+
+
+def counted(eng, since=None):
+    """The engine's counters, or what they grew by since an earlier read."""
+    now = {k: getattr(eng, k) for k in COUNTERS}
+    now.update({"tokens_" + k: v for k, v in eng.recurrent_tokens.items()})
+    now["segment_chunks"] = eng.recurrent_segment_chunks
+    return now if since is None else {k: v - since.get(k, 0)
+                                      for k, v in now.items()}
+
+
+_ORACLE: dict = {}
+
+
+def lm_oracle(ex, w, req, use_cache=True):
+    """The tokens `lm_generate` gives the request alone (its prompt, knobs
+    and key), made once a process for one executor: engines of one model
+    serve the same requests in test after test."""
+    from paddle_tpu.graph.lm_decode import lm_generate
+    import numpy as np
+    key = (ex, id(w), req.prompt_ids.tobytes(), req.max_new, req.temperature,
+           req.top_k, req.top_p, req.eos_id, use_cache,
+           None if req.rng is None else np.asarray(req.rng).tobytes())
+    if key not in _ORACLE:
+        toks, lens = lm_generate(
+            ex, w, req.prompt_ids[None, :], max_new=req.max_new,
+            temperature=req.temperature, top_k=req.top_k, top_p=req.top_p,
+            eos_id=req.eos_id, rng=req.rng, use_cache=use_cache)
+        _ORACLE[key] = np.asarray(toks)[0, :int(np.asarray(lens)[0])]
+    return _ORACLE[key]

@@ -29,6 +29,9 @@ from typing import Mapping, Optional
 import numpy as np
 import pytest
 
+from tests.conftest import (  # noqa: F401
+    counted, engines, interpreted, lm_oracle)
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 # --config-args names of the sizes every DSL file takes, and the keys of
@@ -46,13 +49,12 @@ REFUSALS = ("prefix", "spill", "spill_later", "spec", "spec_later", "mesh",
 @dataclasses.dataclass(frozen=True)
 class EngineCase:
     """One ServingEngine a model's engine test runs: `prefill_chunk`, whether
-    PADDLE_TPU_PALLAS_INTERPRET is set, `decode_steps`, `max_step_tokens`,
-    and the --config-args the executor is built again with (none: the
-    `model` fixture's)."""
+    PADDLE_TPU_PALLAS_INTERPRET is set, `max_step_tokens`, and the
+    --config-args the executor is built again with (none: the `model`
+    fixture's)."""
     id: str
     chunk: int
     kernel: bool = False
-    k: int = 1
     mst: Optional[int] = None
     build: Mapping = dataclasses.field(default_factory=dict)
 
@@ -197,8 +199,7 @@ CASES = {c.name: c for c in (
         slot_parts={"state": ((4, 8, 8), "float32"), "conv": ((3, 96), "")},
         paged={"blk3_attn": (128,)},
         engines=RECURRENT_ENGINES + (
-            EngineCase("scanned-k4", 5, k=4),
-            EngineCase("free-rows-kernel", 5, True, mst=34, build=AUTO)),
+            EngineCase("free-rows-kernel", 5, True, mst=34, build=AUTO),),
         letters={"kda_attention": "K", "mla_attention": "A"},
         depths=(({"num_hidden_layers": 13}, "KKKAKKKAKKKAK", "d" + "e" * 12),
                 ({"num_hidden_layers": 2}, "KA", "de"),
@@ -227,7 +228,7 @@ CASES = {c.name: c for c in (
         recurrent=("blk0_conv", "blk2_conv", "blk3_conv", "blk4_conv"),
         recurrent_type="short_conv",
         slot_parts={"conv": ((2, 256), "")}, paged={"blk1_attn": (1, 128)},
-        engines=RECURRENT_ENGINES + (EngineCase("scanned-k4", 5, k=4),),
+        engines=RECURRENT_ENGINES,
         letters={"short_conv": "C", "multi_head_attention": "A"},
         depths=(({"num_hidden_layers": 5, "num_dense_layers": 1}, "CACCC",
                  "deeee"),
@@ -266,7 +267,7 @@ CASES = {c.name: c for c in (
         slot_parts={"state": ((4, 16, 16), "float32"),
                     "conv": ((3, 128), "")},
         paged={"blk3_attn": (2, 16)}, margin=True,
-        engines=RECURRENT_ENGINES + (EngineCase("decode-steps-2", 5, k=2),),
+        engines=RECURRENT_ENGINES,
         letters={"mamba2": "M", "multi_head_attention": "*"},
         depths=(({}, "MM*", "ee"),
                 ({"num_hidden_layers": 2, "first_layer": 1}, "M", "e"),
@@ -292,8 +293,7 @@ CASES = {c.name: c for c in (
         slot_parts={"state": ((16, 128), "float32"), "conv": ((3, 128), "")},
         paged={"blk1_attn": (1, 16)}, moe=False, margin=True,
         engines=RECURRENT_ENGINES + (
-            EngineCase("decode-steps-2", 5, k=2),
-            EngineCase("free-rows-kernel", 5, True, mst=34, build=AUTO)),
+            EngineCase("free-rows-kernel", 5, True, mst=34, build=AUTO),),
         letters={"mamba": "M", "multi_head_attention": "A"},
         depths=(({}, "MAMMM", "ddddd"),
                 ({"num_hidden_layers": 2}, "MA", "dd"),
@@ -559,11 +559,6 @@ def build(case, c: dict, compute_dtype="", **extra):
     return GraphExecutor(model, compute_dtype=compute_dtype)
 
 
-def interpreted() -> str:
-    """What a traced program depends on beside its executor."""
-    return os.environ.get("PADDLE_TPU_PALLAS_INTERPRET", "0")
-
-
 @functools.lru_cache(maxsize=None)
 def _forward(ex, interpret):
     """The executor's forward compiled once a shape: a test's mixed steps
@@ -648,21 +643,13 @@ def requests(n_tokens, max_new=6, seed=3):
             for i, n in enumerate(n_tokens)]
 
 
-_GENERATED: dict = {}
-
-
 def check_against_lm_generate(ex, w, reqs, results):
-    """The served tokens are lm_generate's whole-sequence tokens.  The same
-    prompt through the same executor is generated once a process: every
-    engine of a model serves the same requests."""
-    from paddle_tpu.graph.lm_decode import lm_generate
+    """The served tokens are lm_generate's whole-sequence tokens (made once
+    a process for one prompt through one executor: every engine of a model
+    serves the same requests)."""
     for r in reqs:
-        key = (ex, id(w), r.prompt_ids.tobytes(), r.max_new)
-        if key not in _GENERATED:
-            toks, lens = lm_generate(ex, w, r.prompt_ids[None, :],
-                                     max_new=r.max_new, rng=r.rng)
-            _GENERATED[key] = np.asarray(toks)[0, :int(np.asarray(lens)[0])]
-        np.testing.assert_array_equal(_GENERATED[key], results[r.req_id])
+        np.testing.assert_array_equal(lm_oracle(ex, w, r, use_cache=False),
+                                      results[r.req_id])
 
 
 def margin(ref, c, w, reqs, results):
@@ -722,46 +709,6 @@ def ref(case):
 def model(case, ref):
     c = cfg(case)
     return c, build(case, c), ref.make_weights(c, 7)
-
-
-@pytest.fixture(scope="module")
-def engines():
-    """`engine(ex, w, **kw)`: ONE ServingEngine a set of constructor
-    arguments a module (two slots, pages of 4, a share of 5 rows, one step a
-    dispatch unless `kw` says otherwise), so tests that ask for the same
-    engine share its compiled steps.  It is handed on only idle and with every page back; its
-    counters run on, so a test reads them as differences.  A test that
-    changes an engine's settings, or leaves it mid-flight, builds its own."""
-    from paddle_tpu.serving import ServingEngine
-    built = {}
-
-    def engine(ex, w, **kw):
-        kw = {"num_slots": 2, "page_size": 4, "max_context": 48,
-              "prefill_chunk": 5, "decode_steps": 1, "max_step_tokens": None,
-              **kw}
-        key = (id(ex), id(w), interpreted(), tuple(sorted(kw.items())))
-        if key not in built:
-            built[key] = ServingEngine(ex, w, **kw)
-        eng = built[key]
-        assert not eng.queue and all(s is None for s in eng.slots)
-        eng.kv.check_reclaimed()
-        return eng
-    return engine
-
-
-COUNTERS = ("n_decode_steps", "n_prefill_chunks", "n_chunk_rows",
-            "n_chunk_extra_rows", "n_scan_flushes", "recurrent_steps",
-            "recurrent_slot_updates", "recurrent_rows", "moe_steps",
-            "moe_pairs_total", "moe_pairs_max_sum")
-
-
-def counted(eng, since=None):
-    """The engine's counters, or what they grew by since an earlier read."""
-    now = {k: getattr(eng, k) for k in COUNTERS}
-    now.update({"tokens_" + k: v for k, v in eng.recurrent_tokens.items()})
-    now["segment_chunks"] = eng.recurrent_segment_chunks
-    return now if since is None else {k: v - since.get(k, 0)
-                                      for k, v in now.items()}
 
 
 # -- the reference and the whole sequence -------------------------------------------
@@ -976,9 +923,8 @@ def test_engine_serves_lm_generates_tokens(case, model, ref, engines,
                                            engine_case, monkeypatch):
     """A real ServingEngine — chunked prefill through mixed steps, slots
     re-admitted after other requests, the pools through the interpreted
-    kernels, the scanned step (`k` bodies a dispatch), a step with free rows
-    for a whole prompt (32 chunk rows: a run of 26 tokens where the share is
-    5) — serves lm_generate's whole-sequence greedy tokens; where the case
+    kernels, a step with free rows for a whole prompt (32 chunk rows: a run
+    of 26 tokens where the share is 5) — serves lm_generate's whole-sequence greedy tokens; where the case
     says `margin`, every served token is the argmax of the reference's ONE
     full forward over prompt + served tokens to within the logits'
     tolerance; and the counters came back with the tokens."""
@@ -991,8 +937,7 @@ def test_engine_serves_lm_generates_tokens(case, model, ref, engines,
     reqs = requests(case.prompts)
     with jax.default_matmul_precision("highest"):
         eng = engines(ex, w, max_context=case.max_context,
-                      prefill_chunk=e.chunk, decode_steps=e.k,
-                      max_step_tokens=e.mst)
+                      prefill_chunk=e.chunk, max_step_tokens=e.mst)
         assert (eng.prefix is None) == bool(case.recurrent)
         before = counted(eng)
         results = eng.run(reqs)
@@ -1009,7 +954,6 @@ def test_engine_serves_lm_generates_tokens(case, model, ref, engines,
         assert n["n_chunk_rows"] == prompt_rows
         assert n["n_chunk_extra_rows"] == sum(
             max(0, p - e.chunk) for p in case.prompts)
-    assert (n["n_scan_flushes"] > 0) == (e.k > 1)
     assert n["n_decode_steps"] > 0
     steps = n["recurrent_steps"] if layers else n["n_decode_steps"]
     if case.moe:

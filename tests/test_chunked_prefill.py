@@ -14,9 +14,9 @@ import pytest
 import jax
 
 from paddle_tpu.config.parser import parse_config
-from paddle_tpu.graph.lm_decode import lm_generate
 from paddle_tpu.serving import Request, ServingEngine
 from paddle_tpu.trainer.trainer import Trainer
+from tests.conftest import lm_oracle
 
 
 @pytest.fixture(scope="module")
@@ -30,12 +30,14 @@ def tr():
     return Trainer(cfg, seed=7)
 
 
+# the geometry of every test whose point is not a budget, a pool or a page
+# size: they meet in one engine (tests/conftest.py `engines`) and read its
+# counters as differences
+GEOM = dict(num_slots=2, page_size=4, max_context=32, prefill_chunk=4,
+            max_step_tokens=6)
+
 def _oracle(tr, req: Request):
-    toks, lens = lm_generate(
-        tr.executor, tr.params, req.prompt_ids[None, :],
-        max_new=req.max_new, temperature=req.temperature, top_k=req.top_k,
-        top_p=req.top_p, eos_id=req.eos_id, rng=req.rng, use_cache=True)
-    return np.asarray(toks)[0, :int(np.asarray(lens)[0])]
+    return lm_oracle(tr.executor, tr.params, req)
 
 
 def _assert_exact(tr, reqs, results):
@@ -57,29 +59,40 @@ def _assert_sigs(eng):
 # the token-exactness oracle under multi-chunk prefill
 # ---------------------------------------------------------------------------
 
-def test_multi_chunk_prompts_stay_oracle_exact_across_knobs(tr):
+KNOBS = {"greedy": dict(), "top-k": dict(temperature=0.8, top_k=5),
+         "nucleus": dict(temperature=0.7, top_p=0.9),
+         "full": dict(temperature=1.1)}
+
+
+@pytest.fixture(scope="module")
+def multi_chunk(tr, engines):
     """Prompts spanning 1..5 chunks with mixed sampling knobs, tiny chunk
-    (= page size) and a tight token budget: every request bit-matches its
-    cold run, at least one request decoded WHILE another was still
-    chunking (the mixed step actually mixed), and the signature set is
-    the fixed pair."""
+    (= page size) and a tight token budget, served together once: at least
+    one request decoded WHILE another was still chunking (the mixed step
+    actually mixed), and the signature set is the fixed pair."""
     rng = np.random.default_rng(0)
-    knobs = [dict(), dict(temperature=0.8, top_k=5),
-             dict(temperature=0.7, top_p=0.9), dict(temperature=1.1)]
     lens = (3, 19, 9, 17)
-    reqs = [Request(f"r{i}", rng.integers(2, 23, n).astype(np.int32),
-                    max_new=5, rng=jax.random.PRNGKey(40 + i), **kw)
-            for i, (n, kw) in enumerate(zip(lens, knobs))]
-    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
-                        max_context=32, prefill_chunk=4, max_step_tokens=7)
-    results = eng.run(reqs)
-    _assert_exact(tr, reqs, results)
-    assert eng.n_mixed_steps > 0 and eng.n_prefill_chunks >= 4
+    reqs = {name: Request(f"r{i}", rng.integers(2, 23, n).astype(np.int32),
+                          max_new=5, rng=jax.random.PRNGKey(40 + i), **kw)
+            for i, (n, (name, kw)) in enumerate(zip(lens, KNOBS.items()))}
+    eng = engines(tr.executor, tr.params, **GEOM)
+    mixed0, chunks0 = eng.n_mixed_steps, eng.n_prefill_chunks
+    results = eng.run(list(reqs.values()))
+    assert eng.n_mixed_steps > mixed0 and eng.n_prefill_chunks - chunks0 >= 4
     _assert_sigs(eng)
     eng.kv.check_reclaimed()
+    return reqs, results
 
 
-def test_decode_advances_while_long_prompt_chunks(tr):
+@pytest.mark.parametrize("knobs", sorted(KNOBS))
+def test_multi_chunk_prompts_stay_oracle_exact_across_knobs(tr, multi_chunk,
+                                                            knobs):
+    """Each of them bit-matches its cold run."""
+    reqs, results = multi_chunk
+    _assert_exact(tr, [reqs[knobs]], results)
+
+
+def test_decode_advances_while_long_prompt_chunks(tr, engines):
     """The HOL-blocking kill shot: a short request is mid-decode when a
     long prompt admits — the short request's tokens keep advancing on
     the very steps that carry the long prompt's chunks (no stall), and
@@ -89,8 +102,7 @@ def test_decode_advances_while_long_prompt_chunks(tr):
                     max_new=12)
     long_ = Request("long", rng.integers(2, 23, 25).astype(np.int32),
                     max_new=4)
-    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
-                        max_context=32, prefill_chunk=4, max_step_tokens=6)
+    eng = engines(tr.executor, tr.params, **GEOM)
     eng.add_request(short)
     eng.step()                       # short: chunk+token0 (mixed step)
     eng.step()                       # short decoding alone
@@ -175,15 +187,15 @@ def test_prefix_hit_ending_mid_chunk_stays_exact(tr):
     eng.kv.check_reclaimed()
 
 
-def test_cow_divergence_inside_chunk_boundary_stays_exact(tr):
+def test_cow_divergence_inside_chunk_boundary_stays_exact(tr, engines):
     """COW divergence landing inside a chunk's page span: two concurrent
     followers of the same prefix, one diverging mid-page — each writes
     only its private boundary copy, both bit-match cold runs, and the
     donor page survives for a later exact repeat."""
     rng = np.random.default_rng(4)
     base = rng.integers(2, 23, 10).astype(np.int32)
-    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
-                        max_context=32, prefill_chunk=4, max_step_tokens=6)
+    eng = engines(tr.executor, tr.params, **GEOM)
+    hits0, cow0 = eng.n_prefix_hits, eng.kv.n_cow
     warm = Request("warm", base.copy(), max_new=5)
     results = eng.run([warm])
     x = Request("x", np.concatenate([base[:9], [3, 4, 5]])
@@ -193,8 +205,9 @@ def test_cow_divergence_inside_chunk_boundary_stays_exact(tr):
     eng.add_request(x)
     eng.add_request(y)
     eng.step()                       # both admitted: both hit, both COW
-    assert eng.n_prefix_hits >= 2
-    assert eng.kv.n_cow >= 2, "mid-page divergence never copied-on-write"
+    assert eng.n_prefix_hits - hits0 >= 2
+    assert eng.kv.n_cow - cow0 >= 2, \
+        "mid-page divergence never copied-on-write"
     assert eng.kv.shared_pages_in_use >= 2
     results.update(eng.run())
     again = Request("again", base.copy(), max_new=5)
@@ -350,18 +363,31 @@ def test_restore_refuses_a_snapshot_taken_unchunked(tr):
 
 
 def test_decode_mode_is_no_longer_an_argument(tr):
-    """The dispatch policy is not an option: the scan composes with
-    speculation always, and the retired argument is a TypeError rather
-    than a silently ignored keyword."""
+    """The dispatch policy is not an option: the retired argument is a
+    TypeError rather than a silently ignored keyword."""
     with pytest.raises(TypeError, match="decode_mode"):
         ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
                       max_context=32, decode_mode="auto")
     assert not hasattr(ServingEngine, "set_decode_mode")
 
 
+def test_decode_steps_is_no_longer_an_argument(tr, engines):
+    """There is one decode body a dispatch (docs/serving.md "The step
+    loop"): the scanned step's argument is a TypeError, and its setter,
+    counters and burst size are gone from the engine."""
+    with pytest.raises(TypeError, match="decode_steps"):
+        ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
+                      max_context=32, decode_steps=2)
+    eng = engines(tr.executor, tr.params, **GEOM)
+    for gone in ("set_decode_steps", "decode_steps", "n_scan_steps",
+                 "n_scan_flushes", "cur_burst", "_scan_step"):
+        assert not hasattr(eng, gone), gone
+
+
 @pytest.mark.parametrize("length", ["chunk-1", "chunk", "chunk+1",
                                     "2*chunk+3"])
-def test_chunk_boundary_lengths_exact_cold_and_on_a_prefix_hit(tr, length):
+def test_chunk_boundary_lengths_exact_cold_and_on_a_prefix_hit(tr, engines,
+                                                               length):
     """The lengths the whole-prompt prefill tests used, on the one path
     that remains: a prompt just under, at, just over one chunk and over
     two chunks bit-matches the cold lm_generate oracle — and so does the
@@ -373,18 +399,23 @@ def test_chunk_boundary_lengths_exact_cold_and_on_a_prefix_hit(tr, length):
          "2*chunk+3": 2 * chunk + 3}[length]
     rng = np.random.default_rng(p)
     prompt = rng.integers(2, 23, p).astype(np.int32)
-    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=ps,
-                        max_context=48, prefill_chunk=chunk)
+    # one engine the four lengths: its counters are read as differences
+    eng = engines(tr.executor, tr.params, num_slots=2, page_size=ps,
+                  max_context=48, prefill_chunk=chunk)
+    hits0, saved0, cow0 = (eng.n_prefix_hits, eng.prefill_tokens_saved,
+                           eng.kv.n_cow)
+    chunks0, rows0, extra0 = (eng.n_prefill_chunks, eng.n_chunk_rows,
+                              eng.n_chunk_extra_rows)
     cold = Request("cold", prompt.copy(), max_new=6, temperature=0.8,
                    top_k=5, rng=jax.random.PRNGKey(p))
     res = eng.run([cold])
     _assert_exact(tr, [cold], res)
-    assert eng.n_prefix_hits == 0
+    assert eng.n_prefix_hits == hits0
     # nothing else runs, so the step's 10 rows (chunk + 2 slots) are all
     # free: ceil(p / 10) runs, the first longer than `prefill_chunk`
-    assert eng.n_prefill_chunks == -(-p // eng.max_step_tokens)
-    assert eng.n_chunk_rows == p
-    assert eng.n_chunk_extra_rows == sum(
+    assert eng.n_prefill_chunks - chunks0 == -(-p // eng.max_step_tokens)
+    assert eng.n_chunk_rows - rows0 == p
+    assert eng.n_chunk_extra_rows - extra0 == sum(
         max(0, min(10, p - at) - chunk) for at in range(0, p, 10))
     chunks0 = eng.n_prefill_chunks
     warm = Request("warm", prompt.copy(), max_new=6, temperature=0.8,
@@ -395,9 +426,10 @@ def test_chunk_boundary_lengths_exact_cold_and_on_a_prefix_hit(tr, length):
     # the walk matches all but the last prompt token (one always
     # prefills): the suffix is one row starting mid-page, on the copy of
     # the boundary page that reservation made
-    assert eng.n_prefix_hits == 1 and eng.prefill_tokens_saved == p - 1
+    assert eng.n_prefix_hits - hits0 == 1
+    assert eng.prefill_tokens_saved - saved0 == p - 1
     assert eng.n_prefill_chunks - chunks0 == 1
-    assert eng.kv.n_cow == (1 if (p - 1) % ps else 0)
+    assert eng.kv.n_cow - cow0 == (1 if (p - 1) % ps else 0)
     _assert_sigs(eng)
     eng.kv.check_reclaimed()
 
@@ -429,18 +461,25 @@ def _spy_packing(eng, monkeypatch):
     return calls
 
 
+def _packing_kw(chunk, budget, slots) -> dict:
+    return dict(num_slots=slots, page_size=4, max_context=40,
+                prefill_chunk=chunk, max_step_tokens=budget,
+                prefix_cache=False)
+
+
 @pytest.mark.parametrize("decoding", [False, True],
                          ids=["alone", "beside-a-decode-row"])
-def test_a_lone_prompt_takes_the_steps_free_rows(tr, decoding, monkeypatch):
+def test_a_lone_prompt_takes_the_steps_free_rows(tr, engines, decoding,
+                                                 monkeypatch):
     """One filling slot gets min(rest of its prompt, the step's free
     rows) a step — its share of 4 and every row no one else wants: 25
     prompt tokens go in 12 + 12 + 1 (11 + 11 + 3 beside one decode row)
     where a chunk capped at 4 took 7 steps; the counters say how many rows
     were given past the share and how many went empty."""
     rng = np.random.default_rng(21)
-    eng = ServingEngine(tr.executor, tr.params, num_slots=3, page_size=4,
-                        max_context=48, prefill_chunk=4, max_step_tokens=12,
-                        prefix_cache=False)
+    eng = engines(tr.executor, tr.params, num_slots=3, page_size=4,
+                  max_context=48, prefill_chunk=4, max_step_tokens=12,
+                  prefix_cache=False)
     reqs = []
     if decoding:
         reqs.append(Request("short", rng.integers(2, 23, 3).astype(np.int32),
@@ -475,16 +514,16 @@ def test_a_lone_prompt_takes_the_steps_free_rows(tr, decoding, monkeypatch):
 
 
 def test_two_filling_slots_get_their_share_and_the_older_the_rest(
-        tr, monkeypatch):
+        tr, engines, monkeypatch):
     """Two prompts of 20 admitted together under a step of 16 rows and a
     share of 4: both get 4 first, the 8 rows left go to the OLDER (12 + 4);
     then the older's last 8 and the younger's 4 + 4; then the younger's
     last 8 beside the older's decode row.  Each slot's rows are one
     contiguous run, the older's first."""
     rng = np.random.default_rng(22)
-    eng = ServingEngine(tr.executor, tr.params, num_slots=3, page_size=4,
-                        max_context=32, prefill_chunk=4, max_step_tokens=16,
-                        prefix_cache=False)
+    eng = engines(tr.executor, tr.params, **_packing_kw(4, 16, 3))
+    chunks0, rows0, extra0 = (eng.n_prefill_chunks, eng.n_chunk_rows,
+                              eng.n_chunk_extra_rows)
     calls = _spy_packing(eng, monkeypatch)
     a = Request("a", rng.integers(2, 23, 20).astype(np.int32), max_new=4)
     b = Request("b", rng.integers(2, 23, 20).astype(np.int32), max_new=4)
@@ -497,8 +536,9 @@ def test_two_filling_slots_get_their_share_and_the_older_the_rest(
     assert calls[2][:2] == (1, 15)   # behind a's decode row
     for _, _, runs, slots in calls:
         assert slots.tolist() == [s for s, _, n, _ in runs for _ in range(n)]
-    assert eng.n_prefill_chunks == 5 and eng.n_chunk_rows == 40
-    assert eng.n_chunk_extra_rows == 8 + (4 + 4) + 4
+    assert eng.n_prefill_chunks - chunks0 == 5
+    assert eng.n_chunk_rows - rows0 == 40
+    assert eng.n_chunk_extra_rows - extra0 == 8 + (4 + 4) + 4
     _assert_exact(tr, [a, b], results)
     _assert_sigs(eng)
 
@@ -506,7 +546,7 @@ def test_two_filling_slots_get_their_share_and_the_older_the_rest(
 @pytest.mark.parametrize("chunk,budget,slots", [(4, 7, 3), (4, 16, 3),
                                                 (8, 24, 4), (3, 5, 4)])
 def test_no_step_packs_more_than_its_budget_and_a_slot_is_one_run(
-        tr, chunk, budget, slots, monkeypatch):
+        tr, engines, chunk, budget, slots, monkeypatch):
     """Whatever the share and the step: the chunk rows end inside the step
     and inside the budget they were given, every slot appears as ONE
     contiguous run a step (the recurrent layers' packing contract), no
@@ -517,9 +557,8 @@ def test_no_step_packs_more_than_its_budget_and_a_slot_is_one_run(
     reqs = [Request(f"r{i}", rng.integers(2, 23, n).astype(np.int32),
                     max_new=5, rng=jax.random.PRNGKey(60 + i))
             for i, n in enumerate((13, 3, 22, 9, 17, 30, 6))]
-    eng = ServingEngine(tr.executor, tr.params, num_slots=slots,
-                        page_size=4, max_context=40, prefill_chunk=chunk,
-                        max_step_tokens=budget, prefix_cache=False)
+    eng = engines(tr.executor, tr.params, **_packing_kw(chunk, budget, slots))
+    rows0 = eng.n_chunk_rows
     calls = _spy_packing(eng, monkeypatch)
     results = eng.run(reqs)
     assert calls and any(n > chunk for _, _, runs, _ in calls
@@ -537,7 +576,7 @@ def test_no_step_packs_more_than_its_budget_and_a_slot_is_one_run(
         if n_rows < b:
             assert all(start + n == p for _, start, n, p in runs)
     assert sum(n for _, _, runs, _ in calls for _, _, n, _ in runs) == \
-        sum(r.prompt_ids.size for r in reqs) == eng.n_chunk_rows
+        sum(r.prompt_ids.size for r in reqs) == eng.n_chunk_rows - rows0
     _assert_exact(tr, reqs, results)
     _assert_sigs(eng)
 
@@ -740,7 +779,7 @@ def test_paged_kernel_ragged_rows_match_fallback(case):
 
 @pytest.mark.parametrize("form", ["decode", "mixed"])
 def test_paged_kernel_inside_scan_matches_fallback(form):
-    """The engine's scanned dispatch: positions are a scan carry, so each
+    """The kernels inside a `lax.scan`: positions are a scan carry, so each
     body's trip counts come from run-time values — three bodies walk one
     slot from the last token of a block into the next."""
     import jax

@@ -212,3 +212,39 @@ def test_restore_guards_config_and_idleness():
                              max_new=4))
     with pytest.raises(ValueError, match="idle"):
         busy.restore_state(busy.checkpoint_state())
+
+
+def test_a_snapshot_with_the_old_scan_counters_restores_exactly():
+    """A snapshot written before the scanned step went carries
+    `n_scan_steps` / `n_scan_flushes` among its counters: it still restores
+    — mid-flight, to the tokens the uninterrupted engine serves — and the
+    two keys become no attribute of the engine (`restore_state` sets the
+    counters `checkpoint_state` writes and no other)."""
+    tr = _make("vocab=31,dim=16,layers=1,heads=2,batch_size=3")
+    kw = dict(num_slots=2, page_size=8, max_context=32)
+    reqs = lambda: [Request("a", np.asarray([3, 4, 5, 6, 7], np.int32),
+                            max_new=7),
+                    Request("b", np.asarray([9, 8, 7], np.int32), max_new=5)]
+    donor = ServingEngine(tr.executor, tr.params, **kw)
+    for r in reqs():
+        donor.add_request(r)
+    for _ in range(3):
+        donor.step()
+    snap = donor.checkpoint_state()
+    assert not {"n_scan_steps", "n_scan_flushes"} & snap["counters"].keys()
+    assert snap["counters"].keys() == set(ServingEngine._SNAPSHOT_COUNTERS)
+    snap["counters"].update(n_scan_steps=8, n_scan_flushes=2)
+    while donor.step():
+        pass
+    eng = ServingEngine(tr.executor, tr.params, **kw)
+    eng.restore_state(snap)
+    assert not hasattr(eng, "n_scan_steps")
+    assert not hasattr(eng, "n_scan_flushes")
+    assert eng.n_decode_steps == snap["counters"]["n_decode_steps"] == 3
+    while eng.step():
+        pass
+    assert set(eng.results) == {"a", "b"}
+    for rid, toks in donor.results.items():
+        np.testing.assert_array_equal(toks, eng.results[rid])
+    assert eng.tokens_generated == donor.tokens_generated == 12
+    eng.kv.check_reclaimed()

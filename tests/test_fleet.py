@@ -514,15 +514,12 @@ def test_fleet_stats_metrics_dump_frames_and_unhealthy_bundle(
         _stop_all(rt, srvs)
 
 
-def test_router_relay_itl_burst_honest_through_multi_step_replicas(
-        tiny_tr):
-    """ISSUE 16 satellite: replicas running decode_steps=3 relay token
-    frames in bursts; the router divides the inter-burst arrival gap by
-    the frame's `burst` stamp so relay ITL counts every token (no
-    k-times undercount, no 0-gap flood), streams stay bit-exact, and the
-    percentiles surface in the stats frame + CATALOG metrics."""
+def test_router_relay_itl_counts_every_relayed_token(tiny_tr):
+    """Relay ITL is the gap between a request's relayed token frames: one
+    sample a token past the first, streams bit-exact, and the percentiles
+    surface in the stats frame + CATALOG metrics."""
     rng = np.random.default_rng(3)
-    rt, host, port, srvs = _fleet(tiny_tr, 2, decode_steps=3)
+    rt, host, port, srvs = _fleet(tiny_tr, 2)
     try:
         prompts = [rng.integers(2, 31, int(rng.integers(3, 10))).tolist()
                    for _ in range(4)]
@@ -532,8 +529,6 @@ def test_router_relay_itl_burst_honest_through_multi_step_replicas(
             for rid, p in zip(ids, prompts):
                 assert out[rid]["tokens"] == _oracle(tiny_tr, p, 7)
                 assert out[rid]["stream"] == out[rid]["tokens"][len(p):]
-            # the replicas really did scan (multi-step actually engaged)
-            assert sum(srv.engine.n_scan_flushes for srv in srvs) > 0
             s = c.stats()
             itl = s["relay_itl_ms"]
             assert set(itl) == {"p50", "p90", "p99"}

@@ -288,8 +288,8 @@ def test_head_rows_counter_by_step_kind(tr):
     assert seen["mixed"] and seen["decode"]
 
 
-def test_head_rows_counter_spec_and_scan(tr):
-    """S x (K + 1) a verify step; k x S a scanned window."""
+def test_head_rows_counter_spec(tr):
+    """S x (K + 1) a verify step."""
     rng = np.random.default_rng(4)
     prompt = np.tile(rng.integers(2, LM_VOCAB, 4).astype(np.int32), 3)
     eng = _engine(tr, spec_k=SPEC_K, drafter=NgramDrafter())
@@ -299,12 +299,6 @@ def test_head_rows_counter_spec_and_scan(tr):
     assert _head_rows() - before == eng.n_head_rows == \
         eng.n_spec_steps * SLOTS * (SPEC_K + 1) + \
         (eng.n_decode_steps - eng.n_spec_steps) * SLOTS
-
-    eng = _engine(tr, decode_steps=3)
-    eng.run([Request("k", prompt[:5], max_new=8)])
-    assert eng.n_scan_steps > 0
-    assert eng.n_head_rows == eng.n_kv_rows - \
-        eng.n_mixed_steps * (BUDGET - SLOTS)
 
 
 @pytest.mark.skipif(len(jax.devices()) < 2,

@@ -40,8 +40,7 @@ def test_engine_serves_lm_generates_tokens_through_the_rings(
     reqs = requests(case.prompts)
     with jax.default_matmul_precision("highest"):
         eng = engines(ex, w, max_context=case.max_context,
-                      prefill_chunk=e.chunk, decode_steps=e.k,
-                      max_step_tokens=e.mst)
+                      prefill_chunk=e.chunk, max_step_tokens=e.mst)
         # a step of 34 rows would size the ring at a whole context: the
         # window layers stay under the logical table there, and nothing of
         # the prefix index is refused

@@ -2,15 +2,14 @@
 tests/test_lfm2_moe.py (a file of its own because `--dist loadfile` gives one
 file to one worker): the shared engine tests of tests/model_parity.py over
 its case — chunked prefill then decode, the packed pool through the
-interpreted kernel, the scanned step, checkpoint and restore, the refusals,
+interpreted kernel, checkpoint and restore, the refusals,
 tools/serve.py:build_engine — and what is this model's own: the expert block
-forced onto its grouped form, and a request that ends inside a scanned
-dispatch."""
+forced onto its grouped form."""
 
 import numpy as np
 
 from tests.model_parity import (  # noqa: F401
-    CASES, case, check_against_lm_generate, engines, model,
+    CASES, case, engines, model,
     pytest_generate_tests, ref, requests,
     test_build_engine_serves_the_model_in_bf16,
     test_checkpoint_and_restore_round_trip_the_slot_parts,
@@ -67,17 +66,3 @@ def test_engine_serves_the_same_tokens_on_the_grouped_form(model,
     assert counted["mixed"] == grouped.n_mixed_steps > 0
     assert counted["decode"] == grouped.moe_steps - counted["mixed"] > 0
 
-
-def test_a_paused_slot_does_not_advance_in_the_scanned_step(model, engines):
-    """--decode-steps 4 with a request that ends inside a dispatch: the
-    slot's remaining bodies run masked, and the request that takes the slot
-    next decodes what an undisturbed engine decodes."""
-    import jax
-    _, ex, w = model
-    reqs = requests((7, 5, 9), max_new=6) + requests((4,), max_new=3, seed=9)
-    reqs[-1].req_id = "short"
-    with jax.default_matmul_precision("highest"):
-        eng = engines(ex, w, decode_steps=4)
-        flushes = eng.n_scan_flushes
-        check_against_lm_generate(ex, w, reqs, eng.run(reqs))
-    assert eng.n_scan_flushes > flushes

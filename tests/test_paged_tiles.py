@@ -305,8 +305,8 @@ def test_the_engines_count_is_the_kernels_fetch(layer, monkeypatch):
     if decode:      # rows of 300 tokens: 3 blocks each, one a dead row
         dead = tile * tiles - R
         assert dead == {24: 8, 12: 4}[R]
-        assert pp.walked_blocks(np.full((2, R), 300), None, bq, block) == (
-            2 * (R * -(-300 // block) + dead), 0)
+        assert pp.walked_blocks(np.full(R, 300), None, bq, block) == (
+            R * -(-300 // block) + dead, 0)
 
 
 def test_layers_of_one_shape_trace_the_kernel_once(monkeypatch):

@@ -14,10 +14,10 @@ import pytest
 import jax
 
 from paddle_tpu.config.parser import parse_config
-from paddle_tpu.graph.lm_decode import lm_generate
 from paddle_tpu.serving import (PagedKVCache, PrefixTree, Request,
                                 ServingEngine)
 from paddle_tpu.trainer.trainer import Trainer
+from tests.conftest import lm_oracle
 
 
 @pytest.fixture(scope="module")
@@ -28,11 +28,18 @@ def tr():
 
 
 def _oracle(tr, req: Request):
-    toks, lens = lm_generate(
-        tr.executor, tr.params, req.prompt_ids[None, :],
-        max_new=req.max_new, temperature=req.temperature, top_k=req.top_k,
-        top_p=req.top_p, eos_id=req.eos_id, rng=req.rng, use_cache=True)
-    return np.asarray(toks)[0, :int(np.asarray(lens)[0])]
+    return lm_oracle(tr.executor, tr.params, req)
+
+
+def _roomy(engines, tr):
+    """The module's one engine with room to spare (pages of 8, the whole
+    pool), for the tests whose subject is the sharing and not the pool: the
+    index and the allocator cold, as a fresh engine's; counters are read as
+    differences."""
+    eng = engines(tr.executor, tr.params, num_slots=2, page_size=8,
+                  max_context=64, prefill_chunk=-1)
+    eng.reset_prefix_cache()
+    return eng
 
 
 def _assert_exact(tr, reqs, results):
@@ -51,7 +58,7 @@ def _pool_reclaimed(eng):
 # the token-exactness oracle, extended to the sharing paths
 # ---------------------------------------------------------------------------
 
-def test_shared_prefix_hits_stay_oracle_exact(tr):
+def test_shared_prefix_hits_stay_oracle_exact(tr, engines):
     """A pool of requests sharing one system-prompt prefix with distinct
     suffixes and mixed sampling knobs: the first pays full prefill, the
     rest prefix-hit (mapping the committed pages read-only + suffix-only
@@ -67,20 +74,20 @@ def test_shared_prefix_hits_stay_oracle_exact(tr):
                                     .astype(np.int32)]),
                     max_new=5, rng=jax.random.PRNGKey(40 + i), **kw)
             for i, kw in enumerate(knobs)]
-    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=8,
-                        max_context=64)
+    eng = _roomy(engines, tr)
+    hits0, saved0 = eng.n_prefix_hits, eng.prefill_tokens_saved
     results = {}
     for r in reqs:                        # sequential: each later request
         results.update(eng.run([r]))      # sees the earlier donations
     _assert_exact(tr, reqs, results)
-    assert eng.n_prefix_hits >= len(reqs) - 1
-    assert eng.prefill_tokens_saved >= (len(reqs) - 1) * 16, \
+    assert eng.n_prefix_hits - hits0 >= len(reqs) - 1
+    assert eng.prefill_tokens_saved - saved0 >= (len(reqs) - 1) * 16, \
         "hits did not skip the shared full pages"
     assert eng._decode_step._cache_size() == 1
     _pool_reclaimed(eng)
 
 
-def test_concurrent_same_prefix_requests_share_pages(tr):
+def test_concurrent_same_prefix_requests_share_pages(tr, engines):
     """Two live slots mapping the same cached prefix simultaneously:
     shared pages show refcount > 1 (shared_pages_in_use), neither slot
     writes them (COW gave each a private boundary), and both outputs stay
@@ -88,8 +95,8 @@ def test_concurrent_same_prefix_requests_share_pages(tr):
     rng = np.random.default_rng(1)
     system = rng.integers(2, 23, 17).astype(np.int32)
     warm = Request("warm", system.copy(), max_new=9)
-    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=8,
-                        max_context=64)
+    eng = _roomy(engines, tr)
+    hits0 = eng.n_prefix_hits
     res = eng.run([warm])
     a = Request("a", np.concatenate([system, [3, 4, 5]]).astype(np.int32),
                 max_new=6)
@@ -98,7 +105,7 @@ def test_concurrent_same_prefix_requests_share_pages(tr):
     eng.add_request(a)
     eng.add_request(b)
     eng.step()                            # both admitted, both hit
-    assert eng.n_prefix_hits == 2
+    assert eng.n_prefix_hits - hits0 == 2
     assert eng.kv.shared_pages_in_use >= 2, \
         "concurrent hits did not actually share physical pages"
     eng.kv.check()
@@ -108,7 +115,7 @@ def test_concurrent_same_prefix_requests_share_pages(tr):
     _pool_reclaimed(eng)
 
 
-def test_cow_divergence_mid_page_and_donor_page_intact(tr):
+def test_cow_divergence_mid_page_and_donor_page_intact(tr, engines):
     """B's prompt follows A's sequence INTO a page and diverges mid-run:
     admission maps the boundary page, COWs it, and B's suffix overwrites
     only its own copy — B is oracle-exact, and a third request repeating
@@ -116,8 +123,8 @@ def test_cow_divergence_mid_page_and_donor_page_intact(tr):
     shared original was never written)."""
     rng = np.random.default_rng(2)
     base = rng.integers(2, 23, 13).astype(np.int32)     # 13 = 1.625 pages
-    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=8,
-                        max_context=64)
+    eng = _roomy(engines, tr)
+    hits0 = eng.n_prefix_hits
     a = Request("a", base.copy(), max_new=6)
     results = eng.run([a])
     cow0 = eng.kv.n_cow
@@ -128,7 +135,7 @@ def test_cow_divergence_mid_page_and_donor_page_intact(tr):
     b = Request("b", b_prompt, max_new=6)
     results.update(eng.run([b]))
     assert eng.kv.n_cow > cow0, "mid-page divergence never copied-on-write"
-    assert eng.n_prefix_hits >= 1
+    assert eng.n_prefix_hits - hits0 >= 1
     # C repeats A's prompt exactly: the original boundary page must still
     # hold A's committed K/V bit-for-bit
     c = Request("c", base.copy(), max_new=6)
@@ -288,7 +295,7 @@ def test_reset_rebuilds_canonical_free_list(tr):
     kv.check()
 
 
-def test_engine_reset_prefix_cache_restores_cold_start(tr):
+def test_engine_reset_prefix_cache_restores_cold_start(tr, engines):
     """ServingEngine.reset_prefix_cache is the engine-level cold start:
     the index empties, the free list returns to canonical order, and
     re-running the same workload reproduces the same page placement AND
@@ -300,15 +307,15 @@ def test_engine_reset_prefix_cache_restores_cold_start(tr):
         [system, rng2.integers(2, 23, 2 + i).astype(np.int32)]), max_new=4)
         for i, rng2 in ((j, np.random.default_rng(50 + j))
                         for j in range(3))]
-    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=8,
-                        max_context=64)
+    eng = _roomy(engines, tr)
+    hits0 = eng.n_prefix_hits
     first = eng.run(mk())
     cached1 = np.flatnonzero(eng.kv._cached).tolist()
     eng.reset_prefix_cache()
     assert eng.prefix.n_nodes == 0 and eng.kv.cached_page_count == 0
     assert eng.kv.free_page_count == eng.kv.num_pages - 1
     assert eng.kv._free == eng.kv._canonical_free()
-    assert eng.n_prefix_hits > 0                  # first pass did share
+    assert eng.n_prefix_hits > hits0              # first pass did share
     again = eng.run(mk())
     for rid in first:
         np.testing.assert_array_equal(first[rid], again[rid])

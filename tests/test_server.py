@@ -261,17 +261,22 @@ def test_metrics_frame_and_consistent_stats_over_tcp(tiny_tr):
         srv.stop_background(drain=True)
 
 
-def test_multi_step_streams_burst_frames_and_honest_itl(tiny_tr):
-    """ISSUE 16: a decode_steps=4 engine behind the server streams token
-    frames in deterministic ≤k bursts — each frame stamped with `burst` =
-    fresh tokens remaining in its burst including itself — the outputs
-    stay oracle-exact, token_latency charges every post-first token an
-    equal SHARE of its burst gap (count == fresh tokens, no k-times
-    undercount), and the scan dispatch counters surface in metrics."""
+def test_token_frame_has_no_burst_and_token_latency_is_the_gap(tiny_tr):
+    """A token frame is its id, token and index — no `burst` field, no
+    scan counters in `stats` or the metrics text — and the server's
+    token_latency of a request is the gap between its tokens' arrivals
+    from the engine, one sample a fresh token past the first."""
     from paddle_tpu.serving import wire
 
-    eng = _engine(tiny_tr, decode_steps=4)
+    eng = _engine(tiny_tr)
     srv = ServingServer(eng, max_queue=8)
+    arrivals, inner = [], srv._on_token
+
+    def on_token(rid, tok, idx):
+        inner(rid, tok, idx)
+        arrivals.append(srv._routes[rid].t_last)
+
+    eng.on_token = on_token
     host, port = srv.start_background()
     try:
         import socket
@@ -281,46 +286,28 @@ def test_multi_step_streams_burst_frames_and_honest_itl(tiny_tr):
         try:
             wire.write_frame_sync(sock, wire.hello_msg("client"))
             assert wire.read_frame_sync(sock)["role"] == "replica"
-            # max_new=9: token 0 from the prefill boundary, then exactly
-            # two full k=4 scanned flushes
-            wire.write_frame_sync(sock, {"type": "generate", "id": "r0",
-                                         "prompt": prompt, "max_new": 9,
-                                         "stream": True})
-            frames = []
-            while True:
-                msg = wire.read_frame_sync(sock)
-                frames.append(msg)
-                if msg["type"] == "done":
-                    break
+            _generate(sock, "r0", prompt, 9)
+            frames = [m for m, _ in _read_until_terminal(sock, ["r0"])]
         finally:
             sock.close()
-        toks = [f for f in frames if f["type"] == "token"]
-        done = frames[-1]
+        toks, done = frames[:-1], frames[-1]
         assert done["reason"] == "length"
         assert done["tokens"] == _oracle(tiny_tr, prompt, 9)
         assert [f["token"] for f in toks] == done["tokens"][len(prompt):]
-        # the burst countdown: first token rides its own 1-burst (the
-        # prefill boundary), then two scanned flushes of 4
-        assert [f["burst"] for f in toks] == [1, 4, 3, 2, 1, 4, 3, 2, 1]
-        assert eng.n_scan_flushes == 2 and eng.n_scan_steps == 8
+        assert all(f.keys() == {"type", "id", "token", "index"}
+                   for f in toks)
 
         with ServingClient(host, port) as c:
             s = c.stats()
-            assert s["decode_steps_k"] == 4
-            assert s["scan_flushes"] == 2 and s["scan_steps"] == 8
+            assert not {"decode_steps_k", "scan_steps", "scan_flushes"} \
+                & s.keys()
+            assert s["decode_steps"] == eng.n_decode_steps
             text = c.metrics()
-            vals = {}
-            for line in text.splitlines():
-                if line and not line.startswith("#"):
-                    key, v = line.rsplit(" ", 1)
-                    vals[key] = float(v)
-        assert vals["serving_scan_steps_total"] == 8.0
-        assert vals["serving_scan_flushes_total"] == 2.0
-        # burst-honest accounting: EVERY fresh post-first token charged
-        # token_latency exactly once (8 = 9 generated - the first)
-        assert vals['serving_latency_count{stat="token_latency"}'] == 8.0
-        assert vals['serving_latency_count'
-                    '{stat="first_token_latency"}'] == 1.0
+        assert "serving_scan_" not in text
+        lat = srv.stats.get("token_latency")
+        assert lat.count == 8 and len(arrivals) == 9
+        assert lat.samples == [b - a for a, b in zip(arrivals, arrivals[1:])]
+        assert srv.stats.get("first_token_latency").count == 1
         eng.kv.check_reclaimed()
     finally:
         srv.stop_background(drain=True)
@@ -1368,7 +1355,7 @@ def test_pump_and_engine_phase_spans_nest_on_the_profilers_clock(
     assert eng.n_lookahead_steps >= busy - 4   # ... one call later
 
 
-@pytest.mark.parametrize("kind", ["decode", "mixed", "scan", "spec"])
+@pytest.mark.parametrize("kind", ["decode", "mixed", "spec"])
 def test_engine_ring_spans_name_the_step_kind(tiny_tr, kind):
     """The ring (the operator's sink) gets the same phases: one compiled-
     step span a step, named by the kind the scheduler chose, between
@@ -1378,7 +1365,7 @@ def test_engine_ring_spans_name_the_step_kind(tiny_tr, kind):
     t = Tracer()
     t.enabled = True
     from paddle_tpu.ops.pallas_paged import tile_rows
-    kw = {"scan": {"decode_steps": 4}, "spec": {"spec_k": 2}}.get(kind, {})
+    kw = {"spec": {"spec_k": 2}}.get(kind, {})
     eng = ServingEngine(tiny_tr.executor, tiny_tr.params, num_slots=2,
                         page_size=8, max_context=64, tracer=t, **kw)
     rng = np.random.default_rng(1)
@@ -1389,7 +1376,7 @@ def test_engine_ring_spans_name_the_step_kind(tiny_tr, kind):
     names = [s["name"] for s in lane]
     assert "pt.step." + kind in names, names
     steps = [s for s in lane if s["name"] in (
-        "pt.step.decode", "pt.step.mixed", "pt.step.scan", "pt.step.spec")]
+        "pt.step.decode", "pt.step.mixed", "pt.step.spec")]
     assert len(steps) == eng.n_decode_steps
     assert [s["attrs"]["step"] for s in steps] == \
         list(range(1, len(steps) + 1))
@@ -1398,8 +1385,8 @@ def test_engine_ring_spans_name_the_step_kind(tiny_tr, kind):
         assert names.count("pt.step.draft") >= eng.n_draft_steps > 0
     # per step, in the order the spans close (a span is recorded when it
     # ends): admit, plan, dispatch, then a decode or mixed step's own span
-    # (its launch) BEFORE the readback and the emit of its land; a scanned
-    # or verify step's span still holds its readback
+    # (its launch) BEFORE the readback and the emit of its land; a verify
+    # step's span still holds its readback
     per_step = [n for n in names if n != "pt.step.draft"]
     i = per_step.index("pt.step.plan") - 1
     first = steps[0]["name"]
@@ -1414,16 +1401,15 @@ def test_engine_ring_spans_name_the_step_kind(tiny_tr, kind):
     assert not re.search(r"\bt_step\b|tracer\.add\(\"\w+_step\"", src)
 
 
-@pytest.mark.parametrize("kind", ["decode", "mixed", "scan", "spec"])
+@pytest.mark.parametrize("kind", ["decode", "mixed", "spec"])
 def test_kv_read_counters_follow_each_step_kind(tiny_tr, kind):
     """`kv_tokens_attended` / `kv_tokens_fetched` grow by what the step's
-    rows read: pos + 1 a decode row (1 for an empty slot), every body of
-    a scanned dispatch at the position it had then, every packed row of a
-    mixed or verify step (a padding row reads 1), and a whole block a
-    row — here the 64 tokens the table maps — or once a run of one slot's
-    rows in a tile (`kv_shared_rows` of `kv_rows`)."""
+    rows read: pos + 1 a decode row (1 for an empty slot), every packed
+    row of a mixed or verify step (a padding row reads 1), and a whole
+    block a row — here the 64 tokens the table maps — or once a run of one
+    slot's rows in a tile (`kv_shared_rows` of `kv_rows`)."""
     from paddle_tpu.ops.pallas_paged import tile_rows
-    kw = {"scan": {"decode_steps": 4}, "spec": {"spec_k": 2}}.get(kind, {})
+    kw = {"spec": {"spec_k": 2}}.get(kind, {})
     eng = ServingEngine(tiny_tr.executor, tiny_tr.params, num_slots=2,
                         page_size=8, max_context=64, **kw)
     assert eng._kv_block == 64
@@ -1439,21 +1425,14 @@ def test_kv_read_counters_follow_each_step_kind(tiny_tr, kind):
         pos = [None if sl is None else sl.pos for sl in eng.slots]
         before = (eng.kv_tokens_attended, eng.kv_tokens_fetched,
                   eng.n_decode_steps, eng.n_mixed_steps, eng.n_spec_steps,
-                  eng.n_scan_flushes, eng.tokens_generated,
                   eng.n_kv_rows, eng.n_kv_shared_rows)
         eng.step()
         att = eng.kv_tokens_attended - before[0]
         fetched = eng.kv_tokens_fetched - before[1]
-        rows = eng.n_kv_rows - before[7]
-        shared = eng.n_kv_shared_rows - before[8]
+        rows = eng.n_kv_rows - before[5]
+        shared = eng.n_kv_shared_rows - before[6]
         if eng.n_decode_steps == before[2]:
             assert att == fetched == 0          # no compiled step ran
-        elif eng.n_scan_flushes > before[5]:
-            seen.add("scan")
-            ran = eng.tokens_generated - before[6]
-            want = sum(pos[0] + min(i, ran) + 1 for i in range(4)) + 4
-            assert (att, fetched) == (want, 4 * S * 64)
-            assert (rows, shared) == (4 * S, 0)
         elif eng.n_mixed_steps > before[3] or eng.n_spec_steps > before[4]:
             seen.add("spec" if eng.n_spec_steps > before[4] else "mixed")
             # a block a row alone, and ONE a run of one slot's rows in a
@@ -1592,7 +1571,7 @@ def _record_per_token_path(srv) -> dict:
         if fresh and st.stream:
             rec.setdefault(st.cid, []).append(wire.encode(
                 {"type": "token", "id": st.cid, "token": int(tok),
-                 "index": int(idx), "burst": st.burst_left + 1}))
+                 "index": int(idx)}))
 
     srv.engine.on_token = on_token
     return rec
@@ -1606,16 +1585,15 @@ def _generate(sock, cid, prompt, max_new, **kw):
                                  "max_new": max_new, "stream": True, **kw})
 
 
-_KIND_KW = {"decode": {}, "mixed": {"num_slots": 4},
-            "scan": {"decode_steps": 4}, "spec": {"spec_k": 2}}
+_KIND_KW = {"decode": {}, "mixed": {"num_slots": 4}, "spec": {"spec_k": 2}}
 
 
-@pytest.mark.parametrize("kind", ["decode", "mixed", "scan", "spec"])
+@pytest.mark.parametrize("kind", ["decode", "mixed", "spec"])
 def test_batched_delivery_is_byte_identical_per_request(tiny_tr, kind):
     """N concurrent streams over ONE connection: the bytes the socket
     carries are, per request, exactly `wire.encode` of the frames the
-    per-token path produced — indexes 0..n-1 in order, the same `burst`
-    fields, `done` last — whatever kind of step banked them."""
+    per-token path produced — indexes 0..n-1 in order, `done` last —
+    whatever kind of step banked them."""
     import socket
 
     eng = _engine(tiny_tr, **_KIND_KW[kind])
@@ -1641,7 +1619,6 @@ def test_batched_delivery_is_byte_identical_per_request(tiny_tr, kind):
         srv.stop_background(drain=True)
     assert {"mixed": eng.n_mixed_steps >= 4,
             "decode": eng.n_decode_steps > eng.n_mixed_steps,
-            "scan": eng.n_scan_flushes > 0,
             "spec": eng.n_spec_steps > 0}[kind]
     for i, (p, n) in enumerate(zip(prompts, news)):
         mine = [(m, raw) for m, raw in got if m["id"] == f"r{i}"]
@@ -1651,8 +1628,6 @@ def test_batched_delivery_is_byte_identical_per_request(tiny_tr, kind):
         assert [m["index"] for m, _ in toks] == list(range(n))
         assert [m["token"] for m, _ in toks] == done["tokens"][len(p):]
         assert done["tokens"] == _oracle(tiny_tr, p, n)
-    if kind == "scan":
-        assert max(m.get("burst", 0) for m, _ in got) == 4
     assert srv.n_token_frames == sum(news)
     assert srv._outbox == [] and srv._tok_lat == []
 
@@ -1858,8 +1833,8 @@ def test_slow_reader_is_still_severed_at_max_write_buffer(tiny_tr, path):
     from paddle_tpu.serving import wire
     from paddle_tpu.serving.server import _Conn
 
-    frames = [{"type": "token", "id": "r", "token": 5, "index": i,
-               "burst": 1} for i in range(3)]
+    frames = [{"type": "token", "id": "r", "token": 5, "index": i}
+              for i in range(3)]
     slow = _StubWriter(buffered=wire.FrameConn.MAX_WRITE_BUFFER + 1)
     ok = _StubWriter(buffered=wire.FrameConn.MAX_WRITE_BUFFER)
     if path == "send_many":

@@ -7,6 +7,8 @@ stream, same sampler semantics, same eos early-stop — while the compiled
 decode step stays at ONE jit signature for the whole workload and prompt
 prefill compiles once per feeder bucket, not per length."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -16,11 +18,22 @@ from paddle_tpu.config.parser import parse_config
 from paddle_tpu.graph.lm_decode import lm_generate
 from paddle_tpu.serving import PagedKVCache, Request, ServingEngine
 from paddle_tpu.trainer.trainer import Trainer
+from tests.conftest import lm_oracle
 
 
+@functools.lru_cache(maxsize=None)
 def _make(args: str):
+    """One Trainer a set of --config-args a module: tests of one model
+    share its executor, so its engines (tests/conftest.py `engines`) and
+    lm_generate's compiled programs."""
     cfg = parse_config("demo/model_zoo/transformer_lm.py", args)
     return Trainer(cfg, seed=7)
+
+
+TINY = "vocab=11,dim=16,layers=1,heads=2,batch_size=3"
+# two slots and pages of 4 in a context of 16, wherever the geometry is not
+# the test's subject
+GEOM = dict(num_slots=2, page_size=4, max_context=16, prefill_chunk=-1)
 
 
 def _prompts(lens, vocab, seed=0):
@@ -29,11 +42,7 @@ def _prompts(lens, vocab, seed=0):
 
 
 def _oracle(tr, req: Request):
-    toks, lens = lm_generate(
-        tr.executor, tr.params, req.prompt_ids[None, :],
-        max_new=req.max_new, temperature=req.temperature, top_k=req.top_k,
-        top_p=req.top_p, eos_id=req.eos_id, rng=req.rng, use_cache=True)
-    return np.asarray(toks)[0, :int(np.asarray(lens)[0])]
+    return lm_oracle(tr.executor, tr.params, req)
 
 
 def _assert_pool_reclaimed(eng):
@@ -51,7 +60,11 @@ def _assert_all_match(tr, reqs, results):
                     f"lm_generate(use_cache=True) oracle")
 
 
-def test_engine_matches_per_request_oracle_greedy():
+# the 2-layer model's engine: three slots refill from six requests
+WIDE = dict(num_slots=3, page_size=8, max_context=64, prefill_chunk=-1)
+
+
+def test_engine_matches_per_request_oracle_greedy(engines):
     """Mixed prompt lengths and max_new across more requests than slots:
     freed slots refill mid-flight, tokens stay per-request exact, and the
     whole workload runs through ONE compiled decode signature."""
@@ -59,14 +72,14 @@ def test_engine_matches_per_request_oracle_greedy():
     prompts = _prompts((3, 9, 5, 12, 7, 4), 61)
     reqs = [Request(i, p, max_new=m)
             for i, (p, m) in enumerate(zip(prompts, (5, 7, 3, 6, 8, 2)))]
-    eng = ServingEngine(tr.executor, tr.params, num_slots=3, page_size=8,
-                        max_context=64)
+    eng = engines(tr.executor, tr.params, **WIDE)
+    steps0 = eng.n_decode_steps
     results = eng.run(reqs)
     _assert_all_match(tr, reqs, results)
     # jit cache inspection (the test_fused_dispatch discipline): the decode
     # step compiled exactly once for the whole mixed workload
     assert eng._decode_step._cache_size() == 1
-    assert eng.n_decode_steps > 0
+    assert eng.n_decode_steps > steps0
 
 
 @pytest.mark.parametrize("extra", ["kv_heads=2", "window=5"])
@@ -83,7 +96,7 @@ def test_engine_oracle_gqa_and_window(extra):
     assert eng._decode_step._cache_size() == 1
 
 
-def test_engine_matches_per_request_oracle_sampled():
+def test_engine_matches_per_request_oracle_sampled(engines):
     """Per-request sampling knobs (greedy / top-k / nucleus / full) and
     per-request rng keys, all inside the one compiled step."""
     tr = _make("vocab=61,dim=32,layers=2,heads=4,batch_size=4")
@@ -94,17 +107,16 @@ def test_engine_matches_per_request_oracle_sampled():
              dict(temperature=1.1)]                      # full sampling
     reqs = [Request(i, p, max_new=6, rng=jax.random.PRNGKey(100 + i), **kw)
             for i, (p, kw) in enumerate(zip(prompts, knobs))]
-    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=8,
-                        max_context=64)
+    eng = engines(tr.executor, tr.params, **WIDE)
     results = eng.run(reqs)
     _assert_all_match(tr, reqs, results)
     assert eng._decode_step._cache_size() == 1
 
 
-def test_engine_eos_early_stop_refills_slots():
+def test_engine_eos_early_stop_refills_slots(engines):
     """eos-stopped requests retire their slot early; the freed slot admits
     the next request mid-flight and every output stays oracle-exact."""
-    tr = _make("vocab=11,dim=16,layers=1,heads=2,batch_size=3")
+    tr = _make(TINY)
     prompts = _prompts((6, 4, 5, 3, 6, 4), 11, seed=3)
     # eos = the first token request 0 greedily emits, so at least one
     # request is guaranteed to stop early
@@ -113,8 +125,7 @@ def test_engine_eos_early_stop_refills_slots():
     eos = int(np.asarray(t0)[0, prompts[0].size])
     reqs = [Request(i, p, max_new=8, eos_id=eos)
             for i, p in enumerate(prompts)]
-    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=8,
-                        max_context=32)
+    eng = engines(tr.executor, tr.params, **dict(GEOM, max_context=32))
     results = eng.run(reqs)
     _assert_all_match(tr, reqs, results)
     assert eng._decode_step._cache_size() == 1
@@ -123,28 +134,31 @@ def test_engine_eos_early_stop_refills_slots():
                for r in reqs)
 
 
-def test_overcommitted_pool_preempts_and_stays_exact():
+# 2 slots x 4 pages would want 8; 5 real pages force preemption
+TIGHT = dict(GEOM, num_pages=6)
+
+
+def test_overcommitted_pool_preempts_and_stays_exact(engines):
     """A pool smaller than the worst case forces pauses/preemptions; the
     deterministic per-request key schedule makes them invisible in the
     output — tokens still match the oracle exactly, and every page returns
     to the free list."""
-    tr = _make("vocab=11,dim=16,layers=1,heads=2,batch_size=3")
+    tr = _make(TINY)
     prompts = _prompts((6, 4, 5, 3, 6), 11, seed=3)
     reqs = [Request(i, p, max_new=8) for i, p in enumerate(prompts)]
-    # 2 slots x 4 pages would want 8; give 5 real pages
-    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
-                        max_context=16, num_pages=6)
+    eng = engines(tr.executor, tr.params, **TIGHT)
+    preempted0 = eng.n_preemptions
     results = eng.run(reqs)
     _assert_all_match(tr, reqs, results)
-    assert eng.n_preemptions > 0, "pool was never actually overcommitted"
+    assert eng.n_preemptions > preempted0, \
+        "pool was never actually overcommitted"
     _assert_pool_reclaimed(eng)
     assert eng._decode_step._cache_size() == 1
 
 
-def test_request_validation():
-    tr = _make("vocab=11,dim=16,layers=1,heads=2,batch_size=3")
-    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
-                        max_context=16)
+def test_request_validation(engines):
+    tr = _make(TINY)
+    eng = engines(tr.executor, tr.params, **GEOM)
     with pytest.raises(ValueError, match="temperature"):
         Request(0, [3, 4], max_new=4, top_k=5)
     with pytest.raises(ValueError, match="slot capacity"):
@@ -168,7 +182,7 @@ def test_pool_too_small_to_complete_is_rejected():
     """A request whose worst-case footprint (prompt + max_new - 1 tokens)
     exceeds the whole pool can never finish — preemption would just replay
     it forever once it is alone.  add_request must reject it up front."""
-    tr = _make("vocab=11,dim=16,layers=1,heads=2,batch_size=3")
+    tr = _make(TINY)
     eng = ServingEngine(tr.executor, tr.params, num_slots=1, page_size=4,
                         max_context=32, num_pages=4)   # 3 real pages
     with pytest.raises(ValueError, match="pages to complete"):
@@ -185,7 +199,7 @@ def test_failed_admission_releases_partial_page_grab():
     return them: a later retry can land on a DIFFERENT free slot, and
     pages stranded on the first one would leak the pool and strand the
     queued request forever."""
-    tr = _make("vocab=11,dim=16,layers=1,heads=2,batch_size=3")
+    tr = _make(TINY)
     # 5 real pages, ps=4: A (prompt 14 -> 4 pages, max_new=3) fills slot 0;
     # B (prompt 17 -> 5 pages, max_new=2) must wait for A, then take the
     # whole pool — regardless of which slot the retry lands on
@@ -200,15 +214,17 @@ def test_failed_admission_releases_partial_page_grab():
     _assert_pool_reclaimed(eng)
 
 
-def test_run_returns_only_its_own_completions_and_pools_stay_live():
+ROOMY = dict(num_slots=2, page_size=8, max_context=32, prefill_chunk=-1)
+
+
+def test_run_returns_only_its_own_completions_and_pools_stay_live(engines):
     """A long-lived engine: each run() pops exactly the requests that
     completed on its watch (no bleed from earlier workloads, no unbounded
     result archive), and kv.pools always points at live buffers (the
     donating jits must rebind it, not leave deleted aliases)."""
     tr = _make("vocab=31,dim=16,layers=1,heads=2,batch_size=4")
     prompts = _prompts((4, 7), 31, seed=6)
-    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=8,
-                        max_context=32)
+    eng = engines(tr.executor, tr.params, **ROOMY)
     first = eng.run([Request("a", prompts[0], max_new=3)])
     assert set(first) == {"a"}
     # the donated-and-rebound pool must still be readable
@@ -219,7 +235,8 @@ def test_run_returns_only_its_own_completions_and_pools_stay_live():
     assert not eng.results, "completed results were retained after run()"
 
 
-def test_cancel_inflight_frees_slot_and_pages_and_survivors_stay_exact():
+def test_cancel_inflight_frees_slot_and_pages_and_survivors_stay_exact(
+        engines):
     """Client-initiated cancellation mid-flight: the victim's slot and
     pages return to the pool immediately (accounting back to baseline at
     the end), its partial tokens are an exact PREFIX of its oracle run,
@@ -228,8 +245,8 @@ def test_cancel_inflight_frees_slot_and_pages_and_survivors_stay_exact():
     tr = _make("vocab=31,dim=16,layers=1,heads=2,batch_size=4")
     prompts = _prompts((5, 9, 4, 7), 31, seed=4)
     reqs = [Request(i, p, max_new=8) for i, p in enumerate(prompts)]
-    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=8,
-                        max_context=32)
+    eng = engines(tr.executor, tr.params, **ROOMY)
+    cancelled0 = eng.n_cancelled
     for r in reqs:
         eng.add_request(r)
     for _ in range(3):                     # get the first wave mid-flight
@@ -259,7 +276,7 @@ def test_cancel_inflight_frees_slot_and_pages_and_survivors_stay_exact():
     _assert_all_match(tr, survivors, results)
     _assert_pool_reclaimed(eng)
     assert eng._decode_step._cache_size() == 1
-    assert eng.n_cancelled == 1
+    assert eng.n_cancelled - cancelled0 == 1
 
 
 def test_deadline_expiry_frees_pages_for_waiting_requests():
@@ -290,16 +307,17 @@ def test_deadline_expiry_frees_pages_for_waiting_requests():
     assert eng._decode_step._cache_size() == 1
 
 
-def test_cancel_and_deadline_on_queued_requests():
+def test_cancel_and_deadline_on_queued_requests(engines, monkeypatch):
     """A queued (never-admitted) request cancels/expires cleanly: result
     is the bare prompt, no slot or page was ever touched."""
-    tr = _make("vocab=11,dim=16,layers=1,heads=2,batch_size=3")
-    eng = ServingEngine(tr.executor, tr.params, num_slots=1, page_size=4,
-                        max_context=16)
-    eng.clock = lambda: float(eng.n_decode_steps)
+    tr = _make(TINY)
+    eng = engines(tr.executor, tr.params, **dict(GEOM, num_slots=1))
+    monkeypatch.setattr(eng, "clock", lambda: float(eng.n_decode_steps))
+    expired0, cancelled0 = eng.n_expired, eng.n_cancelled
     run = Request("run", [3, 4, 5], max_new=4)
     q_cancel = Request("qc", [4, 5], max_new=4)
-    q_expire = Request("qe", [5, 6], max_new=4, deadline=0.0)  # born dead
+    q_expire = Request("qe", [5, 6], max_new=4,
+                       deadline=eng.clock())                   # born dead
     eng.add_request(run)
     eng.add_request(q_cancel)
     eng.add_request(q_expire)
@@ -308,30 +326,33 @@ def test_cancel_and_deadline_on_queued_requests():
     assert eng.finish_reasons["qc"] == "cancelled"
     results = eng.run()
     np.testing.assert_array_equal(results["qe"], [5, 6])
-    assert eng.n_expired == 1 and eng.n_cancelled == 1
+    assert eng.n_expired - expired0 == 1
+    assert eng.n_cancelled - cancelled0 == 1
     np.testing.assert_array_equal(_oracle(tr, run), results["run"])
     assert not eng.cancel("nonexistent")
 
 
-def test_cancel_of_preempted_queued_request_keeps_streamed_tokens():
+def test_cancel_of_preempted_queued_request_keeps_streamed_tokens(
+        engines, monkeypatch):
     """A preempted request waits in the queue with its generated-so-far
     rolled back; cancelling it THERE must still report the tokens that
     were already emitted (a front end streamed them to the client — the
     done frame has to agree with the stream) and restore the
     tokens_generated accounting the preempt rollback subtracted."""
-    tr = _make("vocab=11,dim=16,layers=1,heads=2,batch_size=3")
+    tr = _make(TINY)
     prompts = _prompts((6, 4, 5), 11, seed=3)
     reqs = [Request(i, p, max_new=8) for i, p in enumerate(prompts)]
-    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
-                        max_context=16, num_pages=6)
+    eng = engines(tr.executor, tr.params, **TIGHT)
+    eng.reset_prefix_cache()        # the other test's prompts are these
+    preempted0 = eng.n_preemptions
     streamed: dict = {}
-    eng.on_token = lambda rid, tok, idx: streamed.setdefault(
-        rid, {}).update({idx: tok})
+    monkeypatch.setattr(eng, "on_token", lambda rid, tok, idx:
+                        streamed.setdefault(rid, {}).update({idx: tok}))
     for r in reqs:
         eng.add_request(r)
-    while eng.n_preemptions == 0 and eng.step():
+    while eng.n_preemptions == preempted0 and eng.step():
         pass
-    assert eng.n_preemptions > 0, "pool was never overcommitted"
+    assert eng.n_preemptions > preempted0, "pool was never overcommitted"
     victim = eng.queue[0]              # preemption requeues at the front
     stash = list(victim._preempted_gen)
     assert stash, "preempted request carried no generated-token stash"
@@ -355,15 +376,14 @@ def test_cancel_of_preempted_queued_request_keeps_streamed_tokens():
     _assert_pool_reclaimed(eng)
 
 
-def test_cancel_mid_replay_reports_all_previously_streamed_tokens():
+def test_cancel_mid_replay_reports_all_previously_streamed_tokens(engines):
     """Preempt a request that already emitted k tokens, re-admit it, and
     cancel while the deterministic replay is still short of k: the result
     must carry all k originally-delivered tokens (replay and original are
     identical prefixes of one stream) and re-bank the not-yet-replayed
     remainder in tokens_generated."""
-    tr = _make("vocab=11,dim=16,layers=1,heads=2,batch_size=3")
-    eng = ServingEngine(tr.executor, tr.params, num_slots=1, page_size=4,
-                        max_context=16)
+    tr = _make(TINY)
+    eng = engines(tr.executor, tr.params, **dict(GEOM, num_slots=1))
     r = Request("r", [3, 4, 5], max_new=8)
     eng.add_request(r)
     for _ in range(3):       # mixed(chunk+token 0) + 2 decode: gen = 3
@@ -388,19 +408,18 @@ def test_cancel_mid_replay_reports_all_previously_streamed_tokens():
     _assert_pool_reclaimed(eng)
 
 
-def test_finish_hooks_fire_once_per_token_and_request():
+def test_finish_hooks_fire_once_per_token_and_request(engines, monkeypatch):
     """on_token sees every emitted token exactly once (index = position in
     the generated stream), on_finish exactly once per request with the
     final array — the contract serving/server.py streams through."""
-    tr = _make("vocab=11,dim=16,layers=1,heads=2,batch_size=3")
-    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
-                        max_context=16)
+    tr = _make(TINY)
+    eng = engines(tr.executor, tr.params, **GEOM)
     seen_toks: dict = {}
     finishes: dict = {}
-    eng.on_token = lambda rid, tok, idx: seen_toks.setdefault(
-        rid, []).append((idx, tok))
-    eng.on_finish = lambda rid, toks, reason: finishes.setdefault(
-        rid, (toks, reason))
+    monkeypatch.setattr(eng, "on_token", lambda rid, tok, idx:
+                        seen_toks.setdefault(rid, []).append((idx, tok)))
+    monkeypatch.setattr(eng, "on_finish", lambda rid, toks, reason:
+                        finishes.setdefault(rid, (toks, reason)))
     reqs = [Request(i, p, max_new=m) for i, (p, m) in
             enumerate(zip(_prompts((3, 5, 4), 11, seed=7), (4, 6, 1)))]
     results = eng.run(reqs)
@@ -419,7 +438,7 @@ def test_finish_hooks_fire_once_per_token_and_request():
 def test_paged_kv_allocator():
     """Page accounting: grow on demand, pause on exhaustion, release on
     retire; page 0 stays reserved as the trash page."""
-    tr = _make("vocab=11,dim=16,layers=1,heads=2,batch_size=3")
+    tr = _make(TINY)
     kv = PagedKVCache(tr.executor, num_slots=2, page_size=4,
                       pages_per_slot=3, num_pages=5)   # 4 real pages
     assert kv.free_page_count == 4

@@ -20,9 +20,9 @@ import pytest
 import jax
 
 from paddle_tpu.config.parser import parse_config
-from paddle_tpu.graph.lm_decode import lm_generate
 from paddle_tpu.serving import NgramDrafter, Request, ServingEngine
 from paddle_tpu.trainer.trainer import Trainer
+from tests.conftest import lm_oracle
 
 
 @pytest.fixture(scope="module")
@@ -34,12 +34,25 @@ def tr():
     return Trainer(cfg, seed=7)
 
 
+# the geometry of nearly every test here; with `spec_k=3` it is the module's
+# one speculative engine (tests/conftest.py `engines`), counters read as
+# differences
+GEOM = dict(num_slots=2, page_size=4, max_context=32, prefill_chunk=-1)
+KNOBS = {"greedy": dict(), "top-k": dict(temperature=0.8, top_k=5),
+         "nucleus": dict(temperature=0.7, top_p=0.9),
+         "full": dict(temperature=1.1)}
+
+def _cold(eng):
+    """A shared engine with its allocator and prefix index cold, as a fresh
+    one's are: a draft's tail takes FREE pages only (`try_grow(evict=
+    False)`), so over a pool an earlier test left full of cached prefixes
+    no chain would verify a draft."""
+    eng.reset_prefix_cache()
+    return eng
+
+
 def _oracle(tr, req: Request):
-    toks, lens = lm_generate(
-        tr.executor, tr.params, req.prompt_ids[None, :],
-        max_new=req.max_new, temperature=req.temperature, top_k=req.top_k,
-        top_p=req.top_p, eos_id=req.eos_id, rng=req.rng, use_cache=True)
-    return np.asarray(toks)[0, :int(np.asarray(lens)[0])]
+    return lm_oracle(tr.executor, tr.params, req)
 
 
 def _assert_exact(tr, reqs, results):
@@ -72,36 +85,45 @@ def _assert_sigs(eng):
 # the bit-exact oracle across sampling knobs / GQA / TP
 # ---------------------------------------------------------------------------
 
-def test_spec_on_equals_spec_off_across_sampling_knobs(tr):
+@pytest.fixture(scope="module")
+def on_and_off(tr, engines):
     """All four sampling modes (greedy / top-k / nucleus / full), mixed
-    repetitive prompt lengths: the speculative engine's tokens are
-    bit-identical to the sequential engine's AND to the lm_generate
-    oracle, with at least one draft genuinely accepted (the accept path
-    ran, not just the reject path) and the signature set pinned."""
-    rng = np.random.default_rng(0)
-    knobs = [dict(), dict(temperature=0.8, top_k=5),
-             dict(temperature=0.7, top_p=0.9), dict(temperature=1.1)]
-
+    repetitive prompt lengths, served together once by the sequential
+    engine and once by the speculative one: at least one draft genuinely
+    accepted (the accept path ran, not just the reject path) and the
+    signature set pinned."""
     def reqs():
-        return [Request(f"r{i}", _rep_prompt(rng2, 23, 11 + 2 * i),
-                        max_new=8, rng=jax.random.PRNGKey(40 + i), **kw)
-                for i, (rng2, kw) in enumerate(
-                    (np.random.default_rng(100 + j), k)
-                    for j, k in enumerate(knobs))]
+        return {name: Request(f"r{i}", _rep_prompt(
+                                  np.random.default_rng(100 + i), 23,
+                                  11 + 2 * i),
+                              max_new=8, rng=jax.random.PRNGKey(40 + i), **kw)
+                for i, (name, kw) in enumerate(KNOBS.items())}
 
-    kw = dict(num_slots=2, page_size=4, max_context=32)
-    base = ServingEngine(tr.executor, tr.params, **kw).run(reqs())
-    eng = ServingEngine(tr.executor, tr.params, spec_k=3, **kw)
-    spec = eng.run(reqs())
+    base = engines(tr.executor, tr.params, **GEOM).run(
+        list(reqs().values()))
+    eng = _cold(engines(tr.executor, tr.params, spec_k=3, **GEOM))
+    drafted0, accepted0 = eng.n_spec_drafted, eng.n_spec_accepted
+    spec = eng.run(list(reqs().values()))
     assert set(base) == set(spec)
-    for k in base:
-        np.testing.assert_array_equal(base[k], spec[k], err_msg=str(k))
-    _assert_exact(tr, reqs(), spec)
-    assert eng.n_spec_drafted > 0 and eng.n_spec_accepted > 0, \
+    drafted = eng.n_spec_drafted - drafted0
+    accepted = eng.n_spec_accepted - accepted0
+    assert drafted > 0 and accepted > 0, \
         "the workload never exercised the accept path"
-    assert eng.n_spec_accepted <= eng.n_spec_drafted
+    assert accepted <= drafted
     _assert_sigs(eng)
     eng.kv.check_reclaimed()
+    return reqs(), base, spec
+
+
+@pytest.mark.parametrize("knobs", sorted(KNOBS))
+def test_spec_on_equals_spec_off_across_sampling_knobs(tr, on_and_off,
+                                                       knobs):
+    """The speculative engine's tokens are bit-identical to the sequential
+    engine's AND to the lm_generate oracle, in each sampling mode."""
+    reqs, base, spec = on_and_off
+    r = reqs[knobs]
+    np.testing.assert_array_equal(base[r.req_id], spec[r.req_id])
+    _assert_exact(tr, [r], spec)
 
 
 def test_spec_gqa_grouped_heads_stay_exact():
@@ -164,7 +186,7 @@ def test_spec_tp_model2_host_mesh_stays_exact():
 # the distributional claim: fixed-key acceptance IS lm_generate's law
 # ---------------------------------------------------------------------------
 
-def test_rejection_sampled_acceptance_matches_lm_generate_law(tr):
+def test_rejection_sampled_acceptance_matches_lm_generate_law(tr, engines):
     """The rejection-sampling equivalence at fixed keys: across many rng
     keys, full-distribution sampling through the speculative engine
     emits EXACTLY what lm_generate samples with the same key schedule —
@@ -175,8 +197,7 @@ def test_rejection_sampled_acceptance_matches_lm_generate_law(tr):
     single stream matches.)"""
     rng = np.random.default_rng(6)
     prompt = _rep_prompt(rng, 23, 10)
-    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
-                        max_context=32, spec_k=3)
+    eng = _cold(engines(tr.executor, tr.params, spec_k=3, **GEOM))
     accepted_any = 0
     for seed in range(10):
         # odd keys sample the FULL distribution (the law at maximum
@@ -204,26 +225,27 @@ def test_rejection_sampled_acceptance_matches_lm_generate_law(tr):
 # composition: prefix cache, chunked prefill, preempt/replay
 # ---------------------------------------------------------------------------
 
-def test_spec_with_prefix_hits_and_cow_stays_exact(tr):
+def test_spec_with_prefix_hits_and_cow_stays_exact(tr, engines):
     """Prefix-cache hits + mid-page COW divergence under speculation:
     followers map the donor's pages, diverge inside the boundary page,
     and speculate over their own committed tokens — all bit-exact, with
     the donor page surviving for an exact repeat."""
     rng = np.random.default_rng(7)
     base_p = _rep_prompt(rng, 23, 13)
-    eng = ServingEngine(tr.executor, tr.params, num_slots=2, page_size=4,
-                        max_context=32, spec_k=3)
+    eng = _cold(engines(tr.executor, tr.params, spec_k=3, **GEOM))
+    hits0, cow0, drafted0 = (eng.n_prefix_hits, eng.kv.n_cow,
+                             eng.n_spec_drafted)
     a = Request("a", base_p.copy(), max_new=6)
     results = eng.run([a])
     b = Request("b", np.concatenate(
         [base_p[:11], (base_p[11:13] + 1) % 23 + 2]).astype(np.int32),
         max_new=6)
     results.update(eng.run([b]))
-    assert eng.n_prefix_hits >= 1 and eng.kv.n_cow >= 1
+    assert eng.n_prefix_hits - hits0 >= 1 and eng.kv.n_cow - cow0 >= 1
     again = Request("again", base_p.copy(), max_new=6)
     results.update(eng.run([again]))
     _assert_exact(tr, [a, b, again], results)
-    assert eng.n_spec_drafted > 0
+    assert eng.n_spec_drafted > drafted0
     eng.kv.check_reclaimed()
 
 
@@ -578,39 +600,41 @@ def test_engine_asserts_on_drafter_clamp_violation(tr):
         eng.run([Request("r", _rep_prompt(rng, 23, 8), max_new=6)])
 
 
-def test_model_drafter_self_spec_exact_and_one_signature(tr):
-    """Self-speculation end to end: ModelDrafter.from_target drafting
-    for ALL slots in one batched dispatch, with dynamic k on — tokens
-    bit-identical to the spec-off engine and the lm_generate oracle across all four sampling modes, the
-    accept path genuinely exercised (greedy self-drafts agree with the
-    greedy target), and EXACTLY ONE serving.draft_step signature for
-    the whole workload (dynamic k rides as data)."""
-    rng = np.random.default_rng(0)
-    knobs = [dict(), dict(temperature=0.8, top_k=5),
-             dict(temperature=0.7, top_p=0.9), dict(temperature=1.1)]
+@pytest.fixture(scope="module")
+def self_spec_engine(tr, engines):
+    """`self_spec_engine()`: the module's one self-speculating engine,
+    ModelDrafter.from_target drafting for ALL slots in one batched
+    dispatch, with dynamic k on."""
+    drafter = ModelDrafter.from_target(tr.executor, tr.params, window=16)
+    return lambda: _cold(engines(tr.executor, tr.params, spec_k=3,
+                                 spec_dynamic=True, drafter=drafter, **GEOM))
 
+
+@pytest.fixture(scope="module")
+def self_spec(tr, engines, self_spec_engine):
+    """The four sampling modes served together once by the spec-off engine
+    and once by the self-speculating one: the accept path genuinely
+    exercised (greedy self-drafts agree with the greedy target), and
+    EXACTLY ONE serving.draft_step signature for the whole workload
+    (dynamic k rides as data)."""
     def reqs():
-        return [Request(f"m{i}", _rep_prompt(np.random.default_rng(200 + i),
-                                             23, 9 + 2 * i),
-                        max_new=8, rng=jax.random.PRNGKey(60 + i), **kw)
-                for i, kw in enumerate(knobs)]
+        return {name: Request(f"m{i}", _rep_prompt(
+                                  np.random.default_rng(200 + i), 23,
+                                  9 + 2 * i),
+                              max_new=8, rng=jax.random.PRNGKey(60 + i), **kw)
+                for i, (name, kw) in enumerate(KNOBS.items())}
 
-    kw = dict(num_slots=2, page_size=4, max_context=32)
-    base = ServingEngine(tr.executor, tr.params, **kw).run(reqs())
+    base = engines(tr.executor, tr.params, **GEOM).run(
+        list(reqs().values()))
     cw = get_compile_watch()
     sigs0 = cw.signature_count("serving.draft_step")
     verify0 = cw.signature_count("serving.spec_step")
-    eng = ServingEngine(
-        tr.executor, tr.params, spec_k=3, spec_dynamic=True,
-        drafter=ModelDrafter.from_target(tr.executor, tr.params, window=16),
-        **kw)
-    spec = eng.run(reqs())
+    eng = self_spec_engine()
+    drafts0, accepted0 = eng.n_draft_steps, eng.n_spec_accepted
+    spec = eng.run(list(reqs().values()))
     assert set(base) == set(spec)
-    for k in base:
-        np.testing.assert_array_equal(base[k], spec[k], err_msg=str(k))
-    _assert_exact(tr, reqs(), spec)
     assert eng.drafter_kind == "model"
-    assert eng.n_draft_steps > 0 and eng.n_spec_accepted > 0, \
+    assert eng.n_draft_steps > drafts0 and eng.n_spec_accepted > accepted0, \
         "self-speculation never accepted a draft — greedy agreement " \
         "with the target should be near-certain"
     assert cw.signature_count("serving.draft_step") == sigs0 + 1, \
@@ -620,9 +644,21 @@ def test_model_drafter_self_spec_exact_and_one_signature(tr):
         "ride as data"
     _assert_sigs(eng)
     eng.kv.check_reclaimed()
+    return reqs(), base, spec
 
 
-def test_model_drafter_law_across_ten_keys(tr):
+@pytest.mark.parametrize("knobs", sorted(KNOBS))
+def test_model_drafter_self_spec_exact_and_one_signature(tr, self_spec,
+                                                         knobs):
+    """Self-speculation end to end: tokens bit-identical to the spec-off
+    engine and the lm_generate oracle, in each sampling mode."""
+    reqs, base, spec = self_spec
+    r = reqs[knobs]
+    np.testing.assert_array_equal(base[r.req_id], spec[r.req_id])
+    _assert_exact(tr, [r], spec)
+
+
+def test_model_drafter_law_across_ten_keys(tr, self_spec_engine):
     """The distributional-law matrix with the MODEL drafter: across 10
     rng keys (full-distribution and peaked alternating), the adaptive
     engine (model drafts + dynamic k) emits EXACTLY what lm_generate
@@ -630,10 +666,7 @@ def test_model_drafter_law_across_ten_keys(tr):
     sampling law."""
     rng = np.random.default_rng(15)
     prompt = _rep_prompt(rng, 23, 10)
-    eng = ServingEngine(
-        tr.executor, tr.params, num_slots=2, page_size=4, max_context=32,
-        spec_k=3, spec_dynamic=True,
-        drafter=ModelDrafter.from_target(tr.executor, tr.params, window=16))
+    eng = self_spec_engine()
     accepted_any = 0
     for seed in range(10):
         temp = 1.0 if seed % 2 else 0.05

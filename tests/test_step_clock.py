@@ -234,12 +234,10 @@ def test_every_landed_step_has_one_flight(tr, depth):
     assert "serving_lookahead_steps_total" not in grew      # no twin
 
 
-@pytest.mark.parametrize("kind,kw", [
-    ("spec", {"spec_k": 2}), ("scan", {"decode_steps": 4})])
-def test_spec_and_scan_steps_record_flights_where_they_launched(tr, kind,
-                                                                 kw):
+def test_spec_steps_record_flights_where_they_launched(tr):
+    kind = "spec"
     pc0 = process_counters().snapshot()
-    eng = _engine(tr, 0, max_step_tokens=None, **kw)
+    eng = _engine(tr, 0, max_step_tokens=None, spec_k=2)
     eng.tracer.enabled = True
     prompts = [np.tile(np.random.default_rng(i).integers(2, 61, 4), 4)
                for i in range(3)]
@@ -248,8 +246,7 @@ def test_spec_and_scan_steps_record_flights_where_they_launched(tr, kind,
     flights = [s for s in spans if s["name"] == "pt.step.flight"]
     assert len(flights) == eng.n_decode_steps
     mine = [f for f in flights if f["attrs"]["kind"] == kind]
-    assert len(mine) == (eng.n_spec_steps if kind == "spec"
-                         else eng.n_scan_flushes) > 0
+    assert len(mine) == eng.n_spec_steps > 0
     outer = {s["attrs"]["step"]: s for s in spans
              if s["name"] == "pt.step." + kind}
     for f in mine:                  # the flight lies inside its own step
@@ -257,9 +254,19 @@ def test_spec_and_scan_steps_record_flights_where_they_launched(tr, kind,
         assert f["ts"] == o["ts"] and f["dur"] <= o["dur"]
     grew = _grew(pc0, process_counters().snapshot())
     assert grew[LANDED % kind] == len(mine)
-    if kind == "spec":              # the draft's other taker still gets it
-        assert grew[N % "pt.step.draft"] >= eng.n_draft_steps > 0
-        assert eng.draft_ms_hist.samples()
+    # the draft's other taker still gets it
+    assert grew[N % "pt.step.draft"] >= eng.n_draft_steps > 0
+    assert eng.draft_ms_hist.samples()
+
+
+def test_landed_step_kinds_are_decode_mixed_and_spec():
+    """A step lands as one of three kinds, each with its flight seconds and
+    its landed count in the step clock's counters, and no fourth."""
+    from paddle_tpu.serving import engine
+    kinds = ("decode", "mixed", "spec")
+    assert tuple(engine._FLIGHT_COUNTERS) == kinds
+    assert {k for pair in engine._FLIGHT_COUNTERS.values() for k in pair} \
+        == {c % k for c in (FLIGHT, LANDED) for k in kinds}
 
 
 def test_off_means_off_with_the_clock_running(tr, monkeypatch):
@@ -331,8 +338,7 @@ def test_the_pumps_counters_sum_to_its_wall_time(tr):
     steps = stats["steps"]
     assert set(steps) == {"pump_seconds", "pump_spans", "step_flight_seconds",
                           "steps_landed", "loop_send_seconds", "loop_sends"}
-    assert steps["steps_landed"].keys() <= {"decode", "mixed", "scan",
-                                            "spec"}
+    assert steps["steps_landed"].keys() <= {"decode", "mixed", "spec"}
     assert steps["pump_spans"]["pt.step.readback"] >= landed
     assert step_clock_stats()["pump_spans"]["pt.pump.wait"] >= \
         steps["pump_spans"]["pt.pump.wait"]

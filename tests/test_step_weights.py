@@ -70,8 +70,6 @@ STEP_KINDS = {
     "decode": (dict(prefill_chunk=32), "n_decode_steps"),
     # small chunks: most steps carry chunk rows beside decode rows
     "mixed": (dict(prefill_chunk=4, max_step_tokens=8), "n_mixed_steps"),
-    # k decode bodies in one lax.scan
-    "scan": (dict(decode_steps=3), "n_scan_steps"),
 }
 
 

@@ -61,13 +61,11 @@ def test_hlo_gather_detector_anchors_to_shapes():
 
 def test_hlo_shard_check_decode_has_no_pool_allgather():
     """tools/hlo_shard_check.py on the real engine over a 2-shard host
-    mesh: the tensor-parallel decode, mixed, spec-verify AND multi-step
-    scan programs must contain zero all-gathers of the KV pools or
-    attention projections, and exactly the per-layer post-attention
-    all-reduce — for the scan that count covers ONE body (lax.scan
-    lowers to a while loop; the body appears once in the HLO), the
-    acceptance evidence for the sharded-decode HBM/FLOPs split
-    (docs/serving.md)."""
+    mesh: the tensor-parallel decode, mixed and spec-verify programs must
+    contain zero all-gathers of the KV pools or attention projections,
+    and exactly the per-layer post-attention all-reduce — the acceptance
+    evidence for the sharded-decode HBM/FLOPs split (docs/serving.md).
+    The report holds those three programs and the draft step, no other."""
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     import jax
@@ -80,13 +78,36 @@ def test_hlo_shard_check_decode_has_no_pool_allgather():
         pytest.skip("needs >= 2 devices (conftest provides 8 host devices)")
     out = run_check(model=2, save="")
     assert out["ok"], out["verdict"]
-    for step in ("decode", "mixed", "spec", "scan"):
+    assert set(out["steps"]) == {"decode", "mixed", "spec", "draft"}
+    assert "scan_decode_steps" not in out
+    for step in ("decode", "mixed", "spec"):
         rec = out["steps"][step]
         assert rec["table_all_gathers"] == [], (step, rec)
         assert rec["n_all_gathers"] == 0, \
             (step, "unexpected all-gather — sharded decode must keep ALL "
                    "activations head-local until the out-projection reduce")
         assert rec["n_all_reduces"] == rec["expected_all_reduces"], rec
+
+
+@pytest.mark.parametrize("value,accepted", [("1", True), ("2", False)])
+def test_serve_keeps_decode_steps_for_one_value(value, accepted, capsys):
+    """`--decode-steps` stays a flag of tools/serve.py because every serve
+    configuration's `server_flags` passes it (benchmark/kinds/serve.py turns
+    each key into a flag): 1 parses, and reaches no argument of the engine;
+    any other value is refused by the flag's name, exit code 2."""
+    from tests.model_parity import serve_tool
+    tool, parse = serve_tool()
+    argv = ["--config", "demo/model_zoo/transformer_lm.py",
+            "--decode-steps", value]
+    if accepted:
+        assert parse(argv).decode_steps == 1
+        import inspect
+        assert "decode_steps" not in inspect.getsource(tool.build_engine)
+        return
+    with pytest.raises(SystemExit) as refused:
+        parse(argv)
+    assert refused.value.code == 2
+    assert "--decode-steps" in capsys.readouterr().err
 
 
 def test_check_metrics_names_lint(tmp_path):

@@ -10,13 +10,11 @@ head's pages on every chip) or an attention projection would silently
 forfeit both the HBM win (a model bigger than one chip) and the FLOPs win
 (decode faster than one chip) that sharding exists for.
 
-This tool compiles the REAL engine's decode, mixed, speculative-verify,
-multi-step scan AND batched draft programs (the lax.scan of k decode
-bodies — its body appears ONCE in the HLO, as a while loop, so the
-all-reduce count must match a single body, not k of them; the
-ModelDrafter's draft step must lower with ZERO collectives — its params
-are replicated by contract, so any cross-device op means the
-replication boundary broke) over an N-device mesh,
+This tool compiles the REAL engine's decode, mixed, speculative-verify
+AND batched draft programs (the ModelDrafter's draft step must lower
+with ZERO collectives — its params are replicated by contract, so any
+cross-device op means the replication boundary broke) over an N-device
+mesh,
 inventories every collective in the optimized HLO, flags
 any all-gather whose shape+gather-dim matches a KV pool (kv-head axis),
 an attention projection, a Megatron-split FFN weight, or the row-sharded
@@ -145,16 +143,6 @@ def run_check(model: int = 2, config_args: str = "vocab=61,dim=32,"
         eng._stage(np.zeros((S, eng.spec_k), np.int32)),
         eng._stage(np.zeros(S, bool)), eng._stage(np.zeros(S, bool)),
         eng._stage(np.zeros(S, np.int32))).compile().as_text()
-    # the multi-step SCAN program (decode_steps=k): k decode bodies in
-    # ONE lax.scan, which lowers to a while loop whose body appears ONCE
-    # in the HLO — so the proof obligation is identical to decode's
-    # (zero pool/param all-gathers, exactly the per-body all-reduce
-    # set), NOT k copies of it.  k is a static argument of the jit, so
-    # the program lowers without flipping the engine's dispatch mode.
-    scan_k = 3
-    hlo_scan = eng._scan_step_fn().lower(
-        scan_k, eng.params, eng._build_state(), eng._d_run,
-        eng._d_eos, eng._d_maxnew).compile().as_text()
     # the batched DRAFT step (ModelDrafter): the drafter's replication
     # contract says it holds host/replicated params and compiles with
     # ZERO collectives under any mesh — drafting must never add
@@ -181,13 +169,11 @@ def run_check(model: int = 2, config_args: str = "vocab=61,dim=32,"
            "sharded_params": params_sharded,
            "ffn_pairs_sharded": len(eng._tp_ffn_pairs),
            "lm_head_sharded": bool(eng._tp_lm_head),
-           "scan_decode_steps": scan_k,
            "draft": {"window": drafter.window, "k": draft_k,
                      "kind": drafter.kind}, "steps": {}}
     bad = []
     for step, hlo in (("decode", hlo_decode), ("mixed", hlo_mixed),
-                      ("spec", hlo_spec), ("scan", hlo_scan),
-                      ("draft", hlo_draft)):
+                      ("spec", hlo_spec), ("draft", hlo_draft)):
         colls, gathers, reduces = _collectives(hlo)
         table_gathers = [ln[:200] for ln in gathers
                         if gather_spans_table(ln, tables)]

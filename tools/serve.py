@@ -188,10 +188,6 @@ def build_engine(args):
         print(f"speculative decoding: up to {args.spec_k} drafts/slot/"
               f"step ({args.drafter} drafter{dyn}; emitted tokens "
               f"unchanged)", file=sys.stderr)
-    if args.decode_steps > 1:
-        print(f"multi-step decode: {args.decode_steps} scanned decode "
-              f"bodies per dispatch when pure-decode (emitted tokens "
-              f"unchanged; tokens stream in bursts)", file=sys.stderr)
     if args.spill_budget > 0:
         print(f"KV spill tier: cold cached pages spill to host RAM "
               f"(budget {args.spill_budget} bytes) and restore on "
@@ -206,7 +202,6 @@ def build_engine(args):
                          spec_k=args.spec_k,
                          drafter=drafter,
                          spec_dynamic=args.spec_dynamic,
-                         decode_steps=args.decode_steps,
                          spill_bytes_budget=args.spill_budget,
                          mesh=mesh)
 
@@ -326,13 +321,9 @@ def main(argv=None) -> int:
                          "an accept-rate EWMA picks k in 0..K per slot "
                          "per flush window; low-accept slots degrade to "
                          "plain decode (emitted tokens unchanged)")
-    ap.add_argument("--decode-steps", type=int, default=1,
-                    help="multi-step decode: run K decode bodies per "
-                         "dispatch in ONE jitted lax.scan whenever every "
-                         "live slot is pure-decode (1 = off; emitted "
-                         "tokens are identical either way, streaming "
-                         "arrives in <=K bursts — docs/serving.md "
-                         "'Multi-step decode')")
+    ap.add_argument("--decode-steps", type=int, default=1, choices=(1,),
+                    help="kept for configurations that pass it; 1 is the "
+                         "only value")
     ap.add_argument("--role", choices=["prefill", "decode", "both"],
                     default="both",
                     help="disaggregated prefill/decode placement role, "
