@@ -163,34 +163,54 @@ def compiles_total() -> int:
 
 
 class ProfilerWindow:
-    """jax.profiler around a slice of the window; then the reduction."""
+    """jax.profiler around a slice of the window; then the reduction.  The
+    slice is stamped INTO the trace, as a host event of the benchmark's own
+    (lib/trace.py: WINDOW_EVENT) opened once the profiler has started and
+    closed before it is stopped: the profiler's stop takes a minute with the
+    device still stepping, and every reduction is of the stamped interval."""
 
     def __init__(self, ctx: Ctx):
         self.ctx = ctx
         self.dir = os.path.join(ctx.out_dir, "trace")
-        self.t0 = None
+        self.t0 = self.host_s = self.event = None
 
     def start(self):
         import jax
 
+        from .trace import WINDOW_EVENT
+
         shutil.rmtree(self.dir, ignore_errors=True)
         os.makedirs(self.dir, exist_ok=True)
         jax.profiler.start_trace(self.dir)
+        self.event = jax.profiler.TraceAnnotation(WINDOW_EVENT)
+        self.event.__enter__()
         self.t0 = time.perf_counter()
 
     def stop(self):
+        """On the thread that called start(): the event nests on it."""
         import jax
 
-        self.ctx.trace_window_s = time.perf_counter() - self.t0
+        self.host_s = time.perf_counter() - self.t0
+        self.event.__exit__(None, None, None)
         jax.profiler.stop_trace()
 
     def reduce(self):
-        from .trace import Trace, find_xplane
+        from .trace import Trace, TraceError, find_xplane
 
         t = time.perf_counter()
         tr = Trace.from_xplane(find_xplane(self.dir),
                                cpu_as_device=self.ctx.rehearse)
+        if tr.window_event() is None:
+            raise TraceError("the trace holds no window event: "
+                             f"{json.dumps(tr.describe())[:1500]}")
         self.ctx.trace_data = tr
+        self.ctx.trace_window_s = tr.window_s
         log(f"TRACE planes {json.dumps(tr.describe())[:1500]} "
             f"(read in {time.perf_counter() - t:.1f}s)")
+        # what the trace file holds whenever it ran: its device planes alone
+        whole = Trace({p: tr.planes[p] for p in tr.device_planes()})
+        log(f"TRACE window {tr.window_s:.6f}s on the trace's clock, "
+            f"{self.host_s:.6f}s on the host's; device busy "
+            f"{tr.busy_s():.6f}s in it, {whole.busy_s():.6f}s in the whole "
+            f"file's {whole.window_s:.6f}s")
         return tr

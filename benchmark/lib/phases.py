@@ -7,9 +7,9 @@ or layer>.<phase>` on the same clock as the device planes.  lib/trace.py
 merges the host lines of one name and keeps names only, which is why thread
 and kind are in the span's NAME.
 
-The split: the first device's idle time — the gaps between its busy
-intervals and the traced window's two edges, over `ctx.trace_window_s`, as
-`device_idle_share.*` counts it — goes, piece by piece, to the innermost span
+The split: the first device's idle time — the trace's window (lib/trace.py)
+less its busy intervals, `Trace.idle_intervals`, the very time
+`device_idle_share.*` counts — goes, piece by piece, to the innermost span
 covering it among ONE thread's families: the serving pump's (`pt.pump.*`,
 `pt.engine.*`, `pt.step.*`, `pt.kv.*`) or the trainer loop's (`pt.train.*`).
 Those nest on their thread; `pt.loop.*` and `pt.feeder.*` run on other
@@ -45,8 +45,7 @@ GROUPS = {
         # while the host waits for the tokens; the step span's own time is
         # the bookkeeping between its two children
         "launch": (("pt.step.dispatch", "pt.step.readback"),
-                   ("pt.step.decode", "pt.step.mixed", "pt.step.scan",
-                    "pt.step.spec")),
+                   ("pt.step.decode", "pt.step.mixed", "pt.step.spec")),
     },
     "train": {
         "input": (("pt.train.next_batch", "pt.train.stage"), ()),
@@ -100,21 +99,6 @@ def innermost(spans: list) -> list:
     return out
 
 
-def idle_gaps(trace: Trace, window_s: float) -> list:
-    """The first device's idle (start, end) pieces inside the traced window:
-    the gaps between its busy intervals plus the window's two edges.  Trace
-    times count from the session's start, which is the window's; a window
-    that would not hold the busy span is moved to hold it."""
-    busy = trace.busy_intervals(trace.device_planes()[0])
-    window = int(window_s * 1e9)
-    first, last = busy[0][0], busy[-1][1]
-    w0 = min(first, max(0, last - window))
-    w1 = w0 + window
-    edges = [w0] + [t for iv in busy for t in iv] + [w1]
-    gaps = [(max(a, w0), min(b, w1)) for a, b in zip(edges[0::2], edges[1::2])]
-    return [(a, b) for a, b in gaps if b > a]
-
-
 def split(gaps: list, pieces: list) -> dict:
     """Nanoseconds of `gaps` under each name of `pieces` (both sorted and
     disjoint); what no piece covers goes to NO_SPAN."""
@@ -139,12 +123,16 @@ def split(gaps: list, pieces: list) -> dict:
 class Phases:
     """One family's spans in one trace, and the idle split by them."""
 
-    def __init__(self, trace: Trace, window_s: float, family: str):
+    def __init__(self, trace: Trace, family: str):
         self.family = family
-        self.window_s = float(window_s)
-        self.spans = span_events(trace, FAMILIES[family])
+        self.window_s = trace.window_s
+        self.window = w0, w1 = trace.window
+        # the spans that reach into the window; what the file holds before
+        # the stamp or behind it belongs to no reading
+        self.spans = [sp for sp in span_events(trace, FAMILIES[family])
+                      if sp[0] < w1 and sp[1] > w0]
         self.names = {name for _, _, name in self.spans}
-        gaps = idle_gaps(trace, window_s)
+        gaps = trace.idle_intervals(trace.device_planes()[0])
         self.idle_ns = sum(b - a for a, b in gaps)
         self.idle_by_span = split(gaps, innermost(self.spans))
 
@@ -157,7 +145,7 @@ class Phases:
             return None
         cache = ctx.__dict__.setdefault("_phases", {})
         if family not in cache:
-            ph = cls(ctx.trace_data, ctx.trace_window_s, family)
+            ph = cls(ctx.trace_data, family)
             cache[family] = ph if ph.spans else None
             if ph.spans:
                 rows = sorted(ph.idle_by_span.items(), key=lambda kv: -kv[1])
@@ -196,11 +184,14 @@ class Phases:
                                      for g in GROUPS[self.family])
 
     def durations(self, name: str) -> list:
-        """Seconds of every span called `name` (any thread's)."""
+        """Seconds of every span called `name` (any thread's) that starts
+        in the window, whole."""
         if name not in self.names:
             raise PhaseError(f"no span {name!r} in the trace; the "
                              f"{self.family} family has {sorted(self.names)}")
-        return [(e - s) / 1e9 for s, e, n in self.spans if n == name]
+        w0, w1 = self.window
+        return [(e - s) / 1e9 for s, e, n in self.spans
+                if n == name and w0 <= s < w1]
 
 
 def median_ms(ctx, family: str, name: str):

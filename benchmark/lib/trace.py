@@ -7,6 +7,16 @@ jax.profiler.ProfileData reads it with nothing but JAX.  The reduction works
 on a plain structure {plane: {line: [[name, start_ns, dur_ns], ...]}} so a
 small recorded trace can be kept as JSON and checked on the CPU.
 
+Every reduction is of ONE interval on the trace's own clock, the window: the
+host event WINDOW_EVENT that lib/common.py:ProfilerWindow opens once the
+profiler has started and closes before it is stopped (the profiler's stop
+runs for a minute with the device still stepping, and the file holds the
+first tenths of a second of those ops, with no host span beside them), or
+the `window=` a hand-made trace is given, or with neither the first op's
+start to the last op's end.  Busy, idle and collective time are
+clipped to it; a kernel's time, the top operations and a step's count are of
+the ops that START in it, whole, so that no per-call time is cut at an edge.
+
 A name pattern that matches no event is an error, never a zero."""
 
 from __future__ import annotations
@@ -19,6 +29,7 @@ import re
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 HOST_PLANE = re.compile(r"^/host:")
+WINDOW_EVENT = "bench.window"
 COLLECTIVE = re.compile(
     r"all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all",
     re.I)
@@ -69,8 +80,11 @@ def _union(intervals):
 
 
 class Trace:
-    def __init__(self, planes: dict):
+    def __init__(self, planes: dict, window: tuple | None = None):
         self.planes = planes
+        self._window = window
+        self._busy: dict = {}
+        self._started: dict = {}
 
     # -- loading ---------------------------------------------------------
     @classmethod
@@ -100,9 +114,9 @@ class Trace:
         return cls(planes)
 
     @classmethod
-    def from_json(cls, path: str) -> "Trace":
+    def from_json(cls, path: str, window: tuple | None = None) -> "Trace":
         with open(path) as f:
-            return cls(json.load(f))
+            return cls(json.load(f), window)
 
     def sample(self, max_events: int = 400) -> dict:
         """A small cut of this trace (the first events of every line), for
@@ -128,58 +142,113 @@ class Trace:
     def ops(self, plane: str) -> list:
         return self.planes[plane][OPS_LINE]
 
-    def busy_intervals(self, plane: str) -> list:
-        return _union((s, s + d) for _, s, d in self.ops(plane) if d > 0)
+    # -- the window ------------------------------------------------------
+    def window_event(self):
+        """(start_ns, end_ns) of the host's WINDOW_EVENT, None without one;
+        more than one is an error."""
+        evs = [(s, s + d) for p, lines in self.planes.items()
+               if HOST_PLANE.match(p) for es in lines.values()
+               for name, s, d in es if name == WINDOW_EVENT]
+        if len(evs) > 1:
+            raise TraceError(f"{len(evs)} {WINDOW_EVENT!r} events in one "
+                             f"trace: {evs[:4]}")
+        return evs[0] if evs else None
+
+    @property
+    def window(self) -> tuple:
+        """(w0, w1) in the trace's ns: as given, else the window event,
+        else the first op's start to the last op's end."""
+        if self._window is None:
+            self._window = self.window_event()
+        if self._window is None:
+            ops = [(s, s + d) for p in self.device_planes()
+                   for _, s, d in self.ops(p) if d > 0]
+            self._window = (min(s for s, _ in ops), max(e for _, e in ops))
+        return self._window
+
+    @property
+    def window_s(self) -> float:
+        w0, w1 = self.window
+        return (w1 - w0) / 1e9
+
+    def ops_in_window(self, plane: str) -> list:
+        """The plane's ops that START inside the window."""
+        if plane not in self._started:
+            w0, w1 = self.window
+            self._started[plane] = [e for e in self.ops(plane)
+                                    if w0 <= e[1] < w1]
+        return self._started[plane]
+
+    def busy_intervals(self, plane: str, pattern=None) -> list:
+        """The union of the plane's op intervals (of the ops `pattern`
+        finds), clipped to the window."""
+        if (plane, pattern) not in self._busy:
+            w0, w1 = self.window
+            self._busy[plane, pattern] = _union(
+                (max(s, w0), min(s + d, w1)) for n, s, d in self.ops(plane)
+                if d > 0 and s < w1 and s + d > w0
+                and (pattern is None or pattern.search(n)))
+        return self._busy[plane, pattern]
+
+    def idle_intervals(self, plane: str) -> list:
+        """The window less the plane's busy intervals: the gaps between
+        them and the window's two edges, as (start, end)."""
+        w0, w1 = self.window
+        edges = [w0] + [t for iv in self.busy_intervals(plane)
+                        for t in iv] + [w1]
+        return [(a, b) for a, b in zip(edges[0::2], edges[1::2]) if b > a]
+
+    def _seconds(self, pattern=None) -> float:
+        planes = self.device_planes()
+        return sum(e - s for p in planes
+                   for s, e in self.busy_intervals(p, pattern)) \
+            / 1e9 / len(planes)
 
     def busy_s(self) -> float:
-        """Seconds in which an operation ran on the device: the union of
-        the op intervals, averaged over the chips used."""
-        per = [sum(e - s for s, e in self.busy_intervals(p)) / 1e9
-               for p in self.device_planes()]
-        return sum(per) / len(per)
+        """Seconds of the window in which an operation ran on the device,
+        averaged over the chips used: never above `window_s`."""
+        return self._seconds()
 
     def span_s(self) -> float:
-        """First op start to last op end on the device planes, seconds."""
-        lo = min(iv[0][0] for iv in map(self.busy_intervals,
-                                        self.device_planes()))
-        hi = max(iv[-1][1] for iv in map(self.busy_intervals,
-                                         self.device_planes()))
-        return (hi - lo) / 1e9
+        """First op start to last op end inside the window, seconds."""
+        ivs = [iv for iv in map(self.busy_intervals, self.device_planes())
+               if iv]
+        return (max(iv[-1][1] for iv in ivs)
+                - min(iv[0][0] for iv in ivs)) / 1e9
 
     def kernel(self, pattern: str) -> dict:
-        """Summed device time and call count of the ops whose name matches
-        `pattern`, averaged over the chips used."""
+        """Summed device time and call count of the ops that start in the
+        window and whose name matches `pattern`, averaged over the chips
+        used."""
         rx = re.compile(pattern)
         planes = self.device_planes()
         total_ns = calls = 0
         for p in planes:
-            for name, _, d in self.ops(p):
+            for name, _, d in self.ops_in_window(p):
                 if rx.search(name):
                     total_ns += d
                     calls += 1
         if calls == 0:
             seen = sorted({_op_family(n) for p in planes
-                           for n, _, _ in self.ops(p)})
-            raise TraceError(f"pattern {pattern!r} matches no device op; "
-                             f"op names in the trace: {seen[:60]}")
+                           for n, _, _ in self.ops_in_window(p)})
+            raise TraceError(f"pattern {pattern!r} matches no device op "
+                             f"that starts in the window; op names there: "
+                             f"{seen[:60]}")
         return {"seconds": total_ns / 1e9 / len(planes),
                 "calls": calls / len(planes)}
 
     def collective_s(self) -> float:
-        """Seconds a collective holds the core's op line (on a TPU core ops
-        run one at a time, so compute does not run beside it): the exposed
-        part of the collectives, averaged over the chips."""
-        planes = self.device_planes()
-        ivs = [_union((s, s + d) for n, s, d in self.ops(p)
-                      if COLLECTIVE.search(n)) for p in planes]
-        return sum(e - s for iv in ivs for s, e in iv) / 1e9 / len(planes)
+        """Seconds of the window a collective holds the core's op line (on
+        a TPU core ops run one at a time, so compute does not run beside
+        it): the exposed part of the collectives, averaged over the chips."""
+        return self._seconds(COLLECTIVE)
 
     # -- breakdown -------------------------------------------------------
     def top_ops(self, n: int = 10) -> list:
         agg: dict = {}
         planes = self.device_planes()
         for p in planes:
-            for name, _, d in self.ops(p):
+            for name, _, d in self.ops_in_window(p):
                 key = _op_family(name)
                 agg[key] = agg.get(key, 0) + d
         rows = sorted(agg.items(), key=lambda kv: -kv[1])[:n]
@@ -190,20 +259,18 @@ class Trace:
         for p, lines in self.planes.items():
             if HOST_PLANE.match(p):
                 for ln, es in lines.items():
-                    evs.extend((s, s + d, name) for name, s, d in es if d > 0)
+                    evs.extend((s, s + d, name) for name, s, d in es
+                               if d > 0 and name != WINDOW_EVENT)
         evs.sort()
         return evs
 
     def idle_gaps(self, n: int = 10) -> list:
-        """The idle time of the first device, by what the host was doing:
-        each gap between two busy intervals goes to the innermost host event
-        (the shortest one) that covers the gap's middle."""
+        """The idle time of the first device in the window, by what the
+        host was doing: each idle interval goes to the innermost host event
+        (the shortest one) that covers its middle."""
         import bisect
 
-        plane = self.device_planes()[0]
-        busy = self.busy_intervals(plane)
-        gaps = [(busy[i][1], busy[i + 1][0]) for i in range(len(busy) - 1)
-                if busy[i + 1][0] > busy[i][1]]
+        gaps = self.idle_intervals(self.device_planes()[0])
         gaps = sorted(gaps, key=lambda g: g[0] - g[1])[:2000]
         host = self._host_events()
         starts = [h[0] for h in host]

@@ -11,9 +11,9 @@ import types
 import pytest
 
 from benchmark.lib.phases import (GROUPS, NO_SPAN, PhaseError, Phases,
-                                  idle_gaps, innermost, kernel_ms_per_step,
-                                  median_ms, span_events, split)
-from benchmark.lib.trace import Trace
+                                  innermost, kernel_ms_per_step, median_ms,
+                                  span_events, split)
+from benchmark.lib.trace import WINDOW_EVENT, Trace
 
 MS = 1_000_000      # ns
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
@@ -56,8 +56,15 @@ class Ctx(types.SimpleNamespace):
     pass
 
 
-def serve_ctx():
-    return Ctx(trace_data=Trace(SERVE), trace_window_s=0.050)
+def ctx_of(planes, window=None):
+    """A run's context as ProfilerWindow.reduce() leaves it: the trace, and
+    the length of ITS window."""
+    tr = planes if isinstance(planes, Trace) else Trace(planes, window)
+    return Ctx(trace_data=tr, trace_window_s=tr.window_s)
+
+
+def serve_ctx(window=(0, 50 * MS)):
+    return ctx_of(SERVE, window)
 
 
 def test_innermost_pieces_of_nested_spans():
@@ -72,11 +79,24 @@ def test_innermost_pieces_of_nested_spans():
 
 
 def test_idle_gaps_are_the_gaps_and_the_windows_two_edges():
-    tr = Trace(SERVE)
-    assert idle_gaps(tr, 0.050) == [(0, 5 * MS), (15 * MS, 26 * MS),
-                                    (36 * MS, 46 * MS)]
-    # a window shorter than the busy span is moved to hold its end
-    assert idle_gaps(tr, 0.040)[0] == (15 * MS, 26 * MS)
+    tr = Trace(SERVE, window=(0, 50 * MS))
+    assert tr.idle_intervals("/device:TPU:0") == [
+        (0, 5 * MS), (15 * MS, 26 * MS), (36 * MS, 46 * MS)]
+    # a window shorter than the busy span stays where it was stamped and
+    # cuts the ops that cross it: nothing is moved to hold the span's end
+    tr = Trace(SERVE, window=(10 * MS, 50 * MS))
+    assert tr.idle_intervals("/device:TPU:0") == [
+        (15 * MS, 26 * MS), (36 * MS, 46 * MS)]
+    assert tr.busy_s() == pytest.approx(0.019)
+    # ... and the window is the host's own event, where the trace has one
+    stamped = {"/device:TPU:0": SERVE["/device:TPU:0"],
+               "/host:CPU": {"python3": SERVE["/host:CPU"]["python3"],
+                             "tracer": [[WINDOW_EVENT, 30 * MS, 25 * MS]]}}
+    tr = Trace(stamped)
+    assert tr.window == (30 * MS, 55 * MS)
+    assert tr.idle_intervals("/device:TPU:0") == [
+        (36 * MS, 46 * MS), (50 * MS, 55 * MS)]
+    assert Phases(tr, "serve").idle_pct() == pytest.approx(60.0)
     assert split([(0, 10)], [(2, 4, "x"), (6, 20, "y")]) == \
         {"x": 2, "y": 4, NO_SPAN: 4}
 
@@ -115,6 +135,22 @@ def test_the_shares_sum_to_the_idle_share():
     assert Phases.of(ctx, "serve") is ph          # made once a run
 
 
+def test_steps_are_counted_by_the_spans_that_start_in_the_window():
+    # [18,42): the first step's emit reaches in, the second step starts in
+    # it, the third's commands and engine.step do and its decode span (44)
+    # does not
+    ph = Phases.of(serve_ctx((18 * MS, 42 * MS)), "serve")
+    assert len(ph.durations("pt.step.mixed")) == 1
+    assert "pt.step.decode" not in ph.names     # 4-16 and 44-56
+    assert len(ph.durations("pt.step.emit")) == 1          # 36, not 16
+    assert len(ph.durations("pt.engine.step")) == 2        # 21 and 41
+    # idle [18,26) + [36,42) of 24 ms; the first step's emit holds 18-19
+    assert ph.idle_pct() == pytest.approx(100 * 14 / 24)
+    assert ph.idle_by_span["pt.step.emit"] == 4 * MS
+    # a window that no span of the family reaches has nothing to read
+    assert Phases.of(serve_ctx((70 * MS, 80 * MS)), "serve") is None
+
+
 def test_spans_of_other_threads_are_read_beside_the_split():
     ctx = serve_ctx()
     ph = Phases.of(ctx, "serve")
@@ -141,8 +177,7 @@ def test_an_unknown_name_raises_never_a_zero():
                "/host:CPU": {"python3": [
                    e for e in SERVE["/host:CPU"]["python3"]
                    if e[0] != "pt.step.emit"]}}
-    ph = Phases.of(Ctx(trace_data=Trace(no_emit), trace_window_s=0.05),
-                   "serve")
+    ph = Phases.of(ctx_of(no_emit, (0, 50 * MS)), "serve")
     with pytest.raises(PhaseError, match="none of the spans"):
         ph.idle_share("emit")
     with pytest.raises(PhaseError):
@@ -154,10 +189,10 @@ def test_an_unknown_name_raises_never_a_zero():
 
 
 def test_a_share_above_105_percent_raises():
-    ph = Phases.of(Ctx(trace_data=Trace(SERVE), trace_window_s=0.010),
-                   "serve")
-    # the window is "10 ms" but the spans cover 26 ms of gaps: the window
-    # and the trace disagree, and that is an error, not 100%
+    ph = Phases.of(serve_ctx((0, 10 * MS)), "serve")
+    assert ph.idle_pct() == pytest.approx(50.0)
+    # idle time is the window less the busy time in it, so it cannot pass
+    # the window; a split that did would be an error, not 100%
     ph.idle_ns = 26 * MS
     with pytest.raises(PhaseError, match="of the traced window"):
         ph.idle_pct()
@@ -169,7 +204,7 @@ def test_a_program_without_spans_has_nothing_to_read():
     bare = {"/device:TPU:0": SERVE["/device:TPU:0"],
             "/host:CPU": {"python3": [["$engine.py:1056 step", 0, 5 * MS],
                                       ["bench.engine_step", 0, 5 * MS]]}}
-    ctx = Ctx(trace_data=Trace(bare), trace_window_s=0.05)
+    ctx = ctx_of(bare, (0, 50 * MS))
     assert Phases.of(ctx, "serve") is None
     assert Phases.of(ctx, "train") is None
     assert median_ms(ctx, "serve", "pt.step.decode") is None
@@ -214,7 +249,7 @@ TRAIN = {
 
 
 def test_the_train_split_and_the_kernels_per_step():
-    ctx = Ctx(trace_data=Trace(TRAIN), trace_window_s=0.100)
+    ctx = ctx_of(TRAIN, (0, 100 * MS))
     ph = Phases.of(ctx, "train")
     # chip 0 is idle [0,10) + [40,42) + [80,100) = 32 ms of 100:
     # [0,10): no span 2, next_batch 3, stage 2, dispatch 3
@@ -237,8 +272,7 @@ def test_every_new_reader_reads_the_hand_made_traces(bench):
     """The twelve readers of this PR, through spec.py's own loader: each
     declares the layer, unit and end-to-end metric BENCHMARK.json gives it,
     reads a number from the family's trace and None from a bare one."""
-    serve, train = serve_ctx(), Ctx(trace_data=Trace(TRAIN),
-                                    trace_window_s=0.100)
+    serve, train = serve_ctx(), ctx_of(TRAIN, (0, 100 * MS))
     bare = Ctx(trace_data=None, trace_window_s=None)
     got = {}
     for m in bench.per_layer.values():
@@ -272,8 +306,10 @@ TRAIN_CUT_S = 0.62717131
 
 
 def cut_ctx(name, window_s):
-    return Ctx(trace_data=Trace.from_json(os.path.join(DATA, name)),
-               trace_window_s=window_s)
+    """The cuts were recorded before the window was stamped: theirs ran from
+    the trace's 0 for the host's `window_s`."""
+    return ctx_of(Trace.from_json(os.path.join(DATA, name),
+                                  window=(0, round(window_s * 1e9))))
 
 
 def test_recorded_serve_cut_splits_the_idle_share():
@@ -338,11 +374,14 @@ def test_recorded_train_cut_puts_the_idle_under_the_drain():
                 (r"flash_fwd", r"flash_bwd_dq", r"flash_bwd_dkv"))
     assert three == pytest.approx(
         tr.kernel(r"\[tpu_custom_call\]")["seconds"])
+    # ONE step starts in the cut's window (the fifth began 411 ms before
+    # it), so a step's count is 1 where the whole file's spans gave 2
+    assert len(ph.durations("pt.train.step")) == 1
     assert kernel_ms_per_step(ctx, r"flash_fwd.*\[tpu_custom_call\]") == \
-        pytest.approx(56.217, abs=0.01)
+        pytest.approx(2 * 56.217, abs=0.01)
     assert kernel_ms_per_step(
         ctx, r"flash_bwd_(dq|dkv).*\[tpu_custom_call\]") == \
-        pytest.approx(88.251, abs=0.01)
+        pytest.approx(2 * 88.251, abs=0.01)
 
 
 def test_the_new_readers_read_the_recorded_cuts(bench):
@@ -359,3 +398,52 @@ def test_the_new_readers_read_the_recorded_cuts(bench):
                     if k.startswith("idle_") and k.endswith("." + family))
         idle = bench.reader(f"device_idle_share.{family}").read(ctx)
         assert total == pytest.approx(idle, abs=0.01)
+
+
+# ---------------------------------------------------------------------------
+# one window, one idle time: device_idle_share.* and the split read the same
+# interval wherever the window lies against the busy span
+# ---------------------------------------------------------------------------
+
+def _cut(name):
+    with open(os.path.join(DATA, name)) as f:
+        return json.load(f)
+
+
+TRACES = {"SERVE": (lambda: SERVE, "serve"),
+          "TRAIN": (lambda: TRAIN, "train"),
+          "serve_cut": (lambda: _cut("v5e_serve_pt_spans.json"), "serve"),
+          "train_cut": (lambda: _cut("v5e_train_pt_spans.json"), "train")}
+# the window's two ends, as shares of the busy span (first op to last op)
+WHERE = {"at": (0.0, 1.0), "before": (-0.3, 0.5), "after": (0.5, 1.3),
+         "around": (-0.1, 1.1), "inside": (0.2, 0.7)}
+
+
+@pytest.mark.parametrize("where", WHERE)
+@pytest.mark.parametrize("name", TRACES)
+def test_the_idle_share_and_the_split_are_one_number(bench, name, where):
+    make, family = TRACES[name]
+    # the split reads the first device; the share averages the chips
+    one = {p: ls for p, ls in make().items() if p != "/device:TPU:1"}
+    lo, hi = Trace(one).window
+    a, b = WHERE[where]
+    ctx = ctx_of(one, (round(lo + a * (hi - lo)), round(lo + b * (hi - lo))))
+    idle = bench.reader(f"device_idle_share.{family}").read(ctx)
+    ph = Phases.of(ctx, family)
+    assert 0.0 <= idle <= 100.0
+    assert ph.idle_pct() == pytest.approx(idle, abs=1e-9)
+    assert sum(ph.idle_by_span.values()) == ph.idle_ns
+    assert ctx.trace_data.busy_s() <= ctx.trace_window_s
+    if where in ("at", "around"):           # every group's span is there
+        total = sum(ph.idle_share(g) for g in GROUPS[family]) + \
+            ph.idle_unattributed_share()
+        assert total == pytest.approx(idle, abs=1e-9)
+
+
+def test_across_chips_the_share_is_the_mean_and_the_split_the_first(bench):
+    ctx = ctx_of(TRAIN, (5 * MS, 95 * MS))
+    # chip 0 idle [5,10) + [40,42) + [80,95) = 22 ms; chip 1 [5,10) +
+    # [36,42) + [68,95) = 38 ms, of 90
+    assert bench.reader("device_idle_share.train").read(ctx) == \
+        pytest.approx(100 * 30 / 90)
+    assert Phases.of(ctx, "train").idle_pct() == pytest.approx(100 * 22 / 90)

@@ -73,7 +73,10 @@ def test_rehearsal_prints_the_contracts_last_line(root, bench, cell, trace):
     if trace:
         names = {m["name"] for m in bench.per_layer_for(cell)}
         assert set(out["metrics"]) <= names and out["metrics"]
-        assert out["device"]["busy_s"] > 0 and out["device"]["window_s"] > 0
+        # busy time is of the stamped window: never above it
+        assert 0 < out["device"]["busy_s"] <= out["device"]["window_s"]
+        assert [ln for ln in p.stdout.splitlines()
+                if ln.startswith("TRACE window ")]
         assert len(out["breakdown"]["device_ops"]) <= 10
         assert len(out["breakdown"]["idle_gaps"]) <= 10
     else:
