@@ -20,15 +20,19 @@ cost layer, scaled by attrs['aux_weight'].
 
 A caller that hands in a state entry for the layer (the serving engine)
 gets back `pairs` [rows, E_held]: which held experts each row was routed
-to — the load counters' source.  The entry may say which rows are `live`
-([rows] bool; a mixed step's padding rows are not): the grouped form
-routes only those — the padding rows of a step are all alike, so they
-would all fill the same experts' slots.
+to — the load counters' source — and `overflow_tiles`, the tiles the
+grouped form's overflow loop ran in this call (0 under the dense form).
+The entry may say which rows are `live` ([rows] bool; a mixed step's
+padding rows are not): the grouped form routes only those — the padding
+rows of a step are all alike, so they would all fill the same experts'
+slots.
 
 Which of the expert block's two formulations a program runs is
 `expert_form_of`: parallel/moe.py's rule given this layer's shapes, its
-mesh and its mode.  The layer asks it when it is traced; the serving engine
-asks the same function what its step programs were traced to.
+mesh and its mode — grouped from `_GROUPED_OVER_RIDGE` times the chip's
+ridge on, the first round's slots (`first_round_slots`) a function of the
+same shapes.  The layer asks it when it is traced; the serving engine asks
+the same function what its step programs were traced to.
 """
 
 from __future__ import annotations
@@ -42,7 +46,8 @@ from paddle_tpu.graph.context import ForwardContext
 from paddle_tpu.graph.registry import register_layer
 from paddle_tpu.parallel.mesh import MODEL_AXIS, axis_size
 from paddle_tpu.parallel.moe import (expert_activation, expert_form,
-                                     moe_ffn)
+                                     first_round_slots, moe_ffn,
+                                     overflow_tiles)
 from paddle_tpu.parameter.argument import Argument
 
 
@@ -107,7 +112,11 @@ def moe_layer(ctx: ForwardContext, cfg: LayerConfig) -> Argument:
             else:
                 y = y + activation(v @ shared[0]) @ shared[1]
     if ctx.state_in.get(cfg.name) is not None:
-        ctx.state_out[cfg.name] = {"pairs": pairs}
+        # the grouped form's `pairs` are of the rows it routed (`valid`)
+        tiles = jnp.int32(0) if form == "dense" else jnp.sum(overflow_tiles(
+            jnp.sum(pairs, axis=0, dtype=jnp.int32), first_round_slots(
+                v.shape[0], int(a.get("top_k", 2)), w_router.shape[-1])))
+        ctx.state_out[cfg.name] = {"pairs": pairs, "overflow_tiles": tiles}
     if seq_shape is not None:
         y = y.reshape(seq_shape + (y.shape[-1],))
     if aux_w > 0 and ctx.is_training:

@@ -83,6 +83,13 @@ CATALOG: dict[str, str] = {
     "serving_moe_pairs_max_total":
         "per step, the busiest held expert's routed pairs (summed over the "
         "MoE layers), summed over steps",
+    "serving_moe_layer_pairs_max_total":
+        "per step, the busiest held expert's routed pairs in its busiest "
+        "MoE LAYER, summed over steps",
+    "serving_moe_overflow_tiles_total":
+        "tiles of 128 slots the grouped form's overflow loop ran (pairs "
+        "beyond an expert's first-round slots, summed over the MoE layers): "
+        "over serving_moe_steps_total, the tiles a step",
     "serving_moe_steps_total":
         "compiled steps whose routed pairs were counted",
     "serving_moe_grouped_steps_total":

@@ -47,30 +47,40 @@ a program is traced (`expert_form`; nothing selects one from outside):
     2 and 4 routed pairs an expert).  XLA partitions its einsums where the
     stacked weights shard over the `model` mesh axis.
   * GROUPED: each routed pair of a held expert takes a slot of that
-    expert (its rank among the expert's pairs, by a stable sort); the
-    slots' rows of `x` are gathered `[E_held, slots, D]`; each expert
-    multiplies its own slots — batched einsums against the stacked weights
-    exactly as they are stored —; each pair's output is weighed by its
-    routing weight and the top_k results of a row are added in float32.
-    `_GROUP_SLOTS` slots an expert a round and as many rounds as the
-    busiest expert needs, so nothing is dropped.  Work is slots x E_held a
-    round, under the ridge: a round takes the weights' read, whatever the
-    rows.
+    expert (its rank among the expert's pairs, by a stable sort).  THE FIRST
+    ROUND is whole and unconditional: every held expert's first
+    `first_round_slots` slots (twice the mean load in half tiles of 64 and
+    at least 128 — 128 in every cell but Xing's mixed step, 192: a function
+    of the call's rows, top_k and experts scored), the slots' rows of `x`
+    gathered `[E_held, slots, D]`, each expert multiplying its own slots —
+    batched einsums against the stacked weights exactly as they are stored
+    —, each pair's output weighed by its routing weight and the top_k
+    results of a row added in float32.  Under the ridge the round takes
+    the weights' read, whatever the rows.  THE OVERFLOW — the pairs an expert draws
+    beyond its first-round slots — goes expert by expert: a loop over
+    (expert, tile of `_GROUP_SLOTS` slots) of the experts that have such
+    pairs alone, each tile reading that ONE expert's weights (a dynamic
+    slice of the stack) for its at most 128 rows and adding its weighted
+    results to their rows in float32.  Nothing is dropped whatever the
+    skew, and an overflowing expert costs its own weights' read a tile,
+    not every held expert's.
 
 THE RULE (`expert_form`): past the ridge the dense form is compute-bound
 on products of which E/top_k - 1 parts in E/top_k are multiplied by zero
 — with 64 held experts of 3 x 2048 x 1536 and 512 rows (`lfm2-24b-serve.
 long-output-256`'s mixed step, 32 pairs an expert) 3.7 ms a layer, 14.8
 of a step's 21.2, against 5.9 ms to read the experts' 4.83 GB — so the grouped form
-runs from `_GROUPED_OVER_RIDGE` times the ridge on for each round it
-expects to need (the measurement beside the constant), the dense form
-below it, under a `model`-axis mesh (XLA partitions the dense einsums; the
-grouped form's gathers have not been tried there) and in training (its
-loop over rounds has no reverse mode).
+runs from `_GROUPED_OVER_RIDGE` times the ridge on (the measurement beside
+the constant; a first round wider than the ridge counts as the reads it
+is worth), the dense form below it, under a `model`-axis mesh (XLA
+partitions the dense einsums; the grouped form's gathers have not been
+tried there) and in training (its loop over the overflow has no reverse
+mode).
 """
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -157,7 +167,7 @@ def combine_weights(idx: Array, weight: Array, first_expert: int,
 _RIDGE_ROWS_PER_BYTE = 120
 
 # The grouped form runs where the rows are at least `_GROUPED_OVER_RIDGE`
-# times the ridge for each round it expects to need.  One layer's call on
+# times the ridge (`expert_form`).  One layer's call on
 # the v5e, bf16 gated experts, ms dense -> grouped, beside the weights' read
 # at 819 GB/s (my chip runs, PR 38: tools/moe_forms.py, medians of 5 x 10
 # calls, the same to 0.01 ms in four calls of the tool):
@@ -178,19 +188,67 @@ _RIDGE_ROWS_PER_BYTE = 120
 # slower than the dense form.
 _GROUPED_OVER_RIDGE = 1.25
 
-#: slots an expert has in one round of the grouped form: one MXU tile's
-#: height.  Under the ridge a round is bound by the weights' read whatever
-#: its slots hold (ms a layer at 512 x 64 held: 64 slots 1.91, 96 1.95, 128
-#: 1.96, 192 2.14; my chip runs, PR 38) and a round more costs the whole
-#: read again, so the slots are sized for the busiest expert, not the mean:
-#: 128 is four times the mean load of LFM2's mixed step (32 pairs an expert)
+#: slots of one tile of the grouped form: one MXU tile's height.  The first
+#: round's slots are whole and half tiles of it (`first_round_slots`), an
+#: overflow tile is one.  Under the ridge the first round is bound by the
+#: weights' read whatever its slots hold (ms a layer at 512 x 64 held: 64
+#: slots 1.91, 96 1.95, 128 1.96, 192 2.14; my chip runs, PR 38); an
+#: overflow tile reads ONE expert's weights for its at most 128 rows.
 _GROUP_SLOTS = 128
+
+# The first round holds `_FIRST_ROUND_OVER_MEAN` mean loads (rows x top_k /
+# experts scored) an expert, in half tiles and at least one tile: slots the
+# busiest expert leaves empty are paid in the round's gathers and products,
+# pairs beyond them in overflow tiles.  One layer's call at the Xing cell's
+# mixed step — 1,088 rows x 64 held of 3 x 3584 x 1024, 68 pairs an expert
+# on the mean, the weights' read 1.72 ms — on the v5e (my chip runs, PR 65:
+# tools/moe_forms.py --shapes xing-mixed --skew ... --sweep 128,192, medians
+# of 3-5 x 10 calls), ms at a first round of 128 / of 192 slots:
+#   busiest 87-119 pairs (1.3-1.75 x the mean), no tile:   2.45 / 2.67
+#       (rounds of 128 for all, before PR 65: 2.53-2.54)
+#   busiest 150 (2.2 x):  2 tiles 2.56 / no tile 2.67
+#       (two rounds of 128, before PR 65: 4.79)
+#   busiest 176 (2.6 x):  10 tiles 3.10 / no tile 2.67
+#   busiest 236 (3.5 x):  11 tiles 3.18 / 3 tiles 2.87
+#   busiest 305 (4.5 x):  15 tiles 3.44 / 9 tiles 3.26
+# A tile is 66 us — its products 50 (an expert's 22 MB at 819 GB/s are 27),
+# the float32 scatter-add of its 128 rows 16 (the same as a one-hot product
+# at the highest precision; told that its rows ascend and are unique, XLA's
+# scatter took 74) — and 64 slots more in the first round cost 0.22 ms,
+# three tiles' worth, in every call.  The cell decides: its busiest expert
+# of a layer holds FOUR mean loads (257-273 pairs on the mean of a mixed
+# step's busiest layer, p95 329-372), so 128 slots run 34-36 tiles a step
+# over the five layers and 192 slots 5.7-7.7 — `itl_p95_ms` 59.75 / 59.36
+# at 128, 59.33 / 58.76 at 192, mixed step p50 46.0 / 47.6 against 45.4 /
+# 46.7 ms (two seeds a side).  Twice the mean it is: 192 slots for the Xing
+# step, and one tile for every other cell's grouped call (8-32 pairs an
+# expert on the mean: LFM2's busiest holds 116-126 of 128).
+_FIRST_ROUND_OVER_MEAN = 2.0
 
 
 def ridge_rows(itemsize: int) -> int:
     """Rows from which the dense form is bound by the MXU and no longer by
     the weights' read (weights of `itemsize` bytes)."""
     return _RIDGE_ROWS_PER_BYTE * itemsize
+
+
+def first_round_slots(rows: int, top_k: int, n_experts: int) -> int:
+    """Slots an expert has in the grouped form's first round, for `rows`
+    token rows routed top_k of `n_experts`: a pure function of what a trace
+    sees, as `expert_form` is.  `_GROUP_SLOTS` (128) wherever
+    `_FIRST_ROUND_OVER_MEAN` mean loads fit in it — every grouped call of
+    every cell but the Xing cell's mixed step, 192 —, else the next half
+    tile that holds them."""
+    half = max(_GROUP_SLOTS // 2, 1)
+    want = math.ceil(_FIRST_ROUND_OVER_MEAN * rows * top_k / n_experts)
+    return max(_GROUP_SLOTS, half * -(-want // half))
+
+
+def overflow_tiles(sizes: Array, slots: int) -> Array:
+    """Tiles of `_GROUP_SLOTS` slots that each expert's pairs beyond its
+    first round's `slots` fill (`sizes`: pairs an expert, int32): what the
+    grouped form's overflow loop runs, expert by expert."""
+    return -(-jnp.maximum(sizes - slots, 0) // _GROUP_SLOTS)
 
 
 def expert_form(rows: int, top_k: int, n_experts: int, itemsize: int, *,
@@ -204,10 +262,11 @@ def expert_form(rows: int, top_k: int, n_experts: int, itemsize: int, *,
     ridge = ridge_rows(itemsize)
     if partitioned or training or _GROUP_SLOTS > ridge:
         return "dense"
-    # each round reads the weights once, as `ridge` rows of the dense form
-    # do; the busiest expert is reckoned at twice the mean load
-    rounds = -(-2 * rows * top_k // (n_experts * _GROUP_SLOTS))
-    return "grouped" if rows >= _GROUPED_OVER_RIDGE * ridge * rounds \
+    # the first round reads the weights once, as `ridge` rows of the dense
+    # form do, for as long as its slots stay under the ridge themselves (in
+    # every cell); the overflow reads an expert's weights a tile, not a round
+    reads = -(-first_round_slots(rows, top_k, n_experts) // ridge)
+    return "grouped" if rows >= _GROUPED_OVER_RIDGE * ridge * reads \
         else "dense"
 
 
@@ -243,16 +302,20 @@ def _expert_products(xs, experts, activation):
 
 
 def _experts_grouped(x, experts, idx, weight, first_expert, activation,
-                     valid):
+                     valid, n_experts):
     """The held experts' part over the routed pairs alone: each held pair
     takes a slot of its expert — its rank among that expert's pairs, by a
-    stable sort —, the slots' rows of `x` are gathered `[h, slots, D]`, the
-    experts multiply their own slots, and each pair's result is weighed and
-    added to its row in float32.  `_GROUP_SLOTS` slots an expert a round, as
-    many rounds as the busiest expert needs: nothing is dropped.  An empty
-    slot computes row 0 and is read by no pair."""
+    stable sort.  The first round is whole: every held expert's first
+    `first_round_slots` slots, their rows of `x` gathered `[h, slots, D]`,
+    the experts multiplying their own slots, each pair's result weighed and
+    added to its row in float32; an empty slot computes row 0 and is read
+    by no pair.  The pairs of rank beyond it go expert by expert, a tile of
+    `_GROUP_SLOTS` slots at a time over the experts that have such pairs
+    alone, each tile against that one expert's weights: nothing is
+    dropped."""
     B, k = idx.shape
-    C = _GROUP_SLOTS
+    T = _GROUP_SLOTS
+    C = first_round_slots(B, k, n_experts)
     n_held = experts[0].shape[0]
     e = idx - first_expert
     held = jnp.logical_and(e >= 0, e < n_held)             # [B, k]
@@ -263,28 +326,43 @@ def _experts_grouped(x, experts, idx, weight, first_expert, activation,
     sizes = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :], axis=0,
                     dtype=jnp.int32)
     e = jnp.clip(e, 0, n_held - 1)
-    place = jnp.argsort(jnp.argsort(key, stable=True))     # sorted position
-    rank = place.reshape(B, k) - jnp.take(jnp.cumsum(sizes) - sizes, e)
+    order = jnp.argsort(key, stable=True)          # sorted position -> pair
+    place = jnp.argsort(order)                     # pair -> sorted position
+    first = jnp.cumsum(sizes) - sizes              # an expert's first position
+    rank = place.reshape(B, k) - jnp.take(first, e)
     row_of_pair = jnp.arange(B * k, dtype=jnp.int32) // k
 
-    def one_round(carry):
-        r, y = carry
-        slot = rank - r * C
-        mine = jnp.logical_and(held, jnp.logical_and(slot >= 0, slot < C))
-        at = jnp.where(mine, e * C + slot, n_held * C).reshape(-1)
-        rows = jnp.zeros((n_held * C,), jnp.int32).at[at].set(
-            row_of_pair, mode="drop")
-        xs = jnp.take(x, rows, axis=0).reshape(n_held, C, -1)
-        out = _expert_products(xs, experts, activation)
-        out = jnp.take(out.reshape(n_held * C, -1), at, axis=0, mode="clip")
-        out = jnp.where(mine[:, :, None], out.reshape(B, k, -1).astype(
-            jnp.float32) * weight[:, :, None], 0.0)
-        return r + 1, y + jnp.sum(out, axis=1)
+    mine = jnp.logical_and(held, rank < C)
+    at = jnp.where(mine, e * C + rank, n_held * C).reshape(-1)
+    rows = jnp.zeros((n_held * C,), jnp.int32).at[at].set(
+        row_of_pair, mode="drop")
+    xs = jnp.take(x, rows, axis=0).reshape(n_held, C, -1)
+    out = _expert_products(xs, experts, activation)
+    out = jnp.take(out.reshape(n_held * C, -1), at, axis=0, mode="clip")
+    out = jnp.where(mine[:, :, None], out.reshape(B, k, -1).astype(
+        jnp.float32) * weight[:, :, None], 0.0)
+    y = jnp.sum(out, axis=1)
 
-    d_out = experts[-1].shape[-1]
-    _, y = jax.lax.while_loop(
-        lambda carry: carry[0] * C < jnp.max(sizes), one_round,
-        (jnp.int32(0), jnp.zeros((B, d_out), jnp.float32)))
+    tiles = overflow_tiles(sizes, C)                       # [h]
+    ends = jnp.cumsum(tiles)
+    lane = jnp.arange(T, dtype=jnp.int32)
+    weight = weight.reshape(-1)
+
+    def one_tile(carry):
+        i, y = carry
+        h = jnp.sum(ends <= i, dtype=jnp.int32)    # the tile's expert
+        slot = C + (i - ends[h] + tiles[h]) * T + lane
+        pair = jnp.take(order, first[h] + slot, mode="clip")
+        # an empty slot computes some row and adds it nowhere
+        row = jnp.where(slot < sizes[h], jnp.take(row_of_pair, pair), B)
+        one = tuple(jax.lax.dynamic_index_in_dim(w, h, 0) for w in experts)
+        out = _expert_products(jnp.take(x, row, axis=0, mode="clip")[None],
+                               one, activation)[0]
+        out = out.astype(jnp.float32) * jnp.take(weight, pair)[:, None]
+        return i + 1, y.at[row].add(out, mode="drop")
+
+    _, y = jax.lax.while_loop(lambda carry: carry[0] < ends[-1], one_tile,
+                              (jnp.int32(0), y))
     return y.astype(jnp.result_type(x.dtype, experts[0].dtype))
 
 
@@ -326,7 +404,7 @@ def moe_ffn(
             y = jnp.einsum("ebd,be->bd", out, comb.astype(out.dtype))
         else:
             y = _experts_grouped(x, experts, idx, weight, first_expert,
-                                 activation, valid)
+                                 activation, valid, w_router.shape[-1])
     return y, aux, pairs
 
 

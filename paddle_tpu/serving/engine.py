@@ -557,6 +557,8 @@ class ServingEngine:
                             if l.type == "moe"]
         self.moe_pairs_total = 0       # routed pairs the held experts drew
         self.moe_pairs_max_sum = 0     # sum over steps of the busiest's
+        self.moe_overflow_tiles = 0    # tiles the grouped overflow ran
+        self.moe_layer_pairs_max_sum = 0   # ... of the busiest of a LAYER
         self.moe_steps = 0             # steps counted
         # of those, the steps whose program runs the expert block's grouped
         # form (parallel/moe.py: the rule answers from a step's rows when
@@ -2957,18 +2959,23 @@ class ServingEngine:
 
     def _with_counts(self, nxt, state_out: dict, live_rows):
         """`nxt` with the step's device-side counts behind it, one array
-        and one read-back: [S + E_held (+ 2)] int32 — the routed pairs of
-        the held experts, summed over the MoE layers and the live rows,
-        then for a model with recurrent layers the rows that advanced a
-        state and the slot states read and written, summed over those
-        layers.  `nxt` itself for a model with neither."""
+        and one read-back: [S + E_held + 2 (+ 2)] int32 — the routed pairs
+        of the held experts, summed over the MoE layers and the live rows,
+        the overflow tiles the layers' grouped form ran and the busiest
+        expert's pairs in its busiest LAYER, then for a model with
+        recurrent layers the rows that advanced a state and the slot states
+        read and written, summed over those layers.  `nxt` itself for a
+        model with neither."""
         tail = []
         if self._moe_layers:
             live = live_rows.reshape(-1, 1)
-            tail.append(sum(
-                jnp.sum(jnp.logical_and(state_out[name]["pairs"], live),
-                        axis=0, dtype=jnp.int32)
-                for name in self._moe_layers))
+            pairs = [jnp.sum(jnp.logical_and(state_out[name]["pairs"], live),
+                             axis=0, dtype=jnp.int32)
+                     for name in self._moe_layers]
+            tail += [sum(pairs), jnp.stack([
+                sum(state_out[name]["overflow_tiles"]
+                    for name in self._moe_layers),
+                jnp.max(jnp.stack(pairs))])]
         if self._recurrent:
             tail.append(jnp.stack([
                 state_out[self._recurrent[0]]["rows"],
@@ -3021,8 +3028,8 @@ class ServingEngine:
     def _count_moe(self, nxt: np.ndarray, n_rows: int,
                    kind: str) -> np.ndarray:
         """Split a step's read-back into its tokens and the counts behind
-        them (the MoE pairs, then the two recurrent counts); bank the
-        counts.  Returns the tokens."""
+        them (the MoE pairs, overflow tiles and layer maximum, then the two
+        recurrent counts); bank the counts.  Returns the tokens."""
         if self._recurrent:
             rows, updates = int(nxt[-2]), int(nxt[-1])
             nxt = nxt[:-2]
@@ -3034,14 +3041,19 @@ class ServingEngine:
             pc.add("serving_recurrent_slot_updates_total", updates)
             pc.add("serving_recurrent_steps_total", 1)
         if nxt.size > n_rows:
-            pairs = nxt[n_rows:]
+            pairs = nxt[n_rows:-2]
             total, busiest = int(pairs.sum()), int(pairs.max())
+            tiles, layer_max = int(nxt[-2]), int(nxt[-1])
             self.moe_pairs_total += total
             self.moe_pairs_max_sum += busiest
+            self.moe_overflow_tiles += tiles
+            self.moe_layer_pairs_max_sum += layer_max
             self.moe_steps += 1
             pc = process_counters()
             pc.add("serving_moe_pairs_total", total)
             pc.add("serving_moe_pairs_max_total", busiest)
+            pc.add("serving_moe_overflow_tiles_total", tiles)
+            pc.add("serving_moe_layer_pairs_max_total", layer_max)
             pc.add("serving_moe_steps_total", 1)
             if self._moe_grouped(kind):
                 self.moe_grouped_steps[kind] = 1 + \

@@ -436,6 +436,10 @@ class ServingServer:
                  float(eng.moe_pairs_total)),
                 ("serving_moe_pairs_max_total", "counter", None,
                  float(eng.moe_pairs_max_sum)),
+                ("serving_moe_layer_pairs_max_total", "counter", None,
+                 float(eng.moe_layer_pairs_max_sum)),
+                ("serving_moe_overflow_tiles_total", "counter", None,
+                 float(eng.moe_overflow_tiles)),
                 ("serving_moe_steps_total", "counter", None,
                  float(eng.moe_steps)),
                 *(("serving_moe_grouped_steps_total", "counter",
@@ -1723,6 +1727,10 @@ class ServingServer:
             # routed pairs the held experts drew (0 without MoE layers)
             "moe_pairs_total": eng.moe_pairs_total,
             "moe_pairs_max_sum": eng.moe_pairs_max_sum,
+            # the busiest expert of a step's busiest LAYER, and the tiles
+            # the grouped form's overflow loop ran
+            "moe_layer_pairs_max_sum": eng.moe_layer_pairs_max_sum,
+            "moe_overflow_tiles": eng.moe_overflow_tiles,
             "moe_steps": eng.moe_steps,
             # of those, the steps whose program ran the grouped expert
             # product, by step kind (the rule: parallel/moe.py)

@@ -81,7 +81,8 @@ def engines():
 COUNTERS = ("n_decode_steps", "n_prefill_chunks", "n_chunk_rows",
             "n_chunk_extra_rows", "recurrent_steps",
             "recurrent_slot_updates", "recurrent_rows", "moe_steps",
-            "moe_pairs_total", "moe_pairs_max_sum")
+            "moe_pairs_total", "moe_pairs_max_sum",
+            "moe_layer_pairs_max_sum", "moe_overflow_tiles")
 
 
 def counted(eng, since=None):

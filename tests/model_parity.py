@@ -960,6 +960,10 @@ def test_engine_serves_lm_generates_tokens(case, model, ref, engines,
         # the held experts' load reached the counters with the tokens
         assert n["moe_steps"] == steps
         assert 0 < n["moe_pairs_max_sum"] <= n["moe_pairs_total"]
+        # the busiest expert of a step's busiest layer; no tile under the
+        # dense form these widths keep
+        assert 0 < n["moe_layer_pairs_max_sum"] <= n["moe_pairs_max_sum"]
+        assert n["moe_overflow_tiles"] == 0
     if layers:
         # every counted step; at most one state a slot a layer a step
         assert steps >= n["n_decode_steps"]

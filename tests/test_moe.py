@@ -201,8 +201,8 @@ GROUPED_CASES = {
     # experts [2, 6) of 8 held: pairs of the others belong elsewhere
     "gated-held-block": dict(gated=True, first=2, held=4),
     "plain-held-block": dict(gated=False, first=2, held=4),
-    # expert 0 draws every row (24 pairs: 3 rounds of 8 slots), expert 1
-    # none
+    # expert 0 draws every row (24 pairs: a first round of 12 slots, then
+    # two tiles of 8), expert 1 none
     "hot-and-idle-expert": dict(gated=True, skew=True),
     "plain-hot-and-idle-expert": dict(gated=False, skew=True, first=1,
                                       held=5),
@@ -219,6 +219,26 @@ GROUPED_CASES = {
                                   H=128, first=2, held=4),
     "bf16-hot-and-idle": dict(gated=True, dtype="bfloat16", D=128, H=128,
                               skew=True),
+    # the overflow's own (first round 8 slots at 32 rows top-2 of 16
+    # experts scored, tiles of 8): expert 0 draws all 32 rows — three tiles past the first round
+    # — and expert 1 none, the rest a pair or two each
+    "overflow-three-tiles": dict(gated=True, B=32, E=16, held=16, hot=[0]),
+    # experts 0 and 1 draw 32 and 19 rows: three tiles and two, the last
+    # of expert 1 holding three pairs
+    "overflow-two-experts": dict(gated=False, B=32, E=16, held=16,
+                                 hot=[0, 1], hot_rows=[32, 19]),
+    # loads of exactly the first round and of exactly one tile more: no
+    # tile for the one, one full tile and no empty second for the other
+    "overflow-exact-fill": dict(gated=True, B=32, E=16, held=16,
+                                hot=[0, 1], hot_rows=[8, 16]),
+    # the overflowing expert 0 is held elsewhere and draws nothing here;
+    # expert 5, held, overflows by a tile and a half
+    "overflow-held-elsewhere": dict(gated=True, B=32, E=16, first=4,
+                                    held=8, hot=[0, 5], hot_rows=[32, 20]),
+    "overflow-valid-mask": dict(gated=False, B=32, E=16, held=16, hot=[2],
+                                masked=True),
+    "overflow-bf16": dict(gated=True, dtype="bfloat16", D=128, H=128, B=32,
+                          E=16, held=16, hot=[0, 3], hot_rows=[32, 21]),
 }
 
 
@@ -226,10 +246,12 @@ GROUPED_CASES = {
 def test_grouped_form_equals_the_dense_form(case, monkeypatch):
     """The routed pairs in their experts' slots against every row times
     every held expert: the same `y` within the operands' rounding, `pairs`
-    and `aux` identical.  8 slots an expert a round, so the cases run one
-    round to three."""
+    and `aux` identical.  Tiles of 8 slots (the first round 8 or 12 an
+    expert), so the cases run the first round alone to three tiles past
+    it."""
     c = dict(dict(B=24, k=2, E=8, first=0, held=8, D=8, H=16,
-                  dtype="float32", skew=False, masked=False, slots=8),
+                  dtype="float32", skew=False, masked=False, slots=8,
+                  hot=(), hot_rows=None),
              **GROUPED_CASES[case])
     monkeypatch.setattr(moe, "_GROUP_SLOTS", c["slots"])
     rng = np.random.default_rng(sum(map(ord, case)))
@@ -240,6 +262,13 @@ def test_grouped_form_equals_the_dense_form(case, monkeypatch):
     if c["skew"]:
         x[:, 0] = np.abs(x[:, 0]) + 3.0
         w_r[0, 0], w_r[0, 1] = 10.0, -10.0
+    # a hot expert j draws the first hot_rows[j] rows (all of them without
+    # `hot_rows`): feature j of those rows decides for it
+    for j, e in enumerate(c["hot"]):
+        n = B if c["hot_rows"] is None else c["hot_rows"][j]
+        x[:, j] = np.where(np.arange(B) < n, 4.0 + 0.01 * j, -4.0)
+        w_r[j] = 0.0
+        w_r[j, e] = 10.0 * (D / 8) ** 0.5     # past the other features' sum
     x = jnp.asarray(x, dtype)
     shapes = [(h, D, H), (h, D, H), (h, H, D)] if c["gated"] \
         else [(h, D, H), (h, H), (h, H, D), (h, D)]
@@ -261,6 +290,25 @@ def test_grouped_form_equals_the_dense_form(case, monkeypatch):
         lo = c["first"]
         assert lo > 0 or bool(pairs_d[:, 0].all())      # draws every row
         assert not bool(pairs_d[:, 1 - lo].any())       # draws none
+    if c["hot"]:
+        # the case runs the overflow it names: the tiles the loop ran,
+        # counted from the pairs as the engine's counter counts them
+        lo, live = c["first"], np.ones(B, bool) if valid is None \
+            else np.asarray(valid)
+        rows = [live.sum() if c["hot_rows"] is None else n
+                for n in c["hot_rows"] or [None] * len(c["hot"])]
+        sizes = np.asarray(pairs_d).sum(axis=0)
+        for e, n in zip(c["hot"], rows):
+            if lo <= e < lo + h:
+                assert sizes[e - lo] == n, (sizes, e, n)
+        first = moe.first_round_slots(B, c["k"], E)
+        assert first == c["slots"]
+        tiles = np.asarray(moe.overflow_tiles(jnp.asarray(sizes, jnp.int32),
+                                              first))
+        want = [-(-max(n - first, 0) // c["slots"]) if lo <= e < lo + h
+                else 0 for e, n in zip(c["hot"], rows)]
+        assert [int(tiles[e - lo]) if lo <= e < lo + h else 0
+                for e in c["hot"]] == want and tiles.sum() >= sum(want) > 0
     y_d, y_g = (np.asarray(y, np.float32) for y in (y_d, y_g))
     assert np.isfinite(y_g).all()
     if c["masked"] == "all":
@@ -280,6 +328,15 @@ RULE_AT_THE_CELLS = {
     "kimi-mixed-320x16": ((320, 8, 256), None),
     "lfm2-decode-256x64": ((256, 4, 64), None),
     "lfm2-mixed-512x64": ((512, 4, 64), "grouped"),
+    # 68 pairs an expert on the mean: one first round of 192 slots, the
+    # busiest experts' overflow in tiles (two or three whole rounds of 128
+    # before PR 65)
+    "xing-mixed-1088x64": ((1088, 4, 64), "grouped"),
+    "xing-decode-48x64": ((48, 4, 64), "dense"),
+    "laguna-mixed-320x256": ((320, 8, 256), None),
+    "solar-mixed-320x40": ((320, 8, 320), None),
+    "nemotron-mixed-512x32": ((512, 6, 128), "grouped"),
+    "nemotron-decode-256x32": ((256, 6, 128), None),
 }
 
 
@@ -298,13 +355,36 @@ def test_the_rule_at_the_cells_shapes(cell):
     assert moe.expert_form(rows, k, scored, 2, training=True) == "dense"
 
 
+@pytest.mark.parametrize("cell", list(RULE_AT_THE_CELLS))
+def test_the_first_rounds_slots_at_the_cells_shapes(cell):
+    """One tile of 128 slots an expert at every cell's two step shapes but
+    the Xing cell's mixed step, which takes a tile and a half (twice its 68
+    pairs an expert on the mean; the measurement beside
+    `_FIRST_ROUND_OVER_MEAN`); the rule counts a first round wider than the
+    ridge as the reads it is worth."""
+    (rows, k, scored), _ = RULE_AT_THE_CELLS[cell]
+    assert moe._GROUP_SLOTS == 128
+    want = 192 if cell == "xing-mixed-1088x64" else 128
+    assert moe.first_round_slots(rows, k, scored) == want
+    assert (2 * rows * k / scored <= 128) == (want == 128)
+    # 100 pairs an expert on the mean want 256 slots, a second read's worth
+    # of rows (the ridge is 240): 1,600 rows are past 1.25 x 2 x 240
+    assert moe.first_round_slots(1600, 4, 64) == 256
+    assert moe.expert_form(1600, 4, 64, 2) == "grouped"
+    # 256 pairs an expert on the mean: 512 slots are three reads' worth,
+    # and 512 rows are under 1.25 x 3 x 240
+    assert moe.first_round_slots(512, 4, 8) == 512
+    assert moe.expert_form(512, 4, 8, 2) == "dense"
+    assert moe.expert_form(4096, 4, 64, 2) == "grouped"
+
+
 def test_the_rule_as_the_layer_asks_it():
     """graph/layers_moe.py:expert_form_of reads the shapes off the layer's
     parameters; a mesh with a `model` axis keeps the dense form, a data
     mesh does not; float32 weights double the ridge; where so few experts
-    are scored that the routed pairs would fill round after round, or
-    where a round's slots would themselves pass the ridge (1-byte weights),
-    dense stays."""
+    are scored that the first round's slots pass the ridge and cost a
+    second read's worth, or where a tile's slots would themselves pass the
+    ridge (1-byte weights), dense stays."""
     from types import SimpleNamespace as NS
     from paddle_tpu.graph.layers_moe import expert_form_of
     from paddle_tpu.parallel.mesh import make_mesh

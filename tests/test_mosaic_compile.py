@@ -546,6 +546,64 @@ def test_expert_block_at_the_cells_shapes(one_chip, no_persistent_cache,
     assert temp < 128 * 2 ** 20, temp
 
 
+def test_overflow_tile_reads_one_experts_weights(one_chip,
+                                                 no_persistent_cache):
+    """The Xing cell's mixed step through the grouped form (1,088 rows, 64
+    held experts of 3 x 3584 x 1024, bf16: a first round of 192 slots an
+    expert, then the loop over the overflow's tiles), compiled for the
+    chip: inside the loop the 1.41 GB of stacked weights are operands of
+    dynamic slices alone — no copy, transpose, pad or whole-stack product a
+    tile — and a tile's own buffers are an expert's size, not the stack's."""
+    import re
+    from paddle_tpu.parallel import moe
+    rows, h, D, H, k = 1088, 64, 3584, 1024, 4
+    assert moe.expert_form(rows, k, h, 2) == "grouped"
+    assert moe.first_round_slots(rows, k, h) == 192
+
+    def block(x, w_r, experts):
+        return moe.moe_ffn(x, w_r, experts, top_k=k, scoring="sigmoid")[0]
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(block).lower(
+        arg((rows, D), bf16), arg((D, h), f32),
+        (arg((h, D, H), bf16), arg((h, D, H), bf16), arg((h, H, D), bf16))
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text and text.count(" while(") == 1
+    stack = rf"bf16\[{h},(?:{D},{H}|{H},{D})\]"
+    # the computations of the loop: its body, and the fusions whose ops
+    # were traced inside it
+    comps = re.split(r"\n(?=%|ENTRY )", text)
+    body = re.search(r" while\(.*body=(%[\w.-]+)", text).group(1)
+    in_loop = [c for c in comps if c.startswith(body + " ")
+               or "/while/body/" in c and not c.startswith("ENTRY")]
+    assert len(in_loop) > 3
+    sliced = 0
+    for comp in in_loop:
+        lines = comp.splitlines()[1:]
+        for line in lines:
+            made = re.search(rf"(%[\w.-]+) = {stack}\S* ([\w-]+)\(", line)
+            if made is None:
+                continue
+            # the stack is handed on as it is (the loop's tuple, a fusion's
+            # parameter, a bitcast): nothing in the loop MAKES one, and
+            # what takes one slices it or hands it to a fusion that does
+            name, op = made.groups()
+            assert op in ("parameter", "get-tuple-element", "bitcast"), line
+            for user in lines:
+                took = re.search(rf"= \S+ ([\w-]+)\(.*{re.escape(name)}[,)]",
+                                 user)
+                if took is not None and user is not line:
+                    assert took.group(1) in ("dynamic-slice", "bitcast",
+                                             "fusion", "tuple"), user
+                    sliced += took.group(1) == "dynamic-slice"
+    assert sliced >= 3, sliced              # gate, up and down, one expert's
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 160 * 2 ** 20, temp
+
+
 # the latent cell's own shapes (benchmark/configs/
 # gigachat3.1-702b-a36b-serve.json: 64 slots, page 16, context 4,096, 64
 # query heads against ONE 576-wide latent row stored 640 wide, its first 512
